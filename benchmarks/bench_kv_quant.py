@@ -42,6 +42,7 @@ import numpy as np
 
 from dynamo_tpu.models import llama
 from dynamo_tpu.quant.kv import kv_cache_bytes_per_block
+from dynamo_tpu.runtime.device import device_identity, require_tpu
 
 
 def capacity_report(cfg, block_size: int, hbm_gb: float,
@@ -129,7 +130,7 @@ def decode_report(args) -> dict:
     tok0 = jnp.asarray(
         np.random.default_rng(0).integers(3, cfg.vocab_size, B, np.int32))
 
-    on_tpu = any(d.platform == "tpu" for d in jax.devices())
+    on_tpu = args.mode == "tpu"
     pallas_impl = "pallas" if on_tpu else "pallas_interpret"
     rows = [("bf16", "auto"), ("int8", "auto"),
             ("bf16", pallas_impl), ("int8", pallas_impl)]
@@ -216,7 +217,14 @@ def main() -> None:
                    help="required matching-token fraction bf16 vs int8")
     p.add_argument("--skip-decode", action="store_true",
                    help="capacity + parity only (fast CPU smoke)")
+    p.add_argument("--mode", default="tpu", choices=["tpu", "smoke"],
+                   help="tpu (default): needs a TPU and fails without "
+                        "one, Pallas rows are the compiled kernel.  "
+                        "smoke: CPU run, interpret-mode Pallas rows "
+                        "labeled smoke")
     args = p.parse_args()
+    device = require_tpu() if args.mode == "tpu" else device_identity()
+    print(f"device: {json.dumps(device)} mode={args.mode}")
 
     ratio = capacity_report(llama.PRESETS[args.model], args.block,
                             args.hbm_gb, args.min_ratio)
@@ -238,9 +246,8 @@ def main() -> None:
     # (dtype x impl) decode rows plus the pass/fail state of every
     # assert that already fired above; mode labels interpret-mode rows
     # as a smoke so a scoreboard never mistakes them for chip numbers
-    on_tpu = bool(decode and decode["on_tpu"])
     print(json.dumps({
-        "bench": "kv_quant", "mode": "tpu" if on_tpu else "smoke",
+        "bench": "kv_quant", "mode": args.mode, "device": device,
         "model": args.model, "block_size": args.block,
         "capacity": {"int8_bf16_blocks_ratio": round(ratio, 3),
                      "min_ratio": args.min_ratio},

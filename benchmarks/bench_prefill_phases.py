@@ -51,8 +51,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 from dynamo_tpu.models import llama            # noqa: E402
 from dynamo_tpu.obs.compile_watch import xla_costs  # noqa: E402
 from dynamo_tpu.ops import packed_prefill as pp  # noqa: E402
-
-PEAK_TFLOPS = 197.0  # v5e dense bf16
+from dynamo_tpu.runtime.device import (  # noqa: E402
+    device_identity,
+    device_peaks,
+    require_tpu,
+)
 
 
 def _sync(r):
@@ -90,9 +93,30 @@ def main():
                    choices=["xla", "pallas", "pallas_interpret", "ab"],
                    help="packed-attention impl for the `packed` phase; "
                         "`ab` runs the XLA reference AND the Pallas "
-                        "tile-skip kernel (interpret mode off-TPU) and "
-                        "prints both variants' MFU in one JSON line")
+                        "tile-skip kernel (compiled in --mode tpu, "
+                        "interpreted in --mode smoke) and prints both "
+                        "variants' MFU in one JSON line")
+    p.add_argument("--mode", default="tpu", choices=["tpu", "smoke"],
+                   help="tpu (default): needs a TPU and fails without "
+                        "one; interpret-mode kernels are an error.  "
+                        "smoke: CPU run at toy size, rows labeled smoke")
     args = p.parse_args()
+    if args.mode == "tpu":
+        if args.impl == "pallas_interpret":
+            p.error("--impl pallas_interpret is not a measurement; "
+                    "use --mode smoke")
+        device = require_tpu()
+        # published bf16 peak of the chip JAX found (unknown kind: error)
+        peak_tflops = device_peaks(device["kind"])["bf16_tflops"]
+    else:
+        device = device_identity()
+        peak_tflops = None  # off-chip rows carry no MFU
+
+    def mfu_of(flops, t):
+        return (None if peak_tflops is None
+                else flops / t / (peak_tflops * 1e12))
+
+    print(f"device: {json.dumps(device)} mode={args.mode}")
     if args.seqs > args.tokens:
         p.error(f"--seqs ({args.seqs}) must be <= --tokens "
                 f"({args.tokens})")
@@ -149,17 +173,17 @@ def main():
         tables=tables, last_idx=last_idx).items()}
 
     def report(name, t, tokens, flops):
-        mfu = flops / t / (PEAK_TFLOPS * 1e12)
+        mfu = mfu_of(flops, t)
         print(f"  {name:10s} {t*1e3:8.2f} ms   {tokens/t/1e3:8.1f} ktok/s"
-              f"   MFU {mfu:5.3f}")
+              + (f"   MFU {mfu:5.3f}" if mfu is not None else ""))
 
     state = {"kv": kv}
 
     # --- packed: the serving path --------------------------------------
     if want("packed"):
         if args.impl == "ab":
-            on_tpu = any(d.platform == "tpu" for d in jax.devices())
-            impls = ["xla", "pallas" if on_tpu else "pallas_interpret"]
+            impls = ["xla", "pallas" if args.mode == "tpu"
+                     else "pallas_interpret"]
         else:
             impls = [args.impl]
         # analytic attention FLOPs per layer: score + pv matmuls over
@@ -191,7 +215,7 @@ def main():
 
             t = timeit(run_packed)
             est_flops = flops_per_tok * T
-            est_mfu = est_flops / t / (PEAK_TFLOPS * 1e12)
+            est_mfu = mfu_of(est_flops, t)
             # measured-program FLOPs from the roofline plane: XLA's own
             # HLO cost analysis of the compiled program (for the Pallas
             # variant the kernel's CostEstimate feeds this) — the
@@ -204,24 +228,23 @@ def main():
                 "ms": round(t * 1e3, 3),
                 "tok_per_s": round(T / t, 1),
                 "est_flops": est_flops,
-                "est_mfu": round(est_mfu, 4),
+                "est_mfu": est_mfu and round(est_mfu, 4),
                 "attn_flops_analytic": attn_base
                 * (S if impl == "xla" else 1),
             }
             if costs is not None:
                 row["xla_flops"] = costs["flops"]
                 row["xla_bytes"] = costs["bytes"]
-                row["xla_mfu"] = round(
-                    costs["flops"] / t / (PEAK_TFLOPS * 1e12), 4)
+                xla_mfu = mfu_of(costs["flops"], t)
+                row["xla_mfu"] = xla_mfu and round(xla_mfu, 4)
             variants[impl] = row
             report(f"packed/{impl}", t, T, flops_per_tok * T)
         print(json.dumps({
             "bench": "prefill_phases",
-            "mode": ("tpu" if any(d.platform == "tpu"
-                                  for d in jax.devices()) else "smoke"),
+            "mode": args.mode, "device": device,
             "model": args.model, "seqs": S,
             "tokens": T, "ctx_blocks": MB, "block": BLOCK,
-            "peak_tflops": PEAK_TFLOPS, "target_mfu": 0.4,
+            "peak_tflops": peak_tflops, "target_mfu": 0.4,
             "impls": variants,
         }))
 

@@ -25,13 +25,17 @@ Each bench contributes ONE summary JSON line to stdout:
   {"bench": ..., "round": "r07", "mode": "smoke"|"tpu",
    "gates": [{"name", "target", "value", "status"}...], "result": {...}}
 
-Off-TPU every bench still runs end to end at smoke scale (tiny model,
-interpret-mode kernels, mocker serving) so the driver is tier-1
+`--mode smoke` runs every bench end to end off-chip at smoke scale (tiny
+model, interpret-mode kernels, mocker serving) so the driver is tier-1
 testable — rows are labeled mode=smoke and every gate reports
-status=skipped_smoke instead of pass/fail.  On a chip (--mode tpu or
-auto-detected) the gates are enforced: any fail exits nonzero.
+status=skipped_smoke instead of pass/fail.  `--mode tpu` enforces the
+gates (any fail exits nonzero) and needs a chip: a child process asks JAX
+for the device first and the round stops with an error unless it is a
+TPU.  The mode is never guessed, and this parent never imports JAX — a
+chip belongs to one process at a time, and every bench is a child that
+takes it in its turn.
 
-    python benchmarks/run_round.py [--mode auto|smoke|tpu] [--only ...]
+    python benchmarks/run_round.py --mode smoke|tpu [--only ...]
 """
 
 import argparse
@@ -52,17 +56,17 @@ TARGET_PREFILL_MFU = 0.4
 BENCH_ARGS = {
     "prefill": {
         "script": "bench_prefill_phases.py",
-        "smoke": ["packed", "--impl", "ab", "--model", "tiny",
-                  "--tokens", "64", "--seqs", "2", "--ctx-blocks", "4",
-                  "--block", "16"],
-        "tpu": ["packed", "--impl", "ab"],
+        "smoke": ["packed", "--impl", "ab", "--mode", "smoke",
+                  "--model", "tiny", "--tokens", "64", "--seqs", "2",
+                  "--ctx-blocks", "4", "--block", "16"],
+        "tpu": ["packed", "--impl", "ab", "--mode", "tpu"],
     },
     "kv_quant": {
         "script": "bench_kv_quant.py",
-        "smoke": ["--batch", "2", "--ctx", "64", "--steps", "4",
-                  "--iters", "1", "--parity-seqs", "1"],
-        "tpu": ["--model", "llama-3b", "--ctx", "2048", "--block", "128",
-                "--batch", "8", "--steps", "32"],
+        "smoke": ["--mode", "smoke", "--batch", "2", "--ctx", "64",
+                  "--steps", "4", "--iters", "1", "--parity-seqs", "1"],
+        "tpu": ["--mode", "tpu", "--model", "llama-3b", "--ctx", "2048",
+                "--block", "128", "--batch", "8", "--steps", "32"],
     },
     "serving": {
         "script": "bench_serving.py",
@@ -94,14 +98,23 @@ BENCH_ARGS = {
 }
 
 
-def detect_mode() -> str:
-    try:
-        import jax
-
-        return ("tpu" if any(d.platform == "tpu" for d in jax.devices())
-                else "smoke")
-    except Exception:
-        return "smoke"
+def probe_device(timeout_s: float = 300.0) -> dict:
+    """Ask a CHILD what JAX's default backend is (this parent stays off
+    JAX so the benches it spawns can each take the chip).  The child has
+    exited — and released the chip — before the first bench starts.  A
+    probe that fails is an error, never a mode."""
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import json\n"
+         "from dynamo_tpu.runtime.device import device_identity\n"
+         "print(json.dumps(device_identity()))"],
+        capture_output=True, text=True, timeout=timeout_s,
+        env={**os.environ, "PYTHONPATH": REPO})
+    if proc.returncode != 0:
+        raise RuntimeError(
+            "device probe failed (rc=%d):\n%s" % (
+                proc.returncode, proc.stderr[-2000:]))
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def run_bench(name: str, argv, timeout_s: float):
@@ -202,10 +215,10 @@ EVALS = {"prefill": eval_prefill, "kv_quant": eval_kv_quant,
 def main() -> int:
     p = argparse.ArgumentParser(
         description="one-shot bench round driver (see module docstring)")
-    p.add_argument("--mode", default="auto",
-                   choices=["auto", "smoke", "tpu"],
-                   help="auto = tpu when a TPU backend is attached, "
-                        "else smoke (tiny geometry, gates skipped)")
+    p.add_argument("--mode", required=True, choices=["smoke", "tpu"],
+                   help="tpu = serving geometry on a chip, gates "
+                        "enforced, an error without a TPU; smoke = tiny "
+                        "geometry off-chip, gates skipped")
     p.add_argument("--only", nargs="*", choices=sorted(BENCH_ARGS),
                    default=None,
                    help="run a subset of the round's benches")
@@ -213,8 +226,18 @@ def main() -> int:
                    help="per-bench subprocess timeout")
     args = p.parse_args()
 
-    mode = detect_mode() if args.mode == "auto" else args.mode
+    mode = args.mode
     enforced = mode == "tpu"
+    if enforced:
+        device = probe_device()
+        if device["platform"] != "tpu":
+            sys.stderr.write(
+                f"--mode tpu needs a TPU; JAX's default backend here is "
+                f"{device}.  Smoke rows are never reported under "
+                "--mode tpu: run --mode smoke off-chip.\n")
+            return 2
+        print(json.dumps({"round": ROUND, "mode": mode,
+                          "device": device}), flush=True)
     failed = []
     for bench in (args.only or sorted(BENCH_ARGS)):
         spec = BENCH_ARGS[bench]

@@ -39,10 +39,10 @@ def get_transfer_server():
 
     OPT-IN via DYN_KV_TRANSFER_SERVER=1: the in-process loopback probe
     below cannot prove the backend's CROSS-process bulk transport works,
-    and on at least one PJRT plugin a real cross-process pull aborts the
-    SENDER process (fatal in the aux socket transport) — a dead prefill
-    worker is far worse than a host-staged copy.  Deployments on
-    backends with known-good DCN transfer enable it explicitly."""
+    and a cross-process pull that aborts the SENDER (seen on an earlier
+    set-up; not tried on today's) would kill a prefill worker — far
+    worse than a host-staged copy.  Deployments on backends with
+    known-good DCN transfer enable it explicitly."""
     global _server, _server_failed
     import os
 

@@ -55,11 +55,9 @@ import numpy as np
 
 from .. import obs
 
-try:
-    import ml_dtypes  # jax dependency; provides numpy bfloat16
-    _BF16 = np.dtype(ml_dtypes.bfloat16)
-except ImportError:  # pragma: no cover
-    _BF16 = None
+import ml_dtypes  # jax dependency; provides numpy bfloat16
+
+_BF16 = np.dtype(ml_dtypes.bfloat16)
 
 _DTYPES = {"float32": np.float32, "float16": np.float16,
            "int8": np.int8}
@@ -71,8 +69,6 @@ DEFAULT_CHUNK_BYTES = 16 * 1024 * 1024
 
 def _np_dtype(name: str):
     if name == "bfloat16":
-        if _BF16 is None:
-            raise ValueError("bfloat16 payload needs ml_dtypes")
         return _BF16
     return np.dtype(_DTYPES[name])
 

@@ -91,6 +91,7 @@ class JaxEngineWorker:
 
         self._fpm_window = FpmWindow()
         self._debug_source_name: Optional[str] = None
+        self._resident_bytes = None  # (params, kv) bytes per device id
 
     @property
     def card(self) -> ModelDeploymentCard:
@@ -385,6 +386,26 @@ class JaxEngineWorker:
         comp = rt.namespace(self.namespace).component(self.component)
         from ..protocols.llm import CANARY_GENERATE_PAYLOAD
 
+        if self.config.warmup and self.mh.world == 1:
+            # compile all decode variants BEFORE any endpoint is served:
+            # serving arms the health-check canary (30s idle, 10s to
+            # answer), and at serving widths the warm-up compiles outlast
+            # both — the canary then times out behind them, the endpoint
+            # turns NOT READY and the discovery lease is withdrawn before
+            # the worker ever said `ready` (first chip run, llama-3b).
+            # The canary's own request is warmed too, so the first idle
+            # probe does not sit behind a prefill compile either.
+            # Multi-host slices skip it: warmup dispatches are collective
+            # programs the followers would never replay (they only run
+            # what arrives on the step stream), so a leader-side warmup
+            # would hang the slice's collective schedule.
+            await asyncio.to_thread(self.engine.warmup_decode)
+            with self.engine.compile_watch.warming():
+                async for _ in self.engine.generate(
+                        PreprocessedRequest.from_dict(
+                            {**CANARY_GENERATE_PAYLOAD,
+                             "request_id": "warmup-canary"})):
+                    pass
         self.served = await comp.endpoint("generate").serve_endpoint(
             generate_handler,
             metadata={"model": self.config.served_name},
@@ -437,14 +458,6 @@ class JaxEngineWorker:
 
         broker.register_engine(instance_id, self.engine)
         self._broker_id = instance_id
-        if self.config.warmup and self.mh.world == 1:
-            # compile all decode variants BEFORE the model becomes
-            # discoverable, so no request ever waits on a decode compile.
-            # Multi-host slices skip it: warmup dispatches are collective
-            # programs the followers would never replay (they only run
-            # what arrives on the step stream), so a leader-side warmup
-            # would hang the slice's collective schedule.
-            await asyncio.to_thread(self.engine.warmup_decode)
         await register_model(rt, self.card, instance_id)
         self._load_task = asyncio.create_task(self._load_loop())
         # SLA-aware admission input (engine/core.py set_slo_burn): feed
@@ -558,8 +571,50 @@ class JaxEngineWorker:
             "itl_ema_s": eng.itl_ema_s,
             "itl_p95_s": fw.decode_itl_p95_s(),
             "compile": fw.compile_stats(),
+            # cumulative since start (the FPM window above forgets):
+            # per-family compile counts/seconds and the mid-serving total
+            "compile_watch": {
+                "counts": dict(eng.compile_watch.counts),
+                "seconds": {k: round(v, 3) for k, v in
+                            eng.compile_watch.seconds.items()},
+                "serving_compiles": eng.compile_watch.serving_compiles,
+            },
+            "device": self._device_state(),
             "engine_metrics": dict(eng.metrics),
             "config": dict(self.card.runtime_config),
+        }
+
+    def _device_state(self) -> dict:
+        """The mesh as the process that holds it sees it: identity,
+        per-device allocator stats (None where the backend keeps none,
+        e.g. CPU) and the bytes of parameter / KV shards resident on
+        each device — the evidence that a tp mesh really spread the
+        model instead of leaving it on the first chip."""
+        from ..runtime.device import device_identity
+
+        eng = self.engine
+        devs = list(eng.mesh.devices.flat)
+        if self._resident_bytes is None:
+            # placement is fixed at init: walk the trees once, not on
+            # every /debug/state scrape
+            def resident(tree) -> dict:
+                out = {d.id: 0 for d in devs}
+                for leaf in jax.tree_util.tree_leaves(tree):
+                    for sh in leaf.addressable_shards:
+                        out[sh.device.id] += sh.data.nbytes
+                return out
+
+            self._resident_bytes = (resident(eng.params), resident(eng.kv))
+        pbytes, kbytes = self._resident_bytes
+        return {
+            **device_identity(),
+            "mesh": {k: int(v) for k, v in eng.mesh.shape.items()},
+            "per_device": [
+                {"id": d.id, "param_bytes": pbytes[d.id],
+                 "kv_bytes": kbytes[d.id],
+                 "memory_stats": d.memory_stats()}
+                for d in devs
+            ],
         }
 
     async def _start_follower(self) -> "JaxEngineWorker":

@@ -111,6 +111,14 @@ async def main() -> None:
         grpc_service = await KserveGrpcService(
             rt, manager, host=args.host, port=args.grpc_port,
             resolver=service._resolve_pipeline).start()
+    from ..runtime.aio import install_drain_handler
+
+    async def stop() -> None:
+        # SIGTERM/SIGINT: fall through to the orderly close below and
+        # exit 0 (a second signal terminates at once)
+        rt.root_token.kill()
+
+    install_drain_handler(stop)
     print(f"ready port={args.port}", flush=True)
     try:
         await rt.root_token.wait_killed()

@@ -290,7 +290,7 @@ def registry_literals(mod: Module) -> Iterable[Finding]:
                     "DYN006", node,
                     f"span kind {kind!r} is not in obs.SPAN_KINDS — the "
                     "report and dashboards join on the registered "
-                    "taxonomy; add the kind there or fix the typo")
+                    "vocabulary; add the kind there or fix the typo")
 
 
 # ---------------------------------------------------------------------------
@@ -604,7 +604,7 @@ def hop_literals(mod: Module) -> Iterable[Finding]:
                 "DYN012", node,
                 f"hop kind {kind!r} is not in obs.HOP_KINDS — the exact "
                 "phase partition and the tail autopsy join on the "
-                "registered taxonomy; register the kind (and its "
+                "registered vocabulary; register the kind (and its "
                 "docstring-table row) or fix the typo")
 
 

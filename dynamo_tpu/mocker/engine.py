@@ -552,7 +552,7 @@ class MockEngine:
                  if self.publisher is not None else self.args.model_name))
         # timeline spans: same kinds (and zero-cost-off None check) as
         # JaxEngine._sched_step, so obs.report decomposes a mocker run
-        # with the same phase taxonomy.  Overlap sim: mid decode-only
+        # with the same phase vocabulary.  Overlap sim: mid decode-only
         # stretch the "device" (the previous burst's sleep) was still
         # running while this host work happens, so it reports as
         # enqueue_ahead — and the sleep below shrinks by the host time,

@@ -175,7 +175,11 @@ def kv_cache_specs() -> Tuple[P, P]:
 # ---------------------------------------------------------------------------
 
 
-def init_params(cfg: DeepseekConfig, key: jax.Array) -> Dict[str, Any]:
+def init_params(cfg: DeepseekConfig, key: jax.Array,
+                place=lambda tree: tree) -> Dict[str, Any]:
+    """Random-init parameter pytree; `place` as in llama.init_params
+    (applied to the top-level leaves and to each layer as it exists)."""
+
     def dense(key, shape, scale=None):
         scale = scale if scale is not None else 1.0 / math.sqrt(shape[0])
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(
@@ -192,6 +196,7 @@ def init_params(cfg: DeepseekConfig, key: jax.Array) -> Dict[str, Any]:
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(keys[1], (cfg.d_model, cfg.vocab_size))
+    params = place(params)
     layers = []
     for li in range(cfg.n_layers):
         k = jax.random.split(keys[2 + li], 13)
@@ -237,7 +242,7 @@ def init_params(cfg: DeepseekConfig, key: jax.Array) -> Dict[str, Any]:
             layer["w_gate"] = dense(k[6], (cfg.d_model, cfg.ffn_dim))
             layer["w_up"] = dense(k[7], (cfg.d_model, cfg.ffn_dim))
             layer["w_down"] = dense(k[8], (cfg.ffn_dim, cfg.d_model))
-        layers.append(layer)
+        layers.append(place(layer))
     params["layers"] = layers
     return params
 

@@ -234,8 +234,14 @@ PRESETS: Dict[str, LlamaConfig] = {
 # ---------------------------------------------------------------------------
 
 
-def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
-    """Random-init parameter pytree (weight loading fills the same tree)."""
+def init_params(cfg: LlamaConfig, key: jax.Array,
+                place=lambda tree: tree) -> Dict[str, Any]:
+    """Random-init parameter pytree (weight loading fills the same tree).
+
+    `place` is applied to the top-level leaves and to each layer's dict
+    as soon as it exists (the engine passes shard_params over its mesh):
+    a model that needs the whole mesh is then never whole on the default
+    device — only one layer at a time is.  Values do not depend on it."""
 
     def dense(key, shape, scale=None):
         scale = scale if scale is not None else 1.0 / math.sqrt(shape[0])
@@ -250,6 +256,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(keys[1], (cfg.d_model, cfg.vocab_size))
+    params = place(params)
     layers = []
     for i in range(cfg.n_layers):
         k = jax.random.split(keys[2 + i], 8)
@@ -277,7 +284,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         if cfg.qk_norm:
             layer["q_norm"] = {"norm": jnp.ones((cfg.head_dim,), jnp.float32)}
             layer["k_norm"] = {"norm": jnp.ones((cfg.head_dim,), jnp.float32)}
-        layers.append(layer)
+        layers.append(place(layer))
     params["layers"] = layers
     return params
 

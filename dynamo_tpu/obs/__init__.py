@@ -1,7 +1,7 @@
 """Timeline tracing plane: span-attributed engine steps + flight recorder.
 
-BENCH_r05 measured the served path at 0.40 of its own raw decode loop,
-and nothing in the process could say *where* the other 60% goes — the
+The served path ran at 0.40 of its own raw decode loop (measured on an
+earlier set-up; not measured on today's code), and nothing in the process could say *where* the other 60% goes — the
 FPM deque records per-dispatch aggregates, but no record decomposes a
 scheduler step into host-schedule / device-wait / sample / detokenize /
 frame-egress time, and nothing stitches a request's journey across
@@ -38,7 +38,7 @@ Design (mirrors the chaos plane's zero-cost-off contract):
     `request_end` record, its `request` span, and every worker's
     `worker_request` / pull spans for that request.
 
-Span taxonomy (kind — where — what the time is):
+Span vocabulary (kind — where — what the time is):
 
   step             engine _sched_step / mocker _step: one scheduler
                    iteration end to end
@@ -104,11 +104,11 @@ DEFAULT_RING = 16384
 
 # span kinds the engine-step partition is scored on (report.py groups
 # everything else under its own name); kept here so engine, mocker and
-# report agree on the taxonomy
+# report agree on the vocabulary
 STEP_PHASES = ("sched", "enqueue_ahead", "prefill_dispatch",
                "decode_dispatch", "device_wait", "sample")
 
-# THE canonical span taxonomy (the docstring table above, plus the
+# THE canonical span vocabulary (the docstring table above, plus the
 # compile watchdog's span): every obs.span()/obs.end() call site names
 # one of these, and the DYN006 lint (lint/rules.py) checks the literals
 # statically — a typo'd kind would otherwise produce an orphan span the
@@ -293,7 +293,7 @@ class Tracer:
 
 _TRACER: Optional[Tracer] = None
 
-# the forensics plane's hop taxonomy, re-exported here so call sites
+# the forensics plane's hop vocabulary, re-exported here so call sites
 # (and the DYN012 lint) address it as ``obs.HOP_KINDS`` — the same
 # one-registry pattern as SPAN_KINDS above (forensics.py is stdlib-only,
 # so this import stays cheap for the lint's registry load)

@@ -42,6 +42,7 @@ Mechanism (no second compile, no steady-state cost):
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 from collections import deque
@@ -74,8 +75,6 @@ def xla_costs(fn, args) -> Optional[Dict[str, float]]:
     try:
         sds = jax.tree_util.tree_map(_sds_of, args)
         ca = fn.lower(*sds).cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         flops = float(ca.get("flops", 0.0))
         byts = float(ca.get("bytes accessed", 0.0))
         if flops <= 0.0 and byts <= 0.0:
@@ -91,10 +90,19 @@ class WatchedProgram:
     ``cost(key)`` returns the XLA cost entry for the token-bucket key
     the dispatch site computes (0 for fixed-shape programs)."""
 
-    __slots__ = ("fn", "family", "watch", "tokens_of", "costs")
+    __slots__ = ("fn", "family", "watch", "tokens_of", "costs", "_counted")
 
     def __init__(self, fn, family: str, watch: "CompileWatch",
                  tokens_of: Optional[Callable] = None):
+        # a jit product (anything that lowers) MUST expose the compile
+        # counter: without it every compile would pass unseen and "zero
+        # mid-serving compiles" would read 0 for ever.  Plain callables
+        # (test stand-ins) have neither attribute and pass unwatched.
+        self._counted = hasattr(fn, "_cache_size")
+        if not self._counted and hasattr(fn, "lower"):
+            raise TypeError(
+                f"jit program {family!r} has no _cache_size(): this JAX "
+                "cannot be compile-watched; refusing to serve unwatched")
         self.fn = fn
         self.family = family
         self.watch = watch
@@ -106,11 +114,9 @@ class WatchedProgram:
 
     def __call__(self, *args):
         fn = self.fn
-        try:
-            n0 = fn._cache_size()
-        except AttributeError:
-            # not a pjit function (test stand-in): pass through unwatched
+        if not self._counted:
             return fn(*args)
+        n0 = fn._cache_size()
         t0 = time.monotonic()
         out = fn(*args)
         if fn._cache_size() > n0:
@@ -140,6 +146,17 @@ class CompileWatch:
         self.seconds: Dict[str, float] = {}
         self.serving_compiles = 0
         self.events: deque = deque(maxlen=256)
+
+    @contextlib.contextmanager
+    def warming(self):
+        """Compiles inside this block are warm-up, whatever `serving`
+        says: the worker's warm-up request occupies a slot like a real
+        one, and must not count as a mid-serving stall."""
+        prev, self._serving = self._serving, (lambda: False)
+        try:
+            yield
+        finally:
+            self._serving = prev
 
     def wrap(self, fn, family: str,
              tokens_of: Optional[Callable] = None):

@@ -8,7 +8,7 @@ gone.  This module is the qualitative complement: every request carries
 an ordered **hop timeline** (frontend/request_trace.py RequestTracker),
 and this plane retains the exemplars worth autopsying:
 
-  * **Hop taxonomy** (``HOP_KINDS`` — the DYN012 lint checks every
+  * **Hop vocabulary** (``HOP_KINDS`` — the DYN012 lint checks every
     ``tracker.hop(...)`` literal against it, the DYN006 pattern):
 
       received       tracker created (t=0 of the timeline)
@@ -70,7 +70,7 @@ logger = logging.getLogger(__name__)
 
 SCHEMA = "dynamo.forensics.v1"
 
-# THE canonical hop taxonomy (the docstring table above): every
+# THE canonical hop vocabulary (the docstring table above): every
 # RequestTracker.hop() call site names one of these, and the DYN012
 # lint (lint/rules.py) checks the literals statically — a typo'd hop
 # would otherwise produce an orphan timeline row the partition and the

@@ -71,7 +71,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 logger = logging.getLogger(__name__)
 
-# THE canonical ledger-op taxonomy (the DYN006/SPAN_KINDS registry
+# THE canonical ledger-op vocabulary (the DYN006/SPAN_KINDS registry
 # pattern): every record the ledger tapes names one of these; extend the
 # set and the docstring table together when adding an op.
 #

@@ -330,7 +330,7 @@ def kernel_tp_call(mesh, local, args, specs, k_scale=None, v_scale=None):
     inputs)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.compat import shard_map
+    from jax import shard_map
 
     args = list(args)
     specs = list(specs)

@@ -54,7 +54,16 @@ segments stream past — the property that lets all S segment passes
 share one carry without the reference's per-pass output select.
 Matches packed_prefill_attention's XLA path to bf16 matmul tolerance;
 interpret mode keeps the kernel runnable on CPU for tier-1
-(tests/test_packed_pallas.py).
+(tests/test_packed_pallas.py), tests/test_tpu_compile.py compiles it for
+a described v5e at serving widths, and chip_smoke.py checks the compiled
+kernel against the XLA path on the chip.
+
+Layout: query rows are (token, head-group) pairs flattened to one
+sublane axis — every in-kernel tensor is [nkv, R, *] with R = TB * group
+— and the per-row segment/position planes are [R, 1] columns.  Mosaic
+tiles the last two dims and cannot re-lay a [TB] lane vector out as the
+rows of a [TB, g, C] score tile, so nothing in the kernel ever needs
+that reshape.
 """
 
 from __future__ import annotations
@@ -66,11 +75,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_paged_attention import (
-    make_chunk_chain,
-    make_chunk_dma,
-    tpu_compiler_params,
-)
+from .pallas_paged_attention import make_chunk_chain, make_chunk_dma
 
 NEG_INF = -1e30
 
@@ -89,9 +94,10 @@ def _packed_kernel(
     base_ref,      # [n_tiles, S] int32 global slot phase per pair
     nseg_ref,      # [n_tiles, S] int32 successor segment row (-1 = none)
     # inputs
-    seg_ref,       # [1, TB] int32 segment row per token (-1 = padded)
-    pos_ref,       # [1, TB] int32 absolute position per token
-    q_ref,         # [nkv, TB, g, hd] VMEM (this tile's queries, pre-scaled)
+    seg_ref,       # [R, 1] int32 segment row per query row (-1 = padded)
+    pos_ref,       # [R, 1] int32 absolute position per query row
+    q_ref,         # [nkv, R, hd] VMEM (this tile's queries, pre-scaled;
+                   #   R = TB * g rows, token-major / group-minor)
     k_hbm,         # [nkv, num_blocks, hd, bs] ANY (stays in HBM)
     v_hbm,
     *rest,         # (+ks_hbm, vs_hbm when quantized) o_ref, scratch...
@@ -107,10 +113,10 @@ def _packed_kernel(
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
     t = pl.program_id(0)
     C = bpc * bs  # context positions per chunk
-    q = q_ref[...]            # [nkv, TB, g, hd]
-    seg = seg_ref[0]          # [TB]
-    pos = pos_ref[0]
-    nkv, TB, g, hd = q.shape
+    q = q_ref[...]            # [nkv, R, hd]
+    seg = seg_ref[...]        # [R, 1]
+    pos = pos_ref[...]
+    nkv, R, hd = q.shape
 
     # the chunk DMA contract (descriptor shapes, semaphore pairing, int8
     # scale lanes) is shared with the decode kernel; `row` here is the
@@ -120,10 +126,13 @@ def _packed_kernel(
         ks_hbm=ks_hbm, vs_hbm=vs_hbm, ks_buf=ks_buf, vs_buf=vs_buf)
     prime, chain_step = make_chunk_chain(start_chunk, wait_chunk)
 
+    # rows are sublanes and context positions lanes throughout: every
+    # in-kernel tensor is [nkv, R, *] and the per-row planes [R, 1], so
+    # the mask broadcasts along lanes and no vector is re-laid-out
     carry = (
-        jnp.full((nkv, TB, g), NEG_INF, jnp.float32),
-        jnp.zeros((nkv, TB, g), jnp.float32),
-        jnp.zeros((nkv, TB, g, hd), jnp.float32),
+        jnp.full((nkv, R, 1), NEG_INF, jnp.float32),
+        jnp.zeros((nkv, R, 1), jnp.float32),
+        jnp.zeros((nkv, R, hd), jnp.float32),
     )
     # static unroll over segment rows (S is small — max_prefill_seqs
     # pow2); the chunk count is 0 for every segment with no token in
@@ -139,7 +148,7 @@ def _packed_kernel(
         # last chunk (cross-tile/segment never-drain chain)
         prime(s, nch, base)
 
-        owned = seg == s  # [TB]
+        owned = seg == s  # [R, 1]
 
         def body(c, carry, s=s, owned=owned, nch=nch, base=base,
                  nseg=nseg):
@@ -156,35 +165,33 @@ def _packed_kernel(
                      * ks_buf[slot][:, None, :]).astype(q.dtype)
                 v = (v.astype(jnp.float32)
                      * vs_buf[slot][:, None, :]).astype(q.dtype)
-            # scores [nkv, TB, g, C]: one batched matmul for the tile
+            # scores [nkv, R, C]: one batched matmul for the tile
             sc = jax.lax.dot_general(
-                q, k, (((3,), (1,)), ((0,), (0,))),
+                q, k, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
             )
-            span = c * C + jax.lax.broadcasted_iota(jnp.int32, (TB, C), 1)
-            mask = owned[:, None] & (span <= pos[:, None])  # [TB, C]
-            m4 = mask[None, :, None, :]
-            sc = jnp.where(m4, sc, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(sc, axis=3))
+            span = c * C + jax.lax.broadcasted_iota(jnp.int32, (R, C), 1)
+            mask = (owned & (span <= pos))[None]  # [1, R, C]
+            sc = jnp.where(mask, sc, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=2, keepdims=True))
             alpha = jnp.exp(m - m_new)
             # explicit zero outside the mask: a fully-masked row leaves
             # (m, l, acc) untouched, so the shared carry never mixes
             # foreign segments' junk into a real token's accumulation
-            p = jnp.where(m4, jnp.exp(sc - m_new[..., None]), 0.0)
-            l = l * alpha + jnp.sum(p, axis=3)
+            p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
+            l = l * alpha + jnp.sum(p, axis=2, keepdims=True)
             pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((3,), (2,)), ((0,), (0,))),
+                p.astype(v.dtype), v, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
             )
-            acc = acc * alpha[..., None] + pv
+            acc = acc * alpha + pv
             return m_new, l, acc
 
         carry = jax.lax.fori_loop(0, nch, body, carry)
     m, l, acc = carry
     # tokens no segment owns (padded tail) have l == 0 -> output 0,
     # matching the XLA reference's untouched zero-init output rows
-    o_ref[...] = (acc / jnp.maximum(l, 1e-20)[..., None]).astype(
-        o_ref.dtype)
+    o_ref[...] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -273,14 +280,24 @@ def packed_prefill_attention_pallas(
 
     scale = 1.0 / jnp.sqrt(jnp.float32(hd))
     qg = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    qg = qg.reshape(Tp, nkv, group, hd).transpose(1, 0, 2, 3)
+    # query rows are (token, group) pairs, token-major: R = TB * group
+    # rows per tile, [nkv, Tp * group, hd] overall
+    R = TB * group
+    qg = qg.reshape(Tp, nkv, group, hd).transpose(1, 0, 2, 3) \
+        .reshape(nkv, Tp * group, hd)
 
-    inputs = [seg2d, pos2d, qg, kc, vc]
+    # per-row segment/position planes ride as [n_tiles, R, 1] columns
+    # (rows on sublanes, like the score rows they mask): the block's
+    # last two dims are (R, 1) = (multiple of 8 or the whole array, the
+    # array's own 1), which is what Mosaic's block-shape rule asks for
+    def row_plane(x2d):
+        return jnp.repeat(x2d, group, axis=1)[:, :, None]
+
+    inputs = [row_plane(seg2d), row_plane(pos2d), qg, kc, vc]
     in_specs = [
-        pl.BlockSpec((1, TB), lambda t, *refs: (t, 0)),
-        pl.BlockSpec((1, TB), lambda t, *refs: (t, 0)),
-        pl.BlockSpec((nkv, TB, group, hd),
-                     lambda t, *refs: (0, t, 0, 0)),
+        pl.BlockSpec((None, R, 1), lambda t, *refs: (t, 0, 0)),
+        pl.BlockSpec((None, R, 1), lambda t, *refs: (t, 0, 0)),
+        pl.BlockSpec((nkv, R, hd), lambda t, *refs: (0, t, 0)),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
@@ -306,12 +323,12 @@ def packed_prefill_attention_pallas(
             num_scalar_prefetch=4,
             grid=(n_tiles,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((nkv, TB, group, hd),
-                                   lambda t, *refs: (0, t, 0, 0)),
+            out_specs=pl.BlockSpec((nkv, R, hd),
+                                   lambda t, *refs: (0, t, 0)),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((nkv, Tp, group, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((nkv, Tp * group, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
@@ -325,5 +342,6 @@ def packed_prefill_attention_pallas(
         ),
         interpret=interpret,
     )(block_tables, nchunks, chunk_base, next_seg, *inputs)
-    out = out.transpose(1, 0, 2, 3).reshape(Tp, nh, hd)
+    out = out.reshape(nkv, Tp, group, hd).transpose(1, 0, 2, 3) \
+        .reshape(Tp, nh, hd)
     return out[:T].astype(q.dtype)

@@ -52,8 +52,10 @@ Padded table entries point at physical block 0 (the garbage block) and
 are masked by position, so shapes stay static.  Numerics match
 paged_attention.paged_attention_decode_jnp to bf16 matmul tolerance
 (fp32 softmax and accumulation); tests/test_paged_attention.py and
-tests/test_packed_pallas.py cross-check the two (int8 included), and
-interpret mode keeps the kernel runnable on CPU.
+tests/test_packed_pallas.py cross-check the two (int8 included) in
+interpret mode on the CPU; tests/test_tpu_compile.py compiles the kernel
+for a described v5e, and chip_smoke.py checks the compiled kernel
+against the jnp path on the chip.
 """
 
 from __future__ import annotations
@@ -66,20 +68,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-
-def tpu_compiler_params(**kwargs):
-    """pltpu compiler-params across jax versions: the class was named
-    TPUCompilerParams before jax 0.5.x and CompilerParams after (found
-    by the tier-1 interpreter cross-checks when the toolchain moved)."""
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise AttributeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams; unsupported jax version"
-        )
-    return cls(**kwargs)
 
 
 def make_chunk_dma(tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem, *,
@@ -375,7 +363,7 @@ def paged_attention_decode_pallas(
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, nkv, group, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 * 1024 * 1024,
         ),

@@ -34,10 +34,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..parallel.compat import pvary, shard_map
 
 _NEG_INF = -1e30  # finite -inf stand-in: keeps exp/max NaN-free
 
@@ -96,12 +95,14 @@ def _ring_shard(q, k, v, *, axis_name: str, causal: bool, sm_scale: float):
         return o, m, l, kr, vr
 
     # constants start device-invariant; the accumulators become
-    # device-varying after one update, so align the carry types (jax>=0.9
-    # varying-manual-axes tracking)
-    o = pvary(jnp.zeros((tq, hkv, hq // hkv, d), jnp.float32), axis_name)
-    m = pvary(jnp.full((tq, hkv, hq // hkv), _NEG_INF, jnp.float32),
-              axis_name)
-    l = pvary(jnp.zeros((tq, hkv, hq // hkv), jnp.float32), axis_name)
+    # device-varying after one update, so align the carry types
+    # (varying-manual-axes tracking)
+    def varying(x):
+        return lax.pcast(x, (axis_name,), to="varying")
+
+    o = varying(jnp.zeros((tq, hkv, hq // hkv, d), jnp.float32))
+    m = varying(jnp.full((tq, hkv, hq // hkv), _NEG_INF, jnp.float32))
+    l = varying(jnp.zeros((tq, hkv, hq // hkv), jnp.float32))
     o, m, l = attend(o, m, l, k, v, my_idx)
     o, m, l, _, _ = lax.fori_loop(1, axis_size, step, (o, m, l, k, v))
     l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0 output

@@ -74,12 +74,11 @@ class MultihostContext:
         if "DYN_MH_RANK" in os.environ:
             return cls(rank=int(os.environ["DYN_MH_RANK"]),
                        world=int(os.environ.get("DYN_MH_WORLD", "1")))
-        try:
-            import jax
+        import jax
 
-            return cls(rank=jax.process_index(), world=jax.process_count())
-        except Exception:  # pragma: no cover — jax not initialized yet
-            return cls()
+        # initializes the backend if nothing has yet; a backend that
+        # cannot start is an error here, not a silent single-host default
+        return cls(rank=jax.process_index(), world=jax.process_count())
 
 
 def step_subject(namespace: str, component: str, instance_id: int) -> str:

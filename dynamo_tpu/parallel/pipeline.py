@@ -28,10 +28,9 @@ from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import pvary, shard_map
 
 # stage_fn(params_slice, state_slice, x, active) -> (y, new_state_slice)
 #   params_slice/state_slice: this stage's slice (leading stage axis
@@ -67,8 +66,8 @@ def _pipeline_shard(params, state, xs, *, stage_fn: StageFn, axis: str,
         buf = lax.ppermute(y, axis, perm)
         return buf, ys, state
 
-    buf0 = pvary(jnp.zeros_like(xs[0]), axis)
-    ys0 = pvary(jnp.zeros_like(xs), axis)
+    buf0 = lax.pcast(jnp.zeros_like(xs[0]), (axis,), to="varying")
+    ys0 = lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
     _, ys, state = lax.fori_loop(0, S + M - 1, tick, (buf0, ys0, state))
     # outputs live on the last stage only; sum-reduce replicates them
     ys = lax.psum(ys, axis)

@@ -1,7 +1,7 @@
 """Symmetric int8 KV-cache quantization primitives.
 
-Decode is memory-bandwidth-bound (BENCH_r05: the raw loop at 0.76 of the
-HBM roofline), so halving the bytes the attention read streams per token
+Decode is memory-bandwidth-bound (the raw loop ran at 0.76 of the HBM
+roofline on an earlier set-up; not measured on today's code), so halving the bytes the attention read streams per token
 is the single biggest remaining lever on served throughput — and the
 same halving doubles the KV blocks a fixed HBM budget holds (bigger
 continuous batch, fewer preemptions, more prefix-cache residency).

@@ -126,7 +126,11 @@ def make_indexer(impl: Optional[str] = None):
         from .native_indexer import NativeKvIndexer
 
         return NativeKvIndexer()
-    except (ImportError, OSError):
+    except (ImportError, OSError) as e:
         if impl == "native":
             raise
+        logger.warning(
+            "native indexer unavailable (%s); serving the Python indexer "
+            "— build it with `make -C native`, or pin DYN_INDEXER=native "
+            "to make this an error", e)
         return PyKvIndexer()

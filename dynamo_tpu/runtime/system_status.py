@@ -30,6 +30,7 @@ import inspect
 import json
 import logging
 import os
+import sys
 import time
 from dataclasses import asdict
 from typing import TYPE_CHECKING, Optional
@@ -240,7 +241,8 @@ class SystemStatusServer:
         memory (HBM breakdown) snapshot.  One capture at a time per
         process (409 while busy); no-op-safe on CPU and on processes
         where the profiler is unavailable (status "unavailable", never
-        a 500 — an incident tool must not add incidents)."""
+        a 500 — an incident tool must not add incidents).  Refused
+        (status "unavailable") in a process that holds no engine."""
         err = self._authorize(request)
         if err is not None:
             return err
@@ -258,6 +260,17 @@ class SystemStatusServer:
             return web.json_response(
                 {"error": "a profiler capture is already running"},
                 status=409)
+        if "jax" not in sys.modules:
+            # a process that never imported JAX holds no engine and no
+            # device (frontend, router, planner, mocker).  Importing it
+            # here would start a backend in THIS process and reach for
+            # the chip the worker next door holds — refuse instead.
+            return web.json_response({
+                "worker_id": self.runtime.worker_id, "pid": os.getpid(),
+                "status": "unavailable",
+                "error": "this process holds no device (JAX was never "
+                         "imported here); ask the worker that owns the "
+                         "chip"})
         import asyncio
         import tempfile
 

@@ -1,7 +1,7 @@
 """Speculative decoding subsystem: proposers + packed verification.
 
-Decode is memory-bandwidth-bound (BENCH_r05: the raw loop at 0.76 of the
-HBM roofline), so the only way left to raise tokens/s/chip is to emit
+Decode is memory-bandwidth-bound (the raw loop ran at 0.76 of the HBM
+roofline on an earlier set-up; not measured on today's code), so the only way left to raise tokens/s/chip is to emit
 MORE THAN ONE accepted token per weight/KV pass.  Speculative decoding
 (Leviathan et al. 2023; Chen et al. 2023) does that: a cheap proposer
 drafts k continuation tokens, the target model scores all of them in one

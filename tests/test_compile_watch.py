@@ -96,6 +96,25 @@ def test_watch_sink_and_serving_flag():
     assert watch.serving_compiles == 1
 
 
+def test_unwatchable_jit_program_is_refused():
+    """A jit product without the compile counter must fail at wrap time
+    (else "zero mid-serving compiles" would read 0 for ever); a plain
+    callable — a test stand-in — passes through unwatched."""
+    watch = CompileWatch()
+
+    class LowersButUncounted:
+        def lower(self, *a):
+            raise AssertionError("never reached")
+
+        def __call__(self, *a):
+            return a
+
+    with pytest.raises(TypeError, match="_cache_size"):
+        watch.wrap(LowersButUncounted(), "decode")
+    stand_in = watch.wrap(lambda x: x + 1, "toy")
+    assert stand_in(1) == 2 and watch.counts == {}
+
+
 # --------------------- JAX engine end-to-end --------------------------------
 
 
@@ -207,11 +226,18 @@ async def test_decode_and_spec_records_carry_costs():
     compiled program's flops/bytes — the inputs decode MFU/MBU gauges
     aggregate; FpmWindow.phase_mbu turns them into a utilization."""
     eng = make_engine(spec_decode="ngram", spec_k=2)
-    # a repetitive prompt so the n-gram proposer engages
+    # the n-gram proposer engages on repetition in prompt + output.  The
+    # first probe (1 generated token) hits only if that token already
+    # occurs in the prompt — which depends on the tiny random model's
+    # greedy continuation, and that moved with the JAX version (it now
+    # opens with a token outside the prompt).  A miss re-probes after
+    # SPEC_PROBE_MIN = 8 more tokens, so the request must outlive that
+    # probe plus the decode bursts already in flight: 48 tokens, by
+    # which point a greedy tiny-model stream has entered a cycle.
     req = PreprocessedRequest(
         token_ids=[5, 6, 7, 8] * 8, request_id="rep",
         sampling=SamplingOptions(temperature=0.0),
-        stop=StopConditions(max_tokens=16, ignore_eos=True))
+        stop=StopConditions(max_tokens=48, ignore_eos=True))
     async for _ in eng.generate(req):
         pass
     for i in range(2):

@@ -535,9 +535,13 @@ def test_fleet_e2e_two_process_mockers_and_frontend(tmp_path):
             assert st == 200
             assert any(s.get("kind") == "mocker"
                        for s in state["sources"].values())
+            # a mocker never imported JAX and holds no device: the
+            # capture is refused rather than starting a backend there
+            # (which would reach for a chip some worker holds)
             st, prof = await admin_get(
                 f"{base}/debug/profile?duration_s=0.1")
-            assert st == 200 and prof["status"] in ("ok", "unavailable")
+            assert st == 200 and prof["status"] == "unavailable"
+            assert "holds no device" in prof["error"]
 
         asyncio.run(check_auth())
 

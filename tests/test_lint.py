@@ -263,7 +263,7 @@ def test_registries_are_canonical():
     assert set(obs.STEP_PHASES) <= obs.SPAN_KINDS
     assert COMPILE_KIND in obs.SPAN_KINDS
     assert "engine.step" in chaos.SEAMS
-    # forensics hop taxonomy (obs/forensics.py, DYN012's registry)
+    # forensics hop vocabulary (obs/forensics.py, DYN012's registry)
     from dynamo_tpu.obs.forensics import PHASES
 
     assert {"received", "routed", "dispatched", "prefill_open",

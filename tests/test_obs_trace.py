@@ -55,9 +55,9 @@ def test_disabled_helpers_are_noops():
         obs.end("step", 0.0, anything=1)  # began disabled: still dropped
         assert len(tr.spans) == 0
     # span() hands back one process-wide no-op context manager
-    # dynlint: disable=DYN006 synthetic kinds: this tests tracer mechanics, not the span taxonomy
+    # dynlint: disable=DYN006 synthetic kinds: this tests tracer mechanics, not the span vocabulary
     assert obs.span("a") is obs.span("b")
-    # dynlint: disable=DYN006 synthetic kinds: this tests tracer mechanics, not the span taxonomy
+    # dynlint: disable=DYN006 synthetic kinds: this tests tracer mechanics, not the span vocabulary
     with obs.span("a"):
         pass
     assert obs.flight_dump("nope") is None
@@ -89,7 +89,7 @@ def test_mock_engine_bit_identical_with_tracing_on():
         plain, _ = await run_once(False)
         traced, kinds = await run_once(True)
         assert plain == traced and len(plain) == 32
-        # the mocker emits the engine taxonomy so the timeline plane is
+        # the mocker emits the engine vocabulary so the timeline plane is
         # exercised CPU-only
         assert {"step", "sched", "device_wait",
                 "decode_dispatch", "prefill_dispatch"} <= kinds

@@ -128,50 +128,6 @@ def test_pallas_tp_sharded_matches_jnp():
     )
 
 
-def _tpu_devices():
-    try:
-        return jax.devices("tpu")
-    except RuntimeError:
-        return []
-
-
-@pytest.mark.skipif(not _tpu_devices(),
-                    reason="needs a TPU (compiled-kernel cross-check)")
-def test_pallas_kernel_compiled_matches_jnp_uneven_kv_lens():
-    """COMPILED (non-interpret) kernel vs the jnp reference on real TPU
-    hardware, with uneven kv_lens across a multi-sequence batch.  The
-    interpreter tests above cannot catch Mosaic-level regressions, and
-    impl="auto" no longer routes serving traffic through the kernel (it
-    selects the jnp path) — without this gate the compiled kernel could
-    silently rot."""
-    rng = np.random.default_rng(0)
-    B, nkv, group, hd, bs, max_blocks = 4, 2, 4, 128, 128, 4
-    num_blocks = 1 + B * max_blocks
-    shape = (2, nkv, num_blocks, hd, bs)
-    kc = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-    vc = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-    q = jnp.asarray(rng.standard_normal((B, nkv * group, hd)),
-                    jnp.bfloat16)
-    # uneven contexts incl. partial blocks and a single-block sequence
-    kv_lens = np.asarray([500, 512, 37, 129], np.int32)
-    tables = np.zeros((B, max_blocks), np.int32)
-    perm = rng.permutation(num_blocks - 1) + 1
-    for b in range(B):
-        used = -(-int(kv_lens[b]) // bs)
-        tables[b, :used] = perm[b * max_blocks:b * max_blocks + used]
-    tables = jnp.asarray(tables)
-    kv_lens = jnp.asarray(kv_lens)
-    for layer in range(2):
-        ref = paged_attention_decode_jnp(q, kc, vc, layer, tables,
-                                         kv_lens)
-        out = paged_attention_decode_pallas(q, kc, vc, layer, tables,
-                                            kv_lens, interpret=False)
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(ref, np.float32),
-            rtol=0.05, atol=0.05,
-        )
-
-
 async def test_engine_greedy_with_pallas_attention():
     """End-to-end: the engine produces identical greedy tokens with the
     Pallas decode path (interpret mode) and the jnp path."""

@@ -1,0 +1,146 @@
+"""Compile the main path's kernels for a TPU v5e that is described, not
+attached — the only test file that describes the chip.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a
+topology description (`v5e:2x2`); what it refuses here it refuses on the
+chip (block shapes Mosaic cannot tile, VMEM over the limit, programs over
+HBM).  Nothing runs: a compile that passes is not a chip run —
+`chip_smoke.py` is.
+
+The topology is described inside a module-scoped fixture and nowhere at
+import time (xdist workers all import this file), and every
+shape/sharding is built inside fixtures or tests.  libtpu's lockfile lets
+one process load it at a time — it guards a chip, and describing a
+topology takes none — so the fixture lifts it for this process; without
+that, cases spread over several xdist workers would fail on the lock.
+The fixture skips only where no TPU compiler is installed; any other
+failure to describe the topology is an error, never a silent skip.
+"""
+
+import dataclasses
+import importlib.util
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dynamo_tpu.models import llama
+
+BS = 128  # lane-aligned serving block size
+
+
+@pytest.fixture(scope="module")
+def topo():
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no TPU compiler (libtpu) in this installation")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A described-topology executable can be written to JAX's persistent
+    cache but not read back without a chip (it warns and recompiles):
+    keep the cache off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _sds(sharding):
+    return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=sharding)
+
+
+# (nkv, n_heads, head_dim): llama-3b and llama-8b on a single device, and
+# llama-8b's per-shard shape under tp=4 (the kernels run per shard under
+# shard_map there)
+WIDTHS = {
+    "llama-3b": (8, 24, 128),
+    "llama-8b": (8, 32, 128),
+    "llama-8b/tp4": (2, 8, 128),
+}
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("kernel", ["decode", "packed"])
+def test_pallas_kernel_compiles_for_v5e(one_chip, kernel, widths, quant):
+    """Both Pallas kernels, compiled (never interpret), at serving
+    widths: block 128, contexts to 2048, B=8 decode rows / T=2048 packed
+    tokens in 4 segments."""
+    from dynamo_tpu.ops.pallas_packed_prefill import (
+        packed_prefill_attention_pallas,
+    )
+    from dynamo_tpu.ops.pallas_paged_attention import (
+        paged_attention_decode_pallas,
+    )
+
+    nkv, nh, hd = WIDTHS[widths]
+    S = _sds(one_chip)
+    L, NB, MB, B, T, SEGS = 2, 64, 16, 8, 2048, 4
+    cache = S((L, nkv, NB, hd, BS), jnp.int8 if quant else jnp.bfloat16)
+    scale = S((L, nkv, NB, BS), jnp.float32)
+    kw = dict(k_scale=scale, v_scale=scale) if quant else {}
+    if kernel == "decode":
+        fn = partial(paged_attention_decode_pallas, layer=1)
+        lowered = jax.jit(fn).lower(
+            S((B, nh, hd), jnp.bfloat16), cache, cache,
+            block_tables=S((B, MB), jnp.int32),
+            kv_lens=S((B,), jnp.int32), **kw)
+    else:
+        fn = partial(packed_prefill_attention_pallas, layer=1)
+        lowered = jax.jit(fn).lower(
+            S((T, nh, hd), jnp.bfloat16), cache, cache,
+            block_tables=S((SEGS, MB), jnp.int32),
+            seg_ids=S((T,), jnp.int32), positions=S((T,), jnp.int32),
+            valid=S((T,), jnp.bool_), **kw)
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_multi_step_compiles_for_v5e(one_chip):
+    """One fused decode burst of the engine's own program
+    (JaxEngine._decode_multi_impl) at llama-3b widths, 2 layers, with
+    the Pallas decode kernel inside it."""
+    from dynamo_tpu.engine.core import JaxEngine
+
+    cfg = dataclasses.replace(llama.PRESETS["llama-3b"], n_layers=2,
+                              attn_impl="pallas")
+    S = _sds(one_chip)
+    NB, MB, B, K = 64, 16, 8, 8
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    kv = tuple(S((cfg.n_layers, cfg.n_kv_heads, NB, cfg.head_dim, BS),
+                 cfg.dtype) for _ in range(2))
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+    fn = jax.jit(
+        partial(JaxEngine._decode_multi_impl, llama, cfg, None, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9))
+    compiled = fn.lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
