@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import math
+import statistics
 from typing import Dict, List, Optional, Sequence
 
 
@@ -90,3 +91,25 @@ def mean_live_context(records: List[Dict], t0: float, t1: float) -> float:
         ctx = lambda t: r["prompt_len"] + 1 + (n - 1) * (t - a) / (b - a)  # noqa: E731
         total += (ctx(lo) + ctx(hi)) / 2.0 * (hi - lo)
     return total / (t1 - t0)
+
+
+def without_farthest(values: Sequence[float]) -> List[float]:
+    """The values but the one farthest from their median (the first such
+    in order where two are equally far)."""
+    v = list(values)
+    m = statistics.median(v)
+    v.pop(max(range(len(v)), key=lambda i: abs(v[i] - m)))
+    return v
+
+
+def spread_iqr(values: Sequence[float]) -> float:
+    """The spread a bound is set from: third quartile less first, as
+    `statistics.quantiles(values, n=4)` gives them (numpy's lie closer
+    together), as a share of the median."""
+    q1, _, q3 = statistics.quantiles(values, n=4)
+    return (q3 - q1) / statistics.median(values)
+
+
+def spread_range(values: Sequence[float]) -> float:
+    """Largest less smallest, as a share of the median."""
+    return (max(values) - min(values)) / statistics.median(values)

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 from ..readers.counters import compiles_in_window
 from ..readers.latency import percentile_of
+from . import records
 from .stats import counted, tokens_in_window
 
 
@@ -15,7 +16,10 @@ async def sweep(engine, cfg, mix, args, measure, log) -> None:
         "  tpot_p95_ms  late_p95_ms  out_tok_per_s  compiles_in_window")
     for i, rate in enumerate(float(r) for r in args.sweep.split(",")):
         ctx = await measure(engine, cfg, {**mix, "rate_rps": rate},
-                            args.seconds, args.seed + i)
+                            args.seconds, args.seed + i,
+                            keep_records=records.path(
+                                args.keep_records, args.workload,
+                                args.seed + i, f".w{i}"))
         t0, t1 = ctx["window"]
         c = ctx["counted"] = counted(ctx["records"], t0, t1)
         col = lambda k, q: percentile_of(ctx, k, q) or -1.0  # noqa: E731

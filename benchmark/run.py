@@ -11,8 +11,15 @@ only.  Without a TPU it exits non-zero and prints no result.
 
     --sweep RATES   one set-up, then each offered rate of the cell's mix
                     for --seconds each; prints a table, no result line
+    --keep-records DIR  write each window's per-request records, counter
+                    deltas and the engine's step records to
+                    DIR/<cell>.s<seed>.json.gz (benchmark/lib/records.py)
     --rehearse      tiny widths on the CPU to walk the control flow;
                     prints no result line and always exits 3
+
+Every run logs the window's counter deltas on stderr, and every thread's
+stack where the engine stands still, so that an untraced run that reads far
+off leaves something to say why.
 
 Which files make up a cell is in benchmark/README.md.  This file holds
 no cell, configuration, mix or metric name.
@@ -54,13 +61,16 @@ def _trace_options():
 
 
 async def measure(engine, cfg, mix, seconds: float, seed: int,
-                  trace_dir: str = "") -> dict:
+                  trace_dir: str = "", keep_records: str = "") -> dict:
     """Pre-roll, then one window of `seconds`; with `trace_dir`, the
     profiler runs over TRACE_SECONDS in the middle of it.  Returns the
-    observations the metric readers work from."""
+    observations the metric readers work from; with `keep_records` (a
+    file) they are also written there."""
     import jax
 
     from benchmark.lib.client import Client, settle
+    from benchmark.lib.records import (GcPauses, StallWatch, counter_deltas,
+                                       dump)
 
     loop_kind = spec.loop_module(mix)
     rows = loop_kind.build(mix, seconds, seed)
@@ -68,29 +78,47 @@ async def measure(engine, cfg, mix, seconds: float, seed: int,
     t_open = time.perf_counter() + float(mix.get("preroll_s", 0.0))
     t_close = t_open + seconds
     mono_off = time.monotonic() - time.perf_counter()
-    driver = asyncio.create_task(
-        loop_kind.drive(rows, client, t_open, t_close, mix))
-    await asyncio.sleep(max(0.0, t_open - time.perf_counter()))
-    ctx = {"counters_open": dict(engine.metrics), "trace": None}
-    if trace_dir:
-        span = min(TRACE_SECONDS, seconds / 2.0)
-        await asyncio.sleep(max(
-            0.0, t_open + (seconds - span) / 2.0 - time.perf_counter()))
-        await asyncio.to_thread(jax.profiler.start_trace, trace_dir,
-                                profiler_options=_trace_options())
-        ctx["trace_window"] = [time.perf_counter(), None]
-        ctx["trace_counters"] = [dict(engine.metrics), None]
-        await asyncio.sleep(span)
-        ctx["trace_window"][1] = time.perf_counter()
-        ctx["trace_counters"][1] = dict(engine.metrics)
-        ctx["fpm"] = [dict(r) for r in list(engine.fpm)]
-        await asyncio.to_thread(jax.profiler.stop_trace)
-    tasks = await driver
+    watch = StallWatch(
+        lambda: engine.metrics.get("steps", 0),
+        lambda: any(r["end_t"] is None for r in list(client.records)))
+    with GcPauses() as pauses, watch:      # over the pre-roll too
+        driver = asyncio.create_task(
+            loop_kind.drive(rows, client, t_open, t_close, mix))
+        await asyncio.sleep(max(0.0, t_open - time.perf_counter()))
+        ctx = {"counters_open": dict(engine.metrics), "trace": None}
+        cpu_open = time.process_time()
+        if trace_dir:
+            span = min(TRACE_SECONDS, seconds / 2.0)
+            await asyncio.sleep(max(
+                0.0, t_open + (seconds - span) / 2.0 - time.perf_counter()))
+            await asyncio.to_thread(jax.profiler.start_trace, trace_dir,
+                                    profiler_options=_trace_options())
+            ctx["trace_window"] = [time.perf_counter(), None]
+            ctx["trace_counters"] = [dict(engine.metrics), None]
+            await asyncio.sleep(span)
+            ctx["trace_window"][1] = time.perf_counter()
+            ctx["trace_counters"][1] = dict(engine.metrics)
+            ctx["fpm"] = [dict(r) for r in list(engine.fpm)]
+            await asyncio.to_thread(jax.profiler.stop_trace)
+        tasks = await driver
+    ctx["host"] = {"process_cpu_s": round(time.process_time() - cpu_open, 3),
+                   "loadavg_1m": os.getloadavg()[0],
+                   "gc": pauses.summary(t_open),
+                   "stalls": [[round(t - t_open, 3), round(d, 3)]
+                              for t, d in watch.stalls]}
     ctx["counters_close"] = dict(engine.metrics)
+    ctx["fpm_close"] = [dict(r) for r in list(engine.fpm)]
     ctx["drained"] = await settle(tasks)
     ctx.update(records=client.records, window=(t_open, t_close),
                mono_offset=mono_off, seconds=seconds,
                compile_events=[dict(e) for e in engine.compile_watch.events])
+    deltas = counter_deltas(ctx)
+    print(f"counters seed {seed}: {json.dumps(deltas)}", file=sys.stderr,
+          flush=True)
+    if keep_records:
+        dump(ctx, keep_records, {"seed": seed, "seconds": seconds,
+                                 "rate_rps": mix.get("rate_rps"),
+                                 "counters": deltas})
     return ctx
 
 
@@ -168,7 +196,7 @@ def device_block(ident: dict, ctx: dict) -> dict:
 
 
 async def run(args) -> int:
-    from benchmark.lib import correct, roofline, warmup
+    from benchmark.lib import correct, records, roofline, warmup
     from benchmark.lib.model import build_engine
     from benchmark.lib.peaks import device_peaks
     from benchmark.lib.stats import counted
@@ -219,7 +247,9 @@ async def run(args) -> int:
         log(f"decode programs by the probe: {decode_names}")
         await engine.clear_kv_blocks()
     t_measure = time.perf_counter()
-    ctx = await measure(engine, cfg, mix, args.seconds, args.seed, trace_dir)
+    ctx = await measure(engine, cfg, mix, args.seconds, args.seed, trace_dir,
+                        records.path(args.keep_records, args.workload,
+                                     args.seed))
     await engine.close()
     ctx["setup_s"] = ctx["window"][0] - T_PROCESS
     if trace_dir:
@@ -271,6 +301,8 @@ def main() -> int:
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--keep-trace", default="",
                     help="directory to copy the raw .xplane.pb into")
+    ap.add_argument("--keep-records", default="",
+                    help="directory for each window's records")
     args = ap.parse_args()
     if args.rehearse:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")

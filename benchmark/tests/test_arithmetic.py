@@ -264,3 +264,99 @@ def test_benchmark_json_points_at_files():
         cells = m.get("workloads") or [w["name"] for w in bench["workloads"]]
         moved = e2e[m["moves"]]
         assert all(c in (moved.get("workloads") or cells) for c in cells)
+
+
+SIX = [100.0, 101.0, 99.0, 102.0, 98.0, 120.0]
+
+
+@pytest.mark.parametrize("fn,values,want", [
+    # statistics.quantiles (exclusive): q1 = 98.75, q3 = 106.5 of six
+    (stats.spread_iqr, SIX, (106.5 - 98.75) / 100.5),
+    (stats.spread_iqr, SIX[:5], (101.5 - 98.5) / 100.0),
+    (stats.spread_range, SIX, 22.0 / 100.5),
+    (stats.spread_range, SIX[:5], 4.0 / 100.0),
+    (stats.spread_range, [5.0, 5.0, 5.0], 0.0),
+])
+def test_spreads_are_shares_of_the_median(fn, values, want):
+    assert fn(values) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("values,want", [
+    (SIX, SIX[:5]),                           # the far run goes
+    ([1.0, 2.0, 3.0], [2.0, 3.0]),            # a tie: the first goes
+    ([10.0, 50.0, 11.0, 12.0], [10.0, 11.0, 12.0]),
+])
+def test_without_farthest_drops_one_run(values, want):
+    assert stats.without_farthest(values) == want
+    assert len(values) == len(want) + 1       # the input is not changed
+
+
+def test_spread_iqr_is_wider_than_numpys_quartiles():
+    q1, q3 = np.percentile(SIX, [25, 75])
+    assert stats.spread_iqr(SIX) > (q3 - q1) / np.median(SIX)
+
+
+def test_records_round_trip_and_counter_deltas(tmp_path):
+    from benchmark.lib import records
+
+    t0 = 50.0
+    recs = [dict(rec(t0 + 1.0, t0 + 1.001, [t0 + 1.5, t0 + 1.6]), index=0),
+            dict(rec(t0 + 2.0, t0 + 2.0, [t0 + 9.5, t0 + 10.5]), index=1)]
+    ctx = {"window": (t0, t0 + 10.0), "mono_offset": 1000.0, "drained": 1,
+           "records": recs,
+           "counters_open": {"steps": 10, "cont_bursts": 1},
+           "counters_close": {"steps": 30, "cont_bursts": 6,
+                              "preemptions": 2},
+           "compile_events": [{"t": 1000.0 + t0 + 3.0, "family": "prefill"},
+                              {"t": 1000.0 + t0 - 3.0, "family": "decode"}],
+           "fpm_close": [{"t": 1000.0 + t0 + 1.2, "kind": "decode", "k": 8,
+                          "lanes": 3, "gap_s": 0.1, "xla_flops": 1e9}],
+           "host": {"process_cpu_s": 1.0}}
+    deltas = records.counter_deltas(ctx)
+    assert (deltas["steps"], deltas["cont_bursts"], deltas["preemptions"],
+            deltas["prefill_tokens"], deltas["window_compiles"]) == (
+                20, 5, 2, 0, 1.0)
+    path = str(tmp_path / "set" / "cell.s7.json.gz")
+    assert records.path("", "cell", 7) == ""
+    assert path == records.path(str(tmp_path / "set"), "cell", 7)
+    records.dump(ctx, path, {"seed": 7, "counters": deltas})
+    back = records.load(path)
+    assert back["seed"] == 7 and back["window"] == (0.0, 10.0)
+    assert [len(back["counted"][k]) for k in ("ok", "failed", "inflight")
+            ] == [1, 0, 1]
+    assert latency.percentile_of(back, "ttft_ms", 50) == pytest.approx(500.0)
+    assert back["fpm"] == [{"t": pytest.approx(1.2), "kind": "decode",
+                            "k": 8, "lanes": 3, "gap_s": 0.1}]
+    assert back["compile_events"][0]["t"] == pytest.approx(3.0)
+
+
+def test_gc_pauses_counts_collections_while_installed():
+    import gc
+
+    from benchmark.lib.records import GcPauses
+
+    with GcPauses() as pauses:
+        gc.collect()
+        assert pauses in gc.callbacks
+    assert pauses not in gc.callbacks
+    s = pauses.summary(0.0)
+    assert s["count"][2] == 1 and s["ms"][2] > 0.0
+    assert s["longest_ms"] <= sum(s["ms"]) + 1e-3
+
+
+def test_stall_watch_dumps_stacks_once_a_stall(capfd):
+    import time
+
+    from benchmark.lib.records import StallWatch
+
+    steps, waiting = [0], [True]
+    with StallWatch(lambda: steps[0], lambda: waiting[0],
+                    after_s=0.3) as watch:
+        time.sleep(1.0)               # no step, requests waiting: a stall
+        steps[0] += 1                 # the step ends it
+        waiting[0] = False
+        time.sleep(0.9)               # no step, nothing waiting: no stall
+    assert len(watch.stalls) == 1 and 0.3 < watch.stalls[0][1] < 1.6
+    err = capfd.readouterr().err
+    assert err.count("no scheduler step for") == 1
+    assert "stall-watch" in err or "Thread" in err
