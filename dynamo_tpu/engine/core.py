@@ -106,7 +106,13 @@ class _Slot:
     # the stream's first-token/finish frames (`forensic` metrics block)
     queue_pos: int = 0
     prefill_chunks: int = 0
+    # request stages (metrics req_stage_*): enqueued_t -> dispatched_t
+    # (the request's first prefill chunk is dispatched) -> first_token_t
+    # (the first token is in the host's hands) -> its frame is put on
+    # the stream by the event loop (_emit_first stamps and sums)
+    dispatched_t: float = 0.0
     first_token_t: float = 0.0
+    first_sent: bool = False  # the first frame is on its way to the loop
     last_push_t: float = 0.0  # previous streamed-token time (ITL EMA)
 
     @property
@@ -434,6 +440,9 @@ class JaxEngine:
             serving=lambda: any(s is not None for s in self._slots),
         )
         w = self.compile_watch
+        # every program is jitted under its watch family's name
+        # (w.named): the profiler's modules, compile events and the
+        # benchmark's module_s read `jit_dyn_<family>`
         _toks2 = lambda a: a[2].shape[-1]           # noqa: E731
         _toks2_total = lambda a: int(               # noqa: E731
             np.prod(a[2].shape))
@@ -458,20 +467,23 @@ class JaxEngine:
         _ep = self.sampling_epilogue == "fused"
         self._jit_decode = {
             g: w.wrap(jax.jit(
-                partial(self._decode_impl, self.family, self.model_cfg,
-                        self.mesh, g, _ep),
+                w.named(partial(self._decode_impl, self.family,
+                                self.model_cfg, self.mesh, g, _ep),
+                        "decode"),
                 donate_argnums=(1, 5, 7, 9),
                 out_shardings=_decode_out,
             ), "decode")
             for g in (False, True)
         }
         self._jit_prefill = w.wrap(jax.jit(
-            partial(self._prefill_impl, self.family, self.model_cfg),
+            w.named(partial(self._prefill_impl, self.family,
+                            self.model_cfg), "prefill"),
             donate_argnums=(1,),
             out_shardings=_prefill_out,
         ), "prefill", _toks2)
         self._jit_prefill_batched = w.wrap(jax.jit(
-            partial(self._prefill_batched_impl, self.family, self.model_cfg),
+            w.named(partial(self._prefill_batched_impl, self.family,
+                            self.model_cfg), "prefill_batched"),
             donate_argnums=(1,),
             out_shardings=_prefill_out,
         ), "prefill_batched", _toks2_total)
@@ -494,8 +506,9 @@ class JaxEngine:
         self._jit_prefill_packed = None
         if hasattr(self.family, "prefill_packed"):
             self._jit_prefill_packed = w.wrap(jax.jit(
-                partial(self._prefill_packed_impl, self.family,
-                        self.model_cfg, self.mesh),
+                w.named(partial(self._prefill_packed_impl, self.family,
+                                self.model_cfg, self.mesh),
+                        "prefill_packed"),
                 donate_argnums=(1,),
                 out_shardings=_prefill_out,
             ), "prefill_packed", _toks2)
@@ -506,8 +519,8 @@ class JaxEngine:
         self._jit_spec_verify = None
         if hasattr(self.family, "spec_verify_packed"):
             self._jit_spec_verify = w.wrap(jax.jit(
-                partial(self._spec_verify_impl, self.family,
-                        self.model_cfg, self.mesh),
+                w.named(partial(self._spec_verify_impl, self.family,
+                                self.model_cfg, self.mesh), "spec_verify"),
                 donate_argnums=(1,),
                 out_shardings=(rep, rep, rep, kvsh),
             ), "spec_verify", _toks2)
@@ -561,17 +574,18 @@ class JaxEngine:
         self._jit_prefill_ring = None
         if config.sp > 1 and hasattr(self.family, "prefill_ring"):
             self._jit_prefill_ring = w.wrap(jax.jit(
-                partial(self._prefill_ring_impl, self.family,
-                        self.model_cfg, self.mesh),
+                w.named(partial(self._prefill_ring_impl, self.family,
+                                self.model_cfg, self.mesh), "prefill_ring"),
                 donate_argnums=(1,),
                 out_shardings=_prefill_out,
             ), "prefill_ring", _toks2)
         self._jit_inject = w.wrap(
-            jax.jit(self._inject_impl, donate_argnums=(0,),
-                    out_shardings=kvsh), "inject",
+            jax.jit(w.named(self._inject_impl, "inject"),
+                    donate_argnums=(0,), out_shardings=kvsh), "inject",
             lambda a: a[3].shape[0])
         self._jit_gather = w.wrap(
-            jax.jit(self._gather_impl), "gather", lambda a: a[1].shape[0])
+            jax.jit(w.named(self._gather_impl, "gather")), "gather",
+            lambda a: a[1].shape[0])
         # fused decode: one compiled variant per (greedy, k) ladder rung
         # (adaptive fusion ramps k through _fuse_ladder; a fixed
         # num_steps program dispatched at a smaller accounting k would
@@ -581,8 +595,9 @@ class JaxEngine:
         if config.decode_fused_steps > 1:
             self._jit_decode_multi = {
                 (g, k): w.wrap(jax.jit(
-                    partial(self._decode_multi_impl, self.family,
-                            self.model_cfg, self.mesh, g, k, _ep),
+                    w.named(partial(self._decode_multi_impl, self.family,
+                                    self.model_cfg, self.mesh, g, k, _ep),
+                            "decode_multi"),
                     donate_argnums=(1, 5, 7, 9),
                     out_shardings=_decode_out,
                 ), "decode_multi")
@@ -631,9 +646,18 @@ class JaxEngine:
         self.draining = False
         self.metrics: Dict[str, Any] = {
             "steps": 0, "prefill_tokens": 0, "decode_tokens": 0,
-            "cache_hit_tokens": 0, "preemptions": 0, "step_time_s": 0.0,
+            "cache_hit_tokens": 0, "preemptions": 0,
             "requests": 0, "prompt_tokens": 0,
+            # a request's time to its first token by stage, summed over
+            # the requests whose first token was emitted (seconds; see
+            # _Slot.dispatched_t and _emit_first)
+            "req_stage_s.queue": 0.0, "req_stage_s.prefill": 0.0,
+            "req_stage_s.emit": 0.0, "req_stage_n": 0,
         }
+        # the scheduler thread's phases: counters host_s.<kind> /
+        # host_n.<kind> always, `dyn.<kind>` on the profiler's clock
+        # while a session is live, ring spans under a Tracer (obs/)
+        self._phase = obs.PhaseClock(self.metrics, self._obs_track)
         self.itl_ema_s = 0.0  # streamed inter-token latency (SLA planner)
         # forward-pass metrics stream (ref fpm_publisher.rs:1-10 /
         # instrumented_scheduler.py): one record per dispatched program —
@@ -1707,16 +1731,15 @@ class JaxEngine:
     def _run_embed(self, toks: np.ndarray, true_len: int) -> np.ndarray:
         jit = getattr(self, "_jit_embed", None)
         if jit is None:
-            jit = self._jit_embed = self.compile_watch.wrap(jax.jit(
-                partial(self.family.embed_text, self.params,
-                        self.model_cfg)), "embed",
+            w = self.compile_watch
+            jit = self._jit_embed = w.wrap(jax.jit(
+                w.named(partial(self.family.embed_text, self.params,
+                                self.model_cfg), "embed")), "embed",
                 tokens_of=lambda a: a[0].shape[0])
         with self.mesh:
             vec = jit(jnp.asarray(toks), jnp.int32(true_len))
-            t_obs = obs.begin()
-            out = np.asarray(vec, np.float32)
-            obs.end("device_wait", t_obs, track=self._obs_track,
-                    what="embed_fetch")
+            with self._phase("device_wait", what="embed_fetch"):
+                out = np.asarray(vec, np.float32)
             return out
 
     async def clear_kv_blocks(self) -> int:
@@ -1812,10 +1835,8 @@ class JaxEngine:
             # int8 scale planes): slice the pow2 padding off uniformly
             arrs = tuple(a[:, :count] for a in arrs)
             if to_host:
-                t_d = obs.begin()
-                out = tuple(np.asarray(a) for a in arrs)
-                obs.end("device_wait", t_d, track=self._obs_track,
-                        what="parked_extract")
+                with self._phase("device_wait", what="parked_extract"):
+                    out = tuple(np.asarray(a) for a in arrs)
                 return out
             return arrs
 
@@ -1882,9 +1903,7 @@ class JaxEngine:
                     else:
                         await self._wake.wait()
                     continue
-                t0 = time.monotonic()
                 await asyncio.to_thread(self._sched_step)
-                self.metrics["step_time_s"] = time.monotonic() - t0
                 self.metrics["steps"] += 1
                 await asyncio.sleep(0)  # yield to the event loop
         except asyncio.CancelledError:
@@ -1918,50 +1937,52 @@ class JaxEngine:
             # migratable worker-engine-error marker; a wedge is caught
             # by the canary (health_check.py)
             chaos.hit("engine.step", key=self.config.served_name)
-            # timeline spans (obs/): one `step` covering the iteration,
-            # `sched` over the host-only scheduling work; the dispatch
-            # phases emit their own spans inside.  Each is one
-            # module-global None check when tracing is off.
+            # phases (obs.PhaseClock): one `step` covering the iteration
+            # and, inside it, phases that together cover its wall time —
+            # `sched` over the host-only scheduling work, then the
+            # dispatch phases, each opened where its work is.
             # Overlapped mode: when unread bursts are in flight the
             # device is still executing them, so this host scheduling
             # work is OVERLAPPED, not overhead — it reports as
             # `enqueue_ahead` (report.py excludes it from
             # sched_overhead_frac; the wall partition stays exact).
-            t_step = obs.begin()
-            t = obs.begin()
-            overlapped = self._overlap and bool(self._inflight)
-            self._process_cancellations()
-            self._maybe_offload()
-            self._admit_waiting()
-            obs.end("enqueue_ahead" if overlapped else "sched", t,
-                    track=self._obs_track)
-            # deferred first tokens from the PREVIOUS step's completing
-            # prefills: flushed before this step's dispatches, so the
-            # blocking fetch pays only for work the device has had a
-            # full step to finish (overlap mode; sync fetches inline)
-            self._flush_pending_first()
-            self._prefill_step()
-            self._guided_step()
-            self._spec_step()
-            if any(s is not None and not s.prefilling
-                   and not s.awaiting_first for s in self._slots):
-                self._decode_step()
-            elif self._inflight:
-                # no dispatchable decode work: flush the pipeline tail so
-                # trailing tokens/finishes are delivered promptly
-                self._drain_inflight()
-            led = self.kv_ledger
-            if led is not None and led.audit_due():
-                # reconciliation sweep on the finish cadence (a request
-                # freed its blocks since the last audit) — the books are
-                # checked while the leak is one request old, not one
-                # incident old
-                self._audit_ledger_locked("step")
-            if t_step:  # attrs are only worth computing when tracing
-                obs.end("step", t_step, track=self._obs_track,
-                        active=sum(1 for s in self._slots
-                                   if s is not None),
-                        waiting=len(self.waiting))
+            with self._phase("step") as step:
+                overlapped = self._overlap and bool(self._inflight)
+                with self._phase("enqueue_ahead" if overlapped
+                                 else "sched"):
+                    self._process_cancellations()
+                    self._maybe_offload()
+                    self._admit_waiting()
+                # deferred first tokens from the PREVIOUS step's
+                # completing prefills: flushed before this step's
+                # dispatches, so the blocking fetch pays only for work
+                # the device has had a full step to finish (overlap
+                # mode; sync fetches inline)
+                self._flush_pending_first()
+                self._prefill_step()
+                self._guided_step()
+                self._spec_step()
+                if any(s is not None and not s.prefilling
+                       and not s.awaiting_first for s in self._slots):
+                    self._decode_step()
+                elif self._inflight:
+                    # no dispatchable decode work: flush the pipeline
+                    # tail so trailing tokens/finishes are delivered
+                    # promptly
+                    self._drain_inflight()
+                led = self.kv_ledger
+                if led is not None and led.audit_due():
+                    # reconciliation sweep on the finish cadence (a
+                    # request freed its blocks since the last audit) —
+                    # the books are checked while the leak is one request
+                    # old, not one incident old
+                    with self._phase("audit"):
+                        self._audit_ledger_locked("step")
+                if step.tm is not None or obs.enabled():
+                    # attrs are only worth computing when someone reads
+                    step.set(active=sum(1 for s in self._slots
+                                        if s is not None),
+                             waiting=len(self.waiting))
 
     # -- distributed KVBM (kvbm/remote.py) ---------------------------------
     async def _remote_prefetch(self, request: PreprocessedRequest) -> None:
@@ -2040,24 +2061,21 @@ class JaxEngine:
         )
         if not cands:
             return
-        t_obs = obs.begin()
-        ids = _pow2_ids([bid for _, bid in cands])
-        if self.step_sink is not None:
-            self.step_sink("gather", {"ids": ids})
-        t_d = obs.begin()
-        arrs = [np.asarray(a)
-                for a in self._jit_gather(self.kv, jnp.asarray(ids))]
-        obs.end("device_wait", t_d, track=self._obs_track,
-                what="offload_gather")
-        for i, (h, _) in enumerate(cands):
-            # contiguous copies: a [:, i] view would pin the whole gathered
-            # batch buffer in host RAM for as long as any one block lives.
-            # int8 caches offload (k, v, k_scale, v_scale) per block —
-            # half the host-tier bytes, scales bit-exact (kvbm/pools.py)
-            self._emit_tier_events(self.kvbm.offload(
-                h, *(np.ascontiguousarray(a[:, i]) for a in arrs)))
-        obs.end("kvbm_offload", t_obs, track=self._obs_track,
-                blocks=len(cands))
+        with self._phase("kvbm_offload", blocks=len(cands)):
+            ids = _pow2_ids([bid for _, bid in cands])
+            if self.step_sink is not None:
+                self.step_sink("gather", {"ids": ids})
+            with self._phase("device_wait", what="offload_gather"):
+                arrs = [np.asarray(a)
+                        for a in self._jit_gather(self.kv, jnp.asarray(ids))]
+            for i, (h, _) in enumerate(cands):
+                # contiguous copies: a [:, i] view would pin the whole
+                # gathered batch buffer in host RAM for as long as any one
+                # block lives.  int8 caches offload (k, v, k_scale,
+                # v_scale) per block — half the host-tier bytes, scales
+                # bit-exact (kvbm/pools.py)
+                self._emit_tier_events(self.kvbm.offload(
+                    h, *(np.ascontiguousarray(a[:, i]) for a in arrs)))
 
     def _try_onboard(self, slot: _Slot, hit: int, cap_blocks: int) -> int:
         """Extend a G1 prefix hit with blocks onboarded from G2/G3/G4:
@@ -2073,7 +2091,16 @@ class JaxEngine:
         run = self.kvbm.match_run(hashes[hit:cap_blocks])
         if run == 0:
             return 0
-        t_obs = obs.begin()
+        with self._phase("kvbm_onboard") as ph:
+            n = self._onboard_run(slot, hit, run, ph)
+            if n == 0:
+                ph.off_ring()
+            return n
+
+    def _onboard_run(self, slot: _Slot, hit: int, run: int, ph) -> int:
+        """Fetch and scatter the `run` blocks after `hit` (see
+        _try_onboard; `ph` is its kvbm_onboard phase)."""
+        hashes = slot.seq.block_hashes
         block_ids = self.allocator.seq_block_ids(self._seq_id(slot))
         arity = len(self.kv)
         comps: List[list] = [[] for _ in range(arity)]
@@ -2124,9 +2151,8 @@ class JaxEngine:
         for src, cnt in by_tier.items():
             key = f"kv_onboard_{src}"
             self.metrics[key] = self.metrics.get(key, 0) + cnt
-        obs.end("kvbm_onboard", t_obs, track=self._obs_track, blocks=n,
-                tokens=n * self.config.block_size,
-                **{f"from_{s}": c for s, c in by_tier.items()})
+        ph.set(blocks=n, tokens=n * self.config.block_size,
+               **{f"from_{s}": c for s, c in by_tier.items()})
         return n
 
     # -- prefill ----------------------------------------------------------
@@ -2214,14 +2240,14 @@ class JaxEngine:
         )[: self.config.max_prefill_seqs]
         if not pslots:
             return
-        t_obs = obs.begin()
-        try:
-            self._prefill_dispatch(pslots)
-        finally:
-            extra = self._obs_dispatch_extra or {}
-            self._obs_dispatch_extra = None
-            obs.end("prefill_dispatch", t_obs, track=self._obs_track,
-                    rows=len(pslots), **extra)
+        with self._phase("prefill_dispatch", rows=len(pslots)) as ph:
+            try:
+                self._prefill_dispatch(pslots)
+            finally:
+                extra, self._obs_dispatch_extra = \
+                    self._obs_dispatch_extra, None
+                if extra:
+                    ph.set(**extra)
 
     def _prefill_dispatch(self, pslots) -> None:
         """Route this step's prefilling slots to one program (see
@@ -2309,6 +2335,7 @@ class JaxEngine:
                 "top_ks": top_ks, "top_ps": top_ps,
                 **({"lidx": lidx} if self.lora_bank is not None else {}),
             })
+        self._stamp_dispatch(pslots)
         tok, self.kv = self._jit_prefill_batched(
             self.params, self.kv,
             jnp.asarray(toks), jnp.asarray(positions), jnp.asarray(tables),
@@ -2410,7 +2437,7 @@ class JaxEngine:
         self.fpm.append(rec)
         if obs.enabled():
             # hand the record's roofline-relevant fields to the
-            # enclosing prefill_dispatch span (_prefill_step ends it and
+            # enclosing prefill_dispatch span (_prefill_step owns it and
             # cannot see this path's locals); consumed exactly once
             self._obs_dispatch_extra = {
                 k: rec[k] for k in ("tokens", "bucket", "gap_s", "synced",
@@ -2439,6 +2466,7 @@ class JaxEngine:
         a = plan.arrays
         if self.step_sink is not None:
             self.step_sink("prefill_packed", dict(a))
+        self._stamp_dispatch(plan.slots)
         tok, self.kv = self._jit_prefill_packed(
             self.params, self.kv,
             jnp.asarray(a["toks"]), jnp.asarray(a["positions"]),
@@ -2510,6 +2538,7 @@ class JaxEngine:
                 **({"lidx": np.int32(slot.lora_idx)}
                    if self.lora_bank is not None else {}),
             })
+        self._stamp_dispatch((slot,))
         tok, self.kv = self._jit_prefill(
             self.params, self.kv,
             jnp.asarray(toks), jnp.asarray(positions),
@@ -2556,6 +2585,7 @@ class JaxEngine:
                 "temp": np.float32(s.temperature),
                 "top_k": np.int32(s.top_k), "top_p": np.float32(s.top_p),
             })
+        self._stamp_dispatch((slot,))
         tok, self.kv = self._jit_prefill_ring(
             self.params, self.kv, jnp.asarray(toks),
             jnp.asarray(positions), jnp.asarray(slot.block_table),
@@ -2571,6 +2601,16 @@ class JaxEngine:
         else:
             first = -1
         self._finish_prefill_chunk(slot, T, first)
+
+    @staticmethod
+    def _stamp_dispatch(slots) -> None:
+        """Request stage stamp: the first prefill chunk of these slots'
+        requests is about to be dispatched (one clock read a program;
+        a preempted request's replay keeps its first stamp)."""
+        now = time.monotonic()
+        for s in slots:
+            if s.dispatched_t == 0.0:
+                s.dispatched_t = now
 
     def _completing_rows(self, slots, chunks) -> Dict[int, "_Slot"]:
         """{program row -> slot} of slots whose prompt completes this
@@ -2605,10 +2645,8 @@ class JaxEngine:
                 ents.append((slot, (self._seq_id(slot), slot.epoch), row))
             self._pending_first.append({"tok": tok, "entries": ents})
             return None
-        t_obs = obs.begin()
-        arr = np.asarray(tok)
-        obs.end("device_wait", t_obs, track=self._obs_track,
-                what="prefill_first")
+        with self._phase("device_wait", what="prefill_first"):
+            arr = np.asarray(tok)
         self._fpm_sync_t = time.monotonic()
         return arr
 
@@ -2622,20 +2660,19 @@ class JaxEngine:
         if not self._pending_first:
             return
         pending, self._pending_first = self._pending_first, []
-        t_obs = obs.begin()
-        arrs = [np.asarray(e["tok"]) for e in pending]
-        obs.end("device_wait", t_obs, track=self._obs_track,
-                what="prefill_first")
+        with self._phase("device_wait", what="prefill_first"):
+            arrs = [np.asarray(e["tok"]) for e in pending]
         self._fpm_sync_t = time.monotonic()
-        for e, arr in zip(pending, arrs):
-            flat = np.atleast_1d(arr)
-            for slot, ident, row in e["entries"]:
-                slot.awaiting_first = False
-                if slot.finished or slot.index < 0 \
-                        or self._slots[slot.index] is not slot \
-                        or (self._seq_id(slot), slot.epoch) != ident:
-                    continue
-                self._complete_prefill(slot, int(flat[row]))
+        with self._phase("emit", what="prefill_first"):
+            for e, arr in zip(pending, arrs):
+                flat = np.atleast_1d(arr)
+                for slot, ident, row in e["entries"]:
+                    slot.awaiting_first = False
+                    if slot.finished or slot.index < 0 \
+                            or self._slots[slot.index] is not slot \
+                            or (self._seq_id(slot), slot.epoch) != ident:
+                        continue
+                    self._complete_prefill(slot, int(flat[row]))
 
     def _finish_prefill_chunk(self, slot: "_Slot", chunk: int,
                               first: Optional[int]) -> None:
@@ -2658,7 +2695,7 @@ class JaxEngine:
             # re-derive the first token's logits in the guided step by
             # re-running the last prompt position (its KV rewrite is
             # value-identical)
-            slot.first_token_t = time.monotonic()
+            self._stamp_first_token(slot)
             slot.ctx_len = slot.prompt_len - 1
             slot.last_token = slot.seq.tokens[slot.prompt_len - 1]
             return
@@ -2669,11 +2706,19 @@ class JaxEngine:
     def _complete_prefill(self, slot: "_Slot", first: int) -> None:
         """Prompt fully materialized and first token in hand: emit it (or
         park the KV for disagg pull)."""
-        slot.first_token_t = time.monotonic()
+        self._stamp_first_token(slot)
         if slot.disagg_prefill:
             self._park_prefilled(slot, first)
             return
         self._push_token(slot, first)
+
+    @staticmethod
+    def _stamp_first_token(slot: "_Slot") -> None:
+        """Request stage stamp: the first token is in the host's hands
+        (a preempted request's replay keeps its first stamp, so `ttft_s`
+        and the stages stay the first token's)."""
+        if slot.first_token_t == 0.0:
+            slot.first_token_t = time.monotonic()
 
     async def _stream_pull(self, slot: _Slot, dp: Dict[str, Any]) -> None:
         """Decode-side streaming pull: inject the prefill's KV chunk by
@@ -2875,7 +2920,10 @@ class JaxEngine:
         slot.cached_tokens = prompt_len  # skipped compute entirely
         slot.pulling = False
         self._commit_full_blocks(slot)
-        slot.first_token_t = time.monotonic()
+        # a pulled prompt has no prefill dispatch of its own: its queue
+        # stage ends where the pulled KV is whole
+        self._stamp_dispatch((slot,))
+        self._stamp_first_token(slot)
         if slot.guide is not None:
             # constrained output served via disagg: the prefill worker
             # sampled its first token UNCONSTRAINED (it parks before the
@@ -2961,10 +3009,7 @@ class JaxEngine:
                      # reuse/queue facts ride its single frame
                      "forensic": self._forensic(slot)},
         )
-        if self._loop_ref is not None:
-            self._loop_ref.call_soon_threadsafe(slot.out_q.put_nowait, out)
-        else:
-            slot.out_q.put_nowait(out)
+        self._send(slot, out)
 
     # -- speculative decoding (spec/) --------------------------------------
     def _spec_step(self) -> None:
@@ -2998,6 +3043,14 @@ class JaxEngine:
                  and s.lora_idx == 0]
         if not cands:
             return
+        with self._phase("spec_dispatch") as ph:
+            if not self._spec_round(cands, ph):
+                ph.off_ring()
+
+    def _spec_round(self, cands, ph) -> bool:
+        """_spec_step's round over its candidate slots; False when no
+        verify program was dispatched."""
+        c = self.config
         rows = []
         budget = c.chunk_budget
         for s in cands:
@@ -3050,7 +3103,7 @@ class JaxEngine:
             budget -= len(drafts) + 1
             rows.append((s, drafts))
         if not rows:
-            return
+            return False
         from ..spec import plan_spec_verify
 
         plan = plan_spec_verify(
@@ -3066,58 +3119,55 @@ class JaxEngine:
             jnp.asarray(a["seg_ids"]), jnp.asarray(a["tables"]),
             jnp.asarray(a["valid"]), jnp.asarray(a["temps_t"]),
         )
-        t_obs = obs.begin()
-        ids = np.asarray(ids)
-        vals = np.asarray(vals)
-        lse = np.asarray(lse)
-        obs.end("device_wait", t_obs, track=self._obs_track,
-                what="spec_verify_fetch")
+        with self._phase("device_wait", what="spec_verify_fetch"):
+            ids = np.asarray(ids)
+            vals = np.asarray(vals)
+            lse = np.asarray(lse)
         self._fpm_sync_t = time.monotonic()
         from .sampler import spec_accept_tokens
 
-        t_obs = obs.begin()
         proposed_total = accepted_total = 0
         specced = set()
-        for (s, drafts), off in zip(plan.rows, plan.offsets):
-            n = len(drafts) + 1
-            sm = s.request.sampling
-            # host-side rng stream keyed (seed, position): replayed or
-            # migrated requests re-draw identically, like the device
-            # sampler's fold_in(seed, step)
-            rng = np.random.default_rng(
-                (s.sampling_seed * 0x9E3779B1 + s.generated + 1)
-                & 0xFFFFFFFF)
-            accepted, emitted = spec_accept_tokens(
-                ids[off:off + n], vals[off:off + n], lse[off:off + n],
-                drafts, greedy=sm.temperature <= 0.0, top_k=sm.top_k,
-                top_p=sm.top_p, rng=rng)
-            proposed_total += len(drafts)
-            accepted_total += accepted
-            self._spec_feedback(s, accepted, len(drafts))
-            specced.add(s.index)
-            # the device token chain no longer feeds this lane: its true
-            # last_token is now a host-side spec emission, so a later
-            # decode burst must neither chain it nor treat the lane as a
-            # pure continuation of the pre-spec descriptor
-            self._chain_owner[s.index] = None
-            ctx0 = s.ctx_len
-            for tok in emitted:
-                s.ctx_len += 1
-                self.metrics["decode_tokens"] += 1
-                self._push_token(s, int(tok))
-                if s.finished:
-                    break
-            # the draft cache matches the real sequence through the
-            # accepted prefix (the propose pass wrote draft KV for its k
-            # INPUT positions [ctx0, ctx0+k-1]; the rejected tail is
-            # overwritten on the next round).  Capped at ctx0+k: after
-            # FULL acceptance the last draft token's own KV was never a
-            # decode input, so that position must be re-prefilled
-            s.draft_pos = min(s.ctx_len, ctx0 + len(drafts))
-            if not s.finished:
-                self._spec_trim(s)
-        obs.end("sample", t_obs, track=self._obs_track,
-                what="spec_accept", lanes=len(plan.rows))
+        with self._phase("sample", what="spec_accept",
+                         lanes=len(plan.rows)):
+            for (s, drafts), off in zip(plan.rows, plan.offsets):
+                n = len(drafts) + 1
+                sm = s.request.sampling
+                # host-side rng stream keyed (seed, position): replayed or
+                # migrated requests re-draw identically, like the device
+                # sampler's fold_in(seed, step)
+                rng = np.random.default_rng(
+                    (s.sampling_seed * 0x9E3779B1 + s.generated + 1)
+                    & 0xFFFFFFFF)
+                accepted, emitted = spec_accept_tokens(
+                    ids[off:off + n], vals[off:off + n], lse[off:off + n],
+                    drafts, greedy=sm.temperature <= 0.0, top_k=sm.top_k,
+                    top_p=sm.top_p, rng=rng)
+                proposed_total += len(drafts)
+                accepted_total += accepted
+                self._spec_feedback(s, accepted, len(drafts))
+                specced.add(s.index)
+                # the device token chain no longer feeds this lane: its true
+                # last_token is now a host-side spec emission, so a later
+                # decode burst must neither chain it nor treat the lane as a
+                # pure continuation of the pre-spec descriptor
+                self._chain_owner[s.index] = None
+                ctx0 = s.ctx_len
+                for tok in emitted:
+                    s.ctx_len += 1
+                    self.metrics["decode_tokens"] += 1
+                    self._push_token(s, int(tok))
+                    if s.finished:
+                        break
+                # the draft cache matches the real sequence through the
+                # accepted prefix (the propose pass wrote draft KV for its k
+                # INPUT positions [ctx0, ctx0+k-1]; the rejected tail is
+                # overwritten on the next round).  Capped at ctx0+k: after
+                # FULL acceptance the last draft token's own KV was never a
+                # decode input, so that position must be re-prefilled
+                s.draft_pos = min(s.ctx_len, ctx0 + len(drafts))
+                if not s.finished:
+                    self._spec_trim(s)
         self._specced = frozenset(specced)
         self.metrics["spec_steps"] = self.metrics.get("spec_steps", 0) + 1
         self.metrics["spec_proposed"] = \
@@ -3144,6 +3194,7 @@ class JaxEngine:
             rec["xla_bytes"] = vcost["bytes"]
         self.fpm.append(rec)
         self._fpm_last_spec_t = now
+        return True
 
     def _spec_grow(self, s: _Slot, k: int) -> int:
         """Grow s's block table to cover verify positions [ctx, ctx+k];
@@ -3257,9 +3308,21 @@ class JaxEngine:
         return k
 
     def _decode_step(self) -> None:
+        """Read back the bursts beyond the pipeline depth, grow the
+        active slots' block tables, then build and dispatch one decode
+        burst: one `decode_dispatch` phase (on the ring only when a
+        burst went out)."""
+        with self._phase("decode_dispatch") as ph:
+            sent = self._decode_burst(ph)
+            if not sent:
+                ph.off_ring()
+        if sent and not self._overlap:
+            # lockstep reference mode: block on the burst and emit now
+            self._drain_inflight()
+
+    def _decode_burst(self, ph) -> bool:
+        """_decode_step's body; False when nothing was dispatched."""
         c = self.config
-        B = c.max_num_seqs
-        t_obs = obs.begin()
         # pipeline: keep at most depth-1 unread bursts after this dispatch;
         # processing the oldest here overlaps its (already-complete or
         # nearly-complete) fetch with the device compute of newer bursts.
@@ -3279,7 +3342,7 @@ class JaxEngine:
                   and not s.awaiting_first
                   and s.guide is None and s.index not in self._specced]
         if not active:
-            return
+            return False
         # Every active slot MUST have a block for its next device position
         # ctx_len + inflight (preempt if even that fails); blocks for the
         # rest of the burst are speculative — under allocation pressure
@@ -3298,7 +3361,7 @@ class JaxEngine:
                     # of the table — drain so the length-finish fires
                     # before any further dispatch for this slot
                     self._drain_inflight()
-                    return
+                    return False
                 grow = self.allocator.append_block(self._seq_id(slot))
                 self._emit_events(grow)
                 if grow.block_id is None:
@@ -3334,15 +3397,35 @@ class JaxEngine:
                   and not s.awaiting_first
                   and s.guide is None and s.index not in self._specced]
         if not active:
-            return
+            return False
 
-        # from here to the dispatch call is host work building + enqueuing
-        # the NEXT burst; with unread bursts in flight the device is still
-        # executing, so this is the overlapped enqueue-ahead phase, not
-        # scheduler overhead (obs vocabulary: `enqueue_ahead`, nested inside
-        # decode_dispatch so the report's innermost-span attribution keeps
-        # the wall partition exact).
-        t_ea = obs.begin() if (self._overlap and self._inflight) else 0.0
+        # building + enqueuing the NEXT burst is host work; with unread
+        # bursts in flight the device is still executing, so it is the
+        # overlapped enqueue-ahead phase, not scheduler overhead (obs
+        # vocabulary: `enqueue_ahead`, nested inside decode_dispatch so
+        # innermost-span attribution keeps the wall partition exact).
+        if self._overlap and self._inflight:
+            with self._phase("enqueue_ahead", k=k):
+                burst, cont_burst = self._build_burst(active, k)
+        else:
+            burst, cont_burst = self._build_burst(active, k)
+        lanes = {}
+        for s in active:
+            s.inflight += k
+            lanes[s.index] = (self._seq_id(s), s.epoch)
+            self._chain_owner[s.index] = lanes[s.index]
+        self._inflight.append({"burst": burst, "k": k, "lanes": lanes})
+        extra, self._obs_decode_extra = self._obs_decode_extra, None
+        ph.set(cont=cont_burst, k=k, lanes=len(active), **(extra or {}))
+        return True
+
+    def _build_burst(self, active, k: int):
+        """Build the descriptor of one decode burst over `active` and
+        dispatch it (a device-resident continuation where provable).
+        Returns (the unread burst [k, B], whether it was a
+        continuation)."""
+        c = self.config
+        B = c.max_num_seqs
         # NOTE on buffer reuse: these descriptor arrays CANNOT be pooled /
         # double-buffered in place — jax.device_put may alias numpy memory
         # zero-copy (it does on CPU), continuation bursts keep the aliased
@@ -3432,20 +3515,7 @@ class JaxEngine:
             burst.copy_to_host_async()
         except AttributeError:  # non-jax stand-ins in tests
             pass
-        obs.end("enqueue_ahead", t_ea, track=self._obs_track, k=k)
-        lanes = {}
-        for s in active:
-            s.inflight += k
-            lanes[s.index] = (self._seq_id(s), s.epoch)
-            self._chain_owner[s.index] = lanes[s.index]
-        self._inflight.append({"burst": burst, "k": k, "lanes": lanes})
-        extra = self._obs_decode_extra or {}
-        self._obs_decode_extra = None
-        obs.end("decode_dispatch", t_obs, track=self._obs_track,
-                cont=cont_burst, k=k, lanes=len(active), **extra)
-        if not self._overlap:
-            # lockstep reference mode: block on the burst and emit now
-            self._drain_inflight()
+        return burst, cont_burst
 
     GUIDED_TOPM = 32
     GUIDED_TOPM_WIDE = 256
@@ -3467,9 +3537,11 @@ class JaxEngine:
         """ONE lazy-init site for the guided top-M program — leader and
         follower must compile the identical collective program."""
         if getattr(self, "_jit_decode_topk", None) is None:
-            self._jit_decode_topk = self.compile_watch.wrap(jax.jit(
-                partial(self._decode_topk_impl, self.family,
-                        self.model_cfg, self.mesh, self.GUIDED_TOPM),
+            w = self.compile_watch
+            self._jit_decode_topk = w.wrap(jax.jit(
+                w.named(partial(self._decode_topk_impl, self.family,
+                                self.model_cfg, self.mesh,
+                                self.GUIDED_TOPM), "decode_topk"),
                 donate_argnums=(1,),
             ), "decode_topk")
         return self._jit_decode_topk
@@ -3479,9 +3551,11 @@ class JaxEngine:
         lazily on the first time a guided slot's top-M set has no valid
         continuation, before giving up and force-closing the document."""
         if getattr(self, "_jit_decode_topk_wide", None) is None:
-            self._jit_decode_topk_wide = self.compile_watch.wrap(jax.jit(
-                partial(self._decode_topk_impl, self.family,
-                        self.model_cfg, self.mesh, self.GUIDED_TOPM_WIDE),
+            w = self.compile_watch
+            self._jit_decode_topk_wide = w.wrap(jax.jit(
+                w.named(partial(self._decode_topk_impl, self.family,
+                                self.model_cfg, self.mesh,
+                                self.GUIDED_TOPM_WIDE), "decode_topk_wide"),
                 donate_argnums=(1,),
             ), "decode_topk_wide")
         return self._jit_decode_topk_wide
@@ -3519,14 +3593,18 @@ class JaxEngine:
                   and s.guide is not None and not s.finished]
         if not gslots:
             return
+        with self._phase("sample", what="guided", lanes=len(gslots)):
+            # ONE init site (_topk_jit): a duplicate raw jax.jit here
+            # would bypass the compile watchdog's wrapper — the guided
+            # fork's 8-14s mid-serving compile is exactly what it must see
+            self._topk_jit()
+            self._guided_round(gslots, self._guided_codec())
+
+    def _guided_round(self, gslots, codec) -> None:
+        """One constrained token for each of `gslots` (see
+        _guided_step)."""
         c = self.config
-        # ONE init site (_topk_jit): a duplicate raw jax.jit here would
-        # bypass the compile watchdog's wrapper — the guided fork's
-        # 8-14s mid-serving compile is exactly what it must see
-        self._topk_jit()
-        codec = self._guided_codec()
         B = c.max_num_seqs
-        t_obs = obs.begin()
         for slot in gslots:
             # block for the next position (no burst speculation needed)
             nblocks = int(np.count_nonzero(slot.block_table))
@@ -3582,10 +3660,9 @@ class JaxEngine:
                         return ("tok", tok)
                 return None
 
-            t_d = obs.begin()
-            cand_ids, cand_vals = np.asarray(ids[i]), np.asarray(vals[i])
-            obs.end("device_wait", t_d, track=self._obs_track,
-                    what="guided_fetch")
+            with self._phase("device_wait", what="guided_fetch"):
+                cand_ids = np.asarray(ids[i])
+                cand_vals = np.asarray(vals[i])
             chosen = choose(cand_ids, cand_vals)
             if chosen is None:
                 # nothing in the top-M set extends the document: retry
@@ -3602,10 +3679,8 @@ class JaxEngine:
                     jnp.asarray(a["positions"]), jnp.asarray(a["tables"]),
                     jnp.asarray(a["ctx_lens"]), jnp.asarray(a["valid"]),
                 )
-                t_d = obs.begin()
-                wid_i, wval_i = np.asarray(wids[i]), np.asarray(wvals[i])
-                obs.end("device_wait", t_d, track=self._obs_track,
-                        what="guided_fetch")
+                with self._phase("device_wait", what="guided_fetch"):
+                    wid_i, wval_i = np.asarray(wids[i]), np.asarray(wvals[i])
                 chosen = choose(wid_i, wval_i)
             if chosen is None:
                 # even the widened set has no valid continuation: close
@@ -3625,8 +3700,6 @@ class JaxEngine:
                 # the token budget — close canonically (a few tokens
                 # over) instead of emitting truncated invalid JSON
                 self._guided_finish(slot, codec, forced=True)
-        obs.end("sample", t_obs, track=self._obs_track, what="guided",
-                lanes=len(gslots))
 
     def _guided_emit(self, slot: _Slot, tok: int,
                      finish: Optional[str]) -> None:
@@ -3651,10 +3724,7 @@ class JaxEngine:
                      if (finish is not None or slot.generated == 1)
                      else None),
         )
-        if self._loop_ref is not None:
-            self._loop_ref.call_soon_threadsafe(slot.out_q.put_nowait, out)
-        else:
-            slot.out_q.put_nowait(out)
+        self._send(slot, out)
         if finish is not None:
             slot.finished = True
             if slot.index >= 0:
@@ -3687,10 +3757,7 @@ class JaxEngine:
             metrics["guided_forced_close_tokens"] = len(toks)
         out = LLMEngineOutput(token_ids=list(toks), finish_reason="stop",
                               metrics=metrics)
-        if self._loop_ref is not None:
-            self._loop_ref.call_soon_threadsafe(slot.out_q.put_nowait, out)
-        else:
-            slot.out_q.put_nowait(out)
+        self._send(slot, out)
         slot.finished = True
         if slot.index >= 0:
             self._slots[slot.index] = None
@@ -3836,26 +3903,26 @@ class JaxEngine:
         finish, or to since-freed blocks that device program order
         guarantees were overwritten only by later dispatches)."""
         e = self._inflight.popleft()
-        t_obs = obs.begin()
-        arr = np.asarray(e["burst"])  # [k, B]
-        obs.end("device_wait", t_obs, track=self._obs_track, k=e["k"],
-                what="burst_fetch")
+        with self._phase("device_wait", k=e["k"], what="burst_fetch"):
+            arr = np.asarray(e["burst"])  # [k, B]
         self._fpm_sync_t = time.monotonic()
-        for i, ident in e["lanes"].items():
-            s = self._slots[i] if i < len(self._slots) else None
-            if s is None or (self._seq_id(s), s.epoch) != ident \
-                    or s.finished:
-                continue
-            s.inflight -= e["k"]
-            for j in range(e["k"]):
-                s.ctx_len += 1
-                self.metrics["decode_tokens"] += 1
-                self._push_token(s, int(arr[j, i]))
-                if s.finished:
-                    # mid-burst finish: trailing sampled tokens discarded
-                    # (their KV writes landed in this slot's own blocks,
-                    # which are never committed past the finish ctx_len)
-                    break
+        with self._phase("emit", k=e["k"], what="burst"):
+            for i, ident in e["lanes"].items():
+                s = self._slots[i] if i < len(self._slots) else None
+                if s is None or (self._seq_id(s), s.epoch) != ident \
+                        or s.finished:
+                    continue
+                s.inflight -= e["k"]
+                for j in range(e["k"]):
+                    s.ctx_len += 1
+                    self.metrics["decode_tokens"] += 1
+                    self._push_token(s, int(arr[j, i]))
+                    if s.finished:
+                        # mid-burst finish: trailing sampled tokens
+                        # discarded (their KV writes landed in this slot's
+                        # own blocks, which are never committed past the
+                        # finish ctx_len)
+                        break
 
     def _drain_inflight(self) -> None:
         while self._inflight:
@@ -3930,12 +3997,51 @@ class JaxEngine:
             metrics=metrics,
         )
         if self._loop_ref is not None:
-            self._loop_ref.call_soon_threadsafe(slot.out_q.put_nowait, out)
+            self._send(slot, out)
         if finish is not None:
             slot.finished = True
             if slot.index >= 0:
                 self._slots[slot.index] = None
             self._emit_events(self.allocator.free(self._seq_id(slot)))
+
+    def _send(self, slot: _Slot, out: LLMEngineOutput) -> None:
+        """Hand one frame to the request's stream, which the event loop
+        owns.  The request's first frame goes through _emit_first, which
+        closes the request's stages where the frame reaches the stream."""
+        put = slot.out_q.put_nowait
+        if not slot.first_sent:
+            slot.first_sent = True
+            put = partial(self._emit_first, slot)
+        if self._loop_ref is not None:
+            self._loop_ref.call_soon_threadsafe(put, out)
+        else:
+            put(out)
+
+    def _emit_first(self, slot: _Slot, out: LLMEngineOutput) -> None:
+        """On the event loop: put the request's first frame on its stream
+        and add its three stages to `req_stage_s.*` (seconds; their sum
+        is the engine's time to first token, enqueue to stream).  Under a
+        Tracer the stages are also ring spans on the request's own
+        track."""
+        slot.out_q.put_nowait(out)
+        now = time.monotonic()
+        t_first = slot.first_token_t or now
+        t_disp = slot.dispatched_t or t_first
+        m = self.metrics
+        m["req_stage_s.queue"] += t_disp - slot.enqueued_t
+        m["req_stage_s.prefill"] += t_first - t_disp
+        m["req_stage_s.emit"] += now - t_first
+        m["req_stage_n"] += 1
+        tr = obs.tracer()
+        if tr is not None:
+            rid = slot.request.request_id
+            tid = obs.trace_id_from_annotations(slot.request.annotations)
+            for kind, t0, t1 in zip(
+                    obs.REQUEST_STAGES,
+                    (slot.enqueued_t, t_disp, t_first),
+                    (t_disp, t_first, now)):
+                tr.record(kind, t0, t1, {"request_id": rid}, tid,
+                          f"req:{rid}")
 
     def _preempt(self, slot: _Slot) -> None:
         """KV OOM: drop the slot's blocks and re-enqueue with full replay."""

@@ -28,6 +28,7 @@ NEG_INF = -1e30
 CAP = 64
 
 
+@jax.named_scope("dyn.sample")
 def sample_tokens(
     logits: jax.Array,        # [B, vocab] fp32
     seeds: jax.Array,         # [B] int32 per-request seed
@@ -57,6 +58,7 @@ def sample_tokens(
     return jax.vmap(one)(logits, seeds, steps, temperature, top_k, top_p)
 
 
+@jax.named_scope("dyn.sample")
 def greedy_tokens(logits: jax.Array) -> jax.Array:
     """Argmax-only fast path: the engine dispatches this specialization when
     every slot in the batch is greedy (temperature <= 0), skipping the

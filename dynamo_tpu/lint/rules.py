@@ -283,7 +283,8 @@ def registry_literals(mod: Module) -> Iterable[Finding]:
                     "DYN006", node,
                     f"seam {seam!r} is not in chaos.SEAMS — a rule on an "
                     "unregistered seam silently never fires")
-        elif d in ("obs.span", "obs.end"):
+        elif d in ("obs.span", "obs.end") or t == "_phase":
+            # `<engine>._phase(kind, ...)` is an obs.PhaseClock call
             kind = str_arg(node)
             if kind is not None and kind not in span_kinds:
                 yield mod.finding(
@@ -484,8 +485,13 @@ def _body_of(mod: Module, stmt: ast.stmt):
 
 
 def _in_device_wait_span(mod: Module, node: ast.AST) -> bool:
-    """True when the call follows the sanctioned idiom in its OWN
-    statement block:
+    """True when the call sits inside the sanctioned phase
+
+        with self._phase("device_wait", what=...):
+            <the blocking fetch>
+
+    (an obs.PhaseClock phase: counter, profiler TraceMe, ring), or
+    follows the older pair in its OWN statement block:
 
         t = obs.begin()
         <the blocking fetch>
@@ -495,6 +501,13 @@ def _in_device_wait_span(mod: Module, node: ast.AST) -> bool:
     obs.end("device_wait", ...) somewhere after it, both at the same
     block depth — so the fetch's wall time is attributed to the
     device_wait phase the gap report scores."""
+    for anc in mod.ancestors(node):
+        if isinstance(anc, ast.With) and any(
+                isinstance(it.context_expr, ast.Call)
+                and terminal(it.context_expr.func) == "_phase"
+                and str_arg(it.context_expr) == "device_wait"
+                for it in anc.items):
+            return True
     stmt = _stmt_of(mod, node)
     block = _body_of(mod, stmt)
     if block is None:
@@ -532,8 +545,8 @@ def blocking_sync_in_hot_path(mod: Module) -> Iterable[Finding]:
         yield mod.finding(
             "DYN011", node,
             f"{what} in the scheduler hot path forces a device sync "
-            "outside a device_wait span: wrap it in the t=obs.begin() / "
-            "obs.end(\"device_wait\", t, ...) idiom so the stall is "
+            "outside a device_wait span: wrap it in `with "
+            "self._phase(\"device_wait\", what=...)` so the stall is "
             "attributed (and deliberate), or move the readback behind "
             "the overlap machinery (_pending_first / _inflight)")
 

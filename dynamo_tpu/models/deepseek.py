@@ -252,6 +252,7 @@ def init_params(cfg: DeepseekConfig, key: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("dyn.attn_qkv")
 def _q_proj(layer, cfg: DeepseekConfig, x: jax.Array,
             positions: jax.Array):
     """x [..., T, d] -> (q_nope [..., T, nh, dn], q_rope [..., T, nh, dr],
@@ -268,6 +269,7 @@ def _q_proj(layer, cfg: DeepseekConfig, x: jax.Array,
     return q_nope, q_rope
 
 
+@jax.named_scope("dyn.attn_qkv")
 def _kv_latent(layer, cfg: DeepseekConfig, x: jax.Array,
                positions: jax.Array):
     """x [..., T, d] -> (c [..., T, R] normed latent, kr [..., T, dr]
@@ -279,6 +281,7 @@ def _kv_latent(layer, cfg: DeepseekConfig, x: jax.Array,
     return c, kr
 
 
+@jax.named_scope("dyn.moe_router")
 def _ds_router(layer, cfg: DeepseekConfig, x: jax.Array):
     """DeepSeek routing -> (weights [T, k], ids [T, k]).
 
@@ -331,6 +334,7 @@ def _ds_ffn(layer, cfg: DeepseekConfig, x: jax.Array,
     return out
 
 
+@jax.named_scope("dyn.attn_qkv")
 def _absorb_q(layer, q_nope: jax.Array) -> jax.Array:
     """q_nope [..., nh, dn] @ w_uk^T -> absorbed query [..., nh, R]."""
     return jnp.einsum("...hd,hrd->...hr", q_nope.astype(jnp.float32),

@@ -316,6 +316,9 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+# `dyn.*` named scopes (here, in deepseek.py, ops/ and engine/sampler.py)
+# are op metadata only: a profiler groups device ops by layer part
+@jax.named_scope("dyn.attn_qkv")
 def _qkv(layer, cfg: LlamaConfig, x: jax.Array, positions: jax.Array,
          lora=None):
     """x: [..., seq, d_model] -> q [..., seq, nh, hd], k/v [..., seq, nkv, hd].
@@ -345,6 +348,7 @@ def _qkv(layer, cfg: LlamaConfig, x: jax.Array, positions: jax.Array,
     return q, k, v
 
 
+@jax.named_scope("dyn.attn_out")
 def _attn_out(layer, attn_flat: jax.Array, lora=None) -> jax.Array:
     o = attn_flat @ layer["wo"]
     if lora is not None:
@@ -364,12 +368,14 @@ def _lora_ctx(lora_bank, adapter_idx, li):
     return bank_layer(lora_bank, li), adapter_idx
 
 
+@jax.named_scope("dyn.mlp")
 def _mlp(layer, x: jax.Array) -> jax.Array:
     return (jax.nn.silu(x @ layer["w_gate"]) * (x @ layer["w_up"])) @ layer[
         "w_down"
     ]
 
 
+@jax.named_scope("dyn.moe_router")
 def _moe_router(layer, cfg: LlamaConfig, x: jax.Array):
     """Top-k routing: returns (weights [T,k] softmaxed, expert ids [T,k]).
 
@@ -381,6 +387,7 @@ def _moe_router(layer, cfg: LlamaConfig, x: jax.Array):
     return jax.nn.softmax(top_w, axis=-1), top_e
 
 
+@jax.named_scope("dyn.moe_dispatch")
 def moe_dispatch_dense(layer, cfg: LlamaConfig, x: jax.Array,
                        top_w: jax.Array, top_e: jax.Array,
                        valid: Optional[jax.Array] = None) -> jax.Array:
@@ -410,6 +417,7 @@ def _moe_mlp_dense(layer, cfg: LlamaConfig, x: jax.Array,
     return moe_dispatch_dense(layer, cfg, x, top_w, top_e, valid)
 
 
+@jax.named_scope("dyn.moe_dispatch")
 def moe_dispatch_capacity(layer, cfg: LlamaConfig, x: jax.Array,
                           top_w: jax.Array, top_e: jax.Array,
                           valid: Optional[jax.Array] = None) -> jax.Array:
@@ -481,6 +489,7 @@ def _ffn(layer, cfg: LlamaConfig, x: jax.Array,
     return out.reshape(*lead, x.shape[-1])
 
 
+@jax.named_scope("dyn.lm_head")
 def _logits(params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"]["norm"], cfg.rms_eps)
     if cfg.tie_embeddings:

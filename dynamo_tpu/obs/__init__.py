@@ -10,14 +10,40 @@ This module is that decomposition: named spans on every engine phase,
 exported three ways, reduced to ROADMAP item-3's scoreboard by
 :mod:`dynamo_tpu.obs.report`.
 
-Design (mirrors the chaos plane's zero-cost-off contract):
+Design:
 
-  * **Module-global None check when disabled.**  Every hot-path helper
-    (`begin()`, `end()`, `span()`) starts with ``if _TRACER is None``
-    and allocates NOTHING on that branch: `begin()` returns the shared
-    float ``0.0``, `span()` returns one process-wide no-op context
-    manager.  The engine scheduler loop pays one pointer compare per
-    phase when tracing is off.
+  * **One call site per engine phase, three sinks** (`PhaseClock`, one
+    per engine: ``with engine._phase("device_wait", what=...)``).
+    (1) Counters, always on: ``engine.metrics["host_s.<kind>"]`` gains
+    the phase's self seconds (those spent in no phase nested inside
+    it; ``host_s.step`` counts whole steps, so the kinds partition the
+    steps' wall time) and ``["host_n.<kind>"]`` gains 1.  They ride
+    wherever ``dict(engine.metrics)`` already goes.  (2) The profiler's
+    clock, whenever a ``jax.profiler`` session is live (the benchmark's
+    ``--trace 1`` run, an operator's ``/debug/profile`` capture): a
+    TraceMe named ``dyn.<kind>`` with the phase's attributes, on the
+    thread's line of ``/host:CPU`` — the same axis as ``XLA Modules``
+    and ``XLA Ops``, no environment variable.  (3) The ring, when a
+    `Tracer` is installed.  With no session and no `Tracer` a phase is
+    two clock reads, two dict adds and one small handle: no lock, no
+    span record, nothing per token.
+
+  * **Request stages, always on.**  Three stamps a request —
+    first prefill chunk dispatched, first token in the host's hands,
+    first frame put on the stream by the event loop — summed into
+    ``req_stage_s.queue`` / ``.prefill`` / ``.emit`` (seconds) and
+    ``req_stage_n`` (requests whose first token was emitted); their sum
+    is the engine's time to first token exactly.  Under a `Tracer` the
+    stages are also ring spans (``req_queue``, ``req_prefill``,
+    ``req_emit``) on the track ``req:<request_id>``; they cross threads,
+    so they are not TraceMes.
+
+  * **Module-global None check when the ring is off.**  The helpers for
+    everything that is not an engine phase (`begin()`, `end()`,
+    `span()`: frontend, workers, pulls, the mocker) start with
+    ``if _TRACER is None`` and allocate NOTHING on that branch:
+    `begin()` returns the shared float ``0.0``, `span()` returns one
+    process-wide no-op context manager.
 
   * **Thread-safe ring.**  Spans append to a bounded deque from both
     the scheduler thread and the event loop; the ring IS the flight
@@ -61,8 +87,19 @@ Span vocabulary (kind — where — what the time is):
   device_wait      host blocked on a device fetch (burst readback,
                    prefill first-token sync, KVBM gather); on the
                    mocker, the simulated device step sleep
+  spec_dispatch    proposing drafts and dispatching one packed
+                   spec-verify program
   sample           host-side token acceptance: spec-decode rejection
                    sampling, guided-decoding candidate selection
+  emit             applying one read-back program's tokens: stream
+                   frames, finishes, block commits (``what``: burst |
+                   prefill_first)
+  audit            the KV ledger's reconciliation sweep inside a step
+  req_queue / req_prefill / req_emit
+                   one request's stages to its first token (ring only,
+                   track ``req:<request_id>``, attr ``request_id``):
+                   enqueued -> first prefill chunk dispatched -> first
+                   token in hand -> first frame on the stream
   detok            incremental detokenization of one engine output
   frame_egress     writing one SSE frame to the client socket
   request          frontend: one HTTP request end to end (trace_id)
@@ -72,6 +109,11 @@ Span vocabulary (kind — where — what the time is):
                    receiver-paced pull ops on the wire (tier 3)
   kvbm_offload     one batched G1→G2 offload sweep
   kvbm_onboard     one G2/G3/G4→G1 onboard scatter
+
+What needs what: the counters and the ``dyn.*`` phases in a profiler
+capture need nothing.  ``DYN_TRACE=1`` is needed only for the ring: the
+Chrome dump, the flight recorder, ``dynamo_trace_span_seconds`` and the
+per-request spans.
 
 Env vocabulary (the request-trace config style):
 
@@ -106,7 +148,12 @@ DEFAULT_RING = 16384
 # everything else under its own name); kept here so engine, mocker and
 # report agree on the vocabulary
 STEP_PHASES = ("sched", "enqueue_ahead", "prefill_dispatch",
-               "decode_dispatch", "device_wait", "sample")
+               "decode_dispatch", "spec_dispatch", "device_wait", "sample",
+               "emit", "audit")
+
+# the three stages of one request's time to first token (engine/core.py
+# _emit_first); ring spans only, one track per request id
+REQUEST_STAGES = ("req_queue", "req_prefill", "req_emit")
 
 # THE canonical span vocabulary (the docstring table above, plus the
 # compile watchdog's span): every obs.span()/obs.end() call site names
@@ -114,7 +161,7 @@ STEP_PHASES = ("sched", "enqueue_ahead", "prefill_dispatch",
 # statically — a typo'd kind would otherwise produce an orphan span the
 # report buckets under its own name and no dashboard ever joins on.
 # Extend this set and the docstring table together when adding a kind.
-SPAN_KINDS = frozenset(STEP_PHASES) | frozenset({
+SPAN_KINDS = frozenset(STEP_PHASES + REQUEST_STAGES) | frozenset({
     "step",
     "detok",
     "frame_egress",
@@ -377,6 +424,100 @@ def span(kind: str, track: Optional[str] = None,
     return _Span(kind, track, trace_id, attrs or None)
 
 
+# -- engine phases: one call site, three sinks --------------------------------
+# (the module docstring's first design point: counter always, `dyn.<kind>`
+# TraceMe while a jax.profiler session is live, ring span under a Tracer)
+
+
+class _Phase:
+    """One open phase (the handle `with clock(kind) as ph` yields)."""
+
+    __slots__ = ("clock", "kind", "attrs", "ring", "t0", "child_s", "tm")
+
+    def __init__(self, clock: "PhaseClock", kind: str,
+                 attrs: Optional[dict]):
+        self.clock = clock
+        self.kind = kind
+        self.attrs = attrs
+        self.ring = True
+        self.child_s = 0.0
+        self.tm = None
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the phase's work is done (a burst's
+        `k`, a dispatch's row count)."""
+        if self.attrs is None:
+            self.attrs = attrs
+        else:
+            self.attrs.update(attrs)
+        if self.tm is not None:
+            self.tm.set_metadata(**attrs)
+
+    def off_ring(self) -> None:
+        """The phase ran but dispatched nothing: its time still counts,
+        the ring gets no span (a span there stands for one dispatch)."""
+        self.ring = False
+
+    def __enter__(self) -> "_Phase":
+        clock = self.clock
+        if clock.trace_me.is_enabled():      # a profiler session is live
+            self.tm = clock.trace_me("dyn." + self.kind,
+                                     **(self.attrs or {}))
+            self.tm.__enter__()
+        clock.open.append(self)
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.monotonic()
+        clock = self.clock
+        dur = t1 - self.t0
+        clock.open.pop()
+        if clock.open:
+            clock.open[-1].child_s += dur
+        m = clock.metrics
+        # `step` counts whole steps; every other kind its self time (the
+        # seconds in no phase nested inside it), so the kinds partition
+        # the steps' wall time
+        own = dur if self.kind == "step" else dur - self.child_s
+        ks, kn = clock.keys[self.kind]
+        m[ks] = m.get(ks, 0.0) + own
+        m[kn] = m.get(kn, 0) + 1
+        if self.tm is not None:
+            self.tm.__exit__(*exc)
+        tr = _TRACER
+        if tr is not None and self.ring:
+            tr.record(self.kind, self.t0, t1, self.attrs, None, clock.track)
+        return False
+
+
+class PhaseClock:
+    """An engine's phase timer: ``with clock("device_wait", what=...)``.
+    One per engine, used from the one thread at a time that holds the
+    scheduler (steps and between-step scheduler calls are serialized), so
+    the stack of open phases needs no lock."""
+
+    __slots__ = ("metrics", "track", "open", "keys", "trace_me")
+
+    def __init__(self, metrics: dict, track: Optional[str] = None):
+        self.metrics = metrics
+        self.track = track
+        self.open: List[_Phase] = []
+        self.keys = {k: (f"host_s.{k}", f"host_n.{k}") for k in SPAN_KINDS}
+        # imported here: obs is also loaded by processes that never
+        # touch JAX (frontend, lint), an engine always has
+        from jax.profiler import TraceAnnotation
+
+        self.trace_me = TraceAnnotation
+        for kind in ("step",) + STEP_PHASES:
+            ks, kn = self.keys[kind]
+            metrics.setdefault(ks, 0.0)
+            metrics.setdefault(kn, 0)
+
+    def __call__(self, kind: str, **attrs) -> _Phase:
+        return _Phase(self, kind, attrs or None)
+
+
 def flight_dump(reason: str) -> Optional[str]:
     """Module-level flight-recorder trigger (chaos seams, drain/abort,
     migration); no-op when tracing is disabled."""
@@ -450,6 +591,8 @@ def install_from_env() -> Optional[Tracer]:
 __all__ = [
     "DEFAULT_RING",
     "HOP_KINDS",
+    "PhaseClock",
+    "REQUEST_STAGES",
     "SPAN_KINDS",
     "STEP_PHASES",
     "Tracer",

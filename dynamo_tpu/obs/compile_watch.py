@@ -43,6 +43,7 @@ Mechanism (no second compile, no steady-state cost):
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import time
 from collections import deque
@@ -53,6 +54,12 @@ logger = logging.getLogger(__name__)
 # one place defines the compile FPM record's kind string; engine, mocker,
 # workers, FpmWindow and the report all join on it
 COMPILE_KIND = "compile"
+
+# a watched program is jitted as `dyn_<family>`, so a profiler trace's
+# `XLA Modules` read `jit_dyn_<family>(<fingerprint>)`.  Only the prefill
+# families' names may contain "prefill": trace reductions classify a
+# module as prefill by that word.
+PROGRAM_PREFIX = "dyn_"
 
 
 def _sds_of(x):
@@ -69,7 +76,9 @@ def xla_costs(fn, args) -> Optional[Dict[str, float]]:
     """FLOPs / bytes-accessed of the program ``fn(*args)`` compiled, via
     ``Lowered.cost_analysis()`` on aval stand-ins — re-traces (cached)
     but does NOT re-compile.  None when the backend has no cost model
-    for this program (the roofline is best-effort by design)."""
+    for this program (the roofline is best-effort by design).  Divided
+    by a dispatch gap these give the mfu/mbu gauges: an estimate from
+    host-clock gaps, not a device measurement."""
     import jax
 
     try:
@@ -157,6 +166,16 @@ class CompileWatch:
             yield
         finally:
             self._serving = prev
+
+    @staticmethod
+    def named(fn, family: str):
+        """`fn` under the name ``dyn_<family>``, to hand to ``jax.jit``
+        inside `wrap(...)`: jit names a program after its function, and
+        a ``functools.partial`` has no name (`jit__unknown`).  The name
+        is part of the compile-cache key."""
+        out = functools.partial(fn)
+        out.__name__ = out.__qualname__ = PROGRAM_PREFIX + family
+        return out
 
     def wrap(self, fn, family: str,
              tokens_of: Optional[Callable] = None):

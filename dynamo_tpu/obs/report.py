@@ -4,6 +4,11 @@ ROADMAP item 3 (overlapped scheduling) is scored on.
     python -m dynamo_tpu.obs.report trace.json [more-dumps.json ...]
         [--peak-tflops N] [--peak-hbm-gbps N]
 
+Every time here is the host's (`time.monotonic` around the scheduler's
+phases), and the roofline table is an estimate from host-clock gaps
+between dispatches: the device's own clock is in a `jax.profiler`
+capture, where the same phases appear as `dyn.<kind>` (obs/__init__.py).
+
 "Served is 0.40 of raw" is a symptom; this report turns a recorded
 timeline into the ranked culprits: what fraction of engine wall time is
 host scheduling vs device wait vs dispatch build vs idle, how often

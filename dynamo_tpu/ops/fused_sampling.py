@@ -86,6 +86,7 @@ def _tile_logits(h, w, i, tile, V):
     return lg, cols, fresh
 
 
+@jax.named_scope("dyn.lm_head_sample")
 def fused_greedy_tokens(h: jax.Array,   # [B, d] final-norm hidden
                         w: jax.Array,   # [d, vocab] unembedding matrix
                         *, tile: int = DEFAULT_TILE) -> jax.Array:
@@ -113,6 +114,7 @@ def fused_greedy_tokens(h: jax.Array,   # [B, d] final-norm hidden
     return bi
 
 
+@jax.named_scope("dyn.lm_head_sample")
 def fused_sample_tokens(
     h: jax.Array,            # [B, d] final-norm hidden
     w: jax.Array,            # [d, vocab] unembedding matrix

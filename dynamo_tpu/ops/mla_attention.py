@@ -43,6 +43,7 @@ def _gather_latent(cache: jax.Array, layer: int,
     return g.swapaxes(1, 2).reshape(mb * bs, R)
 
 
+@jax.named_scope("dyn.attention")
 def mla_prefill_attention(
     q_nope: jax.Array,    # [T, nh, dn]  (no rope)
     q_rope: jax.Array,    # [T, nh, dr]  (rope applied)
@@ -92,6 +93,7 @@ def mla_prefill_attention(
     return out.astype(q_nope.dtype)
 
 
+@jax.named_scope("dyn.attention")
 def mla_decode_attention(
     q_abs: jax.Array,     # [B, nh, R]  absorbed queries (q_nope @ w_uk^T)
     q_rope: jax.Array,    # [B, nh, dr]

@@ -75,6 +75,7 @@ from .paged_attention import (
 PACKED_IMPLS = ("auto", "xla", "pallas", "pallas_interpret")
 
 
+@jax.named_scope("dyn.kv_write")
 def write_packed_kv(
     k_cache: jax.Array,       # [L, nkv, nblocks, hd, bs]
     v_cache: jax.Array,
@@ -175,6 +176,7 @@ def _packed_pallas_tp(q, k_cache, v_cache, layer, block_tables, seg_ids,
     )
 
 
+@jax.named_scope("dyn.attention")
 def packed_prefill_attention(
     q: jax.Array,             # [T, nh, hd] packed-stream queries (rope'd)
     k_cache: jax.Array,

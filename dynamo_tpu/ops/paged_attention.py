@@ -98,6 +98,7 @@ def _store_kv(k_cache, v_cache, layer, k, v, blocks, offsets,
     return k_cache, v_cache
 
 
+@jax.named_scope("dyn.kv_write")
 def write_prompt_kv(
     k_cache: jax.Array,  # [L, nkv, nblocks, hd, bs]
     v_cache: jax.Array,
@@ -122,6 +123,7 @@ def write_prompt_kv(
                      k_scale, v_scale)
 
 
+@jax.named_scope("dyn.kv_write")
 def write_prompt_kv_batched(
     k_cache: jax.Array,       # [L, nkv, nblocks, hd, bs]
     v_cache: jax.Array,
@@ -152,6 +154,7 @@ def write_prompt_kv_batched(
                      k_scale, v_scale)
 
 
+@jax.named_scope("dyn.kv_write")
 def write_token_kv(
     k_cache: jax.Array,
     v_cache: jax.Array,
@@ -230,6 +233,7 @@ def _gqa_out(p: jax.Array, v: jax.Array,
     return o.reshape(*p.shape[:-2], nh, v.shape[-1])
 
 
+@jax.named_scope("dyn.attention")
 def paged_prefill_attention(
     q: jax.Array,        # [T, nh, hd] (rope applied)
     k: jax.Array,        # [T, nkv, hd] this chunk's keys
@@ -372,6 +376,7 @@ def _decode_pallas_tp(q, k_cache, v_cache, layer, block_tables, kv_lens,
     )
 
 
+@jax.named_scope("dyn.attention")
 def paged_attention_decode(
     q: jax.Array,
     k_cache: jax.Array,

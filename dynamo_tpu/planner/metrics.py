@@ -468,10 +468,14 @@ def export_engine_gauges(metrics, fw: FpmWindow, peak_tflops: float = 0.0,
         if peak_tflops > 0.0:
             metrics.set("dynamo_engine_mfu",
                         min(flops_rate / (peak_tflops * 1e12), 1.0),
+                        "FLOP/s utilization per phase: an estimate from "
+                        "host-clock gaps between dispatches",
                         phase=phase)
         if peak_hbm_gbps > 0.0:
             metrics.set("dynamo_engine_mbu",
                         min(bytes_rate / (peak_hbm_gbps * 1e9), 1.0),
+                        "HBM bandwidth utilization per phase: an estimate "
+                        "from host-clock gaps between dispatches",
                         phase=phase)
     for tier, occ in (occupancy or {}).items():
         for state in ("used", "free", "capacity"):

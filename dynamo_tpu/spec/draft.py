@@ -105,7 +105,9 @@ class DraftModelProposer:
             compile_watch = CompileWatch()
         self._watch = compile_watch
         self._jit_prefill = compile_watch.wrap(jax.jit(
-            partial(self._prefill_impl, self.family, self.cfg),
+            compile_watch.named(
+                partial(self._prefill_impl, self.family, self.cfg),
+                "draft_prefill"),
             donate_argnums=(1,)), "draft_prefill", lambda a: a[2].shape[-1])
         self._jit_propose = {}  # k -> jitted k-step greedy draft program
 
@@ -153,8 +155,9 @@ class DraftModelProposer:
         jit = self._jit_propose.get(k)
         if jit is None:
             jit = self._jit_propose[k] = self._watch.wrap(jax.jit(
-                partial(self._propose_impl, self.family, self.cfg,
-                        self.mesh, k),
+                self._watch.named(
+                    partial(self._propose_impl, self.family, self.cfg,
+                            self.mesh, k), "draft_propose"),
                 donate_argnums=(1,)), "draft_propose",
                 lambda a, _k=k: _k)
         burst, self.kv = jit(

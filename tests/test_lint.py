@@ -242,6 +242,31 @@ def test_dyn006_seam_and_span_literals():
     assert good == []
 
 
+def test_dyn006_phase_kind_literals():
+    """`<engine>._phase(kind)` (obs.PhaseClock) names a registered kind
+    like obs.span()/obs.end() do."""
+    bad = run("""
+        class JaxEngine:
+            def _sched_step(self):
+                with self._phase("schedd"):
+                    pass
+                with self._phase("emit_", what="burst"):
+                    pass
+        """, path="dynamo_tpu/engine/core.py")
+    assert rule_ids(bad) == ["DYN006"]
+    assert len(bad) == 2
+    good = run("""
+        class JaxEngine:
+            def _sched_step(self):
+                with self._phase("step"):
+                    with self._phase("emit", what="burst"):
+                        pass
+                    with self._phase("spec_dispatch") as ph:
+                        ph.off_ring()
+        """, path="dynamo_tpu/engine/core.py")
+    assert good == []
+
+
 def test_dyn006_rule_scenario_literals():
     bad = run("""
         plane = chaos.ChaosPlane(seed=1).rule("request_plane.framez",
@@ -392,6 +417,32 @@ def test_dyn011_device_wait_span_idiom_passes():
                 return arr
         """, path="dynamo_tpu/engine/core.py")
     assert good == []
+
+
+def test_dyn011_device_wait_phase_passes_other_phases_do_not():
+    good = run("""
+        import numpy as np
+
+        class JaxEngine:
+            def _process_oldest_burst(self):
+                e = self._inflight.popleft()
+                with self._phase("device_wait", k=e["k"],
+                                 what="burst_fetch"):
+                    arr = np.asarray(e["burst"])
+                return arr
+        """, path="dynamo_tpu/engine/core.py")
+    assert good == []
+    bad = run("""
+        import numpy as np
+
+        class JaxEngine:
+            def _process_oldest_burst(self):
+                e = self._inflight.popleft()
+                with self._phase("emit", what="burst"):
+                    arr = np.asarray(e["burst"])
+                return arr
+        """, path="dynamo_tpu/engine/core.py")
+    assert rule_ids(bad) == ["DYN011"]
 
 
 def test_dyn011_item_and_block_until_ready_flagged():

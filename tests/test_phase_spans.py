@@ -1,0 +1,466 @@
+"""One timeline (obs.PhaseClock, engine/core.py): the scheduler's phases
+as always-on counters, as `dyn.<kind>` TraceMes on the profiler's clock
+and as ring spans; the request-stage counters; the programs' names.
+CPU, tiny widths, no wall-clock thresholds: every comparison is between
+numbers of one run."""
+
+import asyncio
+import glob
+import types
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+pytestmark = pytest.mark.allow_slow_callbacks
+
+from dynamo_tpu import obs
+from dynamo_tpu.engine import EngineConfig, JaxEngine
+from dynamo_tpu.models.llama import LlamaConfig
+from dynamo_tpu.obs.compile_watch import (
+    PROGRAM_PREFIX,
+    CompileWatch,
+    WatchedProgram,
+)
+from dynamo_tpu.protocols import (
+    PreprocessedRequest,
+    SamplingOptions,
+    StopConditions,
+)
+
+TINY = LlamaConfig(name="tiny32", vocab_size=256, d_model=64, n_layers=2,
+                   n_heads=4, n_kv_heads=2, head_dim=16, ffn_dim=128,
+                   dtype=jnp.float32)
+
+PREFILL_FAMILIES = {"prefill", "prefill_batched", "prefill_packed",
+                    "prefill_ring", "draft_prefill"}
+
+
+def make_engine(**kw):
+    defaults = dict(model_config=TINY, block_size=4, num_blocks=256,
+                    max_blocks_per_seq=32, max_num_seqs=4,
+                    prefill_buckets=(8, 16, 32, 64), seed=7)
+    defaults.update(kw)
+    return JaxEngine(EngineConfig(**defaults))
+
+
+def request(i, n_prompt=32, max_tokens=8):
+    return PreprocessedRequest(
+        token_ids=[(i * 37 + j) % 200 + 3 for j in range(n_prompt)],
+        request_id=f"r{i}",
+        sampling=SamplingOptions(temperature=0.0),
+        stop=StopConditions(max_tokens=max_tokens, ignore_eos=True))
+
+
+async def serve(eng, i, **kw):
+    toks = []
+    async for out in eng.generate(request(i, **kw)):
+        toks.extend(out.token_ids)
+    return toks
+
+
+async def serve_mix(eng):
+    """Four requests at once (one prompt of several chunks), then one
+    alone: prefill, interleaved and decode-only steps."""
+    outs = await asyncio.gather(
+        serve(eng, 1), serve(eng, 2, n_prompt=100, max_tokens=12),
+        serve(eng, 3, n_prompt=9), serve(eng, 4, max_tokens=20))
+    return outs + [await serve(eng, 5, n_prompt=17)]
+
+
+async def settled(eng):
+    """Wait until the engine stands still: nothing queued, active or in
+    flight, no step running, and the loop has counted the last step (it
+    counts a step after the step's thread returned)."""
+    for _ in range(400):
+        idle = not (eng.waiting or eng._inflight
+                    or any(s is not None for s in eng._slots))
+        if idle and eng._step_lock.acquire(blocking=False):
+            eng._step_lock.release()
+            if eng.metrics["steps"] == eng.metrics["host_n.step"]:
+                return
+        await asyncio.sleep(0.01)
+    raise AssertionError("the scheduler loop never went idle")
+
+
+def profiler_options():
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    return opts
+
+
+def dyn_events(trace_dir):
+    """{line: [(name, start_ns, end_ns, stats)]} of the `dyn.*` events
+    on the host plane of the one trace under `trace_dir`."""
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats)) for e in line.events
+                   if e.name.startswith("dyn.")]
+            if evs:
+                out[line.name] = evs
+    return out
+
+
+# ------------------------- the phase clock alone ---------------------------
+
+
+def fake_clock(monkeypatch, ticks):
+    it = iter(ticks)
+    monkeypatch.setattr(obs, "time",
+                        types.SimpleNamespace(monotonic=lambda: next(it)))
+
+
+def test_phase_clock_counts_self_time_and_whole_steps(monkeypatch):
+    """A kind's seconds are those spent in no nested phase, `step`'s are
+    whole steps: the kinds partition the steps' wall time."""
+    m = {}
+    clock = obs.PhaseClock(m, "sched:t")
+    # step 0..10; sched 1..3; decode_dispatch 3..9 holding device_wait 4..8
+    fake_clock(monkeypatch, [0.0, 1.0, 3.0, 3.0, 4.0, 8.0, 9.0, 10.0])
+    with clock("step"):
+        with clock("sched"):
+            pass
+        with clock("decode_dispatch"):
+            with clock("device_wait", what="burst_fetch"):
+                pass
+    assert m["host_s.step"] == 10.0 and m["host_n.step"] == 1
+    assert m["host_s.sched"] == 2.0
+    assert m["host_s.decode_dispatch"] == 2.0
+    assert m["host_s.device_wait"] == 4.0
+    assert m["host_n.decode_dispatch"] == m["host_n.device_wait"] == 1
+    assert not clock.open
+    # every step phase has its keys from the start, at zero
+    assert m["host_s.emit"] == 0.0 and m["host_n.spec_dispatch"] == 0
+
+
+def test_phase_clock_ring_spans_attrs_and_off_ring():
+    m = {}
+    clock = obs.PhaseClock(m, "sched:t")
+    with obs.Tracer() as tr:
+        with clock("decode_dispatch") as ph:
+            ph.set(k=4, lanes=2)
+        with clock("decode_dispatch") as ph:
+            ph.off_ring()            # nothing was dispatched
+        with clock("device_wait", what="burst_fetch"):
+            pass
+    assert [(s[0], s[3], s[4]) for s in tr.spans] == [
+        ("decode_dispatch", "sched:t", {"k": 4, "lanes": 2}),
+        ("device_wait", "sched:t", {"what": "burst_fetch"})]
+    assert m["host_n.decode_dispatch"] == 2   # the time counts either way
+
+
+def test_phase_clock_closes_on_exception():
+    m = {}
+    clock = obs.PhaseClock(m)
+    with pytest.raises(RuntimeError):
+        with clock("step"):
+            with clock("sched"):
+                raise RuntimeError("boom")
+    assert not clock.open
+    assert m["host_n.step"] == 1 and m["host_n.sched"] == 1
+
+
+# ------------------------- the engine's phases -----------------------------
+
+
+def test_engine_phases_on_the_profilers_clock(tmp_path):
+    """Under a jax.profiler session the host plane holds `dyn.step` with
+    the phases nested inside it, carrying their attributes; no Tracer and
+    no environment variable is involved."""
+
+    async def main():
+        eng = make_engine()
+        await serve(eng, 0)                      # compile outside the trace
+        await settled(eng)       # a step open now would orphan its phases
+        jax.profiler.start_trace(str(tmp_path),
+                                 profiler_options=profiler_options())
+        try:
+            await serve_mix(eng)
+            await settled(eng)
+        finally:
+            jax.profiler.stop_trace()
+        await eng.close()
+
+    assert obs.tracer() is None
+    asyncio.run(main())
+    lines = dyn_events(str(tmp_path))
+    assert lines, "no dyn.* event on /host:CPU"
+    kinds = set()
+    for evs in lines.values():
+        steps = [(a, b) for n, a, b, _ in evs if n == "dyn.step"]
+        assert steps
+        first, last = min(a for a, _ in steps), max(b for _, b in steps)
+        for name, a, b, stats in evs:
+            kinds.add(name)
+            if name == "dyn.step":
+                assert "active" in stats and "waiting" in stats
+                continue
+            # the engine makes no between-step scheduler call here, so
+            # every phase lies inside one step of its own thread's line
+            # (a step cut by the session's edge keeps only its phases)
+            if first <= a and b <= last:
+                assert any(s0 <= a and b <= s1 for s0, s1 in steps), name
+            if name == "dyn.decode_dispatch" and stats:
+                assert {"k", "lanes", "cont"} <= set(stats)
+            if name == "dyn.device_wait":
+                assert stats["what"] in ("burst_fetch", "prefill_first")
+    assert {"dyn.step", "dyn.prefill_dispatch", "dyn.decode_dispatch",
+            "dyn.device_wait", "dyn.emit"} <= kinds
+    assert kinds & {"dyn.sched", "dyn.enqueue_ahead"}
+
+
+def test_no_session_no_tracer_records_nothing(monkeypatch):
+    """Profiler off and no Tracer: no TraceMe is built and no span
+    recorded (either would raise here); only the counters move."""
+
+    def refuse(*a, **kw):
+        raise AssertionError("a span was recorded with tracing off")
+
+    class NoTraceMe:
+        is_enabled = staticmethod(jax.profiler.TraceAnnotation.is_enabled)
+        __new__ = refuse
+
+    monkeypatch.setattr(obs.Tracer, "record", refuse)
+
+    async def main():
+        eng = make_engine()
+        assert eng._phase.trace_me is jax.profiler.TraceAnnotation
+        eng._phase.trace_me = NoTraceMe
+        outs = await serve_mix(eng)
+        await settled(eng)
+        m = dict(eng.metrics)
+        await eng.close()
+        return outs, m
+
+    assert obs.tracer() is None
+    outs, m = asyncio.run(main())
+    assert [len(o) for o in outs] == [8, 12, 8, 20, 8]
+    assert m["host_n.step"] == m["steps"] > 0
+    assert m["req_stage_n"] == 5
+
+
+def test_phase_seconds_partition_the_steps():
+    """Sum of the phases' seconds == `host_s.step` within 2 % (what is
+    left is the glue between phases) and never above it; `host_n.step`
+    counts the loop's steps."""
+
+    async def main():
+        eng = make_engine()
+        await serve_mix(eng)
+        await settled(eng)
+        m = dict(eng.metrics)
+        await eng.close()
+        return m
+
+    m = asyncio.run(main())
+    assert m["host_n.step"] == m["steps"]
+    whole = m["host_s.step"]
+    parts = sum(v for k, v in m.items()
+                if k.startswith("host_s.") and k != "host_s.step")
+    assert whole > 0
+    assert 0.98 * whole <= parts <= whole * (1 + 1e-9)
+    for kind in ("prefill_dispatch", "decode_dispatch", "device_wait",
+                 "emit"):
+        assert m[f"host_n.{kind}"] > 0 and m[f"host_s.{kind}"] > 0
+
+
+# ------------------------- request stages ----------------------------------
+
+
+def watch_stages(eng):
+    """Record, for every first frame, the slot and what it added to the
+    counters (the adds run one after another on the event loop)."""
+    seen = []
+    inner = eng._emit_first
+    keys = ("req_stage_s.queue", "req_stage_s.prefill", "req_stage_s.emit",
+            "req_stage_n")
+
+    def emit_first(slot, out):
+        before = [eng.metrics[k] for k in keys]
+        inner(slot, out)
+        seen.append((slot, [eng.metrics[k] - b
+                            for k, b in zip(keys, before)]))
+
+    eng._emit_first = emit_first
+    return seen
+
+
+def check_stages(eng, seen, n_first_tokens):
+    m = eng.metrics
+    assert m["req_stage_n"] == len(seen) == n_first_tokens
+    rids = [slot.request.request_id for slot, _ in seen]
+    assert len(set(rids)) == len(rids)          # once a request
+    for slot, (queue, prefill, emit, n) in seen:
+        assert n == 1
+        assert min(queue, prefill, emit) >= 0.0
+        assert slot.enqueued_t <= slot.dispatched_t <= slot.first_token_t
+        assert queue + prefill == pytest.approx(
+            slot.first_token_t - slot.enqueued_t, abs=1e-9)
+    for i, key in enumerate(("queue", "prefill", "emit")):
+        assert m[f"req_stage_s.{key}"] == pytest.approx(
+            sum(adds[i] for _, adds in seen), abs=1e-9)
+
+
+def test_request_stages_sum_to_the_engines_ttft():
+    async def main():
+        eng = make_engine(prefill_chunk_tokens=48)
+        seen = watch_stages(eng)
+        await serve_mix(eng)
+        check_stages(eng, seen, 5)
+        multi = next(s for s, _ in seen if s.request.request_id == "r2")
+        assert multi.prefill_chunks > 1          # the 100-token prompt
+        await eng.close()
+
+    asyncio.run(main())
+
+
+def test_request_stages_count_a_preempted_request_once():
+    async def main():
+        # 3 x (8 prompt blocks + 6 of output) do not fit 30 blocks
+        eng = make_engine(num_blocks=30)
+        seen = watch_stages(eng)
+        outs = await asyncio.gather(
+            *[serve(eng, i, max_tokens=24) for i in range(3)])
+        assert [len(o) for o in outs] == [24, 24, 24]
+        assert eng.metrics["preemptions"] >= 1
+        check_stages(eng, seen, 3)
+        await eng.close()
+
+    asyncio.run(main())
+
+
+def test_request_stages_leave_out_a_request_cancelled_in_the_queue():
+    async def main():
+        eng = make_engine(max_num_seqs=1)
+        seen = watch_stages(eng)
+        first = asyncio.create_task(serve(eng, 0, max_tokens=48))
+        while not seen:                          # r0 holds the one slot
+            await asyncio.sleep(0.005)
+        waiting = asyncio.create_task(serve(eng, 1))
+        while not eng.waiting:
+            await asyncio.sleep(0.005)
+        waiting.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await waiting
+        assert len(await first) == 48
+        await serve(eng, 2)
+        check_stages(eng, seen, 2)               # r0 and r2, not r1
+        assert eng.metrics["requests"] == 3
+        await eng.close()
+
+    asyncio.run(main())
+
+
+def test_request_stage_spans_share_the_request_id(tmp_path):
+    async def main():
+        eng = make_engine()
+        # a compile under a Tracer leaves a flight dump beside `out_path`
+        with obs.Tracer(out_path=str(tmp_path / "trace.json")) as tr:
+            await asyncio.gather(serve(eng, 1), serve(eng, 2))
+            await settled(eng)
+        await eng.close()
+        return list(tr.spans)
+
+    spans = asyncio.run(main())
+    for rid in ("r1", "r2"):
+        mine = [s for s in spans if s[3] == f"req:{rid}"]
+        assert [s[0] for s in mine] == list(obs.REQUEST_STAGES)
+        assert all(s[4] == {"request_id": rid} for s in mine)
+        # one after another: queue -> prefill -> emit
+        assert mine[0][2] == mine[1][1] and mine[1][2] == mine[2][1]
+    kinds = {s[0] for s in spans}
+    assert {"step", "prefill_dispatch", "decode_dispatch", "device_wait",
+            "emit"} <= kinds
+
+
+# ------------------------- names --------------------------------------------
+
+
+def watched_programs(eng):
+    for value in vars(eng).values():
+        for wp in (value.values() if isinstance(value, dict) else [value]):
+            if isinstance(wp, WatchedProgram):
+                yield wp
+
+
+def test_named_gives_the_jit_module_its_family_name():
+    fn = CompileWatch.named(partial(lambda a, x: x * a, 2.0), "decode_multi")
+    assert fn.__name__ == "dyn_decode_multi"
+    text = jax.jit(fn).lower(jnp.ones(3)).as_text()
+    assert "module @jit_dyn_decode_multi" in text
+    assert float(jax.jit(fn)(jnp.ones(3))[0]) == 2.0
+
+
+@pytest.mark.parametrize("extra", [{}, {"spec_decode": "ngram"}],
+                         ids=["plain", "spec"])
+def test_every_watched_program_is_named_after_its_family(extra):
+    async def main():
+        eng = make_engine(**extra)
+        eng._topk_jit()
+        eng._topk_wide_jit()
+        await serve(eng, 0)
+        programs = list(watched_programs(eng))
+        events = [dict(e) for e in eng.compile_watch.events]
+        await eng.close()
+        return programs, events
+
+    programs, events = asyncio.run(main())
+    families = {wp.family for wp in programs}
+    assert {"decode", "decode_multi", "prefill", "prefill_batched",
+            "prefill_packed", "inject", "gather", "decode_topk",
+            "decode_topk_wide"} <= families
+    for wp in programs:
+        assert wp.fn.__name__ == PROGRAM_PREFIX + wp.family
+        # trace reductions call a module prefill by this word alone
+        assert ("prefill" in wp.family) == (wp.family in PREFILL_FAMILIES)
+    assert events and all(e["family"] in families for e in events)
+
+
+@pytest.fixture(scope="module")
+def plain_outputs():
+    async def main():
+        eng = make_engine()
+        outs = await serve_mix(eng)
+        await eng.close()
+        return outs
+
+    return asyncio.run(main())
+
+
+@pytest.mark.parametrize("sink", ["tracer", "profiler", "both"])
+def test_jax_engine_bit_identical_with_spans_on(sink, plain_outputs,
+                                                tmp_path):
+    """Greedy outputs do not depend on who listens to the phases (the
+    JAX-engine twin of test_mock_engine_bit_identical_with_tracing_on)."""
+
+    async def main():
+        eng = make_engine()
+        tr = (obs.Tracer(out_path=str(tmp_path / "trace.json")).install()
+              if sink != "profiler" else None)
+        if sink != "tracer":
+            jax.profiler.start_trace(str(tmp_path),
+                                     profiler_options=profiler_options())
+        try:
+            outs = await serve_mix(eng)
+        finally:
+            if sink != "tracer":
+                jax.profiler.stop_trace()
+            if tr is not None:
+                tr.uninstall()
+        await eng.close()
+        return outs, tr
+
+    outs, tr = asyncio.run(main())
+    assert outs == plain_outputs
+    if tr is not None:
+        assert {"step", "decode_dispatch", "req_queue"} <= {
+            s[0] for s in tr.spans}
