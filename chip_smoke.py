@@ -368,7 +368,8 @@ def phase_serve(args, env: dict, children: list, deadline: float) -> dict:
              ("attn_impl", "packed_attn_impl", "sampling_epilogue",
               "kv_cache_dtype", "overlap_scheduling", "prefill_packed")}
     say(f"engine resolved impls: {json.dumps(impls)} "
-        "(attn 'auto' = XLA gather, packed 'auto' = XLA masked flash)")
+        "(attn: 'auto' as resolved for this worker, the Pallas kernel on "
+        "a TPU with 128-token blocks; packed 'auto' = XLA masked flash)")
     warm = wsrc["compile_watch"]
     say(f"after warm-up: compiles={json.dumps(warm['counts'])} "
         f"seconds={json.dumps(warm['seconds'])} "

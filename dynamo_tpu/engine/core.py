@@ -52,6 +52,7 @@ from ..tokens import TokenBlockSequence, request_salt
 from .block_allocator import BlockAllocator
 from .config import EngineConfig
 from ..ops.fused_sampling import fused_greedy_tokens, fused_sample_tokens
+from ..ops.paged_attention import PALLAS_IMPLS, resolve_decode_impl
 from .sampler import greedy_tokens, sample_tokens
 
 logger = logging.getLogger(__name__)
@@ -284,6 +285,17 @@ class JaxEngine:
                 "model family %r has no quantized KV path; "
                 "kv_cache_dtype falls back to bf16", self.model_cfg.name)
             self.kv_dtype = "bf16"
+        # "auto" decode attention becomes what it means for this cache on
+        # this mesh's platform (ops/paged_attention.resolve_decode_impl):
+        # the step programs, the MDC and the decode_attn_* counters all
+        # name the impl that runs.  MLA's config says "jnp" already.
+        if self.model_cfg.attn_impl == "auto":
+            self.model_cfg = dataclasses.replace(
+                self.model_cfg, attn_impl=resolve_decode_impl(
+                    "auto", self.mesh.devices.flat[0].platform,
+                    config.block_size, self.model_cfg.head_dim,
+                    jnp.int8 if self.kv_dtype == "int8"
+                    else self.model_cfg.dtype))
         if config.kv_hbm_gb > 0:
             from ..quant.kv import blocks_for_hbm_budget
 
@@ -653,6 +665,10 @@ class JaxEngine:
             # _Slot.dispatched_t and _emit_first)
             "req_stage_s.queue": 0.0, "req_stage_s.prefill": 0.0,
             "req_stage_s.emit": 0.0, "req_stage_n": 0,
+            # decode attention, in cache blocks per layer summed over
+            # decode steps: what the active lanes' contexts hold, and
+            # what the impl that runs reads for them (_count_decode_attn)
+            "decode_attn_live_blocks": 0, "decode_attn_read_blocks": 0,
         }
         # the scheduler thread's phases: counters host_s.<kind> /
         # host_n.<kind> always, `dyn.<kind>` on the profiler's clock
@@ -3478,6 +3494,7 @@ class JaxEngine:
             for s in active:
                 lidx[s.index] = s.lora_idx
             a["lidx"] = lidx
+        self._count_decode_attn(ctx_lens[valid], k)
         cont_burst = self._is_continuation(a, active, k)
         if cont_burst:
             # steady state: nothing changed but the clock — advance the
@@ -3516,6 +3533,24 @@ class JaxEngine:
         except AttributeError:  # non-jax stand-ins in tests
             pass
         return burst, cont_burst
+
+    def _count_decode_attn(self, ctx, k: int):
+        """How far decode attention's reads follow the live context, for
+        a burst of `k` steps over active lanes holding `ctx` tokens:
+        live = k x sum ceil((ctx + 1) / block_size); read = the blocks
+        the impl moves by construction — every lane's whole table width
+        per step for the gathering paths (jnp, MLA), each step's live
+        blocks for the Pallas kernel."""
+        bs = self.config.block_size
+        self.metrics["decode_attn_live_blocks"] += \
+            k * int(np.sum(-(-(ctx + 1) // bs)))
+        if self.model_cfg.attn_impl in PALLAS_IMPLS:
+            steps = ctx[:, None] + 1 + np.arange(k)[None, :]
+            read = int(np.sum(-(-steps // bs)))
+        else:
+            read = k * self.config.max_num_seqs \
+                * self.config.max_blocks_per_seq
+        self.metrics["decode_attn_read_blocks"] += read
 
     GUIDED_TOPM = 32
     GUIDED_TOPM_WIDE = 256

@@ -127,9 +127,11 @@ class JaxEngineWorker:
                 "prefill_chunk_tokens": self.config.chunk_budget,
                 "prefill_packed": self.config.prefill_packed,
                 # EFFECTIVE attention impls (engine-level overrides
-                # applied to the model config): a fleet debugger sees
-                # which workers run the Pallas kernels vs the XLA
-                # reference paths without reading worker flags
+                # applied to the model config, and the decode impl's
+                # "auto" RESOLVED for this worker's platform and cache:
+                # ops/paged_attention.resolve_decode_impl): a fleet
+                # debugger sees which workers run the Pallas kernels vs
+                # the XLA reference paths without reading worker flags
                 "attn_impl": (self.engine.model_cfg.attn_impl
                               if self.engine is not None
                               else (self.config.attn_impl or "auto")),

@@ -22,8 +22,10 @@ import jax.numpy as jnp
 
 from ..ops.packed_prefill import packed_prefill_attention, write_packed_kv
 from ..ops.paged_attention import (
+    PALLAS_IMPLS,
     paged_attention_decode,
     paged_prefill_attention,
+    resolve_decode_impl,
     write_prompt_kv,
     write_prompt_kv_batched,
     write_token_kv,
@@ -775,16 +777,28 @@ def _decode_trunk(params, cfg, kv_cache, token_ids, positions,
     Returns (pre-final-norm hidden [B, d], updated kv_cache)."""
     x = params["embedding"][token_ids].astype(cfg.dtype)  # [B, d]
     pos1 = positions[:, None]  # [B, 1] for rope
+    # what "auto" means for this cache here (TPU + lane-aligned blocks:
+    # the Pallas kernel).  The kernel reads the pool in its resident
+    # layout, so the token's K/V is written in that layout too, and an
+    # idle lane (valid False) claims no context: it reads nothing.
+    pool = kv_cache[0]
+    impl = resolve_decode_impl(cfg.attn_impl, jax.default_backend(),
+                               pool.shape[4], pool.shape[3], pool.dtype)
+    write_token = partial(write_token_kv, resident=impl in PALLAS_IMPLS,
+                          valid=valid)
+    kv_lens = ctx_lens + 1
+    if valid is not None:
+        kv_lens = jnp.where(valid, kv_lens, 0)
     for li, layer in enumerate(params["layers"]):
         lctx = _lora_ctx(lora_bank, adapter_idx, li)
         h = rms_norm(x, layer["attn_norm"]["norm"], cfg.rms_eps)
         q, k, v = _qkv(layer, cfg, h[:, None, :], pos1, lora=lctx)
-        kv_cache = _write_kv(write_token_kv, kv_cache, li, k[:, 0],
+        kv_cache = _write_kv(write_token, kv_cache, li, k[:, 0],
                              v[:, 0], block_tables, ctx_lens)
         k_cache, v_cache, ks, vs = _unpack_kv(kv_cache)
         attn = paged_attention_decode(
-            q[:, 0], k_cache, v_cache, li, block_tables, ctx_lens + 1,
-            impl=cfg.attn_impl, mesh=mesh, k_scale=ks, v_scale=vs,
+            q[:, 0], k_cache, v_cache, li, block_tables, kv_lens,
+            impl=impl, mesh=mesh, k_scale=ks, v_scale=vs,
         )  # [B, nh, hd]
         x = x + _attn_out(layer, attn.reshape(x.shape[0], cfg.q_dim),
                           lora=lctx)

@@ -24,12 +24,16 @@ These are the jnp reference implementations — numerically exact, fully
 fused-able by XLA.  ops/pallas_paged_attention.py is the hand-tiled
 Pallas decode kernel; the two are interchangeable and cross-checked in
 tests/test_paged_attention.py.  `paged_attention_decode` dispatches
-between them: "auto" selects the jnp/XLA gather path (measured FASTER
-than the Pallas kernel on this platform — see the impl="auto" rationale
-in paged_attention_decode; the kernel stays available via
-impl="pallas"), and "jnp_bf16" keeps matmul operands in the cache dtype
-with fp32 accumulation (the serving fast path; "jnp" upcasts to fp32
-for exact test numerics).
+between them: "auto" is decided by `resolve_decode_impl` from what the
+code can observe — the Pallas kernel on a TPU backend with lane-aligned
+blocks, the jnp path elsewhere (CPU, block_size 16).  The jnp path
+gathers the whole table width for every lane and ("jnp") upcasts it to
+fp32; the kernel DMAs the live blocks in the cache's dtype.  One
+layer-call on a v5e at Mistral-7B widths (device time, my chip run,
+PR 28): 16 lanes x 20 blocks with 26 blocks live, jnp 1079 us, jnp_bf16
+915 us, kernel 24-31 us; 6 lanes x 50 blocks with 204 live, 721 us
+against 149 us.  "jnp_bf16" keeps matmul operands in the cache dtype
+with fp32 accumulation; "jnp" upcasts to fp32 for exact test numerics.
 
 Int8 KV quantization (quant/kv.py, engine `kv_cache_dtype="int8"`):
 every write function takes optional `k_scale`/`v_scale` sibling arrays
@@ -38,12 +42,12 @@ quantize per (token, head) on the way into the cache and the scale
 scatters with the same index math, and the function returns a 4-tuple.
 EVERY read impl supports int8:
 
-  * "jnp" / "jnp_bf16" / "auto" — the int8 block gather is what
+  * "jnp" / "jnp_bf16" — the int8 block gather is what
     streams from HBM; dequantization happens on the gathered context
     (`_gather_ctx`), upcast to fp32 ("jnp") or bf16 ("jnp_bf16", keeping
     the MXU operands 16-bit with fp32 accumulation).
-  * "pallas" / "pallas_interpret" — in-kernel dequant: the kernel DMAs
-    int8 blocks plus their [nkv, bs] fp32 scale rows into VMEM and
+  * "pallas" / "pallas_interpret" ("auto" on a TPU) — in-kernel
+    dequant: the kernel DMAs int8 blocks plus their [nkv, bs] fp32 scale rows into VMEM and
     fuses the scale multiply into the chunk consume (query-dtype MXU
     operands, fp32 softmax/accumulate) — int8's halved HBM traffic
     happens inside the fast path (pallas_paged_attention.py docstring
@@ -154,6 +158,44 @@ def write_prompt_kv_batched(
                      k_scale, v_scale)
 
 
+# dynlint: disable=DYN001 op-level jit: reached only inside the engine's watched decode programs; `layer` is traced, so one trace serves every layer of a program
+@jax.jit
+def _store_columns(caches, layer, xs, blocks, offsets, valid):
+    """Write each valid lane's column — xs[j][b] ([nkv, hd], or [nkv]
+    for a scale plane) into column offsets[b] of block blocks[b] of
+    caches[j] — one lane at a time, as a read-modify-write of that
+    block's whole [nkv, hd, bs] planes: the pools keep the layout they
+    are resident in.  A column alone (the flat scatter of `_store_kv`,
+    or a `dynamic_update_slice` of one column) makes XLA's TPU compiler
+    lay the pool out with the updated window (hd, nkv) minor-most,
+    {3,1,4,2,0}; next to a reader that needs the resident {4,3,2,1,0}
+    (the Pallas decode kernel DMAs [hd, bs] planes) that is a copy of
+    the whole pool per layer per step (compiled for a described v5e,
+    PR 28).  In the resident layout a token's column is spread over
+    every tile of its planes, so whole planes are what any writer has
+    to move: 4 us a lane and tensor on a v5e (my chip run, PR 28), so
+    the loop runs over the valid lanes only."""
+    bs = caches[0].shape[-1]
+    zero = jnp.int32(0)
+    lanes = jnp.argsort(~valid)   # stable: the valid lanes first
+    col = jnp.arange(bs, dtype=jnp.int32)
+
+    def body(i, cs):
+        b = lanes[i]
+        out = []
+        for c, x in zip(cs, xs):
+            at = (layer, zero, blocks[b]) + (zero,) * (c.ndim - 3)
+            plane = jax.lax.dynamic_slice(
+                c, at, (1, c.shape[1], 1) + c.shape[3:])
+            new = x[b].astype(c.dtype)[None, :, None, ..., None]
+            out.append(jax.lax.dynamic_update_slice(
+                c, jnp.where(col == offsets[b], new, plane), at))
+        return tuple(out)
+
+    return jax.lax.fori_loop(0, jnp.sum(valid, dtype=jnp.int32), body,
+                             tuple(caches))
+
+
 @jax.named_scope("dyn.kv_write")
 def write_token_kv(
     k_cache: jax.Array,
@@ -165,13 +207,31 @@ def write_token_kv(
     ctx_lens: jax.Array,      # [B] position to write (== current length)
     k_scale: jax.Array = None,  # [L, nkv, nblocks, bs] fp32 (int8 cache)
     v_scale: jax.Array = None,
+    resident: bool = False,
+    valid: jax.Array = None,    # [B] bool, read by the resident write
 ) -> Tuple[jax.Array, ...]:
+    """`resident=True` writes lane by lane in the pool's resident layout
+    (`_store_columns`), and only the lanes `valid` marks (None: all):
+    what the decode step uses beside the Pallas kernel.  False keeps the
+    one flat scatter the XLA gather path is laid out for, idle lanes
+    landing in the garbage block.  A valid lane's cells get the same
+    values either way."""
     bs = k_cache.shape[4]
     B = k.shape[0]
     blocks = block_tables[jnp.arange(B), ctx_lens // bs]  # [B]
     offsets = ctx_lens % bs
-    return _store_kv(k_cache, v_cache, layer, k, v, blocks, offsets,
-                     k_scale, v_scale)
+    if not resident:
+        return _store_kv(k_cache, v_cache, layer, k, v, blocks, offsets,
+                         k_scale, v_scale)
+    caches, xs = (k_cache, v_cache), (k, v)
+    if k_scale is not None:
+        k, ks = quantize_tokens(k)
+        v, vs = quantize_tokens(v)
+        caches, xs = caches + (k_scale, v_scale), (k, v, ks, vs)
+    if valid is None:
+        valid = jnp.ones((B,), bool)
+    return _store_columns(caches, jnp.int32(layer), xs, blocks, offsets,
+                          valid)
 
 
 # ---------------------------------------------------------------------------
@@ -376,6 +436,36 @@ def _decode_pallas_tp(q, k_cache, v_cache, layer, block_tables, kv_lens,
     )
 
 
+PALLAS_IMPLS = ("pallas", "pallas_interpret")
+
+
+def resolve_decode_impl(impl: str, platform: str, block_size: int,
+                        head_dim: int, cache_dtype) -> str:
+    """What `impl` means for this cache on this platform: the one place
+    "auto" is decided, from what the code can observe.  An explicit impl
+    is returned as given.
+
+    "auto" is the Pallas kernel where it can run as written — a TPU
+    backend, block_size a multiple of 128 (the lane dimension of the
+    [hd, bs] block planes it DMAs), head_dim a whole number of sublane
+    tiles for the cache dtype (16 rows bf16, 32 int8), a bf16 or int8
+    cache — and the jnp path everywhere else (CPU, block_size 16, fp32
+    caches).  The kernel moves the live context's bytes in the cache's
+    dtype straight from the pool; the jnp path gathers lanes x table
+    width and upcasts to fp32.  On `mistral-7b.chat` (16 lanes x 20
+    blocks, ~25 blocks live) that is a decode step of 31.7 ms with jnp
+    (ledger, PR 27) against 10.55 ms with the kernel (my chip run,
+    PR 28; PERF.md section 6)."""
+    if impl != "auto":
+        return impl
+    dt = jnp.dtype(cache_dtype)
+    if (platform == "tpu" and block_size % 128 == 0
+            and dt in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.int8))
+            and head_dim % (32 // dt.itemsize) == 0):
+        return "pallas"
+    return "jnp"
+
+
 @jax.named_scope("dyn.attention")
 def paged_attention_decode(
     q: jax.Array,
@@ -391,16 +481,19 @@ def paged_attention_decode(
 ) -> jax.Array:
     """Single-token batched paged attention (the decode hot loop).
 
-    impl: "auto" (the jnp/XLA gather path — measured faster than the
-    Pallas kernel on this platform, see below), "pallas",
+    impl: "auto" (`resolve_decode_impl` on the default backend: the
+    Pallas kernel on a TPU, the jnp path elsewhere), "pallas",
     "pallas_interpret" (kernel under the interpreter — CPU testing),
     "jnp" (fp32-upcast operands: exact reference numerics for tests), or
-    "jnp_bf16" (operands stay in the cache dtype, fp32 accumulation —
-    the bandwidth-friendly serving variant of the jnp path).
+    "jnp_bf16" (operands stay in the cache dtype, fp32 accumulation).
+
+    kv_lens: valid positions per lane including the token just written;
+    0 marks a lane with nothing to attend (the kernel reads nothing for
+    it and returns 0; the jnp path returns a finite, unused average).
 
     mesh: required for the Pallas path when the kv cache is tensor-parallel
     (kv_heads sharded over a "tp" axis) — the kernel then runs under
-    shard_map per shard.  Without a mesh, "auto" under tp>1 would hit
+    shard_map per shard.  Without a mesh, the kernel under tp>1 would hit
     GSPMD's unpartitionable-custom-call all-gather, so callers serving
     multi-chip must pass their mesh (the engine does).
 
@@ -410,23 +503,10 @@ def paged_attention_decode(
     fuses the multiply in VMEM (module docstring's support matrix).
     """
     tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
-    if impl == "auto":
-        # "auto" = the XLA gather path, bf16 AND int8.  Measured on v5e
-        # (round 5, benchmarks/bench_decode_phases.py, llama-3b B=8
-        # ctx=2048): the full decode step runs 14.2 ms with this path vs
-        # 17.1 ms with the Pallas kernel — the kernel's explicit DMAs
-        # cap at ~206 GB/s on this platform (per-engine ceiling,
-        # measured in benchmarks/bench_dma_layouts.py) while XLA's fused
-        # gather sustains ~340 GB/s.  The kernel stays available via
-        # impl="pallas" for platforms where Pallas DMA streams at full
-        # bandwidth; the int8 in-kernel dequant path is new this round
-        # and unmeasured on TPU (benchmarks/bench_kv_quant.py carries
-        # the int8-Pallas row), so "auto" keeps the measured choice
-        # until a TPU bench round says otherwise.  Under tp the jnp ops
-        # partition natively (kv_heads axis), so no shard_map is needed
-        # either way.
-        impl = "jnp"
-    if impl in ("pallas", "pallas_interpret"):
+    impl = resolve_decode_impl(impl, jax.default_backend(),
+                               k_cache.shape[4], k_cache.shape[3],
+                               k_cache.dtype)
+    if impl in PALLAS_IMPLS:
         interpret = impl == "pallas_interpret"
         if tp > 1:
             return _decode_pallas_tp(

@@ -18,24 +18,30 @@ lane dimension, so with block_size a multiple of 128:
   * scores q[g,hd] @ k[hd,S] and the p@v contraction are MXU-shaped with
     no in-kernel reshapes or lane-splits.
 
-Structure (what round-4's 0.55-of-roofline bench paid for getting wrong,
-each point measured in benchmarks/bench_decode_phases.py):
-  * grid = (batch,), sequential; block tables + kv lengths ride scalar
+Structure:
+  * grid = (batch,), sequential; block tables, kv lengths and the chunk
+    chain's planes (each row's slot phase and successor) ride scalar
     prefetch (SMEM).
-  * KV is consumed in chunks of `bpc` physical blocks DMA'd into
-    [nkv, hd, S=bpc*bs] VMEM buffers, double-buffered, and the prefetch
-    chain CROSSES grid steps (the last chunk of sequence b prefetches
-    chunk 0 of sequence b+1, bookkept in SMEM scratch that persists
-    across grid iterations) — the DMA engines never drain between
-    sequences.  The prior per-(head, block) copies were latency-bound at
-    ~190 GB/s; whole-chunk strided descriptors with a cross-sequence
-    chain stream continuously.
+  * the pools go in WHOLE, [L, nkv, num_blocks, hd, bs] in HBM in their
+    resident layout, and the DMA descriptor indexes layer and block: a
+    slice taken outside the kernel is materialized by XLA for a custom
+    call (one layer's pool copied per layer per step).
+  * KV is consumed in chunks of up to `bpc` physical blocks DMA'd into
+    [nkv, hd, S=bpc*bs] VMEM buffers, double-buffered; only the blocks
+    that hold live positions are copied, and a lane with kv_len 0 has
+    no chunk at all.  The prefetch chain CROSSES grid steps (the last
+    chunk of a sequence prefetches chunk 0 of the next sequence that
+    has one) — the DMA engines never drain between sequences.
   * compute per chunk is TWO batched bf16 dot_generals with fp32
     accumulation ([nkv, g, hd] @ [nkv, hd, S] and the p@v contraction)
-    plus one online-softmax update on [nkv, g, S].  The prior kernel
-    upcast K/V to fp32 and issued 2 matmuls PER BLOCK — fp32 MXU
-    throughput plus 64 fill-bound passes made compute as slow as the
-    entire bandwidth budget.
+    plus one online-softmax update on [nkv, g, S].
+
+Measured on a v5e at Mistral-7B widths, one layer-call, device time (my
+chip run, PR 28): 26 live blocks (13.6 MB) over 6 of 16 lanes in
+24-31 us (435 GB/s and up); 204 live blocks (107 MB) over 6 lanes in
+149 us (718 GB/s of the chip's 819).  The same kernel as it stood
+before PR 28 (layer sliced outside, every chunk whole, idle lanes
+reading the garbage block): 604 us and 675 us.
 
 Int8 KV caches (quant/kv.py) are consumed natively: alongside each
 [nkv, hd, bs] int8 block the kernel DMAs the block's [nkv, bs] fp32
@@ -72,48 +78,70 @@ NEG_INF = -1e30
 
 def make_chunk_dma(tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem, *,
                    bpc, bs, ks_hbm=None, vs_hbm=None, ks_buf=None,
-                   vs_buf=None):
+                   vs_buf=None, layer=None, live_blocks=None):
     """The chunk DMA contract shared by the decode and packed-prefill
-    kernels: (start, wait) closures moving `bpc` physical blocks into a
-    double-buffered VMEM chunk — one strided descriptor per block per
-    tensor ([nkv, hd, bs], all heads, landing at the block's offset in
-    the chunk buffer), and for an int8 cache the block's [nkv, bs] fp32
-    scale rows on two extra semaphore lanes (`sem` is [slots, 2] bf16 /
-    [slots, 4] int8).  Both closures take (row, c, slot) where `row`
-    indexes tables_ref's first axis (the sequence for decode, the
+    kernels: (start, wait) closures moving up to `bpc` physical blocks
+    into a double-buffered VMEM chunk — one strided descriptor per block
+    per tensor ([nkv, hd, bs], all heads, landing at the block's offset
+    in the chunk buffer), and for an int8 cache the block's [nkv, bs]
+    fp32 scale rows on two extra semaphore lanes (`sem` is [slots, 2]
+    bf16 / [slots, 4] int8).  Both closures take (row, c, slot) where
+    `row` indexes tables_ref's first axis (the sequence for decode, the
     segment for packed prefill).  One definition site keeps the two
     kernels' DMA contracts — descriptor shapes, semaphore pairing,
-    scale lanes — from drifting."""
+    scale lanes — from drifting.
+
+    `layer` (a scalar, static or read from SMEM): the HBM refs are the
+    WHOLE pools [L, nkv, nb, hd, bs] (scales [L, nkv, nb, bs]) and the
+    descriptor indexes the layer, so the caller never slices the pool
+    outside the kernel (XLA materializes such a slice for a custom
+    call: a copy of one layer's pool per call).  None: the refs are one
+    layer's [nkv, nb, hd, bs].
+
+    `live_blocks(row, c)` -> traced count of chunk c's blocks that hold
+    live positions: block i is copied (and waited on) only when
+    i < live, so a chunk moves the bytes of its live blocks and the
+    rest of the buffer keeps what it held (the consumer masks those
+    positions and must keep them finite).  Block 0 of a chunk that is
+    fetched at all is always live.  None: all `bpc` blocks."""
     quantized = ks_hbm is not None
 
-    def _copies(row, c, slot):
+    def src(hbm, pid):
+        return hbm.at[:, pid] if layer is None else hbm.at[layer, :, pid]
+
+    def _each(row, c, slot, op):
+        live = None if live_blocks is None else live_blocks(row, c)
         for i in range(bpc):
-            pid = tables_ref[row, c * bpc + i]
-            yield pltpu.make_async_copy(
-                k_hbm.at[:, pid],
-                k_buf.at[slot, :, :, pl.ds(i * bs, bs)],
-                sem.at[slot, 0])
-            yield pltpu.make_async_copy(
-                v_hbm.at[:, pid],
-                v_buf.at[slot, :, :, pl.ds(i * bs, bs)],
-                sem.at[slot, 1])
-            if quantized:
-                yield pltpu.make_async_copy(
-                    ks_hbm.at[:, pid],
-                    ks_buf.at[slot, :, pl.ds(i * bs, bs)],
-                    sem.at[slot, 2])
-                yield pltpu.make_async_copy(
-                    vs_hbm.at[:, pid],
-                    vs_buf.at[slot, :, pl.ds(i * bs, bs)],
-                    sem.at[slot, 3])
+            def block(i=i):
+                pid = tables_ref[row, c * bpc + i]
+                op(pltpu.make_async_copy(
+                    src(k_hbm, pid),
+                    k_buf.at[slot, :, :, pl.ds(i * bs, bs)],
+                    sem.at[slot, 0]))
+                op(pltpu.make_async_copy(
+                    src(v_hbm, pid),
+                    v_buf.at[slot, :, :, pl.ds(i * bs, bs)],
+                    sem.at[slot, 1]))
+                if quantized:
+                    op(pltpu.make_async_copy(
+                        src(ks_hbm, pid),
+                        ks_buf.at[slot, :, pl.ds(i * bs, bs)],
+                        sem.at[slot, 2]))
+                    op(pltpu.make_async_copy(
+                        src(vs_hbm, pid),
+                        vs_buf.at[slot, :, pl.ds(i * bs, bs)],
+                        sem.at[slot, 3]))
+
+            if live is None or i == 0:
+                block()
+            else:
+                pl.when(i < live)(block)
 
     def start(row, c, slot):
-        for dma in _copies(row, c, slot):
-            dma.start()
+        _each(row, c, slot, lambda dma: dma.start())
 
     def wait(row, c, slot):
-        for dma in _copies(row, c, slot):
-            dma.wait()
+        _each(row, c, slot, lambda dma: dma.wait())
 
     return start, wait
 
@@ -168,13 +196,17 @@ def make_chunk_chain(start_chunk, wait_chunk):
 def _decode_kernel(
     # scalar prefetch
     tables_ref,   # [B, n_chunks * bpc] int32 physical block ids
-    kv_lens_ref,  # [B] int32 valid positions (incl. current token)
+    kv_lens_ref,  # [B] int32 valid positions (incl. current token); 0 =
+                  #   a lane with nothing to attend (no chunk, output 0)
+    base_ref,     # [B] int32 chunks consumed by all earlier rows
+    next_ref,     # [B] int32 next row with a chunk (-1 = none)
+    layer_ref,    # [1] int32 the layer of the pool this call reads
     # inputs
     q_ref,        # [1, nkv, group, hd] VMEM (this sequence's query)
-    k_hbm,        # [nkv, num_blocks, hd, bs] ANY (stays in HBM)
-    v_hbm,
-    # int8 caches add (ks_hbm, vs_hbm) [nkv, num_blocks, bs] fp32 ANY,
-    # then: o_ref [1, nkv, group, hd] VMEM; scratch k_buf/v_buf
+    k_hbm,        # [L, nkv, num_blocks, hd, bs] ANY: the WHOLE pool,
+    v_hbm,        #   in HBM; the DMA descriptor picks layer and block
+    # int8 caches add (ks_hbm, vs_hbm) [L, nkv, num_blocks, bs] fp32
+    # ANY, then: o_ref [1, nkv, group, hd] VMEM; scratch k_buf/v_buf
     # [2, nkv, hd, S] VMEM (+ks_buf/vs_buf [2, nkv, S] fp32), DMA
     # semaphores [2 slots, 2 (k/v) or 4 (+scales)]
     *rest,
@@ -189,35 +221,42 @@ def _decode_kernel(
         (o_ref, k_buf, v_buf, sem) = rest
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
     b = pl.program_id(0)
-    B = pl.num_programs(0)
-    nkv = k_hbm.shape[0]
-    hd = k_hbm.shape[2]
+    nkv = k_hbm.shape[1]
+    hd = k_hbm.shape[3]
     S = bpc * bs  # positions per chunk
     kv_len = kv_lens_ref[b]
     n_chunks = pl.cdiv(kv_len, S)
 
     # the chunk DMA contract (descriptor shapes, semaphore pairing, int8
-    # scale lanes) is shared with the packed-prefill kernel
+    # scale lanes) is shared with the packed-prefill kernel; here a
+    # chunk moves only the blocks that hold live positions
     start_chunk, wait_chunk = make_chunk_dma(
         tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem, bpc=bpc, bs=bs,
-        ks_hbm=ks_hbm, vs_hbm=vs_hbm, ks_buf=ks_buf, vs_buf=vs_buf)
+        ks_hbm=ks_hbm, vs_hbm=vs_hbm, ks_buf=ks_buf, vs_buf=vs_buf,
+        layer=layer_ref[0],
+        live_blocks=lambda row, c: pl.cdiv(kv_lens_ref[row] - c * S, bs))
     prime, chain_step = make_chunk_chain(start_chunk, wait_chunk)
 
-    # slot phase = chunks consumed by earlier sequences (recomputed from
-    # kv_lens — stateless, so the kernel needs nothing persisted across
-    # grid steps); the wrapper clamps kv_lens >= 1, mirrored here so the
-    # phase arithmetic cannot desync from the chunk loop
-    base = jax.lax.fori_loop(
-        0, b,
-        lambda j, acc: acc + pl.cdiv(jnp.maximum(kv_lens_ref[j], 1), S),
-        jnp.int32(0),
-    )
-    # the very first grid step primes the pipeline (every sequence has
-    # >= 1 chunk, so base == 0 is exactly b == 0); afterwards chunk 0 of
-    # sequence b was prefetched by sequence b-1's last chunk and the DMA
-    # chain never drains between sequences
+    # Blocks past a chunk's live ones are never copied, so those lanes
+    # of the buffers keep what they held: masked scores never read K,
+    # but p (exactly 0 there) still multiplies V, and 0 * NaN is NaN.
+    # Zero V (and its scales) once per launch, before the first DMA;
+    # afterwards the buffers only ever hold cache data, which is finite.
+    @pl.when(b == 0)
+    def _():
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        if quantized:
+            vs_buf[...] = jnp.zeros(vs_buf.shape, vs_buf.dtype)
+
+    # slot phase = chunks consumed by earlier rows, and the next row
+    # that has a chunk (both precomputed from kv_lens by the wrapper):
+    # the launch's first active row primes the pipeline; afterwards
+    # chunk 0 of a row was prefetched by the previous active row's last
+    # chunk and the DMA chain never drains between sequences.  A row
+    # with kv_len 0 has no chunk: it starts, waits on and reads nothing.
+    base = base_ref[b]
     prime(b, n_chunks, base)
-    next_row = jnp.where(b + 1 < B, b + 1, -1)
+    next_row = next_ref[b]
     q = q_ref[0]     # [nkv, g, hd] bf16, pre-scaled
     g = q.shape[1]
 
@@ -277,21 +316,27 @@ def _decode_kernel(
     l0 = jnp.zeros((nkv, g, 1), jnp.float32)
     a0 = jnp.zeros((nkv, g, hd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, a0))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    # a row with no chunk has l == 0: its output is 0, not 0/0
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
     # dynlint: disable=DYN001 kernel-level jit: engine dispatch reaches this inside already-watched programs; direct calls are bench/test-only
     jax.jit,
-    static_argnames=("layer", "blocks_per_chunk", "interpret", "debug_mode"),
+    static_argnames=("blocks_per_chunk", "interpret", "debug_mode"),
 )
 def paged_attention_decode_pallas(
     q: jax.Array,             # [B, nh, hd] (rope applied, NOT pre-scaled)
     k_cache: jax.Array,       # [L, nkv, num_blocks, hd, bs]
     v_cache: jax.Array,
-    layer: int,
+    layer,                    # int scalar, traced: one trace and one
+                              #   lowering serve every layer of a program
+                              #   (a static layer cost 9 s of lowering a
+                              #   16-layer decode program, paid on every
+                              #   start, compile cache or not; PR 28)
     block_tables: jax.Array,  # [B, max_blocks] int32
-    kv_lens: jax.Array,       # [B] int32, valid positions incl. current
+    kv_lens: jax.Array,       # [B] int32, valid positions incl. current;
+                              #   0 = idle lane (reads nothing, output 0)
     *,
     blocks_per_chunk: int | None = None,
     interpret: bool = False,
@@ -301,13 +346,16 @@ def paged_attention_decode_pallas(
 ) -> jax.Array:
     """Drop-in fast path for paged_attention.paged_attention_decode.
 
+    The pools go into the kernel whole and in their resident layout (the
+    DMA descriptor indexes layer and block), and a lane moves only its
+    live blocks: HBM traffic is the live context in the cache's dtype.
+
     With `k_scale`/`v_scale` (an int8 cache's per-position fp32 scale
     planes, quant/kv.py) the kernel DMAs int8 blocks plus their scale
     rows into VMEM and fuses the dequantizing multiply into the chunk
     consume — int8's halved HBM traffic lands inside the fast path."""
     B, nh, hd = q.shape
-    kc, vc = k_cache[layer], v_cache[layer]
-    nkv, _, _, bs = kc.shape
+    _, nkv, _, _, bs = k_cache.shape
     group = nh // nkv
     max_blocks = block_tables.shape[1]
     quantized = k_scale is not None
@@ -319,18 +367,26 @@ def paged_attention_decode_pallas(
     n_chunks = -(-max_blocks // bpc)
     pad = n_chunks * bpc - max_blocks
     if pad:
-        # padded entries hit the garbage block (0) and are masked by pos
+        # padded entries are past every live position: never copied
         block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
-    # the kernel's slot/semaphore chain assumes every sequence consumes
-    # >= 1 chunk; the engine always passes ctx+1 >= 1, this is a guard
-    kv_lens = jnp.maximum(kv_lens, 1)
+    S = bpc * bs
+    # a lane never reads past its table, whatever length it claims
+    kv_lens = jnp.clip(kv_lens, 0, max_blocks * bs).astype(jnp.int32)
+    # the chunk chain's planes (make_chunk_chain): each row's slot phase
+    # (chunks of all earlier rows) and its successor, the next row with
+    # a chunk (suffix-min over row indices, -1 past the last)
+    nch = -(-kv_lens // S)
+    base = (jnp.cumsum(nch) - nch).astype(jnp.int32)
+    rows = jnp.arange(B, dtype=jnp.int32)
+    suf = jax.lax.cummin(jnp.where(nch > 0, rows, B)[::-1])[::-1]
+    nxt = jnp.concatenate([suf[1:], jnp.full((1,), B, jnp.int32)])
+    next_row = jnp.where(nxt < B, nxt, -1).astype(jnp.int32)
 
     scale = 1.0 / jnp.sqrt(jnp.float32(hd))
     qg = (q.astype(jnp.float32) * scale).astype(q.dtype)
     qg = qg.reshape(B, nkv, group, hd)
 
-    S = bpc * bs
-    inputs = [qg, kc, vc]
+    inputs = [qg, k_cache, v_cache]
     in_specs = [
         pl.BlockSpec((1, nkv, group, hd),
                      lambda b, *refs: (b, 0, 0, 0)),
@@ -338,11 +394,11 @@ def paged_attention_decode_pallas(
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     scratch = [
-        pltpu.VMEM((2, nkv, hd, S), kc.dtype),
-        pltpu.VMEM((2, nkv, hd, S), vc.dtype),
+        pltpu.VMEM((2, nkv, hd, S), k_cache.dtype),
+        pltpu.VMEM((2, nkv, hd, S), v_cache.dtype),
     ]
     if quantized:
-        inputs += [k_scale[layer], v_scale[layer]]
+        inputs += [k_scale, v_scale]
         in_specs += [pl.BlockSpec(memory_space=pl.ANY),
                      pl.BlockSpec(memory_space=pl.ANY)]
         scratch += [pltpu.VMEM((2, nkv, S), jnp.float32),
@@ -350,12 +406,12 @@ def paged_attention_decode_pallas(
     scratch.append(pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)))
     # bytes per context position per head: int8 streams 1-byte elements
     # plus one fp32 scale per (head, position)
-    pos_bytes = hd * kc.dtype.itemsize + (4 if quantized else 0)
+    pos_bytes = hd * k_cache.dtype.itemsize + (4 if quantized else 0)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, bpc=bpc, bs=bs,
                           quantized=quantized, debug_mode=debug_mode),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=5,
             grid=(B,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, nkv, group, hd),
@@ -373,5 +429,6 @@ def paged_attention_decode_pallas(
             transcendentals=B * nh * max_blocks * bs,
         ),
         interpret=interpret,
-    )(block_tables, kv_lens, *inputs)
+    )(block_tables, kv_lens, base, next_row,
+      jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
     return out.reshape(B, nh, hd)

@@ -187,3 +187,184 @@ async def test_engine_tp2_keeps_pallas_fast_path():
     ref = await run("jnp", tp=1)
     assert len(ref) == 4
     assert sharded == ref
+
+
+# ---------------------------------------------------------------------------
+# PR 28: whole-pool operand, live-block DMAs, idle lanes, `auto`, counters
+# ---------------------------------------------------------------------------
+
+BS_T, BPC_T, MB_T = 4, 2, 6  # S = 8 positions a chunk, 3 chunks a table
+
+LANE_LAYOUTS = {
+    # 1, bs, bs+1, S, S+1 positions: every live-block count of a chunk
+    "ragged": [1, BS_T, BS_T + 1, BS_T * BPC_T, BS_T * BPC_T + 1],
+    # idle lanes first, several in a row, and last
+    "idle_edges": [0, 7, 0, 0, 12, 0],
+    # two idle lanes before the first chunk of the launch, a full table
+    "idle_lead_full": [0, 0, BS_T * MB_T, 1, 0],
+}
+
+
+def _poisoned_case(rng, kv_lens, int8):
+    """A decode case whose garbage block (physical id 0, where every
+    table entry past a lane's live blocks points) holds NaN: in V for a
+    bf16 cache, in the scale planes for an int8 one.  A kernel that
+    copies a block it does not need turns p = 0 into 0 * NaN."""
+    from test_packed_pallas import _int8_decode_case
+
+    if int8:
+        q, kc, vc, ks, vs, tables, _ = _int8_decode_case(
+            rng, kv_lens, bs=BS_T, mb=MB_T, L=3)
+        q = q.astype(jnp.bfloat16)
+        tables = np.asarray(tables).copy()
+    else:
+        q, kc, vc, _, _ = _mk_case(
+            rng, B=len(kv_lens), nkv=2, group=2, hd=16, bs=BS_T,
+            max_blocks=MB_T, L=3, dtype=jnp.bfloat16)
+        ks = vs = None
+        # _mk_case zeroes entries past ITS random lengths: own every block
+        tables = 1 + rng.permutation(len(kv_lens) * MB_T).reshape(
+            len(kv_lens), MB_T).astype(np.int32)
+    for b, n in enumerate(kv_lens):
+        tables[b, -(-n // BS_T):] = 0
+    clean = (kc, vc, ks, vs)
+    if int8:
+        bad = (kc, vc, ks.at[:, :, 0].set(jnp.nan),
+               vs.at[:, :, 0].set(jnp.nan))
+    else:
+        bad = (kc.at[:, :, 0].set(jnp.nan), vc.at[:, :, 0].set(jnp.nan),
+               None, None)
+    return (q, jnp.asarray(tables),
+            jnp.asarray(np.asarray(kv_lens, np.int32)), clean, bad)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("layout", sorted(LANE_LAYOUTS))
+def test_pallas_reads_only_live_blocks(layout, int8):
+    """The kernel takes the whole pool (layer 2 of 3), moves only the
+    blocks that hold live positions and gives an idle lane (kv_len 0)
+    no chunk: against the jnp path on the clean cache, with the garbage
+    block poisoned for the kernel."""
+    kv_lens = LANE_LAYOUTS[layout]
+    rng = np.random.default_rng(28)
+    q, tables, lens, clean, bad = _poisoned_case(rng, kv_lens, int8)
+    layer = 2
+    ref = paged_attention_decode_jnp(
+        q, clean[0], clean[1], layer, tables, jnp.maximum(lens, 1),
+        k_scale=clean[2], v_scale=clean[3])
+    out = paged_attention_decode_pallas(
+        q, bad[0], bad[1], layer, tables, lens, interpret=True,
+        blocks_per_chunk=BPC_T, k_scale=bad[2], v_scale=bad[3])
+    out = np.asarray(out, np.float32)
+    active = np.asarray(kv_lens) > 0
+    assert np.isfinite(out).all(), "a block past the live context was read"
+    np.testing.assert_array_equal(out[~active], 0.0)
+    np.testing.assert_allclose(out[active],
+                               np.asarray(ref, np.float32)[active],
+                               rtol=0.05, atol=0.05)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_write_token_kv_resident_matches_scatter(int8):
+    """The per-lane whole-plane write leaves the cache (and an int8
+    cache's scale planes) exactly as the flat scatter does."""
+    from dynamo_tpu.ops.paged_attention import write_token_kv
+
+    rng = np.random.default_rng(5)
+    L, nkv, nb, hd, bs, B = 3, 2, 9, 16, 4, 3
+    dt = jnp.int8 if int8 else jnp.bfloat16
+    kc = jnp.asarray(rng.integers(-9, 9, (L, nkv, nb, hd, bs)), dt)
+    vc = jnp.asarray(rng.integers(-9, 9, (L, nkv, nb, hd, bs)), dt)
+    sc = dict(k_scale=jnp.ones((L, nkv, nb, bs), jnp.float32),
+              v_scale=jnp.ones((L, nkv, nb, bs), jnp.float32)) \
+        if int8 else {}
+    k = jnp.asarray(rng.standard_normal((B, nkv, hd)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((B, nkv, hd)), jnp.bfloat16)
+    tables = jnp.asarray(1 + rng.permutation(nb - 1)[:B * 2].reshape(B, 2),
+                         jnp.int32)
+    ctx = jnp.asarray([0, 5, 7], jnp.int32)
+    want = write_token_kv(kc, vc, 1, k, v, tables, ctx, **sc)
+    got = write_token_kv(kc, vc, 1, k, v, tables, ctx, resident=True, **sc)
+    assert len(got) == len(want) == (4 if int8 else 2)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+    # with `valid`, only the marked lanes are written: lane 1 keeps its
+    # block as it was, lanes 0 and 2 read as after the scatter
+    got = write_token_kv(kc, vc, 1, k, v, tables, ctx, resident=True,
+                         valid=jnp.asarray([True, False, True]), **sc)
+    b1 = int(tables[1, 1])  # ctx 5 // bs 4
+    for g, w, before in zip(got, want, (kc, vc) + tuple(sc.values())):
+        g, w, before = (np.asarray(a, np.float32) for a in (g, w, before))
+        np.testing.assert_array_equal(g[:, :, b1], before[:, :, b1])
+        keep = np.arange(nb) != b1
+        np.testing.assert_array_equal(g[:, :, keep], w[:, :, keep])
+
+
+def test_resolve_decode_impl():
+    """`auto` is decided by what the code can observe; explicit impls
+    pass through untouched."""
+    from dynamo_tpu.ops.paged_attention import (
+        DECODE_IMPLS,
+        resolve_decode_impl,
+    )
+
+    bf16, i8, f32 = jnp.bfloat16, jnp.int8, jnp.float32
+    assert resolve_decode_impl("auto", "cpu", 128, 128, bf16) == "jnp"
+    assert resolve_decode_impl("auto", "tpu", 128, 128, bf16) == "pallas"
+    assert resolve_decode_impl("auto", "tpu", 256, 64, i8) == "pallas"
+    assert resolve_decode_impl("auto", "tpu", 16, 128, bf16) == "jnp"
+    assert resolve_decode_impl("auto", "tpu", 128, 128, f32) == "jnp"
+    assert resolve_decode_impl("auto", "tpu", 128, 16, i8) == "jnp"
+    assert resolve_decode_impl("auto", "gpu", 128, 128, bf16) == "jnp"
+    for impl in DECODE_IMPLS[1:]:
+        for platform, bs in (("cpu", 16), ("tpu", 128)):
+            assert resolve_decode_impl(impl, platform, bs, 128,
+                                       bf16) == impl
+
+
+@pytest.mark.parametrize("impl,read", [
+    # gathering path: every lane's whole table width, every step
+    ("jnp", 4 * 2 * 8),
+    # kernel: each step's live blocks (ctx 3 crosses into block 2 at
+    # step 1: 1 + 2 + 2 + 2; ctx 9: 3 + 3 + 3 + 4)
+    ("pallas_interpret", 7 + 13),
+])
+def test_decode_attn_counters(impl, read):
+    """One burst of k = 4 over lanes holding 3 and 9 tokens, block 4:
+    live = 4 x (ceil(4/4) + ceil(10/4)) = 16 on either impl."""
+    from dataclasses import replace
+
+    from test_engine import FP32
+
+    from dynamo_tpu.engine import EngineConfig, JaxEngine
+
+    eng = JaxEngine(EngineConfig(
+        model_config=replace(FP32, attn_impl=impl), block_size=4,
+        num_blocks=32, max_blocks_per_seq=8, max_num_seqs=2,
+        prefill_buckets=(8,), seed=7))
+    assert eng.model_cfg.attn_impl == impl
+    eng._count_decode_attn(np.asarray([3, 9], np.int32), 4)
+    assert eng.metrics["decode_attn_live_blocks"] == 16
+    assert eng.metrics["decode_attn_read_blocks"] == read
+
+
+async def test_decode_attn_counters_advance_while_serving():
+    """Served end to end on the CPU (`auto` -> jnp): 10 prompt tokens,
+    4 out = 3 decode steps at ctx 10, 11, 12 in lockstep bursts of 1:
+    live 3 + 3 + 4, read 3 x 2 lanes x 8 blocks."""
+    from test_engine import FP32, collect, greedy_req
+
+    from dynamo_tpu.engine import EngineConfig, JaxEngine
+
+    eng = JaxEngine(EngineConfig(
+        model_config=FP32, block_size=4, num_blocks=64,
+        max_blocks_per_seq=8, max_num_seqs=2, prefill_buckets=(8, 16),
+        seed=7, decode_fused_steps=1, overlap_scheduling=False))
+    assert eng.model_cfg.attn_impl == "jnp"  # resolved, reported by the MDC
+    toks = await collect(eng, greedy_req(
+        [5, 9, 13, 2, 7, 11, 3, 1, 8, 20], 4, "cnt"))
+    await eng.close()
+    assert len(toks) == 4
+    assert eng.metrics["decode_attn_live_blocks"] == 10
+    assert eng.metrics["decode_attn_read_blocks"] == 48

@@ -144,3 +144,54 @@ def test_decode_multi_step_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_decode_multi_reads_the_pool_where_it_lies(topo, one_chip):
+    """`decode_multi` at Mistral-7B widths, the `mistral-7b.chat` worker's
+    16 lanes x 20 blocks over a 320-block pool (4 layers), with `auto`
+    resolved as on the chip: the pool goes into the kernel whole and in
+    its resident layout.  Three ways to lose that, each worth as much as
+    the step's weights: a relayout `copy` of the pool (XLA's layout for
+    a column scatter differs from the kernel's), a per-layer slice of
+    it materialized for the custom call, a copy on the way in or out."""
+    import re
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.ops.paged_attention import resolve_decode_impl
+
+    L, NKV, NB, HD, B, MB, K = 4, 8, 320, 128, 16, 20, 8
+    impl = resolve_decode_impl("auto", topo.devices[0].platform, BS, HD,
+                               jnp.bfloat16)
+    assert impl == "pallas"
+    cfg = llama.LlamaConfig(
+        name="mistral-7b-widths", vocab_size=32768, d_model=4096,
+        n_layers=L, n_heads=32, n_kv_heads=NKV, head_dim=HD,
+        ffn_dim=14336, rope_theta=1e6, attn_impl=impl)
+    S = _sds(one_chip)
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    kv = tuple(S((L, NKV, NB, HD, BS), cfg.dtype) for _ in range(2))
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+    fn = jax.jit(
+        partial(JaxEngine._decode_multi_impl, llama, cfg, None, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9))
+    hlo = fn.lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32)).compile().as_text()
+    assert hlo.count("tpu_custom_call") == L
+    pool = rf"bf16\[{L},{NKV},{NB},{HD},{BS}\]"
+    layer = rf"bf16\[(?:1,)?{NKV},{NB},{HD},{BS}\]"
+    made = re.findall(rf"= ({pool}|{layer})\S* (\w[\w-]*)\(", hlo)
+    # what may produce a pool-shaped value: the in-place write and the
+    # plumbing around it; nothing may produce one layer's slice
+    assert {op for _, op in made} <= {
+        "parameter", "get-tuple-element", "dynamic-update-slice",
+        "fusion", "while", "bitcast"}, sorted(set(made))
+    assert not [s for s, _ in made if not re.fullmatch(pool, s)]
+    # the pool keeps one layout from entry to exit
+    layouts = set(re.findall(rf"{pool}(\{{[\d,]+)", hlo))
+    assert layouts == {"{4,3,2,1,0"}, layouts
