@@ -182,6 +182,13 @@ class JaxEngine:
         self.config = config
         self.model_cfg = config.resolve_model()
         self.family = get_family(self.model_cfg)
+        # a family whose pools are addressed by lane takes each prefill
+        # row's lane; one whose cache ends in device-side counts names
+        # them (models/__init__.py)
+        self._lane_addressed = bool(
+            getattr(self.family, "KV_LANE_ADDRESSED", False))
+        self._kv_counters = tuple(getattr(self.family, "KV_COUNTERS", ()))
+        self._kv_counters_seen = np.zeros(len(self._kv_counters), np.int64)
         # attention-impl overrides (ops/paged_attention.py +
         # ops/pallas_packed_prefill.py): the engine-level knobs replace
         # the resolved model config's fields so deployments pick the
@@ -285,6 +292,7 @@ class JaxEngine:
                 "model family %r has no quantized KV path; "
                 "kv_cache_dtype falls back to bf16", self.model_cfg.name)
             self.kv_dtype = "bf16"
+        self._family_gaps(kv_pull_fn)
         # "auto" decode attention becomes what it means for this cache on
         # this mesh's platform (ops/paged_attention.resolve_decode_impl):
         # the step programs, the MDC and the decode_attn_* counters all
@@ -422,7 +430,7 @@ class JaxEngine:
         # sharding-stable: one executable per shape, period.
         self._rep_sharding = NamedSharding(self.mesh, P())
         kv_specs = list(self.family.kv_cache_specs())
-        if is_quantized(self.kv):
+        if self.kv_dtype == "int8":
             kv_specs += list(self.family.kv_cache_scale_specs())
         self._kv_shardings = tuple(
             NamedSharding(self.mesh, spec) for spec in kv_specs)
@@ -581,6 +589,16 @@ class JaxEngine:
                     if k in self.params)
                 if isinstance(self.params, dict) else 0)
         self._flops_per_token = 2.0 * max(n_params - skip, 1)
+        # routed-expert layers as the host knows them, for the moe_*
+        # counters: (layers that route, picks a token, experts held a
+        # layer — the `moe_w_*` stacks' length, the router may be wider)
+        moe = [lp["moe_w_gate"].shape[0]
+               for lp in (self.params.get("layers", ())
+                          if isinstance(self.params, dict) else ())
+               if isinstance(lp, dict) and "moe_w_gate" in lp]
+        self._moe = (len(moe), getattr(self.model_cfg,
+                                       "experts_per_token", 0),
+                     moe[0] if moe else 0)
         # sequence-parallel ring prefill: long-context path for prompts
         # beyond the largest bucket when the mesh has an sp axis
         self._jit_prefill_ring = None
@@ -669,7 +687,23 @@ class JaxEngine:
             # decode steps: what the active lanes' contexts hold, and
             # what the impl that runs reads for them (_count_decode_attn)
             "decode_attn_live_blocks": 0, "decode_attn_read_blocks": 0,
+            # routed experts: picks = tokens x routing layers x experts
+            # a token, by phase; expert slots = held experts x routing
+            # layers a decode step (the denominator of how many of them
+            # a step's tokens visit).  A family that holds a share of
+            # its experts counts on the device which picks fell on a
+            # held expert and how many held experts a step visited
+            # (its KV_COUNTERS: moe_picks_held.*, moe_experts_visited.*)
+            "moe_picks.prefill": 0, "moe_picks.decode": 0,
+            "moe_expert_slots.decode": 0,
         }
+        for name in self._kv_counters:
+            self.metrics[name] = 0
+        if hasattr(self.family, "decode_block_counts"):
+            # window-pool blocks the active lanes hold against what a
+            # uniform cache would hold for them, summed over decode steps
+            self.metrics.update(kv_window_block_steps=0,
+                                kv_uniform_block_steps=0)
         # the scheduler thread's phases: counters host_s.<kind> /
         # host_n.<kind> always, `dyn.<kind>` on the profiler's clock
         # while a session is live, ring spans under a Tracer (obs/)
@@ -709,25 +743,61 @@ class JaxEngine:
         self._slo_burn_t = 0.0
 
     # -- cache ------------------------------------------------------------
+    def _family_gaps(self, kv_pull_fn) -> None:
+        """What the family says it does not carry yet (`UNSUPPORTED`),
+        held against this worker's configuration: a feature that only
+        costs speed falls back with a warning, as MLA's gaps do; one
+        whose absence would change answers or lose state refuses the
+        configuration.  (int8 cache, speculation, LoRA, ring and packed
+        prefill are decided where each is set up, by what functions the
+        family has.)"""
+        gaps = getattr(self.family, "UNSUPPORTED", ())
+        c = self.config
+        name = type(self.model_cfg).__name__
+        if "prefix_caching" in gaps and c.enable_prefix_caching:
+            logger.warning(
+                "model family %s cannot reuse a cached prefix (its window "
+                "layers' state at the boundary is not kept); prefix "
+                "caching is off", name)
+            c.enable_prefix_caching = False
+        refused = [what for what, asked in (
+            ("tp", c.tp > 1),
+            ("kvbm", c.host_cache_blocks > 0 or bool(c.disk_cache_dir)
+             or bool(c.object_store_dir)),
+            ("disagg", kv_pull_fn is not None),
+        ) if asked and what in gaps]
+        if refused:
+            raise ValueError(
+                f"model family {name} does not carry "
+                f"{', '.join(refused)} yet")
+
     def _init_kv_cache(self):
         m = self.model_cfg
         c = self.config
         # family-owned layout: GQA (k, v) or MLA (latent, rope-key) pair,
-        # both in the head-major transposed block layout.  An int8 cache
-        # (self.kv_dtype, quant/kv.py) adds fp32 scale planes as members
-        # 3 and 4 of the tuple, sharded with the same tp split.
+        # both in the head-major transposed block layout; a family with
+        # more than one kind of layer has more members (models/__init__
+        # .py), and one whose pools are addressed by lane sizes them by
+        # the lanes.  An int8 cache (self.kv_dtype, quant/kv.py) adds
+        # fp32 scale planes as members 3 and 4 of the tuple, sharded
+        # with the same tp split.
         dtype = jnp.int8 if self.kv_dtype == "int8" else m.dtype
-        k_shape, v_shape = self.family.kv_cache_shapes(
-            m, c.num_blocks, c.block_size)
-        k_spec, v_spec = self.family.kv_cache_specs()
-        # dynlint: disable=DYN001 one-shot sharded-zeros allocation at init, never dispatched while serving
-        k = jax.jit(partial(jnp.zeros, k_shape, dtype),
-                    out_shardings=NamedSharding(self.mesh, k_spec))()
-        # dynlint: disable=DYN001 one-shot sharded-zeros allocation at init, never dispatched while serving
-        v = jax.jit(partial(jnp.zeros, v_shape, dtype),
-                    out_shardings=NamedSharding(self.mesh, v_spec))()
+        lane_kw = ({"lanes": c.max_num_seqs}
+                   if getattr(self.family, "KV_LANE_ADDRESSED", False)
+                   else {})
+        shapes = self.family.kv_cache_shapes(
+            m, c.num_blocks, c.block_size, **lane_kw)
+        dtypes = (self.family.kv_cache_dtypes(m)
+                  if hasattr(self.family, "kv_cache_dtypes")
+                  else (dtype,) * len(shapes))
+        kv = tuple(
+            # dynlint: disable=DYN001 one-shot sharded-zeros allocation at init, never dispatched while serving
+            jax.jit(partial(jnp.zeros, shape, dt),
+                    out_shardings=NamedSharding(self.mesh, spec))()
+            for shape, dt, spec in zip(shapes, dtypes,
+                                       self.family.kv_cache_specs()))
         if self.kv_dtype != "int8":
-            return (k, v)
+            return kv
         ks_shape, vs_shape = self.family.kv_cache_scale_shapes(
             m, c.num_blocks, c.block_size)
         ks_spec, vs_spec = self.family.kv_cache_scale_specs()
@@ -737,7 +807,7 @@ class JaxEngine:
         # dynlint: disable=DYN001 one-shot sharded-zeros allocation at init, never dispatched while serving
         vs = jax.jit(partial(jnp.zeros, vs_shape, jnp.float32),
                      out_shardings=NamedSharding(self.mesh, vs_spec))()
-        return (k, v, ks, vs)
+        return kv + (ks, vs)
 
     # -- jitted programs --------------------------------------------------
     @staticmethod
@@ -791,7 +861,8 @@ class JaxEngine:
                 next_tokens = sample_tokens(logits, seeds, steps, temps,
                                             top_ks, top_ps)
         # [1, B]: burst-shaped like multi
-        return next_tokens[None], kv, positions, ctx_lens, steps
+        return (JaxEngine._with_counters(family, next_tokens[None], kv),
+                kv, positions, ctx_lens, steps)
 
     @staticmethod
     def _decode_multi_impl(family, model_cfg, mesh, greedy, num_steps,
@@ -827,7 +898,8 @@ class JaxEngine:
                 ctx_lens, num_steps, sample_fn, valid=valid, mesh=mesh,
                 **lora_kw,
             )
-            return burst, kv, positions, ctx_lens, steps
+            return (JaxEngine._with_counters(family, burst, kv), kv,
+                    positions, ctx_lens, steps)
         if greedy:
             sample_fn = None  # decode_multi defaults to argmax
         else:
@@ -840,7 +912,21 @@ class JaxEngine:
             ctx_lens, num_steps, sample_fn, valid=valid, mesh=mesh,
             **lora_kw,
         )
-        return burst, kv, positions, ctx_lens, steps
+        return (JaxEngine._with_counters(family, burst, kv), kv,
+                positions, ctx_lens, steps)
+
+    @staticmethod
+    def _with_counters(family, burst, kv):
+        """A family whose cache ends in a vector of device-side counts
+        (`KV_COUNTERS`) sends it home beside the tokens, one row a
+        count under the burst's k rows: the fetch of the burst is the
+        only one a decode step makes."""
+        n = len(getattr(family, "KV_COUNTERS", ()))
+        if not n:
+            return burst
+        return jnp.concatenate(
+            [burst, jnp.broadcast_to(kv[-1][:, None],
+                                     (n, burst.shape[1]))])
 
     @staticmethod
     def _inject_impl(kv, kb, vb, ids, ksb=None, vsb=None):
@@ -896,9 +982,13 @@ class JaxEngine:
     @staticmethod
     def _prefill_impl(family, model_cfg, params, kv, tokens, positions,
                       block_table, ctx_len, true_len, seed, temp, top_k,
-                      top_p, lora_bank=None, lidx=None):
+                      top_p, lora_bank=None, lidx=None, lanes=None):
+        """`lanes`: the scheduler's lane of the sequence, for a family
+        whose pools are addressed by lane (`KV_LANE_ADDRESSED`)."""
         lora_kw = ({"lora_bank": lora_bank, "adapter_idx": lidx}
                    if lora_bank is not None else {})
+        if lanes is not None:
+            lora_kw["lanes"] = lanes
         logits, kv = family.prefill(
             params, model_cfg, kv, tokens, positions, block_table,
             ctx_len, true_len, **lora_kw,
@@ -930,13 +1020,15 @@ class JaxEngine:
     def _prefill_batched_impl(family, model_cfg, params, kv, toks,
                               positions, tables, ctx_lens, true_lens,
                               seeds, temps, top_ks, top_ps,
-                              lora_bank=None, lidx=None):
+                              lora_bank=None, lidx=None, lanes=None):
         """Multi-sequence chunked prefill (family prefill_batched):
         concurrent arrivals share one program instead of serializing B=1
         chunks.  First tokens are sampled per row; rows whose prompt is not
         finished this chunk have their sample discarded by the host."""
         lora_kw = ({"lora_bank": lora_bank, "adapter_idx": lidx}
                    if lora_bank is not None else {})
+        if lanes is not None:
+            lora_kw["lanes"] = lanes
         logits, kv = family.prefill_batched(
             params, model_cfg, kv, toks, positions, tables,
             ctx_lens, true_lens, **lora_kw,
@@ -1012,6 +1104,7 @@ class JaxEngine:
                 jnp.asarray(a["true_lens"]), jnp.asarray(a["seeds"]),
                 jnp.asarray(a["temps"]), jnp.asarray(a["top_ks"]),
                 jnp.asarray(a["top_ps"]), *lora,
+                jnp.asarray(a["lanes"]) if "lanes" in a else None,
             )
         elif kind == "prefill_packed":
             lora = ((self.lora_bank, jnp.asarray(a["lidx"]))
@@ -1034,6 +1127,7 @@ class JaxEngine:
                 jnp.int32(a["pos"]), jnp.int32(a["chunk"]),
                 jnp.int32(a["seed"]), jnp.float32(a["temp"]),
                 jnp.int32(a["top_k"]), jnp.float32(a["top_p"]), *lora,
+                jnp.int32(a["lanes"]) if "lanes" in a else None,
             )
         elif kind == "decode_topk":
             # guided candidate step: same collective program, result is
@@ -2341,8 +2435,10 @@ class JaxEngine:
             top_ks[i] = s.top_k
             top_ps[i] = s.top_p
         lidx = np.zeros(Bp, np.int32)
+        lanes = np.zeros(Bp, np.int32)
         for i, (slot, _) in enumerate(zip(pslots, chunks)):
             lidx[i] = slot.lora_idx
+            lanes[i] = slot.index
         if self.step_sink is not None:
             self.step_sink("prefill_batch", {
                 "toks": toks, "positions": positions,
@@ -2350,6 +2446,7 @@ class JaxEngine:
                 "true_lens": true_lens, "seeds": seeds, "temps": temps,
                 "top_ks": top_ks, "top_ps": top_ps,
                 **({"lidx": lidx} if self.lora_bank is not None else {}),
+                **({"lanes": lanes} if self._lane_addressed else {}),
             })
         self._stamp_dispatch(pslots)
         tok, self.kv = self._jit_prefill_batched(
@@ -2359,6 +2456,7 @@ class JaxEngine:
             jnp.asarray(seeds), jnp.asarray(temps), jnp.asarray(top_ks),
             jnp.asarray(top_ps), self.lora_bank,
             jnp.asarray(lidx) if self.lora_bank is not None else None,
+            jnp.asarray(lanes) if self._lane_addressed else None,
         )
         self._fpm_prefill(
             rows=n, tokens=int(sum(chunks)), bucket=bucket,
@@ -2553,6 +2651,8 @@ class JaxEngine:
                 "top_k": np.int32(s.top_k), "top_p": np.float32(s.top_p),
                 **({"lidx": np.int32(slot.lora_idx)}
                    if self.lora_bank is not None else {}),
+                **({"lanes": np.int32(slot.index)}
+                   if self._lane_addressed else {}),
             })
         self._stamp_dispatch((slot,))
         tok, self.kv = self._jit_prefill(
@@ -2565,6 +2665,7 @@ class JaxEngine:
             jnp.float32(s.top_p), self.lora_bank,
             jnp.int32(slot.lora_idx) if self.lora_bank is not None
             else None,
+            jnp.int32(slot.index) if self._lane_addressed else None,
         )
         self._fpm_prefill(
             rows=1, tokens=int(chunk), bucket=bucket,
@@ -2698,6 +2799,8 @@ class JaxEngine:
         sample); None marks a completed prompt whose token readback is
         deferred (_pending_first — the flush completes it next step)."""
         self.metrics["prefill_tokens"] += chunk
+        self.metrics["moe_picks.prefill"] += \
+            chunk * self._moe[0] * self._moe[1]
         slot.prefill_pos += chunk
         slot.prefill_chunks += 1
         slot.ctx_len = slot.prefill_pos
@@ -3542,6 +3645,17 @@ class JaxEngine:
         per step for the gathering paths (jnp, MLA), each step's live
         blocks for the Pallas kernel."""
         bs = self.config.block_size
+        layers, picks, held = self._moe
+        self.metrics["moe_picks.decode"] += k * len(ctx) * layers * picks
+        self.metrics["moe_expert_slots.decode"] += k * layers * held
+        if hasattr(self.family, "decode_block_counts"):
+            # more than one kind of layer: the family counts its own
+            for name, n in self.family.decode_block_counts(
+                    self.model_cfg, ctx, k, bs, self.config.max_num_seqs,
+                    self.config.max_blocks_per_seq,
+                    self.model_cfg.attn_impl).items():
+                self.metrics[name] += n
+            return
         self.metrics["decode_attn_live_blocks"] += \
             k * int(np.sum(-(-(ctx + 1) // bs)))
         if self.model_cfg.attn_impl in PALLAS_IMPLS:
@@ -3939,8 +4053,16 @@ class JaxEngine:
         guarantees were overwritten only by later dispatches)."""
         e = self._inflight.popleft()
         with self._phase("device_wait", k=e["k"], what="burst_fetch"):
-            arr = np.asarray(e["burst"])  # [k, B]
+            arr = np.asarray(e["burst"])  # [k (+ counters), B]
         self._fpm_sync_t = time.monotonic()
+        if self._kv_counters:
+            # the rows under the tokens: running int32 totals on the
+            # device; what they grew by (modulo the wrap) is added
+            now = arr[e["k"]:, 0].astype(np.int64)
+            grew = (now - self._kv_counters_seen) % (1 << 32)
+            self._kv_counters_seen = now
+            for name, n in zip(self._kv_counters, grew):
+                self.metrics[name] = self.metrics.get(name, 0) + int(n)
         with self._phase("emit", k=e["k"], what="burst"):
             for i, ident in e["lanes"].items():
                 s = self._slots[i] if i < len(self._slots) else None

@@ -389,6 +389,14 @@ def _moe_router(layer, cfg: LlamaConfig, x: jax.Array):
     return jax.nn.softmax(top_w, axis=-1), top_e
 
 
+def experts_held(cfg) -> Tuple[int, int]:
+    """(first, count) of the routed experts whose weights this program
+    holds, out of the `cfg.n_experts` the router scores: all of them
+    unless the config says otherwise (`experts_held`, a chip's share of
+    an expert-parallel deployment)."""
+    return getattr(cfg, "experts_held", None) or (0, cfg.n_experts)
+
+
 @jax.named_scope("dyn.moe_dispatch")
 def moe_dispatch_dense(layer, cfg: LlamaConfig, x: jax.Array,
                        top_w: jax.Array, top_e: jax.Array,
@@ -399,7 +407,13 @@ def moe_dispatch_dense(layer, cfg: LlamaConfig, x: jax.Array,
 
     With experts sharded over tp, the expert einsums run local to each
     shard and the final combine reduces over the expert axis (one psum on
-    the way out) — no dispatch tensors, no all-to-all."""
+    the way out) — no dispatch tensors, no all-to-all.
+
+    `cfg.n_experts` is the ROUTER's width; the `moe_w_*` stacks hold the
+    experts `experts_held(cfg)` names.  A chip's share of a wider
+    deployment computes the held experts' part for the tokens routed to
+    them and adds nothing for the rest; with all experts held this is
+    the one code path there was."""
     T, d = x.shape
     E = cfg.n_experts
     wmat = jnp.zeros((T, E), jnp.float32).at[
@@ -407,6 +421,9 @@ def moe_dispatch_dense(layer, cfg: LlamaConfig, x: jax.Array,
     ].set(top_w)                                       # [T, E]
     if valid is not None:
         wmat = wmat * valid.astype(jnp.float32)[:, None]
+    first, count = experts_held(cfg)
+    if count != E:
+        wmat = wmat[:, first:first + count]            # the held columns
     h = jnp.einsum("td,edf->etf", x, layer["moe_w_gate"])
     h = jax.nn.silu(h) * jnp.einsum("td,edf->etf", x, layer["moe_w_up"])
     eout = jnp.einsum("etf,efd->etd", h, layer["moe_w_down"])
@@ -444,6 +461,12 @@ def moe_dispatch_capacity(layer, cfg: LlamaConfig, x: jax.Array,
 
     e_flat = top_e.reshape(-1)                         # [T*k]
     w_flat = top_w.reshape(-1)
+    first, count = experts_held(cfg)
+    if count != E:
+        # a share of the experts (moe_dispatch_dense): a pick of an
+        # expert held elsewhere is an all-zero row, claims no capacity
+        # and combines to nothing; C stays the deployment's per expert
+        e_flat, E = jnp.clip(e_flat - first, -1, count), count
     onehot = jax.nn.one_hot(e_flat, E, dtype=jnp.int32)       # [Tk, E]
     if valid is not None:
         onehot = onehot * jnp.repeat(valid.astype(jnp.int32), k)[:, None]

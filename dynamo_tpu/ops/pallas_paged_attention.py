@@ -205,6 +205,7 @@ def _decode_kernel(
     q_ref,        # [1, nkv, group, hd] VMEM (this sequence's query)
     k_hbm,        # [L, nkv, num_blocks, hd, bs] ANY: the WHOLE pool,
     v_hbm,        #   in HBM; the DMA descriptor picks layer and block
+                  #   (V may be another width: [.., hdv, bs], out hdv)
     # int8 caches add (ks_hbm, vs_hbm) [L, nkv, num_blocks, bs] fp32
     # ANY, then: o_ref [1, nkv, group, hd] VMEM; scratch k_buf/v_buf
     # [2, nkv, hd, S] VMEM (+ks_buf/vs_buf [2, nkv, S] fp32), DMA
@@ -222,7 +223,7 @@ def _decode_kernel(
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
     b = pl.program_id(0)
     nkv = k_hbm.shape[1]
-    hd = k_hbm.shape[3]
+    hdv = v_hbm.shape[3]
     S = bpc * bs  # positions per chunk
     kv_len = kv_lens_ref[b]
     n_chunks = pl.cdiv(kv_len, S)
@@ -314,7 +315,7 @@ def _decode_kernel(
 
     m0 = jnp.full((nkv, g, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((nkv, g, 1), jnp.float32)
-    a0 = jnp.zeros((nkv, g, hd), jnp.float32)
+    a0 = jnp.zeros((nkv, g, hdv), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, a0))
     # a row with no chunk has l == 0: its output is 0, not 0/0
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -356,6 +357,8 @@ def paged_attention_decode_pallas(
     consume — int8's halved HBM traffic lands inside the fast path."""
     B, nh, hd = q.shape
     _, nkv, _, _, bs = k_cache.shape
+    hdv = v_cache.shape[3]    # V may be narrower than K (MLA-free GQA
+    #                           families with unequal widths): out is hdv
     group = nh // nkv
     max_blocks = block_tables.shape[1]
     quantized = k_scale is not None
@@ -395,7 +398,7 @@ def paged_attention_decode_pallas(
     ]
     scratch = [
         pltpu.VMEM((2, nkv, hd, S), k_cache.dtype),
-        pltpu.VMEM((2, nkv, hd, S), v_cache.dtype),
+        pltpu.VMEM((2, nkv, hdv, S), v_cache.dtype),
     ]
     if quantized:
         inputs += [k_scale, v_scale]
@@ -406,7 +409,8 @@ def paged_attention_decode_pallas(
     scratch.append(pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)))
     # bytes per context position per head: int8 streams 1-byte elements
     # plus one fp32 scale per (head, position)
-    pos_bytes = hd * k_cache.dtype.itemsize + (4 if quantized else 0)
+    pos_bytes = ((hd + hdv) * k_cache.dtype.itemsize
+                 + (8 if quantized else 0))
     out = pl.pallas_call(
         functools.partial(_decode_kernel, bpc=bpc, bs=bs,
                           quantized=quantized, debug_mode=debug_mode),
@@ -414,21 +418,21 @@ def paged_attention_decode_pallas(
             num_scalar_prefetch=5,
             grid=(B,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, nkv, group, hd),
+            out_specs=pl.BlockSpec((1, nkv, group, hdv),
                                    lambda b, *refs: (b, 0, 0, 0)),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, nkv, group, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, nkv, group, hdv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         cost_estimate=pl.CostEstimate(
-            flops=2 * 2 * B * nh * hd * max_blocks * bs,
-            bytes_accessed=2 * B * nkv * max_blocks * bs * pos_bytes,
+            flops=2 * B * nh * (hd + hdv) * max_blocks * bs,
+            bytes_accessed=B * nkv * max_blocks * bs * pos_bytes,
             transcendentals=B * nh * max_blocks * bs,
         ),
         interpret=interpret,
     )(block_tables, kv_lens, base, next_row,
       jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
-    return out.reshape(B, nh, hd)
+    return out.reshape(B, nh, hdv)

@@ -195,3 +195,54 @@ def test_decode_multi_reads_the_pool_where_it_lies(topo, one_chip):
     # the pool keeps one layout from entry to exit
     layouts = set(re.findall(rf"{pool}(\{{[\d,]+)", hlo))
     assert layouts == {"{4,3,2,1,0"}, layouts
+
+
+def test_hybrid_decode_and_prefill_compile_for_v5e(one_chip):
+    """The window + global family (models/mimo.py) at MiMo-V2-Flash's
+    widths, cut to one global layer (dense MLP) and one window layer
+    (16 of 256 experts held): a fused decode burst of the engine's own
+    program, and a 256-token prefill chunk.  The global layer reads
+    through the Pallas decode kernel with K 192 and V 128 wide (one
+    custom call), its pools keep their resident layout, and the counters
+    ride under the burst's tokens."""
+    import re
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.models import mimo
+
+    cfg = dataclasses.replace(
+        mimo.PRESETS["mimo-v2-flash"], n_layers=2, layer_kinds=(0, 1),
+        moe_layers=(0, 1), experts_held=(0, 16), vocab_size=8192,
+        attn_impl="pallas")
+    S = _sds(one_chip)
+    B, MB, NB, K, T = 8, 6, 49, 4, 256
+    shapes = jax.eval_shape(
+        lambda: mimo.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    kv = tuple(S(s, d) for s, d in zip(
+        mimo.kv_cache_shapes(cfg, NB, BS, lanes=B),
+        mimo.kv_cache_dtypes(cfg)))
+    assert kv[2].shape == (1, 8, 1 + 2 * B, 192, BS)
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+    fn = jax.jit(
+        partial(JaxEngine._decode_multi_impl, mimo, cfg, None, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9))
+    lowered = fn.lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32))
+    assert lowered.out_info[0].shape == (K + len(mimo.KV_COUNTERS), B)
+    hlo = lowered.compile().as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    layouts = set(re.findall(rf"bf16\[1,4,{NB},(?:192|128),{BS}\]"
+                             r"(\{[\d,]+)", hlo))
+    assert layouts == {"{4,3,2,1,0"}, layouts
+    pre = jax.jit(partial(JaxEngine._prefill_impl, mimo, cfg),
+                  donate_argnums=(1,))
+    mem = pre.lower(
+        params, kv, S((T,), i32), S((T,), i32), S((MB,), i32), S((), i32),
+        S((), i32), S((), i32), S((), f32), S((), i32), S((), f32), None,
+        None, S((), i32)).compile().memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
