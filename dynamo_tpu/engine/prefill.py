@@ -23,6 +23,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..ops.packed_prefill import check_packed_stream
+
 
 def _pow2(n: int, lo: int = 1) -> int:
     b = lo
@@ -114,6 +116,7 @@ def plan_packed_prefill(
             lidx[off:off + chunk] = slot.lora_idx
         off += chunk
 
+    check_packed_stream(seg_ids, positions, valid, S)
     arrays = {
         "toks": toks, "positions": positions, "seg_ids": seg_ids,
         "tables": tables, "last_idx": last_idx, "valid": valid,

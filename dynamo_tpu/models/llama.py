@@ -645,8 +645,14 @@ def prefill_packed(
     prefix-hit tails) run as ONE padding-free token stream with segment
     ids (ops/packed_prefill.py) — the MFU path that replaces the padded
     per-row batched program.  Semantically identical to running `prefill`
-    per sequence: K/V scatter into each token's own blocks, attention is
+    per sequence: K/V written into each token's own blocks, attention is
     causal-within-segment over each segment's paged context.
+
+    Contract: each segment row's tokens are ONE run of the stream at
+    consecutive positions, rows in rising order, the padded tail last
+    (ops/packed_prefill.check_packed_stream, which plan_packed_prefill
+    holds its arrays to).  The K/V write sizes itself by that; a stream
+    in another order loses columns without an error.
 
     NOTE: capacity-dispatch MoE is NOT packed-safe (segments would share
     one expert-capacity pool and capacity-drop each other's tokens); the
@@ -677,8 +683,9 @@ def _packed_forward(
     mesh=None,                 # required for the Pallas path under tp>1
 ):
     """Shared packed-stream transformer body (prefill_packed and
-    spec_verify_packed): K/V scatter into each token's own blocks, then
-    causal-within-segment attention over each segment's paged context.
+    spec_verify_packed): K/V written into each token's own blocks, whole
+    planes in the pool's resident layout, then causal-within-segment
+    attention over each segment's paged context.
     Returns (final hidden states [T, d], updated kv_cache)."""
     T = token_ids.shape[0]
     x = params["embedding"][token_ids].astype(cfg.dtype)  # [T, d]
@@ -718,6 +725,8 @@ def spec_verify_packed(
     overwritten when the sequence actually reaches those positions) —
     but logits come back for EVERY packed position, since verification
     needs the target's next-token distribution after each draft prefix.
+    The stream keeps `prefill_packed`'s contract: one run of consecutive
+    positions a row, rows in order (plan_spec_verify checks its arrays).
     Returns (logits [T, vocab], updated kv_cache)."""
     x, kv_cache = _packed_forward(
         params, cfg, kv_cache, token_ids, positions, seg_ids,

@@ -16,10 +16,15 @@ with segment ids:
                        the blocks this dispatch actually touches)
     valid     [T]      False for the padded tail (writes -> garbage)
 
-KV writes scatter each token into its own segment's paged block first;
-attention then reads everything — cached prefix AND this chunk — back
-through the block table, masked causal-within-segment by absolute
-position (token t sees its segment's cache positions [0, positions[t]]).
+KV writes come first: each block a chunk touches is rewritten as whole
+[nkv, hd, bs] planes, in the layout the pool is resident in
+(`write_packed_kv`; a chunk of T tokens in S segments touches at most
+(T - 2S) // bs + 2S blocks).  Attention then reads everything — cached
+prefix AND this chunk — back through the block table, one gather of the
+blocks a flash step names (`_gather_blocks`), masked causal-within-segment
+by absolute position (token t sees its segment's cache positions
+[0, positions[t]]).  Nothing in the program copies, relayouts or slices
+the pool (tests/test_tpu_compile.py holds the compiled program to that).
 Because the chunk's K/V are in the cache before attention runs, chunk
 boundaries need no special casing: later chunks of the same prompt (even
 co-packed in one dispatch at consecutive positions) attend to earlier
@@ -60,19 +65,108 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from .paged_attention import (
-    NEG_INF,
-    _gather_ctx,
-    _gqa_out,
-    _gqa_scores,
-    _store_kv,
-)
+from ..quant.kv import quantize_tokens
+from .paged_attention import NEG_INF, _gqa_out, _gqa_scores
 
 # the packed-prefill dispatch's impl vocabulary — the single source of
 # truth the engine's --packed-attn-impl validation and CLI choices
 # reference (a new impl added here is accepted end-to-end)
 PACKED_IMPLS = ("auto", "xla", "pallas", "pallas_interpret")
+
+
+def check_packed_stream(seg_ids: np.ndarray, positions: np.ndarray,
+                        valid: np.ndarray, rows: int) -> None:
+    """The packed stream's contract, checked on the host where its
+    arrays are built (engine/prefill.py, spec/verify.py; numpy over T
+    elements): the valid tokens lead the stream, and each segment row's
+    tokens are ONE run of it at consecutive positions, the rows in
+    rising order.  `plan_packed_write` sizes its plan by this and drops
+    what overflows it, where the flat scatter it replaced took tokens
+    in any order: a stream outside the contract would lose K/V columns
+    on the device without an error, so it is refused here."""
+    n = int(np.count_nonzero(valid))
+    seg, pos = seg_ids[:n], positions[:n]
+    same = seg[1:] == seg[:-1]
+    if not (valid[:n].all()
+            and (n == 0 or (seg[0] >= 0 and seg[-1] < rows))
+            and np.where(same, pos[1:] == pos[:-1] + 1,
+                         seg[1:] > seg[:-1]).all()):
+        raise ValueError(
+            "packed stream outside its contract: every segment row is "
+            "one run of the stream at consecutive positions, rows in "
+            f"order, padding last ({n} tokens of {len(valid)}, {rows} "
+            "rows)")
+
+
+def plan_packed_write(block_tables: jax.Array, seg_ids: jax.Array,
+                      positions: jax.Array, valid: jax.Array,
+                      block_size: int) -> Tuple[jax.Array, ...]:
+    """Which blocks a packed stream writes and which token fills which
+    column of each (the same for every layer of a program).  A segment
+    row's tokens are one run of the stream at consecutive positions
+    (`check_packed_stream`, which both planners hold their arrays to),
+    so a new (segment, block column) pair starts a new plane, a run of
+    m tokens starts at most (m - 2) // bs + 2 of them (it may begin and
+    end inside a block), and T tokens in S rows at most
+    (T - 2S) // bs + 2S: the static size of the plan.  A stream outside
+    the contract can start more, and what overflows is dropped.
+    Returns (blocks [NP] physical block per plane, src [NP, bs] packed
+    index of the token for each column, T where the stream has none,
+    and the number of planes in use; the padded tail starts none)."""
+    T, S = seg_ids.shape[0], block_tables.shape[0]
+    n_planes = max(1, min(T, (T - 2 * S) // block_size + 2 * S))
+    col = positions // block_size
+
+    def differs(x):
+        return x[1:] != x[:-1]
+
+    starts = valid & jnp.concatenate([
+        jnp.ones((1,), bool),
+        differs(seg_ids) | differs(col) | differs(valid)])
+    plane = jnp.where(valid, jnp.cumsum(starts, dtype=jnp.int32) - 1,
+                      n_planes)
+    blocks = jnp.zeros((n_planes,), jnp.int32).at[plane].set(
+        block_tables[seg_ids, col], mode="drop")
+    src = jnp.full((n_planes, block_size), T, jnp.int32).at[
+        plane, positions % block_size].set(
+            jnp.arange(T, dtype=jnp.int32), mode="drop")
+    return blocks, src, jnp.sum(starts, dtype=jnp.int32)
+
+
+# dynlint: disable=DYN001 op-level jit: reached only inside the engine's watched prefill_packed / spec_verify programs; `layer` is traced, so one trace serves every layer of a program
+@jax.jit
+def _store_planes(caches, layer, xs, blocks, src, used):
+    """The packed stream's sibling of paged_attention._store_columns:
+    plane i of the plan is block blocks[i]'s whole [nkv, hd, bs] planes
+    (or [nkv, bs] of a scale plane), read, given xs[j][src[i, o]] in
+    every column o the stream has a token for, and written back — the
+    pools keep the layout they are resident in, {4,3,2,1,0}, where the
+    flat column scatter made XLA's TPU compiler hold a {3,1,4,2,0} twin
+    of the pool and copy all of it back for every layer's read (34
+    copies of 1.34 GB in a 16-layer program, PR 30).  One plane at a
+    time and in stream order, so two segments that continue one block
+    (two chunks of one prompt in one stream) both land."""
+    T = xs[0].shape[0]
+    zero = jnp.int32(0)
+    has = src < T                       # [NP, bs]
+    at_tok = jnp.minimum(src, T - 1)
+    # the stream's columns in the planes' own shape: [NP, nkv, (hd,) bs]
+    new = [jnp.moveaxis(x.astype(c.dtype)[at_tok], 1, -1)
+           for c, x in zip(caches, xs)]
+
+    def body(i, cs):
+        out = []
+        for c, n in zip(cs, new):
+            at = (layer, zero, blocks[i]) + (zero,) * (c.ndim - 3)
+            plane = jax.lax.dynamic_slice(
+                c, at, (1, c.shape[1], 1) + c.shape[3:])
+            out.append(jax.lax.dynamic_update_slice(
+                c, jnp.where(has[i], n[i][None, :, None], plane), at))
+        return tuple(out)
+
+    return jax.lax.fori_loop(0, used, body, tuple(caches))
 
 
 @jax.named_scope("dyn.kv_write")
@@ -89,16 +183,46 @@ def write_packed_kv(
     k_scale: jax.Array = None,  # [L, nkv, nblocks, bs] fp32 (int8 cache)
     v_scale: jax.Array = None,
 ) -> Tuple[jax.Array, ...]:
-    """Scatter a packed chunk's K/V into each token's own sequence blocks
-    (one flat scatter; sequences own disjoint blocks, padding tokens land
-    in the garbage block).  With scales, tokens quantize per (token,
-    head) on the way in (paged_attention._store_kv)."""
-    bs = k_cache.shape[4]
-    blocks = block_tables[seg_ids, positions // bs]  # [T]
-    offsets = positions % bs
-    blocks = jnp.where(valid, blocks, 0)
-    return _store_kv(k_cache, v_cache, layer, k, v, blocks, offsets,
-                     k_scale, v_scale)
+    """Write a packed chunk's K/V into each token's own sequence blocks:
+    every block the chunk touches is rewritten whole, the stream's
+    tokens in their columns and what the block held elsewhere (a chunk
+    may start or end inside a block; `_store_planes`).  The cells
+    written hold what the flat scatter of paged_attention._store_kv
+    would have put there; the padded tail writes nothing.  With scales,
+    tokens quantize per (token, head) on the way in (quant/kv.py).
+
+    Contract (`check_packed_stream`): each segment row is ONE run of
+    the stream at consecutive positions, rows in order, padding last.
+    The scatter took tokens in any order; this writer does not."""
+    # the same for every layer of a program: XLA merges the copies
+    plan = plan_packed_write(block_tables, seg_ids, positions, valid,
+                             k_cache.shape[4])
+    caches, xs = (k_cache, v_cache), (k, v)
+    if k_scale is not None:
+        k, ks = quantize_tokens(k)
+        v, vs = quantize_tokens(v)
+        caches, xs = caches + (k_scale, v_scale), (k, v, ks, vs)
+    return _store_planes(caches, jnp.int32(layer), xs, *plan)
+
+
+def _gather_blocks(cache: jax.Array, layer: int, cols: jax.Array,
+                   scale: jax.Array = None) -> jax.Array:
+    """[L,nkv,nb,hd,bs] + [C] block ids -> [nkv, C*bs, hd], what
+    paged_attention._gather_ctx returns, with layer and blocks indexed
+    by ONE gather: `cache[layer][:, cols]` made the TPU compiler
+    materialise the layer's whole [nkv, nb, hd, bs] slice of the pool
+    in every flash step before gathering C blocks from it (PR 30).
+    A fork for one PR: `_gather_ctx` (jnp decode, window_attention)
+    was left as it is so that the cells that run it stay controls; it
+    takes this form in place next, and this copy goes (ROADMAP S4)."""
+    li = jnp.int32(layer)
+    g = cache[li, :, cols]              # [C, nkv, hd, bs]
+    C, nkv, hd, bs = g.shape
+    g = g.transpose(1, 0, 3, 2).reshape(nkv, C * bs, hd)
+    if scale is not None:
+        s = scale[li, :, cols].swapaxes(0, 1).reshape(nkv, C * bs)
+        g = g.astype(jnp.float32) * s[..., None]
+    return g
 
 
 def _segment_flash(q, k_cache, v_cache, layer, table, token_mask,
@@ -120,8 +244,8 @@ def _segment_flash(q, k_cache, v_cache, layer, table, token_mask,
         m, l, acc = carry
         cols = jax.lax.dynamic_slice(table, (jc * chunk_cols,),
                                      (chunk_cols,))
-        k_c = _gather_ctx(k_cache, layer, cols, k_scale)  # [nkv, C, hd]
-        v_c = _gather_ctx(v_cache, layer, cols, v_scale)
+        k_c = _gather_blocks(k_cache, layer, cols, k_scale)  # [nkv, C, hd]
+        v_c = _gather_blocks(v_cache, layer, cols, v_scale)
         C = chunk_cols * bs
         s = _gqa_scores(q, k_c) * scale          # [T, nh, C] fp32
         span = jc * C + jnp.arange(C)

@@ -174,7 +174,9 @@ def _store_columns(caches, layer, xs, blocks, offsets, valid):
     PR 28).  In the resident layout a token's column is spread over
     every tile of its planes, so whole planes are what any writer has
     to move: 4 us a lane and tensor on a v5e (my chip run, PR 28), so
-    the loop runs over the valid lanes only."""
+    the loop runs over the valid lanes only.  Its sibling for a packed
+    prefill stream, where a block's plane takes up to bs tokens at
+    once, is packed_prefill._store_planes (PR 30)."""
     bs = caches[0].shape[-1]
     zero = jnp.int32(0)
     lanes = jnp.argsort(~valid)   # stable: the valid lanes first

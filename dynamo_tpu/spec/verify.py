@@ -27,6 +27,7 @@ import numpy as np
 # one bucket-rounding policy for BOTH packed planners: a divergence here
 # would silently fork the verify-plan shape zoo from the prefill one
 from ..engine.prefill import _pow2
+from ..ops.packed_prefill import check_packed_stream
 
 
 @dataclass
@@ -84,6 +85,7 @@ def plan_spec_verify(
         offsets.append(off)
         off += m
 
+    check_packed_stream(seg_ids, positions, valid, S)
     return SpecPlan(
         rows=list(rows), offsets=offsets,
         arrays={

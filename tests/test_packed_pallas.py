@@ -217,6 +217,255 @@ def test_packed_pallas_tp_sharded_matches_xla():
 
 
 # ---------------------------------------------------------------------------
+# the packed write: whole planes in the resident layout vs the flat scatter
+# ---------------------------------------------------------------------------
+
+
+def _flat_scatter(kc, vc, layer, k, v, tables, seg_ids, positions, valid,
+                  k_scale=None, v_scale=None):
+    """`write_packed_kv` as it was until PR 30, kept as the plain
+    reference: one flat column scatter, the padded tail into block 0."""
+    from dynamo_tpu.ops.paged_attention import _store_kv
+
+    bs = kc.shape[4]
+    blocks = jnp.where(valid, tables[seg_ids, positions // bs], 0)
+    return _store_kv(kc, vc, layer, k, v, blocks, positions % bs,
+                     k_scale, v_scale)
+
+
+# name -> (block size, stream length, [(table row, first position,
+# tokens), ...] in stream order, table rows)
+_WRITE_CASES = {
+    "one-segment": (16, 64, [(0, 0, 40)], 1),
+    "four-segments": (16, 64, [(0, 0, 7), (1, 0, 1), (2, 0, 12),
+                               (3, 0, 20)], 4),
+    "inside-one-block": (16, 8, [(0, 5, 6)], 1),
+    "starts-and-ends-inside-blocks": (16, 32, [(0, 21, 30)], 1),
+    "two-chunks-of-one-prompt": (16, 32, [(0, 0, 10), (0, 10, 9)], 2),
+    "prefix-hit-tail": (16, 32, [(0, 32, 20), (1, 37, 6)], 2),
+    "padded-tail-only-one-token": (16, 16, [(0, 15, 1)], 1),
+    "unused-rows": (16, 32, [(0, 0, 5), (2, 0, 9)], 4),
+    "spec-verify-rows": (16, 16, [(0, 15, 5), (1, 9, 5), (2, 30, 5)], 3),
+    "every-segment-ends-a-block-and-starts-one": (
+        16, 72, [(s, 15, 18) for s in range(4)], 4),
+    "block-128": (128, 512, [(0, 100, 300)], 1),
+    "block-128-four-segments": (128, 512, [(0, 0, 130), (1, 127, 3),
+                                           (2, 256, 128), (3, 5, 200)], 4),
+}
+
+
+def _write_case(name, int8):
+    from zlib import crc32
+
+    bs, T, segs, S = _WRITE_CASES[name]
+    rng = np.random.default_rng(crc32(name.encode()))
+    L, nkv, hd, mb = 2, 2, 8, 6
+    nb = 1 + S * mb
+    tables = (1 + rng.permutation(nb - 1))[:S * mb].reshape(S, mb)
+    seg_ids, positions = np.zeros(T, np.int32), np.zeros(T, np.int32)
+    valid = np.zeros(T, bool)
+    off = 0
+    for i, (row, start, n) in enumerate(segs):
+        # two chunks of one prompt are two segment rows over one table
+        tables[i] = tables[row]
+        seg_ids[off:off + n] = i
+        positions[off:off + n] = start + np.arange(n)
+        valid[off:off + n] = True
+        off += n
+    shape = (L, nkv, nb, hd, bs)
+    if int8:
+        kc, vc = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+                  for _ in range(2))
+        scales = tuple(jnp.asarray(rng.random((L, nkv, nb, bs)),
+                                   jnp.float32) for _ in range(2))
+    else:
+        kc, vc = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+                  for _ in range(2))
+        scales = ()
+    k, v = (jnp.asarray(rng.standard_normal((T, nkv, hd)), jnp.bfloat16)
+            for _ in range(2))
+    return ((kc, vc) + scales, k, v, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(seg_ids), jnp.asarray(positions),
+            jnp.asarray(valid))
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("name", sorted(_WRITE_CASES))
+def test_write_packed_kv_matches_flat_scatter(name, int8):
+    """The plane write puts into every cell of both pools (and both
+    scale planes) what the flat scatter put there, bit for bit, and
+    leaves every other cell outside the garbage block as it was: other
+    layers, the rest of a half-filled block, blocks the chunk does not
+    touch."""
+    caches, k, v, tables, seg_ids, positions, valid = _write_case(
+        name, int8)
+    kw = dict(k_scale=caches[2], v_scale=caches[3]) if int8 else {}
+    args = (caches[0], caches[1], 1, k, v, tables, seg_ids, positions,
+            valid)
+    ref = _flat_scatter(*args, **kw)
+    out = write_packed_kv(*args, **kw)
+    assert len(out) == len(ref) == len(caches)
+    for c, o, r in zip(caches, out, ref):
+        assert o.dtype == c.dtype and o.shape == c.shape
+        assert np.array_equal(np.asarray(o[:, :, 1:], np.float32),
+                              np.asarray(r[:, :, 1:], np.float32))
+        # something was written, and only into layer 1
+        assert not np.array_equal(np.asarray(o[1], np.float32),
+                                  np.asarray(c[1], np.float32))
+        assert np.array_equal(np.asarray(o[0], np.float32),
+                              np.asarray(c[0], np.float32))
+
+
+def _slot(start, n, bs, draft=0):
+    """What the two planners read of an engine slot: a prompt of which
+    `start` tokens are in the cache and `n` are left (prefill), or a
+    context of `start` tokens (spec verify)."""
+    from types import SimpleNamespace as NS
+
+    return NS(prompt_len=start + n, prefill_pos=start, ctx_len=start,
+              seq=NS(tokens=list(range(1, start + n + 1))), last_token=7,
+              block_table=np.arange(1, 65, dtype=np.int32) + 64 * draft,
+              sampling_seed=0, lora_idx=0,
+              request=NS(sampling=NS(temperature=0.0, top_k=0, top_p=1.0)))
+
+
+# name -> (planner, block size, [(tokens cached, tokens to run), ...],
+# planes the stream starts, planes the plan holds)
+_PLANNER_CASES = {
+    # every row starts in a block's last column and ends in another's
+    # first, and the stream fills its bucket: the plan is full
+    "prefill-full-plan": ("prefill", 16, [(15, 18), (15, 18), (15, 18),
+                                          (15, 10)], 11, 11),
+    "prefill-three-rows-of-four": ("prefill", 16, [(0, 40), (21, 3),
+                                                   (37, 6)], 5, 11),
+    "prefill-one-token": ("prefill", 16, [(31, 1)], 1, 2),
+    "prefill-doc-chunk": ("prefill", 128, [(2048 + 100, 2048)], 17, 17),
+    "prefill-block-128-chat": ("prefill", 128, [(0, 320), (127, 130),
+                                                (640, 62)], 7, 11),
+    "spec-full-plan": ("spec", 16, [(15, 2)] * 8, 16, 16),
+    "spec-three-rows-of-four": ("spec", 16, [(14, 5), (9, 5), (30, 5)],
+                                5, 8),
+    "spec-block-128": ("spec", 128, [(127, 4), (300, 4)], 3, 4),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PLANNER_CASES))
+def test_planners_streams_fit_the_write_plan(name):
+    """The write plan's static size holds for what the two planners
+    really hand the program, at their bucketed stream length and row
+    count: every token of the stream has its column in a plane, none is
+    dropped, and each plane is the block its tokens' table names."""
+    from dynamo_tpu.engine.prefill import plan_packed_prefill
+    from dynamo_tpu.ops.packed_prefill import plan_packed_write
+    from dynamo_tpu.spec.verify import plan_spec_verify
+
+    planner, bs, rows, want_used, want_planes = _PLANNER_CASES[name]
+    slots = [_slot(start, n, bs, draft=i)
+             for i, (start, n) in enumerate(rows)]
+    if planner == "prefill":
+        a = plan_packed_prefill(
+            slots, sum(n for _, n in rows), block_size=bs,
+            max_blocks_per_seq=64, min_bucket=16, with_lora=False).arrays
+    else:
+        a = plan_spec_verify(
+            [(s, [3] * (n - 1)) for s, (_, n) in zip(slots, rows)],
+            block_size=bs, max_blocks_per_seq=64).arrays
+    T = len(a["toks"])
+    blocks, src, used = (np.asarray(x) for x in plan_packed_write(
+        *(jnp.asarray(a[k]) for k in ("tables", "seg_ids", "positions",
+                                      "valid")), bs))
+    assert (int(used), len(blocks)) == (want_used, want_planes)
+    real = np.flatnonzero(a["valid"])
+    assert sorted(src[src < T]) == real.tolist()
+    plane, col = np.nonzero(src < T)
+    tok = src[plane, col]
+    assert (plane < used).all()
+    assert np.array_equal(col, a["positions"][tok] % bs)
+    assert np.array_equal(
+        blocks[plane],
+        a["tables"][a["seg_ids"][tok], a["positions"][tok] // bs])
+
+
+@pytest.mark.parametrize("seg_ids,positions,valid", [
+    ([0, 1, 0, 0], [0, 0, 1, 0], [1, 1, 1, 0]),      # a row in two runs
+    ([0, 0, 1, 0], [3, 5, 0, 0], [1, 1, 1, 0]),      # a gap in a row
+    ([0, 0, 1, 0], [4, 3, 0, 0], [1, 1, 1, 0]),      # a row backwards
+    ([1, 1, 0, 0], [3, 4, 0, 0], [1, 1, 1, 0]),      # rows out of order
+    ([0, 0, 1, 0], [3, 4, 0, 0], [1, 0, 1, 1]),      # padding inside
+    ([0, 0, 2, 0], [3, 4, 0, 0], [1, 1, 1, 0]),      # a row past the table
+], ids=["two-runs", "gap", "backwards", "rows-out-of-order",
+        "padding-inside", "row-past-the-table"])
+def test_packed_stream_outside_its_contract_is_refused(seg_ids, positions,
+                                                       valid):
+    """The plane write, unlike the scatter it replaced, relies on each
+    row being one run at consecutive positions: the host check the
+    planners make refuses a stream that is not, where the device would
+    drop its columns without a word."""
+    from dynamo_tpu.ops.packed_prefill import check_packed_stream
+
+    check_packed_stream(np.array([0, 0, 1, 0]), np.array([3, 4, 0, 0]),
+                        np.array([1, 1, 1, 0], bool), 2)
+    with pytest.raises(ValueError, match="outside its contract"):
+        check_packed_stream(np.array(seg_ids), np.array(positions),
+                            np.array(valid, bool), 2)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["fp32", "int8"])
+def test_prefill_packed_logits_match_the_scatter_program(monkeypatch, int8):
+    """`prefill_packed` end to end against the program it replaces (flat
+    scatter, a layer sliced out of the pool before each gather): same
+    logits, same cache, over two dispatches of which the second
+    continues the first one's half-filled blocks."""
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.ops import packed_prefill
+    from dynamo_tpu.ops.paged_attention import _gather_ctx
+
+    cfg = llama.LlamaConfig(
+        name="tiny32", vocab_size=256, d_model=64, n_layers=2, n_heads=4,
+        n_kv_heads=2, head_dim=16, ffn_dim=128, dtype=jnp.float32)
+    params = llama.init_params(cfg, jax.random.PRNGKey(1))
+    bs, nb, mb, S, T = 16, 13, 4, 2, 64
+    rng = np.random.default_rng(11)
+    tables = jnp.asarray(
+        (1 + rng.permutation(nb - 1))[:S * mb].reshape(S, mb), jnp.int32)
+    toks = jnp.asarray(rng.integers(1, cfg.vocab_size, T), jnp.int32)
+    shape = (cfg.n_layers, cfg.n_kv_heads, nb, cfg.head_dim, bs)
+
+    def run():
+        kv = tuple(jnp.zeros(shape, jnp.int8 if int8 else cfg.dtype)
+                   for _ in range(2))
+        if int8:
+            kv += tuple(jnp.zeros(shape[:3] + (bs,), jnp.float32)
+                        for _ in range(2))
+        logits = []
+        for starts, lens in (((0, 0), (37, 20)), ((37, 20), (9, 27))):
+            pos, seg = np.zeros(T, np.int32), np.zeros(T, np.int32)
+            val, last, off = np.zeros(T, bool), np.zeros(S, np.int32), 0
+            for i, (st, n) in enumerate(zip(starts, lens)):
+                pos[off:off + n] = st + np.arange(n)
+                seg[off:off + n] = i
+                val[off:off + n] = True
+                last[i] = off + n - 1
+                off += n
+            lg, kv = llama.prefill_packed(
+                params, cfg, kv, toks, jnp.asarray(pos),
+                jnp.asarray(seg), tables, jnp.asarray(last),
+                jnp.asarray(val))
+            logits.append(np.asarray(lg))
+        return logits, kv
+
+    new_logits, new_kv = run()
+    monkeypatch.setattr(llama, "write_packed_kv", _flat_scatter)
+    monkeypatch.setattr(packed_prefill, "_gather_blocks", _gather_ctx)
+    old_logits, old_kv = run()
+    for a, b in zip(new_logits, old_logits):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+    for a, b in zip(new_kv, old_kv):
+        assert np.array_equal(np.asarray(a[:, :, 1:]),
+                              np.asarray(b[:, :, 1:]))
+
+
+# ---------------------------------------------------------------------------
 # decode kernel: in-kernel int8 dequant
 # ---------------------------------------------------------------------------
 

@@ -146,6 +146,52 @@ def test_decode_multi_step_compiles_for_v5e(one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
+def _assert_pool_stays_where_it_lies(hlo, L, NKV, NB, HD):
+    """What a compiled program may do to a [L, NKV, NB, HD, BS] bf16
+    K/V pool: take it, write blocks of it in place, hand it on.  No
+    `copy` of it, no layer's slice of it, one layout from entry to exit,
+    and a fusion that gives out a pool-shaped value gives out the
+    in-place write (a loop fusion rooted in anything else, a bitcast
+    of its parameter say, is a copy of the whole pool by another name)."""
+    import re
+
+    pool = rf"bf16\[{L},{NKV},{NB},{HD},{BS}\]"
+    layer = rf"bf16\[(?:1,)?{NKV},{NB},{HD},{BS}\]"
+    made = re.findall(rf"= ({pool}|{layer})\S* (\w[\w-]*)\(", hlo)
+    # what may produce a pool-shaped value: the in-place write and the
+    # plumbing around it; nothing may produce one layer's slice
+    assert {op for _, op in made} <= {
+        "parameter", "get-tuple-element", "dynamic-update-slice",
+        "fusion", "while", "bitcast"}, sorted(set(made))
+    assert not [s for s, _ in made if not re.fullmatch(pool, s)]
+    # the pool keeps one layout from entry to exit
+    layouts = set(re.findall(rf"{pool}(\{{[\d,]+)", hlo))
+    assert layouts == {"{4,3,2,1,0"}, layouts
+    # computation name -> {instruction name: (result type, op)}, its root
+    comps, roots = {}, {}
+    for head, body in re.findall(
+            r"^(?:ENTRY )?%(\S+) \(.*?\{\n(.*?)^\}", hlo, re.M | re.S):
+        comps[head] = {
+            name: (typ, op, rest) for root, name, typ, op, rest in
+            re.findall(r"^ +(ROOT )?%(\S+) = (.*?) ([\w-]+)\((.*)$", body,
+                       re.M)}
+        roots[head] = re.search(r"^ +ROOT %(\S+) =", body, re.M).group(1)
+    fusions = [(typ, re.search(r"calls=%([^\s,]+)", rest).group(1))
+               for comp in comps.values()
+               for typ, op, rest in comp.values()
+               if op == "fusion" and re.search(pool, typ)]
+    assert fusions                     # the write is there, and fused
+    for _, called in fusions:
+        comp = comps[called]
+        typ, op, rest = comp[roots[called]]
+        outs = (re.findall(r"%([^\s,)]+)", rest.split(")")[0])
+                if op == "tuple" else [roots[called]])
+        for out in outs:
+            typ, op, _ = comp[out]
+            if re.search(pool, typ):
+                assert op == "dynamic-update-slice", (called, out, op)
+
+
 def test_decode_multi_reads_the_pool_where_it_lies(topo, one_chip):
     """`decode_multi` at Mistral-7B widths, the `mistral-7b.chat` worker's
     16 lanes x 20 blocks over a 320-block pool (4 layers), with `auto`
@@ -154,8 +200,6 @@ def test_decode_multi_reads_the_pool_where_it_lies(topo, one_chip):
     the step's weights: a relayout `copy` of the pool (XLA's layout for
     a column scatter differs from the kernel's), a per-layer slice of
     it materialized for the custom call, a copy on the way in or out."""
-    import re
-
     from dynamo_tpu.engine.core import JaxEngine
     from dynamo_tpu.ops.paged_attention import resolve_decode_impl
 
@@ -183,18 +227,54 @@ def test_decode_multi_reads_the_pool_where_it_lies(topo, one_chip):
         S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
         S((), i32)).compile().as_text()
     assert hlo.count("tpu_custom_call") == L
-    pool = rf"bf16\[{L},{NKV},{NB},{HD},{BS}\]"
-    layer = rf"bf16\[(?:1,)?{NKV},{NB},{HD},{BS}\]"
-    made = re.findall(rf"= ({pool}|{layer})\S* (\w[\w-]*)\(", hlo)
-    # what may produce a pool-shaped value: the in-place write and the
-    # plumbing around it; nothing may produce one layer's slice
-    assert {op for _, op in made} <= {
-        "parameter", "get-tuple-element", "dynamic-update-slice",
-        "fusion", "while", "bitcast"}, sorted(set(made))
-    assert not [s for s, _ in made if not re.fullmatch(pool, s)]
-    # the pool keeps one layout from entry to exit
-    layouts = set(re.findall(rf"{pool}(\{{[\d,]+)", hlo))
-    assert layouts == {"{4,3,2,1,0"}, layouts
+    _assert_pool_stays_where_it_lies(hlo, L, NKV, NB, HD)
+
+
+@pytest.mark.parametrize("T,MB", [(2048, 48), (2048, 16), (512, 4)])
+def test_prefill_packed_reads_the_pool_where_it_lies(one_chip, T, MB):
+    """`prefill_packed` at Mistral-7B widths (4 layers, 320 blocks, one
+    segment row), the twin of the decode test above, at the doc cell's
+    later chunks, its first chunk and a chat prompt (one flash step, no
+    `while`): the pool comes in, is written and is read in its resident
+    layout.  The flat column scatter cost 2 + 2 x layers relayout copies
+    of the whole pool a program, a {3,1,4,2,0} twin of it among the
+    temporaries, and one layer's slice of it materialized in every
+    flash step (10 / 10 / 4 copies, 1.81 GB at the first shape: PR 30)."""
+    from dynamo_tpu.engine.core import JaxEngine
+
+    L, NKV, HD, SEGS = 4, 8, 128, 1
+    cfg = llama.LlamaConfig(
+        name="mistral-7b-widths", vocab_size=32768, d_model=4096,
+        n_layers=L, n_heads=32, n_kv_heads=NKV, head_dim=HD,
+        ffn_dim=14336, rope_theta=1e6)
+    S = _sds(one_chip)
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+    fn = jax.jit(
+        partial(JaxEngine._prefill_packed_impl, llama, cfg, None),
+        donate_argnums=(1,))
+
+    def compiled(NB):
+        kv = tuple(S((L, NKV, NB, HD, BS), cfg.dtype) for _ in range(2))
+        return fn.lower(
+            params, kv, S((T,), i32), S((T,), i32), S((T,), i32),
+            S((SEGS, MB), i32), S((SEGS,), i32), S((T,), b1),
+            S((SEGS,), i32), S((SEGS,), f32), S((SEGS,), i32),
+            S((SEGS,), f32)).compile()
+
+    NB = 320
+    program = compiled(NB)
+    _assert_pool_stays_where_it_lies(program.as_text(), L, NKV, NB, HD)
+    # no twin of the pool among the temporaries: they do not grow with
+    # it.  The write that would make one is the same at every shape and
+    # the asserts above rule it out at each, so one shape pays for the
+    # second compile: the doc cell's later chunks, the largest
+    if (T, MB) == (2048, 48):
+        temp = program.memory_analysis().temp_size_in_bytes
+        grown = compiled(480).memory_analysis().temp_size_in_bytes
+        assert abs(grown - temp) < 50e6, (temp, grown)
 
 
 def test_hybrid_decode_and_prefill_compile_for_v5e(one_chip):
