@@ -84,28 +84,18 @@ def timeit(fn, n=8, warm=2):
 
 def epilogue_report(modes):
     """Engine-level fused-sampling A/B (--epilogue): serve B greedy
-    requests through a real JaxEngine per mode and report decode MBU
-    from the dynamo_engine_mbu{phase="decode"} gauge the worker itself
-    exports (planner/metrics.py export_engine_gauges), not a
-    bench-local byte model.  Greedy token streams must match between
+    requests through a real JaxEngine per mode and report served
+    tokens/s.  Greedy token streams must match between
     modes — the epilogue's byte-identity contract, re-proven here on
     the bench geometry."""
     import asyncio
 
     from dynamo_tpu.engine import EngineConfig, JaxEngine
-    from dynamo_tpu.planner.metrics import FpmWindow, export_engine_gauges
     from dynamo_tpu.protocols import (
         PreprocessedRequest,
         SamplingOptions,
         StopConditions,
     )
-
-    class _Gauges:
-        def __init__(self):
-            self.vals = {}
-
-        def set(self, name, value, doc="", **labels):
-            self.vals[(name, tuple(sorted(labels.items())))] = value
 
     max_blocks = CTX // BLOCK + 2
 
@@ -113,8 +103,7 @@ def epilogue_report(modes):
         eng = JaxEngine(EngineConfig(
             model=MODEL, block_size=BLOCK, num_blocks=B * max_blocks + 1,
             max_blocks_per_seq=max_blocks, max_num_seqs=B,
-            kv_cache_dtype=KV_DTYPE, sampling_epilogue=mode,
-            peak_hbm_gbps=HBM_GBPS, seed=0))
+            kv_cache_dtype=KV_DTYPE, sampling_epilogue=mode, seed=0))
         eng.warmup_decode()
         rng = np.random.default_rng(0)
         prompt = [int(t) for t in rng.integers(3, 255, 64)]
@@ -132,27 +121,15 @@ def epilogue_report(modes):
         t0 = time.perf_counter()
         outs = await asyncio.gather(*(one(i) for i in range(B)))
         dt = time.perf_counter() - t0
-        # post-hoc gauge replay: _phase_rates works from each record's
-        # own gap_s/xla_flops/xla_bytes fields, so draining eng.fpm
-        # into a wide-open window reproduces the worker's export
-        fw = FpmWindow(window_s=3600.0)
-        while eng.fpm:
-            fw.add(0, eng.fpm.popleft())
-        g = _Gauges()
-        export_engine_gauges(g, fw, peak_hbm_gbps=HBM_GBPS)
-        mbu = g.vals.get(("dynamo_engine_mbu", (("phase", "decode"),)), 0.0)
         await eng.close()
-        return outs, sum(len(t) for t in outs) / dt, mbu
+        return outs, sum(len(t) for t in outs) / dt
 
     print(f"epilogue A/B: {MODEL}, B={B}, {K} tokens/req, kv {KV_DTYPE}")
     results = {}
     for mode in modes:
-        outs, tok_s, mbu = asyncio.run(run_mode(mode))
-        results[mode] = (outs, tok_s, mbu)
-        print(f"  epilogue[{mode:5s}] {tok_s:9.1f} tok/s   decode MBU "
-              f"{mbu:5.3f}  (dynamo_engine_mbu{{phase=decode}} vs "
-              + (f"{HBM_GBPS:.0f} GB/s pin)" if HBM_GBPS
-                 else "no pin: not a TPU)"))
+        outs, tok_s = asyncio.run(run_mode(mode))
+        results[mode] = (outs, tok_s)
+        print(f"  epilogue[{mode:5s}] {tok_s:9.1f} tok/s")
     if "off" in results and "fused" in results:
         assert results["off"][0] == results["fused"][0], \
             "greedy token streams diverged between epilogue modes"
@@ -403,8 +380,7 @@ if __name__ == "__main__":
                    help="fused sampling epilogue A/B through a real "
                         "JaxEngine: on = fused only, off = reference "
                         "only, ab = both + greedy byte-identity check; "
-                        "reports decode MBU from the worker's "
-                        "dynamo_engine_mbu{phase} gauge")
+                        "reports served tokens/s")
     p.add_argument("--model", default=MODEL,
                    help="model preset for all phases (default llama-3b; "
                         "use tiny for a CPU smoke of --epilogue)")

@@ -14,10 +14,7 @@ chunked-prefill pipeline separately on the real chip:
               (S-fold attention FLOPs) or the Pallas tile-skip kernel
               (ops/pallas_packed_prefill.py) — and `--impl ab` runs
               BOTH and prints one JSON line with each variant's
-              hand-counted est_mfu AND the measured-program MFU from
-              the roofline plane (obs/compile_watch.xla_costs), so the
-              S-fold overhead elimination is visible as a FLOP-count
-              drop rather than just a wall-clock win.
+              hand-counted est_mfu and analytic attention FLOPs.
   batched     the legacy padded multi-row program (every row padded to
               the packed total — what packing replaces)
   single      S serial B=1 bucket programs (the pre-round-6 path)
@@ -49,7 +46,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from dynamo_tpu.models import llama            # noqa: E402
-from dynamo_tpu.obs.compile_watch import xla_costs  # noqa: E402
 from dynamo_tpu.ops import packed_prefill as pp  # noqa: E402
 from dynamo_tpu.runtime.device import (  # noqa: E402
     device_identity,
@@ -141,8 +137,7 @@ def main():
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
     # exclude the embedding lookup and an untied lm_head (logits run on
-    # last-token rows only) — the engine's _flops_per_token convention,
-    # so bench MFU and the FPM-stream MFU are comparable
+    # last-token rows only)
     skip = sum(params[k].size for k in ("embedding", "lm_head")
                if k in params)
     flops_per_tok = 2 * (n_params - skip)
@@ -216,14 +211,6 @@ def main():
             t = timeit(run_packed)
             est_flops = flops_per_tok * T
             est_mfu = mfu_of(est_flops, t)
-            # measured-program FLOPs from the roofline plane: XLA's own
-            # HLO cost analysis of the compiled program (for the Pallas
-            # variant the kernel's CostEstimate feeds this) — the
-            # number the S-fold elimination shows up in
-            costs = xla_costs(packed, (
-                params, state["kv"], dev["toks"], dev["positions"],
-                dev["seg_ids"], dev["tables"], dev["last_idx"],
-                dev["valid"]))
             row = {
                 "ms": round(t * 1e3, 3),
                 "tok_per_s": round(T / t, 1),
@@ -232,11 +219,6 @@ def main():
                 "attn_flops_analytic": attn_base
                 * (S if impl == "xla" else 1),
             }
-            if costs is not None:
-                row["xla_flops"] = costs["flops"]
-                row["xla_bytes"] = costs["bytes"]
-                xla_mfu = mfu_of(costs["flops"], t)
-                row["xla_mfu"] = xla_mfu and round(xla_mfu, 4)
             variants[impl] = row
             report(f"packed/{impl}", t, T, flops_per_tok * T)
         print(json.dumps({

@@ -42,17 +42,9 @@ def fresh_runtime():
     return DistributedRuntime(config=cfg, cluster_id=uuid.uuid4().hex)
 
 
-# simulated accelerator peaks: nonzero so the mocker's roofline gauges
-# (dynamo_engine_mfu/mbu) light up and land in the bench JSON
-SIM_PEAK_TFLOPS = 50.0
-SIM_PEAK_HBM_GBPS = 100.0
-
-
 def engine_args(role="both", overlap=True, fused=8, ledger=None):
     return MockEngineArgs(model_name="bench", block_size=BLOCK,
                           num_blocks=8192, speedup_ratio=1.0, role=role,
-                          peak_tflops=SIM_PEAK_TFLOPS,
-                          peak_hbm_gbps=SIM_PEAK_HBM_GBPS,
                           overlap_scheduling=overlap,
                           decode_fused_steps=fused,
                           kv_ledger=ledger)
@@ -151,7 +143,7 @@ class ForensicCapture:
     def tail_block(self, rt):
         """The bench JSON `tail` block: realized-overlap rate read back
         off the run's own metrics registry with the real parser (the
-        fleet/roofline-block idiom), plus the worst retained exemplar's
+        fleet/compiles-block idiom), plus the worst retained exemplar's
         exact phase partition — the reservoir IS the tail, so its worst
         entry is the p99+ autopsy."""
         if self.plane is None:
@@ -247,31 +239,25 @@ def collect_kv_ledger(workers):
     }}
 
 
-async def collect_roofline(rt):
-    """Scrape the run's worker gauges (one load-loop tick after the
-    replay) into the bench JSON's roofline block: per-phase MFU/MBU and
-    compile counts per program family — the same names a production
-    Prometheus would scrape, parsed with the same parser."""
+async def collect_compiles(rt):
+    """Scrape the run's worker counters (one load-loop tick after the
+    replay) into the bench JSON's compiles block: compile counts per
+    program family — the same names a production Prometheus would
+    scrape, parsed with the same parser."""
     from prometheus_client.parser import text_string_to_metric_families
 
     await asyncio.sleep(0.4)  # let the workers' 0.25s load loops tick
-    out = {"mfu": {}, "mbu": {}, "compiles": {}, "serving_compiles": {}}
+    out = {"total": {}, "serving": {}}
     for fam in text_string_to_metric_families(
             rt.metrics.render().decode()):
-        if fam.name == "dynamo_engine_mfu":
-            for s in fam.samples:
-                out["mfu"][s.labels.get("phase", "")] = round(s.value, 4)
-        elif fam.name == "dynamo_engine_mbu":
-            for s in fam.samples:
-                out["mbu"][s.labels.get("phase", "")] = round(s.value, 4)
-        elif fam.name in ("dynamo_engine_compiles",
-                          "dynamo_engine_serving_compiles"):
+        if fam.name in ("dynamo_engine_compiles",
+                        "dynamo_engine_serving_compiles"):
             # serving_compiles = compiles that landed with requests in
             # flight (obs/compile_watch.py): each one is a serving
             # stall, and the bench round's zero-mid-serving gate reads
             # this block
-            key_out = ("compiles" if fam.name == "dynamo_engine_compiles"
-                       else "serving_compiles")
+            key_out = ("total" if fam.name == "dynamo_engine_compiles"
+                       else "serving")
             for s in fam.samples:
                 if not s.name.endswith("_total"):
                     continue
@@ -303,7 +289,7 @@ async def bench_agg(rows, n_workers, args, overlap=True, label="agg",
         finally:
             stop.set()
             await sampler
-        roofline = await collect_roofline(rt)
+        compiles = await collect_compiles(rt)
     gap = rtrace.gap()
     fleet = await collect_fleet(rt, workers, peaks)
     fleet.update(collect_kv_ledger(workers))
@@ -312,7 +298,7 @@ async def bench_agg(rows, n_workers, args, overlap=True, label="agg",
     for w in workers:
         await w.close()
     await rt.shutdown()
-    return report, roofline, fleet, gap, rtrace.path, tail, cap
+    return report, compiles, fleet, gap, rtrace.path, tail, cap
 
 
 async def bench_disagg(rows, n_prefill, n_decode, args, overlap=True,
@@ -368,7 +354,7 @@ async def bench_disagg(rows, n_prefill, n_decode, args, overlap=True,
         finally:
             stop.set()
             await sampler
-        roofline = await collect_roofline(rt)
+        compiles = await collect_compiles(rt)
     gap = rtrace.gap()
     fleet = await collect_fleet(rt, prefills + decodes, peaks)
     fleet.update(collect_kv_ledger(prefills + decodes))
@@ -379,7 +365,7 @@ async def bench_disagg(rows, n_prefill, n_decode, args, overlap=True,
     for w in prefills + decodes:
         await w.close()
     await rt.shutdown()
-    return report, roofline, fleet, gap, rtrace.path, tail, cap
+    return report, compiles, fleet, gap, rtrace.path, tail, cap
 
 
 async def main():
@@ -473,10 +459,11 @@ async def main():
     GAP_KEYS = ("sched_overhead_frac", "enqueue_ahead_frac",
                 "device_wait_frac", "idle_frac", "cont_burst_frac")
 
-    def line(config, summary, roofline, fleet, gap, tail=None):
+    def line(config, summary, compiles, fleet, gap, tail=None):
         # stable bench JSON schema: the `slo` block mirrors the
         # frontend SLO plane's vocabulary (targets + goodput fraction),
-        # `roofline` the worker gauges, `fleet` the obs.fleet headline
+        # `compiles` the workers' compile counters (total and
+        # mid-serving, per family), `fleet` the obs.fleet headline
         # at peak (imbalance, straggler count, min KV headroom), and
         # `gap` the obs.report wall partition of this run's own engine
         # tracks — a scoreboard diff across rounds reads the same
@@ -501,7 +488,7 @@ async def main():
                             if total else None),
                 "good_rps": gp.get("good_rps"),
             },
-            "roofline": roofline,
+            "compiles": compiles,
             "fleet": fleet,
             "gap": {k: gap[k] for k in GAP_KEYS if k in gap},
             # tail-forensics block (obs/forensics.py via the replay's
@@ -523,7 +510,7 @@ async def main():
         off, *_rest_off, cap_off = await bench_agg(
             rows, args.workers, args, label="agg-kvledger-off",
             ledger=False)
-        on, _roof, fleet_on, _gap, _path, _tail, cap_on = await bench_agg(
+        on, _compiles, fleet_on, _gap, _path, _tail, cap_on = await bench_agg(
             rows, args.workers, args, label="agg-kvledger-on",
             ledger=True)
         s_off = off.summary(slo_ttft_s, slo_itl_s)
@@ -566,7 +553,7 @@ async def main():
         off, *_rest_off, cap_off = await bench_agg(
             rows, args.workers, args, label="agg-forensics-off",
             forensics=False)
-        on, _roof, _fleet, _gap, _path, tail, cap_on = await bench_agg(
+        on, _compiles, _fleet, _gap, _path, tail, cap_on = await bench_agg(
             rows, args.workers, args, label="agg-forensics-on",
             forensics=True)
         s_off = off.summary(slo_ttft_s, slo_itl_s)
@@ -600,18 +587,18 @@ async def main():
     for ov, tag in modes:
         suffix = f"-{tag}" if args.overlap == "ab" else ""
         label = f"agg-{args.workers}w{suffix}"
-        agg, roof, fleet, gap, path, tail, _cap = await bench_agg(
+        agg, compiles, fleet, gap, path, tail, _cap = await bench_agg(
             rows, args.workers, args, overlap=ov, label=label,
             forensics=forensics_on, ledger=ledger)
         trace_paths.append(path)
-        print(line(label, agg.summary(slo_ttft_s, slo_itl_s), roof,
+        print(line(label, agg.summary(slo_ttft_s, slo_itl_s), compiles,
                    fleet, gap, tail))
         label = f"disagg-{np_}p{nd}d{suffix}"
-        dis, roof, fleet, gap, path, tail, _cap = await bench_disagg(
+        dis, compiles, fleet, gap, path, tail, _cap = await bench_disagg(
             rows, np_, nd, args, overlap=ov, label=label,
             forensics=forensics_on, ledger=ledger)
         trace_paths.append(path)
-        print(line(label, dis.summary(slo_ttft_s, slo_itl_s), roof,
+        print(line(label, dis.summary(slo_ttft_s, slo_itl_s), compiles,
                    fleet, gap, tail))
 
     if args.trace_out:

@@ -178,8 +178,8 @@ def eval_serving(lines, enforced):
     # one driver line summarizes BOTH overlap modes: keep the overlap
     # row (the serving configuration) as the headline result and gate
     # on mid-serving compiles across every topology row
-    rows = [l for l in lines if "roofline" in l]
-    compiles = sum(sum(l["roofline"].get("serving_compiles", {}).values())
+    rows = [l for l in lines if "compiles" in l]
+    compiles = sum(sum(l["compiles"].get("serving", {}).values())
                    for l in rows)
     gates = [gate("zero_mid_serving_compiles", "== 0",
                   compiles if rows else None,

@@ -83,14 +83,6 @@ def build_args() -> argparse.ArgumentParser:
     p.add_argument("--no-packed-prefill", action="store_true",
                    help="disable packed chunked prefill (use the padded "
                         "per-row programs)")
-    p.add_argument("--peak-tflops", type=float,
-                   default=float(os.environ.get("DYN_PEAK_TFLOPS", "0")),
-                   help="accelerator dense-bf16 peak, for prefill-phase "
-                        "MFU in the FPM stream (v5e: 197); 0 = unknown")
-    p.add_argument("--peak-hbm-gbps", type=float,
-                   default=float(os.environ.get("DYN_PEAK_HBM_GBPS", "0")),
-                   help="accelerator peak HBM bandwidth GB/s, for the "
-                        "roofline MBU gauges (v5e: 819); 0 = unknown")
     p.add_argument("--host-cache-blocks", type=int, default=0,
                    help="G2 host-DRAM KV cache capacity (blocks); 0 off")
     p.add_argument("--offload-watermark-blocks", type=int, default=0,
@@ -152,10 +144,6 @@ def build_args() -> argparse.ArgumentParser:
                         "dispatch -> block -> emit) instead of the "
                         "overlapped default; greedy output is "
                         "byte-identical, served throughput is not")
-    p.add_argument("--no-adaptive-fusion", action="store_true",
-                   help="always dispatch full decode_fused_steps bursts "
-                        "when no prefill is pending, instead of ramping "
-                        "the burst size up a decode-only stretch")
     p.add_argument("--slo-yield-burn", type=float, default=1.0,
                    help="SLA-aware admission: prefill chunks yield "
                         "budget to decode while the frontend-published "
@@ -194,8 +182,6 @@ async def main() -> None:
         attn_impl=args.attn_impl,
         packed_attn_impl=args.packed_attn_impl,
         sampling_epilogue=args.sampling_epilogue,
-        peak_tflops=args.peak_tflops,
-        peak_hbm_gbps=args.peak_hbm_gbps,
         host_cache_blocks=args.host_cache_blocks,
         offload_watermark_blocks=args.offload_watermark_blocks,
         disk_cache_dir=args.disk_cache_dir or None,
@@ -216,7 +202,6 @@ async def main() -> None:
         spec_draft_model=args.spec_draft_model,
         spec_draft_model_path=args.spec_draft_model_path,
         overlap_scheduling=not args.no_overlap_scheduling,
-        decode_fuse_adaptive=not args.no_adaptive_fusion,
         slo_yield_burn=args.slo_yield_burn,
     )
     rt = await DistributedRuntime.detached().start()

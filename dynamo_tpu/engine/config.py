@@ -88,15 +88,6 @@ class EngineConfig:
     # in tests/test_overlap.py asserts it, including cancellation, chaos
     # and drain).
     overlap_scheduling: bool = True
-    # adaptive decode fusion: in a decode-only stretch the burst size
-    # ramps INTERLEAVE_BURST -> 2x -> ... -> decode_fused_steps (one
-    # compiled variant per ladder rung, all warmed by warmup_decode) and
-    # de-fuses back to the interleave burst the step a new arrival,
-    # cancellation, or pending prefill chunk appears — so steady-state
-    # throughput gets the full fusion while TTFT under arrivals is
-    # bounded by a short burst.  False = the pre-adaptive policy (full
-    # decode_fused_steps whenever no prefill/admission work is pending).
-    decode_fuse_adaptive: bool = True
     # SLA-aware admission (closes the PR 1 mixed-scheduling loop against
     # the PR 7 SLO plane): when the frontend-published error-budget burn
     # rate (obs/slo.py; worst window, fed to the engine by the worker's
@@ -156,15 +147,6 @@ class EngineConfig:
     # with a warning, like the int8-KV precedent, and the worker MDC
     # advertises the EFFECTIVE mode.
     sampling_epilogue: str = "off"
-    # accelerator peak (dense bf16) TFLOP/s, for prefill-phase MFU in the
-    # FPM stream (v5e: 197).  0 = unknown; MFU omitted from records.
-    peak_tflops: float = 0.0
-    # accelerator peak HBM bandwidth in GB/s, for the roofline plane's
-    # memory-bandwidth-utilization gauges (v5e: 819).  The cost-analysis
-    # bytes-accessed of each compiled program (obs/compile_watch.py)
-    # over the dispatch gap gives MBU — the binding axis for decode,
-    # which MFU alone cannot show.  0 = unknown; MBU gauges omitted.
-    peak_hbm_gbps: float = 0.0
 
     # speculative decoding (spec/): emit more than one ACCEPTED token per
     # weight/KV pass once decode is memory-bandwidth-bound.  "ngram" is

@@ -435,16 +435,14 @@ class JaxEngine:
         self._kv_shardings = tuple(
             NamedSharding(self.mesh, spec) for spec in kv_specs)
 
-        # compile watchdog + roofline (obs/compile_watch.py) is
-        # constructed FIRST so every jit below is a WatchedProgram from
-        # the moment it exists — a compile (warmup or the mid-serving
-        # kind the guided fork measured at 8-14s) is counted, timed,
-        # span-recorded, and costed with XLA's own cost_analysis
-        # (per-program FLOPs/bytes feed the decode/spec-verify/
-        # packed-prefill MFU+MBU gauges).  Wrap-at-definition is the
-        # DYN001 lint invariant: a raw jax.jit that dispatches unwatched
-        # cannot be written here without a suppression.  Wrapper
-        # overhead per dispatch is two C++ cache-size reads.
+        # compile watchdog (obs/compile_watch.py) is constructed FIRST
+        # so every jit below is a WatchedProgram from the moment it
+        # exists — a compile (warmup or the mid-serving kind the guided
+        # fork measured at 8-14s) is counted, timed and span-recorded.
+        # Wrap-at-definition is the DYN001 lint invariant: a raw jax.jit
+        # that dispatches unwatched cannot be written here without a
+        # suppression.  Wrapper overhead per dispatch is two C++
+        # cache-size reads.
         from ..obs.compile_watch import CompileWatch
 
         # timeline tracing (obs/): steps run on whatever pool thread
@@ -576,19 +574,6 @@ class JaxEngine:
         # synchronously and must skip the pipelined decode dispatch)
         self._specced: frozenset = frozenset()
         self._fpm_last_spec_t = 0.0
-        # prefill-phase MFU bookkeeping for the FPM stream: dense matmul
-        # FLOPs per prompt token ~ 2 x params, excluding the embedding
-        # (a lookup) and an untied lm_head (logits run only on the few
-        # last-token rows, not the whole stream).  Attention FLOPs are
-        # also excluded — a lower bound that understates long-context
-        # chunks.
-        n_params = sum(int(np.prod(x.shape))
-                       for x in jax.tree_util.tree_leaves(self.params))
-        skip = (sum(int(np.prod(self.params[k].shape))
-                    for k in ("embedding", "lm_head")
-                    if k in self.params)
-                if isinstance(self.params, dict) else 0)
-        self._flops_per_token = 2.0 * max(n_params - skip, 1)
         # routed-expert layers as the host knows them, for the moe_*
         # counters: (layers that route, picks a token, experts held a
         # layer — the `moe_w_*` stacks' length, the router may be wider)
@@ -718,14 +703,6 @@ class JaxEngine:
         self.fpm: deque = deque(maxlen=4096)
         self._fpm_last_decode_t = 0.0
         self._fpm_last_prefill_t = 0.0
-        # roofline attrs handed from a dispatch path to the span that
-        # wraps it (tracing-on only; consumed exactly once per dispatch)
-        self._obs_dispatch_extra: Optional[dict] = None
-        self._obs_decode_extra: Optional[dict] = None
-        # time of the last BLOCKING device fetch (np.asarray round trip):
-        # dispatch-gap MFU is only meaningful when a sync landed inside
-        # the gap — pure async enqueues measure host time, not compute
-        self._fpm_sync_t = 0.0
         # overlapped scheduling (config.overlap_scheduling): deferred
         # prefill first-token readbacks — each entry holds one dispatch's
         # sampled-token device array plus the completing slots awaiting
@@ -2351,13 +2328,11 @@ class JaxEngine:
         if not pslots:
             return
         with self._phase("prefill_dispatch", rows=len(pslots)) as ph:
-            try:
-                self._prefill_dispatch(pslots)
-            finally:
-                extra, self._obs_dispatch_extra = \
-                    self._obs_dispatch_extra, None
-                if extra:
-                    ph.set(**extra)
+            before = self.metrics["prefill_tokens"]
+            self._prefill_dispatch(pslots)
+            # obs.report's fleet_prefix_cache prices a token of prefill
+            # from this span
+            ph.set(tokens=self.metrics["prefill_tokens"] - before)
 
     def _prefill_dispatch(self, pslots) -> None:
         """Route this step's prefilling slots to one program (see
@@ -2461,8 +2436,7 @@ class JaxEngine:
         self._fpm_prefill(
             rows=n, tokens=int(sum(chunks)), bucket=bucket,
             completing=sum(1 for s, ch in zip(pslots, chunks)
-                           if s.prefill_pos + ch >= s.prompt_len),
-            xla=self._jit_prefill_batched.cost(Bp * bucket))
+                           if s.prefill_pos + ch >= s.prompt_len))
         # the sampled tokens matter ONLY when some row completes its
         # prompt this chunk (np.asarray is a blocking device round trip;
         # intermediate chunks discard the sample, so they never pay it);
@@ -2478,39 +2452,21 @@ class JaxEngine:
             self._finish_prefill_chunk(slot, chunk, first)
 
     def _fpm_prefill(self, rows: int, tokens: int, bucket: int,
-                     packed: bool = False, completing: int = 0,
-                     xla: Optional[dict] = None) -> None:
+                     packed: bool = False, completing: int = 0) -> None:
         """One FPM record per prefill program — the inputs the SLA
-        planner's FpmObserver turns into prefill-phase MFU and pressure.
+        planner's FpmObserver turns into prefill rate and pressure.
 
         Beyond (rows, tokens, bucket) the record carries:
 
         - gap_s: dispatch-to-dispatch gap (the decode records'
           convention).  The gap spans everything between two prefill
           dispatches — interleaved decode steps included — and jit
-          dispatch is async, so it only reflects device time when a
-          blocking fetch landed inside it.
-        - flops: dense-matmul estimate for the chunk.  When the config
-          pins the platform peak (peak_tflops) AND a device sync fell
-          inside the gap, the record carries the derived mfu directly,
-          clamped to 1.0; it is an approximation biased LOW by
-          interleaved decode work and absent entirely on sync-free
-          intervals (timing each chunk exactly would need a blocking
-          fetch per dispatch, the round trip this path exists to
-          avoid — bench_prefill_phases.py measures the unbiased
-          number).
+          dispatch is async: host time, not device time.
         - queue_depth: waiting + still-prefilling slots, MINUS the
           `completing` slots whose prompt this very dispatch finishes —
           the burst's final record must read 0, or the observer reports
           phantom pressure for a full window after the fleet goes
-          idle.
-        - xla: the dispatched program's cost_analysis entry from the
-          compile watchdog (obs/compile_watch.py), when XLA has a cost
-          model for it.  Rides the record as xla_flops/xla_bytes (the
-          roofline gauges' inputs) and REPLACES the hand-counted dense
-          estimate in the derived mfu — the measured program includes
-          attention and the real logit rows, which the estimate
-          excludes by construction."""
+          idle."""
         now = time.monotonic()
         gap = (now - self._fpm_last_prefill_t
                if self._fpm_last_prefill_t else 0.0)
@@ -2522,42 +2478,11 @@ class JaxEngine:
         depth = max(0, len(self.waiting) + sum(
             1 for s in self._slots if s is not None and s.prefilling)
             - completing)
-        flops = tokens * self._flops_per_token
-        synced = self._fpm_sync_t >= self._fpm_last_prefill_t
-        rec = {
+        self.fpm.append({
             "t": now, "kind": "prefill", "rows": rows, "tokens": tokens,
             "bucket": bucket, "packed": packed, "gap_s": gap,
-            "flops": flops, "queue_depth": depth, "synced": synced,
-        }
-        if xla is not None:
-            rec["xla_flops"] = xla["flops"]
-            rec["xla_bytes"] = xla["bytes"]
-        if gap > 0.0 and self.config.peak_tflops > 0.0 and synced:
-            # only when a blocking device fetch landed inside the gap:
-            # jit dispatch is async, so a sync-free gap measures host
-            # enqueue time, not chunk compute, and flops/gap would
-            # overstate MFU without bound.  Clamped at 1.0 — a sync near
-            # the interval's start can still leave gap short of the full
-            # device time.  `mfu` prefers the measured program's cost
-            # analysis (it includes attention + the real logit rows AND
-            # the padding the device actually executes); `est_mfu` keeps
-            # the hand count so divergence between the two is visible —
-            # obs.report's roofline table prints them side by side.
-            est = min(flops / gap / (self.config.peak_tflops * 1e12), 1.0)
-            rec["est_mfu"] = est
-            rec["mfu"] = (min(xla["flops"] / gap
-                              / (self.config.peak_tflops * 1e12), 1.0)
-                          if xla is not None else est)
-        self.fpm.append(rec)
-        if obs.enabled():
-            # hand the record's roofline-relevant fields to the
-            # enclosing prefill_dispatch span (_prefill_step owns it and
-            # cannot see this path's locals); consumed exactly once
-            self._obs_dispatch_extra = {
-                k: rec[k] for k in ("tokens", "bucket", "gap_s", "synced",
-                                    "mfu", "est_mfu", "xla_flops",
-                                    "xla_bytes")
-                if k in rec}
+            "queue_depth": depth,
+        })
         self._fpm_last_prefill_t = now
 
     def _prefill_packed_step(self, pslots, budget: int) -> None:
@@ -2565,7 +2490,7 @@ class JaxEngine:
         budget across the prefilling slots and concatenates their chunks
         (including prefix-cache-hit tails, which start at prefill_pos >
         0) into a single padding-free stream — one program, one shape
-        family, no per-row bucket padding (the round-5 0.098-MFU fix)."""
+        family, no per-row bucket padding."""
         from .prefill import plan_packed_prefill
 
         c = self.config
@@ -2595,8 +2520,7 @@ class JaxEngine:
             rows=len(plan.slots), tokens=plan.tokens, bucket=plan.bucket,
             packed=True,
             completing=sum(1 for s, ch in zip(plan.slots, plan.chunks)
-                           if s.prefill_pos + ch >= s.prompt_len),
-            xla=self._jit_prefill_packed.cost(plan.bucket))
+                           if s.prefill_pos + ch >= s.prompt_len))
         # token fetch only when some segment completes its prompt this
         # chunk (see _prefill_step: intermediate chunks discard the
         # sample); overlap mode defers the readback one step
@@ -2669,8 +2593,7 @@ class JaxEngine:
         )
         self._fpm_prefill(
             rows=1, tokens=int(chunk), bucket=bucket,
-            completing=int(slot.prefill_pos + chunk >= slot.prompt_len),
-            xla=self._jit_prefill.cost(bucket))
+            completing=int(slot.prefill_pos + chunk >= slot.prompt_len))
         # token fetch only on the completing chunk (see _prefill_step:
         # intermediate chunks discard the sample); deferred in overlap
         if pos + chunk >= slot.prompt_len \
@@ -2764,7 +2687,6 @@ class JaxEngine:
             return None
         with self._phase("device_wait", what="prefill_first"):
             arr = np.asarray(tok)
-        self._fpm_sync_t = time.monotonic()
         return arr
 
     def _flush_pending_first(self) -> None:
@@ -2779,7 +2701,6 @@ class JaxEngine:
         pending, self._pending_first = self._pending_first, []
         with self._phase("device_wait", what="prefill_first"):
             arrs = [np.asarray(e["tok"]) for e in pending]
-        self._fpm_sync_t = time.monotonic()
         with self._phase("emit", what="prefill_first"):
             for e, arr in zip(pending, arrs):
                 flat = np.atleast_1d(arr)
@@ -3242,7 +3163,6 @@ class JaxEngine:
             ids = np.asarray(ids)
             vals = np.asarray(vals)
             lse = np.asarray(lse)
-        self._fpm_sync_t = time.monotonic()
         from .sampler import spec_accept_tokens
 
         proposed_total = accepted_total = 0
@@ -3299,19 +3219,12 @@ class JaxEngine:
         if gap > 1.0:
             gap = 0.0  # idle stretch, not verify latency: mark unknown
         # one FPM record per verify dispatch: the acceptance-rate input
-        # FpmObserver.spec_acceptance aggregates for the SLA planner;
-        # xla_* (cost analysis of the packed verify program) feeds the
-        # spec_verify roofline gauges
-        rec = {
+        # FpmObserver.spec_acceptance aggregates for the SLA planner
+        self.fpm.append({
             "t": now, "kind": "spec_verify", "lanes": len(plan.rows),
             "proposed": proposed_total, "accepted": accepted_total,
             "tokens": plan.tokens, "gap_s": gap,
-        }
-        vcost = self._jit_spec_verify.cost(len(a["toks"]))
-        if vcost is not None:
-            rec["xla_flops"] = vcost["flops"]
-            rec["xla_bytes"] = vcost["bytes"]
-        self.fpm.append(rec)
+        })
         self._fpm_last_spec_t = now
         return True
 
@@ -3408,9 +3321,7 @@ class JaxEngine:
         burst and resets the ramp.  In a decode-only stretch the burst
         ramps up the fusion ladder one rung per step, so the steps right
         after an arrival stay short (TTFT) while steady state reaches
-        full decode_fused_steps within log2 steps (throughput).
-        decode_fuse_adaptive=False restores the pre-adaptive jump
-        straight to decode_fused_steps."""
+        full decode_fused_steps within log2 steps (throughput)."""
         c = self.config
         if self._jit_decode_multi is None:
             return 1
@@ -3419,8 +3330,6 @@ class JaxEngine:
                        for s in self._slots)):
             self._decode_only_run = 0
             return min(self.INTERLEAVE_BURST, c.decode_fused_steps)
-        if not c.decode_fuse_adaptive:
-            return c.decode_fused_steps
         k = min(self.INTERLEAVE_BURST << self._decode_only_run,
                 c.decode_fused_steps)
         self._decode_only_run = min(self._decode_only_run + 1, 16)
@@ -3534,8 +3443,7 @@ class JaxEngine:
             lanes[s.index] = (self._seq_id(s), s.epoch)
             self._chain_owner[s.index] = lanes[s.index]
         self._inflight.append({"burst": burst, "k": k, "lanes": lanes})
-        extra, self._obs_decode_extra = self._obs_decode_extra, None
-        ph.set(cont=cont_burst, k=k, lanes=len(active), **(extra or {}))
+        ph.set(cont=cont_burst, k=k, lanes=len(active))
         return True
 
     def _build_burst(self, active, k: int):
@@ -3987,7 +3895,7 @@ class JaxEngine:
                if self._fpm_last_decode_t else 0.0)
         if gap > 1.0:
             gap = 0.0  # idle period, not decode latency: mark unknown
-        rec = {
+        self.fpm.append({
             "t": now, "kind": "decode", "k": k,
             "lanes": sum(1 for s in self._slots
                          if s is not None and not s.prefilling),
@@ -3995,18 +3903,7 @@ class JaxEngine:
             # IS the burst's wall time (k tokens per lane per gap);
             # 0.0 = unknown (first burst after an idle stretch)
             "gap_s": gap,
-        }
-        # roofline: the burst program's own cost analysis (fixed shape —
-        # one entry per decode variant); covers all k fused steps
-        dcost = fn.cost()
-        if dcost is not None:
-            rec["xla_flops"] = dcost["flops"]
-            rec["xla_bytes"] = dcost["bytes"]
-        self.fpm.append(rec)
-        if obs.enabled():
-            self._obs_decode_extra = {
-                key: rec[key] for key in ("gap_s", "xla_flops",
-                                          "xla_bytes") if key in rec}
+        })
         self._fpm_last_decode_t = now
         return burst
 
@@ -4054,7 +3951,6 @@ class JaxEngine:
         e = self._inflight.popleft()
         with self._phase("device_wait", k=e["k"], what="burst_fetch"):
             arr = np.asarray(e["burst"])  # [k (+ counters), B]
-        self._fpm_sync_t = time.monotonic()
         if self._kv_counters:
             # the rows under the tokens: running int32 totals on the
             # device; what they grew by (modulo the wrap) is added

@@ -808,18 +808,15 @@ class JaxEngineWorker:
                 fw.add(self.served.instance_id, rec)
             # compile watchdog records -> per-family compile histogram,
             # then the shared gauge surface (planner/metrics.py
-            # export_engine_gauges): headline FPM aggregates, per-phase
-            # roofline MFU/MBU from XLA cost analysis over dispatch
-            # gaps, KV occupancy per tier — ONE definition for both
-            # workers, so mocker /metrics parity can't drift
+            # export_engine_gauges): headline FPM aggregates, KV
+            # occupancy per tier — ONE definition for both workers, so
+            # mocker /metrics parity can't drift
             from ..obs.compile_watch import observe_compile_records
             from ..planner.metrics import export_engine_gauges
 
             observe_compile_records(m, steps)
             export_engine_gauges(
-                m, fw, peak_tflops=self.config.peak_tflops,
-                peak_hbm_gbps=self.config.peak_hbm_gbps,
-                occupancy=self.engine.kv_occupancy(),
+                m, fw, occupancy=self.engine.kv_occupancy(),
                 kv_ledger=self.engine.kv_ledger)
             if steps:
                 try:
@@ -833,18 +830,15 @@ class JaxEngineWorker:
             # engine's parked-KV TTL)
             self._chunk_refs.sweep(self.engine.parked_ttl_s)
             # per-tier onboard costs for the router's tiered selector:
-            # measured prefill rate (roofline plane) over the cache's
-            # per-block payload bytes.  Recomputed each tick — the
-            # measured rate converges as the window fills; the selector
-            # falls back to defaults until the first publish.
-            flops_rate, _bytes_rate = fw._phase_rates("prefill")
+            # this worker's prefill token rate against the cache's
+            # per-block payload bytes.  Recomputed each tick — the rate
+            # converges as the window fills; the selector falls back to
+            # defaults until the first publish.
             tok_rate = fw.prefill_tokens_per_s()
-            if flops_rate > 0.0 and tok_rate > 0.0:
+            if tok_rate > 0.0:
                 tier_costs = compute_tier_costs(
-                    prefill_flops_per_s=flops_rate,
-                    flops_per_token=flops_rate / tok_rate,
-                    bytes_per_block=self.engine.kv_block_bytes(),
-                    block_tokens=self.config.block_size)
+                    tok_rate, self.engine.kv_block_bytes(),
+                    self.config.block_size)
             # degraded-mode plane: fold circuit-breaker states into the
             # advertised costs (a non-closed tier is priced AT recompute
             # so the selector stops steering traffic toward its blocks)

@@ -45,15 +45,8 @@ def build_args() -> argparse.ArgumentParser:
                         "block pool to what the same HBM budget holds "
                         "at int8 bytes-per-block (~1.94x blocks) and is "
                         "advertised in the MDC like the JAX worker")
-    # simulated device-performance plane (obs/): compile records +
-    # roofline fields under the exact names the JAX worker exports
-    p.add_argument("--peak-tflops", type=float, default=0.0,
-                   help="simulated accelerator peak TFLOP/s: prefill "
-                        "FPM records carry mfu and the roofline MFU "
-                        "gauges light up (0 = off)")
-    p.add_argument("--peak-hbm-gbps", type=float, default=0.0,
-                   help="simulated peak HBM GB/s for the roofline MBU "
-                        "gauges (0 = off)")
+    # simulated compile records (obs/compile_watch.py), under the
+    # exact names the JAX worker exports
     p.add_argument("--sim-compile-s", type=float, default=0.002,
                    help="simulated per-family compile duration emitted "
                         "as compile FPM records (0 = off)")
@@ -112,8 +105,6 @@ async def main() -> None:
         speculative=({"k": args.spec_k, "acceptance": args.spec_acceptance}
                      if args.spec_k > 0 else None),
         kv_cache_dtype=args.kv_cache_dtype,
-        peak_tflops=args.peak_tflops,
-        peak_hbm_gbps=args.peak_hbm_gbps,
         sim_compile_s=args.sim_compile_s,
         sim_recompile_every=args.sim_recompile_every,
         fail_after_tokens=args.fail_after_tokens,

@@ -130,11 +130,6 @@ class MockEngineArgs:
     # steps (serving=True) — drives the planner's recompile-storm diag
     # and the flight-recorder path in tests; 0 = off
     sim_recompile_every: int = 0
-    # simulated accelerator peaks: when > 0, prefill/decode FPM records
-    # carry xla_flops/xla_bytes (+ mfu) from the simulated cost model,
-    # so the worker's roofline MFU/MBU gauges light up without a TPU
-    peak_tflops: float = 0.0
-    peak_hbm_gbps: float = 0.0
     # -- fault modes (chaos plane satellites) -----------------------------
     # die (error every stream with the migratable DEATH_ERROR marker,
     # reject everything after) once this many decode tokens have been
@@ -264,12 +259,6 @@ class MockEngine:
         self._decode_run = 0
         self._last_decode_key = None
 
-    # simulated cost model: nominal FLOPs / HBM bytes per token — the
-    # values only need to be self-consistent (gauge math and record
-    # plumbing are what tier-1 asserts, not a real chip's numbers)
-    SIM_FLOPS_PER_TOKEN = 2e9
-    SIM_BYTES_PER_TOKEN = 1e6
-
     def _sim_compile(self, family: str, tokens: int,
                      serving: bool = False) -> None:
         """Emit one compile FPM record (obs/compile_watch.py shape) the
@@ -285,8 +274,6 @@ class MockEngine:
             "t": time.monotonic(), "kind": "compile", "family": family,
             "seconds": a.sim_compile_s, "tokens": tokens,
             "serving": serving,
-            "flops": tokens * self.SIM_FLOPS_PER_TOKEN,
-            "bytes": tokens * self.SIM_BYTES_PER_TOKEN,
         })
 
     def _fpm_dispatch(self, kind: str, tokens: int, lanes: int,
@@ -301,19 +288,10 @@ class MockEngine:
         gap = now - last if last else 0.0
         if gap > 1.0:
             gap = 0.0  # idle stretch, not dispatch latency
-        flops = tokens * self.SIM_FLOPS_PER_TOKEN
-        rec = {
-            "t": now, "kind": kind, "gap_s": gap,
-            "xla_flops": flops,
-            "xla_bytes": tokens * self.SIM_BYTES_PER_TOKEN,
-        }
+        rec = {"t": now, "kind": kind, "gap_s": gap}
         if kind == "prefill":
             rec.update(rows=lanes, tokens=tokens, bucket=tokens,
-                       flops=flops, queue_depth=queue_depth, synced=True)
-            if gap > 0.0 and self.args.peak_tflops > 0.0:
-                rec["mfu"] = min(
-                    flops / gap / (self.args.peak_tflops * 1e12), 1.0)
-                rec["est_mfu"] = rec["mfu"]  # sim: one cost model
+                       queue_depth=queue_depth)
             self._fpm_last_prefill_t = now
         else:
             rec.update(k=k, lanes=lanes)

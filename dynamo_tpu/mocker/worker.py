@@ -374,9 +374,7 @@ class MockerWorker:
             if store is not None:
                 occ["g4"] = {"used": len(store)}
             export_engine_gauges(
-                m, fw, peak_tflops=self.args.peak_tflops,
-                peak_hbm_gbps=self.args.peak_hbm_gbps,
-                occupancy=occ,
+                m, fw, occupancy=occ,
                 kv_ledger=self._merged_ledgers())
             if store is not None and ticks % 40 == 0:
                 # G4 sweep cadence (the JAX worker's load-loop parity):
@@ -413,7 +411,7 @@ class MockerWorker:
                 / sum(weights)
             # tier costs from the timing model itself: onboard seconds
             # per block vs the prefill recompute it displaces — the same
-            # ratio the JAX worker derives from measured roofline rates
+            # ratio the JAX worker derives from its prefill token rate
             # (router/tiered_index.compute_tier_costs), known in closed
             # form here.  speedup_ratio scales both sides, so it cancels.
             tier_costs = None

@@ -643,7 +643,7 @@ def prefill_packed(
 ):
     """Packed multi-sequence prefill: several prompts' chunks (or
     prefix-hit tails) run as ONE padding-free token stream with segment
-    ids (ops/packed_prefill.py) — the MFU path that replaces the padded
+    ids (ops/packed_prefill.py) — the path that replaces the padded
     per-row batched program.  Semantically identical to running `prefill`
     per sequence: K/V written into each token's own blocks, attention is
     causal-within-segment over each segment's paged context.

@@ -1,15 +1,12 @@
-"""Compile watchdog + XLA cost-analysis roofline for the engine's jit
-dispatch sites.
+"""Compile watchdog for the engine's jit dispatch sites.
 
 The engine's own comments record *measured* 8-14s guided-fork compiles
 landing mid-serving with zero telemetry — an invisible latency cliff
 that no span, metric, or FPM record could attribute.  This module makes
-every XLA compile an observed event, and harvests each compiled
-program's FLOPs / bytes-accessed so decode, spec-verify, and packed
-prefill all get live MFU *and* memory-bandwidth-utilization instead of
-the hand-counted prefill-only estimate.
+every XLA compile an observed event.
 
-Mechanism (no second compile, no steady-state cost):
+Mechanism (a program is lowered once, by its compile; no steady-state
+cost):
 
   * ``WatchedProgram`` wraps a ``jax.jit`` callable.  Per call it reads
     the pjit C++ cache size before and after — a growth means THIS call
@@ -21,23 +18,17 @@ Mechanism (no second compile, no steady-state cost):
     switch: an unobserved mid-serving compile is exactly the blind spot
     this exists to close, and the steady-state cost is negligible.
 
-  * On a compile event the watchdog re-lowers the traced call on
-    ``jax.ShapeDtypeStruct`` avals (tracing is cached; donated buffers
-    are already consumed but their aval metadata survives) and runs
-    ``Lowered.cost_analysis()`` — XLA's HLO cost analysis, **without**
-    compiling again.  FLOPs and bytes-accessed are stored per
-    (program, token-bucket) so dispatch sites can stamp them onto FPM
-    records with one dict lookup.
-
   * Every compile emits: a ``compile`` span on the engine's logical
     track (Perfetto shows the cliff in the timeline), a ``compile`` FPM
-    record (``family``, ``seconds``, ``tokens``, ``flops``, ``bytes``,
-    ``serving``) the worker turns into
-    ``dynamo_engine_compile_seconds{family}`` and the planner's
-    recompile-storm diag, and — when the compile landed **mid-serving**
-    (active sequences exist; warmup compiles don't) — a flight-recorder
-    snapshot plus a warning, because a steady-state recompile means a
-    shape leaked past warmup.
+    record (``family``, ``seconds``, ``tokens``, ``serving``) the worker
+    turns into ``dynamo_engine_compile_seconds{family}`` and the
+    planner's recompile-storm diag, and — when the compile landed
+    **mid-serving** (active sequences exist; warmup compiles don't) — a
+    flight-recorder snapshot plus a warning, because a steady-state
+    recompile means a shape leaked past warmup.
+
+How fast a compiled program runs is the device trace's to say
+(`benchmark/`, PERF.md §3), not this module's.
 """
 
 from __future__ import annotations
@@ -62,44 +53,10 @@ COMPILE_KIND = "compile"
 PROGRAM_PREFIX = "dyn_"
 
 
-def _sds_of(x):
-    """Aval stand-in for one call argument: lowering needs shapes/dtypes
-    only, and a donated (already-deleted) jax.Array keeps its metadata."""
-    import jax
-
-    if x is None or isinstance(x, (bool, int, float)):
-        return x
-    return jax.ShapeDtypeStruct(x.shape, x.dtype)
-
-
-def xla_costs(fn, args) -> Optional[Dict[str, float]]:
-    """FLOPs / bytes-accessed of the program ``fn(*args)`` compiled, via
-    ``Lowered.cost_analysis()`` on aval stand-ins — re-traces (cached)
-    but does NOT re-compile.  None when the backend has no cost model
-    for this program (the roofline is best-effort by design).  Divided
-    by a dispatch gap these give the mfu/mbu gauges: an estimate from
-    host-clock gaps, not a device measurement."""
-    import jax
-
-    try:
-        sds = jax.tree_util.tree_map(_sds_of, args)
-        ca = fn.lower(*sds).cost_analysis()
-        flops = float(ca.get("flops", 0.0))
-        byts = float(ca.get("bytes accessed", 0.0))
-        if flops <= 0.0 and byts <= 0.0:
-            return None
-        return {"flops": flops, "bytes": byts}
-    except Exception:  # observability must never take down serving
-        logger.debug("xla cost analysis unavailable", exc_info=True)
-        return None
-
-
 class WatchedProgram:
-    """One jit callable under the watchdog.  Call syntax is unchanged;
-    ``cost(key)`` returns the XLA cost entry for the token-bucket key
-    the dispatch site computes (0 for fixed-shape programs)."""
+    """One jit callable under the watchdog.  Call syntax is unchanged."""
 
-    __slots__ = ("fn", "family", "watch", "tokens_of", "costs", "_counted")
+    __slots__ = ("fn", "family", "watch", "tokens_of", "_counted")
 
     def __init__(self, fn, family: str, watch: "CompileWatch",
                  tokens_of: Optional[Callable] = None):
@@ -119,7 +76,6 @@ class WatchedProgram:
         # prefill bucket = the token array's padded length); None = one
         # fixed shape per program (decode: always [max_num_seqs])
         self.tokens_of = tokens_of
-        self.costs: Dict[int, Dict[str, float]] = {}
 
     def __call__(self, *args):
         fn = self.fn
@@ -132,25 +88,20 @@ class WatchedProgram:
             self.watch.on_compile(self, time.monotonic() - t0, args)
         return out
 
-    def cost(self, tokens: int = 0) -> Optional[Dict[str, float]]:
-        return self.costs.get(int(tokens))
-
     def lower(self, *args, **kw):
         return self.fn.lower(*args, **kw)
 
 
 class CompileWatch:
     """Per-engine compile observer: counts/times every compile per
-    program family and owns the roofline cost registry."""
+    program family."""
 
     def __init__(self, sink: Optional[Callable[[dict], None]] = None,
                  track: Optional[str] = None,
-                 serving: Optional[Callable[[], bool]] = None,
-                 cost_analysis: bool = True):
+                 serving: Optional[Callable[[], bool]] = None):
         self.sink = sink          # fpm ring append (engine.fpm.append)
         self.track = track        # obs logical track for compile spans
         self._serving = serving or (lambda: False)
-        self.cost_analysis = cost_analysis
         self.counts: Dict[str, int] = {}
         self.seconds: Dict[str, float] = {}
         self.serving_compiles = 0
@@ -196,9 +147,6 @@ class CompileWatch:
                 key = int(wp.tokens_of(args))
             except Exception:
                 key = 0
-        costs = xla_costs(wp.fn, args) if self.cost_analysis else None
-        if costs is not None:
-            wp.costs[key] = costs
         self.counts[family] = self.counts.get(family, 0) + 1
         self.seconds[family] = self.seconds.get(family, 0.0) + seconds
         if serving:
@@ -208,9 +156,6 @@ class CompileWatch:
             "seconds": round(seconds, 6), "tokens": key,
             "serving": serving,
         }
-        if costs is not None:
-            ev["flops"] = costs["flops"]
-            ev["bytes"] = costs["bytes"]
         self.events.append(ev)
         if self.sink is not None:
             self.sink(dict(ev))
@@ -218,8 +163,6 @@ class CompileWatch:
 
         tr = tracer()
         if tr is not None:
-            # the span covers the compiling call itself (cost analysis
-            # above ran after it and is not part of the compile)
             tr.record(COMPILE_KIND, t1 - seconds, t1,
                       {k: v for k, v in ev.items()
                        if k not in ("t", "kind")},

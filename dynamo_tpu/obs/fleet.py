@@ -141,24 +141,21 @@ async def _fetch(session, url: str, headers: dict,
 
 
 def _parse_headline_metrics(text: str) -> Dict[str, float]:
-    """A small, stable extract of a scrape: per-phase roofline and the
-    frontend goodput gauge — enough for the merged view without
-    shipping whole scrape bodies around."""
+    """A small, stable extract of a scrape: the frontend goodput gauge
+    and the router's attribution ratios — enough for the merged view
+    without shipping whole scrape bodies around."""
     from prometheus_client.parser import text_string_to_metric_families
 
     out: Dict[str, float] = {}
     for fam in text_string_to_metric_families(text):
-        if fam.name in ("dynamo_engine_mfu", "dynamo_engine_mbu"):
-            for s in fam.samples:
-                out[f"{fam.name}:{s.labels.get('phase', '')}"] = s.value
-        elif fam.name in ("dynamo_frontend_slo_goodput",
-                          "dynamo_engine_itl_ema_seconds",
-                          # router decision attribution (kv_router.py):
-                          # index-staleness + realized reuse, scraped
-                          # into the merged view so a stale indexer is
-                          # visible fleet-wide
-                          "dynamo_router_overlap_staleness_ratio",
-                          "dynamo_frontend_realized_overlap_ratio"):
+        if fam.name in ("dynamo_frontend_slo_goodput",
+                        "dynamo_engine_itl_ema_seconds",
+                        # router decision attribution (kv_router.py):
+                        # index-staleness + realized reuse, scraped
+                        # into the merged view so a stale indexer is
+                        # visible fleet-wide
+                        "dynamo_router_overlap_staleness_ratio",
+                        "dynamo_frontend_realized_overlap_ratio"):
             for s in fam.samples:
                 out[fam.name] = s.value
     return out

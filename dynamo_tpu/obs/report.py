@@ -2,12 +2,11 @@
 ROADMAP item 3 (overlapped scheduling) is scored on.
 
     python -m dynamo_tpu.obs.report trace.json [more-dumps.json ...]
-        [--peak-tflops N] [--peak-hbm-gbps N]
 
 Every time here is the host's (`time.monotonic` around the scheduler's
-phases), and the roofline table is an estimate from host-clock gaps
-between dispatches: the device's own clock is in a `jax.profiler`
-capture, where the same phases appear as `dyn.<kind>` (obs/__init__.py).
+phases): the device's own clock is in a `jax.profiler` capture, where
+the same phases appear as `dyn.<kind>` (obs/__init__.py), and how well
+the chip is used is read from that capture (`benchmark/`, PERF.md §3).
 
 "Served is 0.40 of raw" is a symptom; this report turns a recorded
 timeline into the ranked culprits: what fraction of engine wall time is
@@ -16,17 +15,6 @@ decode ran as a device-resident continuation burst, and the p50/p95 of
 every phase.  Multiple dumps (frontend + each worker) merge; engine
 tracks are recognized by their ``sched:`` prefix (obs/__init__.py pins
 step spans there).
-
-The report also prints a **per-phase roofline table**: the compile
-watchdog (obs/compile_watch.py) stamps every ``compile`` span with the
-program's XLA cost-analysis FLOPs/bytes, and prefill/decode dispatch
-spans carry their program's costs + the dispatch gap — so the table
-shows, per phase, measured FLOP/s and bytes/s (MFU/MBU when the peaks
-are given), the cost-analysis MFU next to the engine's hand-estimated
-one (``est_mfu``, the pre-roofline `_flops_per_token` path — the two
-should agree within tens of percent; a large gap means one of them is
-lying), and every compile with its family, duration, and whether it
-landed mid-serving.
 
 Attribution is **innermost-span self time**: on one track, every
 instant belongs to the deepest span covering it, so nesting (``step``
@@ -136,99 +124,7 @@ def _self_times(events: List[Dict[str, Any]]) -> Dict[str, float]:
     return dict(self_us)
 
 
-def roofline(events: List[Dict[str, Any]], peak_tflops: float = 0.0,
-             peak_hbm_gbps: float = 0.0) -> Dict[str, Any]:
-    """Per-phase roofline from compile spans + dispatch-span attrs.
-
-    Phase rates use the same gates as the live gauges
-    (planner/metrics.py FpmWindow): plausible dispatch gaps only, and
-    prefill only where a device sync landed in the gap (``synced``) —
-    an async enqueue gap measures host time and would inflate MFU."""
-    compiles: Dict[str, Dict[str, Any]] = {}
-    for ev in events:
-        if ev["name"] != "compile":
-            continue
-        a = ev["args"]
-        fam = str(a.get("family", ""))
-        c = compiles.setdefault(fam, {
-            "count": 0, "seconds": 0.0, "serving": 0, "variants": set(),
-        })
-        c["count"] += 1
-        c["seconds"] = round(c["seconds"] + float(a.get("seconds", 0.0)), 6)
-        c["serving"] += int(bool(a.get("serving")))
-        tokens = int(a.get("tokens", 0))
-        c["variants"].add(tokens)
-        if a.get("flops") and tokens >= c.get("_cost_tokens", -1):
-            # deterministic representative: the LARGEST token variant's
-            # costs (dump merge order is not chronological, so
-            # last-seen-wins would flip the intensity verdict run to
-            # run); `variants` says how many shapes the family compiled
-            c["_cost_tokens"] = tokens
-            c["flops"] = float(a["flops"])
-            c["bytes"] = float(a.get("bytes", 0.0))
-            if c["bytes"]:
-                c["intensity"] = round(c["flops"] / c["bytes"], 3)
-    for c in compiles.values():
-        c["variants"] = len(c.pop("variants"))
-        c.pop("_cost_tokens", None)
-
-    phases: Dict[str, Dict[str, Any]] = {}
-    for phase, span_name, need_sync in (("prefill", "prefill_dispatch",
-                                         True),
-                                        ("decode", "decode_dispatch",
-                                         False)):
-        flops = byts = gaps = 0.0
-        est_mfu_w = est_gaps = 0.0
-        n_all = n_used = 0
-        for ev in events:
-            if ev["name"] != span_name:
-                continue
-            n_all += 1
-            a = ev["args"]
-            gap = float(a.get("gap_s", 0.0))
-            if "xla_flops" not in a or not 0.0 < gap < 1.0:
-                continue
-            if need_sync and not a.get("synced"):
-                continue
-            n_used += 1
-            flops += float(a["xla_flops"])
-            byts += float(a.get("xla_bytes", 0.0))
-            gaps += gap
-            if "est_mfu" in a:
-                # gap-weighted: a per-record mfu is flops_i/gap_i, so
-                # weighting by gap recovers Σflops/Σgap — the same
-                # aggregation as the cost-analysis rate above, making
-                # mfu vs est_mfu a pure FLOP-count comparison instead
-                # of a mean-of-ratios artifact
-                est_mfu_w += float(a["est_mfu"]) * gap
-                est_gaps += gap
-        if not n_all:
-            continue
-        # 4 significant digits, not 4 decimals: a CPU test run's MFU at
-        # a TPU peak is ~1e-7 and must not round to a vacuous 0.0
-        sig4 = lambda x: float(f"{x:.4g}")  # noqa: E731
-        entry: Dict[str, Any] = {"dispatches": n_all,
-                                 "costed_dispatches": n_used}
-        if gaps > 0.0:
-            entry["xla_flops_per_s"] = round(flops / gaps, 1)
-            entry["xla_bytes_per_s"] = round(byts / gaps, 1)
-            if peak_tflops > 0.0:
-                entry["mfu"] = sig4(
-                    min(flops / gaps / (peak_tflops * 1e12), 1.0))
-            if peak_hbm_gbps > 0.0:
-                entry["mbu"] = sig4(
-                    min(byts / gaps / (peak_hbm_gbps * 1e9), 1.0))
-        if est_gaps > 0.0:
-            # the engine's own hand-estimated MFU (pre-roofline path),
-            # printed next to the cost-analysis number so divergence is
-            # visible at a glance
-            entry["est_mfu"] = sig4(est_mfu_w / est_gaps)
-        phases[phase] = entry
-    return {"compiles": compiles, "phases": phases}
-
-
-def report(events: List[Dict[str, Any]], peak_tflops: float = 0.0,
-           peak_hbm_gbps: float = 0.0) -> Dict[str, Any]:
+def report(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     by_track: Dict[str, List[Dict[str, Any]]] = defaultdict(list)
     for ev in events:
         by_track[ev["track"]].append(ev)
@@ -312,7 +208,6 @@ def report(events: List[Dict[str, Any]], peak_tflops: float = 0.0,
         "distinct_trace_ids": len(trace_ids),
         "gap": gap,
         "kinds": kinds,
-        "roofline": roofline(events, peak_tflops, peak_hbm_gbps),
         **({"fleet_prefix_cache": fpc} if fpc else {}),
     }
 
@@ -597,10 +492,9 @@ def actuation_report(dumps: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     }
 
 
-def report_paths(paths: Iterable[str], peak_tflops: float = 0.0,
-                 peak_hbm_gbps: float = 0.0) -> Dict[str, Any]:
-    """Reduce a mixed set of dumps: Chrome traces feed the gap/roofline
-    sections, forensics dumps (/debug/requests or ForensicsPlane.dump
+def report_paths(paths: Iterable[str]) -> Dict[str, Any]:
+    """Reduce a mixed set of dumps: Chrome traces feed the gap
+    section, forensics dumps (/debug/requests or ForensicsPlane.dump
     files) feed the tail-autopsy section, kv-ledger dumps (/debug/kv or
     fleet --json snapshots) feed the KV-accounting section, and planner
     debug-state dumps feed the actuation section — pass any mix and the
@@ -621,7 +515,7 @@ def report_paths(paths: Iterable[str], peak_tflops: float = 0.0,
             tails.extend(found)
         elif not led and not plans:
             events.extend(events_of_doc(doc))
-    rep = report(events, peak_tflops, peak_hbm_gbps)
+    rep = report(events)
     if tails:
         rep["tail"] = tail_autopsy(tails)
     if ledgers:
@@ -647,14 +541,8 @@ def main(argv=None) -> int:
                         "dumps, and/or dynamo.kv_ledger.v1 dumps")
     p.add_argument("--indent", type=int, default=2,
                    help="JSON indent (0 = one line)")
-    p.add_argument("--peak-tflops", type=float, default=0.0,
-                   help="accelerator peak TFLOP/s: the roofline table "
-                        "reports per-phase MFU (0 = rates only)")
-    p.add_argument("--peak-hbm-gbps", type=float, default=0.0,
-                   help="accelerator peak HBM GB/s: the roofline table "
-                        "reports per-phase MBU (0 = rates only)")
     args = p.parse_args(argv)
-    rep = report_paths(args.paths, args.peak_tflops, args.peak_hbm_gbps)
+    rep = report_paths(args.paths)
     json.dump(rep, sys.stdout, indent=args.indent or None)
     sys.stdout.write("\n")
     return 0

@@ -1,9 +1,7 @@
 """Request SLO plane: per-request latency histograms, goodput, burn rate.
 
 PR 6's span tracer decomposes *where* time goes; this module answers
-*whether the users got what they were promised*.  The roofline gauges
-exported beside it (``dynamo_engine_mfu/mbu``) are an estimate from
-host-clock gaps between dispatches, not a device measurement.  TTFT/ITL existed only
+*whether the users got what they were promised*.  TTFT/ITL existed only
 as per-request JSONL ``request_end`` records (frontend/request_trace.py)
 — nothing aggregated them onto ``/metrics``, so "p95 TTFT halved"
 (ROADMAP item 3) and the SLA planner loop (item 4) had no live

@@ -1,7 +1,6 @@
 """Packed multi-sequence prefill over the paged KV cache.
 
-The padding killer for the prefill phase (round-5 verdict: prefill MFU
-0.098 while decode sits at 0.76 of its roofline).  The batched prefill
+The padding killer for the prefill phase.  The batched prefill
 path pads EVERY co-scheduled row to the largest chunk's bucket, so a
 (100, 500, 37, 1800)-token admission wave computes 4x2048 padded tokens
 for 2437 real ones — and the B=1 path serializes one jit dispatch per

@@ -159,8 +159,8 @@ class FpmWindow:
     records (`add`) and read the derived engine numbers.  The planner's
     FpmObserver subclasses this with an event-plane subscription; a
     worker feeds its OWN fpm ring through one so `/metrics` scrapes see
-    the headline engine numbers (prefill MFU, spec acceptance, queue
-    depth, decode tok/s) without a planner in the deployment."""
+    the headline engine numbers (spec acceptance, queue depth, prefill
+    and decode tok/s) without a planner in the deployment."""
 
     def __init__(self, window_s: float = 20.0):
         self.window_s = window_s
@@ -261,42 +261,6 @@ class FpmWindow:
             total_rate += toks / span
         return total_rate
 
-    def prefill_mfu(self, peak_tflops: float = 0.0) -> float:
-        """Window-mean prefill-phase MFU, token-weighted across workers.
-
-        Records carrying their own `mfu` field (workers whose config
-        pins peak_tflops compute it at dispatch) always count; records
-        with only `flops` + a plausible `gap_s` fold in against the
-        caller's peak_tflops, token-weighted alongside the rest — but
-        only records marked `synced` (a blocking device fetch landed in
-        the gap; jit dispatch is async, so a sync-free gap measures host
-        enqueue time and flops/gap would overstate MFU without bound —
-        the same gate the engine applies at dispatch), and the result is
-        clamped to 1.0 like the engine's own records.  With
-        peak_tflops=0 (the planner's default: it cannot know a
-        heterogeneous fleet's peaks) fallback workers are ignored.  0.0
-        when nothing in the window carries enough to tell."""
-        w_mfu, w_tok = 0.0, 0
-        flops_total, gap_total, fb_tok = 0.0, 0.0, 0
-        for dq in self._window().values():
-            for _, rec in dq:
-                if rec.get("kind") != "prefill":
-                    continue
-                toks = int(rec.get("tokens", 0))
-                if "mfu" in rec:
-                    w_mfu += float(rec["mfu"]) * toks
-                    w_tok += toks
-                elif rec.get("flops") and rec.get("synced") \
-                        and 0.0 < float(rec.get("gap_s", 0.0)) < 1.0:
-                    flops_total += float(rec["flops"])
-                    gap_total += float(rec["gap_s"])
-                    fb_tok += toks
-        if peak_tflops > 0.0 and gap_total > 0.0 and fb_tok:
-            w_mfu += min(flops_total / gap_total
-                         / (peak_tflops * 1e12), 1.0) * fb_tok
-            w_tok += fb_tok
-        return w_mfu / w_tok if w_tok else 0.0
-
     def spec_acceptance(self) -> Optional[float]:
         """Fleet speculative-decoding acceptance rate over the window:
         Σ accepted / Σ proposed across spec_verify records (one per
@@ -304,7 +268,7 @@ class FpmWindow:
         emits the same shape from its simulated acceptance).  The SLA
         planner surfaces it per tick so acceptance regressions — a
         proposer gone stale, a workload shift away from repetition —
-        are visible next to ITL/MFU.  None when nothing speculated in
+        are visible next to ITL.  None when nothing speculated in
         the window — a REAL 0.0 (every draft rejected) is exactly the
         regression this metric exists to expose and must not be
         conflated with idle."""
@@ -329,63 +293,6 @@ class FpmWindow:
                     total += float(rec["queue_depth"])
                     break
         return total
-
-    # -- roofline (obs/compile_watch.py cost-analysis fields) -------------
-    _PHASE_GATES = {
-        # prefill gaps measure device time only when a blocking fetch
-        # landed inside (the engine marks those `synced`); decode and
-        # spec-verify gaps are device time whenever plausible (decode:
-        # saturated pipeline convention; spec: the verify fetch blocks)
-        "prefill": lambda rec: rec.get("synced"),
-        "decode": lambda rec: True,
-        "spec_verify": lambda rec: True,
-    }
-
-    def _phase_rates(self, kind: str):
-        """(flops/s, bytes/s) for one dispatch kind over the window,
-        from the records' XLA cost-analysis fields — per-worker
-        Σcost/Σgap summed across workers, same gap plausibility gates
-        as the token-rate derivations.  (0, 0) when nothing qualifies."""
-        gate = self._PHASE_GATES.get(kind, lambda rec: True)
-        flops_rate = bytes_rate = 0.0
-        for dq in self._window().values():
-            flops = byts = gaps = 0.0
-            for _, rec in dq:
-                if rec.get("kind") != kind or "xla_flops" not in rec:
-                    continue
-                gap = float(rec.get("gap_s", 0.0))
-                if not 0.0 < gap < 1.0 or not gate(rec):
-                    continue
-                flops += float(rec["xla_flops"])
-                byts += float(rec.get("xla_bytes", 0.0))
-                gaps += gap
-            if gaps > 0.0:
-                flops_rate += flops / gaps
-                bytes_rate += byts / gaps
-        return flops_rate, bytes_rate
-
-    def phase_mfu(self, kind: str, peak_tflops: float) -> float:
-        """Window MFU for one dispatch kind from XLA cost-analysis FLOPs
-        (fleet flops/s over the accelerator peak, clamped to 1.0).  0.0
-        when the peak is unknown or nothing in the window carries
-        costs — decode and spec-verify get a live MFU here for the
-        first time (the hand count only ever covered prefill)."""
-        if peak_tflops <= 0.0:
-            return 0.0
-        flops_rate, _ = self._phase_rates(kind)
-        return min(flops_rate / (peak_tflops * 1e12), 1.0) \
-            if flops_rate else 0.0
-
-    def phase_mbu(self, kind: str, peak_hbm_gbps: float) -> float:
-        """Window memory-bandwidth utilization for one dispatch kind
-        (cost-analysis bytes-accessed over peak HBM bandwidth) — the
-        binding roofline axis for decode, which is bandwidth-bound long
-        before it is FLOPs-bound."""
-        if peak_hbm_gbps <= 0.0:
-            return 0.0
-        _, bytes_rate = self._phase_rates(kind)
-        return min(bytes_rate / (peak_hbm_gbps * 1e9), 1.0) \
-            if bytes_rate else 0.0
 
     def compile_stats(self) -> dict:
         """Compile events in the window (obs/compile_watch.py records):
@@ -436,17 +343,15 @@ class FpmWindow:
         return total_rate
 
 
-def export_engine_gauges(metrics, fw: FpmWindow, peak_tflops: float = 0.0,
-                         peak_hbm_gbps: float = 0.0,
+def export_engine_gauges(metrics, fw: FpmWindow,
                          occupancy: Optional[dict] = None,
                          kv_ledger=None) -> None:
     """One shared /metrics gauge surface for BOTH workers' load loops
     (engine/worker.py, mocker/worker.py): the headline FPM aggregates,
-    the per-phase roofline MFU/MBU, KV occupancy by tier, and the KV
-    ledger's violation counters.  A single definition is what keeps the
-    mocker's CPU-only export byte-name-compatible with the JAX worker —
-    the parity the scrape-contract test pins."""
-    metrics.set("dynamo_engine_prefill_mfu", fw.prefill_mfu(peak_tflops))
+    KV occupancy by tier, and the KV ledger's violation counters.  A
+    single definition is what keeps the mocker's CPU-only export
+    byte-name-compatible with the JAX worker — the parity the
+    scrape-contract test pins."""
     metrics.set("dynamo_engine_prefill_queue_depth",
                 fw.prefill_queue_depth())
     metrics.set("dynamo_engine_prefill_tokens_per_s",
@@ -456,27 +361,6 @@ def export_engine_gauges(metrics, fw: FpmWindow, peak_tflops: float = 0.0,
     acc = fw.spec_acceptance()
     if acc is not None:
         metrics.set("dynamo_engine_spec_acceptance", acc)
-    # roofline: gate on the PEAK being configured, not on the value —
-    # an idle window must drive the gauge to 0.0, or a dashboard reads
-    # the last busy minute's utilization forever.  One window scan per
-    # phase serves BOTH gauges (_phase_rates returns the pair; calling
-    # phase_mfu + phase_mbu would scan twice).
-    for phase in ("prefill", "decode", "spec_verify"):
-        if peak_tflops <= 0.0 and peak_hbm_gbps <= 0.0:
-            continue
-        flops_rate, bytes_rate = fw._phase_rates(phase)
-        if peak_tflops > 0.0:
-            metrics.set("dynamo_engine_mfu",
-                        min(flops_rate / (peak_tflops * 1e12), 1.0),
-                        "FLOP/s utilization per phase: an estimate from "
-                        "host-clock gaps between dispatches",
-                        phase=phase)
-        if peak_hbm_gbps > 0.0:
-            metrics.set("dynamo_engine_mbu",
-                        min(bytes_rate / (peak_hbm_gbps * 1e9), 1.0),
-                        "HBM bandwidth utilization per phase: an estimate "
-                        "from host-clock gaps between dispatches",
-                        phase=phase)
     for tier, occ in (occupancy or {}).items():
         for state in ("used", "free", "capacity"):
             if state in occ:

@@ -732,14 +732,10 @@ class Planner:
             pm.observe_itl(load.active_per_worker, measured, isl)
             diag["fpm_itl_s"] = fpm_itl
         if self.fpm is not None:
-            # prefill-pressure diagnostics off the same stream: phase MFU
-            # (workers emit it when their config pins peak_tflops) and
+            # prefill-pressure diagnostic off the same stream: the
             # chunk-queue depth — surfaced per tick so operators can see
             # a prefill-bound fleet even while the ITL bound is quiet
-            mfu = self.fpm.prefill_mfu()
             depth = self.fpm.prefill_queue_depth()
-            if mfu:
-                diag["prefill_mfu"] = mfu
             if depth:
                 diag["prefill_queue_depth"] = depth
             # speculative-decoding acceptance off the same stream: a

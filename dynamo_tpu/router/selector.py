@@ -48,8 +48,8 @@ class WorkerState:
     kv_usage: float = 0.0        # from load_metrics events
     kv_total_blocks: int = 0
     # per-tier onboard cost in recompute-equivalent blocks, published by
-    # the worker from its roofline measurements (load_metrics
-    # `kv_tier_costs`); defaults cover workers that have not measured yet
+    # the worker from its prefill token rate (load_metrics
+    # `kv_tier_costs`); defaults cover workers that have not prefilled yet
     tier_costs: Dict[str, float] = field(default_factory=dict)
 
 

@@ -38,8 +38,8 @@ TIERS = ("g1", "g2", "g3", "g4")
 LOCAL_TIERS = ("g1", "g2", "g3")
 
 # onboard-cost per block, as a fraction of recomputing the block's tokens
-# (fallbacks when a worker has not yet published measured `kv_tier_costs`
-# from its roofline plane; see `compute_tier_costs`).  g1 is free by
+# (fallbacks when a worker has not yet published `kv_tier_costs` from its
+# prefill token rate; see `compute_tier_costs`).  g1 is free by
 # definition; g4 rides a shared FS so it is priced closest to recompute.
 DEFAULT_TIER_COSTS: Dict[str, float] = {
     "g1": 0.0, "g2": 0.1, "g3": 0.4, "g4": 0.7,
@@ -52,27 +52,23 @@ DEFAULT_TIER_BW: Dict[str, float] = {
 }
 
 
-def compute_tier_costs(prefill_flops_per_s: Optional[float],
-                       flops_per_token: float,
+def compute_tier_costs(prefill_tokens_per_s: Optional[float],
                        bytes_per_block: float,
                        block_tokens: int,
                        tier_bw: Optional[Dict[str, float]] = None,
                        ) -> Dict[str, float]:
     """Per-tier onboard cost as a fraction of recompute cost.
 
-    cost_t = (bytes_per_block / bw_t) / (block_tokens * flops_per_token
-    / prefill_flops_per_s) — onboard seconds over recompute seconds for
-    one block.  The worker computes this from its roofline plane's
-    MEASURED prefill flops/s (FpmWindow phase rates) and publishes it in
-    load_metrics as `kv_tier_costs`; the selector falls back to
-    DEFAULT_TIER_COSTS for workers that have not measured yet."""
-    if (not prefill_flops_per_s or prefill_flops_per_s <= 0
-            or flops_per_token <= 0 or bytes_per_block <= 0
-            or block_tokens <= 0):
+    cost_t = (bytes_per_block / bw_t) / (block_tokens /
+    prefill_tokens_per_s) — onboard seconds over recompute seconds for
+    one block.  The worker computes this from its own prefill token rate
+    (FpmWindow.prefill_tokens_per_s) and publishes it in load_metrics as
+    `kv_tier_costs`; the selector falls back to DEFAULT_TIER_COSTS for
+    workers that have not prefilled yet."""
+    if (not prefill_tokens_per_s or prefill_tokens_per_s <= 0
+            or bytes_per_block <= 0 or block_tokens <= 0):
         return dict(DEFAULT_TIER_COSTS)
-    recompute_s = block_tokens * flops_per_token / prefill_flops_per_s
-    if recompute_s <= 0:
-        return dict(DEFAULT_TIER_COSTS)
+    recompute_s = block_tokens / prefill_tokens_per_s
     bw = dict(DEFAULT_TIER_BW)
     if tier_bw:
         bw.update({t: v for t, v in tier_bw.items() if v and v > 0})

@@ -527,7 +527,7 @@ async def test_packed_prefill_engine_matches_legacy():
     assert any(r.get("packed") for r in p_recs), \
         "packed path never engaged"
     for r in p_recs:
-        assert {"gap_s", "flops", "queue_depth"} <= set(r)
+        assert {"gap_s", "tokens", "queue_depth"} <= set(r)
 
 
 async def test_concurrent_prefill_batched_and_correct():

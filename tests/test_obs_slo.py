@@ -296,21 +296,25 @@ async def test_dispatch_fail_counts_without_polluting_ttft(tmp_path,
 
 def _assert_scrape_contract(text: str) -> int:
     """Every exported family parses and is dynamo_-prefixed — the
-    lint-style gate that fails on any future unprefixed metric."""
+    lint-style gate that fails on any future unprefixed metric — and
+    none claims a utilisation of the chip: that is the device trace's to
+    say, not a host clock's."""
     from prometheus_client.parser import text_string_to_metric_families
 
     families = list(text_string_to_metric_families(text))
     assert families, "empty scrape"
     bad = [f.name for f in families if not f.name.startswith("dynamo_")]
     assert not bad, f"unprefixed metric families exported: {bad}"
+    util = [f.name for f in families
+            if f.name.endswith(("mfu", "mbu"))]
+    assert not util, f"utilisation gauges from host-clock gaps: {util}"
     return len(families)
 
 
 async def test_scrape_contract_frontend_and_mocker():
     rt = await fresh_runtime().start()
     worker, watcher, service, port = await start_stack(
-        rt, model="scrape-model", slo=SloConfig(ttft_ms=1000.0),
-        peak_tflops=50.0, peak_hbm_gbps=100.0)
+        rt, model="scrape-model", slo=SloConfig(ttft_ms=1000.0))
     try:
         await chat(port, "scrape-model")
         await asyncio.sleep(0.4)  # a mocker load-loop tick
@@ -345,8 +349,8 @@ async def test_scrape_contract_jax_worker():
     rt = await fresh_runtime().start()
     worker = await JaxEngineWorker(rt, EngineConfig(
         model_config=tiny, block_size=4, num_blocks=64,
-        max_blocks_per_seq=16, max_num_seqs=2, peak_tflops=100.0,
-        peak_hbm_gbps=100.0, prefill_buckets=(8, 16, 32), seed=7,
+        max_blocks_per_seq=16, max_num_seqs=2,
+        prefill_buckets=(8, 16, 32), seed=7,
     )).start()
     client = await (rt.namespace("dynamo").component("backend")
                     .endpoint("generate").client()).start()
@@ -365,7 +369,7 @@ async def test_scrape_contract_jax_worker():
             if "dynamo_engine_compile_seconds" in text:
                 break
         _assert_scrape_contract(text)
-        # the new device-performance families are on the surface
+        # the compile and occupancy families are on the surface
         assert 'dynamo_engine_compile_seconds_count{' in text
         assert 'family="prefill_packed"' in text
         assert 'dynamo_engine_kv_blocks_used{' in text

@@ -92,12 +92,15 @@ def profiler_options():
 
 
 def dyn_events(trace_dir):
-    """{line: [(name, start_ns, end_ns, stats)]} of the `dyn.*` events
-    on the host plane of the one trace under `trace_dir`."""
+    """[[(name, start_ns, end_ns, stats)]]: the `dyn.*` events of every
+    thread line that holds any, on the host plane of the one trace under
+    `trace_dir`.  Every Python thread's line is named "python", and the
+    steps run on whichever pool thread `asyncio.to_thread` picked: under
+    load that is more than one."""
     from jax.profiler import ProfileData
 
     path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
-    out = {}
+    out = []
     for plane in ProfileData.from_file(path).planes:
         if plane.name != "/host:CPU":
             continue
@@ -106,7 +109,7 @@ def dyn_events(trace_dir):
                     dict(e.stats)) for e in line.events
                    if e.name.startswith("dyn.")]
             if evs:
-                out[line.name] = evs
+                out.append(evs)
     return out
 
 
@@ -195,7 +198,7 @@ def test_engine_phases_on_the_profilers_clock(tmp_path):
     lines = dyn_events(str(tmp_path))
     assert lines, "no dyn.* event on /host:CPU"
     kinds = set()
-    for evs in lines.values():
+    for evs in lines:
         steps = [(a, b) for n, a, b, _ in evs if n == "dyn.step"]
         assert steps
         first, last = min(a for a, _ in steps), max(b for _, b in steps)

@@ -581,24 +581,47 @@ def test_selector_tier_pricing():
     assert logits[1] == _pytest.approx(2 + 8 * 1.0)
 
 
-def test_compute_tier_costs_roofline():
-    import pytest as _pytest
-
+def test_compute_tier_costs_from_the_token_rate():
     from dynamo_tpu.router.tiered_index import (
         DEFAULT_TIER_COSTS,
         compute_tier_costs,
     )
 
-    # recompute_s = 16 tok * 2e9 flop/tok / 1e12 flop/s = 32 ms/block;
-    # a 32 MB block over a 1 GB/s shared FS is ALSO 32 ms -> cost 1.0
-    costs = compute_tier_costs(prefill_flops_per_s=1e12,
-                               flops_per_token=2e9,
-                               bytes_per_block=32e6, block_tokens=16,
-                               tier_bw={"g4": 1e9})
+    # recompute_s = 16 tok / 500 tok/s = 32 ms/block; a 32 MB block over
+    # a 1 GB/s shared FS is ALSO 32 ms -> cost 1.0
+    costs = compute_tier_costs(500.0, bytes_per_block=32e6,
+                               block_tokens=16, tier_bw={"g4": 1e9})
     assert costs["g1"] == 0.0
-    assert costs["g4"] == _pytest.approx(1.0, abs=0.01)
+    assert costs["g4"] == pytest.approx(1.0, abs=0.01)
     # g2 at the default 8 GB/s staging rate: 4 ms onboard -> 0.125
-    assert costs["g2"] == _pytest.approx(0.125, abs=0.01)
-    # unmeasured chip rate falls back to the static defaults
-    assert compute_tier_costs(None, 2e9, 32e6, 16) == DEFAULT_TIER_COSTS
-    assert compute_tier_costs(0.0, 2e9, 32e6, 16) == DEFAULT_TIER_COSTS
+    assert costs["g2"] == pytest.approx(0.125, abs=0.01)
+    # a worker that has not prefilled falls back to the static defaults
+    assert compute_tier_costs(None, 32e6, 16) == DEFAULT_TIER_COSTS
+    assert compute_tier_costs(0.0, 32e6, 16) == DEFAULT_TIER_COSTS
+
+
+@pytest.mark.parametrize("tok_rate", [500.0, 12707.0, None])
+def test_tier_costs_follow_the_token_rate(tok_rate):
+    """The costs are what the formula over FLOPs gave for the same token
+    rate (block_tokens x flops_per_token / flops_per_s with
+    flops_per_token = flops_per_s / tokens_per_s: the FLOPs cancel),
+    whatever the model's FLOPs a token; defaults while the rate is
+    unknown."""
+    from dynamo_tpu.router.tiered_index import (
+        DEFAULT_TIER_BW,
+        DEFAULT_TIER_COSTS,
+        compute_tier_costs,
+    )
+
+    bytes_per_block, block_tokens = 2 * 16 * 8 * 128 * 128 * 2, 128
+    costs = compute_tier_costs(tok_rate, bytes_per_block, block_tokens)
+    if tok_rate is None:
+        assert costs == DEFAULT_TIER_COSTS
+        return
+    for flops_per_token in (2e9, 7.5e9):
+        flops_per_s = flops_per_token * tok_rate
+        recompute_s = block_tokens * flops_per_token / flops_per_s
+        for tier, bw in DEFAULT_TIER_BW.items():
+            assert costs[tier] == pytest.approx(
+                bytes_per_block / bw / recompute_s, abs=1e-4)
+    assert costs["g1"] == 0.0

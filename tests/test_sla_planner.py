@@ -332,13 +332,13 @@ async def test_fpm_observer_derives_itl_and_prefill_rate():
 # real JAX engine in an async body: -O0 compiles dwarf the 200ms
 # loop gate (see conftest); mocker-based tests here stay gated
 @pytest.mark.allow_slow_callbacks
-async def test_fpm_prefill_mfu_queue_depth_and_single_record_rate():
+async def test_fpm_prefill_queue_depth_and_single_record_rate():
     """The chunked-prefill FPM fields flow end-to-end: records produced
-    by the ENGINE's own _fpm_prefill (gap/flops/mfu/queue_depth) publish
+    by the ENGINE's own _fpm_prefill (gap/tokens/queue_depth) publish
     onto the event plane and aggregate through the FpmObserver into
-    prefill-phase MFU and chunk-queue depth; and a window holding a
-    SINGLE prefill record reports a nonzero token rate (tokens/window_s
-    floor) instead of 0.0."""
+    the prefill token rate and chunk-queue depth; and a window holding
+    a SINGLE prefill record reports a nonzero token rate
+    (tokens/window_s floor) instead of 0.0."""
     import time as _time
 
     from dynamo_tpu.engine import EngineConfig, JaxEngine
@@ -351,19 +351,15 @@ async def test_fpm_prefill_mfu_queue_depth_and_single_record_rate():
                        ffn_dim=32, dtype=jnp.float32)
     eng = JaxEngine(EngineConfig(model_config=tiny, block_size=4,
                                  num_blocks=8, max_blocks_per_seq=4,
-                                 max_num_seqs=2, prefill_buckets=(8,),
-                                 peak_tflops=1e-6))
-    # two dispatch records in quick succession: the second carries a real
-    # gap, a FLOPs estimate, and (peak_tflops pinned + a device sync
-    # inside the gap) the MFU itself
+                                 max_num_seqs=2, prefill_buckets=(8,)))
+    # two dispatch records in quick succession: the second carries a
+    # real gap
     eng._fpm_prefill(rows=1, tokens=8, bucket=8, packed=True)
     _time.sleep(0.01)
-    eng._fpm_sync_t = _time.monotonic()  # blocking fetch inside the gap
     eng._fpm_prefill(rows=2, tokens=16, bucket=16, packed=True)
     recs = [r for r in eng.fpm if r["kind"] == "prefill"]
     await eng.close()
-    assert recs[-1]["gap_s"] > 0.0 and recs[-1]["flops"] > 0
-    assert recs[-1]["mfu"] > 0.0
+    assert recs[-1]["gap_s"] > 0.0 and recs[-1]["tokens"] == 16
     assert "queue_depth" in recs[-1]
 
     cfg = RuntimeConfig(discovery_backend="mem", event_plane="inproc")
@@ -374,14 +370,15 @@ async def test_fpm_prefill_mfu_queue_depth_and_single_record_rate():
     await asyncio.sleep(0.05)
     subj = "fpm.dynamo.backend"
     await rt.event_plane.publish(subj, {"worker_id": 1, "steps": recs})
-    # a second worker that does NOT know its peak publishes flops+gap
-    # plus a single-record window for the rate fallback
+    # a second worker publishes a single-record window for the rate
+    # fallback
     await rt.event_plane.publish(subj, {"worker_id": 2, "steps": [
         {"t": 5.0, "kind": "prefill", "rows": 1, "tokens": 4096,
-         "gap_s": 0.5, "flops": 1e9, "queue_depth": 3},
+         "gap_s": 0.5, "queue_depth": 3},
     ]})
     await asyncio.sleep(0.05)
-    assert obs.prefill_mfu() > 0.0          # from worker 1's mfu records
+    # worker 1's two records: 24 tokens over twice their 10 ms span
+    assert obs.prefill_tokens_per_s() > 4096 / 20.0 + 24 / 0.1
     # worker 2's single record: rate floors at tokens/window_s, not 0.0
     assert obs.prefill_tokens_per_s() > 4096 / 20.0 - 1e-6
     # fleet chunk-queue depth sums each worker's latest record
