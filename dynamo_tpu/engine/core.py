@@ -39,6 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import chaos, obs
 from ..models import get_family
+from ..models.llama import moe_form
 from ..parallel.mesh import MeshConfig, make_mesh, shard_params
 from ..protocols import (
     DRAIN_ABORT,
@@ -417,6 +418,23 @@ class JaxEngine:
                     )
                 self.params = shard_params(params, self.mesh)
             self.kv = self._init_kv_cache()
+        # routed-expert layers as the host knows them, for the moe_*
+        # counters: (layers that route, picks a token, experts held a
+        # layer — the `moe_w_*` stacks' length, the router may be wider)
+        moe = [lp["moe_w_gate"]
+               for lp in (self.params.get("layers", ())
+                          if isinstance(self.params, dict) else ())
+               if isinstance(lp, dict) and "moe_w_gate" in lp]
+        self._moe = (len(moe), getattr(self.model_cfg,
+                                       "experts_per_token", 0),
+                     moe[0].shape[0] if moe else 0)
+        if moe:
+            # a traced program cannot see how its arguments are laid
+            # out: the devices the expert stacks are split over, read
+            # off the arrays as placed (llama.moe_dispatch_form)
+            self.model_cfg = dataclasses.replace(
+                self.model_cfg, expert_shards=moe[0].shape[0]
+                // moe[0].sharding.shard_shape(moe[0].shape)[0])
 
         # pinned output shardings for every KV-returning program: XLA is
         # otherwise free to pick a DIFFERENT (equivalent) sharding for a
@@ -574,16 +592,6 @@ class JaxEngine:
         # synchronously and must skip the pipelined decode dispatch)
         self._specced: frozenset = frozenset()
         self._fpm_last_spec_t = 0.0
-        # routed-expert layers as the host knows them, for the moe_*
-        # counters: (layers that route, picks a token, experts held a
-        # layer — the `moe_w_*` stacks' length, the router may be wider)
-        moe = [lp["moe_w_gate"].shape[0]
-               for lp in (self.params.get("layers", ())
-                          if isinstance(self.params, dict) else ())
-               if isinstance(lp, dict) and "moe_w_gate" in lp]
-        self._moe = (len(moe), getattr(self.model_cfg,
-                                       "experts_per_token", 0),
-                     moe[0] if moe else 0)
         # sequence-parallel ring prefill: long-context path for prompts
         # beyond the largest bucket when the mesh has an sp axis
         self._jit_prefill_ring = None
@@ -681,6 +689,10 @@ class JaxEngine:
             # (its KV_COUNTERS: moe_picks_held.*, moe_experts_visited.*)
             "moe_picks.prefill": 0, "moe_picks.decode": 0,
             "moe_expert_slots.decode": 0,
+            # prompt tokens whose program took the dropless dispatch's
+            # grouped form (llama.moe_form: by shape);
+            # / prefill_tokens says how often it engaged
+            "moe_grouped_tokens.prefill": 0,
         }
         for name in self._kv_counters:
             self.metrics[name] = 0
@@ -2451,6 +2463,13 @@ class JaxEngine:
                 first = -1
             self._finish_prefill_chunk(slot, chunk, first)
 
+    def _moe_grouped(self, tokens: int) -> bool:
+        """Whether a program whose expert layers see `tokens` rows takes
+        the dropless dispatch's grouped form: the rule the traced code
+        applies (llama.moe_form), asked from the host."""
+        return self._moe[0] > 0 \
+            and moe_form(self.model_cfg, tokens) == "grouped"
+
     def _fpm_prefill(self, rows: int, tokens: int, bucket: int,
                      packed: bool = False, completing: int = 0) -> None:
         """One FPM record per prefill program — the inputs the SLA
@@ -2484,6 +2503,12 @@ class JaxEngine:
             "queue_depth": depth,
         })
         self._fpm_last_prefill_t = now
+        # the expert layers see the program's rows flattened: a packed
+        # stream or one row is `bucket` long, co-batched rows pad to a
+        # power of two of them (llama.moe_rows)
+        if self._moe_grouped(bucket if packed
+                             else _pow2_len(rows) * bucket):
+            self.metrics["moe_grouped_tokens.prefill"] += tokens
 
     def _prefill_packed_step(self, pslots, budget: int) -> None:
         """One packed prefill dispatch: the planner water-fills the token

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -40,8 +41,8 @@ from ..ops.paged_attention import (
 from .llama import (
     _logits,
     _mlp,
-    moe_dispatch_capacity,
-    moe_dispatch_dense,
+    moe_dispatch,
+    moe_rows,
     rms_norm,
     rope,
 )
@@ -70,6 +71,7 @@ class DeepseekConfig:
     routed_scaling_factor: float = 1.0
     moe_dispatch: str = "dense"   # llama.py semantics: dense | capacity
     moe_capacity_factor: float = 1.25
+    expert_shards: int = 1        # llama.py: set by the engine from the mesh
     # router semantics (HF DeepseekV3TopkRouter / V2 MoEGate):
     #   V2 lineage: softmax scores, plain top-k, no renorm
     #   V3 lineage: sigmoid scores + e_score_correction_bias for CHOICE
@@ -326,9 +328,7 @@ def _ds_ffn(layer, cfg: DeepseekConfig, x: jax.Array,
     if "moe_gate" not in layer:
         return _mlp(layer, x)
     top_w, top_e = _ds_router(layer, cfg, x)
-    dispatch = (moe_dispatch_capacity if cfg.moe_dispatch == "capacity"
-                else moe_dispatch_dense)
-    out = dispatch(layer, cfg, x, top_w, top_e, valid)
+    out = moe_dispatch(layer, cfg, x, top_w, top_e, valid)
     if "shared" in layer:
         out = out + _mlp(layer["shared"], x)
     return out
@@ -414,11 +414,7 @@ def prefill_batched(
         )(q_nope, q_rope, c, kr, block_tables, ctx_lens, true_lens)
         x = x + attn.reshape(Bp, T, -1) @ layer["wo"]
         h = rms_norm(x, layer["mlp_norm"]["norm"], cfg.rms_eps)
-        # per-row dispatch: co-batched sequences keep separate MoE
-        # capacity pools (llama.prefill_batched rationale)
-        x = x + jax.vmap(
-            lambda hb, vb: _ds_ffn(layer, cfg, hb, valid=vb)
-        )(h, valid)
+        x = x + moe_rows(partial(_ds_ffn, layer, cfg), cfg, h, valid)
     last = jnp.maximum(true_lens - 1, 0)
     xl = x[jnp.arange(Bp), last]
     return _logits(params, cfg, xl), (c_cache, kr_cache)

@@ -64,13 +64,16 @@ class LlamaConfig:
     # MoE (Mixtral-family): 0 experts = dense MLP.  Experts shard over the
     # "tp" mesh axis (EP reuses tp, parallel/mesh.py moe_w_* rules).
     # Dispatch modes:
-    #   "dense"    — every expert computes every token; the router weight
-    #                matrix masks the combine.  DROPLESS and batch-
-    #                invariant (same token -> same output regardless of
-    #                chunking/co-batch), which prefix caching and greedy
-    #                determinism rely on.  Costs E/k x the routed MLP
-    #                FLOPs — the right trade for decode (bandwidth-bound)
-    #                and correctness-critical serving.
+    #   "dense"    — DROPLESS and batch-invariant (same token -> same
+    #                output regardless of chunking/co-batch), which prefix
+    #                caching and greedy determinism rely on.  The FORM is
+    #                the program's choice by shape (moe_dispatch_form):
+    #                few tokens (every decode step, the small prefill
+    #                buckets) multiply every token with every held expert
+    #                and mask the combine — both forms read each visited
+    #                expert's weights once and that read is the cost
+    #                there; prompt-sized inputs sort their picks by expert
+    #                and multiply each token with its own experts only.
     #   "capacity" — GShard capacity dispatch: tokens over an expert's
     #                C = ceil(T*k/E * capacity_factor) are dropped.  k/E
     #                of the FLOPs, but outputs vary with batch shape; use
@@ -79,6 +82,11 @@ class LlamaConfig:
     experts_per_token: int = 2
     moe_dispatch: str = "dense"
     moe_capacity_factor: float = 1.25
+    # devices the moe_w_* stacks are split over (parallel/mesh.py shards
+    # them over "tp"); resolved by the engine from the mesh it placed the
+    # parameters on, like attn_impl "auto" — a traced program cannot see
+    # how its arguments are laid out
+    expert_shards: int = 1
 
     @property
     def q_dim(self) -> int:
@@ -430,12 +438,6 @@ def moe_dispatch_dense(layer, cfg: LlamaConfig, x: jax.Array,
     return jnp.einsum("etd,te->td", eout, wmat.astype(cfg.dtype))
 
 
-def _moe_mlp_dense(layer, cfg: LlamaConfig, x: jax.Array,
-                   valid: Optional[jax.Array] = None) -> jax.Array:
-    top_w, top_e = _moe_router(layer, cfg, x)
-    return moe_dispatch_dense(layer, cfg, x, top_w, top_e, valid)
-
-
 @jax.named_scope("dyn.moe_dispatch")
 def moe_dispatch_capacity(layer, cfg: LlamaConfig, x: jax.Array,
                           top_w: jax.Array, top_e: jax.Array,
@@ -490,10 +492,169 @@ def moe_dispatch_capacity(layer, cfg: LlamaConfig, x: jax.Array,
     return out.reshape(T, k, d).sum(axis=1)
 
 
-def _moe_mlp(layer, cfg: LlamaConfig, x: jax.Array,
-             valid: Optional[jax.Array] = None) -> jax.Array:
-    top_w, top_e = _moe_router(layer, cfg, x)
-    return moe_dispatch_capacity(layer, cfg, x, top_w, top_e, valid)
+# rows of one m-tile of the grouped matmul: a group that is not empty
+# pays up to one tile of rows it does not have (moe_dispatch_form)
+_GMM_TILE_M = 128
+
+
+def moe_dispatch_form(tokens: int, k: int, held: int, routed: int,
+                      shards: int = 1) -> str:
+    """Which form the dropless dispatch takes for a program of `tokens`
+    rows: "dense" or "grouped".  Both read every visited expert's
+    weights once; they differ in the rows they multiply: dense
+    tokens x held, grouped the picks that fall on a held expert (about
+    tokens x k x held / routed) plus up to one m-tile a group.  Grouped
+    where dense would multiply at least twice as many.
+
+    One expert layer on a TPU v5e, ms, dense / grouped (my chip runs,
+    PR 32): Moonlight's widths (64 experts of 2048 x 1408, top 6) T 128:
+    1.59 / 1.80, 256: 1.75 / 1.74, 512: 3.51 / 1.94, 2048: 16.88 / 3.06;
+    MiMo's (16 of 256 held, 4096 x 2048, top 8) 128: 1.19 / 1.24, 256:
+    1.42 / 1.26, 512: 2.48 / 1.48, 2048: 10.92 / 3.20.  Under about 256
+    tokens the weights' read (1.35 / 0.98 ms) is the cost of either, so
+    every decode step and the small prefill buckets keep the dense ops.
+
+    Stacks split over devices (`shards` > 1) keep the dense form: its
+    einsums run local to each shard under GSPMD, the grouped kernel
+    would have the stacks gathered to every device first."""
+    if shards > 1:
+        return "dense"
+    dense_rows = tokens * held
+    grouped_rows = tokens * k * held // routed + held * _GMM_TILE_M
+    return "grouped" if dense_rows >= 2 * grouped_rows else "dense"
+
+
+def _gmm_tiling(kdim: int, n: int, itemsize: int) -> Tuple[int, int, int]:
+    """(tm, tk, tn) of the Pallas grouped matmul for an expert matrix
+    [kdim, n]: the whole contraction in one step where a row tile and
+    a weight tile of 6 MiB together allow (the kernel holds two of each
+    in 16 MiB of scoped VMEM beside the output tile and its fp32
+    accumulator), n halved until they do.  Timed on the chip (PR 32):
+    Moonlight's (128, 2048, 1408) / (128, 1408, 2048) and MiMo's
+    (128, 4096, 512) / (128, 2048, 1024) are within 3 % of the best of
+    six a shape; tm 64 or 256 changes nothing."""
+    tm, tk, tn = _GMM_TILE_M, kdim, n
+
+    def over():
+        return (tm + tn) * tk * itemsize > 6 << 20
+
+    while over() and tn > 128:
+        tn = max(128, tn // 2 // 128 * 128)
+    while over() and tk > 128:
+        tk = max(128, tk // 2 // 128 * 128)
+    return tm, tk, tn
+
+
+def _grouped_matmul(lhs: jax.Array, rhs: jax.Array,
+                    group_sizes: jax.Array) -> jax.Array:
+    """lhs [m, k] with its rows sorted by group, rhs [g, k, n],
+    group_sizes [g] -> [m, n]: group i's rows times rhs[i], fp32
+    accumulation, an empty group costs nothing.  Rows past the last
+    group are undefined; the caller masks them.
+
+    A platform rule, not a choice: on the TPU the Pallas kernel (megablox
+    `gmm`, which visits only the m-tiles that hold rows), elsewhere
+    `jax.lax.ragged_dot`, its twin for the CPU.  What XLA makes of
+    `ragged_dot` on the chip was timed too: 2.2-2.8 x the kernel's time
+    at 2048 tokens and slower than the dense form under 1024 (PR 32)."""
+    def tpu(lhs, rhs, group_sizes):
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+        m = lhs.shape[0]
+        lhs = jnp.pad(lhs, ((0, -m % _GMM_TILE_M), (0, 0)))
+        return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+                   tiling=_gmm_tiling(rhs.shape[1], rhs.shape[2],
+                                      rhs.dtype.itemsize))[:m]
+
+    def xla(lhs, rhs, group_sizes):
+        return jax.lax.ragged_dot(
+            lhs, rhs, group_sizes,
+            preferred_element_type=jnp.float32).astype(lhs.dtype)
+
+    return jax.lax.platform_dependent(lhs, rhs, group_sizes,
+                                      tpu=tpu, default=xla)
+
+
+@jax.named_scope("dyn.moe_dispatch")
+def moe_dispatch_grouped(layer, cfg: LlamaConfig, x: jax.Array,
+                         top_w: jax.Array, top_e: jax.Array,
+                         valid: Optional[jax.Array] = None) -> jax.Array:
+    """The dropless dispatch's form for prompt-sized inputs
+    (moe_dispatch_dense's contract and mathematics): the (token, pick)
+    pairs sorted by expert, the three expert matmuls grouped over the
+    sorted rows (a row meets its own expert's matrices only), the k
+    results of a token gathered back and summed in pick order.
+
+    A pick of an expert held elsewhere (`experts_held`) and every pick
+    of a row `valid` masks out sort behind the held groups, belong to no
+    group and are never multiplied.  A row's result depends on no other
+    row: a matmul row by row, and a sum over its own k picks in a fixed
+    order."""
+    T, d = x.shape
+    k = top_e.shape[1]
+    first, count = experts_held(cfg)
+    local = top_e.reshape(-1) - first                  # [T*k]
+    held = (local >= 0) & (local < count)
+    if valid is not None:
+        held = held & jnp.repeat(valid, k)
+    group = jnp.where(held, local, count)              # count = no group
+    order = jnp.argsort(group, stable=True)
+    place = jnp.argsort(order)                         # pair -> sorted row
+    sizes = jnp.sum(group[:, None] == jnp.arange(count)[None, :], axis=0,
+                    dtype=jnp.int32)
+    xs = x[order // k]                                 # [T*k, d]
+    h = jax.nn.silu(_grouped_matmul(xs, layer["moe_w_gate"], sizes)) \
+        * _grouped_matmul(xs, layer["moe_w_up"], sizes)
+    ys = _grouped_matmul(h, layer["moe_w_down"], sizes)
+    held = held.reshape(T, k)
+    y = jnp.where(held[..., None], ys[place].reshape(T, k, d), 0)
+    w = jnp.where(held, top_w, 0).astype(cfg.dtype)
+    return jnp.einsum("tkd,tk->td", y, w)
+
+
+def moe_form(cfg, tokens: int) -> str:
+    """What a program of `tokens` rows runs for its routed experts:
+    "capacity", or the dropless dispatch's "dense" or "grouped" form.
+    The one rule, asked by the traced code and by the engine's counter."""
+    if cfg.moe_dispatch == "capacity":
+        return "capacity"
+    if cfg.moe_dispatch != "dense":
+        raise ValueError(
+            f"moe_dispatch must be 'dense' or 'capacity', "
+            f"got {cfg.moe_dispatch!r}"
+        )
+    return moe_dispatch_form(tokens, cfg.experts_per_token,
+                             experts_held(cfg)[1], cfg.n_experts,
+                             cfg.expert_shards)
+
+
+def moe_dispatch(layer, cfg, x: jax.Array, top_w: jax.Array,
+                 top_e: jax.Array,
+                 valid: Optional[jax.Array] = None) -> jax.Array:
+    """Routed experts for precomputed routing, x [T, d] -> [T, d]: the
+    one entry point of every family with experts.  `cfg.moe_dispatch`
+    says WHAT is computed ("dense": dropless; "capacity": GShard's
+    drop); the dropless FORM follows the program's shape (moe_form),
+    which the caller cannot set."""
+    dispatch = {"capacity": moe_dispatch_capacity,
+                "grouped": moe_dispatch_grouped,
+                "dense": moe_dispatch_dense}[moe_form(cfg, x.shape[0])]
+    return dispatch(layer, cfg, x, top_w, top_e, valid)
+
+
+def moe_rows(fn, cfg, h: jax.Array, valid: jax.Array):
+    """A family's routed FFN `fn(x [T, d], valid [T])` over co-batched
+    prefill rows h [Bp, T, d].  A dropless dispatch has no pools to keep
+    apart and a row's result depends on no other row: the rows run
+    flattened, as one program-sized input.  Capacity dispatch keeps a
+    pool a row (co-scheduled requests must not capacity-drop each
+    other's tokens): vmapped."""
+    if cfg.moe_dispatch == "capacity":
+        return jax.vmap(fn)(h, valid)
+    Bp, T = h.shape[:2]
+    out = fn(h.reshape(Bp * T, -1), valid.reshape(Bp * T))
+    return jax.tree_util.tree_map(
+        lambda o: o.reshape(Bp, T, *o.shape[1:]) if o.ndim else o, out)
 
 
 def _ffn(layer, cfg: LlamaConfig, x: jax.Array,
@@ -501,16 +662,12 @@ def _ffn(layer, cfg: LlamaConfig, x: jax.Array,
     """Dense or routed MLP over [..., d] (leading dims flattened for MoE)."""
     if cfg.n_experts <= 0:
         return _mlp(layer, x)
-    if cfg.moe_dispatch not in ("dense", "capacity"):
-        raise ValueError(
-            f"moe_dispatch must be 'dense' or 'capacity', "
-            f"got {cfg.moe_dispatch!r}"
-        )
     lead = x.shape[:-1]
     if valid is not None:
         valid = valid.reshape(-1)
-    moe = _moe_mlp if cfg.moe_dispatch == "capacity" else _moe_mlp_dense
-    out = moe(layer, cfg, x.reshape(-1, x.shape[-1]), valid)
+    flat = x.reshape(-1, x.shape[-1])
+    top_w, top_e = _moe_router(layer, cfg, flat)
+    out = moe_dispatch(layer, cfg, flat, top_w, top_e, valid)
     return out.reshape(*lead, x.shape[-1])
 
 
@@ -612,12 +769,7 @@ def prefill_batched(
         x = x + _attn_out(layer, attn.reshape(Bp, T, cfg.q_dim), lora=lctx)
         h = rms_norm(x, layer["mlp_norm"]["norm"], cfg.rms_eps)
         if cfg.n_experts > 0:
-            # per-row dispatch: each sequence keeps its OWN expert-capacity
-            # pool, matching the B=1 program — co-scheduled requests must
-            # not capacity-drop each other's tokens
-            x = x + jax.vmap(
-                lambda hb, vb: _ffn(layer, cfg, hb, valid=vb)
-            )(h, valid)
+            x = x + moe_rows(partial(_ffn, layer, cfg), cfg, h, valid)
         else:
             x = x + _ffn(layer, cfg, h, valid=valid)
     last = jnp.maximum(true_lens - 1, 0)

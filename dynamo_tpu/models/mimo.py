@@ -76,8 +76,8 @@ from .deepseek import _ds_router
 from .llama import (
     _logits,
     _mlp,
-    moe_dispatch_capacity,
-    moe_dispatch_dense,
+    moe_dispatch,
+    moe_rows,
     rms_norm,
     rope,
 )
@@ -112,6 +112,7 @@ class MimoConfig:
     experts_held: Optional[Tuple[int, int]] = None   # (first, count)
     moe_dispatch: str = "dense"   # llama.py semantics: dense | capacity
     moe_capacity_factor: float = 1.25
+    expert_shards: int = 1        # llama.py: set by the engine from the mesh
     # models/deepseek.py _ds_router reads these
     moe_scoring: str = "sigmoid"
     norm_topk_prob: bool = True
@@ -350,9 +351,7 @@ def _ffn(layer, cfg: MimoConfig, x: jax.Array,
     if "moe_gate" not in layer:
         return _mlp(layer, x), zero, zero
     top_w, top_e = _ds_router(layer, cfg, x)
-    dispatch = (moe_dispatch_capacity if cfg.moe_dispatch == "capacity"
-                else moe_dispatch_dense)
-    out = dispatch(layer, cfg, x, top_w, top_e, valid)
+    out = moe_dispatch(layer, cfg, x, top_w, top_e, valid)
     first, count = cfg.held
     on = (top_e >= first) & (top_e < first + count)
     if valid is not None:
@@ -425,10 +424,7 @@ def prefill_batched(
                 q, block_tables, ctx_lens, true_lens)
         x = x + _attn_out(layer, cfg, attn)
         h = rms_norm(x, layer["mlp_norm"]["norm"], cfg.rms_eps)
-        # per-row dispatch: co-batched sequences keep separate MoE
-        # capacity pools (llama.prefill_batched rationale)
-        out, n_on, _ = jax.vmap(
-            lambda hb, vb: _ffn(layer, cfg, hb, vb))(h, valid)
+        out, n_on, _ = moe_rows(partial(_ffn, layer, cfg), cfg, h, valid)
         x = x + out
         picks = picks + jnp.sum(n_on)
     counters = counters.at[0].add(picks)

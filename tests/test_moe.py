@@ -21,9 +21,23 @@ from dynamo_tpu.models import llama
 from dynamo_tpu.models.llama import (
     LlamaConfig,
     PRESETS,
-    _moe_mlp,
-    _moe_mlp_dense,
+    moe_dispatch_capacity,
+    moe_dispatch_dense,
+    moe_dispatch_grouped,
 )
+
+
+def _routed(dispatch):
+    """Router + one form of the dispatch, x [T, d] -> [T, d]."""
+    def run(layer, cfg, x):
+        top_w, top_e = llama._moe_router(layer, cfg, x)
+        return dispatch(layer, cfg, x, top_w, top_e)
+    return run
+
+
+_moe_mlp_dense = _routed(moe_dispatch_dense)
+_moe_mlp_grouped = _routed(moe_dispatch_grouped)
+_moe_mlp = _routed(moe_dispatch_capacity)
 
 
 def moe_cfg(**kw):
@@ -40,9 +54,10 @@ def expert_ffn(layer, e, x):
     return g @ layer["moe_w_down"][e]
 
 
-@pytest.mark.parametrize("impl", [_moe_mlp_dense, _moe_mlp])
+@pytest.mark.parametrize("impl", [_moe_mlp_dense, _moe_mlp_grouped, _moe_mlp],
+                         ids=["dense", "grouped", "capacity"])
 def test_moe_routes_to_topk_experts(impl):
-    """Both dispatch modes: output must equal the softmax-weighted sum of
+    """Every dispatch: output must equal the softmax-weighted sum of
     the top-k experts' FFN outputs, computed independently per token."""
     cfg = moe_cfg()
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
@@ -83,7 +98,8 @@ def test_moe_capacity_overflow_drops_tokens():
     np.testing.assert_allclose(np.asarray(out[1:]), 0.0, atol=1e-6)
 
 
-@pytest.mark.parametrize("impl", [_moe_mlp_dense, _moe_mlp])
+@pytest.mark.parametrize("impl", [_moe_mlp_dense, _moe_mlp],
+                         ids=["dense", "capacity"])
 def test_moe_ep_sharding_parity(impl):
     """Expert-parallel (experts sharded over tp) output == unsharded, for
     both dispatch modes."""

@@ -281,7 +281,7 @@ def test_hybrid_decode_and_prefill_compile_for_v5e(one_chip):
     """The window + global family (models/mimo.py) at MiMo-V2-Flash's
     widths, cut to one global layer (dense MLP) and one window layer
     (16 of 256 experts held): a fused decode burst of the engine's own
-    program, and a 256-token prefill chunk.  The global layer reads
+    program, and a 512-token prefill chunk.  The global layer reads
     through the Pallas decode kernel with K 192 and V 128 wide (one
     custom call), its pools keep their resident layout, and the counters
     ride under the burst's tokens."""
@@ -295,7 +295,7 @@ def test_hybrid_decode_and_prefill_compile_for_v5e(one_chip):
         moe_layers=(0, 1), experts_held=(0, 16), vocab_size=8192,
         attn_impl="pallas")
     S = _sds(one_chip)
-    B, MB, NB, K, T = 8, 6, 49, 4, 256
+    B, MB, NB, K, T = 8, 6, 49, 4, 512
     shapes = jax.eval_shape(
         lambda: mimo.init_params(cfg, jax.random.PRNGKey(0)))
     params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
@@ -319,10 +319,67 @@ def test_hybrid_decode_and_prefill_compile_for_v5e(one_chip):
     layouts = set(re.findall(rf"bf16\[1,4,{NB},(?:192|128),{BS}\]"
                              r"(\{[\d,]+)", hlo))
     assert layouts == {"{4,3,2,1,0"}, layouts
+    # the decode half of the expert layer was not touched: every held
+    # expert meets every lane (the dense form), no grouped matmul
+    assert f"bf16[16,{B},2048]" in hlo
     pre = jax.jit(partial(JaxEngine._prefill_impl, mimo, cfg),
                   donate_argnums=(1,))
-    mem = pre.lower(
+    program = pre.lower(
         params, kv, S((T,), i32), S((T,), i32), S((MB,), i32), S((), i32),
         S((), i32), S((), i32), S((), f32), S((), i32), S((), f32), None,
-        None, S((), i32)).compile().memory_analysis()
+        None, S((), i32)).compile()
+    mem = program.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    # a prompt-sized chunk groups its picks: three grouped matmuls at
+    # MiMo's widths, no token met every held expert
+    assert program.as_text().count("tpu_custom_call") == 3
+    assert f"bf16[16,{T},2048]" not in program.as_text()
+
+
+def test_moonlight_prefill_groups_its_picks_and_decode_does_not(one_chip):
+    """Moonlight's widths (benchmark/configs), cut to the dense layer and
+    one routed layer.  The 1024-token prefill program (at 2048 tokens
+    the dense dispatch's intermediate has the shape of the stacks
+    themselves, [64, 2048, 1408], and cannot be told from them) holds no
+    value of the dense dispatch's shape ([64, 1024, 1408]: every token
+    through every expert) and three grouped matmuls; the fused decode burst
+    still holds the dense form at its 16 lanes and no kernel at all (MLA
+    decode is jnp): the shape picks the form, and the decode half of the
+    expert layer is as it was."""
+    import json
+    from pathlib import Path
+
+    from benchmark.reference.deepseek import program_config
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.models import deepseek
+
+    hf = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                     / "moonlight-16b-a3b-8l.json").read_text())
+    cfg = dataclasses.replace(program_config(hf, "moonlight"), n_layers=2,
+                              vocab_size=8192)
+    S = _sds(one_chip)
+    B, MB, NB, K, T = 16, 20, 64, 4, 1024
+    shapes = jax.eval_shape(
+        lambda: deepseek.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    assert params["layers"][1]["moe_w_gate"].shape == (64, 2048, 1408)
+    kv = tuple(S(s, cfg.dtype)
+               for s in deepseek.kv_cache_shapes(cfg, NB, BS))
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+    prefill = jax.jit(partial(JaxEngine._prefill_impl, deepseek, cfg),
+                      donate_argnums=(1,)).lower(
+        params, kv, S((T,), i32), S((T,), i32), S((MB,), i32), S((), i32),
+        S((), i32), S((), i32), S((), f32), S((), i32), S((), f32)
+    ).compile().as_text()
+    assert prefill.count("tpu_custom_call") == 3
+    assert f"[64,{T},1408]" not in prefill
+    decode = jax.jit(
+        partial(JaxEngine._decode_multi_impl, deepseek, cfg, None, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9)).lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32)).compile().as_text()
+    assert f"bf16[64,{B},1408]" in decode
+    assert "tpu_custom_call" not in decode
