@@ -696,11 +696,18 @@ class JaxEngine:
         }
         for name in self._kv_counters:
             self.metrics[name] = 0
+        # a family whose layers differ in what they read counts its own
+        # decode reads, and one whose attention chooses its keys its
+        # prefill pairs too, from the host's positions; an empty burst
+        # or chunk names the counters (mimo.py: kv_window_block_steps
+        # against kv_uniform_block_steps; keye.py: sparse_*)
+        self._prefill_counts = getattr(self.family, "prefill_token_counts",
+                                       None)
         if hasattr(self.family, "decode_block_counts"):
-            # window-pool blocks the active lanes hold against what a
-            # uniform cache would hold for them, summed over decode steps
-            self.metrics.update(kv_window_block_steps=0,
-                                kv_uniform_block_steps=0)
+            self.metrics.update(self._decode_counts(np.zeros(0, np.int64),
+                                                    0))
+        if self._prefill_counts is not None:
+            self.metrics.update(self._prefill_counts(self.model_cfg, 0, 0))
         # the scheduler thread's phases: counters host_s.<kind> /
         # host_n.<kind> always, `dyn.<kind>` on the profiler's clock
         # while a session is live, ring spans under a Tracer (obs/)
@@ -1263,15 +1270,35 @@ class JaxEngine:
             self._task.cancel()
             self._task = None
         self._fail_all_streams()
-        self._inflight.clear()  # drop unread bursts (streams already dead)
-        self._pending_first.clear()  # and deferred first-token readbacks
+        # quiesce: a cancelled loop task does not stop a _sched_step
+        # already running in its thread (it may be mid-write into the G3
+        # dir whose ownership kvbm.close() releases), and what it
+        # dispatched is still on the device
+        await asyncio.to_thread(self._drain_device)
         if self.kvbm is not None:
-            # quiesce: a cancelled loop task does not stop a _sched_step
-            # already running in its thread, and that step may be mid-write
-            # into the G3 dir whose ownership kvbm.close() releases
-            await asyncio.to_thread(self._step_lock.acquire)
-            self._step_lock.release()
             self.kvbm.close()
+
+    def _drain_device(self) -> None:
+        """Wait out the running step and everything it dispatched, then
+        drop the unread bursts and deferred first-token readbacks (their
+        streams are dead).  Each holds a device-to-host copy that starts
+        when its program ends; a process that tore the runtime down
+        under such a copy died in it (SIGSEGV in CopyToLiteralAsync
+        after the result line, with seconds of 2048-token prefill
+        programs queued ahead of the last burst: my chip run, PR 33)."""
+        with self._step_lock:
+            pending = [e["burst"] for e in self._inflight] \
+                + [e["tok"] for e in self._pending_first]
+            self._inflight.clear()
+            self._pending_first.clear()
+            for arr in pending:
+                try:
+                    # dynlint: disable=DYN011 shutdown, after the last step: the wait is the point
+                    np.asarray(arr)
+                except Exception:  # noqa: BLE001 a failed program's output
+                    pass
+            # dynlint: disable=DYN011 shutdown, after the last step: the wait is the point
+            jax.block_until_ready(self.kv)
 
     def _fail_all_streams(
         self,
@@ -2747,6 +2774,10 @@ class JaxEngine:
         self.metrics["prefill_tokens"] += chunk
         self.metrics["moe_picks.prefill"] += \
             chunk * self._moe[0] * self._moe[1]
+        if self._prefill_counts is not None:
+            for name, n in self._prefill_counts(
+                    self.model_cfg, slot.prefill_pos, chunk).items():
+                self.metrics[name] += n
         slot.prefill_pos += chunk
         slot.prefill_chunks += 1
         slot.ctx_len = slot.prefill_pos
@@ -3570,6 +3601,12 @@ class JaxEngine:
             pass
         return burst, cont_burst
 
+    def _decode_counts(self, ctx, k: int) -> Dict[str, int]:
+        return self.family.decode_block_counts(
+            self.model_cfg, ctx, k, self.config.block_size,
+            self.config.max_num_seqs, self.config.max_blocks_per_seq,
+            self.model_cfg.attn_impl)
+
     def _count_decode_attn(self, ctx, k: int):
         """How far decode attention's reads follow the live context, for
         a burst of `k` steps over active lanes holding `ctx` tokens:
@@ -3582,11 +3619,9 @@ class JaxEngine:
         self.metrics["moe_picks.decode"] += k * len(ctx) * layers * picks
         self.metrics["moe_expert_slots.decode"] += k * layers * held
         if hasattr(self.family, "decode_block_counts"):
-            # more than one kind of layer: the family counts its own
-            for name, n in self.family.decode_block_counts(
-                    self.model_cfg, ctx, k, bs, self.config.max_num_seqs,
-                    self.config.max_blocks_per_seq,
-                    self.model_cfg.attn_impl).items():
+            # more than one kind of layer, or keys that are chosen: the
+            # family counts its own
+            for name, n in self._decode_counts(ctx, k).items():
                 self.metrics[name] += n
             return
         self.metrics["decode_attn_live_blocks"] += \

@@ -9,9 +9,10 @@
 
 The engine binds a family once via get_family(cfg) and never branches on
 architecture again — Llama/Qwen/Mixtral (llama.py, GQA cache), the
-DeepSeek MLA family (deepseek.py, latent cache) and the window + global
-hybrid over a share of the experts (mimo.py) serve through identical
-plumbing.
+DeepSeek MLA family (deepseek.py, latent cache), the window + global
+hybrid over a share of the experts (mimo.py) and the GQA decoder whose
+attention reads the keys a learned indexer chooses (keye.py) serve
+through identical plumbing.
 
 The cache is a tuple the family owns: the engine allocates one array a
 shape, hands the tuple to every program and takes it back.  A family
@@ -20,6 +21,24 @@ with ONE kind of layer has two members, (k-like, v-like), each
 table.  What a family may add, each read by the engine through the name
 given (never through the family's type):
 
+    more table-paged         members beyond (k-like, v-like), in the
+    members                  same [L, heads, blocks, width, block_size]
+                             form and paged by the SAME block table
+                             (keye.py: member 2, one index key a token
+                             and layer).  The family's own programs
+                             write them: every program that writes a
+                             token's K and V writes the others beside
+                             them (ops/sparse_attention.py
+                             `write_token_members` for a decode token,
+                             `write_packed_members` for a prefill
+                             chunk), so a block that prefix caching
+                             reuses, or a replay after a preemption
+                             recomputes, holds all its members.  The
+                             engine only allocates, donates and takes
+                             back; what reads the tuple as (k, v) or
+                             (k, v, k_scale, v_scale) by its length
+                             (KVBM tiers, disagg transfer, int8) is
+                             refused for such a family (`UNSUPPORTED`).
     kv_cache_dtypes(cfg)     a dtype a member, where not all cfg.dtype
     KV_LANE_ADDRESSED        True: some members are addressed by LANE
                              (the scheduler's slot) and position, not by
@@ -36,21 +55,29 @@ given (never through the family's type):
                              add to; a decode burst carries it home under
                              its tokens and the engine adds what it grew
                              by to `metrics` under these names.
-    decode_block_counts(..)  host-side block counts of a decode burst for
-                             a family whose layers differ in what they
-                             read (decode_attn_* and kv_* counters).
+    decode_block_counts(..)  host-side counts of a decode burst for a
+                             family whose layers differ in what they
+                             read (mimo.py: decode_attn_* and kv_*
+                             blocks) or whose attention chooses its keys
+                             (keye.py: sparse_* tokens); an empty burst
+                             names the counters.
+    prefill_token_counts(..) the same for a prefill chunk from the
+                             host's positions (keye.py: pairs scored
+                             and kept).
     kv_cache_scale_shapes /  int8 cache; prefill_packed, prefill_ring,
     _specs, prefill_packed,  spec_verify_packed, decode_hidden ...: a
     ...                      family without one falls back or refuses.
     UNSUPPORTED              what the engine must not promise for the
                              family (engine/core.py `_family_gaps`)."""
 
-from . import deepseek, llama, mimo
+from . import deepseek, keye, llama, mimo
 from .deepseek import DeepseekConfig
+from .keye import KeyeConfig
 from .llama import LlamaConfig, init_params
 from .mimo import MimoConfig
 
-PRESETS = {**llama.PRESETS, **deepseek.PRESETS, **mimo.PRESETS}
+PRESETS = {**llama.PRESETS, **deepseek.PRESETS, **mimo.PRESETS,
+           **keye.PRESETS}
 
 
 def get_family(cfg):
@@ -59,6 +86,8 @@ def get_family(cfg):
         return deepseek
     if isinstance(cfg, MimoConfig):
         return mimo
+    if isinstance(cfg, KeyeConfig):
+        return keye
     if isinstance(cfg, LlamaConfig):
         return llama
     raise TypeError(f"unknown model config type: {type(cfg).__name__}")
@@ -66,6 +95,7 @@ def get_family(cfg):
 
 __all__ = [
     "DeepseekConfig",
+    "KeyeConfig",
     "LlamaConfig",
     "MimoConfig",
     "PRESETS",

@@ -405,6 +405,20 @@ def experts_held(cfg) -> Tuple[int, int]:
     return getattr(cfg, "experts_held", None) or (0, cfg.n_experts)
 
 
+def moe_held_counts(cfg, top_e: jax.Array, valid: Optional[jax.Array]):
+    """(picks that fell on a held expert, held experts with a token), two
+    int32 scalars over the valid rows of top_e [T, k]: what a family
+    that holds a share of its experts counts on the device
+    (`KV_COUNTERS`)."""
+    first, count = experts_held(cfg)
+    on = (top_e >= first) & (top_e < first + count)
+    if valid is not None:
+        on = on & valid[:, None]
+    seen = jnp.zeros((count,), bool).at[
+        jnp.where(on, top_e - first, count)].set(True, mode="drop")
+    return jnp.sum(on, dtype=jnp.int32), jnp.sum(seen, dtype=jnp.int32)
+
+
 @jax.named_scope("dyn.moe_dispatch")
 def moe_dispatch_dense(layer, cfg: LlamaConfig, x: jax.Array,
                        top_w: jax.Array, top_e: jax.Array,

@@ -77,6 +77,7 @@ from .llama import (
     _logits,
     _mlp,
     moe_dispatch,
+    moe_held_counts,
     moe_rows,
     rms_norm,
     rope,
@@ -352,13 +353,7 @@ def _ffn(layer, cfg: MimoConfig, x: jax.Array,
         return _mlp(layer, x), zero, zero
     top_w, top_e = _ds_router(layer, cfg, x)
     out = moe_dispatch(layer, cfg, x, top_w, top_e, valid)
-    first, count = cfg.held
-    on = (top_e >= first) & (top_e < first + count)
-    if valid is not None:
-        on = on & valid[:, None]
-    seen = jnp.zeros((count,), bool).at[
-        jnp.where(on, top_e - first, count)].set(True, mode="drop")
-    return out, jnp.sum(on, dtype=jnp.int32), jnp.sum(seen, dtype=jnp.int32)
+    return (out,) + moe_held_counts(cfg, top_e, valid)
 
 
 def _pool_index(cfg: MimoConfig):

@@ -207,15 +207,22 @@ def _decode_kernel(
     v_hbm,        #   in HBM; the DMA descriptor picks layer and block
                   #   (V may be another width: [.., hdv, bs], out hdv)
     # int8 caches add (ks_hbm, vs_hbm) [L, nkv, num_blocks, bs] fp32
-    # ANY, then: o_ref [1, nkv, group, hd] VMEM; scratch k_buf/v_buf
+    # ANY; `biased` adds bias_ref [1, n_chunks, 1, S] fp32 VMEM (this
+    # sequence's per-position addend to the scores); then: o_ref
+    # [1, nkv, group, hd] VMEM; scratch k_buf/v_buf
     # [2, nkv, hd, S] VMEM (+ks_buf/vs_buf [2, nkv, S] fp32), DMA
     # semaphores [2 slots, 2 (k/v) or 4 (+scales)]
     *rest,
     bpc: int,
     bs: int,
     quantized: bool = False,
+    biased: bool = False,
     debug_mode: str = "",  # "" | "dma_only" | "compute_only" (profiling)
 ):
+    bias_ref = None
+    if biased:       # the last input, after the scales
+        at = 2 if quantized else 0
+        bias_ref, rest = rest[at], rest[:at] + rest[at + 1:]
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem) = rest
     else:
@@ -295,6 +302,8 @@ def _decode_kernel(
             q, k, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
+        if biased:
+            s = s + bias_ref[0, c][None]
         pos = c * S + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(pos < kv_len, s, NEG_INF)
 
@@ -344,6 +353,10 @@ def paged_attention_decode_pallas(
     debug_mode: str = "",
     k_scale: jax.Array = None,  # [L, nkv, num_blocks, bs] fp32 (int8)
     v_scale: jax.Array = None,
+    bias: jax.Array = None,     # [B, max_blocks * bs] fp32, added to a
+                                #   lane's scores position by position
+                                #   (NEG_INF = a token left out:
+                                #   ops/sparse_attention.py)
 ) -> jax.Array:
     """Drop-in fast path for paged_attention.paged_attention_decode.
 
@@ -406,6 +419,14 @@ def paged_attention_decode_pallas(
                      pl.BlockSpec(memory_space=pl.ANY)]
         scratch += [pltpu.VMEM((2, nkv, S), jnp.float32),
                     pltpu.VMEM((2, nkv, S), jnp.float32)]
+    if bias is not None:
+        # a sequence's whole bias rides VMEM (4 bytes a position), one
+        # [1, S] row a chunk, indexed by the chunk loop
+        inputs.append(jnp.pad(
+            bias.astype(jnp.float32), ((0, 0), (0, pad * bs)),
+            constant_values=NEG_INF).reshape(B, n_chunks, 1, S))
+        in_specs.append(pl.BlockSpec((1, n_chunks, 1, S),
+                                     lambda b, *refs: (b, 0, 0, 0)))
     scratch.append(pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)))
     # bytes per context position per head: int8 streams 1-byte elements
     # plus one fp32 scale per (head, position)
@@ -413,7 +434,8 @@ def paged_attention_decode_pallas(
                  + (8 if quantized else 0))
     out = pl.pallas_call(
         functools.partial(_decode_kernel, bpc=bpc, bs=bs,
-                          quantized=quantized, debug_mode=debug_mode),
+                          quantized=quantized, biased=bias is not None,
+                          debug_mode=debug_mode),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(B,),

@@ -386,3 +386,51 @@ async def test_mocker_overlap_byte_identity_and_cont_bursts():
     assert any(over_cont), "overlap sim never marked a continuation"
     # fused bursts amortize dispatches: strictly fewer of them
     assert len(over_d) < len(sync_d)
+
+
+async def _until(cond, what, timeout=60.0):
+    t0 = asyncio.get_running_loop().time()
+    while not cond():
+        assert asyncio.get_running_loop().time() - t0 < timeout, what
+        await asyncio.sleep(0.005)
+
+
+async def test_close_with_bursts_in_flight_returns():
+    """close() while fused bursts are dispatched and unread: it waits
+    out the step and what the step dispatched (`_drain_device`), leaves
+    nothing in flight, the stream ends in the worker-engine-error marker
+    and a second close() is harmless."""
+    eng = engine(overlap_scheduling=True)
+    outs = []
+
+    async def run():
+        async for out in eng.generate(greedy_req(PROMPTS[0], 400, "inflight")):
+            outs.append(out)
+
+    task = asyncio.create_task(run())
+    await _until(lambda: len(eng._inflight) > 0 and outs,
+                 "no burst was ever in flight")
+    await asyncio.wait_for(eng.close(), 60.0)
+    await asyncio.wait_for(task, 10.0)
+    assert not eng._inflight and not eng._pending_first
+    assert outs[-1].finish_reason == "error"
+    assert "worker engine error" in outs[-1].error
+    await asyncio.wait_for(eng.close(), 10.0)
+
+
+async def test_close_after_a_failed_step_returns():
+    """A step that raises kills the loop with bursts still unread:
+    later requests fail fast, and close() then returns all the same
+    (the drain swallows what a failed program left behind)."""
+    eng = engine(overlap_scheduling=True)
+    plane = chaos.ChaosPlane(seed=3).rule(
+        "engine.step", "fail", after=4, times=1,
+        error="worker engine error: chaos crash on step N")
+    with plane:
+        with pytest.raises(RuntimeError, match="worker engine error"):
+            await collect(eng, greedy_req(PROMPTS[0], 400, "crash"))
+    assert plane.fired() == 1
+    with pytest.raises(RuntimeError, match="worker engine error"):
+        await collect(eng, greedy_req(PROMPTS[1], 2, "post-crash"))
+    await asyncio.wait_for(eng.close(), 60.0)
+    assert not eng._inflight and not eng._pending_first

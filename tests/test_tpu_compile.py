@@ -383,3 +383,72 @@ def test_moonlight_prefill_groups_its_picks_and_decode_does_not(one_chip):
         S((), i32)).compile().as_text()
     assert f"bf16[64,{B},1408]" in decode
     assert "tpu_custom_call" not in decode
+
+
+def test_sparse_decode_and_prefill_compile_for_v5e(one_chip):
+    """The family whose attention chooses its keys (models/keye.py) at
+    Keye-VL-2.0's widths, cut to two layers (16 of 128 experts held),
+    with the long-context cell's cache (1593 blocks, 8 lanes x 199):
+    a fused decode burst of the engine's own program finds each lane's
+    top-k threshold in VMEM and reads K and V through the Pallas decode
+    kernel under a per-token bias, and a 2048-token packed prefill chunk
+    over the whole table width runs the threshold search, one flash
+    pass under the mask and three grouped matmuls a layer.  In
+    both, the three table-paged pools (K, V, index keys) keep their
+    resident layout and are never copied, and the temporaries stay
+    beside 8.9 GB of weights and cache at the cell's 12 layers (they do
+    not grow with depth: 0.44 GB at 2 layers, off-chip compiles, PR
+    33)."""
+    import re
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.models import keye
+
+    L, NB, B, MB, K, T = 2, 1593, 8, 199, 8, 2048
+    cfg = dataclasses.replace(
+        keye.PRESETS["keye-vl-2.0-30b-a3b"], n_layers=L,
+        experts_held=(0, 16), attn_impl="pallas")
+    S = _sds(one_chip)
+    shapes = jax.eval_shape(
+        lambda: keye.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    kv = tuple(S(s, d) for s, d in zip(keye.kv_cache_shapes(cfg, NB, BS),
+                                       keye.kv_cache_dtypes(cfg)))
+    assert kv[2].shape == (L, 1, NB, 64, BS)
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+    pools = (rf"bf16\[{L},4,{NB},128,{BS}\]", rf"bf16\[{L},1,{NB},64,{BS}\]")
+
+    def pools_stay(hlo):
+        for pool in pools:
+            assert not re.findall(rf"= {pool}\S* copy\(", hlo)
+            assert set(re.findall(rf"{pool}(\{{[\d,]+)", hlo)) \
+                == {"{4,3,2,1,0"}
+
+    fn = jax.jit(
+        partial(JaxEngine._decode_multi_impl, keye, cfg, None, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9))
+    lowered = fn.lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32))
+    assert lowered.out_info[0].shape == (K + len(keye.KV_COUNTERS), B)
+    hlo = lowered.compile().as_text()
+    # a layer: the top-k threshold search and the decode kernel
+    assert hlo.count("tpu_custom_call") == 2 * L
+    pools_stay(hlo)
+    # a decode step keeps the dense form: every held expert, every lane
+    assert f"bf16[16,{B},768]" in hlo
+    pre = jax.jit(partial(JaxEngine._prefill_packed_impl, keye, cfg, None),
+                  donate_argnums=(1,))
+    program = pre.lower(
+        params, kv, S((T,), i32), S((T,), i32), S((T,), i32),
+        S((1, MB), i32), S((1,), i32), S((T,), b1), S((1,), i32),
+        S((1,), f32), S((1,), i32), S((1,), f32)).compile()
+    hlo = program.as_text()
+    # a layer: the threshold search, the flash pass under the mask and
+    # three grouped matmuls
+    assert hlo.count("tpu_custom_call") == 5 * L
+    pools_stay(hlo)
+    assert program.memory_analysis().temp_size_in_bytes < 1.0e9
