@@ -47,6 +47,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 from dynamo_tpu.models import llama            # noqa: E402
 from dynamo_tpu.ops import packed_prefill as pp  # noqa: E402
+from dynamo_tpu.quant.kv import quantize_tokens  # noqa: E402
 from dynamo_tpu.runtime.device import (  # noqa: E402
     device_identity,
     device_peaks,
@@ -71,6 +72,117 @@ def timeit(fn, n=4, warm=1):
     return (time.perf_counter() - t0) / n
 
 
+def _sweep_stream(T, width, rows, block):
+    """One packed stream of `rows` equal runs that END near the table's
+    last column (a prompt's last chunk; its first when T fills the
+    table), every run starting inside a block where there is room."""
+    chunk = T // rows
+    start = max(0, width * block - chunk)
+    start -= 37 if start >= 37 else 0
+    seg = np.repeat(np.arange(rows, dtype=np.int32), chunk)
+    pos = np.tile(start + np.arange(chunk, dtype=np.int32), rows)
+    tables = 1 + np.arange(rows * width, dtype=np.int32).reshape(rows,
+                                                                   width)
+    return seg, pos, np.ones(T, bool), tables
+
+
+def _quantize_cache(x):
+    """[L, nkv, NB, hd, bs] -> (int8 cache, fp32 scales [L, nkv, NB, bs]):
+    per-position quantization over hd, the serving convention
+    (quant/kv.py)."""
+    q8, sc = quantize_tokens(x.swapaxes(3, 4))
+    return q8.swapaxes(3, 4), sc
+
+
+def attn_sweep(args, cfg):
+    """The packed attention op alone, one layer, over `--sweep`'s
+    (tokens x table width [x rows]) shapes: the float32 XLA scan, the
+    packed kernel at each of `--tiles` (token_block x chunk_cols; 0 =
+    its own), ops/sparse_attention._masked_flash_pallas under an
+    all-causal mask, and what "auto" resolves to.  One JSON line a
+    shape, ms a layer, each form's largest difference from the first."""
+    from dynamo_tpu.ops.pallas_packed_prefill import (
+        packed_prefill_attention_pallas,
+    )
+    from dynamo_tpu.ops.sparse_attention import _masked_flash_pallas
+
+    interpret = args.mode != "tpu"
+    L, nkv, nh, hd, bs = 4, cfg.n_kv_heads, cfg.n_heads, cfg.head_dim, \
+        args.block
+    tiles = [tuple(int(x) for x in t.split("x"))
+             for t in args.tiles.split(",") if t]
+    rng = np.random.default_rng(0)
+    for shape in args.sweep.split(","):
+        T, width, rows = (tuple(int(x) for x in shape.split("x"))
+                          + (1,))[:3]
+        seg, pos, valid, tables = _sweep_stream(T, width, rows, bs)
+        nb = 1 + rows * width
+        kc, vc = (jnp.asarray(rng.standard_normal((L, nkv, nb, hd, bs)),
+                              cfg.dtype) for _ in range(2))
+        q0 = jnp.asarray(rng.standard_normal((T, nh, hd)), cfg.dtype)
+        a = tuple(jnp.asarray(x) for x in (tables, seg, pos, valid))
+        scales = {}
+        if args.int8:
+            (kc, ks), (vc, vs) = _quantize_cache(kc), _quantize_cache(vc)
+            scales = dict(k_scale=ks, v_scale=vs)
+
+        def masked(q, kc, vc, li, tables, seg, pos, valid):
+            out = jnp.zeros(q.shape, jnp.float32)
+            span = jnp.arange(tables.shape[1] * bs)
+            for s in range(tables.shape[0]):
+                own = (seg == s) & valid
+                sel = own[:, None] & (span[None, :] <= pos[:, None])
+                o = _masked_flash_pallas(q, kc, vc, li, tables[s], sel,
+                                         interpret)
+                out = jnp.where(own[:, None, None], o, out)
+            return out.astype(q.dtype)
+
+        forms = {
+            "xla": lambda *x: pp.packed_prefill_attention(
+                *x, impl="xla", **scales),
+            "auto": lambda *x: pp.packed_prefill_attention(
+                *x, impl="auto", **scales),
+        }
+        if not args.int8:       # the masked pass has no int8 form
+            forms["masked"] = masked
+        for tb, cc in tiles:
+            forms[f"tile{tb}x{cc}"] = (
+                lambda q, kc, vc, li, *r, tb=tb, cc=cc:
+                packed_prefill_attention_pallas(
+                    q, kc, vc, li, *r, token_block=tb, chunk_cols=cc,
+                    interpret=interpret, **scales))
+        forms = {k: f for k, f in forms.items()
+                 if ("tile" if k.startswith("tile") else k)
+                 in args.forms.split(",")}
+        row = {"bench": "prefill_attn_sweep", "mode": args.mode,
+               "model": args.model, "tokens": T, "width": width,
+               "rows": rows, "int8": args.int8, "ms": {},
+               "max_abs_err": {}}
+        ref = None
+        for name, form in forms.items():
+            @jax.jit
+            def chain(q, kc, vc, *r, form=form):
+                for li in range(L):
+                    o = form(q, kc, vc, li, *r)
+                    q = (o.astype(jnp.float32) * 0.999).astype(q.dtype)
+                return q
+
+            one = jax.jit(lambda q, kc, vc, *r, form=form:
+                          form(q, kc, vc, L - 1, *r))
+            try:
+                got = np.asarray(one(q0, kc, vc, *a), np.float32)
+                ref = got if ref is None else ref
+                row["max_abs_err"][name] = round(
+                    float(np.abs(got - ref).max()), 5)
+                row["ms"][name] = round(
+                    timeit(lambda: chain(q0, kc, vc, *a), n=args.reps)
+                    * 1e3 / L, 4)
+            except Exception as e:  # a tile that does not compile
+                row["ms"][name] = None
+                row["max_abs_err"][name] = repr(e)[:200]
+        print(json.dumps(row), flush=True)
+
+
 def main():
     p = argparse.ArgumentParser(
         description="per-phase prefill profiler (see module docstring)")
@@ -92,6 +204,18 @@ def main():
                         "tile-skip kernel (compiled in --mode tpu, "
                         "interpreted in --mode smoke) and prints both "
                         "variants' MFU in one JSON line")
+    p.add_argument("--sweep", default="",
+                   help="attention op alone over shapes: comma-separated "
+                        "TOKENSxWIDTH[xROWS] (table width in blocks); "
+                        "needs no weights, so any preset's widths fit")
+    p.add_argument("--tiles", default="128x8",
+                   help="--sweep: the tile-skip kernel's "
+                        "token_block x chunk_cols variants, comma-separated")
+    p.add_argument("--forms", default="xla,auto,masked,tile",
+                   help="--sweep: which columns to time")
+    p.add_argument("--int8", action="store_true",
+                   help="--sweep: over an int8 cache with scale planes")
+    p.add_argument("--reps", type=int, default=8)
     p.add_argument("--mode", default="tpu", choices=["tpu", "smoke"],
                    help="tpu (default): needs a TPU and fails without "
                         "one; interpret-mode kernels are an error.  "
@@ -113,6 +237,8 @@ def main():
                 else flops / t / (peak_tflops * 1e12))
 
     print(f"device: {json.dumps(device)} mode={args.mode}")
+    if args.sweep:
+        return attn_sweep(args, llama.PRESETS[args.model])
     if args.seqs > args.tokens:
         p.error(f"--seqs ({args.seqs}) must be <= --tokens "
                 f"({args.tokens})")

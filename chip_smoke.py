@@ -13,7 +13,9 @@ One chip (the default, what the driver runs):
             (same text), then SIGTERM and exit code 0 from both.
   numerics  after the worker is gone, a child run in turn: the COMPILED
             Pallas decode and packed-prefill kernels against their XLA
-            references at llama-3b widths (bf16 and int8); JaxEngine
+            references at llama-3b widths (bf16 and int8), and packed
+            "auto" at the doc cell's shape (2048 tokens, one row, table
+            width 50, Mistral's widths) against the scan; JaxEngine
             serving one request cold and again as a prefix-cache hit
             (first-token logits of the engine's own two prefills within
             a bf16 tolerance); and JaxEngine with attn_impl /
@@ -369,7 +371,9 @@ def phase_serve(args, env: dict, children: list, deadline: float) -> dict:
               "kv_cache_dtype", "overlap_scheduling", "prefill_packed")}
     say(f"engine resolved impls: {json.dumps(impls)} "
         "(attn: 'auto' as resolved for this worker, the Pallas kernel on "
-        "a TPU with 128-token blocks; packed 'auto' = XLA masked flash)")
+        "a TPU with 128-token blocks; packed 'auto' is resolved a program "
+        "by the stream's length: the Pallas kernel on a TPU from 1024 "
+        "tokens up, the XLA scan under that)")
     warm = wsrc["compile_watch"]
     say(f"after warm-up: compiles={json.dumps(warm['counts'])} "
         f"seconds={json.dumps(warm['seconds'])} "
@@ -579,9 +583,10 @@ def check_kernels(args, pallas: str) -> dict:
         return (jnp.asarray(rng.standard_normal(shape), jnp.float32),
                 jnp.asarray(rng.standard_normal(shape), jnp.float32))
 
-    def compare(name, ref_name, what, kf, vf, run, mask=None):
+    def compare(name, ref_name, what, kf, vf, run, mask=None,
+                impl=pallas):
         """run(kc, vc, impl=..., **scales) on a bf16 and an int8 copy of
-        the same cache: the Pallas impl against the reference impl."""
+        the same cache: the impl named against the reference impl."""
         for tag in ("bf16", "int8"):
             if tag == "int8":
                 (kc, ks), (vc, vs) = (_quantize_cache(kf),
@@ -591,7 +596,7 @@ def check_kernels(args, pallas: str) -> dict:
                 kc, vc = kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16)
                 scales = {}
             ref = run(kc, vc, None, **scales).astype(jnp.float32)
-            got = run(kc, vc, pallas, **scales).astype(jnp.float32)
+            got = run(kc, vc, impl, **scales).astype(jnp.float32)
             diff = jnp.abs(got - ref)
             if mask is not None:
                 diff = jnp.where(mask, diff, 0.0)
@@ -602,7 +607,7 @@ def check_kernels(args, pallas: str) -> dict:
                   f"{name} kernel ({tag}) differs from {ref_name} by "
                   f"{err:.4f} > {KERNEL_ATOL}")
             out[f"{name}_{tag}_max_abs_err"] = round(err, 5)
-            say(f"{name} kernel {pallas} vs {ref_name}, {tag}, {what}: "
+            say(f"{name} kernel {impl} vs {ref_name}, {tag}, {what}: "
                 f"max|err|={err:.5f} (atol {KERNEL_ATOL})")
 
     # -- decode: B sequences, uneven kv_lens incl. partial / single block
@@ -662,6 +667,30 @@ def check_kernels(args, pallas: str) -> dict:
     compare("packed", "the xla impl",
             f"T={T} S={S} seg_len={seg_len} ctx0={ctx0}", kf, vf, packed,
             mask=jnp.asarray(valid)[:, None, None])
+
+    # -- the doc cell's last chunk (`mistral-7b.doc-closed`): ONE row of
+    # T tokens at the end of a 48-block context under the cell's table
+    # width of 50, Mistral's widths, run as whatever "auto" resolves to
+    # here (ops/packed_prefill.resolve_packed_impl) against the scan
+    from dynamo_tpu.ops.packed_prefill import resolve_packed_impl
+
+    if not args.rehearse:
+        nkv, nh, mb = 8, 32, 50
+    start = max(0, (mb - 2) * bs - T)
+    kf, vf = cache(1 + mb)
+    qp = jnp.asarray(rng.standard_normal((T, nh, hd)), jnp.bfloat16)
+    a = (jnp.asarray(1 + np.arange(mb, dtype=np.int32))[None],
+         jnp.zeros(T, jnp.int32),
+         jnp.asarray(start + np.arange(T, dtype=np.int32)),
+         jnp.ones(T, bool))
+    out["packed_auto_impl"] = {
+        tag: resolve_packed_impl("auto", jax.default_backend(), bs, hd, dt,
+                                 T)
+        for tag, dt in (("bf16", jnp.bfloat16), ("int8", jnp.int8))}
+    compare("packed_auto", "the xla impl",
+            f"T={T} one row at {start}.. width {mb} nkv={nkv} nh={nh}, "
+            f"auto = {json.dumps(out['packed_auto_impl'])}", kf, vf,
+            packed, impl="auto")
     jax.clear_caches()
     return out
 

@@ -66,8 +66,9 @@ def build_args() -> argparse.ArgumentParser:
                    help="packed-prefill attention impl "
                         "(ops/pallas_packed_prefill.py): pallas = "
                         "segment-aware tile-skip kernel (no S-fold "
-                        "attention overhead), xla = masked reference; "
-                        "default keeps the model family's choice")
+                        "attention overhead), xla = float32 scan; "
+                        "default keeps the model family's choice "
+                        "(auto: by platform, cache and stream length)")
     from ..ops.fused_sampling import EPILOGUE_MODES
 
     p.add_argument("--sampling-epilogue", default="off",

@@ -131,9 +131,10 @@ class EngineConfig:
     # kernel per worker without a custom model_config.
     attn_impl: str = ""
     # packed-prefill attention impl override ("" = family default):
-    # "auto"/"xla" (the masked XLA reference, S-fold attention FLOPs)
-    # | "pallas"/"pallas_interpret" (the tile-skip kernel,
-    # ops/pallas_packed_prefill.py).  Also selects the kernel for
+    # "auto" (ops/packed_prefill.resolve_packed_impl: the kernel on a
+    # TPU from 1024 tokens a program, the scan elsewhere) | "xla" (the float32 scan, S-fold attention
+    # FLOPs) | "pallas"/"pallas_interpret" (the tile-skip kernel,
+    # ops/pallas_packed_prefill.py).  Also selects the impl for
     # spec_verify, which rides the same packed path.
     packed_attn_impl: str = ""
     # fused sampling/top-k epilogue (ops/fused_sampling.py): "fused"

@@ -53,6 +53,7 @@ from ..tokens import TokenBlockSequence, request_salt
 from .block_allocator import BlockAllocator
 from .config import EngineConfig
 from ..ops.fused_sampling import fused_greedy_tokens, fused_sample_tokens
+from ..ops.packed_prefill import resolve_packed_impl
 from ..ops.paged_attention import PALLAS_IMPLS, resolve_decode_impl
 from .sampler import greedy_tokens, sample_tokens
 
@@ -693,6 +694,11 @@ class JaxEngine:
             # grouped form (llama.moe_form: by shape);
             # / prefill_tokens says how often it engaged
             "moe_grouped_tokens.prefill": 0,
+            # prompt tokens whose packed program ran its attention in
+            # the Pallas kernel (ops/packed_prefill.resolve_packed_impl:
+            # by platform, cache and the stream's length);
+            # / prefill_tokens says how often it engaged
+            "prefill_attn_kernel_tokens": 0,
         }
         for name in self._kv_counters:
             self.metrics[name] = 0
@@ -2497,6 +2503,17 @@ class JaxEngine:
         return self._moe[0] > 0 \
             and moe_form(self.model_cfg, tokens) == "grouped"
 
+    def _prefill_attn_kernel(self, bucket: int) -> bool:
+        """Whether a packed prefill program of `bucket` tokens runs its
+        attention in the Pallas kernel: the rule the traced code applies
+        (ops/packed_prefill.resolve_packed_impl), asked from the host."""
+        impl = getattr(self.model_cfg, "packed_attn_impl", None)
+        return impl is not None and resolve_packed_impl(
+            impl, self.mesh.devices.flat[0].platform,
+            self.config.block_size, self.model_cfg.head_dim,
+            jnp.int8 if self.kv_dtype == "int8" else self.model_cfg.dtype,
+            bucket) in PALLAS_IMPLS
+
     def _fpm_prefill(self, rows: int, tokens: int, bucket: int,
                      packed: bool = False, completing: int = 0) -> None:
         """One FPM record per prefill program — the inputs the SLA
@@ -2536,6 +2553,8 @@ class JaxEngine:
         if self._moe_grouped(bucket if packed
                              else _pow2_len(rows) * bucket):
             self.metrics["moe_grouped_tokens.prefill"] += tokens
+        if packed and self._prefill_attn_kernel(bucket):
+            self.metrics["prefill_attn_kernel_tokens"] += tokens
 
     def _prefill_packed_step(self, pslots, budget: int) -> None:
         """One packed prefill dispatch: the planner water-fills the token

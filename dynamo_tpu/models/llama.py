@@ -54,9 +54,10 @@ class LlamaConfig:
     # choice accepts int8 caches — the Pallas kernel dequantizes
     # in-kernel)
     attn_impl: str = "auto"
-    # packed-prefill attention path: "auto"/"xla" (the masked XLA
-    # reference) | "pallas"/"pallas_interpret" (the tile-skip kernel,
-    # ops/pallas_packed_prefill.py)
+    # packed-prefill attention path: "auto" (decided a program by
+    # ops/packed_prefill.resolve_packed_impl: platform, cache, stream
+    # length) | "xla" (the float32 scan) | "pallas"/"pallas_interpret"
+    # (the tile-skip kernel, ops/pallas_packed_prefill.py)
     packed_attn_impl: str = "auto"
     # stop-token set (instruct checkpoints often declare several, e.g.
     # llama-3's <|end_of_text|> and <|eot_id|>)

@@ -29,21 +29,21 @@ boundaries need no special casing: later chunks of the same prompt (even
 co-packed in one dispatch at consecutive positions) attend to earlier
 ones exactly like a prefix-cache hit.
 
-The attention is flash-style: an online-softmax (running max / sum)
-lax.scan over block-column chunks of the gathered context, so the score
-matrix never materializes beyond [T, nh, chunk].  One pass runs per
-segment row (S is small — max_prefill_seqs); each pass computes scores
-for the whole packed stream and masks foreign tokens out, an S-fold
-attention-FLOP overhead traded for zero padding on the projection/MLP
-FLOPs that dominate prefill at serving context lengths.  `impl` selects
-the implementation: "xla"/"auto" is this reference path;
-"pallas"/"pallas_interpret" is the hand-tiled kernel
-(ops/pallas_packed_prefill.py) whose per-token-block segment-aware
-iteration SKIPS (token-block, context-chunk) tiles that belong to
-other segments instead of computing-then-masking — no S-fold overhead,
-and the context streams HBM->VMEM by physical block id instead of
-through an XLA gather.  Both accept int8 caches (the kernel
-dequantizes in VMEM, the reference on the gather).
+The attention is flash-style, in two forms.  The reference ("xla") is
+an online-softmax (running max / sum) lax.scan over block-column chunks
+of the gathered context in float32: the score block [T, nh, chunk]
+goes out to HBM and back every step, and one pass runs per segment row
+over the WHOLE stream and table (foreign tokens and the pairs above the
+causal diagonal are computed, then masked).  The kernel ("pallas" /
+"pallas_interpret", ops/pallas_packed_prefill.py) runs the same pairs'
+attention as one Pallas call a layer: operands in the cache's dtype,
+running max, sum and accumulator float32 in VMEM, no score block in
+HBM, a (query tile, key tile) pair computed only where a query of the
+tile can see a key of the tile (its own segment row, at or before its
+position), K and V moved from the pool by physical block id.  "auto"
+is decided in one place, `resolve_packed_impl`, from the platform, the
+cache and the stream's length.  Both forms accept int8 caches (the
+kernel dequantizes in VMEM, the reference on the gather).
 
 Shape/layout conventions match ops/paged_attention.py: cache
 [L, nkv, nb, hd, bs] head-major transposed blocks, physical block 0 is
@@ -73,6 +73,44 @@ from .paged_attention import NEG_INF, _gqa_out, _gqa_scores
 # truth the engine's --packed-attn-impl validation and CLI choices
 # reference (a new impl added here is accepted end-to-end)
 PACKED_IMPLS = ("auto", "xla", "pallas", "pallas_interpret")
+
+# the stream from which "auto" is the kernel on a TPU.  One layer alone on
+# a v5e (32 heads over 8 KV heads of 128, block 128, tables of 16 to 50
+# blocks; PERF.md section 6, PR 34) the kernel is no slower from 128
+# tokens, but to 512 tokens the two forms are within 0.11 ms a layer
+# (scan 0.09-0.61, kernel 0.08-0.50), and a program that holds the
+# kernel costs a warm set-up a third of a second more to trace and
+# lower; from 1024 tokens the scan's score block no longer stays on the
+# chip (1.25-4.2 ms, then 2.5-8.3 at 2048) and the kernel takes 0.33-0.94,
+# then 0.53-1.76.  The table width moves neither crossover.
+KERNEL_MIN_TOKENS = 1024
+
+
+def resolve_packed_impl(impl: str, platform: str, block_size: int,
+                        head_dim: int, cache_dtype, tokens: int) -> str:
+    """What `impl` means for this cache, on this platform, for a packed
+    stream of `tokens`: the one place "auto" is decided, from what the
+    code can observe (paged_attention.resolve_decode_impl's twin; the
+    engine asks it from the host for `prefill_attn_kernel_tokens`).  An
+    explicit impl is returned as given.
+
+    "auto" is the kernel ("pallas") where it can run as written and
+    repays its set-up: a TPU backend, block_size a multiple of 128 (the
+    lane dimension of the [hd, bs] planes it moves), head_dim a multiple
+    of 128 (a query tile holds a head every head_dim lanes), a bf16 or
+    int8 cache, and a stream of KERNEL_MIN_TOKENS tokens or more.
+    Everywhere else (CPU, block_size 16, fp32 caches, the short buckets,
+    speculative verification's rows of k + 1 tokens) it is the float32
+    scan ("xla").  Under tensor parallelism the kernel runs per shard
+    (`_packed_pallas_tp`): the rule is the same."""
+    if impl != "auto":
+        return impl
+    dt = jnp.dtype(cache_dtype)
+    if (platform == "tpu" and block_size % 128 == 0 and head_dim % 128 == 0
+            and dt in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.int8))
+            and tokens >= KERNEL_MIN_TOKENS):
+        return "pallas"
+    return "xla"
 
 
 def check_packed_stream(seg_ids: np.ndarray, positions: np.ndarray,
@@ -268,7 +306,7 @@ def _segment_flash(q, k_cache, v_cache, layer, table, token_mask,
 
 
 def _packed_pallas_tp(q, k_cache, v_cache, layer, block_tables, seg_ids,
-                      positions, valid, *, mesh, interpret, chunk_cols,
+                      positions, valid, *, mesh, interpret,
                       k_scale=None, v_scale=None):
     """Packed-prefill kernel under tensor parallelism
     (paged_attention.kernel_tp_call — the shard_map scaffolding shared
@@ -285,8 +323,7 @@ def _packed_pallas_tp(q, k_cache, v_cache, layer, block_tables, seg_ids,
         ks, vs = scales if quantized else (None, None)
         return packed_prefill_attention_pallas(
             q, kc, vc, layer, tables, seg, pos, val,
-            chunk_cols=chunk_cols, interpret=interpret,
-            k_scale=ks, v_scale=vs,
+            interpret=interpret, k_scale=ks, v_scale=vs,
         )
 
     return kernel_tp_call(
@@ -323,32 +360,41 @@ def packed_prefill_attention(
     the chunk's own K/V round-trip the quantizer before attention reads
     them — bit-consistent with how every later chunk will see them).
 
-    impl: "auto"/"xla" (this XLA reference — one masked flash pass per
-    segment row, S-fold attention FLOPs); "pallas"/"pallas_interpret"
-    (ops/pallas_packed_prefill.py — per-token-block tile-skip
-    iteration, ~1x attention FLOPs, context DMA'd HBM->VMEM by
-    physical block id).  Int8 caches work on every impl.  `mesh` is
-    required for the Pallas path when the cache is tensor-parallel
-    (kv_heads over a "tp" axis): the kernel then runs under shard_map
-    per shard, like the decode kernel.
+    impl: "xla" (the float32 scan: one masked flash pass per segment
+    row, S-fold attention FLOPs), "pallas" / "pallas_interpret"
+    (ops/pallas_packed_prefill.py: one kernel a layer, bf16 operands,
+    pairs outside a tile's segment and causal frontier skipped, context
+    moved by physical block id), or "auto" (`resolve_packed_impl` on the
+    default backend: the kernel on a TPU from KERNEL_MIN_TOKENS tokens,
+    the scan under that and elsewhere).  Int8 caches work on
+    every impl.  `mesh` is required for the kernel when the cache is
+    tensor-parallel (kv_heads over a "tp" axis): it then runs under
+    shard_map per shard, like the decode kernel.  `chunk_cols` is the
+    scan's step; the kernel's tiles are its own.
     """
+    impl = resolve_packed_impl(impl, jax.default_backend(),
+                               k_cache.shape[4], k_cache.shape[3],
+                               k_cache.dtype, q.shape[0])
     if impl in ("pallas", "pallas_interpret"):
         interpret = impl == "pallas_interpret"
+        # traced, like `_store_planes`' layer: the kernel (a jit of its
+        # own) is traced and lowered once a program, not once a layer
+        layer = jnp.int32(layer)
         tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
         if tp > 1:
             return _packed_pallas_tp(
                 q, k_cache, v_cache, layer, block_tables, seg_ids,
                 positions, valid, mesh=mesh, interpret=interpret,
-                chunk_cols=chunk_cols, k_scale=k_scale, v_scale=v_scale,
+                k_scale=k_scale, v_scale=v_scale,
             )
         from .pallas_packed_prefill import packed_prefill_attention_pallas
 
         return packed_prefill_attention_pallas(
             q, k_cache, v_cache, layer, block_tables, seg_ids,
-            positions, valid, chunk_cols=chunk_cols, interpret=interpret,
+            positions, valid, interpret=interpret,
             k_scale=k_scale, v_scale=v_scale,
         )
-    if impl not in ("auto", "xla"):
+    if impl != "xla":
         raise ValueError(
             f"unknown packed-prefill impl {impl!r}; expected "
             + " | ".join(PACKED_IMPLS)

@@ -1,69 +1,54 @@
 """Pallas TPU packed-prefill kernel: segment-aware causal flash attention
-over the packed token stream.
+over the packed token stream, one call a layer.
 
-The hand-tiled fast path filling the `impl="pallas"` slot
-ops/packed_prefill.py reserved.  The XLA reference there runs one flash
-pass PER SEGMENT ROW over the WHOLE packed stream and masks foreign
-tokens out — an S-fold attention-FLOP overhead (S = co-scheduled
-segment rows), plus a gathered-context round trip through HBM.  This
-kernel removes both:
+What `packed_prefill.packed_prefill_attention` runs for "pallas" /
+"pallas_interpret", and for "auto" where `resolve_packed_impl` says so.
+The XLA reference there runs one flash pass PER SEGMENT ROW over the
+WHOLE packed stream and table in float32, writes its score block to HBM
+every step, and masks foreign tokens and the pairs above the causal
+diagonal after computing them.  This kernel computes the same pairs'
+attention and none of the rest:
 
-  * **Tile-skip iteration.**  The grid walks the packed stream in
-    TOKEN BLOCKS.  For each (token block, segment) pair the wrapper
-    precomputes how many context CHUNKS the pair actually needs —
-    zero when the segment owns no token in the block (the skip), and
-    otherwise only up to the block's own causal frontier
-    ``ceil((max position in block)/chunk)`` rather than the full table
-    width.  The packed stream is segment-contiguous (engine/prefill.py
-    packs each slot's chunk back to back), so almost every token block
-    intersects exactly ONE segment: total attention work is ~1x the
-    stream's own context instead of S x, and the *causal* half of each
-    segment's score rectangle is skipped at chunk granularity too.
+  * **Tile skip.**  The grid is (KV head, query tile, key tile); a key
+    tile is `chunk_cols` block columns of ONE segment row's table.  The
+    wrapper works out from `seg_ids` / `positions` which pairs run: a
+    segment row's key tiles up to the farthest position that one of the
+    tile's queries of that row holds — none where the row owns no query
+    of the tile (the skip by segment; the packed stream is
+    segment-contiguous, so almost every query tile meets one row), none
+    above the tile's causal frontier, none past the stream.  The flags
+    ride in as scalar prefetch; no [T, S] mask plane exists.  A pair
+    under the NEAREST position of a tile that one row owns whole needs
+    no mask at all and takes a body without one; only the tiles on the
+    diagonal (and at a segment boundary) build the mask, from the two
+    [TB, 1] planes and an iota.
 
-  * **In-VMEM context.**  Each chunk's KV blocks are DMA'd from HBM by
-    physical block id into double-buffered VMEM chunk buffers (the
-    layout conventions of pallas_paged_attention.py: head-major
-    TRANSPOSED blocks, [nkv, hd, bs] per-block strided descriptors,
-    lane-aligned for block_size multiples of 128) and consumed by an
-    online-softmax accumulation — no gathered [S, ctx, hd] tensor ever
-    materializes in HBM.
+  * **Context by block id.**  K and V come straight from the pool, a
+    block a BlockSpec, indexed by the table's physical ids from scalar
+    prefetch (head-major TRANSPOSED blocks, [hd, bs] planes with the
+    keys on lanes: nothing is transposed for the MXU and no gathered
+    copy of the context exists in HBM).  Pallas's own pipeline fetches
+    the next pair's blocks under the current pair's matmuls; a skipped
+    step names the blocks already held, so nothing moves for it.
 
-Int8 KV caches (quant/kv.py) are first-class: pass the per-position
-fp32 scale planes and the kernel DMAs int8 blocks + their scale rows
-into VMEM and fuses the dequantizing multiply into the chunk consume
-(operands in the query dtype — bf16 on the serving path — with fp32
-softmax/accumulation), so quantization's halved HBM traffic lands
-inside the fast path instead of routing around it.
+  * **The group shares the tile.**  A query tile is [TB, G * hd]: the G
+    query heads of one KV head side by side, sliced by lanes, so the
+    stream's [T, nh, hd] queries and output are used where they lie (no
+    head-major copy) and each key tile is read once for the group.
 
-The chunk DMA chain CROSSES tile and segment boundaries (the decode
-kernel's never-drain scheme, generalized): the wrapper derives two more
-scalar-prefetch planes from `nchunks` — a global slot PHASE (exclusive
-tile-major cumulative sum: how many chunks all earlier (tile, segment)
-pairs consume) and each pair's successor row (the next active pair in
-tile-major order, -1 at the end) — and every pair's last chunk
-prefetches its successor's chunk 0 into the opposite double-buffer
-slot (pallas_paged_attention.make_chunk_chain, one definition site
-with the decode kernel).  Only the launch's globally first fetch is
-un-overlapped; no per-(tile, segment) chunk-0 latency is exposed.
-
-Numerics: fp32 online softmax and accumulation, operands in the query
-dtype.  One shared running (m, l, acc) per token row accumulates across
-segments; masked positions contribute exp=0 explicitly (not just
-NEG_INF scores), so a token's accumulator is untouched while foreign
-segments stream past — the property that lets all S segment passes
-share one carry without the reference's per-pass output select.
-Matches packed_prefill_attention's XLA path to bf16 matmul tolerance;
-interpret mode keeps the kernel runnable on CPU for tier-1
-(tests/test_packed_pallas.py), tests/test_tpu_compile.py compiles it for
-a described v5e at serving widths, and chip_smoke.py checks the compiled
-kernel against the XLA path on the chip.
-
-Layout: query rows are (token, head-group) pairs flattened to one
-sublane axis — every in-kernel tensor is [nkv, R, *] with R = TB * group
-— and the per-row segment/position planes are [R, 1] columns.  Mosaic
-tiles the last two dims and cannot re-lay a [TB] lane vector out as the
-rows of a [TB, g, C] score tile, so nothing in the kernel ever needs
-that reshape.
+Numerics: matmul operands in the cache's dtype (the queries', bf16 on
+the serving path; an int8 cache's blocks and their per-position fp32
+scale rows are moved as they are and dequantized in VMEM), running max,
+sum and accumulator float32 in VMEM scratch, one row of them a query
+and head; masked pairs contribute exp = 0 explicitly (not just NEG_INF
+scores), so a query's sums are untouched while other rows' tiles pass —
+which lets all segment rows share one carry.  Matches the XLA path to
+bf16 matmul tolerance.  Interpret mode keeps the kernel runnable on CPU
+for tier-1 (tests/test_packed_pallas.py), tests/test_tpu_compile.py
+compiles it for a described v5e at serving widths and holds the
+`prefill_packed` program to one custom call a layer and no score block,
+and chip_smoke.py checks the compiled kernel against the XLA path on the
+chip.
 """
 
 from __future__ import annotations
@@ -75,8 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_paged_attention import make_chunk_chain, make_chunk_dma
-
 NEG_INF = -1e30
 
 
@@ -87,261 +70,242 @@ def _next_pow2(n: int) -> int:
     return b
 
 
+# query tokens and block columns of one (query tile, key tile) pair where
+# the caller names none: the pair at which the kernel was the faster at
+# 2048 tokens over 16 / 32 / 50 blocks (PERF.md section 6, PR 34)
+TOKEN_BLOCK, CHUNK_COLS = 512, 8
+
+
 def _packed_kernel(
     # scalar prefetch
-    tables_ref,    # [S, n_chunks * bpc] int32 physical block ids
-    nchunks_ref,   # [n_tiles, S] int32 context chunks per (tile, segment)
-    base_ref,      # [n_tiles, S] int32 global slot phase per pair
-    nseg_ref,      # [n_tiles, S] int32 successor segment row (-1 = none)
+    layer_ref,     # [1] int32 the pool's layer
+    tables_ref,    # [S * wp] int32 physical block ids, row-major
+    fetch_ref,     # [n_q * n_kt] int32 key tile whose blocks a step holds
+    flag_ref,      # [n_q * n_kt] int32 0 skip | 1 under the mask | 2 whole
     # inputs
-    seg_ref,       # [R, 1] int32 segment row per query row (-1 = padded)
-    pos_ref,       # [R, 1] int32 absolute position per query row
-    q_ref,         # [nkv, R, hd] VMEM (this tile's queries, pre-scaled;
-                   #   R = TB * g rows, token-major / group-minor)
-    k_hbm,         # [nkv, num_blocks, hd, bs] ANY (stays in HBM)
-    v_hbm,
-    *rest,         # (+ks_hbm, vs_hbm when quantized) o_ref, scratch...
-    S: int,
-    bpc: int,
-    bs: int,
+    seg_ref,       # [TB, 1] int32 segment row per query (-1 = padded)
+    pos_ref,       # [TB, 1] int32 absolute position per query
+    q_ref,         # [TB, G * hd] this tile's queries of one KV head's
+                   #   group, pre-scaled, a head every hd lanes
+    *rest,         # cc K blocks [hd, bs], cc V blocks (+ cc + cc scale
+                   #   rows [1, bs] when quantized), o_ref, m, l, acc
+    G: int,
+    cc: int,
+    n_c: int,
     quantized: bool,
 ):
+    k_refs, v_refs = rest[:cc], rest[cc:2 * cc]
+    rest = rest[2 * cc:]
     if quantized:
-        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem) = rest
-    else:
-        (o_ref, k_buf, v_buf, sem) = rest
-        ks_hbm = vs_hbm = ks_buf = vs_buf = None
-    t = pl.program_id(0)
-    C = bpc * bs  # context positions per chunk
-    q = q_ref[...]            # [nkv, R, hd]
-    seg = seg_ref[...]        # [R, 1]
-    pos = pos_ref[...]
-    nkv, R, hd = q.shape
+        ks_refs, vs_refs = rest[:cc], rest[cc:2 * cc]
+        rest = rest[2 * cc:]
+    o_ref, m_sc, l_sc, acc_sc = rest
+    i, j = pl.program_id(1), pl.program_id(2)
+    n_kt = pl.num_programs(2)
+    TB = q_ref.shape[0]
+    hd, bs = k_refs[0].shape
+    tk = cc * bs
 
-    # the chunk DMA contract (descriptor shapes, semaphore pairing, int8
-    # scale lanes) is shared with the decode kernel; `row` here is the
-    # segment index into the per-segment block tables
-    start_chunk, wait_chunk = make_chunk_dma(
-        tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem, bpc=bpc, bs=bs,
-        ks_hbm=ks_hbm, vs_hbm=vs_hbm, ks_buf=ks_buf, vs_buf=vs_buf)
-    prime, chain_step = make_chunk_chain(start_chunk, wait_chunk)
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    # rows are sublanes and context positions lanes throughout: every
-    # in-kernel tensor is [nkv, R, *] and the per-row planes [R, 1], so
-    # the mask broadcasts along lanes and no vector is re-laid-out
-    carry = (
-        jnp.full((nkv, R, 1), NEG_INF, jnp.float32),
-        jnp.zeros((nkv, R, 1), jnp.float32),
-        jnp.zeros((nkv, R, hd), jnp.float32),
-    )
-    # static unroll over segment rows (S is small — max_prefill_seqs
-    # pow2); the chunk count is 0 for every segment with no token in
-    # this tile, so the fori_loop below skips foreign (tile, segment)
-    # pairs entirely — the tile-skip that removes the S-fold overhead
-    for s in range(S):
-        nch = nchunks_ref[t, s]
-        base = base_ref[t, s]
-        nseg = nseg_ref[t, s]
+    def planes(refs, scale_refs):
+        """The tile's cc blocks side by side: [hd, tk], keys on lanes
+        (blocks lie [hd, bs] in the pool: nothing is transposed)."""
+        blocks = [r[...] for r in refs]
+        if quantized:
+            # int8 moved, dequantized here by each key's own scale;
+            # operands in the queries' dtype, float32 sums
+            blocks = [(b.astype(jnp.float32) * sr[...]).astype(q_ref.dtype)
+                      for b, sr in zip(blocks, scale_refs)]
+        return blocks[0] if cc == 1 else jnp.concatenate(blocks, axis=1)
 
-        # only the launch's globally first active pair primes chunk 0;
-        # every other pair's chunk 0 was prefetched by its predecessor's
-        # last chunk (cross-tile/segment never-drain chain)
-        prime(s, nch, base)
+    def pair(masked: bool):
+        k = planes(k_refs, ks_refs if quantized else None)
+        v = planes(v_refs, vs_refs if quantized else None)
+        if masked:
+            # the tile's keys are segment row j // n_c's, from position
+            # (j % n_c) * tk: a query keeps those of its own row at or
+            # before its own position
+            span = (j % n_c) * tk + jax.lax.broadcasted_iota(
+                jnp.int32, (TB, tk), 1)
+            keep = (seg_ref[...] == j // n_c) & (span <= pos_ref[...])
+        for g in range(G):   # the group's heads share the key tile
+            sc = jnp.dot(q_ref[:, g * hd:(g + 1) * hd], k,
+                         preferred_element_type=jnp.float32)
+            if masked:
+                sc = jnp.where(keep, sc, NEG_INF)
+            m_prev = m_sc[g][:, :1]
+            m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(sc - m_new)
+            if masked:
+                # a query with nothing kept so far has m_new = NEG_INF
+                # and exp(0) = 1 for a pair that is out: zero it, so
+                # other rows' tiles leave its sums untouched
+                p = jnp.where(keep, p, 0.0)
+            l_sc[g] = alpha * l_sc[g] + p.sum(axis=1, keepdims=True)
+            acc_sc[g] = acc_sc[g] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_sc[g] = jnp.broadcast_to(m_new, m_sc.shape[1:])
 
-        owned = seg == s  # [R, 1]
+    flag = flag_ref[i * n_kt + j]
+    pl.when(flag == 1)(lambda: pair(True))
+    # every query of the tile keeps every key of the tile: no mask
+    pl.when(flag == 2)(lambda: pair(False))
 
-        def body(c, carry, s=s, owned=owned, nch=nch, base=base,
-                 nseg=nseg):
-            m, l, acc = carry
-            slot = chain_step(s, c, nch, base, nseg)
-            k = k_buf[slot]  # [nkv, hd, C]
-            v = v_buf[slot]
-            if quantized:
-                # fused dequant on the chunk consume: int8 streamed from
-                # HBM, multiplied by the per-position fp32 scale row,
-                # cast to the query dtype for the MXU (bf16 operands,
-                # fp32 accumulation on the serving path)
-                k = (k.astype(jnp.float32)
-                     * ks_buf[slot][:, None, :]).astype(q.dtype)
-                v = (v.astype(jnp.float32)
-                     * vs_buf[slot][:, None, :]).astype(q.dtype)
-            # scores [nkv, R, C]: one batched matmul for the tile
-            sc = jax.lax.dot_general(
-                q, k, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            span = c * C + jax.lax.broadcasted_iota(jnp.int32, (R, C), 1)
-            mask = (owned & (span <= pos))[None]  # [1, R, C]
-            sc = jnp.where(mask, sc, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(sc, axis=2, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            # explicit zero outside the mask: a fully-masked row leaves
-            # (m, l, acc) untouched, so the shared carry never mixes
-            # foreign segments' junk into a real token's accumulation
-            p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
-            l = l * alpha + jnp.sum(p, axis=2, keepdims=True)
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            acc = acc * alpha + pv
-            return m_new, l, acc
-
-        carry = jax.lax.fori_loop(0, nch, body, carry)
-    m, l, acc = carry
-    # tokens no segment owns (padded tail) have l == 0 -> output 0,
-    # matching the XLA reference's untouched zero-init output rows
-    o_ref[...] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+    @pl.when(j == n_kt - 1)
+    def _():
+        # queries no segment owns (the padded tail) have l == 0 -> 0,
+        # the XLA path's untouched zero rows
+        for g in range(G):
+            o_ref[:, g * hd:(g + 1) * hd] = (acc_sc[g] / jnp.maximum(
+                l_sc[g][:, :1], 1e-20)).astype(o_ref.dtype)
 
 
 @functools.partial(
     # dynlint: disable=DYN001 kernel-level jit: engine dispatch reaches this inside already-watched programs (prefill_packed/spec_verify); direct calls are bench/test-only
     jax.jit,
-    static_argnames=("layer", "chunk_cols", "token_block", "interpret"),
+    static_argnames=("chunk_cols", "token_block", "interpret"),
 )
 def packed_prefill_attention_pallas(
     q: jax.Array,             # [T, nh, hd] packed-stream queries (rope'd)
     k_cache: jax.Array,       # [L, nkv, num_blocks, hd, bs]
     v_cache: jax.Array,
-    layer: int,
+    layer,                    # int or int32 scalar: traced, so ONE trace
+                              #   and one kernel serve a program's layers
     block_tables: jax.Array,  # [S, mb] int32 per-segment block tables
     seg_ids: jax.Array,       # [T] int32 segment row per token
     positions: jax.Array,     # [T] int32 absolute position per token
     valid: jax.Array,         # [T] bool (False = padded tail)
     *,
-    chunk_cols: int = 8,      # block columns per context chunk
-    token_block: int = 0,     # query tokens per tile (0 = auto)
+    chunk_cols: int = 0,      # block columns per key tile (0 = CHUNK_COLS)
+    token_block: int = 0,     # query tokens per tile (0 = TOKEN_BLOCK)
     interpret: bool = False,
     k_scale: jax.Array = None,  # [L, nkv, num_blocks, bs] fp32 (int8)
     v_scale: jax.Array = None,
 ) -> jax.Array:
-    """Drop-in fast path for packed_prefill.packed_prefill_attention
-    (impl="pallas"/"pallas_interpret").  Returns [T, nh, hd] in q's
-    dtype; tokens outside every segment (the padded tail) return 0."""
+    """The packed stream's attention as one kernel a layer
+    (packed_prefill.packed_prefill_attention's "pallas" /
+    "pallas_interpret", and its "auto" where `resolve_packed_impl` says
+    so).  Returns [T, nh, hd] in q's dtype; tokens outside every segment
+    (the padded tail) return 0."""
     T, nh, hd = q.shape
-    kc, vc = k_cache[layer], v_cache[layer]
-    nkv, _, _, bs = kc.shape
-    group = nh // nkv
+    _, nkv, _, _, bs = k_cache.shape
+    G = nh // nkv
     S, mb = block_tables.shape
     quantized = k_scale is not None
 
-    TB = token_block or min(128, _next_pow2(T))
-    n_tiles = -(-T // TB)
-    Tp = n_tiles * TB
-
-    bpc = max(1, min(mb, chunk_cols))
-    n_chunks = -(-mb // bpc)
-    pad_cols = n_chunks * bpc - mb
-    if pad_cols:
-        # padded table entries point at the garbage block (0); the span
-        # mask keeps them out of every real token's window
-        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad_cols)))
-    C = bpc * bs
+    TB = min(token_block or TOKEN_BLOCK, _next_pow2(T))
+    n_q = -(-T // TB)
+    Tp = n_q * TB
+    cc = max(1, min(mb, chunk_cols or CHUNK_COLS))
+    n_c = -(-mb // cc)
+    wp = n_c * cc
+    # padded table entries point at the garbage block (0): no pair that
+    # is computed reaches them (a tile runs to its queries' frontier)
+    tables = jnp.pad(block_tables, ((0, 0), (0, wp - mb))).reshape(-1)
+    tk = cc * bs
+    n_kt = S * n_c
 
     # padded-tail / invalid tokens get segment -1: they match no
-    # segment row, so no mask ever selects them and no chunk count
-    # grows on their behalf
+    # segment row, so no mask ever selects them and no tile runs on
+    # their behalf
     seg_eff = jnp.where(valid, seg_ids, -1).astype(jnp.int32)
-    pad_t = Tp - T
-    if pad_t:
-        seg_eff = jnp.pad(seg_eff, (0, pad_t), constant_values=-1)
-        positions = jnp.pad(positions, (0, pad_t))
-        q = jnp.pad(q, ((0, pad_t), (0, 0), (0, 0)))
+    positions = positions.astype(jnp.int32)
+    if Tp > T:
+        seg_eff = jnp.pad(seg_eff, (0, Tp - T), constant_values=-1)
+        positions = jnp.pad(positions, (0, Tp - T))
+        q = jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0)))
 
-    # per-(tile, segment) causal chunk frontier: 0 chunks when the
-    # segment owns no token in the tile (the skip), else enough chunks
-    # to cover the tile's farthest owned position — the wrapper-side
-    # half of the tile-skip scheme
-    seg2d = seg_eff.reshape(n_tiles, TB)
-    pos2d = positions.reshape(n_tiles, TB).astype(jnp.int32)
-    owned = seg2d[None, :, :] == jnp.arange(S, dtype=jnp.int32)[:, None,
-                                                                None]
-    maxpos = jnp.max(jnp.where(owned, pos2d[None, :, :], -1), axis=2)
-    nch = jnp.where(maxpos >= 0, maxpos // C + 1, 0)
-    nchunks = jnp.minimum(nch, n_chunks).astype(jnp.int32).T  # [n_tiles, S]
-
-    # cross-tile/segment DMA chain planes (make_chunk_chain): the global
-    # slot PHASE of each (tile, segment) pair — exclusive tile-major
-    # cumulative sum of nchunks, so slot(chunk c of pair) = (base+c)%2 —
-    # and each pair's successor row: the segment index of the next
-    # active pair in tile-major order (suffix-min over flat indices,
-    # -1 past the last), whose chunk 0 the pair's last chunk prefetches
-    flat = nchunks.reshape(-1)                    # tile-major [n_tiles*S]
-    chunk_base = (jnp.cumsum(flat) - flat).astype(jnp.int32) \
-        .reshape(n_tiles, S)
-    npairs = flat.shape[0]
-    fidx = jnp.arange(npairs, dtype=jnp.int32)
-    cand = jnp.where(flat > 0, fidx, npairs)      # inactive -> sentinel
-    suf = jax.lax.cummin(cand[::-1])[::-1]        # min over cand[i:]
-    suf_excl = jnp.concatenate(
-        [suf[1:], jnp.full((1,), npairs, jnp.int32)])
-    next_seg = jnp.where(suf_excl < npairs, suf_excl % S, -1) \
-        .astype(jnp.int32).reshape(n_tiles, S)
+    # which (query tile, key tile) pairs run: a segment row's key tiles
+    # up to the farthest position one of the tile's queries of that row
+    # holds (none where it owns no query: the skip by segment), the rest
+    # lies above the causal diagonal or past the stream; a pair under
+    # the nearest position of a tile that one row owns whole needs no
+    # mask.  [n_q, S, n_c] -> flat [n_q * n_kt], key tiles row-major
+    seg2d = seg_eff.reshape(n_q, TB)
+    pos2d = positions.reshape(n_q, TB)
+    owned = seg2d[:, None, :] == jnp.arange(S, dtype=jnp.int32)[None, :,
+                                                                 None]
+    far = jnp.max(jnp.where(owned, pos2d[:, None, :], -1), axis=2)
+    near = jnp.where(jnp.all(owned, axis=2), jnp.min(pos2d, axis=1)[:, None],
+                     -1)
+    first = jnp.arange(n_c, dtype=jnp.int32) * tk     # a tile's first key
+    runs = first[None, None, :] <= far[:, :, None]
+    whole = first[None, None, :] + tk - 1 <= near[:, :, None]
+    flags = (runs.astype(jnp.int32) + (runs & whole)).reshape(n_q, n_kt)
+    # a step that is skipped names the blocks the pipeline already holds
+    # (the last pair that ran, or the first that will): nothing is
+    # fetched for it
+    at = jnp.arange(n_kt, dtype=jnp.int32)[None, :]
+    last = jax.lax.cummax(jnp.where(flags > 0, at, -1), axis=1)
+    nxt = jnp.min(jnp.where(flags > 0, at, n_kt), axis=1, keepdims=True)
+    fetch = jnp.where(last >= 0, last, nxt % n_kt).astype(jnp.int32)
 
     scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    qg = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    # query rows are (token, group) pairs, token-major: R = TB * group
-    # rows per tile, [nkv, Tp * group, hd] overall
-    R = TB * group
-    qg = qg.reshape(Tp, nkv, group, hd).transpose(1, 0, 2, 3) \
-        .reshape(nkv, Tp * group, hd)
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype) \
+        .reshape(Tp, nh * hd)
 
-    # per-row segment/position planes ride as [n_tiles, R, 1] columns
-    # (rows on sublanes, like the score rows they mask): the block's
-    # last two dims are (R, 1) = (multiple of 8 or the whole array, the
-    # array's own 1), which is what Mosaic's block-shape rule asks for
-    def row_plane(x2d):
-        return jnp.repeat(x2d, group, axis=1)[:, :, None]
+    def block_of(b):
+        def index(h, i, j, layer_ref, tables_ref, fetch_ref, flag_ref):
+            t = fetch_ref[i * n_kt + j]
+            return (layer_ref[0], h,
+                    tables_ref[(t // n_c) * wp + (t % n_c) * cc + b], 0, 0)
+        return index
 
-    inputs = [row_plane(seg2d), row_plane(pos2d), qg, kc, vc]
-    in_specs = [
-        pl.BlockSpec((None, R, 1), lambda t, *refs: (t, 0, 0)),
-        pl.BlockSpec((None, R, 1), lambda t, *refs: (t, 0, 0)),
-        pl.BlockSpec((nkv, R, hd), lambda t, *refs: (0, t, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    scratch = [
-        pltpu.VMEM((2, nkv, hd, C), kc.dtype),
-        pltpu.VMEM((2, nkv, hd, C), vc.dtype),
-    ]
+    def row(h, i, j, *refs):
+        return (i, 0)
+
+    def heads(h, i, j, *refs):
+        return (i, h)
+
+    # K and V come straight from the pool, a block a descriptor, by the
+    # table's physical ids: no gathered copy of the context exists
+    plane = [pl.BlockSpec((None, None, None, hd, bs), block_of(b))
+             for b in range(cc)]
+    inputs = [seg_eff[:, None], positions[:, None], qs] \
+        + [k_cache] * cc + [v_cache] * cc
+    in_specs = [pl.BlockSpec((TB, 1), row), pl.BlockSpec((TB, 1), row),
+                pl.BlockSpec((TB, G * hd), heads)] + plane + plane
     if quantized:
-        inputs += [k_scale[layer], v_scale[layer]]
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                     pl.BlockSpec(memory_space=pl.ANY)]
-        scratch += [pltpu.VMEM((2, nkv, C), jnp.float32),
-                    pltpu.VMEM((2, nkv, C), jnp.float32)]
-    scratch.append(pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)))
+        # scale rows as [.., 1, bs] planes, so a block is a whole tile
+        srow = [pl.BlockSpec((None, None, None, 1, bs), block_of(b))
+                for b in range(cc)]
+        inputs += [k_scale[..., None, :]] * cc + [v_scale[..., None, :]] * cc
+        in_specs += srow + srow
 
-    # bytes per context position per head: the int8 path streams 1-byte
-    # elements plus one fp32 scale per (head, position)
-    pos_bytes = hd * jnp.dtype(kc.dtype).itemsize + (4 if quantized else 0)
+    pairs = Tp * n_kt * tk
     out = pl.pallas_call(
-        functools.partial(_packed_kernel, S=S, bpc=bpc, bs=bs,
+        functools.partial(_packed_kernel, G=G, cc=cc, n_c=n_c,
                           quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n_tiles,),
+            grid=(nkv, n_q, n_kt),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((nkv, R, hd),
-                                   lambda t, *refs: (0, t, 0)),
-            scratch_shapes=scratch,
+            out_specs=pl.BlockSpec((TB, G * hd), heads),
+            scratch_shapes=[pltpu.VMEM((G, TB, 128), jnp.float32),
+                            pltpu.VMEM((G, TB, 128), jnp.float32),
+                            pltpu.VMEM((G, TB, hd), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((nkv, Tp * group, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Tp, nh * hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
-        # 1x the stream's own context, NOT the reference's S-fold: each
-        # tile visits at most its own segment's table (upper bound —
-        # the causal frontier skips chunks beyond a tile's last token)
+        # an upper bound: every pair of the stream x its rows' tables
+        # (the tiles above a query tile's frontier are not computed)
         cost_estimate=pl.CostEstimate(
-            flops=2 * 2 * Tp * nh * hd * n_chunks * C,
-            bytes_accessed=2 * n_tiles * nkv * n_chunks * C * pos_bytes,
-            transcendentals=Tp * nh * n_chunks * C,
+            flops=4 * pairs * nh * hd,
+            bytes_accessed=2 * n_q * nkv * n_kt * tk * hd
+            * jnp.dtype(k_cache.dtype).itemsize,
+            transcendentals=pairs * nh,
         ),
         interpret=interpret,
-    )(block_tables, nchunks, chunk_base, next_seg, *inputs)
-    out = out.reshape(nkv, Tp, group, hd).transpose(1, 0, 2, 3) \
-        .reshape(Tp, nh, hd)
-    return out[:T].astype(q.dtype)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables, fetch.reshape(-1),
+      flags.reshape(-1), *inputs)
+    return out[:T].reshape(T, nh, hd)

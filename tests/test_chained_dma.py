@@ -1,10 +1,13 @@
 """Cross-tile/segment DMA chain parity (PR 17).
 
-The packed-prefill and decode kernels no longer re-prime their
-double-buffered chunk DMA chain at each (tile, segment) / row boundary:
-a global phase over the prefetched nchunks plane
-(pallas_paged_attention.make_chunk_chain) keeps the chain saturated
-across boundaries.  These layouts are chosen so the HANDOFF itself is
+The decode kernel does not re-prime its double-buffered chunk DMA
+chain at each row boundary: a global phase over the prefetched nchunks
+plane (pallas_paged_attention.make_chunk_chain) keeps the chain
+saturated across boundaries.  The packed-prefill kernel had the same
+chain until PR 34; its blocks now come through Pallas's own pipeline
+(a BlockSpec a block, indexed by the table from scalar prefetch), and a
+skipped (query tile, key tile) step names the blocks already held — the
+same layouts now exercise THAT handoff.  They are chosen so the HANDOFF itself is
 what's exercised — the globally-first active pair not being (0, 0),
 empty rows interleaved between active ones, boundaries landing mid-tile,
 fully-padded tail tiles after the last chunk, single segments spanning

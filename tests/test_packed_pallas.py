@@ -217,6 +217,167 @@ def test_packed_pallas_tp_sharded_matches_xla():
 
 
 # ---------------------------------------------------------------------------
+# what "auto" means (resolve_packed_impl) and the kernel at its boundaries
+# ---------------------------------------------------------------------------
+
+_BF16, _I8, _F32 = jnp.bfloat16, jnp.int8, jnp.float32
+
+
+@pytest.mark.parametrize("platform,bs,hd,dtype,tokens,want", [
+    # the buckets that carry the doc cell's tokens, and the chat
+    # cell's long prompts: the kernel
+    ("tpu", 128, 128, _BF16, 2048, "pallas"),
+    ("tpu", 128, 128, _BF16, 1024, "pallas"),
+    ("tpu", 256, 128, _I8, 1024, "pallas"),
+    ("tpu", 128, 256, _I8, 4096, "pallas"),
+    # under the measured length: the scan (the short buckets,
+    # speculative verification's rows of k + 1 tokens)
+    ("tpu", 128, 128, _BF16, 512, "xla"),
+    ("tpu", 128, 128, _BF16, 128, "xla"),
+    ("tpu", 128, 128, _BF16, 32, "xla"),
+    ("tpu", 128, 128, _I8, 5, "xla"),
+    # where the kernel cannot run as written
+    ("cpu", 128, 128, _BF16, 2048, "xla"),
+    ("gpu", 128, 128, _BF16, 2048, "xla"),
+    ("tpu", 16, 128, _BF16, 2048, "xla"),
+    ("tpu", 128, 64, _BF16, 2048, "xla"),
+    ("tpu", 128, 128, _F32, 2048, "xla"),
+    ("tpu", 64, 128, _I8, 2048, "xla"),
+])
+def test_resolve_packed_impl_auto(platform, bs, hd, dtype, tokens, want):
+    """`auto` is decided in one place from platform, cache and the
+    stream's length; an explicit impl is returned as given whatever the
+    rest says."""
+    from dynamo_tpu.ops.packed_prefill import (
+        PACKED_IMPLS,
+        resolve_packed_impl,
+    )
+
+    assert resolve_packed_impl("auto", platform, bs, hd, dtype,
+                               tokens) == want
+    for impl in PACKED_IMPLS[1:]:
+        assert resolve_packed_impl(impl, platform, bs, hd, dtype,
+                                   tokens) == impl
+
+
+def test_auto_off_the_chip_is_the_float32_scan():
+    """On this backend `auto` traces the scan: the same values as "xla",
+    bit for bit."""
+    rng = np.random.default_rng(11)
+    q, kc, vc, ks, vs, tables, seg_ids, positions, valid = _packed_case(
+        rng, [9, 7], bucket=16)
+    a, b = (packed_prefill_attention(q, kc, vc, 1, tables, seg_ids,
+                                     positions, valid, impl=impl)
+            for impl in ("auto", "xla"))
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    with pytest.raises(ValueError, match="packed-prefill impl"):
+        packed_prefill_attention(q, kc, vc, 1, tables, seg_ids, positions,
+                                 valid, impl="triton")
+
+
+@pytest.mark.parametrize("lens,ctx0,mb,bucket,tiles", [
+    # a chunk that starts and ends inside a block (block 4): 13..26
+    ([14], [13], 8, 16, dict(token_block=8, chunk_cols=2)),
+    # a table padded to the key tile: 7 columns, tiles of 4
+    ([21], [3], 7, 32, dict(token_block=8, chunk_cols=4)),
+    # a stream with a padded tail longer than a query tile
+    ([9], [0], 8, 32, dict(token_block=8, chunk_cols=2)),
+    # four segment rows, two of them sharing a query tile
+    ([12, 4, 9, 7], [0, 6, 0, 17], 8, 32,
+     dict(token_block=16, chunk_cols=2)),
+    # a committed prefix of many blocks under a short chunk: every key
+    # tile but the last runs without a mask
+    ([8], [23], 8, 8, dict(token_block=8, chunk_cols=1)),
+    # the kernel's own tile sizes (larger than the stream)
+    ([11, 5], [2, 9], 8, 16, {}),
+])
+def test_packed_kernel_at_the_boundaries(lens, ctx0, mb, bucket, tiles):
+    """The kernel against the float32 scan where its tile arithmetic
+    turns: block, key-tile and query-tile edges, the padded tail, rows
+    that share a tile, and tiles that skip the mask."""
+    rng = np.random.default_rng(12)
+    case = _packed_case(rng, lens, ctx0=ctx0, mb=mb, bucket=bucket)
+    _assert_packed_parity(case, **tiles)
+
+
+def test_packed_kernel_runs_only_the_pairs_it_needs(monkeypatch):
+    """The wrapper's plan, read off the scalar-prefetch values it hands
+    the kernel: a query tile runs its own row's key tiles up to its
+    frontier and no other; the tiles wholly under it carry the maskless
+    flag."""
+    from dynamo_tpu.ops import pallas_packed_prefill as ppk
+
+    rng = np.random.default_rng(13)
+    # two rows of 16 tokens at positions 0..15 and 16..31 (block 4):
+    # query tiles of 8, key tiles of 2 blocks = 8 keys, 4 a row
+    case = _packed_case(rng, [16, 16], ctx0=[0, 16], mb=8, bucket=32)
+    q, kc, vc, ks, vs, tables, seg_ids, positions, valid = case
+    seen = {}
+    real = ppk.pl.pallas_call
+
+    def spy(kernel, **kw):
+        call = real(kernel, **kw)
+
+        def run(layer, tables_, fetch, flags, *rest):
+            seen["flags"], seen["fetch"] = flags, fetch
+            return call(layer, tables_, fetch, flags, *rest)
+        return run
+
+    monkeypatch.setattr(ppk.pl, "pallas_call", spy)
+    with jax.disable_jit():
+        ppk.packed_prefill_attention_pallas.__wrapped__(
+            q, kc, vc, 1, tables, seg_ids, positions, valid,
+            token_block=8, chunk_cols=2, interpret=True)
+    flags = np.asarray(seen["flags"]).reshape(4, 8)
+    assert flags.tolist() == [
+        [1, 0, 0, 0, 0, 0, 0, 0],      # row 0, positions 0..7
+        [2, 1, 0, 0, 0, 0, 0, 0],      # row 0, 8..15
+        [0, 0, 0, 0, 2, 2, 1, 0],      # row 1, 16..23
+        [0, 0, 0, 0, 2, 2, 2, 1],      # row 1, 24..31
+    ]
+    fetch = np.asarray(seen["fetch"]).reshape(4, 8)
+    # a skipped step names a tile that ran (or will): nothing to fetch
+    assert fetch.tolist() == [
+        [0, 0, 0, 0, 0, 0, 0, 0],
+        [0, 1, 1, 1, 1, 1, 1, 1],
+        [4, 4, 4, 4, 4, 5, 6, 6],
+        [4, 4, 4, 4, 4, 5, 6, 7],
+    ]
+
+
+async def test_the_counter_says_how_often_the_kernel_engaged():
+    """`prefill_attn_kernel_tokens` rises by a packed program's tokens
+    where the rule the traced code applies names the kernel for its
+    bucket — here an explicit impl, which the rule returns as given —
+    and by nothing under `auto` off the chip; it is asked at dispatch,
+    from the host."""
+    from test_engine import collect, greedy_req
+
+    from dynamo_tpu.engine import JaxEngine
+    from dynamo_tpu.ops.packed_prefill import resolve_packed_impl
+
+    prompt = [5, 9, 13, 2, 7, 11, 3, 1, 8, 20]
+    small = dict(decode_fused_steps=1, num_blocks=64, max_blocks_per_seq=8)
+    for impl, share in (("pallas_interpret", 1), ("", 0)):
+        eng = JaxEngine(_engine_cfg(packed_attn_impl=impl, **small))
+        try:
+            assert eng.metrics["prefill_attn_kernel_tokens"] == 0
+            await collect(eng, greedy_req(prompt, 2, f"k-{impl}"))
+            assert eng.metrics["prefill_tokens"] == len(prompt)
+            assert eng.metrics["prefill_attn_kernel_tokens"] \
+                == share * len(prompt)
+            rec = [r for r in eng.fpm if r["kind"] == "prefill"][-1]
+            # the host's answer is the rule's, for the plan's bucket
+            c, m = eng.config, eng.model_cfg
+            assert eng._prefill_attn_kernel(rec["bucket"]) == (
+                resolve_packed_impl(m.packed_attn_impl, "cpu",
+                                    c.block_size, m.head_dim, m.dtype,
+                                    rec["bucket"]) != "xla")
+        finally:
+            await eng.close()
+
+
+# ---------------------------------------------------------------------------
 # the packed write: whole planes in the resident layout vs the flat scatter
 # ---------------------------------------------------------------------------
 

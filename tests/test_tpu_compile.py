@@ -19,6 +19,7 @@ failure to describe the topology is an error, never a silent skip.
 
 import dataclasses
 import importlib.util
+import math
 import os
 from functools import partial
 
@@ -275,6 +276,55 @@ def test_prefill_packed_reads_the_pool_where_it_lies(one_chip, T, MB):
         temp = program.memory_analysis().temp_size_in_bytes
         grown = compiled(480).memory_analysis().temp_size_in_bytes
         assert abs(grown - temp) < 50e6, (temp, grown)
+
+
+@pytest.mark.parametrize("MB", [16, 32, 50])
+def test_prefill_packed_attends_in_the_kernel(topo, one_chip, MB):
+    """`prefill_packed` at Mistral-7B widths (4 layers, 320 blocks, one
+    segment row, 2048 tokens) over the doc cell's three table widths,
+    with `auto` resolved as on the chip: the attention is the Pallas
+    kernel, once a layer; no value of the scan's score block's size is
+    left (`f32[2048,32,1024]`, 268 MB, out to HBM and back every step
+    until PR 34, in whatever layout); the pool is still read where it
+    lies; and the temporaries are under what the scan's program held
+    (0.44 GB)."""
+    import re
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.ops.packed_prefill import resolve_packed_impl
+
+    L, NKV, NB, HD, SEGS, T = 4, 8, 320, 128, 1, 2048
+    impl = resolve_packed_impl("auto", topo.devices[0].platform, BS, HD,
+                               jnp.bfloat16, T)
+    assert impl == "pallas"
+    cfg = llama.LlamaConfig(
+        name="mistral-7b-widths", vocab_size=32768, d_model=4096,
+        n_layers=L, n_heads=32, n_kv_heads=NKV, head_dim=HD,
+        ffn_dim=14336, rope_theta=1e6, packed_attn_impl=impl)
+    S = _sds(one_chip)
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    kv = tuple(S((L, NKV, NB, HD, BS), cfg.dtype) for _ in range(2))
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+    program = jax.jit(
+        partial(JaxEngine._prefill_packed_impl, llama, cfg, None),
+        donate_argnums=(1,)).lower(
+        params, kv, S((T,), i32), S((T,), i32), S((T,), i32),
+        S((SEGS, MB), i32), S((SEGS,), i32), S((T,), b1),
+        S((SEGS,), i32), S((SEGS,), f32), S((SEGS,), i32),
+        S((SEGS,), f32)).compile()
+    hlo = program.as_text()
+    assert hlo.count("tpu_custom_call") == L
+    _assert_pool_stays_where_it_lies(hlo, L, NKV, NB, HD)
+    # nothing the size of a score block: the largest float32 values left
+    # are the MLP's [2048, 14336] and the head's weights
+    score = T * 32 * 1024
+    sized = {m for m in re.findall(r"f32\[([\d,]+)\]", hlo)
+             if len(m.split(",")) >= 3
+             and math.prod(int(d) for d in m.split(",")) >= score}
+    assert not sized, sized
+    assert program.memory_analysis().temp_size_in_bytes < 0.44e9
 
 
 def test_hybrid_decode_and_prefill_compile_for_v5e(one_chip):
