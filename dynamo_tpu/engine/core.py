@@ -758,9 +758,9 @@ class JaxEngine:
         name = type(self.model_cfg).__name__
         if "prefix_caching" in gaps and c.enable_prefix_caching:
             logger.warning(
-                "model family %s cannot reuse a cached prefix (its window "
-                "layers' state at the boundary is not kept); prefix "
-                "caching is off", name)
+                "model family %s cannot reuse a cached prefix (its "
+                "lane-addressed layers' state at the boundary is not "
+                "kept); prefix caching is off", name)
             c.enable_prefix_caching = False
         refused = [what for what, asked in (
             ("tp", c.tp > 1),

@@ -10,9 +10,10 @@
 The engine binds a family once via get_family(cfg) and never branches on
 architecture again — Llama/Qwen/Mixtral (llama.py, GQA cache), the
 DeepSeek MLA family (deepseek.py, latent cache), the window + global
-hybrid over a share of the experts (mimo.py) and the GQA decoder whose
-attention reads the keys a learned indexer chooses (keye.py) serve
-through identical plumbing.
+hybrid over a share of the experts (mimo.py), the GQA decoder whose
+attention reads the keys a learned indexer chooses (keye.py) and the
+delta-rule linear-attention hybrid with one latent-attention layer a
+period (ling.py) serve through identical plumbing.
 
 The cache is a tuple the family owns: the engine allocates one array a
 shape, hands the tuple to every program and takes it back.  A family
@@ -50,6 +51,26 @@ given (never through the family's type):
                              layers use which member is the family's own
                              (mimo.py: members 0-1 the global layers'
                              paged pools, 2-3 the window layers' rings).
+                             A RING forgets by itself: a position
+                             overwrites the one a window before it.  A
+                             lane-addressed member may also be a STATE
+                             that nothing overwrites by position
+                             (ling.py: members 2-3, a float32 matrix a
+                             head and the short convolution's tail, a
+                             lane and layer; `kv_cache_dtypes` says
+                             which member is which).  The family's
+                             programs then keep its life, since the
+                             engine never clears a lane: a row whose
+                             first position is 0 starts from ZEROS
+                             whatever the lane held; chunk n + 1 of a
+                             prompt starts from what chunk n LEFT; a
+                             bucket's padding, a row of no tokens and
+                             the idle lanes of a decode burst leave it
+                             UNTOUCHED; a preempted sequence REBUILDS it
+                             by replay from position 0.  A block that
+                             prefix caching would reuse says nothing of
+                             the state at its end, so such a family
+                             lists `prefix_caching` in `UNSUPPORTED`.
     KV_COUNTERS              names of device-side counts: the tuple's
                              LAST member is an int32 vector the programs
                              add to; a decode burst carries it home under
@@ -70,14 +91,15 @@ given (never through the family's type):
     UNSUPPORTED              what the engine must not promise for the
                              family (engine/core.py `_family_gaps`)."""
 
-from . import deepseek, keye, llama, mimo
+from . import deepseek, keye, ling, llama, mimo
 from .deepseek import DeepseekConfig
 from .keye import KeyeConfig
+from .ling import LingConfig
 from .llama import LlamaConfig, init_params
 from .mimo import MimoConfig
 
 PRESETS = {**llama.PRESETS, **deepseek.PRESETS, **mimo.PRESETS,
-           **keye.PRESETS}
+           **keye.PRESETS, **ling.PRESETS}
 
 
 def get_family(cfg):
@@ -88,6 +110,8 @@ def get_family(cfg):
         return mimo
     if isinstance(cfg, KeyeConfig):
         return keye
+    if isinstance(cfg, LingConfig):
+        return ling
     if isinstance(cfg, LlamaConfig):
         return llama
     raise TypeError(f"unknown model config type: {type(cfg).__name__}")
@@ -96,6 +120,7 @@ def get_family(cfg):
 __all__ = [
     "DeepseekConfig",
     "KeyeConfig",
+    "LingConfig",
     "LlamaConfig",
     "MimoConfig",
     "PRESETS",

@@ -502,3 +502,74 @@ def test_sparse_decode_and_prefill_compile_for_v5e(one_chip):
     assert hlo.count("tpu_custom_call") == 5 * L
     pools_stay(hlo)
     assert program.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_recurrent_decode_and_prefill_compile_for_v5e(one_chip):
+    """The delta-rule linear-attention family (models/ling.py) at
+    Ling-3.0-flash's widths, cut to one period (5 KDA layers and the MLA
+    layer; both dense layers, 4 expert layers with 16 of 512 experts
+    held), with the wide cell's cache (64 lanes, 2881 blocks, tables of
+    45): a fused decode burst of the engine's own program and a
+    2048-token prefill chunk.  In both, the float32 state (5 x 64 lanes x
+    32 heads x 128 x 128: 671 MB here, 1.34 GB at the cell's 12 layers)
+    is updated where it lies: no copy of its shape nor of one layer's
+    slice over the lanes, and the program's temporaries stay beside 7.25 GB of weights,
+    state and cache at 12 layers (1.94 GB decode, 2.25 GB prefill there;
+    off-chip compiles, PR 35)."""
+    import re
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.models import ling
+
+    L, NB, B, MB, K, T = 6, 2881, 64, 45, 8, 2048
+    cfg = dataclasses.replace(ling.PRESETS["ling-3.0-flash"], n_layers=L,
+                              experts_held=(0, 16))
+    S = _sds(one_chip)
+    shapes = jax.eval_shape(
+        lambda: ling.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    kv = tuple(S(s, d) for s, d in zip(
+        ling.kv_cache_shapes(cfg, NB, BS, lanes=B),
+        ling.kv_cache_dtypes(cfg)))
+    assert kv[2].shape == (5, B, 32, 128, 128) and kv[2].dtype == jnp.float32
+    assert kv[3].shape == (5, B, 3, 12288)
+    assert kv[0].shape == (1, 1, NB, 512, BS)
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+
+    def state_stays(hlo):
+        # (a prefill row's own 2 MB working state may move; the pool and
+        # a layer's slice over all lanes may not)
+        for shape in (rf"f32\[5,{B},32,128,128\]",
+                      rf"f32\[{B},32,128,128\]"):
+            assert not re.findall(rf"= {shape}\S* copy\(", hlo), shape
+
+    fn = jax.jit(
+        partial(JaxEngine._decode_multi_impl, ling, cfg, None, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9))
+    lowered = fn.lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32))
+    assert lowered.out_info[0].shape == (K + len(ling.KV_COUNTERS), B)
+    program = lowered.compile()
+    hlo = program.as_text()
+    state_stays(hlo)
+    # a decode step keeps the dense form: every held expert, every lane
+    assert f"bf16[16,{B},768]" in hlo
+    assert "tpu_custom_call" not in hlo
+    assert program.memory_analysis().temp_size_in_bytes < 2.5e9
+    pre = jax.jit(partial(JaxEngine._prefill_impl, ling, cfg),
+                  donate_argnums=(1,))
+    program = pre.lower(
+        params, kv, S((T,), i32), S((T,), i32), S((MB,), i32), S((), i32),
+        S((), i32), S((), i32), S((), f32), S((), i32), S((), f32), None,
+        None, S((), i32)).compile()
+    hlo = program.as_text()
+    state_stays(hlo)
+    # a prompt-sized chunk groups its picks: three grouped matmuls an
+    # expert layer, no token met every held expert
+    assert hlo.count("tpu_custom_call") == 3 * 4
+    assert f"bf16[16,{T},768]" not in hlo
+    assert program.memory_analysis().temp_size_in_bytes < 3.0e9
