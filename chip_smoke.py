@@ -15,7 +15,9 @@ One chip (the default, what the driver runs):
             Pallas decode and packed-prefill kernels against their XLA
             references at llama-3b widths (bf16 and int8), and packed
             "auto" at the doc cell's shape (2048 tokens, one row, table
-            width 50, Mistral's widths) against the scan; JaxEngine
+            width 50, Mistral's widths) against the scan; the latent
+            (MLA) decode kernel against its jnp body at Ling's and
+            Moonlight's shapes; JaxEngine
             serving one request cold and again as a prefix-cache hit
             (first-token logits of the engine's own two prefills within
             a bf16 tolerance); and JaxEngine with attn_impl /
@@ -691,7 +693,61 @@ def check_kernels(args, pallas: str) -> dict:
             f"T={T} one row at {start}.. width {mb} nkv={nkv} nh={nh}, "
             f"auto = {json.dumps(out['packed_auto_impl'])}", kf, vf,
             packed, impl="auto")
+    out.update(check_mla_kernel(args, pallas, rng))
     jax.clear_caches()
+    return out
+
+
+def check_mla_kernel(args, pallas: str, rng) -> dict:
+    """The compiled latent decode kernel (ops/pallas_mla_attention.py)
+    against the gathering jnp body at the two cells' shapes (Ling's 32
+    heads over 64 lanes x 45 blocks, Moonlight's 16 over 16 x 20; R 512,
+    rope key 64, blocks of 128): lanes of unequal length, contexts
+    ending mid-block, idle lanes, tables far wider than what is live."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.ops.mla_attention import mla_decode_attention
+    from dynamo_tpu.ops.paged_attention import resolve_decode_impl
+
+    shapes = {"ling": (32, 64, 45), "moonlight": (16, 16, 20)}
+    R, dr, dv, bs = 512, 64, 128, 128
+    if args.rehearse:
+        shapes = {"ling": (4, 6, 5), "moonlight": (2, 3, 4)}
+        R, dr, dv, bs = 32, 16, 16, 8
+    out = {"mla_auto_impl": resolve_decode_impl(
+        "auto", jax.default_backend(), bs, (R, dr), jnp.bfloat16)}
+    for name, (nh, B, mb) in shapes.items():
+        nb = 1 + B * mb
+        kv_lens = rng.integers(1, mb * bs + 1, B).astype(np.int32)
+        kv_lens[:3] = [mb * bs, 0, bs + 1]      # full, idle, mid-block
+        tables = np.zeros((B, mb), np.int32)
+        perm = rng.permutation(nb - 1) + 1
+        for b in range(B):
+            used = -(-int(kv_lens[b]) // bs)
+            tables[b, :used] = perm[b * mb:b * mb + used]
+        a = [jnp.asarray(rng.standard_normal(s), jnp.bfloat16) for s in (
+            (B, nh, R), (B, nh, dr), (2, 1, nb, R, bs), (2, 1, nb, dr, bs))]
+        w_uv = jnp.asarray(rng.standard_normal((nh, R, dv)) / R ** 0.5,
+                           jnp.bfloat16)
+        run = lambda impl: mla_decode_attention(
+            *a, 1, jnp.asarray(tables), jnp.asarray(kv_lens), w_uv,
+            (128 + dr) ** -0.5, impl=impl).astype(jnp.float32)
+        got, ref = run(pallas), run("jnp")
+        live = jnp.asarray(kv_lens > 0)[:, None, None]
+        err = float(jnp.max(jnp.where(live, jnp.abs(got - ref), 0.0)))
+        check(bool(jnp.all(jnp.isfinite(got))),
+              f"mla decode kernel ({name}) produced non-finite values")
+        check(float(jnp.max(jnp.abs(got[1]))) == 0.0,
+              f"mla decode kernel ({name}): an idle lane's output is not 0")
+        check(err <= KERNEL_ATOL,
+              f"mla decode kernel ({name}) differs from the jnp body by "
+              f"{err:.4f} > {KERNEL_ATOL}")
+        out[f"mla_decode_{name}_max_abs_err"] = round(err, 5)
+        say(f"mla decode kernel {pallas} vs the jnp body, {name}: nh={nh} "
+            f"B={B} table={mb}, {int((-(-kv_lens // bs)).sum())} live "
+            f"blocks: max|err|={err:.5f} (atol {KERNEL_ATOL})")
     return out
 
 

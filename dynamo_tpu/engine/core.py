@@ -198,8 +198,8 @@ class JaxEngine:
         # custom model_config.  "" keeps the family's default.  A knob
         # the family would silently ignore is a loud config error — the
         # MDC advertises the EFFECTIVE impl and must never claim a
-        # kernel the worker doesn't run: MLA consults neither
-        # attn_impl beyond "jnp" (family SUPPORTED_ATTN_IMPLS) nor
+        # kernel the worker doesn't run: MLA's absorbed read has no
+        # "jnp_bf16" form (family SUPPORTED_ATTN_IMPLS) and no
         # packed_attn_impl (no packed path / field).
         from ..ops.packed_prefill import PACKED_IMPLS
         from ..ops.paged_attention import DECODE_IMPLS
@@ -298,12 +298,16 @@ class JaxEngine:
         # "auto" decode attention becomes what it means for this cache on
         # this mesh's platform (ops/paged_attention.resolve_decode_impl):
         # the step programs, the MDC and the decode_attn_* counters all
-        # name the impl that runs.  MLA's config says "jnp" already.
+        # name the impl that runs.  The rule reads the heights of the
+        # cache's block planes: `head_dim`, or what a config whose paged
+        # members are MLA's says they are (`mla_plane_heights`).
         if self.model_cfg.attn_impl == "auto":
             self.model_cfg = dataclasses.replace(
                 self.model_cfg, attn_impl=resolve_decode_impl(
                     "auto", self.mesh.devices.flat[0].platform,
-                    config.block_size, self.model_cfg.head_dim,
+                    config.block_size,
+                    getattr(self.model_cfg, "mla_plane_heights", None)
+                    or self.model_cfg.head_dim,
                     jnp.int8 if self.kv_dtype == "int8"
                     else self.model_cfg.dtype))
         if config.kv_hbm_gb > 0:
@@ -3631,8 +3635,8 @@ class JaxEngine:
         a burst of `k` steps over active lanes holding `ctx` tokens:
         live = k x sum ceil((ctx + 1) / block_size); read = the blocks
         the impl moves by construction — every lane's whole table width
-        per step for the gathering paths (jnp, MLA), each step's live
-        blocks for the Pallas kernel."""
+        per step for the gathering jnp paths, each step's live blocks
+        for the Pallas kernels (GQA's and the latent cache's)."""
         bs = self.config.block_size
         layers, picks, held = self._moe
         self.metrics["moe_picks.decode"] += k * len(ctx) * layers * picks

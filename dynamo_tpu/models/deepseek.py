@@ -17,7 +17,10 @@ Architecture (DeepSeek V2/V3 lineage):
     (llama.py's dispatch machinery, scaled by routed_scaling_factor).
 
 Decode runs the weight-absorbed MLA formulation (never materializes
-per-head K/V); prefill up-projects per chunk.  YaRN long-context scaling
+per-head K/V), its read in the Pallas latent kernel where
+`cfg.attn_impl` resolves to it (a TPU with 128-token blocks: the live
+blocks only, from the pool where it lies) and in jnp elsewhere; prefill
+up-projects per chunk.  YaRN long-context scaling
 is not implemented (rope_theta covers the tested ranges).
 """
 
@@ -32,8 +35,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.mla_attention import mla_decode_attention, mla_prefill_attention
+from ..ops.mla_attention import (
+    MLA_DECODE_IMPLS,
+    mla_decode_attention,
+    mla_prefill_attention,
+    mla_write_token,
+)
 from ..ops.paged_attention import (
+    PALLAS_IMPLS,
+    resolve_decode_impl,
     write_prompt_kv,
     write_prompt_kv_batched,
     write_token_kv,
@@ -86,13 +96,23 @@ class DeepseekConfig:
     tie_embeddings: bool = False
     max_context: int = 8192
     dtype: Any = jnp.bfloat16
-    attn_impl: str = "jnp"        # MLA decode is jnp-only (absorbed path)
+    attn_impl: str = "auto"       # the absorbed decode read: MLA_DECODE_IMPLS
     eos_token_ids: Tuple[int, ...] = (2,)
     qk_norm: bool = False         # unused; uniform surface with LlamaConfig
 
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def mla_plane_heights(self) -> Tuple[int, int]:
+        """The rows of a cached block's planes, latent and rope key:
+        what `resolve_decode_impl` reads as `head_dim` for this cache."""
+        return (self.kv_lora_rank, self.qk_rope_head_dim)
+
+    # callers that resolve "auto" from `cfg.head_dim`, as for the GQA
+    # families (engine/core.py, benchmark/chip_logits.py), get the same
+    head_dim = mla_plane_heights
 
     @property
     def q_dim(self) -> int:
@@ -102,11 +122,10 @@ class DeepseekConfig:
         return self.n_experts > 0 and li >= self.first_k_dense
 
 
-# the absorbed-latent MLA decode path never consults cfg.attn_impl (no
-# paged_attention_decode dispatch in this family), so an engine-level
-# --attn-impl override of anything but "jnp" would be silently ignored;
-# the engine rejects those loudly against this set
-SUPPORTED_ATTN_IMPLS = ("jnp",)
+# what the absorbed decode read can be told to be (there is no
+# "jnp_bf16" form of it); the engine rejects any other --attn-impl
+# loudly against this set
+SUPPORTED_ATTN_IMPLS = MLA_DECODE_IMPLS
 
 PRESETS: Dict[str, DeepseekConfig] = {
     # test-scale
@@ -425,6 +444,30 @@ def prefill_batched(
 # ---------------------------------------------------------------------------
 
 
+def mla_decode_plan(cfg, c_cache, kr_cache, ctx_lens, valid, mesh):
+    """What a decode step's MLA layers share (models/ling.py too):
+    -> (impl, kv_lens, write_token).  `impl` is what `cfg.attn_impl`
+    means for this cache here (a TPU with lane-aligned blocks: the
+    Pallas kernel).  The kernel reads the pools where they lie, so
+    beside it the token's column is written in the resident layout too,
+    by its own kernel (`mla_write_token`; with the flat scatter XLA lays
+    the pool out one way for the write and another for the custom call,
+    and copies it every step), and an idle lane (valid False) claims no
+    context: it reads nothing."""
+    impl = resolve_decode_impl(
+        cfg.attn_impl, jax.default_backend(), c_cache.shape[4],
+        (c_cache.shape[3], kr_cache.shape[3]), c_cache.dtype)
+    kv_lens = ctx_lens + 1
+    if valid is not None:
+        kv_lens = jnp.where(valid, kv_lens, 0)
+    if impl in PALLAS_IMPLS:
+        write = partial(mla_write_token, valid=valid, mesh=mesh,
+                        interpret=impl == "pallas_interpret")
+    else:
+        write = write_token_kv
+    return impl, kv_lens, write
+
+
 def decode(
     params: Dict[str, Any],
     cfg: DeepseekConfig,
@@ -434,7 +477,7 @@ def decode(
     block_tables: jax.Array,   # [B, max_blocks]
     ctx_lens: jax.Array,       # [B]
     valid: Optional[jax.Array] = None,
-    mesh=None,                 # uniform signature; MLA decode is pure jnp
+    mesh=None,                 # the kernel's, under tp > 1
 ):
     # dynlint: disable=DYN009 MLA latent cache is bf16-only by design (no int8 scale shapes); the engine forces the bf16 fallback for this family
     c_cache, kr_cache = kv_cache
@@ -442,18 +485,21 @@ def decode(
     B = x.shape[0]
     pos1 = positions[:, None]
     scale = 1.0 / jnp.sqrt(jnp.float32(cfg.qk_head_dim))
+    impl, kv_lens, write_token = mla_decode_plan(
+        cfg, c_cache, kr_cache, ctx_lens, valid, mesh)
     for li, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["attn_norm"]["norm"], cfg.rms_eps)
         q_nope, q_rope = _q_proj(layer, cfg, h[:, None, :], pos1)
         c, kr = _kv_latent(layer, cfg, h[:, None, :], pos1)
-        c_cache, kr_cache = write_token_kv(
+        c_cache, kr_cache = write_token(
             c_cache, kr_cache, li, c[:, 0][:, None, :],
             kr[:, 0][:, None, :], block_tables, ctx_lens,
         )
         q_abs = _absorb_q(layer, q_nope[:, 0])           # [B, nh, R]
         attn = mla_decode_attention(
             q_abs, q_rope[:, 0], c_cache, kr_cache, li,
-            block_tables, ctx_lens + 1, layer["w_uv"], scale,
+            block_tables, kv_lens, layer["w_uv"], scale,
+            impl=impl, mesh=mesh,
         )                                                # [B, nh, dv]
         x = x + attn.reshape(B, -1) @ layer["wo"]
         h = rms_norm(x, layer["mlp_norm"]["norm"], cfg.rms_eps)

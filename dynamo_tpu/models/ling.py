@@ -14,9 +14,10 @@ Per layer l, by `cfg.layer_kinds[l]` (0 KDA, 1 MLA; MLA where
     corrected by the delta rule and read with q / sqrt(head_dim); the
     read is RMS-normed a head, gated by sigmoid(h Wg) a head and goes
     through Wo.  No rotary: the decay carries position.
-  * MLA: models/deepseek.py's `_q_proj`, `_kv_latent`, `_absorb_q` and
-    ops/mla_attention.py, imported: a latent and a shared rope key a
-    token, absorbed decode.
+  * MLA: models/deepseek.py's `_q_proj`, `_kv_latent`, `_absorb_q`,
+    `mla_decode_plan` and ops/mla_attention.py, imported: a latent and
+    a shared rope key a token, absorbed decode (the Pallas latent
+    kernel where `cfg.attn_impl` resolves to it, jnp elsewhere).
   * FFN: dense SwiGLU below `first_k_dense`, else DeepSeek routing
     (`_ds_router`: sigmoid, choice bias, group-limited top-k,
     renormalised, scaled) over `n_experts` router outputs of which this
@@ -69,9 +70,19 @@ from ..ops.delta_attention import (
     short_conv,
     short_conv_step,
 )
-from ..ops.mla_attention import mla_decode_attention, mla_prefill_attention
-from ..ops.paged_attention import write_prompt_kv_batched, write_token_kv
-from .deepseek import _absorb_q, _ds_router, _kv_latent, _q_proj
+from ..ops.mla_attention import (
+    MLA_DECODE_IMPLS,
+    mla_decode_attention,
+    mla_prefill_attention,
+)
+from ..ops.paged_attention import PALLAS_IMPLS, write_prompt_kv_batched
+from .deepseek import (
+    _absorb_q,
+    _ds_router,
+    _kv_latent,
+    _q_proj,
+    mla_decode_plan,
+)
 from .mimo import _pool_index     # layer -> its index inside its kind's members
 from .llama import (
     _logits,
@@ -128,7 +139,7 @@ class LingConfig:
     tie_embeddings: bool = False
     max_context: int = 8192
     dtype: Any = jnp.bfloat16
-    attn_impl: str = "jnp"        # the MLA layers' absorbed decode is jnp
+    attn_impl: str = "auto"       # the MLA layers' read: MLA_DECODE_IMPLS
     eos_token_ids: Tuple[int, ...] = (2,)
     qk_norm: bool = False         # unused; uniform surface
 
@@ -177,6 +188,12 @@ class LingConfig:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     @property
+    def mla_plane_heights(self) -> Tuple[int, int]:
+        """DeepseekConfig's: what `resolve_decode_impl` reads for the
+        paged members (`head_dim` here is the KDA layers')."""
+        return (self.kv_lora_rank, self.qk_rope_head_dim)
+
+    @property
     def q_dim(self) -> int:
         return self.n_heads * self.qk_head_dim
 
@@ -192,8 +209,8 @@ UNSUPPORTED = ("prefix_caching", "kv_int8", "speculation", "lora",
 # the state and the tail are addressed by lane: prefill takes `lanes`
 KV_LANE_ADDRESSED = True
 
-# the MLA layers' absorbed decode never consults cfg.attn_impl
-SUPPORTED_ATTN_IMPLS = ("jnp",)
+# what the MLA layers' absorbed decode read can be told to be
+SUPPORTED_ATTN_IMPLS = MLA_DECODE_IMPLS
 
 # the cache tuple's last member: device-side counts, one int32 each
 KV_COUNTERS = ("moe_picks_held.prefill", "moe_picks_held.decode",
@@ -251,15 +268,18 @@ def decode_block_counts(cfg: LingConfig, ctx: np.ndarray, k: int,
     """Host-side counts for a decode burst of `k` steps over active
     lanes holding `ctx` tokens (engine/core.py _count_decode_attn).  The
     MLA layers' cache blocks, summed over layers and steps: `live` what
-    the mask needs, `read` what the gathering read moves (every lane's
-    whole table).  And the state pool's lanes: each active lane moves
+    the mask needs, `read` what the impl that runs moves (the kernel each
+    step's live blocks, the gathering read every lane's whole table).
+    And the state pool's lanes: each active lane moves
     one state a KDA layer a step, out of `lanes` slots that a step's
     program runs over."""
     nm = len(cfg.layers_of(MLA))
-    live = -(-(ctx[:, None] + 1 + np.arange(k)[None, :]) // block_size)
+    live = int((-(-(ctx[:, None] + 1 + np.arange(k)[None, :])
+                  // block_size)).sum())
+    read = live if attn_impl in PALLAS_IMPLS else k * lanes * table_width
     return {
-        "decode_attn_live_blocks": nm * int(live.sum()),
-        "decode_attn_read_blocks": nm * k * lanes * table_width,
+        "decode_attn_live_blocks": nm * live,
+        "decode_attn_read_blocks": nm * read,
         "recurrent_lane_steps.decode": k * len(ctx),
         "recurrent_slot_steps.decode": k * lanes,
     }
@@ -564,6 +584,8 @@ def decode(
     scale = 1.0 / math.sqrt(cfg.head_dim)
     pool_li = _pool_index(cfg)
     picks = visited = jnp.zeros((), jnp.int32)
+    impl, kv_lens, write_token = mla_decode_plan(
+        cfg, c_cache, kr_cache, ctx_lens, valid, mesh)
     for li, layer in enumerate(params["layers"]):
         kind, pli = cfg.layer_kinds[li], pool_li[li]
         h = rms_norm(x, layer["attn_norm"]["norm"], cfg.rms_eps)
@@ -583,14 +605,15 @@ def decode(
         else:
             q_nope, q_rope = _q_proj(layer, cfg, h[:, None, :], pos1)
             c, kr = _kv_latent(layer, cfg, h[:, None, :], pos1)
-            c_cache, kr_cache = write_token_kv(
+            c_cache, kr_cache = write_token(
                 c_cache, kr_cache, pli, c[:, 0][:, None, :],
                 kr[:, 0][:, None, :], block_tables, ctx_lens)
             q_abs = _absorb_q(layer, q_nope[:, 0])       # [B, nh, R]
             with jax.named_scope("dyn.attn_mla"):
                 attn = mla_decode_attention(
                     q_abs, q_rope[:, 0], c_cache, kr_cache, pli,
-                    block_tables, ctx_lens + 1, layer["w_uv"], mla_scale)
+                    block_tables, kv_lens, layer["w_uv"], mla_scale,
+                    impl=impl, mesh=mesh)
             with jax.named_scope("dyn.attn_out"):
                 x = x + attn.reshape(B, -1) @ layer["wo"]
         h = rms_norm(x, layer["mlp_norm"]["norm"], cfg.rms_eps)

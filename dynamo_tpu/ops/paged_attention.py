@@ -442,7 +442,7 @@ PALLAS_IMPLS = ("pallas", "pallas_interpret")
 
 
 def resolve_decode_impl(impl: str, platform: str, block_size: int,
-                        head_dim: int, cache_dtype) -> str:
+                        head_dim, cache_dtype) -> str:
     """What `impl` means for this cache on this platform: the one place
     "auto" is decided, from what the code can observe.  An explicit impl
     is returned as given.
@@ -452,7 +452,9 @@ def resolve_decode_impl(impl: str, platform: str, block_size: int,
     [hd, bs] block planes it DMAs), head_dim a whole number of sublane
     tiles for the cache dtype (16 rows bf16, 32 int8), a bf16 or int8
     cache — and the jnp path everywhere else (CPU, block_size 16, fp32
-    caches).  The kernel moves the live context's bytes in the cache's
+    caches).  `head_dim` is the plane's height, or a tuple of them where
+    the cache's members differ (an MLA cache's latent and rope key,
+    ops/mla_attention.py): each has to be whole tiles.  The kernel moves the live context's bytes in the cache's
     dtype straight from the pool; the jnp path gathers lanes x table
     width and upcasts to fp32.  On `mistral-7b.chat` (16 lanes x 20
     blocks, ~25 blocks live) that is a decode step of 31.7 ms with jnp
@@ -461,9 +463,10 @@ def resolve_decode_impl(impl: str, platform: str, block_size: int,
     if impl != "auto":
         return impl
     dt = jnp.dtype(cache_dtype)
+    heights = head_dim if isinstance(head_dim, tuple) else (head_dim,)
     if (platform == "tpu" and block_size % 128 == 0
             and dt in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.int8))
-            and head_dim % (32 // dt.itemsize) == 0):
+            and all(h % (32 // dt.itemsize) == 0 for h in heights)):
         return "pallas"
     return "jnp"
 

@@ -62,6 +62,13 @@ tests/test_packed_pallas.py cross-check the two (int8 included) in
 interpret mode on the CPU; tests/test_tpu_compile.py compiles the kernel
 for a described v5e, and chip_smoke.py checks the compiled kernel
 against the jnp path on the chip.
+
+`make_chunk_dma`, `make_chunk_chain` and `chunk_chain_planes` are the
+chunk contract's one definition site; two kernels stand on it: this one
+and the latent decode kernel of the MLA families
+(pallas_mla_attention.py: the same chain over a latent and a rope-key
+pool, other matmuls).  (The packed-prefill kernel left it in PR 34 for
+BlockSpec-pipelined tiles.)
 """
 
 from __future__ import annotations
@@ -79,17 +86,17 @@ NEG_INF = -1e30
 def make_chunk_dma(tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem, *,
                    bpc, bs, ks_hbm=None, vs_hbm=None, ks_buf=None,
                    vs_buf=None, layer=None, live_blocks=None):
-    """The chunk DMA contract shared by the decode and packed-prefill
-    kernels: (start, wait) closures moving up to `bpc` physical blocks
+    """The chunk DMA contract shared by the decode kernels (this file's
+    and pallas_mla_attention.py's, where k/v are the latent and the rope
+    key, nkv = 1): (start, wait) closures moving up to `bpc` physical blocks
     into a double-buffered VMEM chunk — one strided descriptor per block
     per tensor ([nkv, hd, bs], all heads, landing at the block's offset
     in the chunk buffer), and for an int8 cache the block's [nkv, bs]
     fp32 scale rows on two extra semaphore lanes (`sem` is [slots, 2]
     bf16 / [slots, 4] int8).  Both closures take (row, c, slot) where
-    `row` indexes tables_ref's first axis (the sequence for decode, the
-    segment for packed prefill).  One definition site keeps the two
-    kernels' DMA contracts — descriptor shapes, semaphore pairing,
-    scale lanes — from drifting.
+    `row` indexes tables_ref's first axis (the sequence).  One
+    definition site keeps the two kernels' DMA contracts — descriptor
+    shapes, semaphore pairing, scale lanes — from drifting.
 
     `layer` (a scalar, static or read from SMEM): the HBM refs are the
     WHOLE pools [L, nkv, nb, hd, bs] (scales [L, nkv, nb, bs]) and the
@@ -161,9 +168,9 @@ def make_chunk_chain(start_chunk, wait_chunk):
     `prime(row, nch, base)` issues that first fetch; `step(row, c, nch,
     base, next_row)` runs inside the chunk loop and returns the slot
     holding chunk `c` (next_row < 0 = nothing left to prefetch).  The
-    caller supplies `base` (chunks consumed by all earlier rows — the
-    decode kernel recomputes it from kv_lens, the packed kernel rides a
-    precomputed scalar-prefetch plane) and `next_row`; the double-buffer
+    caller supplies `base` (chunks consumed by all earlier rows) and
+    `next_row`, both scalar-prefetch planes that `chunk_chain_planes`
+    makes from kv_lens; the double-buffer
     safety argument is program order: phase p+1's slot was last read by
     phase p-1's consume, which completes before p's loop iteration
     issues p+1."""
@@ -191,6 +198,32 @@ def make_chunk_chain(start_chunk, wait_chunk):
         return slot
 
     return prime, step
+
+
+def chunk_chain_planes(block_tables, kv_lens, bpc: int, bs: int):
+    """What a decode launch hands make_chunk_dma / make_chunk_chain by
+    scalar prefetch, from [B, max_blocks] tables and [B] lengths (valid
+    positions incl. the current token; 0 = a lane with no chunk):
+    (tables padded to whole chunks of `bpc` blocks, lengths clipped to
+    the table, each row's slot phase, each row's successor)."""
+    B, max_blocks = block_tables.shape
+    pad = -max_blocks % bpc
+    if pad:
+        # padded entries are past every live position: never copied
+        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
+    S = bpc * bs
+    # a lane never reads past its table, whatever length it claims
+    kv_lens = jnp.clip(kv_lens, 0, max_blocks * bs).astype(jnp.int32)
+    # the chunk chain's planes (make_chunk_chain): each row's slot phase
+    # (chunks of all earlier rows) and its successor, the next row with
+    # a chunk (suffix-min over row indices, -1 past the last)
+    nch = -(-kv_lens // S)
+    base = (jnp.cumsum(nch) - nch).astype(jnp.int32)
+    rows = jnp.arange(B, dtype=jnp.int32)
+    suf = jax.lax.cummin(jnp.where(nch > 0, rows, B)[::-1])[::-1]
+    nxt = jnp.concatenate([suf[1:], jnp.full((1,), B, jnp.int32)])
+    next_row = jnp.where(nxt < B, nxt, -1).astype(jnp.int32)
+    return block_tables, kv_lens, base, next_row
 
 
 def _decode_kernel(
@@ -236,7 +269,7 @@ def _decode_kernel(
     n_chunks = pl.cdiv(kv_len, S)
 
     # the chunk DMA contract (descriptor shapes, semaphore pairing, int8
-    # scale lanes) is shared with the packed-prefill kernel; here a
+    # scale lanes) is shared with the latent decode kernel; here a
     # chunk moves only the blocks that hold live positions
     start_chunk, wait_chunk = make_chunk_dma(
         tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem, bpc=bpc, bs=bs,
@@ -382,21 +415,9 @@ def paged_attention_decode_pallas(
     bpc = blocks_per_chunk or max(1, min(max_blocks, -(-1024 // bs)))
     n_chunks = -(-max_blocks // bpc)
     pad = n_chunks * bpc - max_blocks
-    if pad:
-        # padded entries are past every live position: never copied
-        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
     S = bpc * bs
-    # a lane never reads past its table, whatever length it claims
-    kv_lens = jnp.clip(kv_lens, 0, max_blocks * bs).astype(jnp.int32)
-    # the chunk chain's planes (make_chunk_chain): each row's slot phase
-    # (chunks of all earlier rows) and its successor, the next row with
-    # a chunk (suffix-min over row indices, -1 past the last)
-    nch = -(-kv_lens // S)
-    base = (jnp.cumsum(nch) - nch).astype(jnp.int32)
-    rows = jnp.arange(B, dtype=jnp.int32)
-    suf = jax.lax.cummin(jnp.where(nch > 0, rows, B)[::-1])[::-1]
-    nxt = jnp.concatenate([suf[1:], jnp.full((1,), B, jnp.int32)])
-    next_row = jnp.where(nxt < B, nxt, -1).astype(jnp.int32)
+    block_tables, kv_lens, base, next_row = chunk_chain_planes(
+        block_tables, kv_lens, bpc, bs)
 
     scale = 1.0 / jnp.sqrt(jnp.float32(hd))
     qg = (q.astype(jnp.float32) * scale).astype(q.dtype)

@@ -247,3 +247,242 @@ def test_deepseek_presets_resolve():
     assert isinstance(cfg, DeepseekConfig)
     r1 = EngineConfig(model="deepseek-r1").resolve_model()
     assert r1.n_experts == 256 and r1.kv_lora_rank == 512
+
+
+# ---------------------------------------------------------------------------
+# the latent decode kernel (ops/pallas_mla_attention.py) against the jnp
+# body, under the interpreter
+# ---------------------------------------------------------------------------
+
+# (heads, R, dr): the two cells' head counts, widths cut to test size
+LATENT_WIDTHS = {"moonlight": (16, 64, 16), "ling": (32, 64, 32)}
+
+
+@pytest.mark.parametrize("bpc", [None, 2], ids=["chunk8", "chunk2"])
+@pytest.mark.parametrize("family", sorted(LATENT_WIDTHS))
+def test_latent_kernel_matches_the_jnp_body(family, bpc):
+    """Lanes of unequal length in tables of 12 blocks: a full table, a
+    context ending mid-block, one token, exactly one block, two idle
+    lanes (kv_len 0: output 0) with another lane between and after
+    them, a lane whose table is far wider than its two live blocks.
+    Every padded table entry points at the garbage block, which holds
+    NaN for the kernel: it moves live blocks only, so none of it may
+    reach an output.  Layer 1 of two: the layer index is honoured."""
+    from dynamo_tpu.ops.mla_attention import (
+        _mla_decode_jnp,
+        mla_decode_attention,
+    )
+    from dynamo_tpu.ops.pallas_mla_attention import mla_decode_pallas
+
+    nh, R, dr = LATENT_WIDTHS[family]
+    bs, mb, dv = 16, 12, 8
+    lens = np.asarray([mb * bs, 5 * bs + 7, 0, 1, bs, 0, 2 * bs, 3 * bs - 1],
+                      np.int32)
+    B = len(lens)
+    nb = 1 + B * mb
+    rng = np.random.default_rng(11)
+    tables = np.zeros((B, mb), np.int32)
+    perm = rng.permutation(nb - 1) + 1
+    for b in range(B):
+        used = -(-int(lens[b]) // bs)
+        tables[b, :used] = perm[b * mb:b * mb + used]
+    bf = jnp.bfloat16
+    qa = jnp.asarray(rng.standard_normal((B, nh, R)), bf)
+    qr = jnp.asarray(rng.standard_normal((B, nh, dr)), bf)
+    c = jnp.asarray(rng.standard_normal((2, 1, nb, R, bs)), bf)
+    kr = jnp.asarray(rng.standard_normal((2, 1, nb, dr, bs)), bf)
+    scale = 0.11
+    t, n = jnp.asarray(tables), jnp.asarray(lens)
+    want = np.asarray(_mla_decode_jnp(qa, qr, c, kr, 1, t, n, scale))
+    dirty = lambda x: x.at[:, :, 0].set(jnp.nan)
+    got = np.asarray(mla_decode_pallas(
+        qa, qr, dirty(c), dirty(kr), jnp.int32(1), t, n, scale,
+        blocks_per_chunk=bpc, interpret=True))
+    assert got.dtype == np.float32 and np.isfinite(got).all()
+    live = lens > 0
+    assert np.abs(got[~live]).max() == 0.0
+    # the kernel follows the body's float32 arithmetic (unrounded
+    # queries, the weights as a bf16 pair): outputs are averages of unit
+    # normals, and they agree to 1e-4
+    np.testing.assert_allclose(got[live], want[live], atol=1e-4)
+    other = np.asarray(mla_decode_pallas(
+        qa, qr, c, kr, jnp.int32(0), t, n, scale, interpret=True))
+    assert np.abs(other[live] - got[live]).max() > 0.1
+    # through the op: W_UV outside the kernel, as in the jnp form
+    w_uv = jnp.asarray(rng.standard_normal((nh, R, dv)) / R ** 0.5, bf)
+    a, b = (np.asarray(mla_decode_attention(
+        qa, qr, c, kr, 1, t, n, w_uv, scale, impl=impl), np.float32)
+        for impl in ("pallas_interpret", "jnp"))
+    np.testing.assert_allclose(a[live], b[live], atol=0.05)
+    with pytest.raises(ValueError, match="MLA decode impl"):
+        mla_decode_attention(qa, qr, c, kr, 1, t, n, w_uv, scale,
+                             impl="jnp_bf16")
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_latent_token_write_sets_the_cells_the_xla_writer_sets(dtype):
+    """`mla_write_token` (the Pallas writer beside the latent kernel)
+    against `write_token_kv(resident=True)`: first and last column of a
+    block, a block boundary, an idle lane between writers; bit-equal
+    pools, layer 0 untouched."""
+    from dynamo_tpu.ops.mla_attention import mla_write_token
+    from dynamo_tpu.ops.paged_attention import write_token_kv
+
+    R, dr, bs, mb, B = 32, 16, 8, 6, 5
+    nb = 1 + B * mb
+    rng = np.random.default_rng(2)
+    c = jnp.asarray(rng.standard_normal((2, 1, nb, R, bs)), dtype)
+    kr = jnp.asarray(rng.standard_normal((2, 1, nb, dr, bs)), dtype)
+    new_c = jnp.asarray(rng.standard_normal((B, 1, R)), dtype)
+    new_kr = jnp.asarray(rng.standard_normal((B, 1, dr)), dtype)
+    tables = jnp.asarray(1 + rng.permutation(B * mb).reshape(B, mb),
+                         jnp.int32)
+    ctx = jnp.asarray([0, 7, 8, 20, 47], jnp.int32)
+    valid = jnp.asarray([True, True, False, True, True])
+    want = write_token_kv(c, kr, 1, new_c, new_kr, tables, ctx,
+                          resident=True, valid=valid)
+    got = mla_write_token(c, kr, 1, new_c, new_kr, tables, ctx,
+                          valid=valid, interpret=True)
+    for w, g, before in zip(want, got, (c, kr)):
+        assert np.array_equal(np.asarray(w, np.float32),
+                              np.asarray(g, np.float32))
+        assert np.array_equal(np.asarray(g[0], np.float32),
+                              np.asarray(before[0], np.float32))
+        assert not np.array_equal(np.asarray(g[1], np.float32),
+                                  np.asarray(before[1], np.float32))
+
+
+@pytest.mark.parametrize("platform,block,dtype,heights,want", [
+    ("cpu", 128, jnp.bfloat16, (512, 64), "jnp"),
+    ("tpu", 16, jnp.bfloat16, (512, 64), "jnp"),
+    ("tpu", 128, jnp.float32, (512, 64), "jnp"),
+    ("tpu", 128, jnp.bfloat16, (512, 64), "pallas"),
+    ("tpu", 256, jnp.bfloat16, (512, 64), "pallas"),
+    ("tpu", 128, jnp.bfloat16, (512, 8), "jnp"),
+    ("tpu", 128, jnp.bfloat16, (24, 64), "jnp"),
+])
+def test_auto_is_decided_from_what_the_latent_cache_shows(
+        platform, block, dtype, heights, want):
+    """`resolve_decode_impl` asked about both members of a latent cache:
+    the kernel on a TPU with lane-aligned blocks, a bf16 cache and both
+    plane heights whole sublane tiles; jnp elsewhere.  An explicit impl
+    is returned as given, and both families' configs say the heights."""
+    from dynamo_tpu.models.ling import LingConfig
+    from dynamo_tpu.ops.paged_attention import resolve_decode_impl
+
+    assert resolve_decode_impl("auto", platform, block, heights,
+                               dtype) == want
+    assert resolve_decode_impl("pallas_interpret", platform, block,
+                               heights, dtype) == "pallas_interpret"
+    for cfg in (DeepseekConfig(kv_lora_rank=heights[0],
+                               qk_rope_head_dim=heights[1]),
+                LingConfig(kv_lora_rank=heights[0],
+                           qk_rope_head_dim=heights[1])):
+        assert cfg.attn_impl == "auto"
+        assert cfg.mla_plane_heights == heights
+    assert DeepseekConfig().head_dim == DeepseekConfig().mla_plane_heights
+
+
+def _two_lanes_and_an_idle_one(family, cfg, params, kv, prefill_kw):
+    """Lanes 0 and 2 hold prompts of 9 and 21 tokens (blocks of 4: one
+    ends mid-block), lane 1 is idle -> (kv, first tokens, positions,
+    tables, valid)."""
+    rng = np.random.default_rng(3)
+    tables = np.zeros((3, 16), np.int32)
+    tables[0, :8], tables[2, :8] = 1 + np.arange(8), 9 + np.arange(8)
+    first, pos = np.zeros(3, np.int32), np.zeros(3, np.int32)
+    for lane, n in ((0, 9), (2, 21)):
+        toks = np.zeros(32, np.int32)          # one bucket of 32
+        toks[:n] = rng.integers(3, cfg.vocab_size, n)
+        logits, kv = family.prefill(
+            params, cfg, kv, jnp.asarray(toks),
+            jnp.arange(32, dtype=jnp.int32), jnp.asarray(tables[lane]),
+            jnp.int32(0), jnp.int32(n), **prefill_kw(lane))
+        first[lane], pos[lane] = int(jnp.argmax(logits)), n
+    return (kv, jnp.asarray(first), jnp.asarray(pos), jnp.asarray(tables),
+            jnp.asarray([True, False, True]))
+
+
+def test_decode_multi_under_the_kernel_emits_the_same_tokens():
+    """deepseek.decode_multi, 6 fused steps over two lanes and an idle
+    one: `pallas_interpret` (the kernel + the resident column write)
+    against `jnp` (the gather + the flat scatter): equal greedy tokens
+    and, on every block a lane owns, equal latents and rope keys."""
+    import dataclasses
+
+    from dynamo_tpu.models import deepseek
+
+    params = init_params(MLA32, jax.random.PRNGKey(8))
+    kv, first, pos, tables, valid = _two_lanes_and_an_idle_one(
+        deepseek, MLA32, params, fresh_cache(MLA32), lambda lane: {})
+    out = {}
+    for impl in ("jnp", "pallas_interpret"):
+        cfg = dataclasses.replace(MLA32, attn_impl=impl)
+        out[impl] = decode_multi(params, cfg, kv, first, pos, tables, pos,
+                                 6, valid=valid)
+    (ta, kva), (tb, kvb) = out["jnp"], out["pallas_interpret"]
+    live = np.asarray(valid)
+    assert np.asarray(ta)[:, live].tolist() == np.asarray(tb)[:, live].tolist()
+    for a, b in zip(kva, kvb):
+        np.testing.assert_allclose(np.asarray(a[:, :, 1:17]),
+                                   np.asarray(b[:, :, 1:17]), atol=1e-5)
+
+
+def test_ling_decode_multi_under_the_kernel_keeps_state_and_tail():
+    """ling.decode_multi (one period: five KDA layers, then the MLA
+    layer), the same burst: equal greedy tokens, and the float32 state
+    and the convolution tail BIT-equal on every lane, the idle one
+    included: the kernel and the resident write touch the paged members
+    only."""
+    import dataclasses
+
+    from dynamo_tpu.models import ling
+
+    cfg = ling.LingConfig(dtype=jnp.float32, experts_held=(0, 8),
+                          mla_q_block=16)
+    params = ling.init_params(cfg, jax.random.PRNGKey(9))
+    kv = tuple(jnp.ones(s, d) if i in (2, 3) else jnp.zeros(s, d)
+               for i, (s, d) in enumerate(zip(
+                   ling.kv_cache_shapes(cfg, 32, 4, lanes=3),
+                   ling.kv_cache_dtypes(cfg))))
+    kv, first, pos, tables, valid = _two_lanes_and_an_idle_one(
+        ling, cfg, params, kv, lambda lane: {"lanes": jnp.int32(lane)})
+    out = {}
+    for impl in ("jnp", "pallas_interpret"):
+        out[impl] = ling.decode_multi(
+            params, dataclasses.replace(cfg, attn_impl=impl), kv, first,
+            pos, tables, pos, 6, valid=valid)
+    (ta, kva), (tb, kvb) = out["jnp"], out["pallas_interpret"]
+    live = np.asarray(valid)
+    assert np.asarray(ta)[:, live].tolist() == np.asarray(tb)[:, live].tolist()
+    for member in (2, 3):
+        assert np.array_equal(np.asarray(kva[member]),
+                              np.asarray(kvb[member]))
+        assert float(jnp.abs(kvb[member][:, 1] - 1).max()) == 0.0
+    assert not np.array_equal(np.asarray(kvb[2]), np.asarray(kv[2]))
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas_interpret"])
+def test_engine_counts_what_the_resolved_impl_reads(impl):
+    """decode_attn_read_blocks under both families: the gathering read
+    moves lanes x table width a step, the kernel each step's live
+    blocks (= decode_attn_live_blocks: the share reads 100 %)."""
+    from dynamo_tpu.models import ling
+
+    eng = JaxEngine(EngineConfig(
+        model_config=MLA32, block_size=4, num_blocks=128,
+        max_blocks_per_seq=16, max_num_seqs=4, attn_impl=impl,
+        prefill_buckets=(8, 16, 32, 64), seed=7))
+    assert eng.model_cfg.attn_impl == impl
+    ctx, k = np.asarray([9, 21], np.int64), 4
+    eng._count_decode_attn(ctx, k)
+    live = int(sum(-(-(c + 1 + j) // 4) for c in ctx for j in range(k)))
+    assert eng.metrics["decode_attn_read_blocks"] == (
+        live if impl == "pallas_interpret" else k * 4 * 16)
+    assert eng.metrics["decode_attn_live_blocks"] == k * (3 + 6)
+    cfg = ling.LingConfig(n_layers=12)      # two MLA layers
+    counts = ling.decode_block_counts(cfg, ctx, k, 4, 4, 16, impl)
+    assert counts["decode_attn_live_blocks"] == 2 * live
+    assert counts["decode_attn_read_blocks"] == 2 * (
+        live if impl == "pallas_interpret" else k * 4 * 16)

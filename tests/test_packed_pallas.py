@@ -852,10 +852,10 @@ def test_engine_cli_parses_attn_impl_flags():
 
 
 def test_mla_rejects_attn_impl_overrides():
-    """MLA consults neither knob: its absorbed-latent decode never
-    dispatches paged_attention_decode (SUPPORTED_ATTN_IMPLS = jnp) and
-    it has no packed path — asking its worker for a kernel must be a
-    config error, not a silent no-op the MDC then mis-advertises."""
+    """MLA has no packed path, and its absorbed decode read has the
+    impls ops/mla_attention.py dispatches (SUPPORTED_ATTN_IMPLS: no
+    "jnp_bf16" form) — asking its worker for one it cannot run must be
+    a config error, not a silent no-op the MDC then mis-advertises."""
     from dynamo_tpu.engine import EngineConfig, JaxEngine
 
     def mla_cfg(**kw):
@@ -865,7 +865,9 @@ def test_mla_rejects_attn_impl_overrides():
     with pytest.raises(ValueError, match="packed_attn_impl"):
         JaxEngine(mla_cfg(packed_attn_impl="pallas_interpret"))
     with pytest.raises(ValueError, match="attn_impl"):
-        JaxEngine(mla_cfg(attn_impl="pallas"))
-    # the one value MLA actually runs passes through
-    eng = JaxEngine(mla_cfg(attn_impl="jnp"))
-    assert eng.model_cfg.attn_impl == "jnp"
+        JaxEngine(mla_cfg(attn_impl="jnp_bf16"))
+    # what MLA runs passes through; "auto" names what it resolved to
+    for ask, runs in (("jnp", "jnp"), ("pallas_interpret",
+                                       "pallas_interpret"), ("", "jnp")):
+        eng = JaxEngine(mla_cfg(attn_impl=ask))
+        assert eng.model_cfg.attn_impl == runs

@@ -393,9 +393,11 @@ def test_moonlight_prefill_groups_its_picks_and_decode_does_not(one_chip):
     themselves, [64, 2048, 1408], and cannot be told from them) holds no
     value of the dense dispatch's shape ([64, 1024, 1408]: every token
     through every expert) and three grouped matmuls; the fused decode burst
-    still holds the dense form at its 16 lanes and no kernel at all (MLA
-    decode is jnp): the shape picks the form, and the decode half of the
-    expert layer is as it was."""
+    still holds the dense form at its 16 lanes and no kernel at all (the
+    config's `auto` decode read resolves on this CPU host to jnp; the
+    latent kernel's program is
+    test_latent_decode_reads_the_pool_where_it_lies): the shape picks the
+    form, and the decode half of the expert layer is as it was."""
     import json
     from pathlib import Path
 
@@ -573,3 +575,152 @@ def test_recurrent_decode_and_prefill_compile_for_v5e(one_chip):
     assert hlo.count("tpu_custom_call") == 3 * 4
     assert f"bf16[16,{T},768]" not in hlo
     assert program.memory_analysis().temp_size_in_bytes < 3.0e9
+
+
+def _latent_case(family):
+    """-> (module, config with the decode read resolved as on the chip
+    left at "auto", lanes, table width, pool blocks, burst steps, MLA
+    layers): Moonlight's widths (benchmark/configs) cut to the dense
+    layer and two routed layers at the chat cell's 16 lanes x 20 blocks
+    over 512; Ling's cut to two periods (10 KDA layers, 2 MLA) at the
+    wide cell's 64 lanes x 45 blocks over 2881."""
+    import json
+    from pathlib import Path
+
+    if family == "ling":
+        from dynamo_tpu.models import ling
+
+        cfg = dataclasses.replace(ling.PRESETS["ling-3.0-flash"],
+                                  n_layers=12, experts_held=(0, 16),
+                                  vocab_size=8192)
+        return ling, cfg, 64, 45, 2881, 8, 2
+    from benchmark.reference.deepseek import program_config
+    from dynamo_tpu.models import deepseek
+
+    hf = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                     / "moonlight-16b-a3b-8l.json").read_text())
+    cfg = dataclasses.replace(program_config(hf, "moonlight"), n_layers=3,
+                              vocab_size=8192)
+    return deepseek, cfg, 16, 20, 512, 4, 3
+
+
+@pytest.mark.parametrize("family", ["moonlight", "ling"])
+def test_latent_decode_reads_the_pool_where_it_lies(topo, one_chip, family):
+    """`decode_multi` of the two latent-attention families at their
+    cells' lanes, tables and pools, `auto` resolved as on the chip: two
+    custom calls an MLA layer (the token's write, the read) from ONE
+    lowering each (the layer index is traced), and both pools handed
+    from call to call where they lie: nothing but the program's
+    plumbing gives out a value of a pool's or a layer's shape (no copy,
+    slice, gather, relayout: until PR 36 the read gathered lanes x table
+    width = the whole pool a layer), in one layout, and never in VMEM
+    (`S(1)`: left alone the compiler keeps the rope-key pool there for
+    an XLA writer's sake and moves it out and back around every kernel
+    call).  The twin of test_decode_multi_reads_the_pool_where_it_lies."""
+    import re
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.ops.paged_attention import resolve_decode_impl
+
+    mod, cfg, B, MB, NB, K, n_mla = _latent_case(family)
+    R, dr = cfg.mla_plane_heights
+    impl = resolve_decode_impl(cfg.attn_impl, topo.devices[0].platform, BS,
+                               cfg.mla_plane_heights, cfg.dtype)
+    assert impl == "pallas"
+    cfg = dataclasses.replace(cfg, attn_impl=impl)
+    S = _sds(one_chip)
+    shapes = jax.eval_shape(
+        lambda: mod.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    lanes = {"lanes": B} if getattr(mod, "KV_LANE_ADDRESSED", False) else {}
+    kv_shapes = mod.kv_cache_shapes(cfg, NB, BS, **lanes)
+    dtypes = (mod.kv_cache_dtypes(cfg) if lanes
+              else (cfg.dtype,) * len(kv_shapes))
+    kv = tuple(S(s, d) for s, d in zip(kv_shapes, dtypes))
+    assert kv[0].shape == (n_mla, 1, NB, R, BS)
+    assert kv[1].shape == (n_mla, 1, NB, dr, BS)
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+    lowered = jax.jit(
+        partial(JaxEngine._decode_multi_impl, mod, cfg, None, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9)).lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32))
+    assert lowered.as_text().count("stablehlo.custom_call @tpu_custom_call") \
+        == 2
+    program = lowered.compile()
+    hlo = program.as_text()
+    assert hlo.count("tpu_custom_call") == 2 * n_mla
+    for hd in (R, dr):
+        pool = rf"bf16\[{n_mla},1,{NB},{hd},{BS}\]"
+        layer = rf"bf16\[(?:1,)?1,{NB},{hd},{BS}\]"
+        made = re.findall(rf"= ({pool}|{layer})\S* (\w[\w-]*)\(", hlo)
+        assert {op for _, op in made} <= {
+            "parameter", "get-tuple-element", "while", "bitcast"}, \
+            sorted(set(made))
+        assert set(re.findall(rf"{pool}(\{{[\d,]+)", hlo)) == {"{4,3,2,1,0"}
+        assert not re.search(rf"{pool}\{{[^}}]*S\(1\)", hlo)
+        # nor the gather's shapes: every lane's table, or the pool less
+        # the garbage block, block-major
+        for blocks in (B * MB, NB - 1, NB):
+            assert not re.findall(rf"bf16\[{blocks},{hd},{BS}\]", hlo)
+    assert f"f32[{B},{cfg.n_heads},{MB * BS}]" not in hlo    # table-wide scores
+    # the gathered context and its float32 casts were the burst's
+    # temporaries: 0.79 GB (Moonlight, 8 layers), 1.94 GB (Ling)
+    assert program.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_latent_decode_runs_per_head_shard_under_tp(topo):
+    """Moonlight's `decode_multi` for four described chips, tp = 4, the
+    parameters placed by the engine's rules: heads shard through
+    w_uk / w_uv and the latent pools are replicated, so the read runs per
+    head shard under `shard_map` (4 of 16 heads a chip) on the whole
+    pool and every shard writes its own replica: two custom calls a
+    layer, the pools handed on where they lie, and no collective gives
+    out a pool, a layer of one, or the heads gathered for the kernel
+    (what GSPMD does to a custom call it cannot partition)."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.parallel.mesh import param_sharding_rules
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4, 1), ("dp", "tp", "sp"))
+    mod, cfg, B, MB, NB, K, n_mla = _latent_case("moonlight")
+    cfg = dataclasses.replace(cfg, attn_impl="pallas", expert_shards=4)
+    R, dr = cfg.mla_plane_heights
+    rules = param_sharding_rules()
+
+    def placed(path, x):
+        name = next((k.key for k in reversed(path)
+                     if isinstance(getattr(k, "key", None), str)), None)
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype,
+            sharding=NamedSharding(mesh, rules.get(name, P())))
+
+    params = jax.tree_util.tree_map_with_path(placed, jax.eval_shape(
+        lambda: mod.init_params(cfg, jax.random.PRNGKey(0))))
+    S = lambda shape, dt: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, P()))
+    kv = tuple(S(s, cfg.dtype) for s in mod.kv_cache_shapes(cfg, NB, BS))
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+    hlo = jax.jit(
+        partial(JaxEngine._decode_multi_impl, mod, cfg, mesh, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9)).lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32)).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 2 * n_mla
+    assert f"bf16[{B},{cfg.n_heads // 4},{R}]" in hlo     # a shard's queries
+    made = re.findall(rf"= (\w+\[(?:{n_mla},)?1,{NB},(?:{R}|{dr}),{BS}\]|"
+                      rf"\w+\[{B},{cfg.n_heads},(?:{R}|{dr})\])\S* "
+                      rf"(\w[\w-]*)\(", hlo)
+    assert {op for _, op in made} <= {
+        "parameter", "get-tuple-element", "while", "bitcast"}, \
+        sorted(set(made))
