@@ -109,12 +109,22 @@ class _Slot:
     # the stream's first-token/finish frames (`forensic` metrics block)
     queue_pos: int = 0
     prefill_chunks: int = 0
-    # request stages (metrics req_stage_*): enqueued_t -> dispatched_t
-    # (the request's first prefill chunk is dispatched) -> first_token_t
-    # (the first token is in the host's hands) -> its frame is put on
-    # the stream by the event loop (_emit_first stamps and sums)
+    # request stages (metrics req_stage_*), each stamp set once (a
+    # preempted request's replay keeps its first): enqueued_t -> seen_t
+    # (the first _admit_waiting pass that finds it waiting) ->
+    # admitted_t (it gets its lane and blocks) -> dispatched_t (its
+    # first prefill chunk is dispatched) -> first_token_t (the first
+    # token is in the host's hands; its frame is put on the stream by
+    # the event loop, _emit_first stamps and sums) -> second_token_t
+    # (the first token of a decode burst) -> the finish frame
+    seen_t: float = 0.0
+    admitted_t: float = 0.0
     dispatched_t: float = 0.0
     first_token_t: float = 0.0
+    second_token_t: float = 0.0
+    # decode steps dispatched and not yet ready on the device when the
+    # first chunk was dispatched (_stamp_dispatch, metric req_ahead_steps)
+    ahead_steps: int = 0
     first_sent: bool = False  # the first frame is on its way to the loop
     last_push_t: float = 0.0  # previous streamed-token time (ITL EMA)
 
@@ -678,9 +688,19 @@ class JaxEngine:
             "requests": 0, "prompt_tokens": 0,
             # a request's time to its first token by stage, summed over
             # the requests whose first token was emitted (seconds; see
-            # _Slot.dispatched_t and _emit_first)
+            # _Slot.dispatched_t and _emit_first).  wake + lane + turn
+            # is queue, a request at a time; req_ahead_steps is the
+            # decode steps that stood ahead of its first prefill chunk
+            # on the device (_stamp_dispatch)
             "req_stage_s.queue": 0.0, "req_stage_s.prefill": 0.0,
             "req_stage_s.emit": 0.0, "req_stage_n": 0,
+            "req_stage_s.wake": 0.0, "req_stage_s.lane": 0.0,
+            "req_stage_s.turn": 0.0, "req_ahead_steps": 0,
+            # after the first token (_push_token): first -> second token
+            # over the requests that got a second; second -> finish over
+            # the tokens after the second of the requests that finished
+            "req_stage_s.join": 0.0, "req_join_n": 0,
+            "req_stage_s.decode": 0.0, "req_decode_tokens": 0,
             # decode attention, in cache blocks per layer summed over
             # decode steps: what the active lanes' contexts hold, and
             # what the impl that runs reads for them (_count_decode_attn)
@@ -2300,11 +2320,24 @@ class JaxEngine:
 
     def _admit_waiting(self) -> None:
         """Move waiting requests into free slots (block allocation + prefix
-        cache lookup; no model compute)."""
+        cache lookup; no model compute).  Request stage stamps: `seen_t`
+        on every request this pass is the first to find waiting,
+        `admitted_t` where one gets its lane and blocks; the pass reads
+        the clock once, and again only where a request arrived after
+        that read."""
+        now = 0.0
         while True:
             with self._qlock:
                 if not self.waiting:
                     return
+                if self.waiting[-1].seen_t == 0.0:
+                    # arrivals append and a preempted request goes to
+                    # the front seen: the unseen ones are the tail
+                    now = time.monotonic()
+                    for s in reversed(self.waiting):
+                        if s.seen_t != 0.0:
+                            break
+                        s.seen_t = now
                 free_idx = next(
                     (i for i, s in enumerate(self._slots) if s is None), None
                 )
@@ -2327,6 +2360,9 @@ class JaxEngine:
             self._emit_events(res)
             slot.index = free_idx
             self._slots[free_idx] = slot
+            if slot.admitted_t == 0.0:
+                now = now or time.monotonic()
+                slot.admitted_t = now
             bids = res.block_ids
             slot.block_table[: len(bids)] = bids
             slot.committed_blocks = res.cached_blocks
@@ -2717,15 +2753,31 @@ class JaxEngine:
             first = -1
         self._finish_prefill_chunk(slot, T, first)
 
-    @staticmethod
-    def _stamp_dispatch(slots) -> None:
+    def _stamp_dispatch(self, slots) -> None:
         """Request stage stamp: the first prefill chunk of these slots'
         requests is about to be dispatched (one clock read a program;
-        a preempted request's replay keeps its first stamp)."""
+        a preempted request's replay keeps its first stamp).  Such a
+        request also takes with it how much decode work stood ahead of
+        its chunk on the device: the steps (sum of `k`) and the count of
+        the dispatched bursts whose tokens are not ready yet
+        (`is_ready()`, which does not block).  The burst the device is
+        running at that moment counts WHOLE, however far it has come, so
+        the reading is high by half a burst on average; lockstep mode
+        (`overlap_scheduling` off) has no burst in flight here and reads
+        0.  The open `prefill_dispatch` phase gets both as attributes."""
         now = time.monotonic()
-        for s in slots:
-            if s.dispatched_t == 0.0:
-                s.dispatched_t = now
+        first = [s for s in slots if s.dispatched_t == 0.0]
+        if not first:
+            return
+        unready = [e["k"] for e in self._inflight
+                   if not e["burst"].is_ready()]
+        steps = sum(unready)
+        for s in first:
+            s.dispatched_t = now
+            s.ahead_steps = steps
+        open_ = self._phase.open
+        if open_ and open_[-1].kind == "prefill_dispatch":
+            open_[-1].set(ahead_steps=steps, ahead_bursts=len(unready))
 
     def _completing_rows(self, slots, chunks) -> Dict[int, "_Slot"]:
         """{program row -> slot} of slots whose prompt completes this
@@ -3040,8 +3092,10 @@ class JaxEngine:
         slot.pulling = False
         self._commit_full_blocks(slot)
         # a pulled prompt has no prefill dispatch of its own: its queue
-        # stage ends where the pulled KV is whole
-        self._stamp_dispatch((slot,))
+        # stage ends where the pulled KV is whole, with no program of its
+        # own behind the bursts on the device (ahead_steps stays 0)
+        if slot.dispatched_t == 0.0:
+            slot.dispatched_t = time.monotonic()
         self._stamp_first_token(slot)
         if slot.guide is not None:
             # constrained output served via disagg: the prefill worker
@@ -4111,6 +4165,13 @@ class JaxEngine:
         slot.seq.append(tok)
         slot.last_token = tok
         slot.generated += 1
+        if slot.generated == 2:
+            # request stage: the lane's first token out of a decode
+            # burst (`generated` never goes back: once a request)
+            slot.second_token_t = now
+            self.metrics["req_stage_s.join"] += now - slot.first_token_t
+            self.metrics["req_join_n"] += 1
+            self._stage_spans(slot, ("req_join", slot.first_token_t, now))
         self._commit_full_blocks(slot)
         finish = self._finish_reason(slot, tok)
         # forensic stamp on the FIRST token frame and the finish frame
@@ -4119,6 +4180,14 @@ class JaxEngine:
         # predicted-vs-realized feedback wants it — and the finish
         # frame's step counts supersede it as the record's truth
         if finish:
+            if slot.generated > 2:
+                # request stage: second token -> finish, over the tokens
+                # that came after the second
+                self.metrics["req_stage_s.decode"] += \
+                    now - slot.second_token_t
+                self.metrics["req_decode_tokens"] += slot.generated - 2
+                self._stage_spans(
+                    slot, ("req_decode", slot.second_token_t, now))
             metrics = {"kv_usage": self.kv_usage(),
                        "cached_tokens": slot.cached_tokens,
                        "ttft_s": slot.first_token_t - slot.enqueued_t,
@@ -4155,29 +4224,47 @@ class JaxEngine:
 
     def _emit_first(self, slot: _Slot, out: LLMEngineOutput) -> None:
         """On the event loop: put the request's first frame on its stream
-        and add its three stages to `req_stage_s.*` (seconds; their sum
-        is the engine's time to first token, enqueue to stream).  Under a
-        Tracer the stages are also ring spans on the request's own
-        track."""
+        and add its stages to `req_stage_s.*` (seconds): queue, prefill
+        and emit, whose sum is the engine's time to first token, enqueue
+        to stream; and the queue's three waits, wake (for the scheduler
+        thread to come round) + lane (for a lane and blocks) + turn (for
+        its turn at a prefill program) = queue.  A stage the request
+        skipped (a pulled prompt has no dispatch of its own) adds 0.
+        Under a Tracer the stages are also ring spans on the request's
+        own track."""
         slot.out_q.put_nowait(out)
         now = time.monotonic()
         t_first = slot.first_token_t or now
         t_disp = slot.dispatched_t or t_first
+        t_seen = slot.seen_t or slot.enqueued_t
+        t_adm = slot.admitted_t or t_seen
         m = self.metrics
         m["req_stage_s.queue"] += t_disp - slot.enqueued_t
+        m["req_stage_s.wake"] += t_seen - slot.enqueued_t
+        m["req_stage_s.lane"] += t_adm - t_seen
+        m["req_stage_s.turn"] += t_disp - t_adm
+        m["req_ahead_steps"] += slot.ahead_steps
         m["req_stage_s.prefill"] += t_first - t_disp
         m["req_stage_s.emit"] += now - t_first
         m["req_stage_n"] += 1
+        self._stage_spans(
+            slot, ("req_queue", slot.enqueued_t, t_disp),
+            ("req_wake", slot.enqueued_t, t_seen),
+            ("req_lane", t_seen, t_adm), ("req_turn", t_adm, t_disp),
+            ("req_prefill", t_disp, t_first), ("req_emit", t_first, now))
+
+    @staticmethod
+    def _stage_spans(slot: _Slot, *spans) -> None:
+        """Under a Tracer: request stages (kind, t0, t1) as ring spans on
+        the request's own track (they cross threads, so they are not
+        TraceMes)."""
         tr = obs.tracer()
-        if tr is not None:
-            rid = slot.request.request_id
-            tid = obs.trace_id_from_annotations(slot.request.annotations)
-            for kind, t0, t1 in zip(
-                    obs.REQUEST_STAGES,
-                    (slot.enqueued_t, t_disp, t_first),
-                    (t_disp, t_first, now)):
-                tr.record(kind, t0, t1, {"request_id": rid}, tid,
-                          f"req:{rid}")
+        if tr is None:
+            return
+        rid = slot.request.request_id
+        tid = obs.trace_id_from_annotations(slot.request.annotations)
+        for kind, t0, t1 in spans:
+            tr.record(kind, t0, t1, {"request_id": rid}, tid, f"req:{rid}")
 
     def _preempt(self, slot: _Slot) -> None:
         """KV OOM: drop the slot's blocks and re-enqueue with full replay."""

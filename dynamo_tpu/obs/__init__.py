@@ -1,14 +1,12 @@
 """Timeline tracing plane: span-attributed engine steps + flight recorder.
 
-The served path ran at 0.40 of its own raw decode loop (measured on an
-earlier set-up; not measured on today's code), and nothing in the process could say *where* the other 60% goes — the
-FPM deque records per-dispatch aggregates, but no record decomposes a
-scheduler step into host-schedule / device-wait / sample / detokenize /
+The FPM deque records per-dispatch aggregates, but no record decomposes
+a scheduler step into host-schedule / device-wait / sample / detokenize /
 frame-egress time, and nothing stitches a request's journey across
 frontend → router → prefill worker → disagg pull → decode worker.
-This module is that decomposition: named spans on every engine phase,
-exported three ways, reduced to ROADMAP item-3's scoreboard by
-:mod:`dynamo_tpu.obs.report`.
+This module is that decomposition: named spans on every engine phase
+and stamps along a request's life, exported three ways, reduced to
+ROADMAP item-3's scoreboard by :mod:`dynamo_tpu.obs.report`.
 
 Design:
 
@@ -28,15 +26,32 @@ Design:
     two clock reads, two dict adds and one small handle: no lock, no
     span record, nothing per token.
 
-  * **Request stages, always on.**  Three stamps a request —
-    first prefill chunk dispatched, first token in the host's hands,
-    first frame put on the stream by the event loop — summed into
-    ``req_stage_s.queue`` / ``.prefill`` / ``.emit`` (seconds) and
-    ``req_stage_n`` (requests whose first token was emitted); their sum
-    is the engine's time to first token exactly.  Under a `Tracer` the
-    stages are also ring spans (``req_queue``, ``req_prefill``,
-    ``req_emit``) on the track ``req:<request_id>``; they cross threads,
-    so they are not TraceMes.
+  * **Request stages, always on.**  Eight stamps a request, each set
+    once where the work happens (engine/core.py): enqueued; seen by the
+    scheduler thread (the first ``_admit_waiting`` pass that finds it
+    waiting); admitted (it has its lane and blocks); first prefill
+    chunk dispatched; first token in the host's hands; first frame put
+    on the stream by the event loop; second token (the lane's first out
+    of a decode burst); finish frame.  Summed into
+    ``req_stage_s.queue`` / ``.prefill`` / ``.emit`` (seconds) over
+    ``req_stage_n`` (requests whose first token was emitted), whose sum
+    is the engine's time to first token exactly; ``req_stage_s.wake`` +
+    ``.lane`` + ``.turn``, the three waits that ARE the queue, request
+    by request; ``req_ahead_steps``, the decode steps dispatched and
+    not yet ready on the device when the first chunk went out;
+    ``req_stage_s.join`` (first to second token) over ``req_join_n``;
+    ``req_stage_s.decode`` (second token to finish) over
+    ``req_decode_tokens`` (the tokens after the second of the requests
+    that ran to their end).  What it costs with no listener: an
+    admission pass reads the clock once more when it finds a new
+    request or admits one (two reads a request at most), a first-chunk
+    dispatch asks up to ``decode_pipeline_depth`` in-flight bursts
+    ``is_ready()`` (0.3 us a call on a v5e: PERF.md section 6, PR 38),
+    a token costs one integer compare and no clock read of its own.  Under a `Tracer` the stages are also ring spans
+    (``REQUEST_STAGES``) on the track ``req:<request_id>``; they cross
+    threads, so they are not TraceMes.  A profiler capture gets
+    ``ahead_steps`` / ``ahead_bursts`` on the ``dyn.prefill_dispatch``
+    event of a request's first chunk.
 
   * **Module-global None check when the ring is off.**  The helpers for
     everything that is not an engine phase (`begin()`, `end()`,
@@ -80,7 +95,10 @@ Span vocabulary (kind — where — what the time is):
                    from the report's sched_overhead_frac — the device
                    never waited on it
   prefill_dispatch building + dispatching one prefill program (packed /
-                   batched / B=1 / ring), including its FPM accounting
+                   batched / B=1 / ring), including its FPM accounting;
+                   a request's first chunk adds ``ahead_steps`` /
+                   ``ahead_bursts`` (decode work dispatched before it
+                   and not ready yet)
   decode_dispatch  building + dispatching one decode burst; attrs carry
                    ``cont`` (device-resident continuation vs full
                    upload), ``k``, ``lanes``
@@ -100,6 +118,14 @@ Span vocabulary (kind — where — what the time is):
                    track ``req:<request_id>``, attr ``request_id``):
                    enqueued -> first prefill chunk dispatched -> first
                    token in hand -> first frame on the stream
+  req_wake / req_lane / req_turn
+                   the three waits inside ``req_queue``, end to end:
+                   enqueued -> seen by the scheduler thread -> lane and
+                   blocks in hand -> first chunk dispatched (same track)
+  req_join / req_decode
+                   first token -> second token (the lane's first out of
+                   a decode burst) -> finish frame; recorded where they
+                   close, a one-token request has neither (same track)
   detok            incremental detokenization of one engine output
   frame_egress     writing one SSE frame to the client socket
   request          frontend: one HTTP request end to end (trace_id)
@@ -151,9 +177,12 @@ STEP_PHASES = ("sched", "enqueue_ahead", "prefill_dispatch",
                "decode_dispatch", "spec_dispatch", "device_wait", "sample",
                "emit", "audit")
 
-# the three stages of one request's time to first token (engine/core.py
-# _emit_first); ring spans only, one track per request id
-REQUEST_STAGES = ("req_queue", "req_prefill", "req_emit")
+# the stages of one request's life (engine/core.py _emit_first,
+# _push_token): queue -> prefill -> emit are its time to first token,
+# wake -> lane -> turn the three waits inside the queue, join and decode
+# what follows the first token; ring spans only, one track per request id
+REQUEST_STAGES = ("req_queue", "req_wake", "req_lane", "req_turn",
+                  "req_prefill", "req_emit", "req_join", "req_decode")
 
 # THE canonical span vocabulary (the docstring table above, plus the
 # compile watchdog's span): every obs.span()/obs.end() call site names

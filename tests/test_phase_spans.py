@@ -218,6 +218,13 @@ def test_engine_phases_on_the_profilers_clock(tmp_path):
                 assert stats["what"] in ("burst_fetch", "prefill_first")
     assert {"dyn.step", "dyn.prefill_dispatch", "dyn.decode_dispatch",
             "dyn.device_wait", "dyn.emit"} <= kinds
+    # a request's first chunk says what stood ahead of it on the device
+    # (r2's later chunks do not): five requests, at most five programs
+    firsts = [stats for evs in lines for name, _, _, stats in evs
+              if name == "dyn.prefill_dispatch" and "ahead_steps" in stats]
+    assert 1 <= len(firsts) <= 5
+    assert all(int(st["ahead_steps"]) >= int(st["ahead_bursts"]) >= 0
+               for st in firsts)
     assert kinds & {"dyn.sched", "dyn.enqueue_ahead"}
 
 
@@ -285,7 +292,8 @@ def watch_stages(eng):
     seen = []
     inner = eng._emit_first
     keys = ("req_stage_s.queue", "req_stage_s.prefill", "req_stage_s.emit",
-            "req_stage_n")
+            "req_stage_n", "req_stage_s.wake", "req_stage_s.lane",
+            "req_stage_s.turn", "req_ahead_steps")
 
     def emit_first(slot, out):
         before = [eng.metrics[k] for k in keys]
@@ -302,15 +310,61 @@ def check_stages(eng, seen, n_first_tokens):
     assert m["req_stage_n"] == len(seen) == n_first_tokens
     rids = [slot.request.request_id for slot, _ in seen]
     assert len(set(rids)) == len(rids)          # once a request
-    for slot, (queue, prefill, emit, n) in seen:
+    for slot, (queue, prefill, emit, n, wake, lane, turn, ahead) in seen:
         assert n == 1
         assert min(queue, prefill, emit) >= 0.0
         assert slot.enqueued_t <= slot.dispatched_t <= slot.first_token_t
         assert queue + prefill == pytest.approx(
             slot.first_token_t - slot.enqueued_t, abs=1e-9)
+        # the queue is three waits, each stamped once
+        assert min(wake, lane, turn) >= 0.0
+        assert slot.enqueued_t <= slot.seen_t <= slot.admitted_t \
+            <= slot.dispatched_t
+        assert wake + lane + turn == pytest.approx(queue, abs=1e-9)
+        assert ahead == slot.ahead_steps >= 0
     for i, key in enumerate(("queue", "prefill", "emit")):
         assert m[f"req_stage_s.{key}"] == pytest.approx(
             sum(adds[i] for _, adds in seen), abs=1e-9)
+    for i, key in enumerate(("wake", "lane", "turn"), start=4):
+        assert m[f"req_stage_s.{key}"] == pytest.approx(
+            sum(adds[i] for _, adds in seen), abs=1e-9)
+    assert m["req_stage_s.wake"] + m["req_stage_s.lane"] \
+        + m["req_stage_s.turn"] == pytest.approx(m["req_stage_s.queue"],
+                                                 abs=1e-9)
+    assert m["req_ahead_steps"] == sum(adds[7] for _, adds in seen)
+
+
+def check_life(eng, seen, outs):
+    """After the first token: `join` over the requests that got a second
+    token, `decode` over the tokens after the second of those that ran
+    to their end (`outs`: their streams, all complete), and the stages
+    add up to the request's life in the engine."""
+    m = eng.metrics
+    slots = [slot for slot, _ in seen]
+    assert len(slots) == len(outs)
+    assert m["req_join_n"] == sum(1 for o in outs if len(o) >= 2)
+    assert m["req_decode_tokens"] == sum(max(len(o) - 2, 0) for o in outs)
+    join = decode = 0.0
+    for slot in slots:
+        assert slot.finished
+        done_t = slot.last_push_t            # the finish frame's clock read
+        if slot.generated >= 2:
+            assert slot.first_token_t <= slot.second_token_t <= done_t
+            join += slot.second_token_t - slot.first_token_t
+        else:
+            assert slot.second_token_t == 0.0
+        if slot.generated >= 3:
+            decode += done_t - slot.second_token_t
+        if slot.generated >= 2:
+            # queue + prefill + join + decode: enqueue to finish
+            assert sum(t1 - t0 for t0, t1 in (
+                (slot.enqueued_t, slot.dispatched_t),
+                (slot.dispatched_t, slot.first_token_t),
+                (slot.first_token_t, slot.second_token_t),
+                (slot.second_token_t, done_t))) == pytest.approx(
+                    done_t - slot.enqueued_t, abs=1e-9)
+    assert m["req_stage_s.join"] == pytest.approx(join, abs=1e-9)
+    assert m["req_stage_s.decode"] == pytest.approx(decode, abs=1e-9)
 
 
 def test_request_stages_sum_to_the_engines_ttft():
@@ -376,13 +430,246 @@ def test_request_stage_spans_share_the_request_id(tmp_path):
     spans = asyncio.run(main())
     for rid in ("r1", "r2"):
         mine = [s for s in spans if s[3] == f"req:{rid}"]
-        assert [s[0] for s in mine] == list(obs.REQUEST_STAGES)
+        # the first frame's six, in order (one event-loop call); join
+        # and decode come from the scheduler's thread where they close
+        kinds = [s[0] for s in mine]
+        assert sorted(kinds) == sorted(obs.REQUEST_STAGES)
+        first_frame = [k for k in kinds if k not in ("req_join",
+                                                     "req_decode")]
+        assert first_frame == list(obs.REQUEST_STAGES[:6])
+        assert kinds.index("req_join") < kinds.index("req_decode")
         assert all(s[4] == {"request_id": rid} for s in mine)
+        at = {s[0]: (s[1], s[2]) for s in mine}
         # one after another: queue -> prefill -> emit
-        assert mine[0][2] == mine[1][1] and mine[1][2] == mine[2][1]
+        assert at["req_queue"][1] == at["req_prefill"][0]
+        assert at["req_prefill"][1] == at["req_emit"][0]
+        # the three waits tile the queue: wake -> lane -> turn
+        assert at["req_wake"][0] == at["req_queue"][0]
+        assert at["req_wake"][1] == at["req_lane"][0]
+        assert at["req_lane"][1] == at["req_turn"][0]
+        assert at["req_turn"][1] == at["req_queue"][1]
+        # and after the first token: join -> decode
+        assert at["req_join"][0] == at["req_prefill"][1]
+        assert at["req_join"][1] == at["req_decode"][0]
+        assert at["req_decode"][1] >= at["req_decode"][0]
     kinds = {s[0] for s in spans}
     assert {"step", "prefill_dispatch", "decode_dispatch", "device_wait",
             "emit"} <= kinds
+
+
+# ------------------------- the queue's three waits --------------------------
+
+
+@pytest.mark.parametrize("lanes", [4, 1], ids=["free_lane", "no_free_lane"])
+def test_queue_waits_sum_to_the_queue(lanes):
+    """wake + lane + turn is queue for every request (check_stages), with
+    a lane free at arrival and with the three sharing one."""
+
+    async def main():
+        eng = make_engine(max_num_seqs=lanes, prefill_chunk_tokens=48)
+        seen = watch_stages(eng)
+        outs = await asyncio.gather(
+            serve(eng, 1), serve(eng, 2, n_prompt=100, max_tokens=12),
+            serve(eng, 3, n_prompt=9))
+        await settled(eng)
+        check_stages(eng, seen, 3)
+        check_life(eng, seen, [
+            outs[int(slot.request.request_id[1:]) - 1] for slot, _ in seen])
+        await eng.close()
+
+    asyncio.run(main())
+
+
+def test_a_taken_lane_is_a_lane_wait():
+    """One lane, held by a decoding request: the next one is seen while it
+    waits and admitted only when the lane is free; the holder itself was
+    seen and admitted in one pass (one clock read: lane wait 0)."""
+
+    async def main():
+        eng = make_engine(max_num_seqs=1)
+        seen = watch_stages(eng)
+        first = asyncio.create_task(serve(eng, 0, max_tokens=48))
+        while not seen:                          # r0 holds the one lane
+            await asyncio.sleep(0.005)
+        await serve(eng, 1)
+        assert len(await first) == 48
+        check_stages(eng, seen, 2)
+        (r0, a0), (r1, a1) = seen
+        assert a0[5] == 0.0                      # r0's lane wait
+        assert r1.seen_t < r0.last_push_t <= r1.admitted_t
+        assert a1[5] == r1.admitted_t - r1.seen_t > 0.0
+        await eng.close()
+
+    asyncio.run(main())
+
+
+def test_a_prompt_behind_anothers_chunks_is_a_turn_wait():
+    """One prefill program a step: a request that has its lane waits for
+    its turn behind the chunks of the prompt before it."""
+
+    async def main():
+        eng = make_engine(max_prefill_seqs=1, prefill_chunk_tokens=48)
+        seen = watch_stages(eng)
+        await asyncio.gather(serve(eng, 0, n_prompt=100), serve(eng, 1))
+        check_stages(eng, seen, 2)
+        by_id = {slot.request.request_id: (slot, adds)
+                 for slot, adds in seen}
+        (r0, a0), (r1, a1) = by_id["r0"], by_id["r1"]
+        assert r0.prefill_chunks > 1
+        assert a0[5] == a1[5] == 0.0             # lanes were free
+        # r1 had its lane before r0's prompt was done, and its first
+        # chunk went out after r0's first token was in hand
+        assert r1.admitted_t < r0.first_token_t <= r1.dispatched_t
+        assert a1[6] == r1.dispatched_t - r1.admitted_t > 0.0
+        await eng.close()
+
+    asyncio.run(main())
+
+
+class _Burst:
+    def __init__(self, ready):
+        self.ready = ready
+
+    def is_ready(self):
+        return self.ready
+
+
+def test_ahead_steps_count_the_bursts_that_are_not_ready():
+    """`_stamp_dispatch` on a first chunk: the `k` of every dispatched
+    burst whose tokens are not ready, once a request; the open
+    prefill_dispatch phase carries steps and bursts."""
+    eng = make_engine()
+    eng._inflight.extend([
+        {"burst": _Burst(True), "k": 8, "lanes": {}},
+        {"burst": _Burst(False), "k": 4, "lanes": {}},
+        {"burst": _Burst(False), "k": 8, "lanes": {}}])
+    a, b, c = (types.SimpleNamespace(dispatched_t=0.0, ahead_steps=0)
+               for _ in range(3))
+    eng._stamp_dispatch((a,))
+    assert a.ahead_steps == 12 and a.dispatched_t > 0.0
+    t_a = a.dispatched_t
+    eng._inflight.append({"burst": _Burst(False), "k": 8, "lanes": {}})
+    with eng._phase("prefill_dispatch", rows=2) as ph:
+        eng._stamp_dispatch((a, b))              # a's second chunk, b's first
+    assert (a.ahead_steps, a.dispatched_t) == (12, t_a)
+    assert b.ahead_steps == 20
+    assert ph.attrs == {"rows": 2, "ahead_steps": 20, "ahead_bursts": 3}
+    with eng._phase("prefill_dispatch") as ph:
+        eng._stamp_dispatch((a, b))              # later chunks only
+    assert ph.attrs is None
+    for e in eng._inflight:
+        e["burst"].ready = True
+    eng._stamp_dispatch((c,))
+    assert c.ahead_steps == 0 and c.dispatched_t > 0.0
+    eng._inflight.clear()
+
+
+@pytest.mark.parametrize("overlap", [True, False],
+                         ids=["overlapped", "lockstep"])
+def test_ahead_steps_ride_the_first_frame(overlap):
+    """`req_ahead_steps` is what the slots carried (check_stages), whole
+    steps of real bursts; lockstep mode has no burst in flight at a
+    prefill dispatch and reads 0."""
+
+    async def main():
+        eng = make_engine(overlap_scheduling=overlap)
+        seen = watch_stages(eng)
+        await serve_mix(eng)
+        check_stages(eng, seen, 5)
+        m = dict(eng.metrics)
+        await eng.close()
+        return m
+
+    m = asyncio.run(main())
+    assert isinstance(m["req_ahead_steps"], int)
+    if not overlap:
+        assert m["req_ahead_steps"] == 0
+
+
+# ------------------------- after the first token ----------------------------
+
+
+@pytest.mark.parametrize("max_tokens", [1, 2, 3])
+def test_join_needs_a_second_token_and_decode_a_third(max_tokens):
+    async def main():
+        eng = make_engine()
+        seen = watch_stages(eng)
+        out = await serve(eng, 0, max_tokens=max_tokens)
+        await settled(eng)
+        check_stages(eng, seen, 1)
+        check_life(eng, seen, [out])
+        m = dict(eng.metrics)
+        await eng.close()
+        return out, m
+
+    out, m = asyncio.run(main())
+    assert len(out) == max_tokens
+    assert m["req_join_n"] == (1 if max_tokens >= 2 else 0)
+    assert (m["req_stage_s.join"] > 0.0) == (max_tokens >= 2)
+    assert m["req_decode_tokens"] == max(max_tokens - 2, 0)
+    assert (m["req_stage_s.decode"] > 0.0) == (max_tokens >= 3)
+
+
+def test_decode_tokens_are_the_tokens_after_the_second():
+    """Over a mix: `req_decode_tokens` = sum of (generated - 2), and each
+    request's stages add up to its life, enqueue to finish frame."""
+
+    async def main():
+        eng = make_engine(prefill_chunk_tokens=48)
+        seen = watch_stages(eng)
+        outs = await serve_mix(eng)
+        await settled(eng)
+        check_stages(eng, seen, 5)
+        by_id = {f"r{i + 1}": o for i, o in enumerate(outs)}
+        check_life(eng, seen, [by_id[slot.request.request_id]
+                               for slot, _ in seen])
+        assert eng.metrics["req_decode_tokens"] == 6 + 10 + 6 + 18 + 6
+        await eng.close()
+
+    asyncio.run(main())
+
+
+def test_life_stages_count_a_preempted_request_once():
+    async def main():
+        # 3 x (8 prompt blocks + 6 of output) do not fit 30 blocks
+        eng = make_engine(num_blocks=30)
+        seen = watch_stages(eng)
+        outs = await asyncio.gather(
+            *[serve(eng, i, max_tokens=24) for i in range(3)])
+        await settled(eng)
+        assert eng.metrics["preemptions"] >= 1
+        check_stages(eng, seen, 3)
+        check_life(eng, seen, [outs[int(slot.request.request_id[1:])]
+                               for slot, _ in seen])
+        assert eng.metrics["req_join_n"] == 3
+        assert eng.metrics["req_decode_tokens"] == 3 * 22
+        await eng.close()
+
+    asyncio.run(main())
+
+
+def test_life_stages_leave_out_a_request_cancelled_in_the_queue():
+    async def main():
+        eng = make_engine(max_num_seqs=1)
+        seen = watch_stages(eng)
+        first = asyncio.create_task(serve(eng, 0, max_tokens=48))
+        while not seen:                          # r0 holds the one slot
+            await asyncio.sleep(0.005)
+        waiting = asyncio.create_task(serve(eng, 1))
+        while not eng.waiting:
+            await asyncio.sleep(0.005)
+        waiting.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await waiting
+        outs = [await first, await serve(eng, 2)]
+        await settled(eng)
+        check_stages(eng, seen, 2)               # r0 and r2, not r1
+        check_life(eng, seen, outs)
+        assert eng.metrics["req_join_n"] == 2
+        assert eng.metrics["req_decode_tokens"] == 46 + 6
+        await eng.close()
+
+    asyncio.run(main())
 
 
 # ------------------------- names --------------------------------------------
@@ -465,5 +752,5 @@ def test_jax_engine_bit_identical_with_spans_on(sink, plain_outputs,
     outs, tr = asyncio.run(main())
     assert outs == plain_outputs
     if tr is not None:
-        assert {"step", "decode_dispatch", "req_queue"} <= {
+        assert {"step", "decode_dispatch", *obs.REQUEST_STAGES} <= {
             s[0] for s in tr.spans}
