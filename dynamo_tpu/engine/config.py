@@ -56,8 +56,11 @@ class EngineConfig:
     # (lax.scan) when no prefill/admission work is pending.  Fusing
     # amortizes the fixed per-dispatch host cost k-fold at the cost of
     # k-token output bursts and up to k-1 wasted steps when a sequence
-    # finishes mid-burst.  1 disables.  The value was chosen on an
-    # earlier set-up and is to be measured again on today's.
+    # finishes mid-burst.  1 disables.  With decode_pipeline_depth 2 the
+    # one queued burst of 8 is what a new request's first chunk stands
+    # behind in a decode-only stretch (6.0-6.1 steps on average on the
+    # chip, PERF.md section 6, PR 39); a shorter rung while few lanes
+    # decode is ROADMAP S2's next step, not measured yet.
     decode_fused_steps: int = 8
     # decode output pipelining: keep up to depth-1 dispatched bursts
     # UNREAD while the next one runs, chaining sampled ids on device — the
@@ -66,13 +69,22 @@ class EngineConfig:
     # stop detection lag by up to (depth-1)*decode_fused_steps tokens
     # (overshoot is discarded, same as a mid-burst finish).  1 = fetch
     # synchronously every burst.  Depth d gives the async device->host
-    # copy d-1 burst intervals to land before the host reads it.  Depth 4
-    # was chosen on an earlier set-up and is to be measured again on
-    # today's.  Latency-sensitive deployments can trade
-    # throughput for (d-1)*decode_fused_steps fewer tokens of stream lag.
+    # copy d-1 burst intervals to land before the host reads it.  The
+    # order of a step is READ BACK FIRST, ADMIT AFTER (core._sched_step):
+    # the oldest burst at or over the depth is read before admission, so
+    # depth - 1 bursts stand ahead of a new request's first chunk.
+    # Measured on a TPU v5e with that order in (PERF.md section 6, PR 39,
+    # depth 1 / 2 / 3 / 4): mistral-7b.chat ttft_p50_ms 62 / 134 / 204 /
+    # 286; ling-3.0-flash.longgen-closed, the host's longest step (31-41
+    # ms), output_tok_per_s 2348 / 2927 / 2919 / 2907 with the chip
+    # 0.03 % idle at 2 and at 3.  2 is the smallest depth at which no
+    # closed loop idles or loses throughput: ONE burst behind the one
+    # that runs hides the host's step, three do not hide it better and
+    # each costs a new request decode_fused_steps steps of waiting.
+    # Depth 1 pays the host's step between bursts (-20 % on Ling).
     # Only effective with overlap_scheduling on; sync mode is lockstep
     # (depth 1 and drain-after-dispatch) regardless of this value.
-    decode_pipeline_depth: int = 4
+    decode_pipeline_depth: int = 2
     # overlapped scheduler (the ROADMAP item-3 refactor): while step N's
     # programs execute on device, the host schedules and enqueues step
     # N+1 — decode bursts pipeline to decode_pipeline_depth, a completing

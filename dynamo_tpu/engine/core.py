@@ -696,6 +696,11 @@ class JaxEngine:
             "req_stage_s.emit": 0.0, "req_stage_n": 0,
             "req_stage_s.wake": 0.0, "req_stage_s.lane": 0.0,
             "req_stage_s.turn": 0.0, "req_ahead_steps": 0,
+            # requests that arrived while a step's read-back was waiting
+            # and were seen by the admission pass of that same step
+            # (_read_back_first): over req_stage_n, the share that
+            # "read back first, admit after" served a step sooner
+            "req_admitted_after_wait": 0,
             # after the first token (_push_token): first -> second token
             # over the requests that got a second; second -> finish over
             # the tokens after the second of the requests that finished
@@ -759,6 +764,10 @@ class JaxEngine:
         # while this step's programs execute behind it
         self._overlap = bool(config.overlap_scheduling)
         self._pending_first: List[dict] = []
+        # dispatched bursts a step leaves behind it; lockstep is 1 and
+        # drains after its dispatch whatever the field says
+        self._depth = max(1, config.decode_pipeline_depth) \
+            if self._overlap else 1
         # adaptive decode fusion: consecutive decode-only steps (the
         # fusion ladder's ramp clock); reset on arrivals/cancellations
         self._decode_only_run = 0
@@ -2073,6 +2082,19 @@ class JaxEngine:
     def _sched_step(self) -> None:
         """One scheduler iteration, entirely on the worker thread.
 
+        The order (overlapped mode): READ BACK FIRST, ADMIT AFTER.  The
+        step begins with what it must wait for (_read_back_first: the
+        oldest burst at or over the pipeline depth, the previous step's
+        deferred first tokens) and only then looks at what is new:
+        cancellations, admission, one prefill program, the decode burst.
+        A request that arrived while the thread was blocked is so
+        admitted, and its first chunk dispatched, in the step in which
+        the wait ends, AHEAD of that step's decode burst, which
+        _fused_k then keeps at INTERLEAVE_BURST.  With admission before
+        the wait (the order until PR 39) it was seen a step later and
+        its chunk stood behind one more burst.  Lockstep mode has
+        nothing in flight at the top of a step and is untouched.
+
         vLLM-style interleaving: admit any number of waiting requests
         (allocation only), run at most ONE budget-capped prefill chunk, then
         a decode step for every slot past prefill — so a long prompt never
@@ -2103,18 +2125,13 @@ class JaxEngine:
             # `enqueue_ahead` (report.py excludes it from
             # sched_overhead_frac; the wall partition stays exact).
             with self._phase("step") as step:
+                waited_from = self._read_back_first()
                 overlapped = self._overlap and bool(self._inflight)
                 with self._phase("enqueue_ahead" if overlapped
                                  else "sched"):
                     self._process_cancellations()
                     self._maybe_offload()
-                    self._admit_waiting()
-                # deferred first tokens from the PREVIOUS step's
-                # completing prefills: flushed before this step's
-                # dispatches, so the blocking fetch pays only for work
-                # the device has had a full step to finish (overlap
-                # mode; sync fetches inline)
-                self._flush_pending_first()
+                    self._admit_waiting(waited_from)
                 self._prefill_step()
                 self._guided_step()
                 self._spec_step()
@@ -2139,6 +2156,32 @@ class JaxEngine:
                     step.set(active=sum(1 for s in self._slots
                                         if s is not None),
                              waiting=len(self.waiting))
+
+    def _read_back_first(self) -> float:
+        """The top of an overlapped step: make the blocking reads the
+        step would make anyway, before anything new is looked at, in
+        the order the device finishes the work.  The bursts at or over
+        the pipeline depth, where a lane is past its prompt (a step
+        whose lanes are all prefilling drains after its dispatch, as
+        ever); then the previous step's deferred first tokens; at depth
+        1 the one burst in flight went out AFTER that prefill and is
+        read after it.  So one burst is left running (depth 2) while
+        the host emits, admits and dispatches, and whatever arrived
+        during the wait is met by this step's admission pass.  Returns
+        the clock at which the wait began, 0.0 where there was nothing
+        to wait for (always, in lockstep mode)."""
+        depth = self._depth
+        bursts = len(self._inflight) >= depth and any(
+            s is not None and not s.prefilling for s in self._slots)
+        if not (bursts or self._pending_first):
+            return 0.0
+        waited_from = time.monotonic()
+        while bursts and len(self._inflight) >= max(depth, 2):
+            self._process_oldest_burst()
+        self._flush_pending_first()
+        while bursts and len(self._inflight) >= depth:
+            self._process_oldest_burst()
+        return waited_from
 
     # -- distributed KVBM (kvbm/remote.py) ---------------------------------
     async def _remote_prefetch(self, request: PreprocessedRequest) -> None:
@@ -2318,13 +2361,16 @@ class JaxEngine:
                 return b
         return self.config.prefill_buckets[-1]
 
-    def _admit_waiting(self) -> None:
+    def _admit_waiting(self, waited_from: float = 0.0) -> None:
         """Move waiting requests into free slots (block allocation + prefix
         cache lookup; no model compute).  Request stage stamps: `seen_t`
         on every request this pass is the first to find waiting,
         `admitted_t` where one gets its lane and blocks; the pass reads
         the clock once, and again only where a request arrived after
-        that read."""
+        that read.  `waited_from` is when this step's blocking
+        read-back began (_read_back_first; 0.0: it made none): a request
+        enqueued since then arrived while the thread was blocked, and
+        `req_admitted_after_wait` counts it."""
         now = 0.0
         while True:
             with self._qlock:
@@ -2338,6 +2384,8 @@ class JaxEngine:
                         if s.seen_t != 0.0:
                             break
                         s.seen_t = now
+                        if waited_from and s.enqueued_t >= waited_from:
+                            self.metrics["req_admitted_after_wait"] += 1
                 free_idx = next(
                     (i for i, s in enumerate(self._slots) if s is None), None
                 )
@@ -2818,8 +2866,8 @@ class JaxEngine:
 
     def _flush_pending_first(self) -> None:
         """Overlap mode: read back the PREVIOUS step's deferred prefill
-        first tokens (one blocking fetch for everything deferred, while
-        this step's dispatches run behind it) and emit or park them.
+        first tokens (one blocking fetch for everything deferred, at the
+        top of the step: _read_back_first) and emit or park them.
         Entries whose slot finished, cancelled, or preempted since
         dispatch are discarded — the same (seq_id, epoch) identity check
         the in-flight decode bursts use."""
@@ -3469,10 +3517,11 @@ class JaxEngine:
         return k
 
     def _decode_step(self) -> None:
-        """Read back the bursts beyond the pipeline depth, grow the
-        active slots' block tables, then build and dispatch one decode
-        burst: one `decode_dispatch` phase (on the ring only when a
-        burst went out)."""
+        """Grow the active slots' block tables, then build and dispatch
+        one decode burst: one `decode_dispatch` phase (on the ring only
+        when a burst went out).  The bursts at or over the pipeline
+        depth were read back at the top of the step
+        (_read_back_first)."""
         with self._phase("decode_dispatch") as ph:
             sent = self._decode_burst(ph)
             if not sent:
@@ -3482,16 +3531,21 @@ class JaxEngine:
             self._drain_inflight()
 
     def _decode_burst(self, ph) -> bool:
-        """_decode_step's body; False when nothing was dispatched."""
+        """_decode_step's body; False when nothing was dispatched.
+
+        A step leaves `decode_pipeline_depth` bursts dispatched and
+        unread: the one the device runs and, at the default of 2, ONE
+        queued behind it, which is what hides the host's step (PERF.md
+        section 6, PR 39: the depth sweep).  The oldest of them was read
+        back at the top of the step, before admission
+        (_read_back_first); the loop here holds the bound where that
+        read was not due then (no lane was past its prompt, and a guided
+        one left it in this very step).  Sync mode
+        (overlap_scheduling=False) is lockstep: depth 1 and a drain
+        right after dispatch, so tokens emit the step they were
+        computed — the byte-identity reference the overlap tests pin."""
         c = self.config
-        # pipeline: keep at most depth-1 unread bursts after this dispatch;
-        # processing the oldest here overlaps its (already-complete or
-        # nearly-complete) fetch with the device compute of newer bursts.
-        # Sync mode (overlap_scheduling=False) is lockstep: depth 1 and a
-        # drain right after dispatch, so tokens emit the step they were
-        # computed — the byte-identity reference the overlap tests pin.
-        depth = max(1, c.decode_pipeline_depth) if self._overlap else 1
-        while len(self._inflight) >= depth:
+        while len(self._inflight) >= self._depth:
             self._process_oldest_burst()
         k = self._fused_k()
         # slots that speculated this step already emitted synchronously

@@ -38,7 +38,12 @@ Design:
     is the engine's time to first token exactly; ``req_stage_s.wake`` +
     ``.lane`` + ``.turn``, the three waits that ARE the queue, request
     by request; ``req_ahead_steps``, the decode steps dispatched and
-    not yet ready on the device when the first chunk went out;
+    not yet ready on the device when the first chunk went out (a step
+    reads back FIRST and admits AFTER, and leaves one burst behind the
+    one that runs: a first chunk stands behind 8 steps where it stood
+    behind 16-28; ``req_admitted_after_wait`` counts the requests that
+    arrived during a step's read-back and were admitted by that same
+    step; PERF.md section 6, PR 39);
     ``req_stage_s.join`` (first to second token) over ``req_join_n``;
     ``req_stage_s.decode`` (second token to finish) over
     ``req_decode_tokens`` (the tokens after the second of the requests

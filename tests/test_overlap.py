@@ -434,3 +434,72 @@ async def test_close_after_a_failed_step_returns():
         await collect(eng, greedy_req(PROMPTS[1], 2, "post-crash"))
     await asyncio.wait_for(eng.close(), 60.0)
     assert not eng._inflight and not eng._pending_first
+
+
+class _NeverReady:
+    """A dispatched burst that says it is not ready until it is read, so
+    that `ahead_steps` counts everything in flight: a property of the
+    schedule, not of the CPU's speed."""
+
+    def __init__(self, arr):
+        self.arr = arr
+
+    def is_ready(self):
+        return False
+
+    def __array__(self, dtype=None, copy=None):
+        import numpy as np
+
+        return np.asarray(self.arr)
+
+
+@pytest.mark.parametrize("depth", [None, 4], ids=["default", "depth4"])
+async def test_pipeline_depth_bounds_what_stands_ahead_of_a_prefill(depth):
+    """A step leaves `decode_pipeline_depth` bursts in flight (2 by
+    default: the one that runs and one behind it; still honoured where
+    set) and never more; the oldest is read back BEFORE admission, so in
+    a decode-only stretch a first chunk goes out behind depth - 1 bursts
+    at most: 8 steps by default, under the decode_fused_steps +
+    INTERLEAVE_BURST the order of the step promises."""
+    assert EngineConfig().decode_pipeline_depth == 2
+    cfg = {} if depth is None else {"decode_pipeline_depth": depth}
+    depth = depth or 2
+    eng = engine(overlap_scheduling=True, decode_fused_steps=8,
+                 block_size=16, prefill_buckets=(16, 32), **cfg)
+    build, step, stamp = eng._build_burst, eng._sched_step, \
+        eng._stamp_dispatch
+    left_in_flight, firsts = [], []
+
+    def build_unready(active, k):
+        burst, cont = build(active, k)
+        return _NeverReady(burst), cont
+
+    def step_then_look():
+        step()
+        left_in_flight.append(len(eng._inflight))
+
+    def stamp_and_keep(slots):
+        firsts.extend(s for s in slots if s.dispatched_t == 0.0)
+        stamp(slots)
+
+    eng._build_burst, eng._sched_step = build_unready, step_then_look
+    eng._stamp_dispatch = stamp_and_keep
+    long_one = asyncio.create_task(
+        collect(eng, greedy_req(list(range(7, 20)), 200, "depth-r0")))
+    for i in range(3):
+        # each arrival lands in a decode-only stretch at full fusion
+        mark = len(eng.fpm)
+        await _until(lambda: sum(r["kind"] == "decode" and r["k"] == 8
+                                 for r in list(eng.fpm)[mark:]) >= depth,
+                     "the ramp never reached full fusion")
+        assert len(await collect(eng, greedy_req(
+            list(range(40 + i, 49 + i)), 8, f"depth-r{i + 1}"))) == 8
+    assert len(await long_one) == 200
+    assert max(left_in_flight) == depth
+    ahead = [s.ahead_steps for s in firsts]
+    assert len(ahead) == 4 and ahead[0] == 0     # r0 came to an idle engine
+    fused = eng.config.decode_fused_steps
+    assert 0 < max(ahead[1:]) <= (depth - 1) * fused
+    if depth == 2:
+        assert max(ahead) <= fused + JaxEngine.INTERLEAVE_BURST
+    await eng.close()

@@ -6,11 +6,13 @@ numbers of one run."""
 
 import asyncio
 import glob
+import threading
 import types
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 pytestmark = pytest.mark.allow_slow_callbacks
@@ -28,6 +30,7 @@ from dynamo_tpu.protocols import (
     SamplingOptions,
     StopConditions,
 )
+from test_overlap import _until as until  # noqa: E402 (shared helper)
 
 TINY = LlamaConfig(name="tiny32", vocab_size=256, d_model=64, n_layers=2,
                    n_heads=4, n_kv_heads=2, head_dim=16, ffn_dim=128,
@@ -527,11 +530,24 @@ def test_a_prompt_behind_anothers_chunks_is_a_turn_wait():
 
 
 class _Burst:
-    def __init__(self, ready):
-        self.ready = ready
+    """A dispatched burst's tokens as the engine holds them: `is_ready()`
+    and, where a test hands it a real burst and a gate, a read-back that
+    waits for the gate (and says so on `blocked`)."""
+
+    def __init__(self, ready, arr=None, gate=None, blocked=None):
+        self.ready, self.arr = ready, arr
+        self.gate, self.blocked = gate, blocked
 
     def is_ready(self):
-        return self.ready
+        if self.gate is None:
+            return self.ready
+        return self.gate.is_set() and self.arr.is_ready()
+
+    def __array__(self, dtype=None, copy=None):
+        if not self.gate.is_set():
+            self.blocked.set()
+            assert self.gate.wait(60.0), "the test never opened the gate"
+        return np.asarray(self.arr)
 
 
 def test_ahead_steps_count_the_bursts_that_are_not_ready():
@@ -584,6 +600,75 @@ def test_ahead_steps_ride_the_first_frame(overlap):
     assert isinstance(m["req_ahead_steps"], int)
     if not overlap:
         assert m["req_ahead_steps"] == 0
+
+
+@pytest.mark.parametrize("overlap", [True, False],
+                         ids=["overlapped", "lockstep"])
+def test_an_arrival_during_the_read_back_goes_out_in_that_step(overlap):
+    """Read back first, admit after: a request that arrives while the
+    scheduler thread stands in a burst's read-back is seen and admitted
+    by the admission pass of THAT step, its first chunk is dispatched
+    before the step's decode burst, and the burst is an interleave one;
+    `req_admitted_after_wait` counts it and neither the request that
+    came to an idle engine before it nor the one after it.  Lockstep
+    reads back after its dispatch: the same arrival waits for the next
+    step and the counter stays 0.  The three waits still sum to the
+    queue, request by request (check_stages)."""
+
+    async def main():
+        eng = make_engine(overlap_scheduling=overlap, max_blocks_per_seq=200)
+        gate, blocked = threading.Event(), threading.Event()
+        gate.set()
+        build, step = eng._build_burst, eng._sched_step
+        marks = []            # len(fpm) at the start of each step
+
+        def held_burst(active, k):
+            burst, cont = build(active, k)
+            return _Burst(False, burst, gate, blocked), cont
+
+        def marked_step():
+            marks.append(len(eng.fpm))
+            step()
+
+        eng._build_burst, eng._sched_step = held_burst, marked_step
+        seen = watch_stages(eng)
+        first = asyncio.create_task(serve(eng, 0, max_tokens=600))
+        await until(lambda: eng.metrics["decode_tokens"] >= 40,
+                    "r0 never reached a decode-only stretch")
+        gate.clear()
+        await until(blocked.is_set, "no read-back ever met the shut gate")
+        at = len(marks) - 1                      # the step that stands
+        assert eng.metrics["req_admitted_after_wait"] == 0
+        second = asyncio.create_task(serve(eng, 1))
+        await until(lambda: len(eng.waiting) == 1, "r1 never queued")
+        r1 = eng.waiting[0]
+        assert r1.seen_t == 0.0                  # nobody has looked yet
+        gate.set()
+        assert len(await second) == 8
+        first.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await first
+        await serve(eng, 2)                      # to an idle engine again
+        await settled(eng)
+        check_stages(eng, seen, 3)
+        recs = list(eng.fpm)
+        in_step = [[(r["kind"], r.get("k")) for r in recs[a:b]]
+                   for a, b in zip(marks[at:at + 2], marks[at + 1:at + 3])]
+        assert r1.seen_t == r1.admitted_t > 0.0  # seen and admitted at once
+        if overlap:
+            assert in_step[0] == [("prefill", None),
+                                  ("decode", JaxEngine.INTERLEAVE_BURST)]
+            assert r1.ahead_steps <= eng.config.decode_fused_steps
+            assert eng.metrics["req_admitted_after_wait"] == 1
+        else:
+            # the burst went out before its read; the chunk a step later
+            assert [kind for kind, _ in in_step[0]] == ["decode"]
+            assert in_step[1][0] == ("prefill", None)
+            assert r1.ahead_steps == 0
+            assert eng.metrics["req_admitted_after_wait"] == 0
+        await eng.close()
+
+    asyncio.run(main())
 
 
 # ------------------------- after the first token ----------------------------
