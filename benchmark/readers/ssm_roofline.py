@@ -1,0 +1,75 @@
+"""Roofline shares for a configuration of Mamba-2 state-space blocks (a
+float32 state a lane) beside GQA attention blocks and plain routed
+experts held as a share: the floors of benchmark/lib/ssm_floors.py, fed
+from the program's counters over the traced stretch, over the device
+time of the programs of one kind; and the share of the scan's rows that
+were a bucket's padding.  A program without those counters (the parent
+of the PR that added them) gives nothing to read: None, and the metric
+is left out."""
+
+from benchmark.lib import ssm_floors
+from benchmark.lib.stats import overlap
+from benchmark.readers.counters import _delta
+from benchmark.readers.device_trace import _decode_steps, _module_seconds
+from benchmark.readers.moe_roofline import _traced
+from benchmark.readers.sparse_roofline import _all_traced
+
+
+def decode_hbm_share(ctx, kind, dense_weight_bytes, expert_bytes,
+                     lane_step_bytes, kv_token_bytes, attn_layers,
+                     block_size):
+    """100 * bytes the decode steps had to move / device time of the
+    decode programs / peak HBM bytes/s."""
+    s = _module_seconds(ctx, kind)
+    steps = _decode_steps(ctx) if s is not None else 0
+    grown = _all_traced(ctx, "moe_experts_visited.decode",
+                        "ssm_lane_steps.decode",
+                        "decode_attn_live_blocks") if steps else None
+    if grown is None:
+        return None
+    visited, lane_steps, live_blocks = grown
+    need = ssm_floors.decode_bytes(
+        steps, visited, lane_steps,
+        ssm_floors.live_tokens(live_blocks, lane_steps, attn_layers,
+                               block_size),
+        dense_weight_bytes=dense_weight_bytes, expert_bytes=expert_bytes,
+        lane_step_bytes=lane_step_bytes, kv_token_bytes=kv_token_bytes)
+    return 100.0 * need / s / ctx["peaks"]["hbm_bytes_per_s"]
+
+
+def prefill_mxu_share(ctx, kind, dense_flops_per_token, pick_flops,
+                      ssm_layers, scan_flops_per_token, attn_layers,
+                      attn_pair_flops):
+    """100 * FLOPs the prefilled tokens needed / device time of the
+    prefill programs / peak bf16 FLOP/s.  Real tokens only: a bucket's
+    padded rows are work the program adds, not work the prompt needs.
+    The attention blocks' pairs: each request's causal total, by the
+    share of its prefill (sent -> first token) that fell inside the
+    stretch."""
+    s = _module_seconds(ctx, kind)
+    tokens = _traced(ctx, "ssm_tokens.prefill") if s is not None else None
+    picks = _traced(ctx, "moe_picks_held.prefill") if tokens else None
+    if not tokens or picks is None:
+        return None
+    t0, t1 = ctx["trace_window"]
+    pairs = 0.0
+    for rec in ctx["records"]:
+        if rec["sent_t"] is None or not rec["token_times"]:
+            continue
+        a, b = rec["sent_t"], rec["token_times"][0]
+        if b > a:
+            pairs += (overlap(a, b, t0, t1) / (b - a)
+                      * ssm_floors.causal_pairs(rec["prompt_len"]))
+    flops = ssm_floors.prefill_flops(
+        tokens, picks, pairs, dense_flops_per_token=dense_flops_per_token,
+        pick_flops=pick_flops, ssm_layers=ssm_layers,
+        scan_flops_per_token=scan_flops_per_token, attn_layers=attn_layers,
+        attn_pair_flops=attn_pair_flops)
+    return 100.0 * flops / s / ctx["peaks"]["bf16_flops"] / ctx["chips"]
+
+
+def pad_share(ctx, pad, real):
+    """100 * d(pad) / (d(pad) + d(real)) over the window: of the rows
+    the prefill programs ran, those beyond the prompts' tokens."""
+    p, r = _delta(ctx, pad), _delta(ctx, real)
+    return 100.0 * p / (p + r) if p + r else None

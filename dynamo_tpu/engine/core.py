@@ -436,10 +436,10 @@ class JaxEngine:
         # routed-expert layers as the host knows them, for the moe_*
         # counters: (layers that route, picks a token, experts held a
         # layer — the `moe_w_*` stacks' length, the router may be wider)
-        moe = [lp["moe_w_gate"]
+        moe = [lp["moe_w_up"]
                for lp in (self.params.get("layers", ())
                           if isinstance(self.params, dict) else ())
-               if isinstance(lp, dict) and "moe_w_gate" in lp]
+               if isinstance(lp, dict) and "moe_w_up" in lp]
         self._moe = (len(moe), getattr(self.model_cfg,
                                        "experts_per_token", 0),
                      moe[0].shape[0] if moe else 0)
@@ -742,7 +742,7 @@ class JaxEngine:
             self.metrics.update(self._decode_counts(np.zeros(0, np.int64),
                                                     0))
         if self._prefill_counts is not None:
-            self.metrics.update(self._prefill_counts(self.model_cfg, 0, 0))
+            self.metrics.update(self._prefill_counts(self.model_cfg, 0, 0, 0))
         # the scheduler thread's phases: counters host_s.<kind> /
         # host_n.<kind> always, `dyn.<kind>` on the profiler's clock
         # while a session is live, ring spans under a Tracer (obs/)
@@ -2582,7 +2582,7 @@ class JaxEngine:
                 first = int(firsts[i]) if firsts is not None else None
             else:
                 first = -1
-            self._finish_prefill_chunk(slot, chunk, first)
+            self._finish_prefill_chunk(slot, chunk, first, bucket)
 
     def _moe_grouped(self, tokens: int) -> bool:
         """Whether a program whose expert layers see `tokens` rows takes
@@ -2761,7 +2761,7 @@ class JaxEngine:
             first = int(arr) if arr is not None else None
         else:
             first = -1
-        self._finish_prefill_chunk(slot, chunk, first)
+        self._finish_prefill_chunk(slot, chunk, first, bucket)
 
     def _prefill_ring_one(self, slot: "_Slot") -> None:
         """Whole-prompt sequence-parallel prefill (see _prefill_one)."""
@@ -2888,18 +2888,22 @@ class JaxEngine:
                     self._complete_prefill(slot, int(flat[row]))
 
     def _finish_prefill_chunk(self, slot: "_Slot", chunk: int,
-                              first: Optional[int]) -> None:
+                              first: Optional[int],
+                              bucket: int = 0) -> None:
         """Advance a slot past a completed chunk.  `first` is the prompt's
         sampled first token when it completes this chunk; -1 marks a
         non-completing chunk (or a guided completion, which discards the
         sample); None marks a completed prompt whose token readback is
-        deferred (_pending_first — the flush completes it next step)."""
+        deferred (_pending_first — the flush completes it next step).
+        `bucket`: the rows the chunk was padded to where its program pads
+        a row (0 for a packed or ring program), for the family's counts."""
         self.metrics["prefill_tokens"] += chunk
         self.metrics["moe_picks.prefill"] += \
             chunk * self._moe[0] * self._moe[1]
         if self._prefill_counts is not None:
             for name, n in self._prefill_counts(
-                    self.model_cfg, slot.prefill_pos, chunk).items():
+                    self.model_cfg, slot.prefill_pos, chunk,
+                    bucket).items():
                 self.metrics[name] += n
         slot.prefill_pos += chunk
         slot.prefill_chunks += 1

@@ -11,9 +11,11 @@ The engine binds a family once via get_family(cfg) and never branches on
 architecture again — Llama/Qwen/Mixtral (llama.py, GQA cache), the
 DeepSeek MLA family (deepseek.py, latent cache), the window + global
 hybrid over a share of the experts (mimo.py), the GQA decoder whose
-attention reads the keys a learned indexer chooses (keye.py) and the
+attention reads the keys a learned indexer chooses (keye.py), the
 delta-rule linear-attention hybrid with one latent-attention layer a
-period (ling.py) serve through identical plumbing.
+period (ling.py) and the Mamba-2 state-space hybrid with a few GQA
+layers and plain experts, one mixer a block (nemotron_h.py) serve
+through identical plumbing.
 
 The cache is a tuple the family owns: the engine allocates one array a
 shape, hands the tuple to every program and takes it back.  A family
@@ -55,11 +57,13 @@ given (never through the family's type):
                              overwrites the one a window before it.  A
                              lane-addressed member may also be a STATE
                              that nothing overwrites by position
-                             (ling.py: members 2-3, a float32 matrix a
-                             head and the short convolution's tail, a
-                             lane and layer; `kv_cache_dtypes` says
-                             which member is which).  The family's
-                             programs then keep its life, since the
+                             (ling.py and nemotron_h.py: members 2-3,
+                             a float32 matrix a head and the short
+                             convolution's tail, a lane and layer;
+                             `kv_cache_dtypes` says which member is
+                             which).  The family's programs then keep
+                             its life (ops/lane_state.py, the one copy
+                             of it), since the
                              engine never clears a lane: a row whose
                              first position is 0 starts from ZEROS
                              whatever the lane held; chunk n + 1 of a
@@ -83,23 +87,26 @@ given (never through the family's type):
                              (keye.py: sparse_* tokens); an empty burst
                              names the counters.
     prefill_token_counts(..) the same for a prefill chunk from the
-                             host's positions (keye.py: pairs scored
-                             and kept).
+                             host's positions and the rows its program
+                             padded it to (keye.py: pairs scored and
+                             kept; nemotron_h.py: what padding costs
+                             the scan).
     kv_cache_scale_shapes /  int8 cache; prefill_packed, prefill_ring,
     _specs, prefill_packed,  spec_verify_packed, decode_hidden ...: a
     ...                      family without one falls back or refuses.
     UNSUPPORTED              what the engine must not promise for the
                              family (engine/core.py `_family_gaps`)."""
 
-from . import deepseek, keye, ling, llama, mimo
+from . import deepseek, keye, ling, llama, mimo, nemotron_h
 from .deepseek import DeepseekConfig
 from .keye import KeyeConfig
 from .ling import LingConfig
 from .llama import LlamaConfig, init_params
 from .mimo import MimoConfig
+from .nemotron_h import NemotronHConfig
 
 PRESETS = {**llama.PRESETS, **deepseek.PRESETS, **mimo.PRESETS,
-           **keye.PRESETS, **ling.PRESETS}
+           **keye.PRESETS, **ling.PRESETS, **nemotron_h.PRESETS}
 
 
 def get_family(cfg):
@@ -112,6 +119,8 @@ def get_family(cfg):
         return keye
     if isinstance(cfg, LingConfig):
         return ling
+    if isinstance(cfg, NemotronHConfig):
+        return nemotron_h
     if isinstance(cfg, LlamaConfig):
         return llama
     raise TypeError(f"unknown model config type: {type(cfg).__name__}")
@@ -123,6 +132,7 @@ __all__ = [
     "LingConfig",
     "LlamaConfig",
     "MimoConfig",
+    "NemotronHConfig",
     "PRESETS",
     "get_family",
     "init_params",

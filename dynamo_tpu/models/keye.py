@@ -186,11 +186,12 @@ def decode_block_counts(cfg: KeyeConfig, ctx: np.ndarray, k: int,
     }
 
 
-def prefill_token_counts(cfg: KeyeConfig, pos: int, chunk: int
-                         ) -> Dict[str, int]:
+def prefill_token_counts(cfg: KeyeConfig, pos: int, chunk: int,
+                         bucket: int = 0) -> Dict[str, int]:
     """Host-side counts for `chunk` prompt tokens prefilled from
     position `pos`, in (query, key) pairs a layer: scored by the
-    indexer (position + 1 a token) and kept for attention."""
+    indexer (position + 1 a token) and kept for attention.  (`bucket`,
+    a padded program's rows, is the contract's: pairs do not pad.)"""
     seen = pos + 1 + np.arange(chunk, dtype=np.int64)
     return {
         "sparse_pairs_scored.prefill": int(seen.sum()),

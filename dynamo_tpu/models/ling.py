@@ -70,6 +70,7 @@ from ..ops.delta_attention import (
     short_conv,
     short_conv_step,
 )
+from ..ops.lane_state import lanes_keep, rows_put, rows_start, rows_target
 from ..ops.mla_attention import (
     MLA_DECODE_IMPLS,
     mla_decode_attention,
@@ -285,12 +286,13 @@ def decode_block_counts(cfg: LingConfig, ctx: np.ndarray, k: int,
     }
 
 
-def prefill_token_counts(cfg: LingConfig, pos: int, chunk: int
-                         ) -> Dict[str, int]:
+def prefill_token_counts(cfg: LingConfig, pos: int, chunk: int,
+                         bucket: int = 0) -> Dict[str, int]:
     """Host-side counts for `chunk` prompt tokens prefilled from
     position `pos`: tokens through the chunked rule, those of them in a
     program that started from a carried state, rows that started from
-    zeros."""
+    zeros.  (`bucket`, the padded program's rows, is the contract's and
+    not counted here.)"""
     return {
         "recurrent_tokens.prefill": chunk,
         "recurrent_carried_tokens.prefill": chunk if pos > 0 else 0,
@@ -484,8 +486,7 @@ def prefill_batched(
     x = params["embedding"][token_ids].astype(cfg.dtype)  # [Bp, T, d]
     valid = jnp.arange(T)[None, :] < true_lens[:, None]
     fresh = ctx_lens == 0
-    # a row of no tokens is a bucket's filler: its writes fall outside
-    put = jnp.where(true_lens > 0, lanes, state.shape[1])
+    put = rows_target(lanes, true_lens, state.shape[1])
     scale = 1.0 / math.sqrt(cfg.head_dim)
     pool_li = _pool_index(cfg)
     picks = jnp.zeros((), jnp.int32)
@@ -494,9 +495,8 @@ def prefill_batched(
         h = rms_norm(x, layer["attn_norm"]["norm"], cfg.rms_eps)
         if kind == KDA:
             qkv, f, b, g = _kda_proj(layer, h)
-            t0 = jnp.where(fresh[:, None, None], 0, tail[pli, lanes])
-            s0 = jnp.where(fresh[:, None, None, None], 0,
-                           state[pli, lanes]).astype(jnp.float32)
+            t0 = rows_start(tail, pli, lanes, fresh)
+            s0 = rows_start(state, pli, lanes, fresh).astype(jnp.float32)
             conv, t1 = jax.vmap(short_conv, in_axes=(0, 0, None, 0))(
                 qkv, t0, layer["conv_w"], true_lens)
             q, k, v = _kda_heads(cfg, conv)
@@ -509,9 +509,8 @@ def prefill_batched(
                                      chunk=cfg.kda_chunk,
                                      sub=max(cfg.kda_chunk // 4, 1)))(
                 q, k, v, log_a, beta, s0)
-            state = state.at[pli, put].set(s1.astype(state.dtype),
-                                           mode="drop")
-            tail = tail.at[pli, put].set(t1, mode="drop")
+            state = rows_put(state, pli, put, s1)
+            tail = rows_put(tail, pli, put, t1)
             x = x + _kda_out(layer, cfg, o, g)
         else:
             q_nope, q_rope = _q_proj(layer, cfg, h, positions)
@@ -599,8 +598,7 @@ def decode(
             o, s1 = kda_step(q, k, v, log_a, beta,
                              state[pli].astype(jnp.float32), scale, live)
             state = state.at[pli].set(s1.astype(state.dtype))
-            tail = tail.at[pli].set(
-                jnp.where(live[:, None, None], t1, tail[pli]))
+            tail = tail.at[pli].set(lanes_keep(live, t1, tail[pli]))
             x = x + _kda_out(layer, cfg, o, g)
         else:
             q_nope, q_rope = _q_proj(layer, cfg, h[:, None, :], pos1)

@@ -336,7 +336,8 @@ def _qkv(layer, cfg: LlamaConfig, x: jax.Array, positions: jax.Array,
 
     `lora`: optional (bank_layer, adapter_idx) — batched low-rank deltas
     added to the projections (lora/bank.py); slot 0 is zeros so mixed
-    base/adapter batches share this program."""
+    base/adapter batches share this program.  `positions` None: a family
+    whose attention layers carry no rotary (models/nemotron_h.py)."""
     *lead, seq, _ = x.shape
     zq = x @ layer["wq"]
     zk = x @ layer["wk"]
@@ -354,8 +355,9 @@ def _qkv(layer, cfg: LlamaConfig, x: jax.Array, positions: jax.Array,
     if cfg.qk_norm:
         q = rms_norm(q, layer["q_norm"]["norm"], cfg.rms_eps)
         k = rms_norm(k, layer["k_norm"]["norm"], cfg.rms_eps)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if positions is not None:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -406,6 +408,25 @@ def experts_held(cfg) -> Tuple[int, int]:
     return getattr(cfg, "experts_held", None) or (0, cfg.n_experts)
 
 
+def relu2(h: jax.Array) -> jax.Array:
+    """relu(h)^2, the activation of a plain (non-gated) expert."""
+    return jnp.square(jax.nn.relu(h))
+
+
+def _expert_hidden(layer, cfg, mm) -> jax.Array:
+    """A routed expert's hidden activations in the form the family's
+    configuration gives it, for all three dispatches: `mm(w)` multiplies
+    the dispatch's rows with an expert matrix stack.  GATED (the default:
+    `cfg.expert_gated` absent or True) is act(x Wgate) * (x Wup), three
+    matrices an expert; PLAIN is act(x Wup), two, and the layer has no
+    `moe_w_gate`.  `cfg.expert_act` is the activation, a function (SiLU
+    where absent: SwiGLU)."""
+    act = getattr(cfg, "expert_act", jax.nn.silu)
+    if getattr(cfg, "expert_gated", True):
+        return act(mm(layer["moe_w_gate"])) * mm(layer["moe_w_up"])
+    return act(mm(layer["moe_w_up"]))
+
+
 def moe_held_counts(cfg, top_e: jax.Array, valid: Optional[jax.Array]):
     """(picks that fell on a held expert, held experts with a token), two
     int32 scalars over the valid rows of top_e [T, k]: what a family
@@ -447,8 +468,8 @@ def moe_dispatch_dense(layer, cfg: LlamaConfig, x: jax.Array,
     first, count = experts_held(cfg)
     if count != E:
         wmat = wmat[:, first:first + count]            # the held columns
-    h = jnp.einsum("td,edf->etf", x, layer["moe_w_gate"])
-    h = jax.nn.silu(h) * jnp.einsum("td,edf->etf", x, layer["moe_w_up"])
+    h = _expert_hidden(layer, cfg,
+                       lambda w: jnp.einsum("td,edf->etf", x, w))
     eout = jnp.einsum("etf,efd->etd", h, layer["moe_w_down"])
     return jnp.einsum("etd,te->td", eout, wmat.astype(cfg.dtype))
 
@@ -500,8 +521,8 @@ def moe_dispatch_capacity(layer, cfg: LlamaConfig, x: jax.Array,
 
     x_rep = jnp.repeat(x, k, axis=0)                   # [Tk, d]
     ein = jnp.einsum("sec,sd->ecd", disp.astype(cfg.dtype), x_rep)
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", ein, layer["moe_w_gate"])) \
-        * jnp.einsum("ecd,edf->ecf", ein, layer["moe_w_up"])
+    h = _expert_hidden(layer, cfg,
+                       lambda w: jnp.einsum("ecd,edf->ecf", ein, w))
     eout = jnp.einsum("ecf,efd->ecd", h, layer["moe_w_down"])
     out = jnp.einsum("sec,ecd->sd", comb.astype(cfg.dtype), eout)
     return out.reshape(T, k, d).sum(axis=1)
@@ -596,9 +617,10 @@ def moe_dispatch_grouped(layer, cfg: LlamaConfig, x: jax.Array,
                          valid: Optional[jax.Array] = None) -> jax.Array:
     """The dropless dispatch's form for prompt-sized inputs
     (moe_dispatch_dense's contract and mathematics): the (token, pick)
-    pairs sorted by expert, the three expert matmuls grouped over the
-    sorted rows (a row meets its own expert's matrices only), the k
-    results of a token gathered back and summed in pick order.
+    pairs sorted by expert, the expert's matmuls (three, or two where it
+    is plain: `_expert_hidden`) grouped over the sorted rows (a row
+    meets its own expert's matrices only), the k results of a token
+    gathered back and summed in pick order.
 
     A pick of an expert held elsewhere (`experts_held`) and every pick
     of a row `valid` masks out sort behind the held groups, belong to no
@@ -618,8 +640,8 @@ def moe_dispatch_grouped(layer, cfg: LlamaConfig, x: jax.Array,
     sizes = jnp.sum(group[:, None] == jnp.arange(count)[None, :], axis=0,
                     dtype=jnp.int32)
     xs = x[order // k]                                 # [T*k, d]
-    h = jax.nn.silu(_grouped_matmul(xs, layer["moe_w_gate"], sizes)) \
-        * _grouped_matmul(xs, layer["moe_w_up"], sizes)
+    h = _expert_hidden(layer, cfg,
+                       lambda w: _grouped_matmul(xs, w, sizes))
     ys = _grouped_matmul(h, layer["moe_w_down"], sizes)
     held = held.reshape(T, k)
     y = jnp.where(held[..., None], ys[place].reshape(T, k, d), 0)

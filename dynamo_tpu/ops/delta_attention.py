@@ -57,30 +57,39 @@ F32 = jnp.float32
 # ---------------------------------------------------------------------------
 
 
-@jax.named_scope("dyn.attn_conv")
-def short_conv(x: jax.Array, tail: jax.Array, w: jax.Array,
-               true_len: jax.Array):
+def causal_conv(x: jax.Array, tail: jax.Array, w: jax.Array,
+                true_len: jax.Array, bias: jax.Array | None = None):
     """Causal depthwise convolution over time, then SiLU.
     x [T, C] this chunk's inputs, tail [W - 1, C] the inputs before it
-    (zeros at a sequence's start), w [W, C].  -> (c [T, C] float32,
-    new tail [W - 1, C]: the last W - 1 inputs up to the `true_len`-th,
-    so padding behind it does not shift the tail)."""
+    (zeros at a sequence's start), w [W, C], bias [C] where the layer
+    has one.  -> (c [T, C] float32, new tail [W - 1, C]: the last W - 1
+    inputs up to the `true_len`-th, so padding behind it does not shift
+    the tail)."""
     T, W = x.shape[0], w.shape[0]
     xx = jnp.concatenate([tail.astype(x.dtype), x], axis=0)   # [T+W-1, C]
     xf, wf = xx.astype(F32), w.astype(F32)
     c = sum(wf[j] * xf[j:j + T] for j in range(W))
+    if bias is not None:
+        c = c + bias.astype(F32)
     new_tail = jax.lax.dynamic_slice_in_dim(xx, true_len, W - 1, axis=0)
     return jax.nn.silu(c), new_tail.astype(tail.dtype)
 
 
-@jax.named_scope("dyn.attn_conv")
-def short_conv_step(x: jax.Array, tail: jax.Array, w: jax.Array):
+def causal_conv_step(x: jax.Array, tail: jax.Array, w: jax.Array,
+                     bias: jax.Array | None = None):
     """One token a lane: x [B, C], tail [B, W - 1, C] -> (c [B, C]
     float32, new tail)."""
     xx = jnp.concatenate([tail.astype(x.dtype), x[:, None]], axis=1)
     c = jnp.einsum("bwc,wc->bc", xx.astype(F32), w.astype(F32),
                    precision=HI)
+    if bias is not None:
+        c = c + bias.astype(F32)
     return jax.nn.silu(c), xx[:, 1:].astype(tail.dtype)
+
+
+# the same two under this family's scope (ops/ssm.py has them under its own)
+short_conv = jax.named_scope("dyn.attn_conv")(causal_conv)
+short_conv_step = jax.named_scope("dyn.attn_conv")(causal_conv_step)
 
 
 def l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:

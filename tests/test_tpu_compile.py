@@ -724,3 +724,87 @@ def test_latent_decode_runs_per_head_shard_under_tp(topo):
     assert {op for _, op in made} <= {
         "parameter", "get-tuple-element", "while", "bitcast"}, \
         sorted(set(made))
+
+
+def test_ssm_decode_and_prefill_compile_for_v5e(topo, one_chip):
+    """The state-space family (models/nemotron_h.py) at the published
+    nemotron_h widths, cut to the pattern's first 13 blocks (`MEMEM*`
+    and one whole period `EMEMEM*`: 6 Mamba-2, 5 expert blocks with 16
+    of 128 experts held, 2 attention), with the chat cell's cache (64
+    lanes, 1281 blocks, tables of 20) and `auto` resolved as on the chip:
+    a fused decode burst of the engine's own program and a 2048-token
+    prefill chunk.  In both, the float32 state (6 x 64 lanes x 64 heads x
+    64 x 128: 805 MB here, 1.61 GB at the cell's 12 Mamba blocks) is
+    updated where it lies: no copy of its shape nor of one block's slice
+    over the lanes; the K/V pool goes into the decode kernel whole (one
+    custom call an attention block, 2 KV heads of 128 under 16 query
+    heads each); a decode step keeps the dense dispatch and the prompt
+    groups its picks in TWO grouped matmuls an expert block (a plain
+    expert has no gate matrix)."""
+    import re
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.models import nemotron_h as nh
+    from dynamo_tpu.ops.paged_attention import resolve_decode_impl
+
+    NB, B, MB, K, T = 1281, 64, 20, 8, 2048
+    impl = resolve_decode_impl("auto", topo.devices[0].platform, BS, 128,
+                               jnp.bfloat16)
+    assert impl == "pallas"
+    cfg = dataclasses.replace(
+        nh.PRESETS["nemotron-twotower-30b-a3b"], pattern="MEMEM*EMEMEM*",
+        experts_held=(0, 16), attn_impl=impl)
+    NM, NA, NE = (len(cfg.layers_of(k)) for k in "M*E")
+    assert (NM, NA, NE) == (6, 2, 5)
+    S = _sds(one_chip)
+    shapes = jax.eval_shape(
+        lambda: nh.init_params(cfg, jax.random.PRNGKey(0)))
+    assert shapes["layers"][0]["w_in"].shape == (2688, 10304)
+    assert "moe_w_gate" not in shapes["layers"][1]
+    assert shapes["layers"][1]["moe_w_up"].shape == (16, 2688, 1856)
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    kv = tuple(S(s, d) for s, d in zip(
+        nh.kv_cache_shapes(cfg, NB, BS, lanes=B), nh.kv_cache_dtypes(cfg)))
+    assert kv[0].shape == (NA, 2, NB, 128, BS)
+    assert kv[2].shape == (NM, B, 64, 64, 128) and kv[2].dtype == jnp.float32
+    assert kv[3].shape == (NM, B, 3, 6144)
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+
+    def state_stays(hlo):
+        # (a prefill row's own 2 MB working state may move; the pool and
+        # a block's slice over all lanes may not)
+        for shape in (rf"f32\[{NM},{B},64,64,128\]",
+                      rf"f32\[{B},64,64,128\]"):
+            assert not re.findall(rf"= {shape}\S* copy\(", hlo), shape
+
+    fn = jax.jit(
+        partial(JaxEngine._decode_multi_impl, nh, cfg, None, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9))
+    lowered = fn.lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32))
+    assert lowered.out_info[0].shape == (K + len(nh.KV_COUNTERS), B)
+    program = lowered.compile()
+    hlo = program.as_text()
+    state_stays(hlo)
+    _assert_pool_stays_where_it_lies(hlo, NA, 2, NB, 128)
+    # the attention blocks' kernel and nothing else custom: a decode
+    # step keeps the dense form, every held expert for every lane
+    assert hlo.count("tpu_custom_call") == NA
+    assert f"bf16[16,1856,{B}]" in hlo or f"bf16[16,{B},1856]" in hlo
+    assert program.memory_analysis().temp_size_in_bytes < 2.0e9
+    pre = jax.jit(partial(JaxEngine._prefill_impl, nh, cfg),
+                  donate_argnums=(1,))
+    program = pre.lower(
+        params, kv, S((T,), i32), S((T,), i32), S((MB,), i32), S((), i32),
+        S((), i32), S((), i32), S((), f32), S((), i32), S((), f32), None,
+        None, S((), i32)).compile()
+    hlo = program.as_text()
+    state_stays(hlo)
+    assert hlo.count("tpu_custom_call") == 2 * NE
+    assert f"bf16[16,{T},1856]" not in hlo \
+        and f"bf16[16,1856,{T}]" not in hlo
+    assert program.memory_analysis().temp_size_in_bytes < 3.0e9
