@@ -70,13 +70,22 @@ from ..ops.delta_attention import (
     short_conv,
     short_conv_step,
 )
-from ..ops.lane_state import lanes_keep, rows_put, rows_start, rows_target
+from ..ops.lane_state import (
+    lanes_keep,
+    lanes_plan,
+    lanes_step,
+    resolve_state_impl,
+    rows_put,
+    rows_start,
+    rows_target,
+)
 from ..ops.mla_attention import (
     MLA_DECODE_IMPLS,
     mla_decode_attention,
     mla_prefill_attention,
 )
 from ..ops.paged_attention import PALLAS_IMPLS, write_prompt_kv_batched
+from ..ops.pallas_lane_state import kda_lanes_step
 from .deepseek import (
     _absorb_q,
     _ds_router,
@@ -140,7 +149,8 @@ class LingConfig:
     tie_embeddings: bool = False
     max_context: int = 8192
     dtype: Any = jnp.bfloat16
-    attn_impl: str = "auto"       # the MLA layers' read: MLA_DECODE_IMPLS
+    attn_impl: str = "auto"       # the MLA layers' read: MLA_DECODE_IMPLS;
+                                  # by its own conditions the state's step
     eos_token_ids: Tuple[int, ...] = (2,)
     qk_norm: bool = False         # unused; uniform surface
 
@@ -273,8 +283,10 @@ def decode_block_counts(cfg: LingConfig, ctx: np.ndarray, k: int,
     step's live blocks, the gathering read every lane's whole table).
     And the state pool's lanes: each active lane moves
     one state a KDA layer a step, out of `lanes` slots that a step's
-    program runs over."""
-    nm = len(cfg.layers_of(MLA))
+    program runs over; `state_live` those lane steps over the KDA
+    layers, `state_moved` the lanes whose state the step that runs moves
+    (`state_impl`: the kernel the busy ones, the jnp step every slot)."""
+    nm, nk = len(cfg.layers_of(MLA)), len(cfg.layers_of(KDA))
     live = int((-(-(ctx[:, None] + 1 + np.arange(k)[None, :])
                   // block_size)).sum())
     read = live if attn_impl in PALLAS_IMPLS else k * lanes * table_width
@@ -283,7 +295,19 @@ def decode_block_counts(cfg: LingConfig, ctx: np.ndarray, k: int,
         "decode_attn_read_blocks": nm * read,
         "recurrent_lane_steps.decode": k * len(ctx),
         "recurrent_slot_steps.decode": k * lanes,
+        "state_live_lane_steps.decode": nk * k * len(ctx),
+        "state_moved_lane_steps.decode": nk * k * (
+            len(ctx) if state_impl(cfg, attn_impl) in PALLAS_IMPLS
+            else lanes),
     }
+
+
+def state_impl(cfg: LingConfig, attn_impl: str) -> str:
+    """The impl of the state's decode step under `attn_impl`, by the
+    state's own conditions (ops/lane_state.resolve_state_impl), asked by
+    the traced step and by the host's counts alike."""
+    return resolve_state_impl(attn_impl, jax.default_backend(),
+                              cfg.head_dim, cfg.head_dim, cfg.state_dtype)
 
 
 def prefill_token_counts(cfg: LingConfig, pos: int, chunk: int,
@@ -570,10 +594,11 @@ def decode(
     ctx_lens: jax.Array,       # [B]
     valid: Optional[jax.Array] = None,
     mesh=None,
+    state_plan=None,           # decode_multi's: `lanes_plan`, once a burst
 ):
-    """One token a lane.  A KDA layer reads and writes every lane's
-    state where it lies (rows ARE lanes); a lane that is not `valid`
-    keeps state and tail as they were."""
+    """One token a lane.  A KDA layer reads and writes the live lanes'
+    state where it lies (rows ARE lanes: `lanes_step`); a lane that is
+    not `valid` keeps state and tail as they were."""
     c_cache, kr_cache, state, tail, counters = kv_cache
     x = params["embedding"][token_ids].astype(cfg.dtype)  # [B, d]
     B = x.shape[0]
@@ -585,6 +610,9 @@ def decode(
     picks = visited = jnp.zeros((), jnp.int32)
     impl, kv_lens, write_token = mla_decode_plan(
         cfg, c_cache, kr_cache, ctx_lens, valid, mesh)
+    s_impl = state_impl(cfg, cfg.attn_impl)
+    if state_plan is None:
+        state_plan = lanes_plan(live, s_impl)
     for li, layer in enumerate(params["layers"]):
         kind, pli = cfg.layer_kinds[li], pool_li[li]
         h = rms_norm(x, layer["attn_norm"]["norm"], cfg.rms_eps)
@@ -595,9 +623,11 @@ def decode(
             log_a, beta = kda_gates(
                 f.reshape(B, cfg.n_heads, cfg.head_dim), b,
                 layer["a_log"], layer["dt_bias"], cfg.kda_lower_bound)
-            o, s1 = kda_step(q, k, v, log_a, beta,
-                             state[pli].astype(jnp.float32), scale, live)
-            state = state.at[pli].set(s1.astype(state.dtype))
+            rule = (q, k, v, log_a, beta)
+            o, state = lanes_step(
+                state, pli, state_plan,
+                partial(kda_step, *rule, scale=scale),
+                partial(kda_lanes_step, *rule, scale=scale), s_impl)
             tail = tail.at[pli].set(lanes_keep(live, t1, tail[pli]))
             x = x + _kda_out(layer, cfg, o, g)
         else:
@@ -641,10 +671,14 @@ def decode_multi(
         def sample_fn(logits, _):
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+    # the busy lanes are the burst's: compacted once, outside the scan
+    plan = None if valid is None else lanes_plan(
+        valid, state_impl(cfg, cfg.attn_impl))
+
     def body(carry, step_idx):
         tokens, kv, pos, cls = carry
         logits, kv = decode(params, cfg, kv, tokens, pos, block_tables,
-                            cls, valid=valid, mesh=mesh)
+                            cls, valid=valid, mesh=mesh, state_plan=plan)
         nt = sample_fn(logits, step_idx).astype(jnp.int32)
         return (nt, kv, pos + 1, cls + 1), nt
 

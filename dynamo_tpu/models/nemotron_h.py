@@ -65,7 +65,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..ops.lane_state import lanes_keep, rows_put, rows_start, rows_target
+from ..ops.lane_state import (
+    lanes_keep,
+    lanes_plan,
+    lanes_step,
+    resolve_state_impl,
+    rows_put,
+    rows_start,
+    rows_target,
+)
 from ..ops.paged_attention import (
     PALLAS_IMPLS,
     paged_attention_decode,
@@ -74,6 +82,7 @@ from ..ops.paged_attention import (
     write_prompt_kv_batched,
     write_token_kv,
 )
+from ..ops.pallas_lane_state import ssd_lanes_step
 from ..ops.ssm import (
     gated_group_norm,
     ssd_chunked,
@@ -139,7 +148,8 @@ class NemotronHConfig:
     tie_embeddings: bool = False
     max_context: int = 8192
     dtype: Any = jnp.bfloat16
-    attn_impl: str = "auto"       # the GQA layers' decode read
+    attn_impl: str = "auto"       # the GQA layers' decode read and, by
+                                  # its own conditions, the state's step
     eos_token_ids: Tuple[int, ...] = (2,)
 
     def __post_init__(self):
@@ -266,8 +276,10 @@ def decode_block_counts(cfg: NemotronHConfig, ctx: np.ndarray, k: int,
     each step's live blocks, the gathering read every lane's whole
     table).  And the state pool's lanes: each active lane moves one
     state a Mamba block a step, out of `lanes` slots that a step's
-    program runs over."""
-    na = len(cfg.layers_of(ATTN))
+    program runs over; `state_live` those lane steps over the Mamba
+    blocks, `state_moved` the lanes whose state the step that runs moves
+    (`state_impl`: the kernel the busy ones, the jnp step every slot)."""
+    na, nm = len(cfg.layers_of(ATTN)), len(cfg.layers_of(MAMBA))
     live = int((-(-(ctx[:, None] + 1 + np.arange(k)[None, :])
                   // block_size)).sum())
     read = live if attn_impl in PALLAS_IMPLS else k * lanes * table_width
@@ -276,7 +288,20 @@ def decode_block_counts(cfg: NemotronHConfig, ctx: np.ndarray, k: int,
         "decode_attn_read_blocks": na * read,
         "ssm_lane_steps.decode": k * len(ctx),
         "ssm_slot_steps.decode": k * lanes,
+        "state_live_lane_steps.decode": nm * k * len(ctx),
+        "state_moved_lane_steps.decode": nm * k * (
+            len(ctx) if state_impl(cfg, attn_impl) in PALLAS_IMPLS
+            else lanes),
     }
+
+
+def state_impl(cfg: NemotronHConfig, attn_impl: str) -> str:
+    """The impl of the state's decode step under `attn_impl`, by the
+    state's own conditions (ops/lane_state.resolve_state_impl), asked by
+    the traced step and by the host's counts alike."""
+    return resolve_state_impl(attn_impl, jax.default_backend(),
+                              cfg.ssm_head_dim, cfg.ssm_state,
+                              cfg.state_dtype)
 
 
 def prefill_token_counts(cfg: NemotronHConfig, pos: int, chunk: int,
@@ -575,10 +600,11 @@ def decode(
     ctx_lens: jax.Array,       # [B]
     valid: Optional[jax.Array] = None,
     mesh=None,
+    state_plan=None,           # decode_multi's: `lanes_plan`, once a burst
 ):
-    """One token a lane.  A Mamba block reads and writes every lane's
-    state where it lies (rows ARE lanes); a lane that is not `valid`
-    keeps state and tail as they were."""
+    """One token a lane.  A Mamba block reads and writes the live lanes'
+    state where it lies (rows ARE lanes: `lanes_step`); a lane that is
+    not `valid` keeps state and tail as they were."""
     k_cache, v_cache, state, tail, counters = kv_cache
     x = params["embedding"][token_ids].astype(jnp.float32)  # [B, d]
     B = x.shape[0]
@@ -591,6 +617,9 @@ def decode(
     write_token = partial(write_token_kv, resident=impl in PALLAS_IMPLS,
                           valid=valid)
     kv_lens = jnp.where(live, ctx_lens + 1, 0)
+    s_impl = state_impl(cfg, cfg.attn_impl)
+    if state_plan is None:
+        state_plan = lanes_plan(live, s_impl)
     kind_index = cfg.kind_index
     for li, layer in enumerate(params["layers"]):
         kind, pli = cfg.pattern[li], kind_index[li]
@@ -601,11 +630,11 @@ def decode(
             conv, t1 = ssm_conv_step(xbc, tail[pli], layer["conv_w"],
                                      layer["conv_b"])
             xs, b, c = _ssm_heads(cfg, conv)
-            y, s1 = ssd_step(xs, ssm_dt(dt, layer["dt_bias"]),
-                             -jnp.exp(layer["a_log"]), b, c,
-                             layer["d_skip"],
-                             state[pli].astype(jnp.float32), live)
-            state = state.at[pli].set(s1.astype(state.dtype))
+            rule = (xs, ssm_dt(dt, layer["dt_bias"]),
+                    -jnp.exp(layer["a_log"]), b, c, layer["d_skip"])
+            y, state = lanes_step(state, pli, state_plan,
+                                  partial(ssd_step, *rule),
+                                  partial(ssd_lanes_step, *rule), s_impl)
             tail = tail.at[pli].set(lanes_keep(live, t1, tail[pli]))
             x = x + _ssm_out(layer, cfg, y, z)
         elif kind == ATTN:
@@ -644,10 +673,14 @@ def decode_multi(
         def sample_fn(logits, _):
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+    # the busy lanes are the burst's: compacted once, outside the scan
+    plan = None if valid is None else lanes_plan(
+        valid, state_impl(cfg, cfg.attn_impl))
+
     def body(carry, step_idx):
         tokens, kv, pos, cls = carry
         logits, kv = decode(params, cfg, kv, tokens, pos, block_tables,
-                            cls, valid=valid, mesh=mesh)
+                            cls, valid=valid, mesh=mesh, state_plan=plan)
         nt = sample_fn(logits, step_idx).astype(jnp.int32)
         return (nt, kv, pos + 1, cls + 1), nt
 

@@ -4,7 +4,7 @@ member does around its own recurrence, written once.
 
 A member is [layers of the kind, lanes, ...].  No program clears a lane
 and the engine never touches one, so the family's programs keep the
-state's life with these four:
+state's life with these five:
 
     rows_start   a prefill row starts from its lane's entry, or from
                  ZEROS where its first position is 0 (whatever the lane
@@ -16,6 +16,18 @@ state's life with these four:
     rows_put     the write itself
     lanes_keep   a decode step's new entry for the live lanes, the old
                  one bit for bit for the idle ones
+    lanes_step   a decode step of the family's recurrence over one
+                 layer of the member: the live lanes' entries read and
+                 written, the idle ones bit for bit what they were.
+                 Where `resolve_state_impl` says so the member goes
+                 WHOLE into ops/pallas_lane_state.py's kernel, which
+                 moves the busy lanes' entries once in and once out, in
+                 place (`lanes_plan` compacts the busy lanes, once a
+                 burst); elsewhere the jnp step runs over every lane of
+                 `member[pli]` and a select keeps the idle ones
+
+The small members (a convolution's tail: KB a lane) take `lanes_keep`;
+the float32 state (MB a lane and layer) takes `lanes_step`.
 
 What a bucket's padding does to the state is the recurrence's own
 business (a padded token must be a no-op of the rule), as is the replay
@@ -24,8 +36,13 @@ after a preemption (it starts at position 0, so `rows_start` zeroes).
 
 from __future__ import annotations
 
+from typing import Callable
+
 import jax
 import jax.numpy as jnp
+
+from .paged_attention import PALLAS_IMPLS
+from .pallas_lane_state import LanePlan
 
 
 def _over(mask: jax.Array, like: jax.Array) -> jax.Array:
@@ -53,3 +70,66 @@ def rows_put(member: jax.Array, pli: int, target: jax.Array,
 def lanes_keep(live: jax.Array, new: jax.Array, old: jax.Array
                ) -> jax.Array:
     return jnp.where(_over(live, new), new, old)
+
+
+def resolve_state_impl(impl: str, platform: str, dk: int, dv: int,
+                       state_dtype) -> str:
+    """What the family's `attn_impl` means for the decode step of a
+    state member whose entry is [heads, dk, dv] a lane:
+    `resolve_decode_impl`'s twin, with the STATE's conditions (the
+    attention read's are about the paged pool's blocks).  -> "pallas" |
+    "pallas_interpret" | "jnp".
+
+    The kernel runs where Mosaic can tile a head's [dk, dv] entry whole:
+    a float32 member, dk a multiple of 8 sublanes and dv of 128 lanes.
+    "auto" takes it on a TPU.  "pallas" is what the engine has made of
+    "auto" by the time a program is traced (it names the impl that runs
+    in the MDC) or what a caller asked for: the kernel under the same
+    conditions, whatever the backend (a compile for a described chip).
+    "pallas_interpret" is the kernel under the interpreter, which tiles
+    anything (CPU tests).  "jnp" (and "jnp_bf16", the GQA read's other
+    jnp form) keeps the jnp step and with it the parent's program: the
+    A/B on the chip.  On `nemotron-twotower.chat` (64 lanes, 13.7 busy)
+    the jnp step moves 4.8 GB of state a decode step and the kernel
+    0.7 GB (PERF.md section 6, PR 41)."""
+    if jnp.dtype(state_dtype) != jnp.dtype(jnp.float32):
+        return "jnp"
+    if impl == "pallas_interpret":
+        return impl
+    if (impl == "pallas" or (impl == "auto" and platform == "tpu")) \
+            and dk % 8 == 0 and dv % 128 == 0:
+        return "pallas"
+    return "jnp"
+
+
+def lanes_plan(valid: jax.Array, impl: str) -> LanePlan:
+    """The busy lanes of a burst: `valid` and, where `impl`
+    (`resolve_state_impl`'s answer) is the kernel, the busy lanes
+    compacted for its scalar prefetch: busy lanes first in their order,
+    the tail repeating the last busy one (lane 0 where none is busy),
+    and their number.  The jnp step needs `valid` alone.  `valid` is
+    constant over a burst's steps: decode_multi makes the plan once,
+    outside its scan."""
+    if impl not in PALLAS_IMPLS:
+        return LanePlan(valid, None, None)
+    n = jnp.sum(valid, dtype=jnp.int32)
+    order = jnp.argsort(~valid, stable=True).astype(jnp.int32)
+    last = jnp.maximum(n - 1, 0)
+    return LanePlan(valid, order[jnp.minimum(jnp.arange(valid.shape[0]),
+                                             last)], n.reshape(1))
+
+
+def lanes_step(member: jax.Array, pli: int, plan: LanePlan,
+               jnp_step: Callable, kernel_step: Callable, impl: str):
+    """One decode step of a recurrence over `member[pli]` -> (the read
+    [lanes, ...] float32, member).  `impl` is `resolve_state_impl`'s
+    answer and `plan` `lanes_plan`'s for it.  `kernel_step(member, pli,
+    plan, interpret=...)` is one of ops/pallas_lane_state.py's;
+    `jnp_step(state [lanes, ...] float32, valid=...)` -> (read, new
+    state) is the family's jnp recurrence, which keeps a lane that is
+    not valid itself."""
+    if impl in PALLAS_IMPLS:
+        return kernel_step(member, pli, plan,
+                           interpret=impl == "pallas_interpret")
+    read, new = jnp_step(member[pli].astype(jnp.float32), valid=plan.valid)
+    return read, member.at[pli].set(new.astype(member.dtype))

@@ -570,6 +570,39 @@ async def test_preempted_sequence_resumes_with_the_same_tokens():
     await tight.close()
 
 
+@pytest.mark.parametrize("impl", ["jnp", "pallas_interpret"])
+async def test_engine_counts_the_lanes_the_state_step_moves(impl):
+    """`state_moved_lane_steps.decode` under both impls of the state's
+    decode step (`state_impl`: the state's own conditions, asked from the
+    host as the traced step asks them): the kernel moves the busy lanes
+    of every state layer (= `state_live_lane_steps.decode`: the share
+    reads 100 %), the jnp step every slot; the tokens are the
+    reference's either way (three requests over four lanes: lanes join
+    and finish inside bursts, one slot stays idle)."""
+    eng = _engine(attn_impl=impl)
+    assert nh.state_impl(eng.model_cfg, eng.model_cfg.attn_impl) == impl
+    rng = np.random.default_rng(5)
+    sizes = ((23, 14), (40, 9), (17, 20))
+    prompts = [rng.integers(3, TINY.vocab_size, n).tolist()
+               for n, _ in sizes]
+    outs = await asyncio.gather(*[
+        _generate(eng, f"r{i}", p, n)
+        for i, (p, (_, n)) in enumerate(zip(prompts, sizes))])
+    for p, toks in zip(prompts, outs):
+        full = ref.reference_logits(eng.params, eng.model_cfg,
+                                    p + toks[:-1])
+        assert [int(jnp.argmax(full[len(p) - 1 + j]))
+                for j in range(len(toks))] == toks
+    m, layers = eng.metrics, len(TINY.layers_of("M"))
+    assert layers > 0
+    live, moved = (m[f"state_{x}_lane_steps.decode"]
+                   for x in ("live", "moved"))
+    assert 0 < live < layers * m["ssm_slot_steps.decode"]
+    assert moved == (live if impl == "pallas_interpret"
+                     else layers * m["ssm_slot_steps.decode"])
+    await eng.close()
+
+
 def test_unsupported_features_refuse_or_fall_back():
     """Prefix caching asked for is refused at start-up (switched off,
     warned: a reused K/V block says nothing of the state at its end);

@@ -506,26 +506,61 @@ def test_sparse_decode_and_prefill_compile_for_v5e(one_chip):
     assert program.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
+def _assert_state_steps_in_place(hlo, shape, n_layers):
+    """What a compiled decode burst may do to a lane-addressed float32
+    state member of `shape` [layers, lanes, heads, dk, dv]: take it,
+    hand it to ops/pallas_lane_state.py's kernel (one custom call a
+    state layer, the member aliased in place) and hand it on.  Nothing
+    but the program's plumbing and those calls gives out a value of the
+    member's shape or of one layer's slice over all lanes: no copy, no
+    `select` / `dynamic-update-slice` fusion over the whole member (the
+    jnp step's `where` and `.at[pli].set`)."""
+    import re
+
+    dims = lambda d: ",".join(map(str, d))
+    member = rf"f32\[{dims(shape)}\]"
+    layer = rf"f32\[(?:1,)?{dims(shape[1:])}\]"
+    made, calls = set(), []
+    for typ, op, rest in re.findall(
+            r"^ +(?:ROOT )?%\S+ = (.*?) ([\w-]+)\((.*)$", hlo, re.M):
+        assert not re.search(layer, typ), (typ, op)
+        if re.search(member, typ):
+            made.add(op)
+            if op == "custom-call":
+                calls.append(rest)
+    assert made <= {"parameter", "get-tuple-element", "while", "tuple",
+                    "bitcast", "custom-call"}, sorted(made)
+    assert len(calls) == n_layers
+    for rest in calls:      # in place: the member operand is the output
+        assert "tpu_custom_call" in rest
+        assert "output_to_operand_aliasing" in rest
+    assert not re.search(rf"{member}\{{[^}}]*S\(1\)", hlo)
+
 def test_recurrent_decode_and_prefill_compile_for_v5e(one_chip):
     """The delta-rule linear-attention family (models/ling.py) at
     Ling-3.0-flash's widths, cut to one period (5 KDA layers and the MLA
     layer; both dense layers, 4 expert layers with 16 of 512 experts
     held), with the wide cell's cache (64 lanes, 2881 blocks, tables of
-    45): a fused decode burst of the engine's own program and a
-    2048-token prefill chunk.  In both, the float32 state (5 x 64 lanes x
-    32 heads x 128 x 128: 671 MB here, 1.34 GB at the cell's 12 layers)
-    is updated where it lies: no copy of its shape nor of one layer's
-    slice over the lanes, and the program's temporaries stay beside 7.25 GB of weights,
-    state and cache at 12 layers (1.94 GB decode, 2.25 GB prefill there;
-    off-chip compiles, PR 35)."""
+    45) and `auto` resolved as on the chip: a fused decode burst of the
+    engine's own program and a 2048-token prefill chunk.  In both, the
+    float32 state (5 x 64 lanes x 32 heads x 128 x 128: 671 MB here,
+    1.34 GB at the cell's 12 layers) is updated where it lies: no copy
+    of its shape nor of one layer's slice over the lanes, and the
+    program's temporaries stay beside 7.25 GB of weights, state and
+    cache at 12 layers (1.94 GB decode, 2.25 GB prefill there; off-chip
+    compiles, PR 35).  The decode burst hands the member WHOLE to the
+    state kernel, one custom call a KDA layer (PR 41:
+    `_assert_state_steps_in_place`)."""
     import re
 
     from dynamo_tpu.engine.core import JaxEngine
     from dynamo_tpu.models import ling
+    from dynamo_tpu.ops.paged_attention import PALLAS_IMPLS
 
     L, NB, B, MB, K, T = 6, 2881, 64, 45, 8, 2048
     cfg = dataclasses.replace(ling.PRESETS["ling-3.0-flash"], n_layers=L,
-                              experts_held=(0, 16))
+                              experts_held=(0, 16), attn_impl="pallas")
+    assert ling.state_impl(cfg, cfg.attn_impl) in PALLAS_IMPLS
     S = _sds(one_chip)
     shapes = jax.eval_shape(
         lambda: ling.init_params(cfg, jax.random.PRNGKey(0)))
@@ -558,10 +593,12 @@ def test_recurrent_decode_and_prefill_compile_for_v5e(one_chip):
     program = lowered.compile()
     hlo = program.as_text()
     state_stays(hlo)
+    _assert_state_steps_in_place(hlo, kv[2].shape, 5)
     # a decode step keeps the dense form: every held expert, every lane
     assert f"bf16[16,{B},768]" in hlo
-    assert "tpu_custom_call" not in hlo
-    assert program.memory_analysis().temp_size_in_bytes < 2.5e9
+    # the state's kernel a KDA layer, the latent's two an MLA layer
+    assert hlo.count("tpu_custom_call") == 5 + 2 * 1
+    assert program.memory_analysis().temp_size_in_bytes < 0.3e9
     pre = jax.jit(partial(JaxEngine._prefill_impl, ling, cfg),
                   donate_argnums=(1,))
     program = pre.lower(
@@ -648,11 +685,14 @@ def test_latent_decode_reads_the_pool_where_it_lies(topo, one_chip, family):
         S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
         S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
         S((), i32))
+    # (a family with a lane-addressed state adds its step's kernel: one
+    # lowering, one call a state layer, test_recurrent_decode_...)
+    n_state = kv[2].shape[0] if lanes else 0
     assert lowered.as_text().count("stablehlo.custom_call @tpu_custom_call") \
-        == 2
+        == 2 + bool(n_state)
     program = lowered.compile()
     hlo = program.as_text()
-    assert hlo.count("tpu_custom_call") == 2 * n_mla
+    assert hlo.count("tpu_custom_call") == 2 * n_mla + n_state
     for hd in (R, dr):
         pool = rf"bf16\[{n_mla},1,{NB},{hd},{BS}\]"
         layer = rf"bf16\[(?:1,)?1,{NB},{hd},{BS}\]"
@@ -736,21 +776,26 @@ def test_ssm_decode_and_prefill_compile_for_v5e(topo, one_chip):
     prefill chunk.  In both, the float32 state (6 x 64 lanes x 64 heads x
     64 x 128: 805 MB here, 1.61 GB at the cell's 12 Mamba blocks) is
     updated where it lies: no copy of its shape nor of one block's slice
-    over the lanes; the K/V pool goes into the decode kernel whole (one
-    custom call an attention block, 2 KV heads of 128 under 16 query
-    heads each); a decode step keeps the dense dispatch and the prompt
+    over the lanes, and the decode burst hands the member WHOLE to the
+    state kernel, one custom call a Mamba block (PR 41:
+    `_assert_state_steps_in_place`); the K/V pool goes into the decode
+    kernel whole (one custom call an attention block, 2 KV heads of 128
+    under 16 query heads each); a decode step keeps the dense dispatch and the prompt
     groups its picks in TWO grouped matmuls an expert block (a plain
     expert has no gate matrix)."""
     import re
 
     from dynamo_tpu.engine.core import JaxEngine
     from dynamo_tpu.models import nemotron_h as nh
+    from dynamo_tpu.ops.lane_state import resolve_state_impl
     from dynamo_tpu.ops.paged_attention import resolve_decode_impl
 
     NB, B, MB, K, T = 1281, 64, 20, 8, 2048
     impl = resolve_decode_impl("auto", topo.devices[0].platform, BS, 128,
                                jnp.bfloat16)
     assert impl == "pallas"
+    assert resolve_state_impl("auto", topo.devices[0].platform, 64, 128,
+                              jnp.float32) == "pallas"
     cfg = dataclasses.replace(
         nh.PRESETS["nemotron-twotower-30b-a3b"], pattern="MEMEM*EMEMEM*",
         experts_held=(0, 16), attn_impl=impl)
@@ -791,11 +836,13 @@ def test_ssm_decode_and_prefill_compile_for_v5e(topo, one_chip):
     hlo = program.as_text()
     state_stays(hlo)
     _assert_pool_stays_where_it_lies(hlo, NA, 2, NB, 128)
-    # the attention blocks' kernel and nothing else custom: a decode
-    # step keeps the dense form, every held expert for every lane
-    assert hlo.count("tpu_custom_call") == NA
+    _assert_state_steps_in_place(hlo, kv[2].shape, NM)
+    # the attention blocks' kernel, the Mamba blocks' and nothing else
+    # custom: a decode step keeps the dense form, every held expert for
+    # every lane
+    assert hlo.count("tpu_custom_call") == NA + NM
     assert f"bf16[16,1856,{B}]" in hlo or f"bf16[16,{B},1856]" in hlo
-    assert program.memory_analysis().temp_size_in_bytes < 2.0e9
+    assert program.memory_analysis().temp_size_in_bytes < 0.3e9
     pre = jax.jit(partial(JaxEngine._prefill_impl, nh, cfg),
                   donate_argnums=(1,))
     program = pre.lower(
