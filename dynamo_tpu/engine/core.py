@@ -1078,7 +1078,7 @@ class JaxEngine:
     def _prefill_packed_impl(family, model_cfg, mesh, params, kv, toks,
                              positions, seg_ids, tables, last_idx, valid,
                              seeds, temps, top_ks, top_ps,
-                             lora_bank=None, lidx=None):
+                             lora_bank=None, lidx=None, lanes=None):
         """Packed multi-sequence chunked prefill (family prefill_packed):
         co-scheduled prompts/chunks run as ONE padding-free token stream
         with segment ids.  First tokens are sampled per segment row; rows
@@ -1087,6 +1087,8 @@ class JaxEngine:
         Pallas packed kernel's tp shard_map (like _decode_impl)."""
         lora_kw = ({"lora_bank": lora_bank, "adapter_idx": lidx}
                    if lora_bank is not None else {})
+        if lanes is not None:
+            lora_kw["lanes"] = lanes
         logits, kv = family.prefill_packed(
             params, model_cfg, kv, toks, positions, seg_ids, tables,
             last_idx, valid, mesh=mesh, **lora_kw,
@@ -1151,6 +1153,7 @@ class JaxEngine:
                 jnp.asarray(a["last_idx"]), jnp.asarray(a["valid"]),
                 jnp.asarray(a["seeds"]), jnp.asarray(a["temps"]),
                 jnp.asarray(a["top_ks"]), jnp.asarray(a["top_ps"]), *lora,
+                jnp.asarray(a["lanes"]) if "lanes" in a else None,
             )
         elif kind == "prefill":
             lora = ((self.lora_bank, jnp.int32(a["lidx"]))
@@ -2662,6 +2665,10 @@ class JaxEngine:
         if plan is None:
             return
         a = plan.arrays
+        if self._lane_addressed:
+            # each segment row's lane (unused rows: lane 0, no tokens)
+            a["lanes"] = np.zeros(len(a["last_idx"]), np.int32)
+            a["lanes"][:len(plan.slots)] = [s.index for s in plan.slots]
         if self.step_sink is not None:
             self.step_sink("prefill_packed", dict(a))
         self._stamp_dispatch(plan.slots)
@@ -2674,6 +2681,7 @@ class JaxEngine:
             jnp.asarray(a["top_ks"]), jnp.asarray(a["top_ps"]),
             self.lora_bank,
             jnp.asarray(a["lidx"]) if self.lora_bank is not None else None,
+            jnp.asarray(a["lanes"]) if self._lane_addressed else None,
         )
         self._fpm_prefill(
             rows=len(plan.slots), tokens=plan.tokens, bucket=plan.bucket,
@@ -2691,7 +2699,7 @@ class JaxEngine:
                 first = int(firsts[i]) if firsts is not None else None
             else:
                 first = -1
-            self._finish_prefill_chunk(slot, chunk, first)
+            self._finish_prefill_chunk(slot, chunk, first, plan.bucket)
 
     def _ring_eligible(self, slot: "_Slot") -> bool:
         """A cold (prefill_pos == 0), non-LoRA prompt longer than the
@@ -2896,7 +2904,8 @@ class JaxEngine:
         sample); None marks a completed prompt whose token readback is
         deferred (_pending_first — the flush completes it next step).
         `bucket`: the rows the chunk was padded to where its program pads
-        a row (0 for a packed or ring program), for the family's counts."""
+        a row, a packed program's whole stream (0 for a ring program), for
+        the family's counts."""
         self.metrics["prefill_tokens"] += chunk
         self.metrics["moe_picks.prefill"] += \
             chunk * self._moe[0] * self._moe[1]

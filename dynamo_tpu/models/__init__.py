@@ -13,9 +13,12 @@ DeepSeek MLA family (deepseek.py, latent cache), the window + global
 hybrid over a share of the experts (mimo.py), the GQA decoder whose
 attention reads the keys a learned indexer chooses (keye.py), the
 delta-rule linear-attention hybrid with one latent-attention layer a
-period (ling.py) and the Mamba-2 state-space hybrid with a few GQA
-layers and plain experts, one mixer a block (nemotron_h.py) serve
-through identical plumbing.
+period (ling.py), the Mamba-2 state-space hybrid with a few GQA
+layers and plain experts, one mixer a block (nemotron_h.py) and the
+window + NoPE-global decoder whose attention and experts (routed, and
+shared ones averaged) are ONE parallel block under one LayerNorm
+(cohere2.py; a window of many blocks: its rings are read by the paged
+pools' kernels) serve through identical plumbing.
 
 The cache is a tuple the family owns: the engine allocates one array a
 shape, hands the tuple to every program and takes it back.  A family
@@ -48,8 +51,10 @@ given (never through the family's type):
                              the block table: state of bounded size a
                              sequence, such as a window layer's ring.
                              kv_cache_shapes then takes `lanes=`, prefill
-                             and prefill_batched take `lanes=` (the lane
-                             of each row); decode rows ARE lanes.  Which
+                             and prefill_batched (and prefill_packed,
+                             where the family has one: cohere2.py) take
+                             `lanes=` (the lane of each row); decode
+                             rows ARE lanes.  Which
                              layers use which member is the family's own
                              (mimo.py: members 0-1 the global layers'
                              paged pools, 2-3 the window layers' rings).
@@ -90,14 +95,17 @@ given (never through the family's type):
                              host's positions and the rows its program
                              padded it to (keye.py: pairs scored and
                              kept; nemotron_h.py: what padding costs
-                             the scan).
+                             the scan; cohere2.py: pairs a window and
+                             a global layer attend, and whether the
+                             window read ran in the kernel).
     kv_cache_scale_shapes /  int8 cache; prefill_packed, prefill_ring,
     _specs, prefill_packed,  spec_verify_packed, decode_hidden ...: a
     ...                      family without one falls back or refuses.
     UNSUPPORTED              what the engine must not promise for the
                              family (engine/core.py `_family_gaps`)."""
 
-from . import deepseek, keye, ling, llama, mimo, nemotron_h
+from . import cohere2, deepseek, keye, ling, llama, mimo, nemotron_h
+from .cohere2 import Cohere2Config
 from .deepseek import DeepseekConfig
 from .keye import KeyeConfig
 from .ling import LingConfig
@@ -106,7 +114,8 @@ from .mimo import MimoConfig
 from .nemotron_h import NemotronHConfig
 
 PRESETS = {**llama.PRESETS, **deepseek.PRESETS, **mimo.PRESETS,
-           **keye.PRESETS, **ling.PRESETS, **nemotron_h.PRESETS}
+           **keye.PRESETS, **ling.PRESETS, **nemotron_h.PRESETS,
+           **cohere2.PRESETS}
 
 
 def get_family(cfg):
@@ -121,12 +130,15 @@ def get_family(cfg):
         return ling
     if isinstance(cfg, NemotronHConfig):
         return nemotron_h
+    if isinstance(cfg, Cohere2Config):
+        return cohere2
     if isinstance(cfg, LlamaConfig):
         return llama
     raise TypeError(f"unknown model config type: {type(cfg).__name__}")
 
 
 __all__ = [
+    "Cohere2Config",
     "DeepseekConfig",
     "KeyeConfig",
     "LingConfig",

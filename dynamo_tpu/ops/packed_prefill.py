@@ -263,7 +263,8 @@ def _gather_blocks(cache: jax.Array, layer: int, cols: jax.Array,
 
 
 def _segment_flash(q, k_cache, v_cache, layer, table, token_mask,
-                   positions, chunk_cols, k_scale=None, v_scale=None):
+                   positions, chunk_cols, k_scale=None, v_scale=None,
+                   lower=None):
     """One segment row's flash pass: online-softmax scan over chunks of
     `chunk_cols` block columns of the segment's paged context.  Returns
     fp32 attention output [T, nh, hd] for every packed token (foreign
@@ -288,6 +289,8 @@ def _segment_flash(q, k_cache, v_cache, layer, table, token_mask,
         span = jc * C + jnp.arange(C)
         mask = token_mask[:, None, None] \
             & (span[None, None, :] <= positions[:, None, None])
+        if lower is not None:
+            mask = mask & (span[None, None, :] >= lower[:, None, None])
         s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(-1))
         alpha = jnp.exp(m - m_new)
@@ -351,6 +354,7 @@ def packed_prefill_attention(
     k_scale: jax.Array = None,  # int8 cache: dequant scales (quant/kv.py)
     v_scale: jax.Array = None,
     mesh=None,                # required for the Pallas path under tp>1
+    lower: jax.Array = None,  # [T] first position of its row a token sees
 ) -> jax.Array:
     """Causal-within-segment attention for a packed prefill chunk.
 
@@ -371,6 +375,13 @@ def packed_prefill_attention(
     tensor-parallel (kv_heads over a "tp" axis): it then runs under
     shard_map per shard, like the decode kernel.  `chunk_cols` is the
     scan's step; the kernel's tiles are its own.
+
+    `lower`: a LOWER bound a token: it attends its row's positions
+    [lower[t], positions[t]], a band (ops/window_attention.py hands a
+    window layer's context over that way).  The kernel skips the key
+    tiles wholly under a query tile's bounds; the scan masks them.
+    Without it every caller's program is the one it was.  Not carried
+    under tp.
     """
     impl = resolve_packed_impl(impl, jax.default_backend(),
                                k_cache.shape[4], k_cache.shape[3],
@@ -382,6 +393,8 @@ def packed_prefill_attention(
         layer = jnp.int32(layer)
         tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
         if tp > 1:
+            if lower is not None:
+                raise NotImplementedError("lower under tp > 1")
             return _packed_pallas_tp(
                 q, k_cache, v_cache, layer, block_tables, seg_ids,
                 positions, valid, mesh=mesh, interpret=interpret,
@@ -392,7 +405,7 @@ def packed_prefill_attention(
         return packed_prefill_attention_pallas(
             q, k_cache, v_cache, layer, block_tables, seg_ids,
             positions, valid, interpret=interpret,
-            k_scale=k_scale, v_scale=v_scale,
+            k_scale=k_scale, v_scale=v_scale, lower=lower,
         )
     if impl != "xla":
         raise ValueError(
@@ -405,6 +418,6 @@ def packed_prefill_attention(
         seg_mask = (seg_ids == s) & valid
         o_s = _segment_flash(q, k_cache, v_cache, layer, block_tables[s],
                              seg_mask, positions, chunk_cols,
-                             k_scale, v_scale)
+                             k_scale, v_scale, lower)
         out = jnp.where(seg_mask[:, None, None], o_s, out)
     return out.astype(q.dtype)

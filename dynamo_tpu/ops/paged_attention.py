@@ -352,6 +352,7 @@ def paged_attention_decode_jnp(
     native_dtype: bool = False,
     k_scale: jax.Array = None,  # int8 cache: dequant scales (quant/kv.py)
     v_scale: jax.Array = None,
+    kv_lo: jax.Array = None,    # [B] first table position a lane attends
 ) -> jax.Array:
     """XLA path: the block gather feeds the einsums directly (fused by
     XLA — no explicit DMA kernel).  native_dtype=True keeps matmul
@@ -363,16 +364,20 @@ def paged_attention_decode_jnp(
     scale = 1.0 / jnp.sqrt(jnp.float32(hd))
     deq_dtype = jnp.bfloat16 if native_dtype else None
 
-    def one(qb, table, kvlen):
+    def one(qb, table, kvlen, lo=None):
         kb = _gather_ctx(k_cache, layer, table, k_scale, deq_dtype)
         vb = _gather_ctx(v_cache, layer, table, v_scale, deq_dtype)
         s = _gqa_scores(qb, kb, native_dtype) * scale   # [nh, S]
-        mask = (jnp.arange(kb.shape[1]) < kvlen)[None, :]
+        at = jnp.arange(kb.shape[1])
+        mask = (at < kvlen)[None, :]
+        if lo is not None:
+            mask = mask & (at >= lo)[None, :]
         s = jnp.where(mask, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         return _gqa_out(p, vb, native_dtype)     # [nh, hd]
 
-    out = jax.vmap(one)(q, block_tables, kv_lens)
+    bounds = () if kv_lo is None else (kv_lo,)
+    out = jax.vmap(one)(q, block_tables, kv_lens, *bounds)
     return out.astype(q.dtype)
 
 
@@ -483,6 +488,7 @@ def paged_attention_decode(
     mesh=None,
     k_scale: jax.Array = None,
     v_scale: jax.Array = None,
+    kv_lo: jax.Array = None,
 ) -> jax.Array:
     """Single-token batched paged attention (the decode hot loop).
 
@@ -506,6 +512,13 @@ def paged_attention_decode(
     Every impl consumes them natively — the jnp paths dequantize on
     the gather, the Pallas kernel DMAs int8 blocks + scale rows and
     fuses the multiply in VMEM (module docstring's support matrix).
+
+    kv_lo: [B] a LOWER bound beside kv_lens: a lane attends the table's
+    positions kv_lo <= pos < kv_lens.  What a window layer's ring needs
+    when it is handed over as a table, oldest live block first
+    (ops/window_attention.ring_decode_table): the cells of that block
+    that have left the window are masked.  Both impls take it; without
+    it every caller's program is the one it was.  Not carried under tp.
     """
     tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
     impl = resolve_decode_impl(impl, jax.default_backend(),
@@ -514,6 +527,8 @@ def paged_attention_decode(
     if impl in PALLAS_IMPLS:
         interpret = impl == "pallas_interpret"
         if tp > 1:
+            if kv_lo is not None:
+                raise NotImplementedError("kv_lo under tp > 1")
             return _decode_pallas_tp(
                 q, k_cache, v_cache, layer, block_tables, kv_lens,
                 mesh=mesh, interpret=interpret,
@@ -524,6 +539,7 @@ def paged_attention_decode(
         return paged_attention_decode_pallas(
             q, k_cache, v_cache, layer, block_tables, kv_lens,
             interpret=interpret, k_scale=k_scale, v_scale=v_scale,
+            kv_lo=kv_lo,
         )
     if impl not in ("jnp", "jnp_bf16"):
         raise ValueError(
@@ -533,5 +549,5 @@ def paged_attention_decode(
     return paged_attention_decode_jnp(
         q, k_cache, v_cache, layer, block_tables, kv_lens,
         native_dtype=(impl == "jnp_bf16"),
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=k_scale, v_scale=v_scale, kv_lo=kv_lo,
     )

@@ -87,13 +87,18 @@ def _packed_kernel(
     pos_ref,       # [TB, 1] int32 absolute position per query
     q_ref,         # [TB, G * hd] this tile's queries of one KV head's
                    #   group, pre-scaled, a head every hd lanes
-    *rest,         # cc K blocks [hd, bs], cc V blocks (+ cc + cc scale
-                   #   rows [1, bs] when quantized), o_ref, m, l, acc
+    *rest,         # (`banded`: lo_ref [TB, 1] int32, the first position
+                   #   a query keeps,) cc K blocks [hd, bs], cc V blocks
+                   #   (+ cc + cc scale rows [1, bs] when quantized),
+                   #   o_ref, m, l, acc
     G: int,
     cc: int,
     n_c: int,
     quantized: bool,
+    banded: bool = False,
 ):
+    if banded:
+        lo_ref, rest = rest[0], rest[1:]
     k_refs, v_refs = rest[:cc], rest[cc:2 * cc]
     rest = rest[2 * cc:]
     if quantized:
@@ -133,6 +138,8 @@ def _packed_kernel(
             span = (j % n_c) * tk + jax.lax.broadcasted_iota(
                 jnp.int32, (TB, tk), 1)
             keep = (seg_ref[...] == j // n_c) & (span <= pos_ref[...])
+            if banded:
+                keep = keep & (span >= lo_ref[...])
         for g in range(G):   # the group's heads share the key tile
             sc = jnp.dot(q_ref[:, g * hd:(g + 1) * hd], k,
                          preferred_element_type=jnp.float32)
@@ -188,6 +195,13 @@ def packed_prefill_attention_pallas(
     interpret: bool = False,
     k_scale: jax.Array = None,  # [L, nkv, num_blocks, bs] fp32 (int8)
     v_scale: jax.Array = None,
+    lower: jax.Array = None,    # [T] int32: a query keeps its row's
+                                #   positions lower <= pos <= its own (a
+                                #   band: ops/window_attention.py); key
+                                #   tiles wholly under a query tile's
+                                #   bounds are skipped like those above
+                                #   its frontier.  None: the program is
+                                #   the one it was
 ) -> jax.Array:
     """The packed stream's attention as one kernel a layer
     (packed_prefill.packed_prefill_attention's "pallas" /
@@ -217,10 +231,13 @@ def packed_prefill_attention_pallas(
     # their behalf
     seg_eff = jnp.where(valid, seg_ids, -1).astype(jnp.int32)
     positions = positions.astype(jnp.int32)
+    banded = lower is not None
     if Tp > T:
         seg_eff = jnp.pad(seg_eff, (0, Tp - T), constant_values=-1)
         positions = jnp.pad(positions, (0, Tp - T))
         q = jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0)))
+        if banded:
+            lower = jnp.pad(lower, (0, Tp - T))
 
     # which (query tile, key tile) pairs run: a segment row's key tiles
     # up to the farthest position one of the tile's queries of that row
@@ -238,6 +255,16 @@ def packed_prefill_attention_pallas(
     first = jnp.arange(n_c, dtype=jnp.int32) * tk     # a tile's first key
     runs = first[None, None, :] <= far[:, :, None]
     whole = first[None, None, :] + tk - 1 <= near[:, :, None]
+    if banded:
+        # the band's other edge: no tile that ends under the nearest
+        # bound of the row's queries, no mask only from the farthest
+        lower = lower.astype(jnp.int32)
+        lo2d = lower.reshape(n_q, TB)
+        big = jnp.iinfo(jnp.int32).max
+        lo_near = jnp.min(jnp.where(owned, lo2d[:, None, :], big), axis=2)
+        lo_far = jnp.max(lo2d, axis=1)[:, None]
+        runs = runs & (first[None, None, :] + tk - 1 >= lo_near[:, :, None])
+        whole = whole & (first[None, None, :] >= lo_far[:, :, None])
     flags = (runs.astype(jnp.int32) + (runs & whole)).reshape(n_q, n_kt)
     # a step that is skipped names the blocks the pipeline already holds
     # (the last pair that ran, or the first that will): nothing is
@@ -268,10 +295,12 @@ def packed_prefill_attention_pallas(
     # table's physical ids: no gathered copy of the context exists
     plane = [pl.BlockSpec((None, None, None, hd, bs), block_of(b))
              for b in range(cc)]
-    inputs = [seg_eff[:, None], positions[:, None], qs] \
+    bound = [lower[:, None]] if banded else []
+    inputs = [seg_eff[:, None], positions[:, None], qs] + bound \
         + [k_cache] * cc + [v_cache] * cc
     in_specs = [pl.BlockSpec((TB, 1), row), pl.BlockSpec((TB, 1), row),
-                pl.BlockSpec((TB, G * hd), heads)] + plane + plane
+                pl.BlockSpec((TB, G * hd), heads)] \
+        + [pl.BlockSpec((TB, 1), row)] * len(bound) + plane + plane
     if quantized:
         # scale rows as [.., 1, bs] planes, so a block is a whole tile
         srow = [pl.BlockSpec((None, None, None, 1, bs), block_of(b))
@@ -282,7 +311,7 @@ def packed_prefill_attention_pallas(
     pairs = Tp * n_kt * tk
     out = pl.pallas_call(
         functools.partial(_packed_kernel, G=G, cc=cc, n_c=n_c,
-                          quantized=quantized),
+                          quantized=quantized, banded=banded),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(nkv, n_q, n_kt),

@@ -234,11 +234,14 @@ def _decode_kernel(
     base_ref,     # [B] int32 chunks consumed by all earlier rows
     next_ref,     # [B] int32 next row with a chunk (-1 = none)
     layer_ref,    # [1] int32 the layer of the pool this call reads
-    # inputs
-    q_ref,        # [1, nkv, group, hd] VMEM (this sequence's query)
-    k_hbm,        # [L, nkv, num_blocks, hd, bs] ANY: the WHOLE pool,
-    v_hbm,        #   in HBM; the DMA descriptor picks layer and block
-                  #   (V may be another width: [.., hdv, bs], out hdv)
+    # `bounded` adds lo_ref [B] int32 as the LAST scalar prefetch: a
+    # lane attends positions lo <= pos < kv_len of its table (a ring
+    # handed over oldest block first: ops/window_attention.py).  Then
+    # the inputs:
+    #   q_ref   [1, nkv, group, hd] VMEM (this sequence's query)
+    #   k_hbm   [L, nkv, num_blocks, hd, bs] ANY: the WHOLE pool,
+    #   v_hbm     in HBM; the DMA descriptor picks layer and block
+    #             (V may be another width: [.., hdv, bs], out hdv)
     # int8 caches add (ks_hbm, vs_hbm) [L, nkv, num_blocks, bs] fp32
     # ANY; `biased` adds bias_ref [1, n_chunks, 1, S] fp32 VMEM (this
     # sequence's per-position addend to the scores); then: o_ref
@@ -250,8 +253,13 @@ def _decode_kernel(
     bs: int,
     quantized: bool = False,
     biased: bool = False,
+    bounded: bool = False,
     debug_mode: str = "",  # "" | "dma_only" | "compute_only" (profiling)
 ):
+    lo_ref = None
+    if bounded:
+        lo_ref, rest = rest[0], rest[1:]
+    q_ref, k_hbm, v_hbm, *rest = rest
     bias_ref = None
     if biased:       # the last input, after the scales
         at = 2 if quantized else 0
@@ -266,6 +274,7 @@ def _decode_kernel(
     hdv = v_hbm.shape[3]
     S = bpc * bs  # positions per chunk
     kv_len = kv_lens_ref[b]
+    kv_lo = lo_ref[b] if bounded else None
     n_chunks = pl.cdiv(kv_len, S)
 
     # the chunk DMA contract (descriptor shapes, semaphore pairing, int8
@@ -338,11 +347,18 @@ def _decode_kernel(
         if biased:
             s = s + bias_ref[0, c][None]
         pos = c * S + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos < kv_len, s, NEG_INF)
+        live = pos < kv_len
+        if bounded:
+            live = live & (pos >= kv_lo)
+        s = jnp.where(live, s, NEG_INF)
 
         m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
+        if bounded:
+            # a chunk wholly under the bound leaves m_new at NEG_INF and
+            # exp(0) = 1 for every pair that is out: zero them
+            p = jnp.where(live, p, 0.0)
         l = l * alpha + jnp.sum(p, axis=2, keepdims=True)
         # out [nkv, g, hd]: p is cast to the operand dtype for the MXU
         # (standard flash practice; fp32 running accumulation keeps the
@@ -390,6 +406,13 @@ def paged_attention_decode_pallas(
                                 #   lane's scores position by position
                                 #   (NEG_INF = a token left out:
                                 #   ops/sparse_attention.py)
+    kv_lo: jax.Array = None,    # [B] int32: a lane attends the table's
+                                #   positions kv_lo <= pos < kv_lens (a
+                                #   window layer's ring, oldest block
+                                #   first: the stale cells of that block
+                                #   are masked; blocks under the bound
+                                #   are still moved, so the caller's
+                                #   table starts at the oldest live one)
 ) -> jax.Array:
     """Drop-in fast path for paged_attention.paged_attention_decode.
 
@@ -449,6 +472,12 @@ def paged_attention_decode_pallas(
         in_specs.append(pl.BlockSpec((1, n_chunks, 1, S),
                                      lambda b, *refs: (b, 0, 0, 0)))
     scratch.append(pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)))
+    bounded = kv_lo is not None
+    # with no bound the call is the one it was: five scalar operands
+    prefetch = [block_tables, kv_lens, base, next_row,
+                jnp.asarray(layer, jnp.int32).reshape(1)]
+    if bounded:
+        prefetch.append(kv_lo.astype(jnp.int32))
     # bytes per context position per head: int8 streams 1-byte elements
     # plus one fp32 scale per (head, position)
     pos_bytes = ((hd + hdv) * k_cache.dtype.itemsize
@@ -456,9 +485,9 @@ def paged_attention_decode_pallas(
     out = pl.pallas_call(
         functools.partial(_decode_kernel, bpc=bpc, bs=bs,
                           quantized=quantized, biased=bias is not None,
-                          debug_mode=debug_mode),
+                          bounded=bounded, debug_mode=debug_mode),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=len(prefetch),
             grid=(B,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, nkv, group, hdv),
@@ -476,6 +505,5 @@ def paged_attention_decode_pallas(
             transcendentals=B * nh * max_blocks * bs,
         ),
         interpret=interpret,
-    )(block_tables, kv_lens, base, next_row,
-      jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
+    )(*prefetch, *inputs)
     return out.reshape(B, nh, hdv)

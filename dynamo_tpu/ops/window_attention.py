@@ -20,19 +20,40 @@ Softmax of a window layer may carry a learned per-head SINK: one scalar
 logit a head, in the denominator only (no value): p_ij = exp(s_ij) /
 (sum_j' exp(s_ij') + exp(sink_h)).
 
-These are jnp paths.  Decode reads W blocks a lane (window-bounded,
-never lanes x table width); prefill reads the ring's last `window` cells
-once and then only the chunk itself, in tiles of `window` queries against
-2 x `window` keys, so its work is T x 2 x window and not T x T.
+`window_prefill_attention` / `window_decode_attention` are jnp paths for
+a window of about a block (K and V of any widths, a sink).  Decode reads
+W blocks a lane (window-bounded, never lanes x table width); prefill
+reads the ring's last `window` cells once and then only the chunk
+itself, in tiles of `window` queries against 2 x `window` keys, so its
+work is T x 2 x window and not T x T.
+
+A window of many blocks (4096 tokens over 33 blocks a lane) goes through
+the two kernels the paged pools have, the ring seen AS A BLOCK TABLE of
+period W (`ring_table`: column c of lane b is block 1 + b W + c % W):
+
+  * writes: `paged_attention.write_token_kv` and
+    `packed_prefill.write_packed_kv` over `ring_table`, in the pool's
+    resident layout (same cells as `write_ring_token` /
+    `write_ring_prompt`);
+  * decode: `ring_decode_table` cuts the table to the W columns from
+    the oldest live block and gives `paged_attention_decode` a lower
+    bound beside the length (`kv_lo`): live blocks only, the stale cells
+    of the oldest one masked;
+  * prefill: `window_prefill_flash` lays [the ring's last `window` cells
+    || the chunk's own K/V] out as a small pool of its own and hands it
+    to `packed_prefill_attention` with a lower bound a query (`lower`):
+    flash over the band, key tiles outside it skipped, no score block.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from .packed_prefill import packed_prefill_attention, resolve_packed_impl
 from .paged_attention import NEG_INF, _gather_ctx, _store_kv
 
 
@@ -49,6 +70,37 @@ def ring_pool_blocks(lanes: int, window: int, block_size: int) -> int:
 
 def _ring_block(lane, pos, W: int, bs: int):
     return 1 + lane * W + (pos // bs) % W
+
+
+def _ring_col(lanes, cols, W: int):
+    """Block of column `cols` (positions c bs .. c bs + bs - 1; any
+    integer, floor semantics) of the rings of `lanes` [B] -> [B, C]."""
+    return 1 + lanes.astype(jnp.int32)[:, None] * W + cols % W
+
+
+def ring_table(lanes, W: int, width: int):
+    """The rings of `lanes` [B] as block tables [B, width]: column c is
+    the lane's block c % W."""
+    return _ring_col(lanes, jnp.arange(width, dtype=jnp.int32)[None, :], W)
+
+
+def ring_decode_table(positions, valid, window: int, block_size: int):
+    """What `paged_attention_decode` reads a window layer's ring by; row
+    b is lane b and its token at `positions[b]` is already written.
+    -> (table [B, W] from the oldest live block on, kv_lens [B] the
+    table-relative end of the live positions (0 on an idle lane), kv_lo
+    [B] their table-relative start, inside the first block)."""
+    B = positions.shape[0]
+    W = ring_blocks(window, block_size)
+    lo = jnp.maximum(positions - window + 1, 0)
+    first = lo // block_size
+    table = _ring_col(jnp.arange(B, dtype=jnp.int32), first[:, None]
+                      + jnp.arange(W, dtype=jnp.int32)[None, :], W)
+    kv_lens = positions + 1 - first * block_size
+    if valid is not None:
+        kv_lens = jnp.where(valid, kv_lens, 0)
+    return table, kv_lens.astype(jnp.int32), \
+        (lo - first * block_size).astype(jnp.int32)
 
 
 def _sink_softmax(s: jax.Array, sink: Optional[jax.Array]) -> jax.Array:
@@ -235,3 +287,78 @@ def causal_prefill_attention(q, k_cache, v_cache, layer: int, block_table,
     out = jax.lax.map(tile, (q.reshape(T // tq, tq, nh, hd),
                              jnp.arange(T // tq, dtype=jnp.int32) * tq))
     return out.reshape(T, nh, vc.shape[-1])
+
+
+# ---------------------------------------------------------------------------
+# a window of many blocks: the packed flash forms over the band
+# ---------------------------------------------------------------------------
+
+
+def _band_block(window: int, tokens: int) -> int:
+    """Block size of the band's own pool: 128 (the kernel's lane tile)
+    where window and chunk are whole blocks of it."""
+    return math.gcd(window, tokens, 128)
+
+
+def resolve_window_prefill_impl(impl: str, platform: str, window: int,
+                                head_dim: int, dtype, tokens: int) -> str:
+    """The impl of `window_prefill_flash` for a stream of `tokens`:
+    `packed_prefill.resolve_packed_impl`'s rule over the band's own pool,
+    asked by the traced read and by the host's count alike."""
+    return resolve_packed_impl(impl, platform, _band_block(window, tokens),
+                               head_dim, dtype, tokens)
+
+
+@jax.named_scope("dyn.attn_window")
+def window_prefill_flash(q, k, v, k_ring, v_ring, layer: int, lanes,
+                         seg_ids, positions, valid, window: int,
+                         impl: str = "auto") -> jax.Array:
+    """A packed stream's window read, BEFORE the chunk is written to the
+    rings (a chunk may overwrite cells its first queries still see).
+    q [T, nh, hd], the stream's own k / v [T, nkv, hd], the ring pools,
+    lanes [S] the lane of each segment row; the stream's contract is
+    packed_prefill's (a row is one run at consecutive positions).
+
+    Row s's keys are laid out as blocks of a pool of their own: the
+    `window` cells before the row's first position (W ring blocks from
+    the one that holds position ctx - window, cut at that cell), then the
+    stream rolled so that the row's first token follows them.  Cell r of
+    the row is position ctx - window + r, so a query at position p reads
+    cells [max(p - ctx + 1, window - ctx), p - ctx + window]: a band,
+    given to `packed_prefill_attention` as `lower`.  -> [T, nh, hd]."""
+    T, nh, hd = q.shape
+    S = lanes.shape[0]
+    nkv, bs = k_ring.shape[1], k_ring.shape[4]
+    W = ring_blocks(window, bs)
+    tb = _band_block(window, T)
+    nb = (window + T) // tb
+    rows = jnp.arange(S, dtype=jnp.int32)
+    own = valid[None, :] & (seg_ids[None, :] == rows[:, None])   # [S, T]
+    start = jnp.argmax(own, axis=1).astype(jnp.int32)
+    ctx = positions[start].astype(jnp.int32)
+    first = (ctx - window) // bs                  # floor: may be negative
+    cut = ctx - window - first * bs               # in [0, bs)
+    ids = _ring_col(lanes, first[:, None]
+                    + jnp.arange(W, dtype=jnp.int32)[None, :], W)
+    li = jnp.int32(layer)
+
+    def pool(ring, cur):
+        g = ring[li, :, ids]                      # [S, W, nkv, hd, bs]
+        g = g.transpose(0, 2, 3, 1, 4).reshape(S, nkv, cur.shape[-1], W * bs)
+        tail = jax.vmap(lambda x, c: jax.lax.dynamic_slice_in_dim(
+            x, c, window, axis=2))(g, cut)
+        cur = cur.astype(ring.dtype).transpose(1, 2, 0)          # [nkv, hd, T]
+        own_k = jax.vmap(lambda s0: jnp.roll(cur, -s0, axis=2))(start)
+        ext = jnp.concatenate([tail, own_k], axis=3)
+        ext = ext.reshape(S, nkv, cur.shape[1], nb, tb)
+        return ext.transpose(1, 0, 3, 2, 4).reshape(
+            1, nkv, S * nb, cur.shape[1], tb)
+
+    tables = rows[:, None] * nb + jnp.arange(nb, dtype=jnp.int32)[None, :]
+    rel = positions - ctx[seg_ids] + window
+    lower = jnp.maximum(rel - window + 1, window - ctx[seg_ids])
+    impl = resolve_window_prefill_impl(impl, jax.default_backend(), window,
+                                       hd, k_ring.dtype, T)
+    return packed_prefill_attention(
+        q, pool(k_ring, k), pool(v_ring, v), 0, tables, seg_ids, rel, valid,
+        impl=impl, lower=lower)

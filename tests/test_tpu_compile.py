@@ -855,3 +855,151 @@ def test_ssm_decode_and_prefill_compile_for_v5e(topo, one_chip):
     assert f"bf16[16,{T},1856]" not in hlo \
         and f"bf16[16,1856,{T}]" not in hlo
     assert program.memory_analysis().temp_size_in_bytes < 3.0e9
+
+
+def test_window_ring_decode_and_prefill_compile_for_v5e(topo, one_chip,
+                                                        capsys):
+    """The window + NoPE-global family (models/cohere2.py) at Command
+    A+'s published widths (128 heads over 8 KV heads of 128, 16 of 128
+    experts of 4096 held, 4 shared), cut to one period of four layers
+    and a vocabulary of 32768, with the long-context cell's cache (8
+    lanes, 1593 blocks, tables of 199; rings of 33 blocks a lane) and
+    `auto` resolved as on the chip: a fused decode burst of the engine's
+    own program and a 2048-token packed prefill chunk.  Both kinds of
+    layer read through the paged pools' kernels: one custom call a layer
+    in the burst (the ring handed over as a table with a lower bound),
+    and in the chunk one a layer beside the experts' three grouped
+    matmuls; the global pool and the ring pool keep their resident
+    layout and are never copied, relaid or sliced; nothing of a score
+    block's size is left in float32."""
+    import re
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.models import cohere2
+    from dynamo_tpu.ops.paged_attention import resolve_decode_impl
+    from dynamo_tpu.ops.window_attention import resolve_window_prefill_impl
+
+    NB, B, MB, K, T, L = 1593, 8, 199, 8, 2048, 4
+    impl = resolve_decode_impl("auto", topo.devices[0].platform, BS, 128,
+                               jnp.bfloat16)
+    assert impl == "pallas"
+    assert resolve_window_prefill_impl(
+        "auto", topo.devices[0].platform, 4096, 128, jnp.bfloat16,
+        T) == "pallas"
+    cfg = dataclasses.replace(
+        cohere2.PRESETS["command-a-plus"], n_layers=L,
+        layer_kinds=(1, 1, 1, 0), experts_held=(0, 16), vocab_size=32768,
+        attn_impl=impl, packed_attn_impl="pallas")
+    S = _sds(one_chip)
+    shapes = jax.eval_shape(
+        lambda: cohere2.init_params(cfg, jax.random.PRNGKey(0)))
+    assert shapes["layers"][0]["moe_w_up"].shape == (16, 4096, 4096)
+    assert shapes["layers"][0]["shared"]["w_down"].shape == (16384, 4096)
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    kv = tuple(S(s, d) for s, d in zip(
+        cohere2.kv_cache_shapes(cfg, NB, BS, lanes=B),
+        cohere2.kv_cache_dtypes(cfg)))
+    assert kv[0].shape == (1, 8, NB, 128, BS)
+    assert kv[2].shape == (3, 8, 1 + 33 * B, 128, BS)
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+
+    def pools_stay(hlo):
+        _assert_pool_stays_where_it_lies(hlo, 1, 8, NB, 128)
+        _assert_pool_stays_where_it_lies(hlo, 3, 8, 1 + 33 * B, 128)
+
+    def report(what, program):
+        mem = program.memory_analysis()
+        with capsys.disabled():
+            print(f"\ncohere2 {what}: arguments "
+                  f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+                  f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+        return mem
+
+    fn = jax.jit(
+        partial(JaxEngine._decode_multi_impl, cohere2, cfg, None, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9))
+    lowered = fn.lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32))
+    assert lowered.out_info[0].shape == (K + len(cohere2.KV_COUNTERS), B)
+    program = lowered.compile()
+    hlo = program.as_text()
+    assert hlo.count("tpu_custom_call") == L
+    pools_stay(hlo)
+    # a decode step keeps the dense form: every held expert for every lane
+    assert f"bf16[16,{B},4096]" in hlo or f"bf16[16,4096,{B}]" in hlo
+    mem = report("decode burst", program)
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+    pre = jax.jit(
+        partial(JaxEngine._prefill_packed_impl, cohere2, cfg, None),
+        donate_argnums=(1,))
+    program = pre.lower(
+        params, kv, S((T,), i32), S((T,), i32), S((T,), i32),
+        S((1, MB), i32), S((1,), i32), S((T,), b1), S((1,), i32),
+        S((1,), f32), S((1,), i32), S((1,), f32), None, None,
+        S((1,), i32)).compile()
+    hlo = program.as_text()
+    assert hlo.count("tpu_custom_call") == L + 3 * L
+    pools_stay(hlo)
+    assert f"bf16[16,{T},4096]" not in hlo
+    # nothing the size of a score block ([2048, 128, 256] and up) but
+    # the grouped dispatch's own combine of a token's 8 picks
+    score = T * 128 * 256
+    sized = {m for m in re.findall(r"f32\[([\d,]+)\]", hlo)
+             if len(m.split(",")) >= 3
+             and math.prod(int(d) for d in m.split(",")) >= score}
+    assert sized <= {f"{T},8,4096"}, sized
+    mem = report("2048-token prefill", program)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_the_bounds_are_one_more_operand_each(one_chip):
+    """The decode kernel's `kv_lo` and the packed kernel's `lower`
+    compile for v5e, and each is ONE more operand of its custom call:
+    without it a caller's call is the one it was (five scalar operands
+    and q, K, V in decode; four scalars, the two planes, q and 2 x 8
+    block operands in prefill).  (The whole programs of Mistral, MiMo,
+    Keye, Ling, Moonlight and Nemotron that hold these kernels were
+    compared with the parent's as traced, kernels' bodies included:
+    PERF.md section 6, PR 42.)"""
+    import re
+
+    from dynamo_tpu.ops.pallas_packed_prefill import (
+        packed_prefill_attention_pallas,
+    )
+    from dynamo_tpu.ops.pallas_paged_attention import (
+        paged_attention_decode_pallas,
+    )
+
+    nkv, nh, hd = WIDTHS["llama-8b"]
+    S = _sds(one_chip)
+    L, NB, MB, B, T = 2, 64, 16, 8, 2048
+    cache = S((L, nkv, NB, hd, BS), jnp.bfloat16)
+    i32 = jnp.int32
+
+    def operands(lowered):
+        hlo = lowered.compile().as_text()
+        call = re.search(r"custom-call\((.*?)\), custom_call_target="
+                         r"\"tpu_custom_call\"", hlo).group(1)
+        return call.count("%")
+
+    decode = partial(paged_attention_decode_pallas, layer=1)
+    args = (S((B, nh, hd), jnp.bfloat16), cache, cache)
+    kw = dict(block_tables=S((B, MB), i32), kv_lens=S((B,), i32))
+    free = operands(jax.jit(decode).lower(*args, **kw))
+    assert free == 5 + 3
+    assert operands(jax.jit(decode).lower(
+        *args, **kw, kv_lo=S((B,), i32))) == free + 1
+    packed = partial(packed_prefill_attention_pallas, layer=1)
+    args = (S((T, nh, hd), jnp.bfloat16), cache, cache)
+    kw = dict(block_tables=S((1, MB), i32), seg_ids=S((T,), i32),
+              positions=S((T,), i32), valid=S((T,), jnp.bool_))
+    free = operands(jax.jit(packed).lower(*args, **kw))
+    assert free == 4 + 3 + 2 * 8
+    assert operands(jax.jit(packed).lower(
+        *args, **kw, lower=S((T,), i32))) == free + 1
