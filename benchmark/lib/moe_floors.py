@@ -16,6 +16,8 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
+from .roofline import step_weight_bytes
+
 
 def decode_bytes(steps: float, experts_visited: float,
                  global_block_steps: float, window_block_steps: float, *,
@@ -28,8 +30,9 @@ def decode_bytes(steps: float, experts_visited: float,
     matrices for each (step, layer, held expert) that a token visited;
     the cache blocks the masks need, a kind's blocks a layer of that
     kind (block counts are summed over steps and lanes, one layer)."""
-    return (steps * dense_weight_bytes
-            + experts_visited * expert_bytes
+    return (step_weight_bytes(steps, experts_visited,
+                              dense_weight_bytes=dense_weight_bytes,
+                              expert_bytes=expert_bytes)
             + global_block_steps * global_layers * global_block_bytes
             + window_block_steps * window_layers * window_block_bytes)
 

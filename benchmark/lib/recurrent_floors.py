@@ -20,6 +20,7 @@ from __future__ import annotations
 from typing import Any, Dict
 
 from .moe_floors import causal_pairs  # noqa: F401  (an MLA layer's pairs)
+from .roofline import step_weight_bytes
 
 
 def live_latent_tokens(live_blocks: float, lane_steps: float,
@@ -43,8 +44,9 @@ def decode_bytes(steps: float, experts_visited: float, lane_steps: float,
     read and written once and the convolution's tail with it; and the
     MLA layers' latent and rope key of each live token (`latent_tokens`
     is of one layer, `latent_token_bytes` of all of them)."""
-    return (steps * dense_weight_bytes
-            + experts_visited * expert_bytes
+    return (step_weight_bytes(steps, experts_visited,
+                              dense_weight_bytes=dense_weight_bytes,
+                              expert_bytes=expert_bytes)
             + lane_steps * lane_step_bytes
             + latent_tokens * latent_token_bytes)
 

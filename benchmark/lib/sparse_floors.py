@@ -18,6 +18,8 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
+from .roofline import step_weight_bytes
+
 
 def decode_bytes(steps: float, experts_visited: float, ctx_tokens: float,
                  selected_tokens: float, *, dense_weight_bytes: float,
@@ -29,8 +31,9 @@ def decode_bytes(steps: float, experts_visited: float, ctx_tokens: float,
     and in every layer one index key for each token the indexer must
     score and K and V of each token it keeps (token counts are summed
     over steps and lanes, one layer)."""
-    return (steps * dense_weight_bytes
-            + experts_visited * expert_bytes
+    return (step_weight_bytes(steps, experts_visited,
+                              dense_weight_bytes=dense_weight_bytes,
+                              expert_bytes=expert_bytes)
             + layers * (ctx_tokens * index_key_bytes
                         + selected_tokens * kv_token_bytes))
 

@@ -22,6 +22,7 @@ from typing import Any, Dict
 
 from .moe_floors import causal_pairs  # noqa: F401  (an attention block's pairs)
 from .recurrent_floors import live_latent_tokens as live_tokens  # noqa: F401
+from .roofline import step_weight_bytes
 
 
 def decode_bytes(steps: float, experts_visited: float, lane_steps: float,
@@ -35,8 +36,9 @@ def decode_bytes(steps: float, experts_visited: float, lane_steps: float,
     block read and written once and the convolution's tail with it; and
     the attention blocks' K and V of each live token (`kv_tokens` is of
     one block, `kv_token_bytes` of all of them)."""
-    return (steps * dense_weight_bytes
-            + experts_visited * expert_bytes
+    return (step_weight_bytes(steps, experts_visited,
+                              dense_weight_bytes=dense_weight_bytes,
+                              expert_bytes=expert_bytes)
             + lane_steps * lane_step_bytes
             + kv_tokens * kv_token_bytes)
 

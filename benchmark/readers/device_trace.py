@@ -2,7 +2,7 @@
 (benchmark/lib/trace_reduce.py), combined with counts over the same
 stretch and the roofline floors of benchmark/lib/roofline.py."""
 
-from benchmark.lib.roofline import causal_attention_flops, decode_step_bytes
+from benchmark.lib.roofline import causal_attention_flops, decode_bytes
 from benchmark.lib.stats import mean_live_context, overlap
 
 
@@ -24,6 +24,13 @@ def _decode_steps(ctx):
                if r["kind"] == "decode" and t0 <= r["t"] < t1)
 
 
+def _traced(ctx, key):
+    """The counter's growth while the trace ran; None where the program
+    has no such counter."""
+    a, b = ctx["trace_counters"]
+    return b[key] - a.get(key, 0) if key in b else None
+
+
 def idle_share(ctx):
     """100 * (1 - union of device-op intervals / traced stretch), both by
     the trace's own clock."""
@@ -40,17 +47,29 @@ def module_ms_per_decode_step(ctx, kind):
 
 
 def decode_hbm_share(ctx, kind):
-    """100 * bytes the decode steps had to read (weights once a step +
-    the live context's cache) / device time of the decode modules / peak
-    HBM bytes/s."""
+    """100 * bytes the decode steps had to read / device time of the
+    decode modules / peak HBM bytes/s.  The bytes: every weight outside
+    the embedding and the routed experts once a step, an expert's
+    matrices for each (step, layer, held expert) that a token visited
+    (the growth of `moe_experts_visited.decode` over the stretch), and
+    the live context's cache.  A configuration without experts reads
+    the first and the last; one WITH experts whose program does not
+    count the visits gives nothing to read: None, never the number that
+    counts every expert."""
     s = _module_seconds(ctx, kind)
     steps = _decode_steps(ctx) if s is not None else 0
     if not steps:
         return None
     r = ctx["roofline"]
+    visited = (_traced(ctx, "moe_experts_visited.decode")
+               if r["expert_bytes"] else 0)
+    if visited is None:
+        return None
     live = mean_live_context(ctx["records"], *ctx["trace_window"])
-    need = steps * decode_step_bytes(r["weight_bytes"],
-                                     r["kv_bytes_per_token"], live)
+    need = decode_bytes(steps, visited, live,
+                        dense_weight_bytes=r["dense_weight_bytes"],
+                        expert_bytes=r["expert_bytes"],
+                        kv_bytes_per_token=r["kv_bytes_per_token"])
     return 100.0 * need / s / ctx["peaks"]["hbm_bytes_per_s"]
 
 

@@ -7,14 +7,8 @@ gives nothing to read: None, and the metric is left out."""
 
 from benchmark.lib import moe_floors
 from benchmark.lib.stats import overlap
-from benchmark.readers.device_trace import _decode_steps, _module_seconds
-
-
-def _traced(ctx, key):
-    """The counter's growth while the trace ran; None where the program
-    has no such counter."""
-    a, b = ctx["trace_counters"]
-    return b[key] - a.get(key, 0) if key in b else None
+from benchmark.readers.device_trace import (_decode_steps, _module_seconds,
+                                            _traced)
 
 
 def decode_hbm_share(ctx, kind, dense_weight_bytes, expert_bytes,

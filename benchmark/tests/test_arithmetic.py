@@ -132,7 +132,8 @@ def test_trace_readers_on_hand_trace():
                               {"prefill_tokens": 3000}],
            "records": [], "peaks": {"hbm_bytes_per_s": 1e9,
                                     "bf16_flops": 1e12},
-           "roofline": {"weight_bytes": 1e6, "kv_bytes_per_token": 10.0,
+           "roofline": {"weight_bytes": 1e6, "dense_weight_bytes": 1e6,
+                        "expert_bytes": 0.0, "kv_bytes_per_token": 10.0,
                         "matmul_flops_per_token": 1e6,
                         "attn_pair_flops": 8.0, "n_layers": 2}}
     assert device_trace.idle_share(ctx) == pytest.approx(50.0)
@@ -180,7 +181,14 @@ def test_roofline_counts():
     # wq 64 + gate 32 + 2 of 4 experts x 128
     assert roofline.matmul_flops_per_token(params, 2) == 2 * (64 + 32 + 256)
     assert roofline.kv_bytes_per_token([(2, 1, 1, 8, 16)] * 2, 16, 2) == 64
-    assert roofline.decode_step_bytes(1000.0, 64.0, 10.0) == 1640.0
+    # the two parts: all but embedding and the expert stack; one expert
+    dense, expert = roofline.weight_parts(params)
+    assert dense == (800 + 64 + 32) * 2 + 8 * 4 * 2
+    assert expert == 8 * 16 * 2
+    assert dense + 4 * expert == roofline.weight_bytes_per_step(params)
+    assert roofline.decode_bytes(
+        1, 0, 10.0, dense_weight_bytes=1000.0, expert_bytes=0.0,
+        kv_bytes_per_token=64.0) == 1640.0
     assert roofline.causal_attention_flops(3, 8.0, 2) == 8 * 2 * 6
 
 
