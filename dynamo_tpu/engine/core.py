@@ -719,6 +719,11 @@ class JaxEngine:
             # (its KV_COUNTERS: moe_picks_held.*, moe_experts_visited.*)
             "moe_picks.prefill": 0, "moe_picks.decode": 0,
             "moe_expert_slots.decode": 0,
+            # the expert slots of decode steps whose program took the
+            # dropless dispatch's visited form (llama.moe_form: by
+            # shape), which reads the visited experts only;
+            # / moe_expert_slots.decode says how often it engaged
+            "moe_visited_form_slots.decode": 0,
             # prompt tokens whose program took the dropless dispatch's
             # grouped form (llama.moe_form: by shape);
             # / prefill_tokens says how often it engaged
@@ -3762,6 +3767,10 @@ class JaxEngine:
         layers, picks, held = self._moe
         self.metrics["moe_picks.decode"] += k * len(ctx) * layers * picks
         self.metrics["moe_expert_slots.decode"] += k * layers * held
+        if layers and moe_form(self.model_cfg,
+                               self.config.max_num_seqs) == "visited":
+            self.metrics["moe_visited_form_slots.decode"] += \
+                k * layers * held
         if hasattr(self.family, "decode_block_counts"):
             # more than one kind of layer, or keys that are chosen: the
             # family counts its own

@@ -18,8 +18,9 @@ Per layer (all alike):
   * FFN: softmax router over `n_experts`, the top `experts_per_token`
     renormalised (llama `_moe_router`), SwiGLU experts `moe_ffn_dim`
     wide of which this program holds `experts_held` = (first, count)
-    (llama `moe_dispatch`: dense form for a decode step, grouped for a
-    prompt-sized chunk); what the absent experts would add is left out.
+    (llama `moe_dispatch`: the visited form for a decode step, grouped
+    for a prompt-sized chunk); what the absent experts would add is left
+    out.
 
 Cache (models/__init__.py): four members, (k, v, index keys,
 counters).  The first three are paged by the sequence's ONE block

@@ -69,12 +69,14 @@ class LlamaConfig:
     #                output regardless of chunking/co-batch), which prefix
     #                caching and greedy determinism rely on.  The FORM is
     #                the program's choice by shape (moe_dispatch_form):
-    #                few tokens (every decode step, the small prefill
-    #                buckets) multiply every token with every held expert
-    #                and mask the combine — both forms read each visited
-    #                expert's weights once and that read is the cost
-    #                there; prompt-sized inputs sort their picks by expert
-    #                and multiply each token with its own experts only.
+    #                few tokens (every decode step, the prefill buckets
+    #                to 256 tokens) multiply every token with every
+    #                VISITED expert and mask the combine, in one kernel
+    #                that reads no expert nobody picked — the weights'
+    #                read is the cost there; prompt-sized inputs sort
+    #                their picks by expert and multiply each token with
+    #                its own experts only; what lies between multiplies
+    #                every token with every held expert.
     #   "capacity" — GShard capacity dispatch: tokens over an expert's
     #                C = ceil(T*k/E * capacity_factor) are dropped.  k/E
     #                of the FLOPs, but outputs vary with batch shape; use
@@ -427,18 +429,41 @@ def _expert_hidden(layer, cfg, mm) -> jax.Array:
     return act(mm(layer["moe_w_up"]))
 
 
-def moe_held_counts(cfg, top_e: jax.Array, valid: Optional[jax.Array]):
-    """(picks that fell on a held expert, held experts with a token), two
-    int32 scalars over the valid rows of top_e [T, k]: what a family
-    that holds a share of its experts counts on the device
-    (`KV_COUNTERS`)."""
+def _held_picks(cfg, top_e: jax.Array, valid: Optional[jax.Array]):
+    """(on [T, k] bool: the picks of valid rows that fall on a held
+    expert; seen [held] bool: the held experts such a pick visits)."""
     first, count = experts_held(cfg)
     on = (top_e >= first) & (top_e < first + count)
     if valid is not None:
         on = on & valid[:, None]
     seen = jnp.zeros((count,), bool).at[
         jnp.where(on, top_e - first, count)].set(True, mode="drop")
+    return on, seen
+
+
+def moe_held_counts(cfg, top_e: jax.Array, valid: Optional[jax.Array]):
+    """(picks that fell on a held expert, held experts with a token), two
+    int32 scalars over the valid rows of top_e [T, k]: what a family
+    that holds a share of its experts counts on the device
+    (`KV_COUNTERS`)."""
+    on, seen = _held_picks(cfg, top_e, valid)
     return jnp.sum(on, dtype=jnp.int32), jnp.sum(seen, dtype=jnp.int32)
+
+
+def _combine_weights(cfg, top_w: jax.Array, top_e: jax.Array,
+                     valid: Optional[jax.Array]) -> jax.Array:
+    """[T, held] in cfg.dtype: a row's routing weight for each held
+    expert, 0 where it did not pick it and for a row `valid` masks."""
+    T, E = top_e.shape[0], cfg.n_experts
+    wmat = jnp.zeros((T, E), jnp.float32).at[
+        jnp.arange(T)[:, None], top_e
+    ].set(top_w)                                       # [T, E]
+    if valid is not None:
+        wmat = wmat * valid.astype(jnp.float32)[:, None]
+    first, count = experts_held(cfg)
+    if count != E:
+        wmat = wmat[:, first:first + count]            # the held columns
+    return wmat.astype(cfg.dtype)
 
 
 @jax.named_scope("dyn.moe_dispatch")
@@ -458,20 +483,11 @@ def moe_dispatch_dense(layer, cfg: LlamaConfig, x: jax.Array,
     deployment computes the held experts' part for the tokens routed to
     them and adds nothing for the rest; with all experts held this is
     the one code path there was."""
-    T, d = x.shape
-    E = cfg.n_experts
-    wmat = jnp.zeros((T, E), jnp.float32).at[
-        jnp.arange(T)[:, None], top_e
-    ].set(top_w)                                       # [T, E]
-    if valid is not None:
-        wmat = wmat * valid.astype(jnp.float32)[:, None]
-    first, count = experts_held(cfg)
-    if count != E:
-        wmat = wmat[:, first:first + count]            # the held columns
+    wmat = _combine_weights(cfg, top_w, top_e, valid)
     h = _expert_hidden(layer, cfg,
                        lambda w: jnp.einsum("td,edf->etf", x, w))
     eout = jnp.einsum("etf,efd->etd", h, layer["moe_w_down"])
-    return jnp.einsum("etd,te->td", eout, wmat.astype(cfg.dtype))
+    return jnp.einsum("etd,te->td", eout, wmat)
 
 
 @jax.named_scope("dyn.moe_dispatch")
@@ -533,28 +549,70 @@ def moe_dispatch_capacity(layer, cfg: LlamaConfig, x: jax.Array,
 _GMM_TILE_M = 128
 
 
+# rows up to which a program's experts take the visited form: the
+# weights' read is the cost there (moe_dispatch_form)
+_VISITED_MAX_ROWS = 256
+
+
 def moe_dispatch_form(tokens: int, k: int, held: int, routed: int,
                       shards: int = 1) -> str:
     """Which form the dropless dispatch takes for a program of `tokens`
-    rows: "dense" or "grouped".  Both read every visited expert's
-    weights once; they differ in the rows they multiply: dense
-    tokens x held, grouped the picks that fall on a held expert (about
-    tokens x k x held / routed) plus up to one m-tile a group.  Grouped
-    where dense would multiply at least twice as many.
+    rows: "visited", "dense" or "grouped".  They differ in the experts
+    whose weights they read and in the rows they multiply: dense reads
+    every HELD expert's weights and multiplies tokens x held rows;
+    grouped reads the visited experts' and multiplies the picks that
+    fall on a held expert (about tokens x k x held / routed) plus up to
+    one m-tile a group; visited reads the visited experts' and
+    multiplies tokens x visited rows.  Visited up to
+    `_VISITED_MAX_ROWS` rows, where the weights' read is the cost
+    (every decode step, the prefill buckets to 256 tokens); grouped
+    where dense would multiply at least twice as many rows; dense
+    between.
 
     One expert layer on a TPU v5e, ms, dense / grouped (my chip runs,
-    PR 32): Moonlight's widths (64 experts of 2048 x 1408, top 6) T 128:
-    1.59 / 1.80, 256: 1.75 / 1.74, 512: 3.51 / 1.94, 2048: 16.88 / 3.06;
-    MiMo's (16 of 256 held, 4096 x 2048, top 8) 128: 1.19 / 1.24, 256:
-    1.42 / 1.26, 512: 2.48 / 1.48, 2048: 10.92 / 3.20.  Under about 256
-    tokens the weights' read (1.35 / 0.98 ms) is the cost of either, so
-    every decode step and the small prefill buckets keep the dense ops.
+    PR 32): Moonlight's widths (64 experts of 2048 x 1408, top 6) T 512:
+    3.51 / 1.94, 2048: 16.88 / 3.06; MiMo's (16 of 256 held, 4096 x
+    2048, top 8) 512: 2.48 / 1.48, 2048: 10.92 / 3.20.
+
+    The same at each expert cell's decode rows by the held experts
+    visited, ms, dense / grouped / visited, and the visited form's share
+    of 819 GB/s on the visited experts' bytes (my chip runs, PR 44,
+    benchmarks/bench_moe_decode.py; the kernel at `f_tile`'s width):
+      Moonlight, 16 rows, 64 held:  6: 1.485 / 0.182 / 0.160 (79 %)
+        16: 1.474 / 0.410 / 0.381 (89 %)  32: 1.479 / 0.772 / 0.749
+        (90 %)  64: 1.485 / 1.518 / 1.488 (91 %)
+      MiMo, 32 rows, 16 held:  1: 1.102 / 0.112 / 0.080 (77 %)
+        4: 1.105 / 0.316 / 0.289 (85 %)  8: 1.105 / 0.598 / 0.560 (88 %)
+        16: 1.108 / 1.146 / 1.110 (89 %)
+      Keye, 8 rows, 16 of 2048 x 768:  1: 0.211 / 0.149 / 0.018 (66 %)
+        4: 0.205 / 0.169 / 0.054 (85 %)  8: 0.204 / 0.191 / 0.103 (90 %)
+        16: 0.212 / 0.243 / 0.212 (87 %)
+      Ling, 64 rows, 16 of 2560 x 768:  1: 0.250 / 0.137 / 0.016 (88 %)
+        4: 0.261 / 0.188 / 0.076 (76 %)  8: 0.261 / 0.243 / 0.143 (80 %)
+        16: 0.266 / 0.348 / 0.272 (85 %)
+      Nemotron, 64 rows, 16 of 2688 x 1856, two matrices:  1: 0.437 /
+        0.115 / 0.041 (59 %)  4: 0.432 / 0.211 / 0.117 (83 %)  8: 0.433 /
+        0.382 / 0.225 (87 %)  16: 0.433 / 0.645 / 0.441 (88 %)
+      Command A+, 8 rows, 16 of 4096 x 4096:  1: 2.162 / 0.168 / 0.157
+        (78 %)  4: 2.156 / 0.601 / 0.553 (89 %)  8: 2.165 / 1.166 / 1.090
+        (90 %)  16: 2.166 / 2.285 / 2.150 (91 %)
+    The dense form's time does not depend on what was visited; the
+    grouped form reads the visited experts too but pays three calls and
+    a sort a layer (Keye 27 %, Ling 31 %, Nemotron 46 % of the bound at 4
+    of 16), so it is not decode's form.  With every expert visited the
+    visited form is the dense form's time to 2.5 %, and at 128 and 256
+    rows, picks drawn evenly, it is the faster one: Moonlight 128: 1.537
+    / 1.613 / 1.489, 256: 1.724 / 1.719 / 1.524; MiMo 128: 1.141 / 1.068
+    / 0.996, 256: 1.343 / 1.323 / 1.177; Nemotron 128: 0.429 / 0.619 /
+    0.441, 256: 0.507 / 0.680 / 0.479.  Hence the bound.
 
     Stacks split over devices (`shards` > 1) keep the dense form: its
-    einsums run local to each shard under GSPMD, the grouped kernel
-    would have the stacks gathered to every device first."""
+    einsums run local to each shard under GSPMD, the kernels would have
+    the stacks gathered to every device first."""
     if shards > 1:
         return "dense"
+    if tokens <= _VISITED_MAX_ROWS:
+        return "visited"
     dense_rows = tokens * held
     grouped_rows = tokens * k * held // routed + held * _GMM_TILE_M
     return "grouped" if dense_rows >= 2 * grouped_rows else "dense"
@@ -649,10 +707,61 @@ def moe_dispatch_grouped(layer, cfg: LlamaConfig, x: jax.Array,
     return jnp.einsum("tkd,tk->td", y, w)
 
 
+@partial(
+    # dynlint: disable=DYN001 kernel-level jit: engine dispatch reaches this inside already-watched programs; direct calls are bench/test-only
+    jax.jit, static_argnames=("cfg", "tile", "interpret"))
+@jax.named_scope("dyn.moe_dispatch")
+def _visited(stacks, cfg, x, top_w, top_e, valid, tile, interpret):
+    """moe_dispatch_visited over a layer's expert stacks alone, jitted
+    with the (hashable) config static: a program traces and lowers the
+    form ONCE for its expert layers and not once a layer.  A Pallas
+    call site costs about 60 ms of tracing: 11 layers x 10 programs
+    were 6 s of `setup_s` on the state-space cell (my chip runs,
+    PR 44)."""
+    from ..ops.pallas_moe_visited import moe_visited, visited_plan
+
+    def tpu(stacks, x, top_w, top_e, valid):
+        ids, n = visited_plan(_held_picks(cfg, top_e, valid)[1])
+        return moe_visited(
+            stacks, lambda refs, mm: _expert_hidden(refs, cfg, mm), x,
+            _combine_weights(cfg, top_w, top_e, valid), ids, n,
+            tile=tile, interpret=interpret)
+
+    def xla(stacks, x, top_w, top_e, valid):
+        return moe_dispatch_dense(stacks, cfg, x, top_w, top_e, valid)
+
+    if interpret:
+        return tpu(stacks, x, top_w, top_e, valid)
+    return jax.lax.platform_dependent(stacks, x, top_w, top_e, valid,
+                                      tpu=tpu, default=xla)
+
+
+def moe_dispatch_visited(layer, cfg: LlamaConfig, x: jax.Array,
+                         top_w: jax.Array, top_e: jax.Array,
+                         valid: Optional[jax.Array] = None, *,
+                         tile: Optional[int] = None,
+                         interpret: bool = False) -> jax.Array:
+    """The dropless dispatch's form for decode-sized inputs
+    (moe_dispatch_dense's contract and mathematics, its rounding points
+    too): every row through every VISITED expert, the combine weight
+    deciding, and an expert no valid row picked is never read.  One
+    Pallas call a layer (ops/pallas_moe_visited.py) that walks the
+    visited experts' ids; batch-invariant as the dense form is.
+
+    A platform rule, not a choice: the kernel on the TPU, the dense
+    einsums elsewhere (the same results over every held expert).
+    `interpret` runs the kernel under the interpreter, for the tests;
+    `tile` is the kernel's hidden tile where it is not its own choice
+    (benchmarks/bench_moe_decode.py)."""
+    stacks = {k: w for k, w in layer.items() if k.startswith("moe_w_")}
+    return _visited(stacks, cfg, x, top_w, top_e, valid, tile, interpret)
+
+
 def moe_form(cfg, tokens: int) -> str:
     """What a program of `tokens` rows runs for its routed experts:
-    "capacity", or the dropless dispatch's "dense" or "grouped" form.
-    The one rule, asked by the traced code and by the engine's counter."""
+    "capacity", or the dropless dispatch's "visited", "dense" or
+    "grouped" form.  The one rule, asked by the traced code and by the
+    engine's counters."""
     if cfg.moe_dispatch == "capacity":
         return "capacity"
     if cfg.moe_dispatch != "dense":
@@ -675,6 +784,7 @@ def moe_dispatch(layer, cfg, x: jax.Array, top_w: jax.Array,
     which the caller cannot set."""
     dispatch = {"capacity": moe_dispatch_capacity,
                 "grouped": moe_dispatch_grouped,
+                "visited": moe_dispatch_visited,
                 "dense": moe_dispatch_dense}[moe_form(cfg, x.shape[0])]
     return dispatch(layer, cfg, x, top_w, top_e, valid)
 

@@ -121,16 +121,18 @@ def test_a_row_does_not_depend_on_the_rows_beside_it(form):
 
 
 @pytest.mark.parametrize("tokens,k,held,routed,shards,form", [
-    # Moonlight: 64 of 64, top 6 — every decode step and the buckets to
-    # 256 stay dense, 512 and up group
-    (16, 6, 64, 64, 1, "dense"), (128, 6, 64, 64, 1, "dense"),
-    (256, 6, 64, 64, 1, "dense"), (512, 6, 64, 64, 1, "grouped"),
+    # Moonlight: 64 of 64, top 6 — every decode step and the buckets
+    # to 256 walk their visited experts (tests/test_moe_visited.py),
+    # 512 and up group
+    (16, 6, 64, 64, 1, "visited"), (128, 6, 64, 64, 1, "visited"),
+    (256, 6, 64, 64, 1, "visited"), (512, 6, 64, 64, 1, "grouped"),
     (2048, 6, 64, 64, 1, "grouped"),
     # MiMo's cut: 16 of 256 held, top 8
-    (32, 8, 16, 256, 1, "dense"), (256, 8, 16, 256, 1, "dense"),
+    (32, 8, 16, 256, 1, "visited"), (256, 8, 16, 256, 1, "visited"),
     (512, 8, 16, 256, 1, "grouped"), (2048, 8, 16, 256, 1, "grouped"),
     # Mixtral-shaped: 8 experts, top 2
-    (256, 2, 8, 8, 1, "dense"), (512, 2, 8, 8, 1, "grouped"),
+    (256, 2, 8, 8, 1, "visited"), (384, 2, 8, 8, 1, "dense"),
+    (512, 2, 8, 8, 1, "grouped"),
     # as many picks as experts: dense multiplies nothing it need not
     (4096, 4, 4, 4, 1, "dense"),
     # stacks split over a mesh axis: the dense form, whatever the shape
@@ -142,8 +144,10 @@ def test_the_shape_picks_the_form(tokens, k, held, routed, shards, form):
 
 def test_one_entry_point_picks_by_shape_and_keeps_capacity_apart():
     """`moe_dispatch` is what the three families call: the traced
-    program of a prompt-sized input holds grouped matmuls, a small one
-    the dense einsums; `expert_shards` > 1 keeps dense; "capacity" is
+    program of a prompt-sized input holds grouped matmuls, one between
+    the two bounds the dense einsums alone (under it the visited form's
+    kernel beside them: tests/test_moe_visited.py); `expert_shards` > 1
+    keeps dense; "capacity" is
     another mathematics and goes its own way; anything else is an
     error."""
     cfg = LlamaConfig(d_model=D, ffn_dim=F, n_experts=8, experts_per_token=2,
@@ -158,7 +162,7 @@ def test_one_entry_point_picks_by_shape_and_keeps_capacity_apart():
         return "ragged_dot" in text or "pallas_call" in text, text
 
     assert prims(cfg, 512)[0]
-    assert not prims(cfg, 256)[0]
+    assert not prims(cfg, 384)[0]
     assert not prims(dataclasses.replace(cfg, expert_shards=4), 512)[0]
     grouped, text = prims(
         dataclasses.replace(cfg, moe_dispatch="capacity"), 512)
@@ -191,7 +195,7 @@ def test_co_batched_rows_run_flattened_and_equal_their_own_programs():
             jnp.arange(T, dtype=jnp.int32), jnp.asarray(tables[i]),
             jnp.int32(0), jnp.int32(lens[i]))
         solo.append(np.asarray(lg))
-    assert moe_dispatch_form(T, 2, 8, 8) == "dense"
+    assert moe_dispatch_form(T, 2, 8, 8) == "visited"
     assert moe_dispatch_form(2 * T, 2, 8, 8) == "grouped"
     kv = (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
     blg, _ = llama.prefill_batched(

@@ -327,14 +327,40 @@ def test_prefill_packed_attends_in_the_kernel(topo, one_chip, MB):
     assert program.memory_analysis().temp_size_in_bytes < 0.44e9
 
 
+def _assert_experts_walk_the_visited_list(hlo, held, B, d, f):
+    """What a compiled decode burst may do with an expert layer
+    (models/llama.py `moe_dispatch_visited`): no value of the dense
+    form's intermediate ([held, lanes, f]: every lane through every held
+    expert), and the stacks are the kernel's operands where they lie:
+    never copied, relaid, transposed, sliced or gathered (any of which
+    would read a whole stack a step, what the form is there to avoid).
+    (A custom call may give one out: `ConcatBitcast`, the compiler's
+    prefetch of a stack that fits, Keye's 25 MB, into VMEM ahead of the
+    burst's loop, once for all its steps.)"""
+    import re
+
+    assert f"bf16[{held},{B},{f}]" not in hlo \
+        and f"bf16[{held},{f},{B}]" not in hlo
+    for shape in (rf"bf16\[{held},{d},{f}\]", rf"bf16\[{held},{f},{d}\]"):
+        made = re.findall(rf"= {shape}\S* ([\w-]+)\(", hlo)
+        # (a bitcast moves nothing: a stack that lies with the model
+        # width minor, Nemotron's, handed over transposed)
+        assert set(made) <= {"parameter", "get-tuple-element", "bitcast",
+                             "custom-call"}, (shape, sorted(set(made)))
+        for call in re.findall(rf"= {shape}\S* custom-call\(.*", hlo):
+            assert 'custom_call_target="ConcatBitcast"' in call, call[:300]
+
+
 def test_hybrid_decode_and_prefill_compile_for_v5e(one_chip):
     """The window + global family (models/mimo.py) at MiMo-V2-Flash's
     widths, cut to one global layer (dense MLP) and one window layer
     (16 of 256 experts held): a fused decode burst of the engine's own
     program, and a 512-token prefill chunk.  The global layer reads
     through the Pallas decode kernel with K 192 and V 128 wide (one
-    custom call), its pools keep their resident layout, and the counters
-    ride under the burst's tokens."""
+    custom call), its pools keep their resident layout, the expert
+    layer is one more custom call over the stacks where they lie (PR 44:
+    `_assert_experts_walk_the_visited_list`), and the counters ride
+    under the burst's tokens."""
     import re
 
     from dynamo_tpu.engine.core import JaxEngine
@@ -365,13 +391,13 @@ def test_hybrid_decode_and_prefill_compile_for_v5e(one_chip):
         S((), i32))
     assert lowered.out_info[0].shape == (K + len(mimo.KV_COUNTERS), B)
     hlo = lowered.compile().as_text()
-    assert hlo.count("tpu_custom_call") == 1
+    # the global layer's decode kernel and the expert layer's: a decode
+    # step walks the experts its lanes visited, one custom call a layer
+    assert hlo.count("tpu_custom_call") == 1 + 1
     layouts = set(re.findall(rf"bf16\[1,4,{NB},(?:192|128),{BS}\]"
                              r"(\{[\d,]+)", hlo))
     assert layouts == {"{4,3,2,1,0"}, layouts
-    # the decode half of the expert layer was not touched: every held
-    # expert meets every lane (the dense form), no grouped matmul
-    assert f"bf16[16,{B},2048]" in hlo
+    _assert_experts_walk_the_visited_list(hlo, 16, B, 4096, 2048)
     pre = jax.jit(partial(JaxEngine._prefill_impl, mimo, cfg),
                   donate_argnums=(1,))
     program = pre.lower(
@@ -392,12 +418,13 @@ def test_moonlight_prefill_groups_its_picks_and_decode_does_not(one_chip):
     the dense dispatch's intermediate has the shape of the stacks
     themselves, [64, 2048, 1408], and cannot be told from them) holds no
     value of the dense dispatch's shape ([64, 1024, 1408]: every token
-    through every expert) and three grouped matmuls; the fused decode burst
-    still holds the dense form at its 16 lanes and no kernel at all (the
+    through every expert) and three grouped matmuls; the fused decode
+    burst at its 16 lanes holds none of [64, 16, 1408] either and ONE
+    kernel, the routed layer's walk over its visited experts (PR 44; the
     config's `auto` decode read resolves on this CPU host to jnp; the
     latent kernel's program is
-    test_latent_decode_reads_the_pool_where_it_lies): the shape picks the
-    form, and the decode half of the expert layer is as it was."""
+    test_latent_decode_reads_the_pool_where_it_lies): the shape picks
+    the form."""
     import json
     from pathlib import Path
 
@@ -433,8 +460,8 @@ def test_moonlight_prefill_groups_its_picks_and_decode_does_not(one_chip):
         S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
         S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
         S((), i32)).compile().as_text()
-    assert f"bf16[64,{B},1408]" in decode
-    assert "tpu_custom_call" not in decode
+    assert decode.count("tpu_custom_call") == 1
+    _assert_experts_walk_the_visited_list(decode, 64, B, 2048, 1408)
 
 
 def test_sparse_decode_and_prefill_compile_for_v5e(one_chip):
@@ -487,11 +514,11 @@ def test_sparse_decode_and_prefill_compile_for_v5e(one_chip):
         S((), i32))
     assert lowered.out_info[0].shape == (K + len(keye.KV_COUNTERS), B)
     hlo = lowered.compile().as_text()
-    # a layer: the top-k threshold search and the decode kernel
-    assert hlo.count("tpu_custom_call") == 2 * L
+    # a layer: the top-k threshold search, the decode kernel and the
+    # experts' walk over the visited list
+    assert hlo.count("tpu_custom_call") == 3 * L
     pools_stay(hlo)
-    # a decode step keeps the dense form: every held expert, every lane
-    assert f"bf16[16,{B},768]" in hlo
+    _assert_experts_walk_the_visited_list(hlo, 16, B, 2048, 768)
     pre = jax.jit(partial(JaxEngine._prefill_packed_impl, keye, cfg, None),
                   donate_argnums=(1,))
     program = pre.lower(
@@ -594,10 +621,10 @@ def test_recurrent_decode_and_prefill_compile_for_v5e(one_chip):
     hlo = program.as_text()
     state_stays(hlo)
     _assert_state_steps_in_place(hlo, kv[2].shape, 5)
-    # a decode step keeps the dense form: every held expert, every lane
-    assert f"bf16[16,{B},768]" in hlo
-    # the state's kernel a KDA layer, the latent's two an MLA layer
-    assert hlo.count("tpu_custom_call") == 5 + 2 * 1
+    _assert_experts_walk_the_visited_list(hlo, 16, B, 2560, 768)
+    # the state's kernel a KDA layer, the latent's two an MLA layer,
+    # the visited experts' walk an expert layer
+    assert hlo.count("tpu_custom_call") == 5 + 2 * 1 + 4
     assert program.memory_analysis().temp_size_in_bytes < 0.3e9
     pre = jax.jit(partial(JaxEngine._prefill_impl, ling, cfg),
                   donate_argnums=(1,))
@@ -687,12 +714,16 @@ def test_latent_decode_reads_the_pool_where_it_lies(topo, one_chip, family):
         S((), i32))
     # (a family with a lane-addressed state adds its step's kernel: one
     # lowering, one call a state layer, test_recurrent_decode_...)
+    # (and the walk over the visited experts: one lowering too, the
+    # form is jitted on the config, and one call an expert layer; PR 44,
+    # _assert_experts_walk_the_visited_list)
     n_state = kv[2].shape[0] if lanes else 0
+    n_moe = sum("moe_w_up" in layer for layer in shapes["layers"])
     assert lowered.as_text().count("stablehlo.custom_call @tpu_custom_call") \
-        == 2 + bool(n_state)
+        == 2 + bool(n_state) + bool(n_moe)
     program = lowered.compile()
     hlo = program.as_text()
-    assert hlo.count("tpu_custom_call") == 2 * n_mla + n_state
+    assert hlo.count("tpu_custom_call") == 2 * n_mla + n_state + n_moe
     for hd in (R, dr):
         pool = rf"bf16\[{n_mla},1,{NB},{hd},{BS}\]"
         layer = rf"bf16\[(?:1,)?1,{NB},{hd},{BS}\]"
@@ -780,9 +811,12 @@ def test_ssm_decode_and_prefill_compile_for_v5e(topo, one_chip):
     state kernel, one custom call a Mamba block (PR 41:
     `_assert_state_steps_in_place`); the K/V pool goes into the decode
     kernel whole (one custom call an attention block, 2 KV heads of 128
-    under 16 query heads each); a decode step keeps the dense dispatch and the prompt
-    groups its picks in TWO grouped matmuls an expert block (a plain
-    expert has no gate matrix)."""
+    under 16 query heads each); a decode step walks the experts its
+    lanes visited, one custom call an expert block over the two stacks
+    where they lie (PR 44: the up stack, 1856 wide, lies with the model
+    width minor and the kernel takes it transposed, no copy), and the
+    prompt groups its picks in TWO grouped matmuls an expert block (a
+    plain expert has no gate matrix)."""
     import re
 
     from dynamo_tpu.engine.core import JaxEngine
@@ -837,11 +871,10 @@ def test_ssm_decode_and_prefill_compile_for_v5e(topo, one_chip):
     state_stays(hlo)
     _assert_pool_stays_where_it_lies(hlo, NA, 2, NB, 128)
     _assert_state_steps_in_place(hlo, kv[2].shape, NM)
-    # the attention blocks' kernel, the Mamba blocks' and nothing else
-    # custom: a decode step keeps the dense form, every held expert for
-    # every lane
-    assert hlo.count("tpu_custom_call") == NA + NM
-    assert f"bf16[16,1856,{B}]" in hlo or f"bf16[16,{B},1856]" in hlo
+    # the attention blocks' kernel, the Mamba blocks' and the expert
+    # blocks' walk over the visited list
+    assert hlo.count("tpu_custom_call") == NA + NM + NE
+    _assert_experts_walk_the_visited_list(hlo, 16, B, 2688, 1856)
     assert program.memory_analysis().temp_size_in_bytes < 0.3e9
     pre = jax.jit(partial(JaxEngine._prefill_impl, nh, cfg),
                   donate_argnums=(1,))
@@ -867,9 +900,9 @@ def test_window_ring_decode_and_prefill_compile_for_v5e(topo, one_chip,
     `auto` resolved as on the chip: a fused decode burst of the engine's
     own program and a 2048-token packed prefill chunk.  Both kinds of
     layer read through the paged pools' kernels: one custom call a layer
-    in the burst (the ring handed over as a table with a lower bound),
-    and in the chunk one a layer beside the experts' three grouped
-    matmuls; the global pool and the ring pool keep their resident
+    in the burst (the ring handed over as a table with a lower bound)
+    beside the experts' walk over the visited list (PR 44), and in the
+    chunk one a layer beside the experts' three grouped matmuls; the global pool and the ring pool keep their resident
     layout and are never copied, relaid or sliced; nothing of a score
     block's size is left in float32."""
     import re
@@ -927,10 +960,10 @@ def test_window_ring_decode_and_prefill_compile_for_v5e(topo, one_chip,
     assert lowered.out_info[0].shape == (K + len(cohere2.KV_COUNTERS), B)
     program = lowered.compile()
     hlo = program.as_text()
-    assert hlo.count("tpu_custom_call") == L
+    # a layer: its read and its experts' walk over the visited list
+    assert hlo.count("tpu_custom_call") == 2 * L
     pools_stay(hlo)
-    # a decode step keeps the dense form: every held expert for every lane
-    assert f"bf16[16,{B},4096]" in hlo or f"bf16[16,4096,{B}]" in hlo
+    _assert_experts_walk_the_visited_list(hlo, 16, B, 4096, 4096)
     mem = report("decode burst", program)
     assert mem.temp_size_in_bytes < 0.3e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
