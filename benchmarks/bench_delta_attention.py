@@ -9,6 +9,14 @@ as a scan).
 
     python3 benchmarks/bench_delta_attention.py [--lanes 64] [--tokens 2048]
 
+The chunked rule's KERNEL (ops/pallas_chunk_state.py, PR 45) is timed
+beside `kda_chunked` at 512 / 1024 / 2048 tokens, with each one's error
+against the token recurrence under slow decay, by
+benchmarks/bench_chunk_state.py `--kda`, as ONE program of 8 dependent
+calls: a call alone, which is what this script times, has 0.8-1.0 ms of
+dispatch and sync in it on the chip's host (`chunked_ms` 4.52 here is
+3.54 there).
+
 Prints one JSON line of milliseconds a call (device time by the host's
 clock around block_until_ready, median of 10 after 3 warm calls), the
 bytes and FLOPs the floors of benchmark/lib/recurrent_floors.py count

@@ -74,6 +74,7 @@ from ..ops.lane_state import (
     lanes_keep,
     lanes_plan,
     lanes_step,
+    resolve_chunk_impl,
     resolve_state_impl,
     rows_put,
     rows_start,
@@ -85,6 +86,7 @@ from ..ops.mla_attention import (
     mla_prefill_attention,
 )
 from ..ops.paged_attention import PALLAS_IMPLS, write_prompt_kv_batched
+from ..ops.pallas_chunk_state import kda_chunk_rows
 from ..ops.pallas_lane_state import kda_lanes_step
 from .deepseek import (
     _absorb_q,
@@ -310,17 +312,33 @@ def state_impl(cfg: LingConfig, attn_impl: str) -> str:
                               cfg.head_dim, cfg.head_dim, cfg.state_dtype)
 
 
+def chunk_impl(cfg: LingConfig, attn_impl: str, tokens: int) -> str:
+    """The impl of the chunked rule over a prefill row of `tokens`
+    tokens (the program's bucket) under `attn_impl`, by the shape's own
+    conditions (ops/lane_state.resolve_chunk_impl), asked by the traced
+    program and by the host's counts alike."""
+    return resolve_chunk_impl(attn_impl, jax.default_backend(), tokens,
+                              cfg.kda_chunk, cfg.head_dim, cfg.head_dim,
+                              cfg.state_dtype, unit=2 * cfg.kda_chunk,
+                              sub=max(cfg.kda_chunk // 4, 1))
+
+
 def prefill_token_counts(cfg: LingConfig, pos: int, chunk: int,
                          bucket: int = 0) -> Dict[str, int]:
     """Host-side counts for `chunk` prompt tokens prefilled from
-    position `pos`: tokens through the chunked rule, those of them in a
-    program that started from a carried state, rows that started from
-    zeros.  (`bucket`, the padded program's rows, is the contract's and
-    not counted here.)"""
+    position `pos` in a program of `bucket` rows: tokens through the
+    chunked rule, those of them in a program that started from a carried
+    state, rows that started from zeros; and the same tokens a KDA
+    layer, with those of them whose program ran the rule in
+    ops/pallas_chunk_state.py's kernel (`chunk_impl` of the bucket)."""
+    nk = len(cfg.layers_of(KDA))
+    kernel = chunk_impl(cfg, cfg.attn_impl, bucket) in PALLAS_IMPLS
     return {
         "recurrent_tokens.prefill": chunk,
         "recurrent_carried_tokens.prefill": chunk if pos > 0 else 0,
         "recurrent_resets": int(chunk > 0 and pos == 0),
+        "state_chunk_tokens.prefill": nk * chunk,
+        "state_chunk_kernel_tokens.prefill": nk * chunk if kernel else 0,
     }
 
 
@@ -514,6 +532,9 @@ def prefill_batched(
     scale = 1.0 / math.sqrt(cfg.head_dim)
     pool_li = _pool_index(cfg)
     picks = jnp.zeros((), jnp.int32)
+    c_impl = chunk_impl(cfg, cfg.attn_impl, T)
+    rule = dict(scale=scale, chunk=cfg.kda_chunk,
+                sub=max(cfg.kda_chunk // 4, 1))
     for li, layer in enumerate(params["layers"]):
         kind, pli = cfg.layer_kinds[li], pool_li[li]
         h = rms_norm(x, layer["attn_norm"]["norm"], cfg.rms_eps)
@@ -529,10 +550,13 @@ def prefill_batched(
                 layer["a_log"], layer["dt_bias"], cfg.kda_lower_bound)
             log_a = jnp.where(valid[..., None, None], log_a, 0.0)
             beta = jnp.where(valid[..., None], beta, 0.0)
-            o, s1 = jax.vmap(partial(kda_chunked, scale=scale,
-                                     chunk=cfg.kda_chunk,
-                                     sub=max(cfg.kda_chunk // 4, 1)))(
-                q, k, v, log_a, beta, s0)
+            if c_impl in PALLAS_IMPLS:
+                o, s1 = kda_chunk_rows(
+                    q, k, v, log_a, beta, s0, **rule,
+                    interpret=c_impl == "pallas_interpret")
+            else:
+                o, s1 = jax.vmap(partial(kda_chunked, **rule))(
+                    q, k, v, log_a, beta, s0)
             state = rows_put(state, pli, put, s1)
             tail = rows_put(tail, pli, put, t1)
             x = x + _kda_out(layer, cfg, o, g)

@@ -211,7 +211,17 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, log_a: jax.Array,
     """A row of T tokens from `state`.  q, k, log_a [T, H, dk], v
     [T, H, dv], beta [T, H], state [H, dk, dv] float32 -> (o [T, H, dv]
     float32, state after the last token).  A token with beta 0 and
-    log a 0 (padding) leaves the state as it was."""
+    log a 0 (padding) leaves the state as it was.
+
+    This is the REFERENCE form of the chunked rule, the path off the
+    chip and the path of a row under one chunk.  Where ops/lane_state.py
+    `resolve_chunk_impl` says so (a TPU, a float32 state of whole tiles,
+    a row of whole chunks) a prefill program runs the same arithmetic as
+    ONE Pallas call a layer, ops/pallas_chunk_state.py `kda_chunk_rows`,
+    which keeps a chunk's operands, everything made of them here
+    (k_seen, A, B, the inverse, the solve) and the carried state in VMEM
+    (PR 45); tests/test_chunk_state_kernel.py holds it to this form and
+    to `kda_step`."""
     T, H, dk = k.shape
     dv = v.shape[-1]
     sub = min(sub, chunk)

@@ -133,3 +133,42 @@ def lanes_step(member: jax.Array, pli: int, plan: LanePlan,
                            interpret=impl == "pallas_interpret")
     read, new = jnp_step(member[pli].astype(jnp.float32), valid=plan.valid)
     return read, member.at[pli].set(new.astype(member.dtype))
+
+
+def resolve_chunk_impl(impl: str, platform: str, tokens: int, chunk: int,
+                       dk: int, dv: int, state_dtype, *, unit: int,
+                       sub: int) -> str:
+    """`resolve_state_impl`'s twin for the PREFILL half: what the
+    family's `attn_impl` means for the chunked form of its recurrence
+    over a row of `tokens` tokens (a program's bucket) in chunks of
+    `chunk`, between `rows_start` and `rows_put`.  `dk` and `dv` are the
+    widths of a head's planes ([T, dk] and [T, dv]), `unit` the tokens
+    the kernel's body works on at a time (two of the delta rule's
+    chunks), `sub` a sub-chunk.  -> "pallas" | "pallas_interpret" |
+    "jnp".
+
+    ops/pallas_chunk_state.py's kernel (ONE call a layer over every row,
+    a chunk's operands and the carried state in VMEM) runs where a row
+    is whole chunks (at least one) of a float32 state, and where Mosaic
+    tiles what the body makes: planes of whole 128-lane tiles, a unit of
+    whole tiles (its [unit, unit] masks and transposes), and a sub-chunk
+    whose sum is a tree (a power of two).  "auto" takes it on a TPU,
+    "pallas" whatever the backend (what the engine has made of "auto" by
+    the time a program is traced; a compile for a described chip),
+    "pallas_interpret" runs it under the interpreter, which tiles
+    anything (CPU tests).  The family's jnp form a row (`jax.vmap`)
+    stays for everything else: the CPU, a bucket under one chunk (Ling's
+    32-token bucket), an explicit "jnp" (the parent's program: the A/B
+    on the chip).  On a v5e a 2048-token row of 32 heads x 128 x 128
+    takes 3.54 ms in the jnp form and 2.4 in the kernel; Ling's
+    2048-token program goes 94.97 -> 78.33 ms, its 64- to 1024-token
+    programs gain 0 to 11 % (PERF.md section 6, PR 45)."""
+    if jnp.dtype(state_dtype) != jnp.dtype(jnp.float32) \
+            or tokens < chunk or tokens % chunk or sub & (sub - 1):
+        return "jnp"
+    if impl == "pallas_interpret":
+        return impl
+    if (impl == "pallas" or (impl == "auto" and platform == "tpu")) \
+            and dk % 128 == 0 and dv % 128 == 0 and unit % 128 == 0:
+        return "pallas"
+    return "jnp"

@@ -475,6 +475,35 @@ async def test_engine_counts_the_lanes_the_state_step_moves(impl):
     await eng.close()
 
 
+async def test_prefill_through_the_chunk_kernel_gives_the_jnp_tokens():
+    """A prompt of two prefill programs (32 tokens from zeros, 18 more
+    in a bucket of 32 from the CARRIED state) with the chunked rule in
+    ops/pallas_chunk_state.py's kernel (`chunk_impl`: the bucket's own
+    conditions, asked from the host as the traced program asks them)
+    emits the tokens it emits under the jnp form, and
+    `state_chunk_kernel_tokens.prefill` counts its tokens a KDA layer
+    (all of `state_chunk_tokens.prefill`: the share reads 100 %); under
+    the jnp form it stays 0."""
+    prompt = np.random.default_rng(9).integers(
+        3, TINY.vocab_size, 50).tolist()
+    layers = len(TINY.layers_of(ling.KDA))
+    got = {}
+    for impl in ("jnp", "pallas_interpret"):
+        eng = _engine(attn_impl=impl)
+        assert ling.chunk_impl(eng.model_cfg, eng.model_cfg.attn_impl,
+                               32) == impl
+        assert ling.chunk_impl(eng.model_cfg, eng.model_cfg.attn_impl,
+                               4) == "jnp"             # under one chunk
+        got[impl] = await _generate(eng, "r", prompt, 12)
+        m = eng.metrics
+        assert m["recurrent_carried_tokens.prefill"] == 18   # two programs
+        assert m["state_chunk_tokens.prefill"] == layers * 50
+        assert m["state_chunk_kernel_tokens.prefill"] == (
+            layers * 50 if impl == "pallas_interpret" else 0)
+        await eng.close()
+    assert got["pallas_interpret"] == got["jnp"]
+
+
 def test_unsupported_features_refuse_or_fall_back():
     """Prefix caching asked for is refused at start-up (switched off,
     warned: a reused latent block says nothing of the state at its

@@ -577,7 +577,8 @@ def test_recurrent_decode_and_prefill_compile_for_v5e(one_chip):
     cache at 12 layers (1.94 GB decode, 2.25 GB prefill there; off-chip
     compiles, PR 35).  The decode burst hands the member WHOLE to the
     state kernel, one custom call a KDA layer (PR 41:
-    `_assert_state_steps_in_place`)."""
+    `_assert_state_steps_in_place`); the prefill chunk runs the chunked
+    rule in its kernel, one custom call a KDA layer (PR 45)."""
     import re
 
     from dynamo_tpu.engine.core import JaxEngine
@@ -635,8 +636,16 @@ def test_recurrent_decode_and_prefill_compile_for_v5e(one_chip):
     hlo = program.as_text()
     state_stays(hlo)
     # a prompt-sized chunk groups its picks: three grouped matmuls an
-    # expert layer, no token met every held expert
-    assert hlo.count("tpu_custom_call") == 3 * 4
+    # expert layer, no token met every held expert; and the chunked rule
+    # is ONE custom call a KDA layer (PR 45: `chunk_impl` of the bucket,
+    # ops/pallas_chunk_state.py), so nothing of the jnp form's
+    # [N, H, n, C, dk] float32 intermediates (`k_seen`: 134 MB a layer)
+    # nor its [N, H, C, C] matrices is left in the program
+    assert ling.chunk_impl(cfg, cfg.attn_impl, T) in PALLAS_IMPLS
+    assert hlo.count("tpu_custom_call") == 3 * 4 + 5
+    for lead in ("", "1,"):
+        assert f"f32[{lead}{T // 64},32,4,64,128]" not in hlo
+        assert f"f32[{lead}{T // 64},32,64,64]" not in hlo
     assert f"bf16[16,{T},768]" not in hlo
     assert program.memory_analysis().temp_size_in_bytes < 3.0e9
 
