@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Time one expert layer's dropless dispatch alone on the chip
-(models/llama.py `moe_dispatch_dense` / `moe_dispatch_grouped` /
+(models/moe.py `moe_dispatch_dense` / `moe_dispatch_grouped` /
 `moe_dispatch_visited`), at each expert cell's widths and decode rows:
 
     moonlight  16 rows, 64 of 64 experts of 2048 x 1408, top 6
@@ -94,7 +94,7 @@ def main() -> int:
     import numpy as np
 
     from benchmark.lib.peaks import device_peaks
-    from dynamo_tpu.models import llama
+    from dynamo_tpu.models import moe
     from dynamo_tpu.ops import pallas_moe_visited as pmv
     from dynamo_tpu.runtime.device import require_tpu
 
@@ -125,7 +125,7 @@ def main() -> int:
     for name in args.shapes.split(","):
         T0, d, f, held, routed, k, gated = SHAPES[name]
         cfg = Cfg(routed, k, (0, held), bf16, gated,
-                  jax.nn.silu if gated else llama.relu2)
+                  jax.nn.silu if gated else moe.relu2)
         ks = jax.random.split(jax.random.PRNGKey(1), 4)
         layer = {"moe_w_up": jax.random.normal(ks[1], (held, d, f), bf16)
                  * d ** -0.5,
@@ -169,14 +169,14 @@ def main() -> int:
             row = {"shape": name, "rows": T, "held": held,
                    "visited": visited, "kernel_tile": own,
                    "loop_ms": round(loop_ms, 4),
-                   "dense_ms": net(form(llama.moe_dispatch_dense)),
-                   "grouped_ms": net(form(llama.moe_dispatch_grouped))}
+                   "dense_ms": net(form(moe.moe_dispatch_dense)),
+                   "grouped_ms": net(form(moe.moe_dispatch_grouped))}
             for tf in tiles:
                 row[f"visited_t{tf}_ms"] = net(
-                    form(llama.moe_dispatch_visited, tile=tf))
-            a = jax.jit(form(llama.moe_dispatch_dense))(layer, x).astype(
+                    form(moe.moe_dispatch_visited, tile=tf))
+            a = jax.jit(form(moe.moe_dispatch_dense))(layer, x).astype(
                 jnp.float32)
-            b = jax.jit(form(llama.moe_dispatch_visited))(layer, x).astype(
+            b = jax.jit(form(moe.moe_dispatch_visited))(layer, x).astype(
                 jnp.float32)
             row["out_max"] = float(jnp.abs(a).max())
             row["visited_max_err"] = float(jnp.abs(a - b).max())

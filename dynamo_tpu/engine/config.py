@@ -126,8 +126,7 @@ class EngineConfig:
     # co-scheduled prompts/chunks concatenate into one padding-free token
     # stream with segment ids instead of padding each row to a bucket.
     # Auto-falls back to the padded paths for families without
-    # prefill_packed (MLA) and for capacity-dispatch MoE (segments must
-    # not share an expert-capacity pool).
+    # prefill_packed (MLA).
     prefill_packed: bool = True
     # chunk budget for one packed prefill dispatch (the chunk-budget knob:
     # bounds how long a prefill program can hold decode back, so decode

@@ -39,7 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import chaos, obs
 from ..models import get_family
-from ..models.llama import moe_form
+from ..models.moe import moe_form
 from ..parallel.mesh import MeshConfig, make_mesh, shard_params
 from ..protocols import (
     DRAIN_ABORT,
@@ -446,7 +446,7 @@ class JaxEngine:
         if moe:
             # a traced program cannot see how its arguments are laid
             # out: the devices the expert stacks are split over, read
-            # off the arrays as placed (llama.moe_dispatch_form)
+            # off the arrays as placed (moe.moe_dispatch_form)
             self.model_cfg = dataclasses.replace(
                 self.model_cfg, expert_shards=moe[0].shape[0]
                 // moe[0].sharding.shard_shape(moe[0].shape)[0])
@@ -540,16 +540,9 @@ class JaxEngine:
         ), "prefill_batched", _toks2_total)
         # packed chunked prefill (engine/prefill.py planner +
         # ops/packed_prefill.py): the padding-free multi-sequence path.
-        # Gated off for families without prefill_packed (MLA) and for
-        # capacity-dispatch MoE, whose per-sequence expert-capacity pools
-        # a packed stream would merge (the batched path vmaps per row).
+        # Gated off for families without prefill_packed (MLA).
         self._packed_prefill_ok = (
-            config.prefill_packed
-            and hasattr(self.family, "prefill_packed")
-            and not (getattr(self.model_cfg, "n_experts", 0) > 0
-                     and getattr(self.model_cfg, "moe_dispatch", "dense")
-                     == "capacity")
-        )
+            config.prefill_packed and hasattr(self.family, "prefill_packed"))
         # the jit must exist whenever the FAMILY supports packing, even
         # with packing config-disabled on this worker: a multi-host
         # follower replays whatever step kinds its leader broadcasts,
@@ -720,12 +713,12 @@ class JaxEngine:
             "moe_picks.prefill": 0, "moe_picks.decode": 0,
             "moe_expert_slots.decode": 0,
             # the expert slots of decode steps whose program took the
-            # dropless dispatch's visited form (llama.moe_form: by
+            # dropless dispatch's visited form (moe.moe_form: by
             # shape), which reads the visited experts only;
             # / moe_expert_slots.decode says how often it engaged
             "moe_visited_form_slots.decode": 0,
             # prompt tokens whose program took the dropless dispatch's
-            # grouped form (llama.moe_form: by shape);
+            # grouped form (moe.moe_form: by shape);
             # / prefill_tokens says how often it engaged
             "moe_grouped_tokens.prefill": 0,
             # prompt tokens whose packed program ran its attention in
@@ -2458,9 +2451,9 @@ class JaxEngine:
         active slot).  Default path: PACKED chunked prefill — every
         co-scheduled chunk concatenates into one padding-free token
         stream with segment ids (engine/prefill.py planner).  Families
-        without prefill_packed (and capacity-MoE configs) fall back to
-        the padded B=1 / batched programs; cold long prompts on an sp
-        mesh still take the one-shot ring program."""
+        without prefill_packed fall back to the padded B=1 / batched
+        programs; cold long prompts on an sp mesh still take the one-shot
+        ring program."""
         pslots = sorted(
             (s for s in self._slots
              if s is not None and s.prefilling and not s.pulling),
@@ -2595,7 +2588,7 @@ class JaxEngine:
     def _moe_grouped(self, tokens: int) -> bool:
         """Whether a program whose expert layers see `tokens` rows takes
         the dropless dispatch's grouped form: the rule the traced code
-        applies (llama.moe_form), asked from the host."""
+        applies (moe.moe_form), asked from the host."""
         return self._moe[0] > 0 \
             and moe_form(self.model_cfg, tokens) == "grouped"
 
@@ -2645,7 +2638,7 @@ class JaxEngine:
         self._fpm_last_prefill_t = now
         # the expert layers see the program's rows flattened: a packed
         # stream or one row is `bucket` long, co-batched rows pad to a
-        # power of two of them (llama.moe_rows)
+        # power of two of them (moe.moe_rows)
         if self._moe_grouped(bucket if packed
                              else _pow2_len(rows) * bucket):
             self.metrics["moe_grouped_tokens.prefill"] += tokens
@@ -3675,7 +3668,7 @@ class JaxEngine:
         temps = np.zeros(B, np.float32)
         top_ks = np.zeros(B, np.int32)
         top_ps = np.ones(B, np.float32)
-        valid = np.zeros(B, bool)  # padding rows must not eat MoE capacity
+        valid = np.zeros(B, bool)  # padding rows pick no expert
         for s in active:
             i = s.index
             tokens[i] = s.last_token

@@ -7,6 +7,11 @@
     kv_cache_specs()                 PartitionSpecs, one a member
     PRESETS                          name -> config
 
+What families share lives in no family's module and imports none:
+moe.py is the expert layer (the routers, the one dropless dispatch and
+the rule that picks its form), common.py the decode burst's scan, the
+one-row prefill and a layer's index inside its kind's cache members.
+
 The engine binds a family once via get_family(cfg) and never branches on
 architecture again — Llama/Qwen/Mixtral (llama.py, GQA cache), the
 DeepSeek MLA family (deepseek.py, latent cache), the window + global

@@ -15,7 +15,7 @@ Per layer, by `cfg.layer_kinds[l]` (0 global, 1 window):
     f  = sum over the token's k experts THAT THIS SHARE HOLDS of
          w_e SwiGLU_e(h)  +  (1 / n_shared) sum_j Shared_j(h)
          (sigmoid scores, the k largest, weights over their sum:
-         models/deepseek.py `_ds_router` at one group and no bias; the
+         models/moe.py `ds_router` at one group and no bias; the
          shared experts are stored as one SwiGLU n_shared x wide and
          their sum is divided by n_shared: an average)
     x' = x + a + f               attention and experts read the SAME h
@@ -25,8 +25,8 @@ Per layer, by `cfg.layer_kinds[l]` (0 global, 1 window):
 A module of its own because every program's layer body differs from
 models/mimo.py's (one norm, both branches from one `h`, one add) while
 every part it shares is imported: the routing, the expert dispatches and
-their counts (`_ds_router`, `moe_dispatch`, `moe_held_counts`,
-`experts_held`), `_mlp`, `_pool_index`, the paged and packed reads and
+their counts (models/moe.py: `ds_router`, `moe_dispatch`,
+`moe_held_counts`), `_mlp`, `pool_index`, the paged and packed reads and
 writes and the ring's addressing (ops/window_attention.py `ring_blocks`, `ring_table`).
 `_logits` is this module's own: the final norm subtracts its mean.
 
@@ -77,9 +77,9 @@ from ..ops.window_attention import (
     ring_table,
     window_prefill_flash,
 )
-from .deepseek import _ds_router
-from .llama import _mlp, moe_dispatch, moe_held_counts
-from .mimo import _pool_index
+from .common import burst_scan, pool_index, prefill_one_row
+from .llama import _mlp
+from .moe import ds_router, moe_dispatch, moe_held_counts
 
 GLOBAL, WINDOW = 0, 1
 
@@ -101,10 +101,8 @@ class Cohere2Config:
     n_experts: int = 16           # the ROUTER's width
     experts_per_token: int = 4
     experts_held: Optional[Tuple[int, int]] = None   # (first, count)
-    moe_dispatch: str = "dense"   # llama.py semantics: dense | capacity
-    moe_capacity_factor: float = 1.25
-    expert_shards: int = 1        # llama.py: set by the engine from the mesh
-    # models/deepseek.py _ds_router reads these
+    expert_shards: int = 1        # moe.py: set by the engine from the mesh
+    # models/moe.py ds_router reads these
     moe_scoring: str = "sigmoid"
     norm_topk_prob: bool = True
     n_group: int = 1
@@ -365,7 +363,7 @@ def _experts(layer, cfg: Cohere2Config, hf: jax.Array, h: jax.Array,
     with random weights the eighth and ninth of 128 scores lie close,
     and a rounded input flips picks against the float32 reference
     (PERF.md section 7t)."""
-    top_w, top_e = _ds_router(layer, cfg, hf)
+    top_w, top_e = ds_router(layer, cfg, hf)
     out = moe_dispatch(layer, cfg, h, top_w, top_e, valid)
     return (out.astype(jnp.float32)
             + _shared(layer, cfg, h).astype(jnp.float32),) \
@@ -407,7 +405,7 @@ def _forward_packed(params, cfg: Cohere2Config, kv_cache, token_ids,
     kept = valid & (positions > end[seg_ids] - cfg.sliding_window)
     # the stream between layers is float32 (section 7t, as above)
     x = params["embedding"][token_ids].astype(jnp.float32)    # [T, d]
-    pool_li = _pool_index(cfg)
+    pool_li = pool_index(cfg)
     picks = jnp.zeros((), jnp.int32)
     for li, layer in enumerate(params["layers"]):
         kind, pli = cfg.layer_kinds[li], pool_li[li]
@@ -485,23 +483,8 @@ def prefill_batched(
     return _logits(params, cfg, x[last]), kv_cache
 
 
-def prefill(
-    params: Dict[str, Any],
-    cfg: Cohere2Config,
-    kv_cache,
-    token_ids: jax.Array,      # [T_pad] int32
-    positions: jax.Array,      # [T_pad] int32
-    block_table: jax.Array,    # [max_blocks] int32
-    ctx_len: jax.Array,
-    true_len: jax.Array,
-    lanes: jax.Array = None,   # scalar: this sequence's lane
-):
-    """One sequence's chunk (llama.prefill contract): a batch of one."""
-    logits, kv_cache = prefill_batched(
-        params, cfg, kv_cache, token_ids[None], positions[None],
-        block_table[None], ctx_len[None], true_len[None],
-        None if lanes is None else lanes[None])
-    return logits[0], kv_cache
+# one sequence's chunk (llama.prefill contract): a batch of one
+prefill = prefill_one_row(prefill_batched)
 
 
 # ---------------------------------------------------------------------------
@@ -539,7 +522,7 @@ def decode(
         rings = jnp.where(valid[:, None], rings, 0)
     w_table, w_lens, w_lo = ring_decode_table(
         ctx_lens, valid, cfg.sliding_window, bs)
-    pool_li = _pool_index(cfg)
+    pool_li = pool_index(cfg)
     picks = visited = jnp.zeros((), jnp.int32)
     for li, layer in enumerate(params["layers"]):
         kind, pli = cfg.layer_kinds[li], pool_li[li]
@@ -582,19 +565,9 @@ def decode_multi(
     mesh=None,
 ):
     """num_steps fused decode steps (llama.decode_multi contract)."""
-    if sample_fn is None:
-        def sample_fn(logits, _):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    def step(kv, tokens, pos, cls):
+        return decode(params, cfg, kv, tokens, pos, block_tables, cls,
+                      valid=valid, mesh=mesh)
 
-    def body(carry, step_idx):
-        tokens, kv, pos, cls = carry
-        logits, kv = decode(params, cfg, kv, tokens, pos, block_tables,
-                            cls, valid=valid, mesh=mesh)
-        nt = sample_fn(logits, step_idx).astype(jnp.int32)
-        return (nt, kv, pos + 1, cls + 1), nt
-
-    (_, kv_cache, _, _), toks = jax.lax.scan(
-        body, (token_ids, kv_cache, positions, ctx_lens),
-        jnp.arange(num_steps), length=num_steps,
-    )
-    return toks, kv_cache
+    return burst_scan(step, kv_cache, token_ids, positions, ctx_lens,
+                      num_steps, sample_fn)

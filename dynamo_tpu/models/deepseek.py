@@ -14,7 +14,7 @@ Architecture (DeepSeek V2/V3 lineage):
     cache stores (latent, rope-key) pairs — ops/mla_attention.py.
   * DeepSeekMoE: first_k_dense dense layers, then MoE layers with
     n_shared_experts always-on dense experts plus top-k routed experts
-    (llama.py's dispatch machinery, scaled by routed_scaling_factor).
+    (models/moe.py's router and dispatch, scaled by routed_scaling_factor).
 
 Decode runs the weight-absorbed MLA formulation (never materializes
 per-head K/V), its read in the Pallas latent kernel where
@@ -48,14 +48,9 @@ from ..ops.paged_attention import (
     write_prompt_kv_batched,
     write_token_kv,
 )
-from .llama import (
-    _logits,
-    _mlp,
-    moe_dispatch,
-    moe_rows,
-    rms_norm,
-    rope,
-)
+from .common import burst_scan
+from .llama import _logits, _mlp, rms_norm, rope
+from .moe import ds_router, moe_dispatch, moe_rows
 
 
 @dataclass(frozen=True)
@@ -79,9 +74,7 @@ class DeepseekConfig:
     n_shared_experts: int = 0     # always-on experts (hidden = n * moe_ffn)
     first_k_dense: int = 1        # leading dense layers before MoE starts
     routed_scaling_factor: float = 1.0
-    moe_dispatch: str = "dense"   # llama.py semantics: dense | capacity
-    moe_capacity_factor: float = 1.25
-    expert_shards: int = 1        # llama.py: set by the engine from the mesh
+    expert_shards: int = 1        # moe.py: set by the engine from the mesh
     # router semantics (HF DeepseekV3TopkRouter / V2 MoEGate):
     #   V2 lineage: softmax scores, plain top-k, no renorm
     #   V3 lineage: sigmoid scores + e_score_correction_bias for CHOICE
@@ -302,51 +295,13 @@ def _kv_latent(layer, cfg: DeepseekConfig, x: jax.Array,
     return c, kr
 
 
-@jax.named_scope("dyn.moe_router")
-def _ds_router(layer, cfg: DeepseekConfig, x: jax.Array):
-    """DeepSeek routing -> (weights [T, k], ids [T, k]).
-
-    Mirrors HF DeepseekV3TopkRouter exactly: scores are sigmoid (V3) or
-    softmax (V2); expert CHOICE adds e_score_correction_bias and applies
-    group-limited top-k (per-group score = sum of that group's top-2),
-    but combine WEIGHTS are the raw scores of the chosen experts,
-    optionally renormalized, then scaled by routed_scaling_factor."""
-    T = x.shape[0]
-    E, k = cfg.n_experts, cfg.experts_per_token
-    logits = x.astype(jnp.float32) @ layer["moe_gate"].astype(jnp.float32)
-    if cfg.moe_scoring == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
-    else:
-        scores = jax.nn.softmax(logits, axis=-1)
-    choice = scores + layer["moe_gate_bias"] if "moe_gate_bias" in layer \
-        else scores
-    if cfg.n_group > 1:
-        g = choice.reshape(T, cfg.n_group, E // cfg.n_group)
-        if cfg.moe_scoring == "sigmoid":
-            # V3 lineage: group score = sum of the group's top-2
-            group_scores = jax.lax.top_k(g, 2)[0].sum(-1)    # [T, n_group]
-        else:
-            # V2 lineage (group_limited_greedy): group score = group max
-            group_scores = g.max(-1)
-        _, keep = jax.lax.top_k(group_scores, cfg.topk_group)
-        gmask = jnp.zeros((T, cfg.n_group), bool).at[
-            jnp.arange(T)[:, None], keep].set(True)
-        choice = jnp.where(
-            jnp.repeat(gmask, E // cfg.n_group, axis=1), choice, 0.0)
-    _, top_e = jax.lax.top_k(choice, k)                      # [T, k]
-    top_w = jnp.take_along_axis(scores, top_e, axis=1)
-    if cfg.norm_topk_prob:
-        top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-20)
-    return top_w * cfg.routed_scaling_factor, top_e
-
-
 def _ds_ffn(layer, cfg: DeepseekConfig, x: jax.Array,
             valid: Optional[jax.Array] = None) -> jax.Array:
     """Dense layer, or DeepSeekMoE = shared experts + routed experts
-    (DeepSeek routing + llama.py's dispatch over the moe_* keys)."""
+    (models/moe.py's DeepSeek routing and dispatch over the moe_* keys)."""
     if "moe_gate" not in layer:
         return _mlp(layer, x)
-    top_w, top_e = _ds_router(layer, cfg, x)
+    top_w, top_e = ds_router(layer, cfg, x)
     out = moe_dispatch(layer, cfg, x, top_w, top_e, valid)
     if "shared" in layer:
         out = out + _mlp(layer["shared"], x)
@@ -433,7 +388,7 @@ def prefill_batched(
         )(q_nope, q_rope, c, kr, block_tables, ctx_lens, true_lens)
         x = x + attn.reshape(Bp, T, -1) @ layer["wo"]
         h = rms_norm(x, layer["mlp_norm"]["norm"], cfg.rms_eps)
-        x = x + moe_rows(partial(_ds_ffn, layer, cfg), cfg, h, valid)
+        x = x + moe_rows(partial(_ds_ffn, layer, cfg), h, valid)
     last = jnp.maximum(true_lens - 1, 0)
     xl = x[jnp.arange(Bp), last]
     return _logits(params, cfg, xl), (c_cache, kr_cache)
@@ -521,19 +476,9 @@ def decode_multi(
     mesh=None,
 ):
     """num_steps fused decode steps (llama.decode_multi contract)."""
-    if sample_fn is None:
-        def sample_fn(logits, _):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    def step(kv, tokens, pos, cls):
+        return decode(params, cfg, kv, tokens, pos, block_tables, cls,
+                      valid=valid, mesh=mesh)
 
-    def body(carry, step_idx):
-        tokens, kv, pos, cls = carry
-        logits, kv = decode(params, cfg, kv, tokens, pos, block_tables,
-                            cls, valid=valid, mesh=mesh)
-        nt = sample_fn(logits, step_idx).astype(jnp.int32)
-        return (nt, kv, pos + 1, cls + 1), nt
-
-    (_, kv_cache, _, _), toks = jax.lax.scan(
-        body, (token_ids, kv_cache, positions, ctx_lens),
-        jnp.arange(num_steps), length=num_steps,
-    )
-    return toks, kv_cache
+    return burst_scan(step, kv_cache, token_ids, positions, ctx_lens,
+                      num_steps, sample_fn)

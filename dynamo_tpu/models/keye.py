@@ -16,9 +16,9 @@ Per layer (all alike):
     (ops/sparse_attention.py);
   * softmax attention over S_t only, one set for all heads;
   * FFN: softmax router over `n_experts`, the top `experts_per_token`
-    renormalised (llama `_moe_router`), SwiGLU experts `moe_ffn_dim`
+    renormalised (moe.py `softmax_router`), SwiGLU experts `moe_ffn_dim`
     wide of which this program holds `experts_held` = (first, count)
-    (llama `moe_dispatch`: the visited form for a decode step, grouped
+    (moe.py `moe_dispatch`: the visited form for a decode step, grouped
     for a prompt-sized chunk); what the absent experts would add is left
     out.
 
@@ -59,16 +59,13 @@ from ..ops.sparse_attention import (
     write_packed_members,
     write_token_members,
 )
-from .llama import (
-    _attn_out,
-    _logits,
-    _moe_router,
-    _qkv,
+from .common import burst_scan, prefill_one_row
+from .llama import _attn_out, _logits, _qkv, rms_norm, rope
+from .moe import (
     experts_held,
     moe_dispatch,
     moe_held_counts,
-    rms_norm,
-    rope,
+    softmax_router,
 )
 
 
@@ -88,9 +85,7 @@ class KeyeConfig:
     n_experts: int = 16           # the ROUTER's width
     experts_per_token: int = 4
     experts_held: Optional[Tuple[int, int]] = None   # (first, count)
-    moe_dispatch: str = "dense"   # llama.py semantics: dense | capacity
-    moe_capacity_factor: float = 1.25
-    expert_shards: int = 1        # llama.py: set by the engine from the mesh
+    expert_shards: int = 1        # moe.py: set by the engine from the mesh
     rope_theta: float = 1e7
     rms_eps: float = 1e-6
     qk_norm: bool = True
@@ -301,7 +296,7 @@ def _ffn(layer, cfg: KeyeConfig, x: jax.Array,
          valid: Optional[jax.Array]):
     """x [T, d] -> (out [T, d], picks on held experts, held experts with
     a token), the two counts over valid rows."""
-    top_w, top_e = _moe_router(layer, cfg, x)
+    top_w, top_e = softmax_router(layer, cfg, x)
     out = moe_dispatch(layer, cfg, x, top_w, top_e, valid)
     return (out,) + moe_held_counts(cfg, top_e, valid)
 
@@ -366,13 +361,8 @@ def prefill_batched(params, cfg: KeyeConfig, kv_cache, token_ids, positions,
     return _logits(params, cfg, x[last]), kv_cache
 
 
-def prefill(params, cfg: KeyeConfig, kv_cache, token_ids, positions,
-            block_table, ctx_len, true_len):
-    """One sequence's chunk (llama.prefill's contract): a batch of one."""
-    logits, kv_cache = prefill_batched(
-        params, cfg, kv_cache, token_ids[None], positions[None],
-        block_table[None], ctx_len[None], true_len[None])
-    return logits[0], kv_cache
+# one sequence's chunk (llama.prefill contract): a batch of one
+prefill = prefill_one_row(prefill_batched)
 
 
 # ---------------------------------------------------------------------------
@@ -417,19 +407,9 @@ def decode_multi(params, cfg: KeyeConfig, kv_cache, token_ids, positions,
                  block_tables, ctx_lens, num_steps: int, sample_fn=None,
                  valid: Optional[jax.Array] = None, mesh=None):
     """num_steps fused decode steps (llama.decode_multi's contract)."""
-    if sample_fn is None:
-        def sample_fn(logits, _):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    def step(kv, tokens, pos, cls):
+        return decode(params, cfg, kv, tokens, pos, block_tables, cls,
+                      valid=valid, mesh=mesh)
 
-    def body(carry, step_idx):
-        tokens, kv, pos, cls = carry
-        logits, kv = decode(params, cfg, kv, tokens, pos, block_tables,
-                            cls, valid=valid, mesh=mesh)
-        nt = sample_fn(logits, step_idx).astype(jnp.int32)
-        return (nt, kv, pos + 1, cls + 1), nt
-
-    (_, kv_cache, _, _), toks = jax.lax.scan(
-        body, (token_ids, kv_cache, positions, ctx_lens),
-        jnp.arange(num_steps), length=num_steps,
-    )
-    return toks, kv_cache
+    return burst_scan(step, kv_cache, token_ids, positions, ctx_lens,
+                      num_steps, sample_fn)

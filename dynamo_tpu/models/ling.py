@@ -19,7 +19,7 @@ Per layer l, by `cfg.layer_kinds[l]` (0 KDA, 1 MLA; MLA where
     a shared rope key a token, absorbed decode (the Pallas latent
     kernel where `cfg.attn_impl` resolves to it, jnp elsewhere).
   * FFN: dense SwiGLU below `first_k_dense`, else DeepSeek routing
-    (`_ds_router`: sigmoid, choice bias, group-limited top-k,
+    (moe.py `ds_router`: sigmoid, choice bias, group-limited top-k,
     renormalised, scaled) over `n_experts` router outputs of which this
     program holds `experts_held` = (first, count), plus one shared
     SwiGLU.  What the absent experts would add is left out; the partial
@@ -88,22 +88,10 @@ from ..ops.mla_attention import (
 from ..ops.paged_attention import PALLAS_IMPLS, write_prompt_kv_batched
 from ..ops.pallas_chunk_state import kda_chunk_rows
 from ..ops.pallas_lane_state import kda_lanes_step
-from .deepseek import (
-    _absorb_q,
-    _ds_router,
-    _kv_latent,
-    _q_proj,
-    mla_decode_plan,
-)
-from .mimo import _pool_index     # layer -> its index inside its kind's members
-from .llama import (
-    _logits,
-    _mlp,
-    moe_dispatch,
-    moe_held_counts,
-    moe_rows,
-    rms_norm,
-)
+from .common import burst_scan, pool_index, prefill_one_row
+from .deepseek import _absorb_q, _kv_latent, _q_proj, mla_decode_plan
+from .llama import _logits, _mlp, rms_norm
+from .moe import ds_router, moe_dispatch, moe_held_counts, moe_rows
 
 KDA, MLA = 0, 1
 
@@ -137,10 +125,8 @@ class LingConfig:
     experts_per_token: int = 4
     experts_held: Optional[Tuple[int, int]] = None   # (first, count)
     swiglu_limits: Tuple[float, ...] = ()   # a layer; non-zero is refused
-    moe_dispatch: str = "dense"   # llama.py semantics: dense | capacity
-    moe_capacity_factor: float = 1.25
-    expert_shards: int = 1        # llama.py: set by the engine from the mesh
-    # models/deepseek.py _ds_router reads these
+    expert_shards: int = 1        # moe.py: set by the engine from the mesh
+    # models/moe.py ds_router reads these
     moe_scoring: str = "sigmoid"
     norm_topk_prob: bool = True
     n_group: int = 4
@@ -467,7 +453,7 @@ def _ffn(layer, cfg: LingConfig, x: jax.Array,
     zero = jnp.zeros((), jnp.int32)
     if "moe_gate" not in layer:
         return _mlp(layer, x), zero, zero
-    top_w, top_e = _ds_router(layer, cfg, x)
+    top_w, top_e = ds_router(layer, cfg, x)
     out = moe_dispatch(layer, cfg, x, top_w, top_e, valid) \
         + _mlp(layer["shared"], x)
     return (out,) + moe_held_counts(cfg, top_e, valid)
@@ -530,7 +516,7 @@ def prefill_batched(
     fresh = ctx_lens == 0
     put = rows_target(lanes, true_lens, state.shape[1])
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    pool_li = _pool_index(cfg)
+    pool_li = pool_index(cfg)
     picks = jnp.zeros((), jnp.int32)
     c_impl = chunk_impl(cfg, cfg.attn_impl, T)
     rule = dict(scale=scale, chunk=cfg.kda_chunk,
@@ -574,7 +560,7 @@ def prefill_batched(
             with jax.named_scope("dyn.attn_out"):
                 x = x + attn.reshape(Bp, T, -1) @ layer["wo"]
         h = rms_norm(x, layer["mlp_norm"]["norm"], cfg.rms_eps)
-        out, n_on, _ = moe_rows(partial(_ffn, layer, cfg), cfg, h, valid)
+        out, n_on, _ = moe_rows(partial(_ffn, layer, cfg), h, valid)
         x = x + out
         picks = picks + jnp.sum(n_on)
     counters = counters.at[0].add(picks)
@@ -584,23 +570,8 @@ def prefill_batched(
                                       counters)
 
 
-def prefill(
-    params: Dict[str, Any],
-    cfg: LingConfig,
-    kv_cache,
-    token_ids: jax.Array,      # [T_pad] int32
-    positions: jax.Array,      # [T_pad] int32
-    block_table: jax.Array,    # [max_blocks] int32
-    ctx_len: jax.Array,
-    true_len: jax.Array,
-    lanes: jax.Array = None,   # scalar: this sequence's lane
-):
-    """One sequence's chunk (llama.prefill contract): a batch of one."""
-    logits, kv_cache = prefill_batched(
-        params, cfg, kv_cache, token_ids[None], positions[None],
-        block_table[None], ctx_len[None], true_len[None],
-        None if lanes is None else lanes[None])
-    return logits[0], kv_cache
+# one sequence's chunk (llama.prefill contract): a batch of one
+prefill = prefill_one_row(prefill_batched)
 
 
 # ---------------------------------------------------------------------------
@@ -630,7 +601,7 @@ def decode(
     live = jnp.ones((B,), bool) if valid is None else valid
     mla_scale = 1.0 / jnp.sqrt(jnp.float32(cfg.qk_head_dim))
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    pool_li = _pool_index(cfg)
+    pool_li = pool_index(cfg)
     picks = visited = jnp.zeros((), jnp.int32)
     impl, kv_lens, write_token = mla_decode_plan(
         cfg, c_cache, kr_cache, ctx_lens, valid, mesh)
@@ -691,23 +662,13 @@ def decode_multi(
     mesh=None,
 ):
     """num_steps fused decode steps (llama.decode_multi contract)."""
-    if sample_fn is None:
-        def sample_fn(logits, _):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
     # the busy lanes are the burst's: compacted once, outside the scan
     plan = None if valid is None else lanes_plan(
         valid, state_impl(cfg, cfg.attn_impl))
 
-    def body(carry, step_idx):
-        tokens, kv, pos, cls = carry
-        logits, kv = decode(params, cfg, kv, tokens, pos, block_tables,
-                            cls, valid=valid, mesh=mesh, state_plan=plan)
-        nt = sample_fn(logits, step_idx).astype(jnp.int32)
-        return (nt, kv, pos + 1, cls + 1), nt
+    def step(kv, tokens, pos, cls):
+        return decode(params, cfg, kv, tokens, pos, block_tables, cls,
+                      valid=valid, mesh=mesh, state_plan=plan)
 
-    (_, kv_cache, _, _), toks = jax.lax.scan(
-        body, (token_ids, kv_cache, positions, ctx_lens),
-        jnp.arange(num_steps), length=num_steps,
-    )
-    return toks, kv_cache
+    return burst_scan(step, kv_cache, token_ids, positions, ctx_lens,
+                      num_steps, sample_fn)

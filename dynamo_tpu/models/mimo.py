@@ -15,10 +15,10 @@ Per layer, by `cfg.layer_kinds[l]` (0 global, 1 window):
   * values scaled by `attn_value_scale` (applied to the output: the sum
     is linear in v);
   * FFN: dense SwiGLU where `moe_layers[l]` is 0, else DeepSeek routing
-    (models/deepseek.py `_ds_router`: sigmoid, choice bias, renormalised
+    (models/moe.py `ds_router`: sigmoid, choice bias, renormalised
     top-k) over `n_experts` router outputs, of which this program holds
     `experts_held` = (first, count) — the `moe_w_*` stacks are `count`
-    long (models/llama.py `experts_held`).  What the absent experts
+    long (models/moe.py `experts_held`).  What the absent experts
     would add is left out; the partial result goes on to the next layer.
 
 Cache (the family contract in models/__init__.py): five members,
@@ -72,16 +72,9 @@ from ..ops.window_attention import (
     write_ring_prompt,
     write_ring_token,
 )
-from .deepseek import _ds_router
-from .llama import (
-    _logits,
-    _mlp,
-    moe_dispatch,
-    moe_held_counts,
-    moe_rows,
-    rms_norm,
-    rope,
-)
+from .common import burst_scan, pool_index, prefill_one_row
+from .llama import _logits, _mlp, rms_norm, rope
+from .moe import ds_router, moe_dispatch, moe_held_counts, moe_rows
 
 GLOBAL, WINDOW = 0, 1
 
@@ -111,10 +104,8 @@ class MimoConfig:
     n_experts: int = 16           # the ROUTER's width
     experts_per_token: int = 4
     experts_held: Optional[Tuple[int, int]] = None   # (first, count)
-    moe_dispatch: str = "dense"   # llama.py semantics: dense | capacity
-    moe_capacity_factor: float = 1.25
-    expert_shards: int = 1        # llama.py: set by the engine from the mesh
-    # models/deepseek.py _ds_router reads these
+    expert_shards: int = 1        # moe.py: set by the engine from the mesh
+    # models/moe.py ds_router reads these
     moe_scoring: str = "sigmoid"
     norm_topk_prob: bool = True
     n_group: int = 1
@@ -351,19 +342,9 @@ def _ffn(layer, cfg: MimoConfig, x: jax.Array,
     zero = jnp.zeros((), jnp.int32)
     if "moe_gate" not in layer:
         return _mlp(layer, x), zero, zero
-    top_w, top_e = _ds_router(layer, cfg, x)
+    top_w, top_e = ds_router(layer, cfg, x)
     out = moe_dispatch(layer, cfg, x, top_w, top_e, valid)
     return (out,) + moe_held_counts(cfg, top_e, valid)
-
-
-def _pool_index(cfg: MimoConfig):
-    """layer -> its index inside its kind's pool."""
-    seen = {GLOBAL: 0, WINDOW: 0}
-    out = []
-    for kind in cfg.layer_kinds:
-        out.append(seen[kind])
-        seen[kind] += 1
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -395,7 +376,7 @@ def prefill_batched(
     W = ring_blocks(cfg.sliding_window, kw.shape[4])
     x = params["embedding"][token_ids].astype(cfg.dtype)  # [Bp, T, d]
     valid = jnp.arange(T)[None, :] < true_lens[:, None]
-    pool_li = _pool_index(cfg)
+    pool_li = pool_index(cfg)
     picks = jnp.zeros((), jnp.int32)
     for li, layer in enumerate(params["layers"]):
         kind, pli = cfg.layer_kinds[li], pool_li[li]
@@ -419,7 +400,7 @@ def prefill_batched(
                 q, block_tables, ctx_lens, true_lens)
         x = x + _attn_out(layer, cfg, attn)
         h = rms_norm(x, layer["mlp_norm"]["norm"], cfg.rms_eps)
-        out, n_on, _ = moe_rows(partial(_ffn, layer, cfg), cfg, h, valid)
+        out, n_on, _ = moe_rows(partial(_ffn, layer, cfg), h, valid)
         x = x + out
         picks = picks + jnp.sum(n_on)
     counters = counters.at[0].add(picks)
@@ -428,23 +409,8 @@ def prefill_batched(
     return _logits(params, cfg, xl), (kg, vg, kw, vw, counters)
 
 
-def prefill(
-    params: Dict[str, Any],
-    cfg: MimoConfig,
-    kv_cache,
-    token_ids: jax.Array,      # [T_pad] int32
-    positions: jax.Array,      # [T_pad] int32
-    block_table: jax.Array,    # [max_blocks] int32
-    ctx_len: jax.Array,
-    true_len: jax.Array,
-    lanes: jax.Array = None,   # scalar: this sequence's lane
-):
-    """One sequence's chunk (llama.prefill contract): a batch of one."""
-    logits, kv_cache = prefill_batched(
-        params, cfg, kv_cache, token_ids[None], positions[None],
-        block_table[None], ctx_len[None], true_len[None],
-        None if lanes is None else lanes[None])
-    return logits[0], kv_cache
+# one sequence's chunk (llama.prefill contract): a batch of one
+prefill = prefill_one_row(prefill_batched)
 
 
 # ---------------------------------------------------------------------------
@@ -473,7 +439,7 @@ def decode(
     kv_lens = ctx_lens + 1
     if valid is not None:
         kv_lens = jnp.where(valid, kv_lens, 0)
-    pool_li = _pool_index(cfg)
+    pool_li = pool_index(cfg)
     picks = visited = jnp.zeros((), jnp.int32)
     for li, layer in enumerate(params["layers"]):
         kind, pli = cfg.layer_kinds[li], pool_li[li]
@@ -515,19 +481,9 @@ def decode_multi(
     mesh=None,
 ):
     """num_steps fused decode steps (llama.decode_multi contract)."""
-    if sample_fn is None:
-        def sample_fn(logits, _):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    def step(kv, tokens, pos, cls):
+        return decode(params, cfg, kv, tokens, pos, block_tables, cls,
+                      valid=valid, mesh=mesh)
 
-    def body(carry, step_idx):
-        tokens, kv, pos, cls = carry
-        logits, kv = decode(params, cfg, kv, tokens, pos, block_tables,
-                            cls, valid=valid, mesh=mesh)
-        nt = sample_fn(logits, step_idx).astype(jnp.int32)
-        return (nt, kv, pos + 1, cls + 1), nt
-
-    (_, kv_cache, _, _), toks = jax.lax.scan(
-        body, (token_ids, kv_cache, positions, ctx_lens),
-        jnp.arange(num_steps), length=num_steps,
-    )
-    return toks, kv_cache
+    return burst_scan(step, kv_cache, token_ids, positions, ctx_lens,
+                      num_steps, sample_fn)

@@ -17,11 +17,11 @@ Block i is `x += mixer_i(RMSNorm(x))` with ONE mixer, chosen by
     GQA write and read of ops/paged_attention.py (the Pallas kernel
     where `cfg.attn_impl` resolves to it), imported.  NO rotary: position
     lives in the Mamba layers (`_qkv` with no positions).
-  * `E` experts: DeepSeek routing (`_ds_router` at one group: sigmoid,
+  * `E` experts: DeepSeek routing (`ds_router` at one group: sigmoid,
     choice bias, top-k, renormalised, scaled) over `n_experts` router
     outputs of which this program holds `experts_held` = (first, count),
     each `Wdown relu(x Wup)^2` (two matrices, no gate: `expert_gated`
-    False, `expert_act` relu2 tell llama.py's `moe_dispatch`), plus one
+    False, `expert_act` relu2 tell moe.py's `moe_dispatch`), plus one
     shared expert of the same form.  What the absent experts would add
     is left out; the partial result goes on to the next block.
   * `-` (a plain MLP block) is not modelled and refused by the config.
@@ -91,16 +91,14 @@ from ..ops.ssm import (
     ssm_conv_step,
     ssm_dt,
 )
-from .deepseek import _ds_router
-from .llama import (
-    _attn_out,
-    _logits,
-    _qkv,
+from .common import burst_scan, prefill_one_row
+from .llama import _attn_out, _logits, _qkv, rms_norm
+from .moe import (
+    ds_router,
     moe_dispatch,
     moe_held_counts,
     moe_rows,
     relu2,
-    rms_norm,
 )
 
 MAMBA, ATTN, MOE = "M", "*", "E"
@@ -127,7 +125,7 @@ class NemotronHConfig:
     attn_q_block: int = 512       # queries a pass of the prefill read
     qk_norm: bool = False
     rope_theta: float = 10000.0   # a carried key: no rotary is applied
-    # experts (models/llama.py moe_dispatch reads these)
+    # experts (models/moe.py moe_dispatch reads these)
     moe_ffn_dim: int = 32
     shared_ffn_dim: int = 64
     n_experts: int = 16           # the ROUTER's width
@@ -135,10 +133,8 @@ class NemotronHConfig:
     experts_held: Optional[Tuple[int, int]] = None   # (first, count)
     expert_gated: bool = False    # Wdown act(x Wup): two matrices
     expert_act: Callable = relu2
-    moe_dispatch: str = "dense"   # llama.py semantics: dense | capacity
-    moe_capacity_factor: float = 1.25
-    expert_shards: int = 1        # llama.py: set by the engine from the mesh
-    # models/deepseek.py _ds_router reads these
+    expert_shards: int = 1        # moe.py: set by the engine from the mesh
+    # models/moe.py ds_router reads these
     moe_scoring: str = "sigmoid"
     norm_topk_prob: bool = True
     n_group: int = 1
@@ -456,7 +452,7 @@ def _experts(layer, cfg: NemotronHConfig, h: jax.Array,
     a pick that flips against the float32 reference moves every later
     token through the state."""
     with jax.default_matmul_precision("highest"):
-        top_w, top_e = _ds_router(layer, cfg, h)
+        top_w, top_e = ds_router(layer, cfg, h)
     x = h.astype(cfg.dtype)
     out = moe_dispatch(layer, cfg, x, top_w, top_e, valid) \
         + _plain_mlp(layer["shared"], cfg, x)
@@ -555,8 +551,7 @@ def prefill_batched(
             )(q, k, v, block_tables, ctx_lens, true_lens)
             x = x + _attn_out(layer, attn.reshape(Bp, T, cfg.q_dim))
         else:
-            out, n_on, _ = moe_rows(partial(_experts, layer, cfg), cfg, h,
-                                    valid)
+            out, n_on, _ = moe_rows(partial(_experts, layer, cfg), h, valid)
             x = x + out
             picks = picks + jnp.sum(n_on)
     counters = counters.at[0].add(picks)
@@ -566,23 +561,8 @@ def prefill_batched(
                                       counters)
 
 
-def prefill(
-    params: Dict[str, Any],
-    cfg: NemotronHConfig,
-    kv_cache,
-    token_ids: jax.Array,      # [T_pad] int32
-    positions: jax.Array,      # [T_pad] int32
-    block_table: jax.Array,    # [max_blocks] int32
-    ctx_len: jax.Array,
-    true_len: jax.Array,
-    lanes: jax.Array = None,   # scalar: this sequence's lane
-):
-    """One sequence's chunk (llama.prefill contract): a batch of one."""
-    logits, kv_cache = prefill_batched(
-        params, cfg, kv_cache, token_ids[None], positions[None],
-        block_table[None], ctx_len[None], true_len[None],
-        None if lanes is None else lanes[None])
-    return logits[0], kv_cache
+# one sequence's chunk (llama.prefill contract): a batch of one
+prefill = prefill_one_row(prefill_batched)
 
 
 # ---------------------------------------------------------------------------
@@ -669,23 +649,13 @@ def decode_multi(
     mesh=None,
 ):
     """num_steps fused decode steps (llama.decode_multi contract)."""
-    if sample_fn is None:
-        def sample_fn(logits, _):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
     # the busy lanes are the burst's: compacted once, outside the scan
     plan = None if valid is None else lanes_plan(
         valid, state_impl(cfg, cfg.attn_impl))
 
-    def body(carry, step_idx):
-        tokens, kv, pos, cls = carry
-        logits, kv = decode(params, cfg, kv, tokens, pos, block_tables,
-                            cls, valid=valid, mesh=mesh, state_plan=plan)
-        nt = sample_fn(logits, step_idx).astype(jnp.int32)
-        return (nt, kv, pos + 1, cls + 1), nt
+    def step(kv, tokens, pos, cls):
+        return decode(params, cfg, kv, tokens, pos, block_tables, cls,
+                      valid=valid, mesh=mesh, state_plan=plan)
 
-    (_, kv_cache, _, _), toks = jax.lax.scan(
-        body, (token_ids, kv_cache, positions, ctx_lens),
-        jnp.arange(num_steps), length=num_steps,
-    )
-    return toks, kv_cache
+    return burst_scan(step, kv_cache, token_ids, positions, ctx_lens,
+                      num_steps, sample_fn)

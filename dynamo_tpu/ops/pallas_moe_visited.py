@@ -1,5 +1,5 @@
 """Pallas TPU kernel for the dropless expert dispatch of a decode-sized
-program (models/llama.py `moe_dispatch_visited`): one call a layer that
+program (models/moe.py `moe_dispatch_visited`): one call a layer that
 moves an expert's matrices from HBM only if a valid row of this step
 picked it.
 
@@ -51,7 +51,7 @@ from .delta_attention import F32
 from .lane_state import lanes_plan
 
 # one step's weight tiles (gate, up, down), a buffer; the pipeline holds
-# two.  Timed on the chip (PR 44, models/llama.py moe_dispatch_form):
+# two.  Timed on the chip (PR 44, models/moe.py moe_dispatch_form):
 # the wider tile is the faster one (a [d, 128] tile is runs of 4 KB:
 # Moonlight 0.231 ms at 128 against 0.158 whole, 6 experts visited)
 _TILE_BUDGET = 26 << 20
@@ -155,7 +155,7 @@ def moe_visited(layer, hidden: Callable, x: jax.Array, wmat: jax.Array,
     `visited_plan`; the layer's stacks moe_w_gate (absent for a plain
     expert) / moe_w_up [held, d, f] and moe_w_down [held, f, d];
     hidden(refs by name, mm) an expert's hidden activations from its
-    tiles (models/llama.py `_expert_hidden`) -> [T, d] in x's dtype."""
+    tiles (models/moe.py `_expert_hidden`) -> [T, d] in x's dtype."""
     T, d = x.shape
     names = tuple(k for k in _IN if k in layer)
     stacks = [layer[k] for k in names] + [layer["moe_w_down"]]

@@ -11,6 +11,7 @@ differ by summation order only."""
 
 import asyncio
 import dataclasses
+from functools import partial
 
 import pytest
 
@@ -24,11 +25,8 @@ from benchmark.reference import cohere2 as ref
 from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.models import cohere2, get_family
 from dynamo_tpu.models.cohere2 import Cohere2Config
-from dynamo_tpu.models.llama import (
-    moe_dispatch_capacity,
-    moe_dispatch_dense,
-    rope,
-)
+from dynamo_tpu.models.llama import rope
+from dynamo_tpu.models.moe import moe_dispatch_dense, moe_dispatch_visited
 from dynamo_tpu.ops.packed_prefill import packed_prefill_attention
 from dynamo_tpu.ops.paged_attention import paged_attention_decode
 from dynamo_tpu.ops.window_attention import (
@@ -252,28 +250,31 @@ def test_bfloat16_in_a_float32_piece_breaks_agreement(model, piece,
             x.astype(bf).astype(jnp.float32), w.astype(bf), eps
         ).astype(bf).astype(jnp.float32))
     else:
-        real = cohere2._ds_router
-        monkeypatch.setattr(cohere2, "_ds_router", lambda layer, cfg, x: real(
+        real = cohere2.ds_router
+        monkeypatch.setattr(cohere2, "ds_router", lambda layer, cfg, x: real(
             {"moe_gate": layer["moe_gate"].astype(bf)}, cfg, x.astype(bf)))
     got, _ = prefill_chunks(params, TINY, fresh_cache(), toks, 70, 2, 32,
                             prefill=cohere2.prefill)   # traced anew
     assert float(np.abs(got - full[69]).max()) > 4 * TOL, piece
 
 
-@pytest.mark.parametrize("dispatch", [moe_dispatch_dense,
-                                      moe_dispatch_capacity])
+# the kernel's body on the CPU: the form a decode step takes on the chip
+_visited = partial(moe_dispatch_visited, interpret=True)
+
+
+@pytest.mark.parametrize("dispatch", [moe_dispatch_dense, _visited],
+                         ids=["moe_dispatch_dense", "moe_dispatch_visited"])
 def test_expert_shares_add_up_to_the_uncut_layer(dispatch):
     """The routed parts of the four shares of 4 experts plus the shared
     experts counted ONCE add up to what the program gives with all 16
     held, and to the reference's uncut layer; a share alone equals the
     reference given the same share; the shared part is the mean of its
     four experts."""
-    whole = dataclasses.replace(TINY, experts_held=None,
-                                moe_capacity_factor=64.0)   # no drops
+    whole = dataclasses.replace(TINY, experts_held=None)
     params = cohere2.init_params(whole, jax.random.PRNGKey(3))
     layer = params["layers"][1]
     x = jax.random.normal(jax.random.PRNGKey(4), (9, whole.d_model))
-    w, ids = cohere2._ds_router(layer, whole, x)
+    w, ids = cohere2.ds_router(layer, whole, x)
     rw, rids = ref._route(whole, layer, x)
     np.testing.assert_array_equal(np.asarray(ids), np.asarray(rids))
     np.testing.assert_allclose(np.asarray(w), np.asarray(rw), atol=1e-6)

@@ -22,10 +22,10 @@ from benchmark.reference import keye as ref
 from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.models import get_family, keye
 from dynamo_tpu.models.keye import KeyeConfig
-from dynamo_tpu.models.llama import (
-    _moe_router,
+from dynamo_tpu.models.moe import (
     moe_dispatch_dense,
     moe_dispatch_grouped,
+    softmax_router,
 )
 from dynamo_tpu.ops import sparse_attention as sa
 from dynamo_tpu.ops.paged_attention import (
@@ -296,7 +296,7 @@ def test_expert_shares_add_up_to_the_uncut_layer(dispatch):
     whole = dataclasses.replace(TINY, experts_held=None)
     layer = keye.init_params(whole, jax.random.PRNGKey(3))["layers"][1]
     x = jax.random.normal(jax.random.PRNGKey(4), (9, whole.d_model))
-    w, ids = _moe_router(layer, whole, x)
+    w, ids = softmax_router(layer, whole, x)
     uncut = dispatch(layer, whole, x, w, ids)
     np.testing.assert_allclose(
         np.asarray(uncut), np.asarray(ref._routed(whole, layer, x, "")),

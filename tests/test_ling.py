@@ -13,6 +13,7 @@ form with its triangular solve."""
 
 import asyncio
 import dataclasses
+from functools import partial
 
 import pytest
 
@@ -25,11 +26,8 @@ import numpy as np
 from benchmark.reference import ling as ref
 from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.models import get_family, ling
-from dynamo_tpu.models.llama import (
-    moe_dispatch_capacity,
-    moe_dispatch_dense,
-)
 from dynamo_tpu.models.ling import LingConfig
+from dynamo_tpu.models.moe import moe_dispatch_dense, moe_dispatch_visited
 from dynamo_tpu.ops.delta_attention import kda_chunked, kda_step, l2norm
 from dynamo_tpu.protocols import (
     PreprocessedRequest,
@@ -310,20 +308,23 @@ def test_steps_equal_the_chunked_rule():
     assert bool((S[1] == S0).all())
 
 
-@pytest.mark.parametrize("dispatch", [moe_dispatch_dense,
-                                      moe_dispatch_capacity])
+# the kernel's body on the CPU: the form a decode step takes on the chip
+_visited = partial(moe_dispatch_visited, interpret=True)
+
+
+@pytest.mark.parametrize("dispatch", [moe_dispatch_dense, _visited],
+                         ids=["moe_dispatch_dense", "moe_dispatch_visited"])
 def test_expert_shares_add_up_to_the_uncut_layer(dispatch):
     """Under group-limited routing, the parts that the four shares of 8
     experts give, with the shared expert (which every chip computes
     alike) counted once, add up to what the program gives with all 32
     held, and to the reference's uncut layer; a share alone equals the
     reference given the same share."""
-    whole = dataclasses.replace(TINY, experts_held=None,
-                                moe_capacity_factor=64.0)   # no drops
+    whole = dataclasses.replace(TINY, experts_held=None)
     params = ling.init_params(whole, jax.random.PRNGKey(3))
     layer = params["layers"][2]
     x = jax.random.normal(jax.random.PRNGKey(4), (9, whole.d_model))
-    w, ids = ling._ds_router(layer, whole, x)
+    w, ids = ling.ds_router(layer, whole, x)
     rw, rids = ref._route(whole, layer, x, "")
     assert (np.asarray(ids) == np.asarray(rids)).all()
     # the group limit binds: plain top-4 of 32 chooses differently

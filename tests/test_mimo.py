@@ -9,6 +9,7 @@ program and reference differ by summation order only."""
 
 import asyncio
 import dataclasses
+from functools import partial
 
 import pytest
 
@@ -21,11 +22,8 @@ import numpy as np
 from benchmark.reference import mimo as ref
 from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.models import get_family, mimo
-from dynamo_tpu.models.llama import (
-    moe_dispatch_capacity,
-    moe_dispatch_dense,
-)
 from dynamo_tpu.models.mimo import MimoConfig
+from dynamo_tpu.models.moe import moe_dispatch_dense, moe_dispatch_visited
 from dynamo_tpu.ops.window_attention import ring_blocks
 from dynamo_tpu.protocols import (
     PreprocessedRequest,
@@ -143,18 +141,21 @@ def test_leaving_out_a_published_detail_breaks_agreement(model, detail):
     assert float(np.abs(full - without).max()) > 100 * TOL
 
 
-@pytest.mark.parametrize("dispatch", [moe_dispatch_dense,
-                                      moe_dispatch_capacity])
+# the kernel's body on the CPU: the form a decode step takes on the chip
+_visited = partial(moe_dispatch_visited, interpret=True)
+
+
+@pytest.mark.parametrize("dispatch", [moe_dispatch_dense, _visited],
+                         ids=["moe_dispatch_dense", "moe_dispatch_visited"])
 def test_expert_shares_add_up_to_the_uncut_layer(dispatch):
     """The parts that the four shares of 4 experts give add up to what
     the program gives with all 16 held, and to the reference's uncut
     layer; a share alone equals the reference given the same share."""
-    whole = dataclasses.replace(TINY, experts_held=None,
-                                moe_capacity_factor=64.0)   # no drops
+    whole = dataclasses.replace(TINY, experts_held=None)
     params = mimo.init_params(whole, jax.random.PRNGKey(3))
     layer = params["layers"][1]
     x = jax.random.normal(jax.random.PRNGKey(4), (9, whole.d_model))
-    w, ids = mimo._ds_router(layer, whole, x)
+    w, ids = mimo.ds_router(layer, whole, x)
     uncut = dispatch(layer, whole, x, w, ids)
     np.testing.assert_allclose(
         np.asarray(uncut),

@@ -1,9 +1,11 @@
-"""MoE model family: routing math, capacity overflow, EP sharding parity,
-and engine e2e on the tiny-moe preset.
+"""MoE model family: routing math, EP sharding parity, and engine e2e
+on the tiny-moe preset.
 
 The EP check is the load-bearing one: expert weights shard over the tp mesh
-axis (parallel/mesh.py moe_w_* rules) and the GShard dispatch einsums must
+axis (parallel/mesh.py moe_w_* rules) and the dispatch einsums must
 produce identical outputs sharded vs unsharded."""
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -18,26 +20,27 @@ pytestmark = pytest.mark.allow_slow_callbacks
 
 
 from dynamo_tpu.models import llama
-from dynamo_tpu.models.llama import (
-    LlamaConfig,
-    PRESETS,
-    moe_dispatch_capacity,
+from dynamo_tpu.models.llama import LlamaConfig, PRESETS
+from dynamo_tpu.models.moe import (
     moe_dispatch_dense,
     moe_dispatch_grouped,
+    moe_dispatch_visited,
+    softmax_router,
 )
 
 
 def _routed(dispatch):
     """Router + one form of the dispatch, x [T, d] -> [T, d]."""
     def run(layer, cfg, x):
-        top_w, top_e = llama._moe_router(layer, cfg, x)
+        top_w, top_e = softmax_router(layer, cfg, x)
         return dispatch(layer, cfg, x, top_w, top_e)
     return run
 
 
 _moe_mlp_dense = _routed(moe_dispatch_dense)
 _moe_mlp_grouped = _routed(moe_dispatch_grouped)
-_moe_mlp = _routed(moe_dispatch_capacity)
+# the kernel's body on the CPU: the form a decode step takes on the chip
+_moe_mlp_visited = _routed(partial(moe_dispatch_visited, interpret=True))
 
 
 def moe_cfg(**kw):
@@ -54,10 +57,11 @@ def expert_ffn(layer, e, x):
     return g @ layer["moe_w_down"][e]
 
 
-@pytest.mark.parametrize("impl", [_moe_mlp_dense, _moe_mlp_grouped, _moe_mlp],
-                         ids=["dense", "grouped", "capacity"])
+@pytest.mark.parametrize("impl", [_moe_mlp_dense, _moe_mlp_grouped,
+                                  _moe_mlp_visited],
+                         ids=["dense", "grouped", "visited"])
 def test_moe_routes_to_topk_experts(impl):
-    """Every dispatch: output must equal the softmax-weighted sum of
+    """Every form of the dispatch: output must equal the softmax-weighted sum of
     the top-k experts' FFN outputs, computed independently per token."""
     cfg = moe_cfg()
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
@@ -78,31 +82,10 @@ def test_moe_routes_to_topk_experts(impl):
                                    rtol=2e-4, atol=2e-4)
 
 
-def test_moe_capacity_overflow_drops_tokens():
-    """Capacity mode: with 1 slot per expert and every token routed to the
-    same expert, only the first token gets expert compute; the rest
-    contribute 0 (residual passthrough happens in the transformer block)."""
-    cfg = moe_cfg(experts_per_token=1, moe_dispatch="capacity",
-                  moe_capacity_factor=0.25)  # C=1 for T=4
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    layer = dict(params["layers"][0])
-    # force all tokens to expert 2
-    gate = np.zeros((cfg.d_model, cfg.n_experts), np.float32)
-    gate[:, 2] = 1.0
-    layer["moe_gate"] = jnp.asarray(gate)
-    x = jnp.ones((4, cfg.d_model), jnp.float32)
-    out = _moe_mlp(layer, cfg, x)
-    expect0 = expert_ffn(layer, 2, x[0])
-    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(expect0),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(out[1:]), 0.0, atol=1e-6)
-
-
-@pytest.mark.parametrize("impl", [_moe_mlp_dense, _moe_mlp],
-                         ids=["dense", "capacity"])
+@pytest.mark.parametrize("impl", [_moe_mlp_dense], ids=["dense"])
 def test_moe_ep_sharding_parity(impl):
-    """Expert-parallel (experts sharded over tp) output == unsharded, for
-    both dispatch modes."""
+    """Expert-parallel (experts sharded over tp) output == unsharded, in
+    the form split stacks take (moe_dispatch_form: shards > 1)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh, shard_params
@@ -126,11 +109,12 @@ def test_moe_ep_sharding_parity(impl):
 
 
 async def test_moe_prefix_cache_rerun_deterministic():
-    """Regression (caught live): a rerun of the same prompt takes the
-    cached-prefix + short-tail-prefill path, whose different chunk size
-    changed capacity-mode drops and produced DIFFERENT greedy output.  The
-    default dense dispatch must be batch-invariant: identical tokens out,
-    whatever the chunking."""
+    """Regression (caught live, under a dispatch that dropped tokens over
+    an expert's capacity and is gone for it): a rerun of the same prompt
+    takes the cached-prefix + short-tail-prefill path, whose different
+    chunk size changed the drops and produced DIFFERENT greedy output.
+    The dispatch must be batch-invariant: identical tokens out, whatever
+    the chunking."""
     from dynamo_tpu.engine import EngineConfig, JaxEngine
     from dynamo_tpu.protocols import (
         PreprocessedRequest,
@@ -201,15 +185,14 @@ def test_moe_preset_registered():
     assert PRESETS["mixtral-8x7b"].n_experts == 8
 
 
-def test_moe_batched_prefill_per_row_capacity():
-    """prefill_batched must give each sequence its OWN expert-capacity pool
-    (capacity dispatch): co-scheduled requests must not capacity-drop each
-    other's tokens, so batched logits equal per-sequence prefill logits."""
+def test_moe_batched_prefill_rows_get_their_own_logits():
+    """Co-batched MoE rows give each row's own logits: prefill_batched
+    runs its rows flattened through the one dispatch (moe_rows), and a
+    row's result depends on no other row, so batched logits equal
+    per-sequence prefill logits."""
     from dynamo_tpu.models.llama import init_params, prefill, prefill_batched
 
-    # tight capacity so cross-row pooling WOULD drop tokens if shared
-    cfg = moe_cfg(n_layers=2, moe_dispatch="capacity",
-                  moe_capacity_factor=1.0)
+    cfg = moe_cfg(n_layers=2)
     params = init_params(cfg, jax.random.PRNGKey(4))
     bs, nb, mb, T = 4, 64, 8, 16
     shape = (cfg.n_layers, cfg.n_kv_heads, nb, cfg.head_dim, bs)

@@ -1,4 +1,4 @@
-"""The dropless dispatch's grouped form (models/llama.py
+"""The dropless dispatch's grouped form (models/moe.py
 `moe_dispatch_grouped`): the same mathematics as `moe_dispatch_dense` and
 as the float32 references' one-token-at-a-time expert loops, over the
 cases the contract names; a row's result whatever else is in the batch;
@@ -15,8 +15,8 @@ import pytest
 from benchmark.reference import deepseek as ref_ds
 from benchmark.reference import mimo as ref_mimo
 from dynamo_tpu.models import llama
-from dynamo_tpu.models.llama import (
-    LlamaConfig,
+from dynamo_tpu.models.llama import LlamaConfig
+from dynamo_tpu.models.moe import (
     moe_dispatch,
     moe_dispatch_dense,
     moe_dispatch_form,
@@ -142,14 +142,12 @@ def test_the_shape_picks_the_form(tokens, k, held, routed, shards, form):
     assert moe_dispatch_form(tokens, k, held, routed, shards) == form
 
 
-def test_one_entry_point_picks_by_shape_and_keeps_capacity_apart():
-    """`moe_dispatch` is what the three families call: the traced
-    program of a prompt-sized input holds grouped matmuls, one between
-    the two bounds the dense einsums alone (under it the visited form's
-    kernel beside them: tests/test_moe_visited.py); `expert_shards` > 1
-    keeps dense; "capacity" is
-    another mathematics and goes its own way; anything else is an
-    error."""
+def test_one_entry_point_picks_by_shape():
+    """`moe_dispatch` is what every family with experts calls: the
+    traced program of a prompt-sized input holds grouped matmuls, one
+    between the two bounds the dense einsums alone (under it the visited
+    form's kernel beside them: tests/test_moe_visited.py);
+    `expert_shards` > 1 keeps dense."""
     cfg = LlamaConfig(d_model=D, ffn_dim=F, n_experts=8, experts_per_token=2,
                       dtype=jnp.float32)
     layer = _stacks(8)
@@ -159,16 +157,11 @@ def test_one_entry_point_picks_by_shape_and_keeps_capacity_apart():
         x = jnp.zeros((T, D), jnp.float32)
         text = str(jax.make_jaxpr(
             lambda *a: moe_dispatch(layer, cfg, *a))(x, top_w, top_e))
-        return "ragged_dot" in text or "pallas_call" in text, text
+        return "ragged_dot" in text or "pallas_call" in text
 
-    assert prims(cfg, 512)[0]
-    assert not prims(cfg, 384)[0]
-    assert not prims(dataclasses.replace(cfg, expert_shards=4), 512)[0]
-    grouped, text = prims(
-        dataclasses.replace(cfg, moe_dispatch="capacity"), 512)
-    assert not grouped and "cumsum" in text
-    with pytest.raises(ValueError, match="moe_dispatch"):
-        prims(dataclasses.replace(cfg, moe_dispatch="grouped"), 512)
+    assert prims(cfg, 512)
+    assert not prims(cfg, 384)
+    assert not prims(dataclasses.replace(cfg, expert_shards=4), 512)
 
 
 def test_co_batched_rows_run_flattened_and_equal_their_own_programs():

@@ -1,4 +1,4 @@
-"""The dropless dispatch's visited form (models/llama.py
+"""The dropless dispatch's visited form (models/moe.py
 `moe_dispatch_visited` over ops/pallas_moe_visited.py): the kernel under
 the interpreter against `moe_dispatch_dense` and the float32 references'
 one-token-at-a-time expert loops, over the cases the contract names; a
@@ -16,8 +16,8 @@ import pytest
 import test_moe_grouped as tmg
 
 from benchmark.reference import mimo as ref_mimo
-from dynamo_tpu.models.llama import (
-    LlamaConfig,
+from dynamo_tpu.models.llama import LlamaConfig
+from dynamo_tpu.models.moe import (
     moe_dispatch,
     moe_dispatch_dense,
     moe_dispatch_form,

@@ -12,6 +12,7 @@ form."""
 
 import asyncio
 import dataclasses
+from functools import partial
 
 import pytest
 
@@ -23,12 +24,12 @@ import numpy as np
 
 from benchmark.reference import nemotron_h as ref
 from dynamo_tpu.engine import EngineConfig, JaxEngine
-from dynamo_tpu.models import deepseek, get_family, keye, ling, llama, mimo
+from dynamo_tpu.models import deepseek, get_family, keye, ling, mimo, moe
 from dynamo_tpu.models import nemotron_h as nh
-from dynamo_tpu.models.llama import (
-    moe_dispatch_capacity,
+from dynamo_tpu.models.moe import (
     moe_dispatch_dense,
     moe_dispatch_grouped,
+    moe_dispatch_visited,
 )
 from dynamo_tpu.models.nemotron_h import NemotronHConfig
 from dynamo_tpu.ops.ssm import ssd_chunked, ssd_step
@@ -376,21 +377,25 @@ def _expert_layer(cfg, seed=3):
     return params["layers"][cfg.layers_of("E")[0]]
 
 
+# the kernel's body on the CPU: the form a decode step takes on the chip
+_visited = partial(moe_dispatch_visited, interpret=True)
+
+
 @pytest.mark.parametrize("dispatch", [moe_dispatch_dense,
-                                      moe_dispatch_grouped,
-                                      moe_dispatch_capacity])
+                                      moe_dispatch_grouped, _visited],
+                         ids=["moe_dispatch_dense", "moe_dispatch_grouped",
+                              "moe_dispatch_visited"])
 def test_plain_experts_equal_a_loop_over_experts(dispatch):
-    """`Wdown relu(x Wup)^2` through each form of llama.py's dispatch
+    """`Wdown relu(x Wup)^2` through each form of moe.py's dispatch
     (the family's config says the expert is plain and names the
     activation) = the reference's loop, a token and an expert at a time,
     with a share held and a padded tail masked out."""
-    cfg = dataclasses.replace(TINY, experts_held=(4, 8),
-                              moe_capacity_factor=64.0)     # no drops
+    cfg = dataclasses.replace(TINY, experts_held=(4, 8))
     layer = _expert_layer(cfg)
     assert "moe_w_gate" not in layer
     x = jax.random.normal(jax.random.PRNGKey(4), (37, cfg.d_model))
     valid = jnp.arange(37) < 30
-    w, ids = nh._ds_router(layer, cfg, x)
+    w, ids = nh.ds_router(layer, cfg, x)
     got = dispatch(layer, cfg, x, w, ids, valid)
     want = ref._routed(cfg, layer, x, w, ids) * valid[:, None]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -429,9 +434,9 @@ def test_gated_families_compute_what_they_did(family, form):
     else:
         sizes = jnp.asarray([5, 0, 7] + [0] * (layer["moe_w_up"].shape[0]
                                                - 3), jnp.int32)
-        mm = lambda w: llama._grouped_matmul(x, w, sizes)
+        mm = lambda w: moe._grouped_matmul(x, w, sizes)
     parent = jax.nn.silu(mm(layer["moe_w_gate"])) * mm(layer["moe_w_up"])
-    assert bool((llama._expert_hidden(layer, cfg, mm) == parent).all())
+    assert bool((moe._expert_hidden(layer, cfg, mm) == parent).all())
 
 
 def test_expert_shares_add_up_to_the_uncut_layer():
@@ -444,7 +449,7 @@ def test_expert_shares_add_up_to_the_uncut_layer():
     whole = dataclasses.replace(TINY, experts_held=None)
     layer = _expert_layer(whole)
     x = jax.random.normal(jax.random.PRNGKey(4), (9, whole.d_model))
-    w, ids = nh._ds_router(layer, whole, x)
+    w, ids = nh.ds_router(layer, whole, x)
     rw, rids = ref._route(whole, layer, x)
     assert (np.asarray(ids) == np.asarray(rids)).all()
     np.testing.assert_allclose(np.asarray(w), np.asarray(rw), atol=1e-6)
