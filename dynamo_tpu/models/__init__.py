@@ -19,11 +19,14 @@ hybrid over a share of the experts (mimo.py), the GQA decoder whose
 attention reads the keys a learned indexer chooses (keye.py), the
 delta-rule linear-attention hybrid with one latent-attention layer a
 period (ling.py), the Mamba-2 state-space hybrid with a few GQA
-layers and plain experts, one mixer a block (nemotron_h.py) and the
+layers and plain experts, one mixer a block (nemotron_h.py), the
 window + NoPE-global decoder whose attention and experts (routed, and
 shared ones averaged) are ONE parallel block under one LayerNorm
 (cohere2.py; a window of many blocks: its rings are read by the paged
-pools' kernels) serve through identical plumbing.
+pools' kernels) and the muP-scaled decoder whose sparse layers choose
+BLOCKS of keys from mean-pooled compressed keys, one set a KV group,
+beside lightning linear-attention layers with a constant decay a head
+(minicpm_sala.py) serve through identical plumbing.
 
 The cache is a tuple the family owns: the engine allocates one array a
 shape, hands the tuple to every program and takes it back.  A family
@@ -36,8 +39,11 @@ given (never through the family's type):
     members                  same [L, heads, blocks, width, block_size]
                              form and paged by the SAME block table
                              (keye.py: member 2, one index key a token
-                             and layer).  The family's own programs
-                             write them: every program that writes a
+                             and layer; minicpm_sala.py: member 2,
+                             one mean-pooled key a stride of tokens,
+                             [L, blocks, slots, heads, width]: a page
+                             holds the windows that END in it).  The
+                             family's own programs write them: every program that writes a
                              token's K and V writes the others beside
                              them (ops/sparse_attention.py
                              `write_token_members` for a decode token,
@@ -70,6 +76,8 @@ given (never through the family's type):
                              (ling.py and nemotron_h.py: members 2-3,
                              a float32 matrix a head and the short
                              convolution's tail, a lane and layer;
+                             minicpm_sala.py: member 3, the matrix
+                             alone;
                              `kv_cache_dtypes` says which member is
                              which).  The family's programs then keep
                              its life (ops/lane_state.py, the one copy
@@ -94,7 +102,8 @@ given (never through the family's type):
                              family whose layers differ in what they
                              read (mimo.py: decode_attn_* and kv_*
                              blocks) or whose attention chooses its keys
-                             (keye.py: sparse_* tokens); an empty burst
+                             (keye.py: sparse_* tokens; minicpm_sala.py:
+                             sala_* blocks and tokens); an empty burst
                              names the counters.
     prefill_token_counts(..) the same for a prefill chunk from the
                              host's positions and the rows its program
@@ -109,18 +118,28 @@ given (never through the family's type):
     UNSUPPORTED              what the engine must not promise for the
                              family (engine/core.py `_family_gaps`)."""
 
-from . import cohere2, deepseek, keye, ling, llama, mimo, nemotron_h
+from . import (
+    cohere2,
+    deepseek,
+    keye,
+    ling,
+    llama,
+    mimo,
+    minicpm_sala,
+    nemotron_h,
+)
 from .cohere2 import Cohere2Config
 from .deepseek import DeepseekConfig
 from .keye import KeyeConfig
 from .ling import LingConfig
 from .llama import LlamaConfig, init_params
 from .mimo import MimoConfig
+from .minicpm_sala import SalaConfig
 from .nemotron_h import NemotronHConfig
 
 PRESETS = {**llama.PRESETS, **deepseek.PRESETS, **mimo.PRESETS,
            **keye.PRESETS, **ling.PRESETS, **nemotron_h.PRESETS,
-           **cohere2.PRESETS}
+           **cohere2.PRESETS, **minicpm_sala.PRESETS}
 
 
 def get_family(cfg):
@@ -137,6 +156,8 @@ def get_family(cfg):
         return nemotron_h
     if isinstance(cfg, Cohere2Config):
         return cohere2
+    if isinstance(cfg, SalaConfig):
+        return minicpm_sala
     if isinstance(cfg, LlamaConfig):
         return llama
     raise TypeError(f"unknown model config type: {type(cfg).__name__}")
@@ -151,6 +172,7 @@ __all__ = [
     "MimoConfig",
     "NemotronHConfig",
     "PRESETS",
+    "SalaConfig",
     "get_family",
     "init_params",
 ]

@@ -345,7 +345,16 @@ def _decode_kernel(
             preferred_element_type=jnp.float32,
         )
         if biased:
-            s = s + bias_ref[0, c][None]
+            bias = bias_ref[0, c][None]                    # [1, 1, S]
+            if g > 8 and g % 8 == 0:
+                # the TPU compiler aborts on this add where a KV head's
+                # group passes one float32 tile of sublanes (16 heads a
+                # KV head: ops/block_sparse_attention.py; compiled for a
+                # described v5e, PR 47): a tile of heads at a time
+                s = jnp.concatenate([s[:, i:i + 8] + bias
+                                     for i in range(0, g, 8)], axis=1)
+            else:
+                s = s + bias
         pos = c * S + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         live = pos < kv_len
         if bounded:

@@ -1045,3 +1045,90 @@ def test_the_bounds_are_one_more_operand_each(one_chip):
     assert free == 4 + 3 + 2 * 8
     assert operands(jax.jit(packed).lower(
         *args, **kw, lower=S((T,), i32))) == free + 1
+
+
+def test_block_sparse_decode_and_prefill_compile_for_v5e(one_chip):
+    """The family whose sparse layers choose BLOCKS of keys beside
+    lightning linear-attention layers (models/minicpm_sala.py) at the
+    published widths, cut to a sparse, two lightning and a sparse layer,
+    with the long-document cell's cache (8 lanes, 3129 blocks, tables of
+    391) and `auto` resolved as on the chip: a fused decode burst of the
+    engine's own program and a 2048-token prefill chunk.  The decode
+    burst reads the chosen pages through the paged pool's decode kernel,
+    once a KV group (16 query heads a KV head: the bias is added a tile
+    of heads at a time, or the compiler aborts) over the pool seen as
+    [layers x nkv, 1, ...]: a bitcast, never a copy; the choice is
+    `topk_mask`'s kernel; the state is stepped in place.  The prefill
+    chunk runs one search and one flash pass under the block mask a
+    sparse layer.  Neither copies a pool or the state."""
+    import re
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.models import minicpm_sala as sala
+    from dynamo_tpu.ops.paged_attention import PALLAS_IMPLS
+
+    kinds = (sala.SPARSE, sala.LIGHTNING, sala.LIGHTNING, sala.SPARSE)
+    NB, B, MB, K, T = 3129, 8, 391, 8, 2048
+    cfg = dataclasses.replace(sala.PRESETS["minicpm-sala-9b"],
+                              n_layers=len(kinds), layer_kinds=kinds,
+                              attn_impl="pallas")
+    assert sala.state_impl(cfg, cfg.attn_impl) in PALLAS_IMPLS
+    S = _sds(one_chip)
+    shapes = jax.eval_shape(
+        lambda: sala.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    kv = tuple(S(s, d) for s, d in zip(
+        sala.kv_cache_shapes(cfg, NB, BS, lanes=B),
+        sala.kv_cache_dtypes(cfg)))
+    assert kv[0].shape == (2, 2, NB, 128, BS)
+    assert kv[2].shape == (2, NB, 8, 2, 128)
+    assert kv[3].shape == (2, B, 32, 128, 128) and kv[3].dtype == jnp.float32
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+
+    def members_stay(hlo):
+        for shape in (rf"bf16\[2,2,{NB},128,{BS}\]",
+                      rf"bf16\[4,1,{NB},128,{BS}\]",
+                      rf"bf16\[2,{NB},8,2,128\]",
+                      rf"f32\[2,{B},32,128,128\]", rf"f32\[{B},32,128,128\]"):
+            assert not re.findall(rf"= {shape}\S* copy\(", hlo), shape
+
+    fn = jax.jit(
+        partial(JaxEngine._decode_multi_impl, sala, cfg, None, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9))
+    lowered = fn.lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32))
+    assert lowered.out_info[0].shape == (K + len(sala.KV_COUNTERS), B)
+    program = lowered.compile()
+    hlo = program.as_text()
+    members_stay(hlo)
+    # the state is stepped where it lies: nothing but plumbing and the
+    # kernel's call gives out a value of the member's shape (no `select`
+    # or `dynamic-update-slice` over it: the jnp step's `where` and
+    # `.at[pli].set`).  (`_assert_state_steps_in_place` does not fit: a
+    # member this small, 33 MB at two layers, is moved whole into fast
+    # memory for the burst by XLA's memory-space assignment, slice by
+    # slice, and back with one asynchronous copy: 151 MB at the cell's
+    # nine layers is not.)
+    made = set(re.findall(rf"= f32\[2,{B},32,128,128\]\S* ([\w-]+)\(", hlo))
+    assert made <= {"parameter", "get-tuple-element", "while", "tuple",
+                    "bitcast", "custom-call", "copy-done"}, sorted(made)
+    # a sparse layer: the search and the read of each of two KV groups;
+    # a lightning layer: the state's kernel
+    assert hlo.count("tpu_custom_call") == 2 * (1 + 2) + 2
+    assert re.search(rf"bf16\[4,1,{NB},128,{BS}\]\S* bitcast\(", hlo)
+    assert program.memory_analysis().temp_size_in_bytes < 0.5e9
+    pre = jax.jit(partial(JaxEngine._prefill_impl, sala, cfg),
+                  donate_argnums=(1,))
+    program = pre.lower(
+        params, kv, S((T,), i32), S((T,), i32), S((MB,), i32), S((), i32),
+        S((), i32), S((), i32), S((), f32), S((), i32), S((), f32), None,
+        None, S((), i32)).compile()
+    hlo = program.as_text()
+    members_stay(hlo)
+    assert hlo.count("tpu_custom_call") == 2 * 2
+    # the token mask a KV group is the largest thing the chunk makes
+    assert program.memory_analysis().temp_size_in_bytes < 2.0e9
