@@ -699,7 +699,9 @@ def check_kernels(args, pallas: str) -> dict:
 
 
 def check_mla_kernel(args, pallas: str, rng) -> dict:
-    """The compiled latent decode kernel (ops/pallas_mla_attention.py)
+    """The compiled latent kernels (ops/pallas_mla_attention.py): the
+    prefill read against the jnp form (`_check_mla_prefill`), and the
+    decode kernel
     against the gathering jnp body at the two cells' shapes (Ling's 32
     heads over 64 lanes x 45 blocks, Moonlight's 16 over 16 x 20; R 512,
     rope key 64, blocks of 128): lanes of unequal length, contexts
@@ -748,7 +750,65 @@ def check_mla_kernel(args, pallas: str, rng) -> dict:
         say(f"mla decode kernel {pallas} vs the jnp body, {name}: nh={nh} "
             f"B={B} table={mb}, {int((-(-kv_lens // bs)).sum())} live "
             f"blocks: max|err|={err:.5f} (atol {KERNEL_ATOL})")
+        out[f"mla_prefill_{name}_max_abs_err"] = _check_mla_prefill(
+            name, nh, mb, (R, dr, dv, bs), pallas, rng)
     return out
+
+
+def _check_mla_prefill(name, nh, mb, widths, pallas: str, rng) -> float:
+    """The compiled latent PREFILL kernel (one flash pass over the
+    pool's live blocks, `mla_prefill_flash`) against the jnp form a row,
+    over what `mla_write_rows` wrote: a row carried from mid-block with
+    a padded tail, a fresh row that fills its bucket, a row with nothing
+    valid."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.ops.mla_attention import (
+        mla_prefill_attention,
+        mla_prefill_flash,
+        mla_write_rows,
+    )
+
+    R, dr, dv, bs = widths
+    dn = dv
+    T = min(4 * bs, (mb - 2) * bs)
+    rows = [(bs + bs // 2 + 3, max(T - 17, 1)), (0, T), (bs, 0)]
+    S = len(rows)
+    nb = 1 + S * mb
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape),
+                                        jnp.bfloat16)
+    pools = normal(2, 1, nb, R, bs), normal(2, 1, nb, dr, bs)
+    qn, qr = normal(S, T, nh, dn), normal(S, T, nh, dr)
+    c, kr = normal(S, T, R), normal(S, T, dr)
+    w_uk, w_uv = (jnp.asarray(rng.standard_normal((nh, R, d)) / R ** 0.5,
+                              jnp.bfloat16) for d in (dn, dv))
+    tables = jnp.asarray((1 + rng.permutation(nb - 1)).reshape(S, mb),
+                         jnp.int32)
+    ctx = jnp.asarray([r[0] for r in rows], jnp.int32)
+    true = jnp.asarray([r[1] for r in rows], jnp.int32)
+    pools = jax.jit(mla_write_rows)(*pools, 1, c, kr, tables, ctx, true)
+    ref = jax.jit(jax.vmap(
+        lambda a, b, cb, krb, tb, cl, tl: mla_prefill_attention(
+            a, b, cb, krb, *pools, 1, tb, cl, tl, w_uk, w_uv)))(
+        qn, qr, c, kr, tables, ctx, true).astype(jnp.float32)
+    got = jax.jit(lambda *a: mla_prefill_flash(
+        *a, interpret=pallas == "pallas_interpret"))(
+        qn, qr, *pools, 1, tables, ctx, true, w_uk, w_uv).astype(jnp.float32)
+    real = (jnp.arange(T)[None, :] < true[:, None])[..., None, None]
+    err = float(jnp.max(jnp.where(real, jnp.abs(got - ref), 0.0)))
+    check(bool(jnp.all(jnp.isfinite(got))),
+          f"mla prefill kernel ({name}) produced non-finite values")
+    check(float(jnp.max(jnp.where(real, 0.0, jnp.abs(got)))) == 0.0,
+          f"mla prefill kernel ({name}): a row's padding is not 0")
+    check(err <= KERNEL_ATOL,
+          f"mla prefill kernel ({name}) differs from the jnp form by "
+          f"{err:.4f} > {KERNEL_ATOL}")
+    say(f"mla prefill kernel {pallas} vs the jnp form, {name}: nh={nh} "
+        f"T={T} rows={rows} table={mb}: max|err|={err:.5f} "
+        f"(atol {KERNEL_ATOL})")
+    return round(err, 5)
 
 
 def _engine_probe(engine_cfg, prompt_ids, n_out: int):

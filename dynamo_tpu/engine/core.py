@@ -528,13 +528,14 @@ class JaxEngine:
         }
         self._jit_prefill = w.wrap(jax.jit(
             w.named(partial(self._prefill_impl, self.family,
-                            self.model_cfg), "prefill"),
+                            self.model_cfg, mesh=self.mesh), "prefill"),
             donate_argnums=(1,),
             out_shardings=_prefill_out,
         ), "prefill", _toks2)
         self._jit_prefill_batched = w.wrap(jax.jit(
             w.named(partial(self._prefill_batched_impl, self.family,
-                            self.model_cfg), "prefill_batched"),
+                            self.model_cfg, mesh=self.mesh),
+                    "prefill_batched"),
             donate_argnums=(1,),
             out_shardings=_prefill_out,
         ), "prefill_batched", _toks2_total)
@@ -1015,13 +1016,19 @@ class JaxEngine:
     @staticmethod
     def _prefill_impl(family, model_cfg, params, kv, tokens, positions,
                       block_table, ctx_len, true_len, seed, temp, top_k,
-                      top_p, lora_bank=None, lidx=None, lanes=None):
+                      top_p, lora_bank=None, lidx=None, lanes=None,
+                      mesh=None):
         """`lanes`: the scheduler's lane of the sequence, for a family
-        whose pools are addressed by lane (`KV_LANE_ADDRESSED`)."""
+        whose pools are addressed by lane (`KV_LANE_ADDRESSED`).  `mesh`
+        rides to a family whose prefill read is a kernel that runs per
+        shard under tp (models/deepseek.py), where the signature takes
+        it."""
         lora_kw = ({"lora_bank": lora_bank, "adapter_idx": lidx}
                    if lora_bank is not None else {})
         if lanes is not None:
             lora_kw["lanes"] = lanes
+        if "mesh" in inspect.signature(family.prefill).parameters:
+            lora_kw["mesh"] = mesh
         logits, kv = family.prefill(
             params, model_cfg, kv, tokens, positions, block_table,
             ctx_len, true_len, **lora_kw,
@@ -1053,15 +1060,19 @@ class JaxEngine:
     def _prefill_batched_impl(family, model_cfg, params, kv, toks,
                               positions, tables, ctx_lens, true_lens,
                               seeds, temps, top_ks, top_ps,
-                              lora_bank=None, lidx=None, lanes=None):
+                              lora_bank=None, lidx=None, lanes=None,
+                              mesh=None):
         """Multi-sequence chunked prefill (family prefill_batched):
         concurrent arrivals share one program instead of serializing B=1
         chunks.  First tokens are sampled per row; rows whose prompt is not
-        finished this chunk have their sample discarded by the host."""
+        finished this chunk have their sample discarded by the host.
+        `mesh` as for `_prefill_impl`."""
         lora_kw = ({"lora_bank": lora_bank, "adapter_idx": lidx}
                    if lora_bank is not None else {})
         if lanes is not None:
             lora_kw["lanes"] = lanes
+        if "mesh" in inspect.signature(family.prefill_batched).parameters:
+            lora_kw["mesh"] = mesh
         logits, kv = family.prefill_batched(
             params, model_cfg, kv, toks, positions, tables,
             ctx_lens, true_lens, **lora_kw,

@@ -20,7 +20,10 @@ Decode runs the weight-absorbed MLA formulation (never materializes
 per-head K/V), its read in the Pallas latent kernel where
 `cfg.attn_impl` resolves to it (a TPU with 128-token blocks: the live
 blocks only, from the pool where it lies) and in jnp elsewhere; prefill
-up-projects per chunk.  YaRN long-context scaling
+up-projects K and V a key tile at a time inside one flash kernel over
+the pool's live blocks where `mla_prefill_plan` says so (the same
+caches, a bucket of 512 tokens or more), and for the whole table in jnp
+elsewhere.  YaRN long-context scaling
 is not implemented (rope_theta covers the tested ranges).
 """
 
@@ -39,12 +42,14 @@ from ..ops.mla_attention import (
     MLA_DECODE_IMPLS,
     mla_decode_attention,
     mla_prefill_attention,
+    mla_prefill_flash,
+    mla_write_rows,
     mla_write_token,
+    resolve_mla_prefill_impl,
 )
 from ..ops.paged_attention import (
     PALLAS_IMPLS,
     resolve_decode_impl,
-    write_prompt_kv,
     write_prompt_kv_batched,
     write_token_kv,
 )
@@ -320,6 +325,80 @@ def _absorb_q(layer, q_nope: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def mla_prefill_impl(cfg, tokens: int, block_size: int = 1,
+                     cache_dtype=None) -> str:
+    """The impl of the MLA layers' prefill read in a program whose rows
+    are `tokens` long, under `cfg.attn_impl`
+    (ops/mla_attention.resolve_mla_prefill_impl), asked by the traced
+    program about its cache and by the host's counts alike
+    (models/ling.py too).  The host has no cache to show: it asks about
+    the impl the engine has made of "auto" for its cache at start-up,
+    which the rule takes as given; an "auto" that nobody resolved is the
+    jnp form there."""
+    return resolve_mla_prefill_impl(
+        cfg.attn_impl, jax.default_backend(), block_size,
+        cfg.mla_plane_heights, cache_dtype or cfg.dtype, tokens)
+
+
+def mla_prefill_plan(cfg, c_cache, kr_cache, tokens: int, mesh, jnp_read):
+    """What a prefill program's MLA layers share (models/ling.py too):
+    -> (write, read).  `write(c_cache, kr_cache, li, c, kr, tables,
+    ctx_lens, true_lens)` puts the rows' chunks [S, T, .] into the
+    pools; `read(layer, li, q_nope, q_rope, c, kr, c_cache, kr_cache,
+    tables, ctx_lens, true_lens)` -> [S, T, nh, dv] attends them and the
+    cached context.  Where the program's bucket takes the kernel
+    (`mla_prefill_impl`) the read is one flash pass over the pool's
+    live blocks, elsewhere `jnp_read`, the family's jnp form.  Where the
+    cache's reads are kernels at SOME bucket (the decode read's rule)
+    the chunk is written as whole planes in the resident layout, in the
+    buckets whose read stays jnp too: the column scatter would have XLA
+    relay the pool around every kernel call; elsewhere the scatter, and
+    the program is the one it was."""
+    bs = c_cache.shape[4]
+    planes = resolve_decode_impl(
+        cfg.attn_impl, jax.default_backend(), bs,
+        (c_cache.shape[3], kr_cache.shape[3]), c_cache.dtype) in PALLAS_IMPLS
+    impl = mla_prefill_impl(cfg, tokens, bs, c_cache.dtype)
+
+    def scatter(c_cache, kr_cache, li, c, kr, *where):
+        return write_prompt_kv_batched(
+            c_cache, kr_cache, li, c[:, :, None, :], kr[:, :, None, :],
+            *where)
+
+    def flash(layer, li, q_nope, q_rope, c, kr, c_cache, kr_cache, *where):
+        # (the chunk's own c and kr are in the pools already)
+        return mla_prefill_flash(
+            q_nope, q_rope, c_cache, kr_cache, li, *where, layer["w_uk"],
+            layer["w_uv"], mesh=mesh, interpret=impl == "pallas_interpret")
+
+    return (mla_write_rows if planes else scatter,
+            flash if impl in PALLAS_IMPLS else jnp_read)
+
+
+def _mla_prefill_jnp(layer, li, q_nope, q_rope, c, kr, c_cache, kr_cache,
+                     block_tables, ctx_lens, true_lens):
+    """The jnp form a row: `mla_prefill_plan`'s `jnp_read` here."""
+    return jax.vmap(
+        lambda qn, qr, cb, krb, tb, cl, tl: mla_prefill_attention(
+            qn, qr, cb, krb, c_cache, kr_cache, li, tb, cl, tl,
+            layer["w_uk"], layer["w_uv"])
+    )(q_nope, q_rope, c, kr, block_tables, ctx_lens, true_lens)
+
+
+def prefill_token_counts(cfg: DeepseekConfig, pos: int, chunk: int,
+                         bucket: int = 0) -> Dict[str, int]:
+    """Host-side counts for `chunk` prompt tokens prefilled from
+    position `pos` in a program of `bucket` rows: the tokens an MLA
+    layer's prefill read took, and those of them whose program ran it
+    in the kernel (`mla_prefill_impl` of the bucket)."""
+    kernel = mla_prefill_impl(cfg, bucket) in PALLAS_IMPLS
+    return {
+        "mla_prefill_tokens.prefill": cfg.n_layers * chunk,
+        "mla_prefill_kernel_tokens.prefill":
+            cfg.n_layers * chunk if kernel else 0,
+    }
+
+
 def prefill(
     params: Dict[str, Any],
     cfg: DeepseekConfig,
@@ -329,25 +408,24 @@ def prefill(
     block_table: jax.Array,    # [max_blocks] int32
     ctx_len: jax.Array,
     true_len: jax.Array,
+    mesh=None,                 # the kernel's, under tp > 1
 ):
     """Same contract as llama.prefill; cache pair = (latent, rope key)."""
     # dynlint: disable=DYN009 MLA latent cache is bf16-only by design (no int8 scale shapes); the engine forces the bf16 fallback for this family
     c_cache, kr_cache = kv_cache
     x = params["embedding"][token_ids].astype(cfg.dtype)  # [T, d]
     T = x.shape[0]
+    write, read = mla_prefill_plan(cfg, c_cache, kr_cache, T, mesh,
+                                   _mla_prefill_jnp)
+    row = (block_table[None], ctx_len[None], true_len[None])
     for li, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["attn_norm"]["norm"], cfg.rms_eps)
         q_nope, q_rope = _q_proj(layer, cfg, h, positions)
         c, kr = _kv_latent(layer, cfg, h, positions)
-        c_cache, kr_cache = write_prompt_kv(
-            c_cache, kr_cache, li, c[:, None, :], kr[:, None, :],
-            block_table, ctx_len, true_len,
-        )
-        attn = mla_prefill_attention(
-            q_nope, q_rope, c, kr, c_cache, kr_cache, li,
-            block_table, ctx_len, true_len,
-            layer["w_uk"], layer["w_uv"],
-        )
+        c_cache, kr_cache = write(c_cache, kr_cache, li, c[None], kr[None],
+                                  *row)
+        attn = read(layer, li, q_nope[None], q_rope[None], c[None],
+                    kr[None], c_cache, kr_cache, *row)[0]
         x = x + attn.reshape(T, -1) @ layer["wo"]
         h = rms_norm(x, layer["mlp_norm"]["norm"], cfg.rms_eps)
         x = x + _ds_ffn(layer, cfg, h,
@@ -365,6 +443,7 @@ def prefill_batched(
     block_tables: jax.Array,   # [Bp, max_blocks]
     ctx_lens: jax.Array,       # [Bp]
     true_lens: jax.Array,      # [Bp]
+    mesh=None,
 ):
     """Multi-sequence chunked prefill (llama.prefill_batched contract)."""
     # dynlint: disable=DYN009 MLA latent cache is bf16-only by design (no int8 scale shapes); the engine forces the bf16 fallback for this family
@@ -372,20 +451,16 @@ def prefill_batched(
     Bp, T = token_ids.shape
     x = params["embedding"][token_ids].astype(cfg.dtype)  # [Bp, T, d]
     valid = jnp.arange(T)[None, :] < true_lens[:, None]
+    write, read = mla_prefill_plan(cfg, c_cache, kr_cache, T, mesh,
+                                   _mla_prefill_jnp)
+    rows = (block_tables, ctx_lens, true_lens)
     for li, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["attn_norm"]["norm"], cfg.rms_eps)
         q_nope, q_rope = _q_proj(layer, cfg, h, positions)
         c, kr = _kv_latent(layer, cfg, h, positions)
-        c_cache, kr_cache = write_prompt_kv_batched(
-            c_cache, kr_cache, li, c[:, :, None, :], kr[:, :, None, :],
-            block_tables, ctx_lens, true_lens,
-        )
-        attn = jax.vmap(
-            lambda qn, qr, cb, krb, tb, cl, tl: mla_prefill_attention(
-                qn, qr, cb, krb, c_cache, kr_cache, li, tb, cl, tl,
-                layer["w_uk"], layer["w_uv"],
-            )
-        )(q_nope, q_rope, c, kr, block_tables, ctx_lens, true_lens)
+        c_cache, kr_cache = write(c_cache, kr_cache, li, c, kr, *rows)
+        attn = read(layer, li, q_nope, q_rope, c, kr, c_cache, kr_cache,
+                    *rows)
         x = x + attn.reshape(Bp, T, -1) @ layer["wo"]
         h = rms_norm(x, layer["mlp_norm"]["norm"], cfg.rms_eps)
         x = x + moe_rows(partial(_ds_ffn, layer, cfg), h, valid)

@@ -15,9 +15,13 @@ Per layer l, by `cfg.layer_kinds[l]` (0 KDA, 1 MLA; MLA where
     read is RMS-normed a head, gated by sigmoid(h Wg) a head and goes
     through Wo.  No rotary: the decay carries position.
   * MLA: models/deepseek.py's `_q_proj`, `_kv_latent`, `_absorb_q`,
-    `mla_decode_plan` and ops/mla_attention.py, imported: a latent and
-    a shared rope key a token, absorbed decode (the Pallas latent
-    kernel where `cfg.attn_impl` resolves to it, jnp elsewhere).
+    `mla_decode_plan`, `mla_prefill_plan` and ops/mla_attention.py,
+    imported: a latent and a shared rope key a token, absorbed decode
+    (the Pallas latent kernel where `cfg.attn_impl` resolves to it, jnp
+    elsewhere) and a prefill read that is one flash kernel over the
+    pool's live blocks from the 512-token bucket up where the decode
+    kernel runs (`_mla_prefill`, `mla_q_block` queries a pass over the
+    whole table, elsewhere).
   * FFN: dense SwiGLU below `first_k_dense`, else DeepSeek routing
     (moe.py `ds_router`: sigmoid, choice bias, group-limited top-k,
     renormalised, scaled) over `n_experts` router outputs of which this
@@ -85,11 +89,18 @@ from ..ops.mla_attention import (
     mla_decode_attention,
     mla_prefill_attention,
 )
-from ..ops.paged_attention import PALLAS_IMPLS, write_prompt_kv_batched
+from ..ops.paged_attention import PALLAS_IMPLS
 from ..ops.pallas_chunk_state import kda_chunk_rows
 from ..ops.pallas_lane_state import kda_lanes_step
 from .common import burst_scan, pool_index, prefill_one_row
-from .deepseek import _absorb_q, _kv_latent, _q_proj, mla_decode_plan
+from .deepseek import (
+    _absorb_q,
+    _kv_latent,
+    _q_proj,
+    mla_decode_plan,
+    mla_prefill_impl,
+    mla_prefill_plan,
+)
 from .llama import _logits, _mlp, rms_norm
 from .moe import ds_router, moe_dispatch, moe_held_counts, moe_rows
 
@@ -316,15 +327,21 @@ def prefill_token_counts(cfg: LingConfig, pos: int, chunk: int,
     chunked rule, those of them in a program that started from a carried
     state, rows that started from zeros; and the same tokens a KDA
     layer, with those of them whose program ran the rule in
-    ops/pallas_chunk_state.py's kernel (`chunk_impl` of the bucket)."""
-    nk = len(cfg.layers_of(KDA))
+    ops/pallas_chunk_state.py's kernel (`chunk_impl` of the bucket);
+    and the same tokens an MLA layer, with those of them whose program
+    ran the prefill read in ops/pallas_mla_attention.py's kernel
+    (`mla_prefill_impl` of the bucket)."""
+    nk, nm = len(cfg.layers_of(KDA)), len(cfg.layers_of(MLA))
     kernel = chunk_impl(cfg, cfg.attn_impl, bucket) in PALLAS_IMPLS
+    flash = mla_prefill_impl(cfg, bucket) in PALLAS_IMPLS
     return {
         "recurrent_tokens.prefill": chunk,
         "recurrent_carried_tokens.prefill": chunk if pos > 0 else 0,
         "recurrent_resets": int(chunk > 0 and pos == 0),
         "state_chunk_tokens.prefill": nk * chunk,
         "state_chunk_kernel_tokens.prefill": nk * chunk if kernel else 0,
+        "mla_prefill_tokens.prefill": nm * chunk,
+        "mla_prefill_kernel_tokens.prefill": nm * chunk if flash else 0,
     }
 
 
@@ -459,10 +476,12 @@ def _ffn(layer, cfg: LingConfig, x: jax.Array,
     return (out,) + moe_held_counts(cfg, top_e, valid)
 
 
-@jax.named_scope("dyn.attn_mla")
 def _mla_prefill(layer, cfg: LingConfig, q_nope, q_rope, c, kr, c_cache,
                  kr_cache, pli, table, ctx_len, true_len):
-    """One row's chunk [T, ...] over latents ALREADY written to the
+    """The jnp form of the MLA layers' prefill read (the CPU, the
+    buckets under `mla_prefill_impl`'s floor; the kernel form is
+    ops/mla_attention.mla_prefill_flash): one row's chunk [T, ...] over
+    latents ALREADY written to the
     pool, `mla_q_block` queries a pass: a pass reads the context (the
     cache up to its first query) and its own block's latents, so the
     score block is [q_block, heads, table + q_block] whatever T is."""
@@ -521,6 +540,17 @@ def prefill_batched(
     c_impl = chunk_impl(cfg, cfg.attn_impl, T)
     rule = dict(scale=scale, chunk=cfg.kda_chunk,
                 sub=max(cfg.kda_chunk // 4, 1))
+
+    def mla_jnp(layer, pli, q_nope, q_rope, c, kr, c_cache, kr_cache,
+                *rows):
+        return jax.vmap(
+            lambda qn, qr, cb, krb, tb, cl, tl: _mla_prefill(
+                layer, cfg, qn, qr, cb, krb, c_cache, kr_cache, pli,
+                tb, cl, tl))(q_nope, q_rope, c, kr, *rows)
+
+    # (no mesh: tp > 1 is not carried by this family)
+    mla_write, mla_read = mla_prefill_plan(cfg, c_cache, kr_cache, T, None,
+                                           mla_jnp)
     for li, layer in enumerate(params["layers"]):
         kind, pli = cfg.layer_kinds[li], pool_li[li]
         h = rms_norm(x, layer["attn_norm"]["norm"], cfg.rms_eps)
@@ -549,14 +579,13 @@ def prefill_batched(
         else:
             q_nope, q_rope = _q_proj(layer, cfg, h, positions)
             c, kr = _kv_latent(layer, cfg, h, positions)
-            c_cache, kr_cache = write_prompt_kv_batched(
-                c_cache, kr_cache, pli, c[:, :, None, :],
-                kr[:, :, None, :], block_tables, ctx_lens, true_lens)
-            attn = jax.vmap(
-                lambda qn, qr, cb, krb, tb, cl, tl: _mla_prefill(
-                    layer, cfg, qn, qr, cb, krb, c_cache, kr_cache, pli,
-                    tb, cl, tl)
-            )(q_nope, q_rope, c, kr, block_tables, ctx_lens, true_lens)
+            c_cache, kr_cache = mla_write(
+                c_cache, kr_cache, pli, c, kr, block_tables, ctx_lens,
+                true_lens)
+            with jax.named_scope("dyn.attn_mla"):
+                attn = mla_read(
+                    layer, pli, q_nope, q_rope, c, kr, c_cache, kr_cache,
+                    block_tables, ctx_lens, true_lens)
             with jax.named_scope("dyn.attn_out"):
                 x = x + attn.reshape(Bp, T, -1) @ layer["wo"]
         h = rms_norm(x, layer["mlp_norm"]["norm"], cfg.rms_eps)

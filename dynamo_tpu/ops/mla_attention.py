@@ -23,8 +23,27 @@ Decode uses the WEIGHT-ABSORBED formulation: per head
 so the per-head key is never materialized — queries are absorbed into
 latent space ([B, nh, R]) and attention runs directly against the cache;
 the context vector (sum_t p_t c_t) is up-projected once by W_UV.  Prefill
-materializes per-head K/V for the chunk+context (the standard non-absorbed
-path: better MXU shapes for long chunks, and it runs once per prompt).
+MATERIALISES per-head K/V (the standard non-absorbed path: 640 FLOP a
+(query, key) pair and head against the absorbed form's 2176), in two
+forms.  The jnp form (`mla_prefill_attention`) gathers a row's WHOLE
+table, up-projects K and V for every position of it and passes
+[T, heads, table + T] float32 scores through HBM, whatever the context:
+the CPU's path, block_size 16, float32 caches and the short buckets.
+The Pallas kernel (`mla_prefill_flash` ->
+pallas_mla_attention.mla_prefill_pallas, PR 49) is one flash pass over
+the LIVE blocks of the pool, the chunk's own latents among them: a key
+tile is DMA'd from the pool where it lies by the table's block ids and
+up-projected a head at a time in VMEM, scores, running max, sum and
+accumulator never leave VMEM, and tiles above a query tile's frontier
+are skipped.  `resolve_mla_prefill_impl` picks, from what the code can
+observe (platform, block, cache dtype, the program's bucket).  Beside a
+kernel that reads the pool as it lies the chunk is written as whole
+planes in the resident layout (`mla_write_rows`): the flat column
+scatter has XLA relay the whole pool to the scatter's layout and back
+every layer.  One layer-call on a v5e, op alone (PERF.md section 6,
+PR 49): Ling's 32 heads, a 2048-token chunk over a 45-block table, the
+jnp form 12.5 ms at every context, the kernel 1.03 ms at context 0 and
+2.16 at 2048; Moonlight's 16 heads over 20 blocks 1.85 against 0.52.
 
 The decode read has two forms and `mla_decode_attention` dispatches
 between them as paged_attention_decode does for GQA: the jnp body
@@ -119,6 +138,129 @@ def mla_prefill_attention(
 
 def _tp(mesh) -> int:
     return 1 if mesh is None else int(mesh.shape.get("tp", 1))
+
+
+# the bucket from which the prefill read is the kernel where the decode
+# read is.  By time alone the kernel is no slower from 32 tokens up
+# (PERF.md section 6, PR 49: under 512 it buys 0.05 ms a layer on
+# Moonlight, and Ling's short buckets hold 0.5 % of its tokens); with
+# the kernel at the 256 bucket `moonlight-16b.chat`'s `correct` (one
+# fixed 256-token prompt that follows one expert's pick, ROADMAP Y0)
+# left the float32 reference's trajectory at its fifth token (PR 48:
+# gap 0.074 against a limit of 0.04, the jnp form's gaps there 0.031 to
+# 0.038), with float32-accurate K and V as with bf16.  The floor goes
+# lower when that check is a median over positions.
+MLA_PREFILL_KERNEL_MIN_TOKENS = 512
+
+
+def resolve_mla_prefill_impl(impl: str, platform: str, block_size: int,
+                             plane_heights, cache_dtype,
+                             tokens: int) -> str:
+    """What `impl` (a family's `attn_impl`) means for the PREFILL read
+    of a program whose rows are `tokens` long (its bucket): the twin of
+    `resolve_decode_impl` / `resolve_packed_impl`, and like them the one
+    place it is decided, from what the code can observe.  -> "pallas" |
+    "pallas_interpret" | "jnp".
+
+    The kernel where the decode kernel runs ("auto" asks
+    `resolve_decode_impl` about this cache: a TPU, 128-token blocks, a
+    bf16 cache; "pallas" is what the engine has made of "auto" by the
+    time a program is traced, or an explicit choice) for a bucket of
+    MLA_PREFILL_KERNEL_MIN_TOKENS or more; "pallas_interpret" runs it
+    under the interpreter at every bucket (CPU tests); the jnp form on
+    the CPU, block 16, float32 caches, an explicit "jnp" and the
+    shorter buckets."""
+    if impl == "auto":
+        impl = resolve_decode_impl(impl, platform, block_size, plane_heights,
+                                   cache_dtype)
+    if impl == "pallas" and tokens < MLA_PREFILL_KERNEL_MIN_TOKENS:
+        return "jnp"
+    return impl
+
+
+@jax.named_scope("dyn.kv_write")
+def mla_write_rows(
+    c_cache: jax.Array,
+    kr_cache: jax.Array,
+    layer: int,
+    c: jax.Array,             # [S, T, R]  the rows' latents (normed)
+    kr: jax.Array,            # [S, T, dr] and rope keys
+    block_tables: jax.Array,  # [S, max_blocks]
+    ctx_lens: jax.Array,      # [S] tokens cached before each row
+    true_lens: jax.Array,     # [S] real tokens of each row
+):
+    """The rows' chunks into both pools as WHOLE planes in the resident
+    layout: the cells `write_prompt_kv_batched` sets, set by
+    `packed_prefill.write_packed_kv` over the padded rows laid end to
+    end (a packed stream: a row a segment, its padding invalid).  For a
+    cache whose reads are kernels: beside a custom call that reads the
+    pool as it lies, the flat column scatter has XLA relay the whole
+    pool to the scatter's layout and back every layer (1.5 ms a layer on
+    Moonlight's 537 MB pool, PR 48)."""
+    from .packed_prefill import write_packed_kv
+
+    S, T = c.shape[:2]
+    t = jnp.arange(T, dtype=jnp.int32)[None, :]
+    rows = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None], (S, T))
+    return write_packed_kv(
+        c_cache, kr_cache, layer, c.reshape(S * T, 1, -1),
+        kr.reshape(S * T, 1, -1), block_tables, rows.reshape(-1),
+        (ctx_lens[:, None] + t).reshape(-1),
+        (t < true_lens[:, None]).reshape(-1))
+
+
+@jax.named_scope("dyn.attention")
+def mla_prefill_flash(
+    q_nope: jax.Array,        # [S, T, nh, dn]
+    q_rope: jax.Array,        # [S, T, nh, dr]
+    c_cache: jax.Array,       # the rows' chunks ALREADY written
+    kr_cache: jax.Array,
+    layer: int,
+    block_tables: jax.Array,  # [S, max_blocks]
+    ctx_lens: jax.Array,      # [S]
+    true_lens: jax.Array,     # [S]
+    w_uk: jax.Array,          # [nh, R, dn]
+    w_uv: jax.Array,          # [nh, R, dv]
+    *,
+    mesh=None,
+    interpret: bool = False,
+) -> jax.Array:
+    """`mla_prefill_attention` for every row of a program at once, as
+    the kernel (pallas_mla_attention.mla_prefill_pallas): query t of row
+    s attends its table's positions [0, ctx[s] + t].  Returns
+    [S, T, nh, dv]; a row's padding returns 0.  Per head shard under
+    `shard_map` where the mesh has a tp axis, like the decode read
+    (`_mla_decode_pallas`): the pools are replicated and the heads
+    shard through w_uk / w_uv."""
+    from jax.sharding import PartitionSpec as P
+
+    from .pallas_mla_attention import mla_prefill_pallas
+
+    # traced, so the kernel (a jit of its own) is traced and lowered
+    # once a program, not once a layer
+    layer = jnp.int32(layer)
+    if _tp(mesh) == 1:
+        return mla_prefill_pallas(
+            q_nope, q_rope, c_cache, kr_cache, layer, block_tables,
+            ctx_lens, true_lens, w_uk, w_uv, interpret=interpret)
+    # `kernel_tp_call` shards [tokens, heads, width]: the rows laid end
+    # to end on the way in and out
+    S, T = q_nope.shape[:2]
+    flat = lambda x: x.reshape(S * T, *x.shape[2:])
+
+    def local(qn, qr, cc, krc, tables, ctx, true, uk, uv):
+        rows = lambda x: x.reshape(S, T, *x.shape[1:])
+        return flat(mla_prefill_pallas(
+            rows(qn), rows(qr), cc, krc, layer, tables, ctx, true, uk, uv,
+            interpret=interpret))
+
+    out = kernel_tp_call(
+        mesh, local,
+        [flat(q_nope), flat(q_rope), c_cache, kr_cache, block_tables,
+         ctx_lens, true_lens, w_uk, w_uv],
+        [P(None, "tp", None), P(None, "tp", None), P(), P(), P(None, None),
+         P(None), P(None), P("tp", None, None), P("tp", None, None)])
+    return out.reshape(S, T, *out.shape[1:])
 
 
 def _mla_decode_jnp(q_abs, q_rope, c_cache, kr_cache, layer,

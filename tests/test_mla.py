@@ -486,3 +486,319 @@ def test_engine_counts_what_the_resolved_impl_reads(impl):
     assert counts["decode_attn_live_blocks"] == 2 * live
     assert counts["decode_attn_read_blocks"] == 2 * (
         live if impl == "pallas_interpret" else k * 4 * 16)
+
+
+# ---------------------------------------------------------------------------
+# the latent PREFILL read as one flash kernel over the pool's live blocks
+# (ops/pallas_mla_attention.py `mla_prefill_pallas`), the planes write
+# beside it and the rule that picks (PR 49)
+# ---------------------------------------------------------------------------
+
+# rows of (context, real tokens) in a bucket of 32 over tables of 6
+# blocks of 16: a row that starts mid-block with a padded tail, a fresh
+# row that fills its bucket, a row with nothing valid, one token at a
+# block's first column, a context of whole blocks
+PREFILL_ROWS = {
+    "one_fresh": [(0, 32)],
+    "one_carried_padded": [(21, 27)],
+    "several": [(21, 27), (0, 32), (40, 0), (48, 1), (32, 32)],
+}
+
+
+def _prefill_case(nh, rows, dtype, seed=5):
+    """-> (q_nope, q_rope, c, kr, pools, tables, ctx, true, w_uk, w_uv):
+    random operands over a two-layer pool whose garbage block is NaN."""
+    R, dr, dn, dv, bs, mb, T = 32, 8, 16, 16, 16, 6, 32
+    S = len(rows)
+    nb = 1 + S * mb
+    rng = np.random.default_rng(seed)
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)
+    pools = (normal(2, 1, nb, R, bs).at[:, :, 0].set(jnp.nan),
+             normal(2, 1, nb, dr, bs).at[:, :, 0].set(jnp.nan))
+    tables = jnp.asarray(1 + rng.permutation(nb - 1).reshape(S, mb),
+                         jnp.int32)
+    ctx = jnp.asarray([r[0] for r in rows], jnp.int32)
+    true = jnp.asarray([r[1] for r in rows], jnp.int32)
+    w = lambda d: jnp.asarray(rng.standard_normal((nh, R, d)) / R ** 0.5,
+                              dtype)
+    return (normal(S, T, nh, dn), normal(S, T, nh, dr), normal(S, T, R),
+            normal(S, T, dr), pools, tables, ctx, true, w(dn), w(dv))
+
+
+def _jnp_rows(qn, qr, c, kr, pools, tables, ctx, true, w_uk, w_uv):
+    from dynamo_tpu.ops.mla_attention import mla_prefill_attention
+
+    return jax.vmap(
+        lambda a, b, cb, krb, tb, cl, tl: mla_prefill_attention(
+            a, b, cb, krb, *pools, 1, tb, cl, tl, w_uk, w_uv)
+    )(qn, qr, c, kr, tables, ctx, true)
+
+
+@pytest.mark.parametrize("tiles", [{}, {"token_block": 8, "chunk_cols": 2},
+                                   {"token_block": 16, "chunk_cols": 1,
+                                    "heads_a_step": 4}],
+                         ids=["default", "q8_k32", "q16_k16_h4"])
+@pytest.mark.parametrize("rows", sorted(PREFILL_ROWS))
+@pytest.mark.parametrize("family", sorted(LATENT_WIDTHS))
+def test_latent_prefill_kernel_matches_the_jnp_form(family, rows, tiles):
+    """The flash kernel under the interpreter against the jnp form a
+    row, at both cells' head counts: every real query agrees (float32
+    operands: to rounding of the running sums), a row's padding and a
+    row with nothing valid return 0, nothing of the garbage block (NaN)
+    or of a block past a row's frontier reaches an output, and layer 1
+    of two is the layer read."""
+    from dynamo_tpu.ops.mla_attention import mla_write_rows
+    from dynamo_tpu.ops.pallas_mla_attention import mla_prefill_pallas
+
+    nh = LATENT_WIDTHS[family][0]
+    qn, qr, c, kr, pools, tables, ctx, true, w_uk, w_uv = _prefill_case(
+        nh, PREFILL_ROWS[rows], jnp.float32)
+    pools = mla_write_rows(*pools, 1, c, kr, tables, ctx, true)
+    want = np.asarray(_jnp_rows(qn, qr, c, kr, pools, tables, ctx, true,
+                                w_uk, w_uv))
+    got = np.asarray(mla_prefill_pallas(
+        qn, qr, *pools, jnp.int32(1), tables, ctx, true, w_uk, w_uv,
+        interpret=True, **tiles))
+    real = np.arange(32)[None, :] < np.asarray(true)[:, None]
+    assert got.shape == want.shape and np.isfinite(got).all()
+    np.testing.assert_allclose(got[real], want[real], atol=2e-5)
+    assert np.abs(got[~real]).max(initial=0.0) == 0.0
+    if real.any():
+        other = np.asarray(mla_prefill_pallas(
+            qn, qr, *(jnp.nan_to_num(p) for p in pools), jnp.int32(0),
+            tables, ctx, true, w_uk, w_uv, interpret=True, **tiles))
+        assert np.abs(other[real] - got[real]).max() > 0.05
+
+
+def test_latent_prefill_kernel_in_bf16_follows_the_jnp_form():
+    """bf16 pools, weights and queries (the serving dtypes): K, V and
+    the weights of the softmax are rounded to bf16 between the kernel's
+    products, as XLA's default precision rounds the jnp form's on the
+    chip; against the jnp form's float32 products on the CPU the
+    outputs (averages of unit normals) agree to bf16's step."""
+    from dynamo_tpu.ops.mla_attention import mla_prefill_flash, mla_write_rows
+
+    qn, qr, c, kr, pools, tables, ctx, true, w_uk, w_uv = _prefill_case(
+        16, PREFILL_ROWS["several"], jnp.bfloat16)
+    pools = mla_write_rows(*pools, 1, c, kr, tables, ctx, true)
+    want = np.asarray(_jnp_rows(qn, qr, c, kr, pools, tables, ctx, true,
+                                w_uk, w_uv), np.float32)
+    got = mla_prefill_flash(qn, qr, *pools, 1, tables, ctx, true, w_uk,
+                            w_uv, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    real = np.arange(32)[None, :] < np.asarray(true)[:, None]
+    np.testing.assert_allclose(np.asarray(got, np.float32)[real], want[real],
+                               atol=0.03)
+
+
+@pytest.mark.parametrize("n_c,tk,ctx,true,want", [
+    # one tile of 8 real queries at positions 16..23 over key tiles of 8:
+    # tiles 0 to 2 hold keys a query sees, tile 3 lies above the frontier
+    (4, 8, 16, 8, [1, 1, 1, 0]),
+    # a padded tail: runs to the farthest REAL query (position 16)
+    (4, 8, 16, 1, [1, 1, 1, 0]),
+    (4, 8, 15, 1, [1, 1, 0, 0]),
+    # nothing valid: nothing runs
+    (4, 8, 16, 0, [0, 0, 0, 0]),
+    # a fresh row: the diagonal tile alone
+    (4, 8, 0, 8, [1, 0, 0, 0]),
+])
+def test_prefill_tile_plan_skips_what_no_query_sees(n_c, tk, ctx, true,
+                                                    want):
+    """`prefill_tile_plan`: which pairs of one query tile of 8 tokens
+    run, and a skipped step names the blocks of the last pair that ran;
+    a row's second tile starts 8 tokens on."""
+    from dynamo_tpu.ops.pallas_mla_attention import prefill_tile_plan
+
+    runs, fetch = prefill_tile_plan(
+        jnp.asarray([ctx], jnp.int32), jnp.asarray([true], jnp.int32), 1, 8,
+        n_c, tk)
+    assert np.asarray(runs).tolist() == [want]
+    live = max(sum(want), 1)
+    assert np.asarray(fetch).tolist() == [
+        [min(j, live - 1) for j in range(n_c)]]
+    runs2, _ = prefill_tile_plan(
+        jnp.asarray([ctx, 0], jnp.int32), jnp.asarray([16, 12], jnp.int32),
+        2, 8, n_c, tk)
+    assert np.asarray(runs2).shape == (4, n_c)
+    assert np.asarray(runs2)[2:].tolist() == [[1, 0, 0, 0], [1, 1, 0, 0]]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("rows", sorted(PREFILL_ROWS))
+def test_latent_rows_write_sets_the_cells_the_scatter_sets(rows, dtype):
+    """`mla_write_rows` (whole planes in the resident layout, the padded
+    rows laid end to end as a packed stream) against
+    `write_prompt_kv_batched` (the flat column scatter): bit-equal pools
+    outside the garbage block, which the scatter alone dirties with the
+    padding; layer 0 untouched."""
+    from dynamo_tpu.ops.mla_attention import mla_write_rows
+    from dynamo_tpu.ops.paged_attention import write_prompt_kv_batched
+
+    _, _, c, kr, pools, tables, ctx, true, _, _ = _prefill_case(
+        4, PREFILL_ROWS[rows], dtype)
+    pools = tuple(jnp.nan_to_num(p) for p in pools)
+    want = write_prompt_kv_batched(
+        *pools, 1, c[:, :, None, :], kr[:, :, None, :], tables, ctx, true)
+    got = mla_write_rows(*pools, 1, c, kr, tables, ctx, true)
+    for w, g, before in zip(want, got, pools):
+        f32 = lambda x: np.asarray(x, np.float32)
+        assert np.array_equal(f32(w)[:, :, 1:], f32(g)[:, :, 1:])
+        assert np.array_equal(f32(g)[:, :, 0], f32(before)[:, :, 0])
+        assert np.array_equal(f32(g[0]), f32(before[0]))
+        assert not np.array_equal(f32(g[1]), f32(before[1]))
+
+
+@pytest.mark.parametrize("impl,platform,block,dtype,tokens,want", [
+    ("auto", "tpu", 128, jnp.bfloat16, 2048, "pallas"),
+    ("auto", "tpu", 128, jnp.bfloat16, 512, "pallas"),
+    ("auto", "tpu", 128, jnp.bfloat16, 256, "jnp"),       # under the floor
+    ("auto", "tpu", 128, jnp.bfloat16, 32, "jnp"),
+    ("auto", "cpu", 128, jnp.bfloat16, 2048, "jnp"),
+    ("auto", "tpu", 16, jnp.bfloat16, 2048, "jnp"),
+    ("auto", "tpu", 128, jnp.float32, 2048, "jnp"),
+    # what the engine has made of "auto" by the time a program is traced
+    ("pallas", "tpu", 128, jnp.bfloat16, 1024, "pallas"),
+    ("pallas", "tpu", 128, jnp.bfloat16, 256, "jnp"),
+    ("pallas", "cpu", 128, jnp.bfloat16, 512, "pallas"),  # a described chip
+    ("pallas_interpret", "cpu", 16, jnp.float32, 8, "pallas_interpret"),
+    ("jnp", "tpu", 128, jnp.bfloat16, 2048, "jnp"),       # the A/B
+])
+def test_prefill_impl_is_decided_from_what_the_code_sees(
+        impl, platform, block, dtype, tokens, want):
+    """`resolve_mla_prefill_impl`: the kernel where the decode kernel
+    runs, from the 512-token bucket up; and both families ask it the
+    same way (`mla_prefill_impl`)."""
+    import dataclasses
+
+    from dynamo_tpu.models.deepseek import mla_prefill_impl
+    from dynamo_tpu.models.ling import LingConfig
+    from dynamo_tpu.ops.mla_attention import (
+        MLA_PREFILL_KERNEL_MIN_TOKENS,
+        resolve_mla_prefill_impl,
+    )
+
+    assert MLA_PREFILL_KERNEL_MIN_TOKENS == 512
+    assert resolve_mla_prefill_impl(impl, platform, block, (512, 64), dtype,
+                                    tokens) == want
+    if platform == "cpu":      # the host the test runs on
+        for cfg in (DeepseekConfig(attn_impl=impl),
+                    LingConfig(attn_impl=impl)):
+            assert mla_prefill_impl(cfg, tokens, block, dtype) == want
+        # the host's counts have no cache to show: an impl the engine
+        # resolved is taken as given, an unresolved "auto" is jnp
+        assert mla_prefill_impl(DeepseekConfig(attn_impl=impl), tokens) == (
+            "jnp" if impl == "auto" else want)
+
+
+def test_latent_prefill_runs_per_head_shard_under_tp():
+    """`mla_prefill_flash` under a tp = 2 mesh: the pools replicated,
+    the heads sharded through the queries and w_uk / w_uv, each shard
+    the kernel on its own heads: the unsharded jnp form's outputs."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dynamo_tpu.ops.mla_attention import mla_prefill_flash, mla_write_rows
+    from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    qn, qr, c, kr, pools, tables, ctx, true, w_uk, w_uv = _prefill_case(
+        4, PREFILL_ROWS["several"], jnp.float32)
+    pools = mla_write_rows(*pools, 1, c, kr, tables, ctx, true)
+    want = np.asarray(_jnp_rows(qn, qr, c, kr, pools, tables, ctx, true,
+                                w_uk, w_uv))
+    mesh = make_mesh(MeshConfig(dp=4, tp=2))
+    heads = NamedSharding(mesh, P("tp", None, None))
+    with mesh:
+        got = jax.jit(lambda *a: mla_prefill_flash(
+            *a, mesh=mesh, interpret=True))(
+            qn, qr, *pools, 1, tables, ctx, true,
+            jax.device_put(w_uk, heads), jax.device_put(w_uv, heads))
+    real = np.arange(32)[None, :] < np.asarray(true)[:, None]
+    np.testing.assert_allclose(np.asarray(got)[real], want[real], atol=2e-5)
+
+
+@pytest.mark.parametrize("family", ["deepseek", "ling"])
+def test_prefill_through_the_kernel_gives_the_jnp_logits(family):
+    """prefill_batched of both families, two rows (one carried from a
+    first chunk, one fresh with a padded tail): `pallas_interpret` (the
+    flash kernel + the planes write) against `jnp` (the gather + the
+    column scatter): equal logits and, on every block a row owns, equal
+    latents and rope keys, to float32 rounding (a later layer's latents
+    carry the earlier reads' rounding)."""
+    import dataclasses
+
+    from dynamo_tpu.models import deepseek, ling
+
+    if family == "ling":
+        mod = ling
+        cfg = ling.LingConfig(dtype=jnp.float32, experts_held=(0, 8),
+                              mla_q_block=16)
+        lane_kw = {"lanes": jnp.asarray([0, 2], jnp.int32)}
+        kv = tuple(jnp.zeros(s, d) for s, d in zip(
+            ling.kv_cache_shapes(cfg, 32, 4, lanes=3),
+            ling.kv_cache_dtypes(cfg)))
+    else:
+        mod, cfg, lane_kw = deepseek, dataclasses.replace(
+            MLA32, dtype=jnp.float32), {}
+        kv = tuple(jnp.zeros(s, jnp.float32) for s in kv_cache_shapes(
+            cfg, 32, 4))
+    params = mod.init_params(cfg, jax.random.PRNGKey(12))
+    rng = np.random.default_rng(4)
+    toks = jnp.asarray(rng.integers(3, cfg.vocab_size, (2, 32)), jnp.int32)
+    tables = jnp.asarray(np.stack([1 + np.arange(12), 13 + np.arange(12)]),
+                         jnp.int32)
+    pos = jnp.arange(32, dtype=jnp.int32)[None, :]
+    out = {}
+    for impl in ("jnp", "pallas_interpret"):
+        c = dataclasses.replace(cfg, attn_impl=impl)
+        zero = jnp.zeros((2,), jnp.int32)
+        # chunk 1: row 0 takes 13 tokens; chunk 2: row 0 goes on from 13
+        # with 19 more, row 1 starts with 30 of 32
+        _, kv1 = mod.prefill_batched(
+            params, c, kv, toks, pos + zero[:, None], tables, zero,
+            jnp.asarray([13, 0], jnp.int32), **lane_kw)
+        ctx = jnp.asarray([13, 0], jnp.int32)
+        out[impl] = mod.prefill_batched(
+            params, c, kv1, toks, pos + ctx[:, None], tables, ctx,
+            jnp.asarray([19, 30], jnp.int32), **lane_kw)
+    (la, kva), (lb, kvb) = out["jnp"], out["pallas_interpret"]
+    np.testing.assert_allclose(np.asarray(la), np.asarray(lb), atol=2e-4)
+    for a, b in zip(kva[:2], kvb[:2]):
+        np.testing.assert_allclose(np.asarray(a[:, :, 1:25]),
+                                   np.asarray(b[:, :, 1:25]), atol=1e-4)
+
+
+async def test_engine_serves_mla_through_the_prefill_kernel():
+    """JaxEngine on the MLA family with `attn_impl="pallas_interpret"`:
+    prompts prefilled through the flash kernel and the planes write
+    (one in two chunks) give the jnp engine's greedy tokens, and both
+    counters say so: MLA layers x prompt tokens, all of them in the
+    kernel; under `jnp` the second stays 0."""
+    import dataclasses
+
+    cfg32 = dataclasses.replace(MLA32, dtype=jnp.float32)
+    rng = np.random.default_rng(21)
+    prompts = [rng.integers(3, 256, n).tolist() for n in (11, 45)]
+    got = {}
+    for impl in ("jnp", "pallas_interpret"):
+        eng = JaxEngine(EngineConfig(
+            model_config=cfg32, block_size=4, num_blocks=128,
+            max_blocks_per_seq=16, max_num_seqs=4, attn_impl=impl,
+            prefill_buckets=(8, 16, 32), prefill_chunk_tokens=32, seed=7))
+        assert eng.metrics["mla_prefill_tokens.prefill"] == 0
+        outs = []
+        for i, prompt in enumerate(prompts):
+            toks = []
+            async for frame in eng.generate(PreprocessedRequest(
+                    token_ids=prompt, request_id=f"p{i}",
+                    sampling=SamplingOptions(temperature=0.0, seed=0),
+                    stop=StopConditions(max_tokens=4, ignore_eos=True))):
+                toks.extend(frame.token_ids)
+            outs.append(toks)
+        got[impl] = outs
+        n = cfg32.n_layers * sum(len(p) for p in prompts)
+        assert eng.metrics["mla_prefill_tokens.prefill"] == n
+        assert eng.metrics["mla_prefill_kernel_tokens.prefill"] == (
+            n if impl == "pallas_interpret" else 0)
+        await eng.close()
+    assert got["jnp"] == got["pallas_interpret"]

@@ -642,7 +642,10 @@ def test_recurrent_decode_and_prefill_compile_for_v5e(one_chip):
     # [N, H, n, C, dk] float32 intermediates (`k_seen`: 134 MB a layer)
     # nor its [N, H, C, C] matrices is left in the program
     assert ling.chunk_impl(cfg, cfg.attn_impl, T) in PALLAS_IMPLS
-    assert hlo.count("tpu_custom_call") == 3 * 4 + 5
+    # and since PR 49 the MLA layer's prefill read is ONE more
+    # (`mla_prefill_impl` of the bucket;
+    # test_latent_prefill_reads_the_pool_where_it_lies)
+    assert hlo.count("tpu_custom_call") == 3 * 4 + 5 + 1
     for lead in ("", "1,"):
         assert f"f32[{lead}{T // 64},32,4,64,128]" not in hlo
         assert f"f32[{lead}{T // 64},32,64,64]" not in hlo
@@ -804,6 +807,184 @@ def test_latent_decode_runs_per_head_shard_under_tp(topo):
     assert {op for _, op in made} <= {
         "parameter", "get-tuple-element", "while", "bitcast"}, \
         sorted(set(made))
+
+
+def _latent_prefill_lowered(mod, cfg, S, T, MB, NB, lanes):
+    """The engine's own `prefill` program of a latent family, one row of
+    T tokens, lowered for the shardings `S` makes."""
+    from dynamo_tpu.engine.core import JaxEngine
+
+    shapes = jax.eval_shape(
+        lambda: mod.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    lane_kw = {"lanes": lanes} if lanes else {}
+    kv_shapes = mod.kv_cache_shapes(cfg, NB, BS, **lane_kw)
+    dtypes = (mod.kv_cache_dtypes(cfg) if lanes
+              else (cfg.dtype,) * len(kv_shapes))
+    kv = tuple(S(s, d) for s, d in zip(kv_shapes, dtypes))
+    i32, f32 = jnp.int32, jnp.float32
+    lane = (None, None, S((), i32)) if lanes else ()
+    return shapes, jax.jit(
+        partial(JaxEngine._prefill_impl, mod, cfg), donate_argnums=(1,)
+    ).lower(params, kv, S((T,), i32), S((T,), i32), S((MB,), i32),
+            S((), i32), S((), i32), S((), i32), S((), f32), S((), i32),
+            S((), f32), *lane)
+
+
+@pytest.mark.parametrize("family", ["moonlight", "ling"])
+def test_latent_prefill_reads_the_pool_where_it_lies(one_chip, family):
+    """The 2048-token `prefill` program of the two latent-attention
+    families at their cells' tables and pools, `auto` resolved as on the
+    chip: ONE custom call more an MLA layer (the flash read over the
+    pool's live blocks, PR 49: `mla_prefill_impl` of the bucket) from
+    ONE lowering (the layer index is traced), the chunk written as
+    whole planes, and both pools handed from write to read to write
+    where they lie: no copy of a pool, no layer's slice, one layout, and
+    the only fusion that gives out a pool gives out the in-place write
+    (`_assert_pool_stays_where_it_lies`; beside the kernel the flat
+    column scatter had XLA relay the whole pool every layer).  Nothing
+    of the jnp form is left: no gathered table, no table-wide K, V or
+    scores.  The 256-token program keeps the jnp read (under the
+    rule's floor) and writes whole planes all the same."""
+    import re
+
+    from dynamo_tpu.models.deepseek import mla_prefill_impl
+    from dynamo_tpu.ops.paged_attention import PALLAS_IMPLS
+
+    mod, cfg, B, MB, NB, _, n_mla = _latent_case(family)
+    cfg = dataclasses.replace(cfg, attn_impl="pallas")
+    R, dr = cfg.mla_plane_heights
+    T = 2048
+    assert mla_prefill_impl(cfg, T, BS, cfg.dtype) in PALLAS_IMPLS
+    lanes = B if getattr(mod, "KV_LANE_ADDRESSED", False) else 0
+    S = _sds(one_chip)
+    shapes, lowered = _latent_prefill_lowered(mod, cfg, S, T, MB, NB, lanes)
+    n_moe = sum("moe_w_up" in layer for layer in shapes["layers"])
+    n_state = len(shapes["layers"]) - n_mla if lanes else 0
+    # the read's one lowering: a private function the layers call
+    text = lowered.as_text()
+    assert text.count('kernel_name = "_mla_prefill_kernel"') == 1
+    program = lowered.compile()
+    hlo = program.as_text()
+    assert hlo.count("tpu_custom_call") == n_mla + n_state + 3 * n_moe
+    for hd in (R, dr):
+        _assert_pool_stays_where_it_lies(hlo, n_mla, 1, NB, hd)
+        # nor in VMEM (`_in_hbm`: a rope-key pool that fits XLA's share
+        # of it would be moved out and back around every call)
+        assert not re.search(rf"bf16\[{n_mla},1,{NB},{hd},{BS}\]\{{[^}}]*S\(1\)",
+                             hlo)
+        assert f"bf16[{MB},{hd},{BS}]" not in hlo       # the gathered table
+        assert f"f32[{MB * BS},{hd}]" not in hlo
+    nh = cfg.n_heads
+    assert f"f32[{nh},{MB * BS + T},128]" not in hlo    # table-wide K / V
+    assert f"f32[{T},{nh},{MB * BS + T}]" not in hlo    # and scores
+    # a short bucket: the jnp read, the planes write
+    assert mla_prefill_impl(cfg, 256, BS, cfg.dtype) == "jnp"
+    _, short = _latent_prefill_lowered(mod, cfg, S, 256, MB, NB, lanes)
+    hlo = short.compile().as_text()
+    for hd in (R, dr):
+        pool = rf"bf16[{n_mla},1,{NB},{hd},{BS}]"
+        assert pool + "{4,3,2,1,0" in hlo
+        assert pool + "{3,1,4,2,0" not in hlo           # the scatter's
+
+
+def test_latent_prefill_runs_per_head_shard_under_tp(topo):
+    """Moonlight's 2048-token `prefill` for four described chips,
+    tp = 4, the parameters placed by the engine's rules: the flash read
+    runs per head shard under `shard_map` (4 of 16 heads a chip, the
+    pools replicated and whole): one custom call a layer, and no
+    collective gives out a pool, a layer of one, or the heads gathered
+    for the kernel."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.parallel.mesh import param_sharding_rules
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4, 1), ("dp", "tp", "sp"))
+    mod, cfg, _, MB, NB, _, n_mla = _latent_case("moonlight")
+    cfg = dataclasses.replace(cfg, attn_impl="pallas", expert_shards=4)
+    R, dr = cfg.mla_plane_heights
+    T, nh = 2048, cfg.n_heads
+    rules = param_sharding_rules()
+
+    def placed(path, x):
+        name = next((k.key for k in reversed(path)
+                     if isinstance(getattr(k, "key", None), str)), None)
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype,
+            sharding=NamedSharding(mesh, rules.get(name, P())))
+
+    params = jax.tree_util.tree_map_with_path(placed, jax.eval_shape(
+        lambda: mod.init_params(cfg, jax.random.PRNGKey(0))))
+    S = lambda shape, dt: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, P()))
+    kv = tuple(S(s, cfg.dtype) for s in mod.kv_cache_shapes(cfg, NB, BS))
+    i32, f32 = jnp.int32, jnp.float32
+    hlo = jax.jit(
+        partial(JaxEngine._prefill_impl, mod, cfg, mesh=mesh),
+        donate_argnums=(1,)).lower(
+        params, kv, S((T,), i32), S((T,), i32), S((MB,), i32), S((), i32),
+        S((), i32), S((), i32), S((), f32), S((), i32), S((), f32)
+    ).compile().as_text()
+    assert hlo.count("tpu_custom_call") == n_mla
+    # a shard's queries and output as the kernel takes them
+    assert f"bf16[{T},{nh // 4 * 128}]" in hlo
+    made = re.findall(rf"= (\w+\[(?:{n_mla},)?1,{NB},(?:{R}|{dr}),{BS}\]|"
+                      rf"\w+\[{T},{nh * 128}\])\S* (all-gather|all-to-all|"
+                      rf"collective-permute|copy)\(", hlo)
+    assert not made, sorted(set(made))
+
+
+def test_latent_prefill_body_stays_small(one_chip):
+    """The SIZE guard.  Mosaic's code for the flash read's body is
+    embedded once a layer in every kernel-bearing prefill program and is
+    read, deserialized and loaded at every start: PR 48's body (8 heads
+    unrolled a loop step, a pair with and without the mask, hi + lo
+    products) made the 8-layer Moonlight-shaped program of the reads
+    alone 27.0 MB where the jnp form's is 8.0 MB, 14 s to compile where
+    it takes 2.2, and cost `moonlight-16b.chat` 11 s of `setup_s`, which
+    refused it.  Today (PR 49: 2 heads a step, 512 x 512 tiles, one
+    body): 8.3-8.7 MB and 1.6 s here (2.8 s on the chip's host).  The
+    serialized executable is held to 1.5 x the jnp form's, so that a
+    later tuning cannot grow the body unseen (`bench_mla_prefill.py
+    --tune` prints all three numbers a candidate)."""
+    from jax.experimental.serialize_executable import serialize
+
+    from dynamo_tpu.ops.mla_attention import mla_prefill_attention
+    from dynamo_tpu.ops.pallas_mla_attention import mla_prefill_pallas
+
+    S = _sds(one_chip)
+    bf, i32 = jnp.bfloat16, jnp.int32
+    L, T, nh, MB, NB, R, dr, dn, dv = 8, 2048, 16, 20, 512, 512, 64, 128, 128
+    args = (S((T, nh, dn), bf), S((T, nh, dr), bf), S((T, R), bf),
+            S((T, dr), bf), S((L, 1, NB, R, BS), bf),
+            S((L, 1, NB, dr, BS), bf), S((MB,), i32), S((), i32),
+            S((), i32), S((L, nh, R, dn), bf), S((L, nh, R, dv), bf))
+
+    def reads(kernel):
+        def program(qn, qr, c, kr, cc, krc, table, ctx, true, w_uk, w_uv):
+            out = 0.0
+            for li in range(L):
+                if kernel:
+                    o = mla_prefill_pallas(
+                        qn[None], qr[None], cc, krc, jnp.int32(li),
+                        table[None], ctx[None], true[None], w_uk[li],
+                        w_uv[li])[0]
+                else:
+                    o = mla_prefill_attention(
+                        qn, qr, c, kr, cc, krc, li, table, ctx, true,
+                        w_uk[li], w_uv[li])
+                out = out + o.astype(jnp.float32)
+                qn = (qn + 1e-3 * o[..., :dn]).astype(bf)
+            return out
+        compiled = jax.jit(program).lower(*args).compile()
+        return len(serialize(compiled)[0])
+
+    jnp_bytes, kernel_bytes = reads(False), reads(True)
+    assert kernel_bytes <= 1.5 * jnp_bytes, (kernel_bytes, jnp_bytes)
 
 
 def test_ssm_decode_and_prefill_compile_for_v5e(topo, one_chip):
