@@ -695,6 +695,11 @@ class JaxEngine:
             # (_read_back_first): over req_stage_n, the share that
             # "read back first, admit after" served a step sooner
             "req_admitted_after_wait": 0,
+            # decode bursts dispatched with their size from _fused_k's
+            # decode-only branch, and those of them it held at the
+            # interleave rung because a lane stood free (PR 50): the
+            # share says how often the rule engaged
+            "decode_only_bursts": 0, "decode_held_bursts": 0,
             # after the first token (_push_token): first -> second token
             # over the requests that got a second; second -> finish over
             # the tokens after the second of the requests that finished
@@ -770,6 +775,9 @@ class JaxEngine:
         # adaptive decode fusion: consecutive decode-only steps (the
         # fusion ladder's ramp clock); reset on arrivals/cancellations
         self._decode_only_run = 0
+        # whether the request admitted last joined others on their
+        # lanes (_admit_waiting): _fused_k holds the ladder then
+        self._shared = False
         # SLA-aware admission: worst SLO burn rate the worker last fed
         # us (obs/slo.py via the worker's slo_metrics subscription) and
         # when — stale signals decay to 0 (_effective_slo_burn)
@@ -2418,6 +2426,9 @@ class JaxEngine:
                     return  # capacity: stay in queue (FIFO)
                 self.waiting.pop(0)
             self._emit_events(res)
+            # a request that joins others shares the engine; one that
+            # comes to an empty engine is a single stream (_fused_k)
+            self._shared = any(s is not None for s in self._slots)
             slot.index = free_idx
             self._slots[free_idx] = slot
             if slot.admitted_t == 0.0:
@@ -3492,13 +3503,19 @@ class JaxEngine:
                 else max(1, c.spec_k // 2)
 
     # -- decode -----------------------------------------------------------
-    # decode burst size while prefill/admission work is pending: single
+    # decode burst size while prefill/admission work is pending, and
+    # (PR 50) in a decode-only stretch while a lane stands free: single
     # stepping bounds how long a chunk waits behind decode, but every
     # dispatch has a fixed host cost — at burst 1 that interleave tax
     # can dominate the prefill phase.  A burst of 4 amortizes the
     # dispatch 4x while holding a prefill chunk back ~3 extra steps.
-    # The value was chosen on an earlier set-up and is to be measured
-    # again on today's.
+    # What a burst costs at its edges, f = 2 t(4) - t(8) of a burst
+    # alone under the profiler (PERF.md section 5, PR 50): 2.55 ms on
+    # Mistral-7B's 9.98 ms step (42.48 / 82.41 ms), 0.43 ms on
+    # Moonlight's 2.86 (11.85 / 23.27), 0.4 ms on Nemotron's 4.45
+    # (18.2 / 36.0) — so 4-step bursts cost f / 8 = 0.05-0.32 ms a
+    # token over 8-step ones, and stand 4 steps less ahead of the next
+    # arrival's first chunk.
     INTERLEAVE_BURST = 4
 
     def _fuse_ladder(self) -> List[int]:
@@ -3515,28 +3532,43 @@ class JaxEngine:
             k = min(k * 2, fused)
         return ladder
 
-    def _fused_k(self) -> int:
-        """Decode-burst size for this step (the adaptive fusion policy).
+    def _fused_k(self) -> Tuple[int, Optional[bool]]:
+        """Decode-burst size for this step (the adaptive fusion policy)
+        and, where the decode-only branch sized it, whether that branch
+        HELD it at the interleave rung (None where pending work did).
 
         Pending admissions or prefill chunks run between SHORT decode
         bursts (chunked-prefill interleaving — a full burst would hold
         them back k steps): any pending work de-fuses to the interleave
         burst and resets the ramp.  In a decode-only stretch the burst
-        ramps up the fusion ladder one rung per step, so the steps right
-        after an arrival stay short (TTFT) while steady state reaches
-        full decode_fused_steps within log2 steps (throughput)."""
+        ramps up the fusion ladder one rung per step — but (PR 50) not
+        while a lane stands free on an engine that requests SHARE.
+        With a lane free (the test _admit_waiting makes) the next
+        arrival would be admitted in the very step that sees it, and
+        the burst queued now is what stands ahead of its first chunk on
+        the in-order device: it stays at the interleave rung and the
+        ramp does not advance.  With every lane taken an arrival waits
+        for a lane whatever the burst's length, so steady state reaches
+        full decode_fused_steps within log2 steps (throughput), as
+        before.  So does a SINGLE STREAM: a request that came to an
+        empty engine ramps as it always did until a second one joins it
+        (`_shared`, set at every admission: another request held a
+        lane); one user of a server keeps full bursts, and a probe of
+        one request still meets every rung's program."""
         c = self.config
         if self._jit_decode_multi is None:
-            return 1
+            return 1, None
+        short = min(self.INTERLEAVE_BURST, c.decode_fused_steps)
         if (self.waiting
                 or any(s is not None and (s.prefilling or s.awaiting_first)
                        for s in self._slots)):
             self._decode_only_run = 0
-            return min(self.INTERLEAVE_BURST, c.decode_fused_steps)
-        k = min(self.INTERLEAVE_BURST << self._decode_only_run,
-                c.decode_fused_steps)
+            return short, None
+        if self._shared and any(s is None for s in self._slots):
+            return short, short < c.decode_fused_steps
+        k = min(short << self._decode_only_run, c.decode_fused_steps)
         self._decode_only_run = min(self._decode_only_run + 1, 16)
-        return k
+        return k, False
 
     def _decode_step(self) -> None:
         """Grow the active slots' block tables, then build and dispatch
@@ -3569,7 +3601,7 @@ class JaxEngine:
         c = self.config
         while len(self._inflight) >= self._depth:
             self._process_oldest_burst()
-        k = self._fused_k()
+        k, held = self._fused_k()
         # slots that speculated this step already emitted synchronously
         # (engine/_spec_step); dispatching them again would double-step.
         # awaiting_first slots have no last_token yet (deferred prefill
@@ -3652,7 +3684,10 @@ class JaxEngine:
             lanes[s.index] = (self._seq_id(s), s.epoch)
             self._chain_owner[s.index] = lanes[s.index]
         self._inflight.append({"burst": burst, "k": k, "lanes": lanes})
-        ph.set(cont=cont_burst, k=k, lanes=len(active))
+        if held is not None:
+            self.metrics["decode_only_bursts"] += 1
+            self.metrics["decode_held_bursts"] += held
+        ph.set(cont=cont_burst, k=k, k_held=bool(held), lanes=len(active))
         return True
 
     def _build_burst(self, active, k: int):

@@ -63,7 +63,9 @@ class MockEngineArgs:
     # BURST instead of per token, the same dispatch-amortization the
     # real engine's fused path buys.  De-fuses to the interleave burst
     # (min(4, decode_fused_steps) — the real _fused_k policy) the step
-    # an arrival or prefill chunk appears.  Token streams are
+    # an arrival or prefill chunk appears, and stays there while fewer
+    # than max_num_seqs sequences run (a lane is free) on an engine
+    # that more than one request shares.  Token streams are
     # byte-identical either way (position-addressed stream).
     overlap_scheduling: bool = True
     decode_fused_steps: int = 8
@@ -257,6 +259,9 @@ class MockEngine:
         # dispatch's (membership, k) — a matching pair is a continuation
         # burst (`cont` span attr, the real engine's zero-upload path)
         self._decode_run = 0
+        # the sequence admitted last joined others (the real engine's
+        # `_shared`): the ladder is held while a lane stands free
+        self._shared = False
         self._last_decode_key = None
 
     def _sim_compile(self, family: str, tokens: int,
@@ -507,6 +512,7 @@ class MockEngine:
                 seq.prefill_pos = seq.num_prompt_tokens
             self._publish(res)
             self.waiting.pop(0)
+            self._shared = bool(self.running)   # it joins others
             self.running.append(seq)
 
     async def _step(self) -> None:
@@ -582,7 +588,12 @@ class MockEngine:
         # adaptive decode fusion (overlap sim, the real _fused_k policy):
         # pending arrivals / prefill chunks de-fuse to the interleave
         # burst within one step (the TTFT bound); a decode-only stretch
-        # ramps interleave -> 2x -> ... -> decode_fused_steps
+        # ramps interleave -> 2x -> ... -> decode_fused_steps, but not
+        # while a lane stands free on an engine that requests share
+        # (the one admitted last joined others): the next
+        # arrival's first chunk would stand behind the burst queued
+        # now, so it stays at the interleave burst and the ramp does
+        # not advance; a single stream ramps as it always did
         k = 1
         if (self.args.overlap_scheduling and decode_seqs
                 and self.args.decode_fused_steps > 1
@@ -593,6 +604,9 @@ class MockEngine:
             ib = min(4, self.args.decode_fused_steps)
             if prefill_tokens or self.waiting:
                 self._decode_run = 0
+                k = ib
+            elif (self._shared
+                  and len(self.running) < self.args.max_num_seqs):
                 k = ib
             else:
                 k = min(ib << min(self._decode_run, 10),

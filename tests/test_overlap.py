@@ -229,6 +229,92 @@ async def test_adaptive_fusion_ramps_and_defuses_on_arrival():
     await eng.close()
 
 
+async def _ladder_run(overlap, fused, lanes, n_long, tag, tmp_path):
+    """`n_long` long requests (160 and 60 tokens) on `lanes` lanes, to
+    their ends.  Returns the token streams, every dispatched decode
+    burst as (k, k_held, lanes decoding) in order, and the counters."""
+    from dynamo_tpu import obs
+
+    eng = engine(overlap_scheduling=overlap, decode_fused_steps=fused,
+                 max_num_seqs=lanes, block_size=16,
+                 prefill_buckets=(16, 32))
+    with obs.Tracer(out_path=str(tmp_path / f"{tag}.json")) as tr:
+        outs = await asyncio.wait_for(asyncio.gather(*[
+            collect(eng, greedy_req(PROMPTS[i], n, f"{tag}-r{i}"))
+            for i, n in enumerate([160, 60][:n_long])]), 180.0)
+    bursts = [(a["k"], a["k_held"], a["lanes"])
+              for name, _, _, _, a, *_ in tr.spans
+              if name == "decode_dispatch"]
+    m = dict(eng.metrics)
+    await eng.close()
+    return outs, bursts, m
+
+
+LADDER_CASES = {
+    # name: (decode_fused_steps, lanes, long requests)
+    "a-two-share-a-free-lane-hold-at-4": (8, 3, 2),
+    "a-single-stream-ramps-as-before": (8, 2, 1),
+    "b-full-lanes-ramp-to-8": (8, 2, 2),
+    "c-a-finish-brings-4-back": (8, 2, 2),
+    "d-cap-1-untouched": (1, 3, 2),
+    "d-cap-4-untouched": (4, 3, 2),
+    "e-streams-as-lockstep-shared": (8, 3, 2),
+    "e-streams-as-lockstep-full": (8, 2, 2),
+    "e-streams-as-lockstep-single": (8, 2, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(LADDER_CASES))
+async def test_burst_ladder_holds_while_a_lane_stands_free(case, tmp_path):
+    """_fused_k's rule (PR 50): in a decode-only stretch of an engine
+    that requests share, the ladder climbs only while NO lane is free.
+    With one free the burst stays at the interleave rung (it is what
+    would stand ahead of the next arrival's first chunk) and is counted
+    held, also after one of the two has finished; with every lane taken
+    the ramp reaches decode_fused_steps and nothing is held; a single
+    stream ramps as it always did; a cap at or under the interleave
+    rung leaves nothing to hold; the greedy streams are the lockstep
+    mode's either way."""
+    fused, lanes, n_long = LADDER_CASES[case]
+    outs, bursts, m = await _ladder_run(True, fused, lanes, n_long, case,
+                                        tmp_path)
+    only, held = m["decode_only_bursts"], m["decode_held_bursts"]
+    assert [len(o) for o in outs] == [160, 60][:n_long]
+    assert held == sum(h for _, h, _ in bursts) <= only <= len(bursts)
+    # every lane decoding: no lane is free, nothing may be held
+    full = [(k, h) for k, h, busy in bursts if busy == lanes]
+    assert not any(h for _, h in full)
+    if case.startswith("a-two"):
+        assert {k for k, _, _ in bursts} == {4}
+        assert held == only > 40        # the 100 tokens r0 decodes alone
+        # the benchmark's reader of the two counters; a parent that
+        # lacks them reports nothing and does not raise
+        from benchmark.lib import spec
+        read = spec.metric_reader("layer_metrics", "burst_held_share")
+        assert read({"counters_open": {}, "counters_close": m}) == 100.0
+        assert read({"counters_open": {}, "counters_close": {}}) is None
+    elif case.startswith("a-single"):
+        assert max(k for k, _, _ in bursts) == 8, "one stream never ramped"
+        assert only > 0 and held == 0
+    elif case.startswith("b-"):
+        assert max(k for k, _ in full) == 8, "full lanes never ramped"
+    elif case.startswith("c-"):
+        # the bursts dispatched after the last one over both lanes: the
+        # short request's finish has been read back, its lane is free
+        last_full = max(i for i, b in enumerate(bursts) if b[2] == 2)
+        tail = bursts[last_full + 1:]
+        assert bursts[last_full][0] == 8 and len(tail) > 10
+        assert all(k == 4 and h for k, h, _ in tail), tail
+    elif case.startswith("d-"):
+        # (a cap of 1 has no fused program: no branch of the rule runs)
+        assert {k for k, _, _ in bursts} == {fused}
+        assert held == 0 and (only > 0) == (fused > 1)
+    else:
+        ref, _, _ = await _ladder_run(False, fused, lanes, n_long,
+                                      case + "-sync", tmp_path)
+        assert outs == ref
+
+
 async def test_serving_steady_state_zero_recompiles():
     """The compile-watchdog acceptance gate: once warmup + the first
     request have compiled every shape serving reaches, further traffic
@@ -388,6 +474,38 @@ async def test_mocker_overlap_byte_identity_and_cont_bursts():
     assert len(over_d) < len(sync_d)
 
 
+@pytest.mark.parametrize("lanes, n_req, top",
+                         [(64, 2, 4), (64, 1, 8), (1, 1, 8), (2, 2, 8)],
+                         ids=["two-of-64", "single-stream", "one-lane-full",
+                              "two-lanes-full"])
+async def test_mocker_burst_ladder_follows_the_engine(lanes, n_req, top):
+    """The mocker mirrors _fused_k (PR 50): while sequences share the
+    engine and fewer run than it has lanes every burst is the interleave
+    one; with the lanes full, and for a single stream, the ramp reaches
+    decode_fused_steps."""
+    from dynamo_tpu.mocker import MockEngineArgs
+    from dynamo_tpu.mocker.engine import MockEngine
+
+    eng = MockEngine(MockEngineArgs(
+        model_name="m", block_size=4, base_step_s=0.0,
+        prefill_s_per_token=0.0, decode_s_per_seq=0.0,
+        overlap_scheduling=True, decode_fused_steps=8,
+        max_num_seqs=lanes))
+
+    async def one(i):
+        req = PreprocessedRequest(
+            token_ids=list(range(i, 40 + i)), request_id=f"ladder-{i}",
+            stop=StopConditions(max_tokens=64, ignore_eos=True))
+        return [t async for out in eng.generate(req)
+                for t in out.token_ids]
+
+    outs = await asyncio.gather(*[one(i) for i in range(n_req)])
+    ks = [r["k"] for r in eng.fpm if r["kind"] == "decode"]
+    await eng.close()
+    assert [len(o) for o in outs] == [64] * n_req
+    assert max(ks) == top and min(ks) >= 4, ks
+
+
 async def _until(cond, what, timeout=60.0):
     t0 = asyncio.get_running_loop().time()
     while not cond():
@@ -453,19 +571,31 @@ class _NeverReady:
         return np.asarray(self.arr)
 
 
+@pytest.mark.parametrize("lanes", ["free", "full"])
 @pytest.mark.parametrize("depth", [None, 4], ids=["default", "depth4"])
-async def test_pipeline_depth_bounds_what_stands_ahead_of_a_prefill(depth):
+async def test_pipeline_depth_bounds_what_stands_ahead_of_a_prefill(
+        depth, lanes):
     """A step leaves `decode_pipeline_depth` bursts in flight (2 by
     default: the one that runs and one behind it; still honoured where
     set) and never more; the oldest is read back BEFORE admission, so in
     a decode-only stretch a first chunk goes out behind depth - 1 bursts
-    at most: 8 steps by default, under the decode_fused_steps +
+    at most.  A single stream has ramped, so the FIRST request to join
+    it stands behind full bursts; from then on the engine is shared
+    and, with a lane FREE, those bursts are held at the interleave rung
+    (_fused_k, PR 50): 4 steps by default where the ramp left 8.  With
+    every lane taken the ramp reaches decode_fused_steps, an arrival
+    waits for a lane, and what stands ahead of its chunk is under
+    depth - 1 full bursts: by default under the decode_fused_steps +
     INTERLEAVE_BURST the order of the step promises."""
     assert EngineConfig().decode_pipeline_depth == 2
     cfg = {} if depth is None else {"decode_pipeline_depth": depth}
     depth = depth or 2
+    free = lanes == "free"
     eng = engine(overlap_scheduling=True, decode_fused_steps=8,
-                 block_size=16, prefill_buckets=(16, 32), **cfg)
+                 block_size=16, prefill_buckets=(16, 32),
+                 max_blocks_per_seq=32, max_num_seqs=4 if free else 2,
+                 **cfg)
+    fused, short = eng.config.decode_fused_steps, JaxEngine.INTERLEAVE_BURST
     build, step, stamp = eng._build_burst, eng._sched_step, \
         eng._stamp_dispatch
     left_in_flight, firsts = [], []
@@ -484,22 +614,50 @@ async def test_pipeline_depth_bounds_what_stands_ahead_of_a_prefill(depth):
 
     eng._build_burst, eng._sched_step = build_unready, step_then_look
     eng._stamp_dispatch = stamp_and_keep
-    long_one = asyncio.create_task(
-        collect(eng, greedy_req(list(range(7, 20)), 200, "depth-r0")))
-    for i in range(3):
-        # each arrival lands in a decode-only stretch at full fusion
+
+    def arrival(i, n):
+        return asyncio.create_task(collect(eng, greedy_req(
+            list(range(40 + i, 49 + i)), n, f"depth-r{i + 1}")))
+
+    async def stretch(k, what):
+        # `depth` bursts of k steps since now: all in flight are such
         mark = len(eng.fpm)
-        await _until(lambda: sum(r["kind"] == "decode" and r["k"] == 8
+        await _until(lambda: sum(r["kind"] == "decode" and r["k"] == k
                                  for r in list(eng.fpm)[mark:]) >= depth,
-                     "the ramp never reached full fusion")
-        assert len(await collect(eng, greedy_req(
-            list(range(40 + i, 49 + i)), 8, f"depth-r{i + 1}"))) == 8
-    assert len(await long_one) == 200
+                     what)
+
+    n_long = 200 if free else 440          # r0 outlasts every arrival
+    long_one = asyncio.create_task(collect(
+        eng, greedy_req(list(range(7, 20)), n_long, "depth-r0")))
+    if free:
+        for i in range(3):
+            # each arrival lands in a decode-only stretch of r0 alone:
+            # the first meets a single stream at full fusion, the
+            # others an engine that has been shared since
+            if i == 0:
+                await stretch(fused, "one stream never reached full fusion")
+            else:
+                await stretch(short, "no stretch at the interleave rung")
+            assert len(await arrival(i, 8)) == 8
+        n_first = 4
+    else:
+        # r0 and one more hold both lanes; each arrival is sent into a
+        # stretch at full fusion and waits for that other's lane
+        holder = arrival(0, 80)
+        for i in range(1, 4):
+            await stretch(fused, "the ramp never reached full fusion")
+            nxt = arrival(i, 80 if i < 3 else 8)
+            assert len(await holder) == 80
+            holder = nxt
+        assert len(await holder) == 8
+        n_first = 5
+    assert len(await long_one) == n_long
     assert max(left_in_flight) == depth
     ahead = [s.ahead_steps for s in firsts]
-    assert len(ahead) == 4 and ahead[0] == 0     # r0 came to an idle engine
-    fused = eng.config.decode_fused_steps
+    assert len(ahead) == n_first and ahead[0] == 0   # r0: an idle engine
     assert 0 < max(ahead[1:]) <= (depth - 1) * fused
+    if free:
+        assert 0 < max(ahead[2:]) <= (depth - 1) * short
     if depth == 2:
-        assert max(ahead) <= fused + JaxEngine.INTERLEAVE_BURST
+        assert max(ahead) <= fused + short
     await eng.close()

@@ -648,6 +648,7 @@ def test_an_arrival_during_the_read_back_goes_out_in_that_step(overlap):
         first.cancel()
         with pytest.raises(asyncio.CancelledError):
             await first
+        await settled(eng)       # r0's last bursts read back, not in wait
         await serve(eng, 2)                      # to an idle engine again
         await settled(eng)
         check_stages(eng, seen, 3)
