@@ -55,7 +55,7 @@ from .config import EngineConfig
 from ..ops.fused_sampling import fused_greedy_tokens, fused_sample_tokens
 from ..ops.packed_prefill import resolve_packed_impl
 from ..ops.paged_attention import PALLAS_IMPLS, resolve_decode_impl
-from .sampler import greedy_tokens, sample_tokens
+from .sampler import greedy_tokens, sample_block_tokens, sample_tokens
 
 logger = logging.getLogger(__name__)
 
@@ -127,10 +127,22 @@ class _Slot:
     ahead_steps: int = 0
     first_sent: bool = False  # the first frame is on its way to the loop
     last_push_t: float = 0.0  # previous streamed-token time (ITL EMA)
+    # a family that generates by blocks (models/__init__.py
+    # `GEN_BLOCK`): the block length; 0 for one token a step
+    gen_block: int = 0
+
+    @property
+    def prefill_end(self) -> int:
+        """Where this slot's prefill ends: the prompt's end, or for a
+        family that generates by blocks the last multiple of the block
+        length in it (the rest enters the first block unmasked)."""
+        if self.gen_block:
+            return self.prompt_len - self.prompt_len % self.gen_block
+        return self.prompt_len
 
     @property
     def prefilling(self) -> bool:
-        return self.prefill_pos < self.prompt_len
+        return self.prefill_pos < self.prefill_end
     # disaggregation
     disagg_prefill: bool = False       # prefill-only; park KV for pulling
     # decode side of a disagg pull: the slot sits admitted-but-idle while
@@ -201,6 +213,12 @@ class JaxEngine:
             getattr(self.family, "KV_LANE_ADDRESSED", False))
         self._kv_counters = tuple(getattr(self.family, "KV_COUNTERS", ()))
         self._kv_counters_seen = np.zeros(len(self._kv_counters), np.int64)
+        # a family that generates by blocks of positions says so by
+        # `GEN_BLOCK` (models/__init__.py): a decode burst's unit is
+        # then a PASS over every busy lane's block and a lane's state
+        # between bursts lives on the device
+        gen_block = getattr(self.family, "GEN_BLOCK", None)
+        self._gen_block = int(gen_block(self.model_cfg)) if gen_block else 0
         # attention-impl overrides (ops/paged_attention.py +
         # ops/pallas_packed_prefill.py): the engine-level knobs replace
         # the resolved model config's fields so deployments pick the
@@ -516,12 +534,20 @@ class JaxEngine:
         # pinned out_shardings are unchanged, so the zero-recompile
         # steady state carries over
         _ep = self.sampling_epilogue == "fused"
+        # a family that generates by blocks runs its pass program in
+        # the decode programs' place, one pass where they run one step
+        _donate = (1, 5, 7, 9)
+        if self._gen_block:
+            _decode_out, _donate = (rep, kvsh, rep), (1,)
         self._jit_decode = {
             g: w.wrap(jax.jit(
-                w.named(partial(self._decode_impl, self.family,
+                w.named(partial(self._denoise_impl, self.family,
+                                self.model_cfg, self.mesh, g, 1, _ep)
+                        if self._gen_block else
+                        partial(self._decode_impl, self.family,
                                 self.model_cfg, self.mesh, g, _ep),
                         "decode"),
-                donate_argnums=(1, 5, 7, 9),
+                donate_argnums=_donate,
                 out_shardings=_decode_out,
             ), "decode")
             for g in (False, True)
@@ -627,10 +653,13 @@ class JaxEngine:
         if config.decode_fused_steps > 1:
             self._jit_decode_multi = {
                 (g, k): w.wrap(jax.jit(
-                    w.named(partial(self._decode_multi_impl, self.family,
+                    w.named(partial(self._denoise_impl
+                                    if self._gen_block
+                                    else self._decode_multi_impl,
+                                    self.family,
                                     self.model_cfg, self.mesh, g, k, _ep),
                             "decode_multi"),
-                    donate_argnums=(1, 5, 7, 9),
+                    donate_argnums=_donate,
                     out_shardings=_decode_out,
                 ), "decode_multi")
                 for g in (False, True)
@@ -735,6 +764,15 @@ class JaxEngine:
         }
         for name in self._kv_counters:
             self.metrics[name] = 0
+        if self._gen_block:
+            # generation by blocks: lane passes = busy lanes summed over
+            # passes, of them the commit passes; the tokens and blocks
+            # that lost their last mask; the rows the pass programs ran
+            # (passes x lanes x block length)
+            self.metrics.update({
+                "diff_lane_passes": 0, "diff_commit_passes": 0,
+                "diff_tokens_unmasked": 0, "diff_blocks_done": 0,
+                "diff_rows": 0})
         # a family whose layers differ in what they read counts its own
         # decode reads, and one whose attention chooses its keys its
         # prefill pairs too, from the host's positions; an empty burst
@@ -956,6 +994,45 @@ class JaxEngine:
         )
         return (JaxEngine._with_counters(family, burst, kv), kv,
                 positions, ctx_lens, steps)
+
+    @staticmethod
+    def _denoise_impl(family, model_cfg, mesh, greedy, num_steps, epilogue,
+                      params, kv, chain, use_chain, tokens, positions,
+                      block_tables, ctx_lens, seeds, steps, temps, top_ks,
+                      top_ps, valid, advance, lora_bank=None, lidx=None):
+        """`num_steps` fused PASSES of a family that generates by blocks
+        (family denoise_multi), in `_decode_multi_impl`'s place and under
+        its signature, so that dispatch, continuation and warm-up are the
+        decode programs' own.  `chain` is every lane's state after the
+        last burst (tokens, mask flags, block start, step:
+        `lane_state_width` int32 columns), `tokens` the state the host
+        gives a lane that joins, `use_chain` which of the two a lane
+        takes.  How far a lane advances is data, so `positions`,
+        `ctx_lens`, `steps` and `advance` say nothing here: a
+        continuation uploads nothing because the state IS the chain.
+        Returns (burst [num_steps * B + 1 (+ counters), lanes]: row
+        j * B + b the token at position b of the block that lost its
+        last mask in pass j, -1 elsewhere, then each lane's block start
+        after the burst; cache; the lanes' state)."""
+        state = jnp.where(use_chain[:, None], chain, tokens)
+        if greedy:
+            sample_fn = None   # argmax and its probability
+        else:
+            passes = model_cfg.denoising_steps + 1
+
+            def sample_fn(logits, pos, stp):
+                return sample_block_tokens(
+                    logits, seeds, pos * passes + stp[:, None], temps,
+                    top_ks, top_ps)
+
+        outs, state, kv = family.denoise_multi(
+            params, model_cfg, kv, state, block_tables, num_steps,
+            sample_fn, valid=valid, mesh=mesh)
+        start = family.unpack_lane_state(model_cfg, state)[2]
+        burst = jnp.concatenate(
+            [outs.transpose(0, 2, 1).reshape(-1, outs.shape[1]),
+             start[None]])
+        return JaxEngine._with_counters(family, burst, kv), kv, state
 
     @staticmethod
     def _with_counters(family, burst, kv):
@@ -1284,7 +1361,7 @@ class JaxEngine:
         health-check canary cannot be that request.)"""
         B = self.config.max_num_seqs
         zero = {
-            "tokens": np.zeros(B, np.int32),
+            "tokens": np.zeros(self._chain_shape(), np.int32),
             "use_chain": np.zeros(B, bool),
             "positions": np.zeros(B, np.int32),
             "tables": np.zeros((B, self.config.max_blocks_per_seq),
@@ -1573,6 +1650,11 @@ class JaxEngine:
                       f"max_context is {self.config.max_context}",
             )
             return
+        if self._gen_block:
+            refused = self._gen_block_refusal(request)
+            if refused:
+                yield LLMEngineOutput(finish_reason="error", error=refused)
+                return
         # after validation: rejected requests cost no engine work and must
         # not inflate the SLA planner's arrival rate / mean ISL
         self.metrics["requests"] += 1
@@ -1631,6 +1713,7 @@ class JaxEngine:
             ),
             lora_idx=lora_idx,
             enqueued_t=time.monotonic(),
+            gen_block=self._gen_block,
         )
         from ..protocols.llm import DISAGG_ANNOTATION
 
@@ -1683,6 +1766,28 @@ class JaxEngine:
                 # actual teardown happens on the scheduler thread
                 slot.cancel_requested = True
                 self._wake.set()
+
+    def _gen_block_refusal(self, request: PreprocessedRequest
+                           ) -> Optional[str]:
+        """What a family that generates by blocks cannot serve yet, said
+        at admission: each needs ONE distribution a token, and a block's
+        positions are filled out of order over several passes."""
+        from ..protocols.llm import DISAGG_ANNOTATION
+
+        sa = request.sampling
+        what = [name for name, asked in (
+            ("guided decoding", sa.guided_json is not None),
+            ("frequency / presence penalties",
+             bool(sa.frequency_penalty or sa.presence_penalty)),
+            ("disaggregated prefill",
+             DISAGG_ANNOTATION in (request.annotations or [])
+             or request.disaggregated_params is not None),
+        ) if asked]
+        if not what:
+            return None
+        return (f"model family {type(self.model_cfg).__name__} generates "
+                f"by blocks of {self._gen_block} positions and does not "
+                f"carry {', '.join(what)} yet")
 
     def _process_cancellations(self) -> None:
         """Runs on the scheduler thread at the top of every step."""
@@ -2539,8 +2644,9 @@ class JaxEngine:
             self._prefill_one(pslots[0], budget)
             return
         share = max(budget // n, c.prefill_buckets[0])
-        chunks = [min(c.prefill_buckets[-1], share,
-                      s.prompt_len - s.prefill_pos) for s in pslots]
+        chunks = [self._whole_blocks(min(c.prefill_buckets[-1], share,
+                                         s.prefill_end - s.prefill_pos))
+                  for s in pslots]
 
         bucket = self._bucket_for(max(chunks))
         Bp = _pow2_len(n)
@@ -2592,7 +2698,7 @@ class JaxEngine:
         self._fpm_prefill(
             rows=n, tokens=int(sum(chunks)), bucket=bucket,
             completing=sum(1 for s, ch in zip(pslots, chunks)
-                           if s.prefill_pos + ch >= s.prompt_len))
+                           if s.prefill_pos + ch >= s.prefill_end))
         # the sampled tokens matter ONLY when some row completes its
         # prompt this chunk (np.asarray is a blocking device round trip;
         # intermediate chunks discard the sample, so they never pay it);
@@ -2606,6 +2712,14 @@ class JaxEngine:
             else:
                 first = -1
             self._finish_prefill_chunk(slot, chunk, first, bucket)
+
+    def _whole_blocks(self, chunk: int) -> int:
+        """A prefill chunk of a family that generates by blocks ends on
+        a multiple of the block length, so that every key a query may
+        see (to its block's end) is written by its own program or an
+        earlier one; anyone else's chunk is as it was."""
+        gb = self._gen_block
+        return chunk - chunk % gb if gb else chunk
 
     def _moe_grouped(self, tokens: int) -> bool:
         """Whether a program whose expert layers see `tokens` rows takes
@@ -2681,6 +2795,7 @@ class JaxEngine:
             max_blocks_per_seq=c.max_blocks_per_seq,
             min_bucket=c.prefill_buckets[0],
             with_lora=self.lora_bank is not None,
+            align=self._gen_block,
         )
         if plan is None:
             return
@@ -2707,7 +2822,7 @@ class JaxEngine:
             rows=len(plan.slots), tokens=plan.tokens, bucket=plan.bucket,
             packed=True,
             completing=sum(1 for s, ch in zip(plan.slots, plan.chunks)
-                           if s.prefill_pos + ch >= s.prompt_len))
+                           if s.prefill_pos + ch >= s.prefill_end))
         # token fetch only when some segment completes its prompt this
         # chunk (see _prefill_step: intermediate chunks discard the
         # sample); overlap mode defers the readback one step
@@ -2744,7 +2859,8 @@ class JaxEngine:
             self._prefill_ring_one(slot)
             return
         pos = slot.prefill_pos
-        chunk = min(c.prefill_buckets[-1], budget, slot.prompt_len - pos)
+        chunk = self._whole_blocks(min(c.prefill_buckets[-1], budget,
+                                       slot.prefill_end - pos))
         bucket = self._bucket_for(chunk)
         toks = np.zeros(bucket, np.int32)
         toks[:chunk] = slot.seq.tokens[pos: pos + chunk]
@@ -2780,10 +2896,10 @@ class JaxEngine:
         )
         self._fpm_prefill(
             rows=1, tokens=int(chunk), bucket=bucket,
-            completing=int(slot.prefill_pos + chunk >= slot.prompt_len))
+            completing=int(slot.prefill_pos + chunk >= slot.prefill_end))
         # token fetch only on the completing chunk (see _prefill_step:
         # intermediate chunks discard the sample); deferred in overlap
-        if pos + chunk >= slot.prompt_len \
+        if pos + chunk >= slot.prompt_len and not self._gen_block \
                 and (slot.guide is None or slot.disagg_prefill):
             arr = self._prefill_samples(tok, [(slot, 0)])
             first = int(arr) if arr is not None else None
@@ -2861,6 +2977,8 @@ class JaxEngine:
         (guided non-disagg completions discard the unconstrained sample
         and re-derive it in the guided step, so they never cost a
         fetch)."""
+        if self._gen_block:
+            return {}   # a prefill yields no token: the first block does
         return {
             i: s for i, (s, ch) in enumerate(zip(slots, chunks))
             if s.prefill_pos + ch >= s.prompt_len
@@ -2942,6 +3060,11 @@ class JaxEngine:
         self._commit_full_blocks(slot)
         if slot.prefilling:
             return  # more chunks to go; decode runs in between
+        if self._gen_block:
+            # no token comes of a prefill: the lane starts in its first
+            # block, which holds the prompt's last tokens unmasked, and
+            # first_token_t is stamped where that block is emitted
+            return
         if slot.guide is not None and not slot.disagg_prefill:
             # constrained output: discard the unconstrained sample and
             # re-derive the first token's logits in the guided step by
@@ -3622,9 +3745,8 @@ class JaxEngine:
             # stale snapshot: growing a freed sequence would KeyError
             if slot.finished or self._slots[slot.index] is not slot:
                 continue
-            eff = slot.ctx_len + slot.inflight
             nblocks = int(np.count_nonzero(slot.block_table))
-            if eff >= nblocks * c.block_size:
+            if self._burst_reach(slot, 1) >= nblocks * c.block_size:
                 if nblocks >= c.max_blocks_per_seq:
                     # capacity: the in-flight tokens already reach the end
                     # of the table — drain so the length-finish fires
@@ -3646,7 +3768,8 @@ class JaxEngine:
                         continue
                 slot.block_table[nblocks] = grow.block_id
                 nblocks += 1
-            while k > 1 and eff + k - 1 >= nblocks * c.block_size:
+            while k > 1 and self._burst_reach(slot, k) \
+                    >= nblocks * c.block_size:
                 if nblocks >= c.max_blocks_per_seq:
                     # table is full: burst positions past it would clamp to
                     # the last column and overwrite that block's KV — run
@@ -3690,6 +3813,28 @@ class JaxEngine:
         ph.set(cont=cont_burst, k=k, k_held=bool(held), lanes=len(active))
         return True
 
+    def _burst_reach(self, slot: _Slot, k: int) -> int:
+        """The last position a burst of `k` dispatched now may write for
+        this slot, which its block table has to cover.  One token a
+        step: the k positions after what is in flight.  Generation by
+        blocks: a burst's unit is a pass and how far a lane advances is
+        data; a block takes two passes at least (the one that unmasks
+        the last of it and its commit), so the passes in flight and
+        these k end at most (inflight + k) div 2 blocks on, inside the
+        block after."""
+        gb = self._gen_block
+        if gb:
+            return slot.ctx_len + gb * ((slot.inflight + k) // 2 + 1) - 1
+        return slot.ctx_len + slot.inflight + k - 1
+
+    def _chain_shape(self) -> tuple:
+        """The device chain's shape: a token a lane, or a lane's whole
+        state where the family generates by blocks."""
+        B = self.config.max_num_seqs
+        if self._gen_block:
+            return (B, self.family.lane_state_width(self.model_cfg))
+        return (B,)
+
     def _build_burst(self, active, k: int):
         """Build the descriptor of one decode burst over `active` and
         dispatch it (a device-resident continuation where provable).
@@ -3704,7 +3849,8 @@ class JaxEngine:
         # same arrays to the loop thread.  Fresh arrays per full dispatch
         # are the double buffer: the previous generation stays pinned by
         # the in-flight burst while this one is built.
-        tokens = np.zeros(B, np.int32)
+        gb = self._gen_block
+        tokens = np.zeros(self._chain_shape(), np.int32)
         use_chain = np.zeros(B, bool)
         positions = np.zeros(B, np.int32)
         ctx_lens = np.zeros(B, np.int32)
@@ -3717,19 +3863,32 @@ class JaxEngine:
         valid = np.zeros(B, bool)  # padding rows pick no expert
         for s in active:
             i = s.index
-            tokens[i] = s.last_token
             # a lane whose previous burst is unread takes its input token
             # from the device chain; host last_token would be k steps stale
             use_chain[i] = (
                 self._chain_tokens is not None
                 and self._chain_owner[i] == (self._seq_id(s), s.epoch)
-                and s.inflight > 0
+                and (s.inflight > 0 or gb > 0)
             )
-            positions[i] = s.ctx_len + s.inflight
-            ctx_lens[i] = s.ctx_len + s.inflight
+            if gb:
+                # generation by blocks: a lane's state stays on the
+                # device from its first burst on (a block half unmasked
+                # is nowhere else); where a lane stands is data, so the
+                # three clocks stay 0
+                if not use_chain[i]:
+                    # a lane that joins: its block starts at ctx_len
+                    # (what is committed) and holds what is known
+                    # beyond it, the prompt's last P mod B tokens
+                    tokens[i] = self.family.new_lane_state(
+                        self.model_cfg, s.ctx_len,
+                        s.seq.tokens[s.ctx_len: s.ctx_len + gb])
+            else:
+                tokens[i] = s.last_token
+                positions[i] = s.ctx_len + s.inflight
+                ctx_lens[i] = s.ctx_len + s.inflight
+                steps[i] = s.generated + s.inflight + 1
             tables[i] = s.block_table
             seeds[i] = s.sampling_seed
-            steps[i] = s.generated + s.inflight + 1
             temps[i] = s.request.sampling.temperature
             top_ks[i] = s.request.sampling.top_k
             top_ps[i] = s.request.sampling.top_p
@@ -3749,13 +3908,15 @@ class JaxEngine:
             for s in active:
                 lidx[s.index] = s.lora_idx
             a["lidx"] = lidx
-        self._count_decode_attn(ctx_lens[valid], k)
+        self._count_decode_attn(
+            np.array([s.ctx_len for s in active], np.int32) if gb
+            else ctx_lens[valid], k)
         cont_burst = self._is_continuation(a, active, k)
         if cont_burst:
             # steady state: nothing changed but the clock — advance the
             # device-resident descriptor in-program, upload nothing
             prev = self._last_desc
-            adv = prev["k"]
+            adv = self._clock_advance(prev)
             greedy = bool(np.all(a["temps"] <= 0.0))
             if self.step_sink is not None:
                 self.step_sink("decode_cont", {
@@ -3804,10 +3965,13 @@ class JaxEngine:
         for the Pallas kernels (GQA's and the latent cache's)."""
         bs = self.config.block_size
         layers, picks, held = self._moe
-        self.metrics["moe_picks.decode"] += k * len(ctx) * layers * picks
+        # a pass runs a block's positions a lane where a step runs one
+        rows = self._gen_block or 1
+        self.metrics["moe_picks.decode"] += \
+            k * len(ctx) * rows * layers * picks
         self.metrics["moe_expert_slots.decode"] += k * layers * held
         if layers and moe_form(self.model_cfg,
-                               self.config.max_num_seqs) == "visited":
+                               self.config.max_num_seqs * rows) == "visited":
             self.metrics["moe_visited_form_slots.decode"] += \
                 k * layers * held
         if hasattr(self.family, "decode_block_counts"):
@@ -4085,7 +4249,7 @@ class JaxEngine:
         chain = self._chain_tokens
         if chain is None:
             chain = jax.device_put(
-                jnp.zeros((self.config.max_num_seqs,), jnp.int32),
+                jnp.zeros(self._chain_shape(), jnp.int32),
                 self._desc_sharding)
         # COMMITTED uploads: continuation bursts feed the program's own
         # (committed) outputs back in, and a committed-vs-uncommitted
@@ -4138,9 +4302,13 @@ class JaxEngine:
         )
         fn = self._jit_decode_multi[(greedy, k)] if k > 1 \
             else self._jit_decode[greedy]
-        burst, self.kv, pos, ctx, steps = fn(*args)
-        dd["positions"], dd["ctx_lens"], dd["steps"] = pos, ctx, steps
-        self._chain_tokens = burst[k - 1]
+        if self._gen_block:
+            # generation by blocks: the chain is every lane's state
+            burst, self.kv, self._chain_tokens = fn(*args)
+        else:
+            burst, self.kv, pos, ctx, steps = fn(*args)
+            dd["positions"], dd["ctx_lens"], dd["steps"] = pos, ctx, steps
+            self._chain_tokens = burst[k - 1]
         self._dev_desc = dd
         now = time.monotonic()
         gap = (now - self._fpm_last_decode_t
@@ -4158,6 +4326,13 @@ class JaxEngine:
         })
         self._fpm_last_decode_t = now
         return burst
+
+    def _clock_advance(self, prev: Dict[str, Any]) -> int:
+        """What a continuation adds to the last burst's positions,
+        context lengths and sampling steps: its k, a token a step.
+        Generation by blocks: 0, where a lane stands rides the chain
+        and the three clocks stay as they were uploaded."""
+        return 0 if self._gen_block else prev["k"]
 
     def _is_continuation(self, a: Dict[str, np.ndarray], active,
                          k: int) -> bool:
@@ -4177,7 +4352,7 @@ class JaxEngine:
             if self._chain_owner[s.index] != (self._seq_id(s), s.epoch):
                 return False
         m = a["valid"]
-        adv = prev["k"]
+        adv = self._clock_advance(prev)
         return (
             np.array_equal(a["valid"], prev["valid"])
             and ("lidx" in a) == (prev.get("lidx") is not None)
@@ -4203,21 +4378,29 @@ class JaxEngine:
         e = self._inflight.popleft()
         with self._phase("device_wait", k=e["k"], what="burst_fetch"):
             arr = np.asarray(e["burst"])  # [k (+ counters), B]
+        # rows of tokens: a token a step, or a block's positions a pass
+        # and the lanes' block starts under them
+        gb = self._gen_block
+        n_rows = e["k"] * gb + 1 if gb else e["k"]
         if self._kv_counters:
             # the rows under the tokens: running int32 totals on the
             # device; what they grew by (modulo the wrap) is added
-            now = arr[e["k"]:, 0].astype(np.int64)
+            now = arr[n_rows:, 0].astype(np.int64)
             grew = (now - self._kv_counters_seen) % (1 << 32)
             self._kv_counters_seen = now
             for name, n in zip(self._kv_counters, grew):
                 self.metrics[name] = self.metrics.get(name, 0) + int(n)
-        with self._phase("emit", k=e["k"], what="burst"):
+        with self._phase("emit", k=e["k"], what="burst") as emit:
+            done0 = self.metrics.get("diff_blocks_done", 0)
             for i, ident in e["lanes"].items():
                 s = self._slots[i] if i < len(self._slots) else None
                 if s is None or (self._seq_id(s), s.epoch) != ident \
                         or s.finished:
                     continue
                 s.inflight -= e["k"]
+                if gb:
+                    self._emit_blocks(s, arr[:n_rows, i], e["k"])
+                    continue
                 for j in range(e["k"]):
                     s.ctx_len += 1
                     self.metrics["decode_tokens"] += 1
@@ -4228,6 +4411,13 @@ class JaxEngine:
                         # own blocks, which are never committed past the
                         # finish ctx_len)
                         break
+            if gb:
+                # a burst of passes: the rows its program ran (counted
+                # here, where the lane passes are, so that the two move
+                # together) and the blocks that lost their last mask
+                self.metrics["diff_rows"] += \
+                    e["k"] * self.config.max_num_seqs * gb
+                emit.set(blocks_out=self.metrics["diff_blocks_done"] - done0)
 
     def _drain_inflight(self) -> None:
         while self._inflight:
@@ -4267,28 +4457,82 @@ class JaxEngine:
             "generated": slot.generated,
         }
 
+    def _emit_blocks(self, s: _Slot, col: np.ndarray, k: int) -> None:
+        """One lane's share of a burst of `k` passes of a family that
+        generates by blocks: col = k x B rows of tokens (the block that
+        lost its last mask in that pass, -1 elsewhere) and the lane's
+        block start after the burst.  A block is emitted where its last
+        mask goes (its commit pass is the next one), as ONE frame of the
+        tokens that were not known before, truncated at `max_tokens`
+        and at a stop token; what is committed (`ctx_len`) comes home
+        with the burst, so prefix caching and a preemption's replay see
+        committed blocks only.  Counts lane passes (a busy lane runs
+        every pass of a burst, to the one that finishes it), the commit
+        passes among them, and the tokens and blocks unmasked."""
+        gb = self._gen_block
+        m = self.metrics
+        # a block emitted by the last burst's last pass commits first
+        commits = int(len(s.seq) - s.ctx_len >= gb)
+        for j in range(k):
+            blk = col[j * gb:(j + 1) * gb]
+            if blk[0] < 0:
+                continue
+            known = len(s.seq) % gb   # the prompt's tail: first block only
+            new = [int(t) for t in blk[known:]]
+            m["diff_blocks_done"] += 1
+            m["diff_tokens_unmasked"] += len(new)
+            self._stamp_first_token(s)
+            before = s.generated
+            self._push_tokens(s, new)
+            m["decode_tokens"] += s.generated - before
+            if s.finished:
+                m["diff_lane_passes"] += j + 1
+                m["diff_commit_passes"] += commits
+                return
+            commits += 1
+        start = int(col[k * gb])
+        m["diff_lane_passes"] += k
+        m["diff_commit_passes"] += (start - s.ctx_len) // gb
+        s.ctx_len = start
+        self._commit_full_blocks(s)
+
     def _push_token(self, slot: _Slot, tok: int) -> None:
         """Append a generated token, stream it, handle finish."""
+        self._push_tokens(slot, (tok,))
+
+    def _push_tokens(self, slot: _Slot, toks) -> None:
+        """Append generated tokens, stream them as ONE frame, handle
+        finish: a token a step for most families, a block's tokens for
+        one that generates by blocks (cut after the token that
+        finishes the request)."""
         now = time.monotonic()
         if slot.last_push_t > 0.0:
             # per-slot gap EMA; burst-internal ~0 gaps and between-burst
             # step gaps average out to the true mean inter-token latency
-            gap = now - slot.last_push_t
+            gap = (now - slot.last_push_t) / len(toks)
             self.itl_ema_s = gap if self.itl_ema_s == 0.0 \
                 else 0.95 * self.itl_ema_s + 0.05 * gap
         slot.last_push_t = now
-        slot.seq.append(tok)
-        slot.last_token = tok
-        slot.generated += 1
-        if slot.generated == 2:
-            # request stage: the lane's first token out of a decode
-            # burst (`generated` never goes back: once a request)
-            slot.second_token_t = now
-            self.metrics["req_stage_s.join"] += now - slot.first_token_t
-            self.metrics["req_join_n"] += 1
-            self._stage_spans(slot, ("req_join", slot.first_token_t, now))
+        first_frame = slot.generated == 0
+        finish, sent = None, []
+        for tok in toks:
+            slot.seq.append(tok)
+            slot.last_token = tok
+            slot.generated += 1
+            sent.append(tok)
+            if slot.generated == 2:
+                # request stage: the lane's first token out of a decode
+                # burst (`generated` never goes back: once a request)
+                slot.second_token_t = now
+                self.metrics["req_stage_s.join"] += \
+                    now - slot.first_token_t
+                self.metrics["req_join_n"] += 1
+                self._stage_spans(
+                    slot, ("req_join", slot.first_token_t, now))
+            finish = self._finish_reason(slot, tok)
+            if finish:
+                break
         self._commit_full_blocks(slot)
-        finish = self._finish_reason(slot, tok)
         # forensic stamp on the FIRST token frame and the finish frame
         # (frontend RequestTracker.on_worker_stamp): realized prefix
         # reuse lands with the first token — when the router's
@@ -4307,12 +4551,12 @@ class JaxEngine:
                        "cached_tokens": slot.cached_tokens,
                        "ttft_s": slot.first_token_t - slot.enqueued_t,
                        "forensic": self._forensic(slot)}
-        elif slot.generated == 1:
+        elif first_frame:
             metrics = {"forensic": self._forensic(slot)}
         else:
             metrics = None
         out = LLMEngineOutput(
-            token_ids=[tok],
+            token_ids=sent,
             finish_reason=finish,
             metrics=metrics,
         )
@@ -4412,4 +4656,9 @@ class JaxEngine:
             return "length"
         if slot.ctx_len + 1 >= self.config.max_context:
             return "length"
+        gb = self._gen_block
+        if gb and len(slot.seq) + gb > min(
+                self.config.max_context,
+                self.config.max_blocks_per_seq * self.config.block_size):
+            return "length"   # no room for one more block
         return None

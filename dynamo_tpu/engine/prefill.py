@@ -69,11 +69,21 @@ def plan_packed_prefill(
     max_blocks_per_seq: int,
     min_bucket: int,
     with_lora: bool,
+    align: int = 0,
 ) -> Optional[PackedPlan]:
     """Build the packed arrays for one prefill dispatch, or None when no
-    slot can take even one token of the budget."""
+    slot can take even one token of the budget.  `align`: the block
+    length of a family that generates by blocks (0: none): a slot's
+    prefill ends at the last multiple of it in the prompt (the rest
+    enters the first block) and each chunk on a multiple of it, so that
+    every key a query may see is written by its own program or an
+    earlier one."""
     needs = [s.prompt_len - s.prefill_pos for s in pslots]
+    if align:
+        needs = [n - s.prompt_len % align for n, s in zip(needs, pslots)]
     chunks = waterfill(needs, max(budget, 1))
+    if align:
+        chunks = [c - c % align for c in chunks]
     used = [(s, c) for s, c in zip(pslots, chunks) if c > 0]
     if not used:
         return None

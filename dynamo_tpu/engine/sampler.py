@@ -42,20 +42,57 @@ def sample_tokens(
     def one(lg, seed, step, temp, tk, tp):
         greedy = jnp.argmax(lg)
         key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
-        scaled = lg / jnp.maximum(temp, 1e-6)
-        vals, idx = jax.lax.top_k(scaled, CAP)     # sorted descending
-        k_eff = jnp.clip(jnp.where(tk > 0, tk, CAP), 1, CAP)
-        keep_k = jnp.arange(CAP) < k_eff
-        # nucleus mass against the TRUE distribution (full-vocab logsumexp,
-        # no sort); first candidate always kept
-        probs = jnp.exp(vals - jax.scipy.special.logsumexp(scaled))
-        cum = jnp.cumsum(probs)
-        keep_p = jnp.concatenate([jnp.array([True]), cum[:-1] < tp])
-        masked = jnp.where(keep_k & keep_p, vals, NEG_INF)
+        idx, masked = _candidate_window(lg, temp, tk, tp)
         sampled = idx[jax.random.categorical(key, masked)]
         return jnp.where(temp <= 0.0, greedy, sampled)
 
     return jax.vmap(one)(logits, seeds, steps, temperature, top_k, top_p)
+
+
+def _candidate_window(lg, temp, tk, tp):
+    """One slot's candidates: (ids [CAP] of the CAP highest
+    temperature-scaled logits, their scaled logits with what top-k and
+    top-p leave out at NEG_INF)."""
+    scaled = lg / jnp.maximum(temp, 1e-6)
+    vals, idx = jax.lax.top_k(scaled, CAP)     # sorted descending
+    k_eff = jnp.clip(jnp.where(tk > 0, tk, CAP), 1, CAP)
+    keep_k = jnp.arange(CAP) < k_eff
+    # nucleus mass against the TRUE distribution (full-vocab logsumexp,
+    # no sort); first candidate always kept
+    probs = jnp.exp(vals - jax.scipy.special.logsumexp(scaled))
+    cum = jnp.cumsum(probs)
+    keep_p = jnp.concatenate([jnp.array([True]), cum[:-1] < tp])
+    return idx, jnp.where(keep_k & keep_p, vals, NEG_INF)
+
+
+@jax.named_scope("dyn.sample")
+def sample_block_tokens(
+    logits: jax.Array,        # [L, B, vocab] fp32: a block's positions a lane
+    seeds: jax.Array,         # [L] int32 per-request seed
+    steps: jax.Array,         # [L, B] int32 rng stream of each position
+    temperature: jax.Array,   # [L] fp32; <=0 means greedy
+    top_k: jax.Array,         # [L] int32; 0 disables
+    top_p: jax.Array,         # [L] fp32; >=1 disables
+):
+    """One distribution a (lane, block position), for a family that
+    generates by blocks (models/sdar.py): `sample_tokens`' draw at every
+    position of a lane's block, and beside each token the probability it
+    was drawn with: of the filtered, renormalised window for a sampled
+    request, softmax(logits)[argmax] over the unfiltered logits for a
+    greedy one.  Returns (tokens [L, B] int32, probabilities [L, B])."""
+
+    def one(lg, seed, step, temp, tk, tp):
+        top = jnp.max(lg)
+        p_greedy = jnp.exp(top - jax.scipy.special.logsumexp(lg))
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
+        idx, masked = _candidate_window(lg, temp, tk, tp)
+        j = jax.random.categorical(key, masked)
+        greedy = temp <= 0.0
+        return (jnp.where(greedy, jnp.argmax(lg), idx[j]).astype(jnp.int32),
+                jnp.where(greedy, p_greedy, jax.nn.softmax(masked)[j]))
+
+    lane = jax.vmap(one, in_axes=(0, None, 0, None, None, None))
+    return jax.vmap(lane)(logits, seeds, steps, temperature, top_k, top_p)
 
 
 @jax.named_scope("dyn.sample")

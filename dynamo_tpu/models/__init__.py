@@ -26,7 +26,9 @@ shared ones averaged) are ONE parallel block under one LayerNorm
 pools' kernels) and the muP-scaled decoder whose sparse layers choose
 BLOCKS of keys from mean-pooled compressed keys, one set a KV group,
 beside lightning linear-attention layers with a constant decay a head
-(minicpm_sala.py) serve through identical plumbing.
+(minicpm_sala.py) and the Qwen3-MoE-style decoder that generates by
+diffusion over blocks of a few positions under block-causal attention
+(sdar.py) serve through identical plumbing.
 
 The cache is a tuple the family owns: the engine allocates one array a
 shape, hands the tuple to every program and takes it back.  A family
@@ -115,6 +117,35 @@ given (never through the family's type):
     kv_cache_scale_shapes /  int8 cache; prefill_packed, prefill_ring,
     _specs, prefill_packed,  spec_verify_packed, decode_hidden ...: a
     ...                      family without one falls back or refuses.
+    GEN_BLOCK(cfg)           the family GENERATES BY BLOCKS of that many
+                             positions (sdar.py: diffusion over a
+                             block's masks), not one token a lane and
+                             step.  The engine then prefills
+                             B * (P div B) prompt tokens, in chunks that
+                             end on a multiple of B, and takes NO token
+                             from a prefill; the prompt's last P mod B
+                             tokens enter the first block unmasked.  In
+                             `decode_multi`'s place stands
+                             `denoise_multi(params, cfg, kv, state,
+                             tables, passes, sample_fn, valid, mesh)`:
+                             a burst's unit is a PASS over every busy
+                             lane's block, a lane's state between
+                             passes (`lane_state_width(cfg)` int32
+                             columns: tokens, mask flags, block start,
+                             step; the family's own layout, built for
+                             a lane that joins by `new_lane_state` and
+                             read by `unpack_lane_state`) stays on the
+                             device from burst to burst, how far a lane advances is data
+                             (tables must cover B * (k div 2 + 1)
+                             positions), and a burst sends home the
+                             blocks that lost their last mask (-1
+                             elsewhere) and each lane's block start.
+                             A block is emitted as one frame; only
+                             committed blocks reach prefix caching and
+                             a preemption's replay.  What needs one
+                             distribution a token (guided decoding,
+                             penalties, logprobs, speculation) is
+                             refused.
     UNSUPPORTED              what the engine must not promise for the
                              family (engine/core.py `_family_gaps`)."""
 
@@ -127,6 +158,7 @@ from . import (
     mimo,
     minicpm_sala,
     nemotron_h,
+    sdar,
 )
 from .cohere2 import Cohere2Config
 from .deepseek import DeepseekConfig
@@ -136,10 +168,11 @@ from .llama import LlamaConfig, init_params
 from .mimo import MimoConfig
 from .minicpm_sala import SalaConfig
 from .nemotron_h import NemotronHConfig
+from .sdar import SdarConfig
 
 PRESETS = {**llama.PRESETS, **deepseek.PRESETS, **mimo.PRESETS,
            **keye.PRESETS, **ling.PRESETS, **nemotron_h.PRESETS,
-           **cohere2.PRESETS, **minicpm_sala.PRESETS}
+           **cohere2.PRESETS, **minicpm_sala.PRESETS, **sdar.PRESETS}
 
 
 def get_family(cfg):
@@ -158,6 +191,8 @@ def get_family(cfg):
         return cohere2
     if isinstance(cfg, SalaConfig):
         return minicpm_sala
+    if isinstance(cfg, SdarConfig):
+        return sdar
     if isinstance(cfg, LlamaConfig):
         return llama
     raise TypeError(f"unknown model config type: {type(cfg).__name__}")
@@ -173,6 +208,7 @@ __all__ = [
     "NemotronHConfig",
     "PRESETS",
     "SalaConfig",
+    "SdarConfig",
     "get_family",
     "init_params",
 ]

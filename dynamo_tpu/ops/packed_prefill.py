@@ -355,6 +355,7 @@ def packed_prefill_attention(
     v_scale: jax.Array = None,
     mesh=None,                # required for the Pallas path under tp>1
     lower: jax.Array = None,  # [T] first position of its row a token sees
+    upper: jax.Array = None,  # [T] last position of its row a token sees
 ) -> jax.Array:
     """Causal-within-segment attention for a packed prefill chunk.
 
@@ -382,7 +383,20 @@ def packed_prefill_attention(
     tiles wholly under a query tile's bounds; the scan masks them.
     Without it every caller's program is the one it was.  Not carried
     under tp.
+
+    `upper`: the twin, an UPPER bound a token: it attends its row's
+    positions [0, upper[t]] where upper[t] >= positions[t], a frontier
+    that several queries share (models/sdar.py: the last position of a
+    query's diffusion block, so attention inside a block runs both
+    ways).  Both forms read `positions` only as each query's frontier
+    (rotary is applied before, the write takes positions separately),
+    so the bound stands in its place; every key up to it has to be in
+    the cache already (the caller's chunks end where a block ends).
+    Without it every caller's program is the one it was.  Not carried
+    under tp.
     """
+    if upper is not None:
+        positions = upper
     impl = resolve_packed_impl(impl, jax.default_backend(),
                                k_cache.shape[4], k_cache.shape[3],
                                k_cache.dtype, q.shape[0])
@@ -393,8 +407,8 @@ def packed_prefill_attention(
         layer = jnp.int32(layer)
         tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
         if tp > 1:
-            if lower is not None:
-                raise NotImplementedError("lower under tp > 1")
+            if lower is not None or upper is not None:
+                raise NotImplementedError("lower / upper under tp > 1")
             return _packed_pallas_tp(
                 q, k_cache, v_cache, layer, block_tables, seg_ids,
                 positions, valid, mesh=mesh, interpret=interpret,

@@ -1313,3 +1313,75 @@ def test_block_sparse_decode_and_prefill_compile_for_v5e(one_chip):
     assert hlo.count("tpu_custom_call") == 2 * 2
     # the token mask a KV group is the largest thing the chunk makes
     assert program.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
+def test_diffusion_passes_and_prefill_compile_for_v5e(one_chip):
+    """The family that generates by diffusion over blocks
+    (models/sdar.py) at SDAR-30B-A3B's widths, cut to two layers (all
+    128 experts held), with the reasoning cell's cache (929 blocks, 32
+    lanes x 29): a burst of 8 PASSES of the engine's own program (128
+    rows: 32 lanes x a block of 4) writes each lane's block as one plane,
+    reads the cache through the Pallas decode kernel with a lane's four
+    queries as 4 x 8 query heads a KV head, walks the visited experts
+    and ranks the block by the head's float32 logits; and a 2048-token
+    packed prefill chunk attends block-causally in the packed kernel
+    (`upper` in the positions' place: the same one custom call a layer)
+    over three grouped matmuls.  In all, the pools keep their resident
+    layout and are never copied, and the temporaries stay beside 10.2 GB
+    of weights and cache at the cell's 6 layers."""
+    import re
+
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.models import sdar
+
+    L, NB, B, MB, K, T = 2, 929, 32, 29, 8, 2048
+    cfg = dataclasses.replace(
+        sdar.PRESETS["sdar-30b-a3b"], n_layers=L, attn_impl="pallas",
+        packed_attn_impl="pallas")
+    S = _sds(one_chip)
+    shapes = jax.eval_shape(
+        lambda: sdar.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    kv = tuple(S(s, d) for s, d in zip(sdar.kv_cache_shapes(cfg, NB, BS),
+                                       sdar.kv_cache_dtypes(cfg)))
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+    W = sdar.lane_state_width(cfg)
+    pool = rf"bf16\[{L},4,{NB},128,{BS}\]"
+
+    def pools_stay(hlo):
+        assert not re.findall(rf"= {pool}\S* copy\(", hlo)
+        assert set(re.findall(rf"{pool}(\{{[\d,]+)", hlo)) \
+            == {"{4,3,2,1,0"}
+
+    fn = jax.jit(
+        partial(JaxEngine._denoise_impl, sdar, cfg, None, True, K, False),
+        donate_argnums=(1,))
+    lowered = fn.lower(
+        params, kv, S((B, W), i32), S((B,), b1), S((B, W), i32),
+        S((B,), i32), S((B, MB), i32), S((B,), i32), S((B,), i32),
+        S((B,), i32), S((B,), f32), S((B,), i32), S((B,), f32),
+        S((B,), b1), S((), i32))
+    assert lowered.out_info[0].shape == (
+        K * cfg.block_length + 1 + len(sdar.KV_COUNTERS), B)
+    assert lowered.out_info[2].shape == (B, W)
+    program = lowered.compile()
+    hlo = program.as_text()
+    # a layer: the attention read and the experts' walk over the visited
+    # list
+    assert hlo.count("tpu_custom_call") == 2 * L
+    pools_stay(hlo)
+    _assert_experts_walk_the_visited_list(hlo, 128, B * cfg.block_length,
+                                          2048, 768)
+    assert program.memory_analysis().temp_size_in_bytes < 1.0e9
+    pre = jax.jit(partial(JaxEngine._prefill_packed_impl, sdar, cfg, None),
+                  donate_argnums=(1,))
+    program = pre.lower(
+        params, kv, S((T,), i32), S((T,), i32), S((T,), i32),
+        S((1, MB), i32), S((1,), i32), S((T,), b1), S((1,), i32),
+        S((1,), f32), S((1,), i32), S((1,), f32)).compile()
+    hlo = program.as_text()
+    # a layer: the flash pass under the block-causal bound and three
+    # grouped matmuls
+    assert hlo.count("tpu_custom_call") == 4 * L
+    pools_stay(hlo)
+    assert program.memory_analysis().temp_size_in_bytes < 2.0e9
