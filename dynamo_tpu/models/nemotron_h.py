@@ -15,8 +15,10 @@ Block i is `x += mixer_i(RMSNorm(x))` with ONE mixer, chosen by
     goes through W_out.
   * `*` attention: models/llama.py's `_qkv` / `_attn_out` and the paged
     GQA write and read of ops/paged_attention.py (the Pallas kernel
-    where `cfg.attn_impl` resolves to it), imported.  NO rotary: position
-    lives in the Mamba layers (`_qkv` with no positions).
+    where `cfg.attn_impl` resolves to it), imported; a prompt's chunk is
+    read through ops/packed_prefill.py (the flash scan, or the Pallas
+    kernel where `cfg.packed_attn_impl` resolves to it).  NO rotary:
+    position lives in the Mamba layers (`_qkv` with no positions).
   * `E` experts: DeepSeek routing (`ds_router` at one group: sigmoid,
     choice bias, top-k, renormalised, scaled) over `n_experts` router
     outputs of which this program holds `experts_held` = (first, count),
@@ -74,12 +76,15 @@ from ..ops.lane_state import (
     rows_start,
     rows_target,
 )
+from ..ops.packed_prefill import (
+    packed_prefill_attention,
+    resolve_packed_impl,
+    write_packed_kv,
+)
 from ..ops.paged_attention import (
     PALLAS_IMPLS,
     paged_attention_decode,
-    paged_prefill_attention,
     resolve_decode_impl,
-    write_prompt_kv_batched,
     write_token_kv,
 )
 from ..ops.pallas_lane_state import ssd_lanes_step
@@ -122,7 +127,6 @@ class NemotronHConfig:
     n_heads: int = 4
     n_kv_heads: int = 2
     head_dim: int = 16
-    attn_q_block: int = 512       # queries a pass of the prefill read
     qk_norm: bool = False
     rope_theta: float = 10000.0   # a carried key: no rotary is applied
     # experts (models/moe.py moe_dispatch reads these)
@@ -146,6 +150,8 @@ class NemotronHConfig:
     dtype: Any = jnp.bfloat16
     attn_impl: str = "auto"       # the GQA layers' decode read and, by
                                   # its own conditions, the state's step
+    packed_attn_impl: str = "auto"  # the GQA layers' prefill read
+                                    # (ops/packed_prefill.py)
     eos_token_ids: Tuple[int, ...] = (2,)
 
     def __post_init__(self):
@@ -306,12 +312,25 @@ def prefill_token_counts(cfg: NemotronHConfig, pos: int, chunk: int,
     position `pos` in a program of `bucket` rows: tokens through the
     chunked scan, the bucket's rows beyond them (what padding costs the
     scan), tokens in a program that started from a carried state, rows
-    that started from zeros."""
+    that started from zeros; the tokens the attention blocks' prefill
+    read took, and those of them whose program ran it in the kernel: the
+    rule the traced read applies to its cache
+    (ops/packed_prefill.resolve_packed_impl), asked from the host as
+    `deepseek.mla_prefill_impl` asks its own.  The host has no cache to
+    show: it asks about the engine's default pool, 128-token blocks in
+    the configuration's dtype, and about one row a program (the stream
+    is the bucket; `Bp` rows make a stream `Bp` buckets long)."""
+    gqa = len(cfg.layers_of(ATTN)) * chunk
+    kernel = resolve_packed_impl(
+        cfg.packed_attn_impl, jax.default_backend(), 128, cfg.head_dim,
+        cfg.dtype, bucket) in PALLAS_IMPLS
     return {
         "ssm_tokens.prefill": chunk,
         "ssm_pad_tokens.prefill": max(bucket - chunk, 0),
         "ssm_carried_tokens.prefill": chunk if pos > 0 else 0,
         "ssm_resets": int(chunk > 0 and pos == 0),
+        "gqa_prefill_tokens.prefill": gqa,
+        "gqa_prefill_kernel_tokens.prefill": gqa if kernel else 0,
     }
 
 
@@ -459,30 +478,45 @@ def _experts(layer, cfg: NemotronHConfig, h: jax.Array,
     return (out,) + moe_held_counts(cfg, top_e, valid)
 
 
-def _attn_prefill(cfg: NemotronHConfig, q, k, v, k_cache, v_cache, pli,
-                  table, ctx_len, true_len):
-    """One row's chunk [T, ...] over K and V ALREADY written to the
-    pool, `attn_q_block` queries a pass: a pass reads the context (the
-    cache up to its first query) and its own block's keys, so the score
-    block is [q_block, heads, table + q_block] whatever T is (2048
-    queries at once over a table of 20 blocks: 1.2 GB of float32
-    scores)."""
-    T = q.shape[0]
-    qb = min(cfg.attn_q_block, T)
-    if T % qb:
-        raise ValueError(f"a chunk of {T} tokens does not split into "
-                         f"query blocks of {qb}")
-    blocks = lambda x: x.reshape(T // qb, qb, *x.shape[1:])
+# query heads a KV head in ONE call of the packed prefill kernel.  Its body
+# is unrolled over the group's heads and holds their running max, sum and
+# accumulator in VMEM: at this family's 16 heads a KV head one call took
+# 0.50-0.60 ms a block at 1024 tokens and 1.05-1.15 at 2048 and made each
+# kernel-bearing program's executable 23 MB larger (3.9 s more to load at
+# a warm start, 16-20 s more to compile cold), where four calls of 4 heads
+# (Mistral's ratio, the one the kernel's tiles were chosen at) under
+# `lax.map`, one call site, take 0.28-0.39 and 0.64-0.75 ms and 2.5 MB,
+# bit for bit the same result (chip runs, PR 52; PERF.md section 6).  The
+# split belongs in the op, for every caller of this ratio (Command A+);
+# PR 52 could not edit ops/ (ROADMAP S9d).
+KERNEL_HEADS = 4
 
-    def one(args):
-        qi, ki, vi, i = args
-        return paged_prefill_attention(
-            qi, ki, vi, k_cache, v_cache, pli, table, ctx_len + i * qb,
-            jnp.clip(true_len - i * qb, 0, qb))
 
-    out = jax.lax.map(one, (blocks(q), blocks(k), blocks(v),
-                            jnp.arange(T // qb)))
-    return out.reshape(T, *out.shape[2:])
+def _attn_prefill(cfg: NemotronHConfig, q, k_cache, v_cache, pli, stream):
+    """The packed stream's queries [T, heads, hd] over K and V ALREADY
+    written to the pool (`stream`: tables, segment rows, positions,
+    valid), in the form the op's own rule gives this cache and stream.
+    The scan takes every head at once; the kernel takes `KERNEL_HEADS`
+    heads of each KV head a call, the calls one after another."""
+    T, nh, hd = q.shape
+    impl = resolve_packed_impl(cfg.packed_attn_impl, jax.default_backend(),
+                               k_cache.shape[4], k_cache.shape[3],
+                               k_cache.dtype, T)
+
+    def read(qi):
+        return packed_prefill_attention(qi, k_cache, v_cache, pli, *stream,
+                                        impl=impl)
+
+    nkv = cfg.n_kv_heads
+    n, odd = divmod(nh // nkv, KERNEL_HEADS)
+    if impl not in PALLAS_IMPLS or n < 2 or odd:
+        return read(q)
+    # heads lie KV head major: [nkv, n, KERNEL_HEADS] -> n streams of
+    # [nkv, KERNEL_HEADS]
+    qs = q.reshape(T, nkv, n, KERNEL_HEADS, hd).transpose(2, 0, 1, 3, 4)
+    out = jax.lax.map(read, qs.reshape(n, T, nkv * KERNEL_HEADS, hd))
+    return out.reshape(n, T, nkv, KERNEL_HEADS, hd).transpose(
+        1, 2, 0, 3, 4).reshape(T, nh, hd)
 
 
 # ---------------------------------------------------------------------------
@@ -505,14 +539,30 @@ def prefill_batched(
     padded per row.  A Mamba block takes each row's state and tail from
     its lane (zeros where the row starts at position 0), runs the
     chunked scan with padding switched off (dt 0 and a zeroed input) and
-    puts both back; a row of no tokens writes nothing."""
+    puts both back; a row of no tokens writes nothing.  An attention
+    block writes the rows' K and V to the pool and reads them, and the
+    cached context, back through ops/packed_prefill.py: the rows laid
+    end to end are a packed stream whose segments are the rows
+    (cohere2.prefill_batched, keye.prefill_batched).  The write is the
+    stream's too, whole planes in the layout the pool is resident in:
+    beside the kernel's read the flat column scatter has XLA copy the
+    pool (tests/test_tpu_compile.py).  The scan form of
+    that read runs one flash pass a segment ROW over the whole stream,
+    so at Bp > 1 it computes Bp-fold; every cell pins `max_prefill_seqs`
+    1, and co-batched rows (ROADMAP S10) inherit this note."""
     if lanes is None:
         raise ValueError("this family's state is addressed by lane: "
                          "prefill needs `lanes`")
     k_cache, v_cache, state, tail, counters = kv_cache
     Bp, T = token_ids.shape
     x = params["embedding"][token_ids].astype(jnp.float32)  # [Bp, T, d]
-    valid = jnp.arange(T)[None, :] < true_lens[:, None]
+    idx = jnp.arange(T, dtype=jnp.int32)[None, :]
+    valid = idx < true_lens[:, None]
+    # the packed stream (tables, segment rows, positions, valid), the
+    # same for every attention block; no rotary here: a position says
+    # where a token's K and V go and how far its query sees
+    stream = (block_tables, jnp.repeat(jnp.arange(Bp, dtype=jnp.int32), T),
+              (ctx_lens[:, None] + idx).reshape(-1), valid.reshape(-1))
     fresh = ctx_lens == 0
     put = rows_target(lanes, true_lens, state.shape[1])
     picks = jnp.zeros((), jnp.int32)
@@ -541,14 +591,11 @@ def prefill_batched(
             tail = rows_put(tail, pli, put, t1)
             x = x + _ssm_out(layer, cfg, y, z)
         elif kind == ATTN:
-            q, k, v = _qkv(layer, cfg, h.astype(cfg.dtype), None)
-            k_cache, v_cache = write_prompt_kv_batched(
-                k_cache, v_cache, pli, k, v, block_tables, ctx_lens,
-                true_lens)
-            attn = jax.vmap(
-                lambda qb, kb, vb, tb, cl, tl: _attn_prefill(
-                    cfg, qb, kb, vb, k_cache, v_cache, pli, tb, cl, tl)
-            )(q, k, v, block_tables, ctx_lens, true_lens)
+            q, k, v = _qkv(layer, cfg,
+                           h.astype(cfg.dtype).reshape(Bp * T, -1), None)
+            k_cache, v_cache = write_packed_kv(
+                k_cache, v_cache, pli, k, v, *stream)
+            attn = _attn_prefill(cfg, q, k_cache, v_cache, pli, stream)
             x = x + _attn_out(layer, attn.reshape(Bp, T, cfg.q_dim))
         else:
             out, n_on, _ = moe_rows(partial(_experts, layer, cfg), h, valid)

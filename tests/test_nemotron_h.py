@@ -39,8 +39,7 @@ from dynamo_tpu.protocols import (
     StopConditions,
 )
 
-TINY = NemotronHConfig(dtype=jnp.float32, experts_held=(0, 8),
-                       attn_q_block=16)
+TINY = NemotronHConfig(dtype=jnp.float32, experts_held=(0, 8))
 # the family's programs, compiled once a shape as the engine does
 PREFILL = jax.jit(nh.prefill, static_argnums=1)
 PREFILL_BATCHED = jax.jit(nh.prefill_batched, static_argnums=1)
@@ -53,6 +52,15 @@ BS, LANES, TABLE = 16, 4, 8
 # another platform's reductions and is four orders under the smallest
 # effect of a left-out detail (1.7, below)
 TOL = 1e-4
+# the two forms of the attention blocks' prefill read (ops/packed_prefill.py):
+# the float32 flash scan ("auto" on the CPU) and the Pallas kernel under
+# the interpreter (it takes this block size and head_dim: blocks of 16,
+# heads of 16, tests/test_packed_pallas.py)
+PACKED_IMPLS = ("xla", "pallas_interpret")
+
+
+def packed(impl, cfg=TINY):
+    return dataclasses.replace(cfg, packed_attn_impl=impl)
 
 
 def fresh_cache(cfg=TINY, num_blocks=40, lanes=LANES, dirty=False):
@@ -137,13 +145,15 @@ def test_layer_pattern_is_the_published_one():
         dataclasses.replace(TINY, pattern="ME-*")
 
 
-def test_paged_path_matches_reference_logits(model):
+@pytest.mark.parametrize("impl", PACKED_IMPLS)
+def test_paged_path_matches_reference_logits(model, impl):
     """Prompt of 50 tokens prefilled as 32 + 18 (two programs: the state
     carried once, the second padded to its bucket, a chunk of the scan
-    cut by the prompt's end), then 20 decode steps across the block
+    cut by the prompt's end; the attention block's second read crosses
+    into the cached context), then 20 decode steps across the block
     boundary at 64, on a lane that was dirty; the state itself agrees."""
     params, toks, full, states = model
-    got, _, kv = paged_logits(params, TINY, toks, 50)
+    got, _, kv = paged_logits(params, packed(impl), toks, 50)
     for i, row in enumerate(got):
         np.testing.assert_allclose(row, full[49 + i], rtol=0, atol=TOL)
     # after the last decode step the state has seen all 70 tokens
@@ -155,11 +165,40 @@ def test_paged_path_matches_reference_logits(model):
     assert float(jnp.abs(kv[3][:, 3] - 1).max()) == 0.0
 
 
-def test_prompt_of_several_programs_carries_the_state(model):
+def test_kernel_takes_the_heads_of_a_group_four_at_a_time():
+    """16 query heads over 2 KV heads: under the kernel the attention
+    blocks' read is `nh.KERNEL_HEADS` = 4 heads of each KV head a call,
+    two calls one after another (`_attn_prefill`); the logits of a prompt
+    in two programs (the second reads the first's keys from the pool)
+    are the scan's, which takes all 16 at once, and the reference's."""
+    cfg = dataclasses.replace(TINY, n_heads=16, pattern="M*E*")
+    params = nh.init_params(cfg, jax.random.PRNGKey(1))
+    toks = np.random.default_rng(2).integers(3, cfg.vocab_size, 50)
+    want = np.asarray(ref.reference_logits(params, cfg, toks.tolist()))[49]
+    for impl in PACKED_IMPLS:
+        got, _, _ = paged_logits(params, packed(impl, cfg), toks, 50)
+        np.testing.assert_allclose(got[0], want, rtol=0, atol=TOL)
+    # and the split is really taken: one kernel call in a loop a block
+    S = jax.ShapeDtypeStruct
+    kv = tuple(S(s, d) for s, d in zip(
+        nh.kv_cache_shapes(cfg, 40, BS, lanes=LANES),
+        nh.kv_cache_dtypes(cfg)))
+    i32 = jnp.int32
+    text = PREFILL.lower(
+        jax.eval_shape(lambda: params), packed("pallas_interpret", cfg), kv,
+        S((32,), i32), S((32,), i32), S((TABLE,), i32), S((), i32),
+        S((), i32), lanes=S((), i32)).as_text()
+    assert f"tensor<2x32x{2 * nh.KERNEL_HEADS}x16xf32>" in text
+
+
+@pytest.mark.parametrize("impl", PACKED_IMPLS)
+def test_prompt_of_several_programs_carries_the_state(model, impl):
     """Buckets of 16: a prompt of 50 is four programs, the state handed
-    on three times and the convolution's tail with it."""
+    on three times and the convolution's tail with it; the attention
+    block reads three programs' keys back from the pool."""
     params, toks, full, _ = model
-    got, _, _ = paged_logits(params, TINY, toks[:52], 50, bucket=16)
+    got, _, _ = paged_logits(params, packed(impl), toks[:52], 50,
+                             bucket=16)
     for i, row in enumerate(got):
         np.testing.assert_allclose(row, full[49 + i], rtol=0, atol=TOL)
 
@@ -213,13 +252,16 @@ def test_two_lanes_of_different_length_in_one_burst(model):
                                    atol=TOL)
 
 
-def test_padded_row_beside_a_full_one(model):
+@pytest.mark.parametrize("impl", PACKED_IMPLS)
+def test_padded_row_beside_a_full_one(model, impl):
     """prefill_batched: a row of 32 tokens, a row of 11 padded to 32 and
-    a filler row of none (lane 0, as the engine pads).  Both real rows
+    a filler row of none (lane 0, as the engine pads): a packed stream
+    of four segments whose padding lies between the runs.  Both real rows
     agree with the reference; the short row's state and tail are what
     its 11th token left (a later chunk continues from them: 1.4 chunks
     of scan behind it changed nothing); lane 0 keeps what it held."""
     params, toks, full, _ = model
+    cfg = packed(impl)
     short = np.random.default_rng(6).integers(3, TINY.vocab_size, 24)
     full_s = np.asarray(ref.reference_logits(params, TINY, short.tolist()))
     kv = fresh_cache(dirty=True)
@@ -229,7 +271,7 @@ def test_padded_row_beside_a_full_one(model):
     tables[0, :3], tables[1, :2] = [3, 7, 9], [11, 13]
     pos = np.tile(np.arange(32, dtype=np.int32), (4, 1))
     logits, kv = PREFILL_BATCHED(
-        params, TINY, kv, jnp.asarray(rows), jnp.asarray(pos),
+        params, cfg, kv, jnp.asarray(rows), jnp.asarray(pos),
         jnp.asarray(tables), jnp.zeros(4, jnp.int32),
         jnp.asarray([32, 11, 0, 0], jnp.int32),
         lanes=jnp.asarray([2, 1, 0, 0], jnp.int32))
@@ -245,7 +287,7 @@ def test_padded_row_beside_a_full_one(model):
     t = np.zeros(32, np.int32)
     t[:13] = short[11:]
     logits, kv = PREFILL(
-        params, TINY, kv, jnp.asarray(t),
+        params, cfg, kv, jnp.asarray(t),
         jnp.asarray(11 + np.arange(32, dtype=np.int32)),
         jnp.asarray(tables[1]), jnp.int32(11), jnp.int32(13),
         lanes=jnp.int32(1))
@@ -538,6 +580,58 @@ async def test_engine_serves_the_family_and_counts():
     assert 0 < m["moe_picks_held.decode"] < m["moe_picks.decode"]
     assert 0 < m["moe_experts_visited.decode"] \
         <= m["moe_expert_slots.decode"]
+    # every prompt token through every attention block's prefill read,
+    # none of them in the kernel: the CPU's "auto" is the scan
+    assert m["gqa_prefill_tokens.prefill"] \
+        == total * len(TINY.layers_of("*"))
+    assert m["gqa_prefill_kernel_tokens.prefill"] == 0
+    await eng.close()
+
+
+def test_prefill_counts_follow_the_rule_the_read_applies():
+    """`prefill_token_counts` at the cell's cut (four attention blocks
+    of heads of 128): four reads a token; the kernel's tokens are those
+    of a program whose bucket `resolve_packed_impl` gives the kernel: no
+    bucket on the CPU under "auto", every bucket under an explicit
+    kernel, and on a TPU ("auto" asked about that platform) the buckets
+    from KERNEL_MIN_TOKENS."""
+    from dynamo_tpu.ops.packed_prefill import (
+        KERNEL_MIN_TOKENS,
+        resolve_packed_impl,
+    )
+
+    big = nh.PRESETS["nemotron-twotower-30b-a3b"]
+    cut = dataclasses.replace(big, pattern=big.pattern[:27])
+    assert cut.packed_attn_impl == "auto"
+    for bucket in (256, 2048):
+        got = nh.prefill_token_counts(cut, 128, 200, bucket)
+        assert got["gqa_prefill_tokens.prefill"] == 4 * 200
+        assert got["gqa_prefill_kernel_tokens.prefill"] == 0
+        got = nh.prefill_token_counts(
+            dataclasses.replace(cut, packed_attn_impl="pallas"), 0, 200,
+            bucket)
+        assert got["gqa_prefill_kernel_tokens.prefill"] == 4 * 200
+    assert [resolve_packed_impl("auto", "tpu", 128, cut.head_dim,
+                                cut.dtype, t)
+            for t in (KERNEL_MIN_TOKENS // 2, KERNEL_MIN_TOKENS)] \
+        == ["xla", "pallas"]
+
+
+async def test_engine_takes_the_shared_packed_impl_override():
+    """`EngineConfig.packed_attn_impl` reaches this family's field as it
+    reaches llama's, cohere2's and sdar's (engine/core.py): under the
+    interpreted kernel the engine emits the reference's greedy tokens
+    and counts every prompt token as the kernel's."""
+    eng = _engine(packed_attn_impl="pallas_interpret")
+    assert eng.model_cfg.packed_attn_impl == "pallas_interpret"
+    p = np.random.default_rng(4).integers(3, TINY.vocab_size, 45).tolist()
+    toks = await _generate(eng, "r", p, 6)
+    full = ref.reference_logits(eng.params, eng.model_cfg, p + toks[:-1])
+    assert [int(jnp.argmax(full[len(p) - 1 + j]))
+            for j in range(len(toks))] == toks
+    m = eng.metrics
+    assert m["gqa_prefill_kernel_tokens.prefill"] \
+        == m["gqa_prefill_tokens.prefill"] == 45
     await eng.close()
 
 

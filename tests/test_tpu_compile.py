@@ -1006,12 +1006,16 @@ def test_ssm_decode_and_prefill_compile_for_v5e(topo, one_chip):
     where they lie (PR 44: the up stack, 1856 wide, lies with the model
     width minor and the kernel takes it transposed, no copy), and the
     prompt groups its picks in TWO grouped matmuls an expert block (a
-    plain expert has no gate matrix)."""
+    plain expert has no gate matrix) and reads its attention blocks in
+    the packed flash kernel (PR 52: the padded row as a packed stream,
+    written as whole planes; the pool never copied, no float32 score
+    plane of the table-wide read it replaced)."""
     import re
 
     from dynamo_tpu.engine.core import JaxEngine
     from dynamo_tpu.models import nemotron_h as nh
     from dynamo_tpu.ops.lane_state import resolve_state_impl
+    from dynamo_tpu.ops.packed_prefill import resolve_packed_impl
     from dynamo_tpu.ops.paged_attention import resolve_decode_impl
 
     NB, B, MB, K, T = 1281, 64, 20, 8, 2048
@@ -1066,18 +1070,32 @@ def test_ssm_decode_and_prefill_compile_for_v5e(topo, one_chip):
     assert hlo.count("tpu_custom_call") == NA + NM + NE
     _assert_experts_walk_the_visited_list(hlo, 16, B, 2688, 1856)
     assert program.memory_analysis().temp_size_in_bytes < 0.3e9
-    pre = jax.jit(partial(JaxEngine._prefill_impl, nh, cfg),
-                  donate_argnums=(1,))
+    packed = resolve_packed_impl("auto", topo.devices[0].platform, BS, 128,
+                                 jnp.bfloat16, T)
+    assert packed == "pallas"
+    pre = jax.jit(
+        partial(JaxEngine._prefill_impl, nh,
+                dataclasses.replace(cfg, packed_attn_impl=packed)),
+        donate_argnums=(1,))
     program = pre.lower(
         params, kv, S((T,), i32), S((T,), i32), S((MB,), i32), S((), i32),
         S((), i32), S((), i32), S((), f32), S((), i32), S((), f32), None,
         None, S((), i32)).compile()
     hlo = program.as_text()
     state_stays(hlo)
-    assert hlo.count("tpu_custom_call") == 2 * NE
+    # the attention blocks read the chunk and its context in the packed
+    # flash kernel (PR 52: `auto` at 2048 tokens on the chip), one
+    # custom call a block beside the experts' two; the pool is written
+    # and read where it lies (beside the kernel the flat column scatter
+    # cost a copy of the pool), and nothing of the table-wide read's
+    # float32 score plane (`[512, 32, 2560 + 512]`, 201 MB a pass) is left
+    assert hlo.count("tpu_custom_call") == 2 * NE + NA
+    _assert_pool_stays_where_it_lies(hlo, NA, 2, NB, 128)
+    assert "f32[512,32," not in hlo
     assert f"bf16[16,{T},1856]" not in hlo \
         and f"bf16[16,1856,{T}]" not in hlo
-    assert program.memory_analysis().temp_size_in_bytes < 3.0e9
+    # 1.124 GB here (1.289 with the table-wide read and the scatter)
+    assert program.memory_analysis().temp_size_in_bytes < 1.3e9
 
 
 def test_window_ring_decode_and_prefill_compile_for_v5e(topo, one_chip,
