@@ -788,7 +788,12 @@ class JaxEngine:
         # the scheduler thread's phases: counters host_s.<kind> /
         # host_n.<kind> always, `dyn.<kind>` on the profiler's clock
         # while a session is live, ring spans under a Tracer (obs/)
-        self._phase = obs.PhaseClock(self.metrics, self._obs_track)
+        self._phase = obs.PhaseClock(self.metrics, self._obs_track,
+                                     behind=self._bursts_behind,
+                                     compiles=self.compile_watch.events)
+        # the phases that outlasted obs.PAUSE_S, newest last, each saying
+        # what stood behind it on the device when it ended
+        self.pauses = self._phase.pauses
         self.itl_ema_s = 0.0  # streamed inter-token latency (SLA planner)
         # forward-pass metrics stream (ref fpm_publisher.rs:1-10 /
         # instrumented_scheduler.py): one record per dispatched program —
@@ -2154,48 +2159,29 @@ class JaxEngine:
 
     # -- scheduler loop ---------------------------------------------------
     async def _loop(self) -> None:
+        """The scheduler loop, on the event-loop thread.  Its wall time
+        is tiled by three phases (obs.PhaseClock): `step` (on a pool
+        thread), `hop` from a step's end to the next step's start, and
+        `idle` while the engine stands empty."""
         try:
+            work = await self._step_work()
             while not self._closed:
-                if self._sched_calls:
-                    # heavy calls (KV gathers) run off the event loop; no
-                    # scheduler step is in flight while we await this
-                    await asyncio.to_thread(self._drain_sched_calls)
-                self._reap_parked()
-                # a slot mid-pull has no step work of its own (its chunk
-                # injects arrive as sched_calls, which set _wake): don't
-                # hot-spin the step loop on its behalf — EXCEPT when its
-                # cancellation is pending, which needs one step to reap
-                # it (_process_cancellations); without that carve-out a
-                # request cancelled mid-pull on an otherwise idle worker
-                # held its KV blocks until unrelated traffic arrived
-                busy = (any(s is not None
-                            and (not s.pulling or s.cancel_requested)
-                            for s in self._slots)
-                        or bool(self._inflight))
-                if not busy and not self.waiting:
+                if not work:
+                    # nothing below yields between `_step_work`'s look at
+                    # the queue and this clear: an enqueue cannot fall
+                    # between them
                     self._wake.clear()
-                    if self._sched_calls:
-                        continue
-                    if self.kv_ledger is not None \
-                            and self.kv_ledger.audit_due(5.0):
-                        # idle-tick reconciliation: an idle worker's
-                        # books still get swept (leaks hide best in
-                        # caches nobody is touching)
-                        await asyncio.to_thread(self._audit_ledger,
-                                                "idle")
-                    if self._parked:
-                        # wake periodically so the parked-KV TTL reaper runs
-                        # even on an otherwise idle worker
-                        try:
-                            await asyncio.wait_for(self._wake.wait(), 5.0)
-                        except asyncio.TimeoutError:
-                            pass
-                    else:
-                        await self._wake.wait()
+                    if not self._sched_calls:
+                        await self._stand_empty()
+                    work = await self._step_work()
                     continue
                 await asyncio.to_thread(self._sched_step)
-                self.metrics["steps"] += 1
-                await asyncio.sleep(0)  # yield to the event loop
+                # this thread's line of the hop (the counter is taken on
+                # the step's thread: PhaseClock.step_opens)
+                with self._phase("hop"):
+                    self.metrics["steps"] += 1
+                    await asyncio.sleep(0)  # yield to the event loop
+                    work = await self._step_work()
         except asyncio.CancelledError:
             pass
         except Exception:
@@ -2203,6 +2189,45 @@ class JaxEngine:
             obs.flight_dump("engine_crash")
             self._fail_all_streams()
             raise
+
+    async def _step_work(self) -> bool:
+        """Between two steps: run the queued scheduler calls, reap
+        expired parked KV, and say whether a step has anything to do."""
+        if self._sched_calls:
+            # heavy calls (KV gathers) run off the event loop; no
+            # scheduler step is in flight while we await this
+            await asyncio.to_thread(self._drain_sched_calls)
+        self._reap_parked()
+        # a slot mid-pull has no step work of its own (its chunk
+        # injects arrive as sched_calls, which set _wake): don't
+        # hot-spin the step loop on its behalf — EXCEPT when its
+        # cancellation is pending, which needs one step to reap
+        # it (_process_cancellations); without that carve-out a
+        # request cancelled mid-pull on an otherwise idle worker
+        # held its KV blocks until unrelated traffic arrived
+        return (any(s is not None
+                    and (not s.pulling or s.cancel_requested)
+                    for s in self._slots)
+                or bool(self._inflight) or bool(self.waiting))
+
+    async def _stand_empty(self) -> None:
+        """The empty engine waits to be woken, under `idle`."""
+        if self.kv_ledger is not None \
+                and self.kv_ledger.audit_due(5.0):
+            # idle-tick reconciliation: an idle worker's
+            # books still get swept (leaks hide best in
+            # caches nobody is touching)
+            await asyncio.to_thread(self._audit_ledger, "idle")
+        with self._phase("idle"):
+            if self._parked:
+                # wake periodically so the parked-KV TTL reaper runs
+                # even on an otherwise idle worker
+                try:
+                    await asyncio.wait_for(self._wake.wait(), 5.0)
+                except asyncio.TimeoutError:
+                    pass
+            else:
+                await self._wake.wait()
 
     def _sched_step(self) -> None:
         """One scheduler iteration, entirely on the worker thread.
@@ -2994,7 +3019,13 @@ class JaxEngine:
         next step emits them) — the dispatching step never blocks on its
         own program, so the device_wait only ever pays for work the
         device had a full step to finish.  Returns the host array, or
-        None when deferred.  `entries` is [(slot, program row)]."""
+        None when deferred.  `entries` is [(slot, program row)].
+
+        The wait carries `programs`, the chunk programs of the longest
+        prompt it completes: a prompt that came to an idle engine has
+        them all queued ahead of this read, so the wait is honestly that
+        many programs long (obs.PhaseClock.pause scales its limit)."""
+        programs = 1 + max((s.prefill_chunks for s, _ in entries), default=0)
         if self._overlap:
             try:
                 tok.copy_to_host_async()
@@ -3004,9 +3035,11 @@ class JaxEngine:
             for slot, row in entries:
                 slot.awaiting_first = True
                 ents.append((slot, (self._seq_id(slot), slot.epoch), row))
-            self._pending_first.append({"tok": tok, "entries": ents})
+            self._pending_first.append({"tok": tok, "entries": ents,
+                                        "programs": programs})
             return None
-        with self._phase("device_wait", what="prefill_first"):
+        with self._phase("device_wait", what="prefill_first",
+                         programs=programs):
             arr = np.asarray(tok)
         return arr
 
@@ -3020,7 +3053,8 @@ class JaxEngine:
         if not self._pending_first:
             return
         pending, self._pending_first = self._pending_first, []
-        with self._phase("device_wait", what="prefill_first"):
+        with self._phase("device_wait", what="prefill_first",
+                         programs=max(e["programs"] for e in pending)):
             arrs = [np.asarray(e["tok"]) for e in pending]
         with self._phase("emit", what="prefill_first"):
             for e, arr in zip(pending, arrs):
@@ -4422,6 +4456,16 @@ class JaxEngine:
     def _drain_inflight(self) -> None:
         while self._inflight:
             self._process_oldest_burst()
+
+    def _bursts_behind(self) -> Tuple[int, int]:
+        """(bursts in flight, those whose tokens are ready): asked when a
+        phase ends that outlasted obs.PAUSE_S.  The device runs programs
+        in order and a burst is tens of milliseconds of it, so after a
+        `burst_fetch`, which popped the OLDEST burst, all of them ready
+        says the chip ran on while the host waited, none ready that the
+        chip itself stood."""
+        bursts = [e["burst"] for e in self._inflight]
+        return len(bursts), sum(1 for b in bursts if b.is_ready())
 
     def _commit_full_blocks(self, slot: _Slot) -> None:
         """Register newly-completed full blocks under their PLH.

@@ -23,8 +23,55 @@ Design:
     thread's line of ``/host:CPU`` — the same axis as ``XLA Modules``
     and ``XLA Ops``, no environment variable.  (3) The ring, when a
     `Tracer` is installed.  With no session and no `Tracer` a phase is
-    two clock reads, two dict adds and one small handle: no lock, no
-    span record, nothing per token.
+    two clock reads, two dict adds, one small handle and one float
+    compare against ``PAUSE_S``: no lock, no span record, nothing per
+    token.
+
+  * **The loop's whole wall time.**  Three kinds tile the scheduler
+    loop's wall time (engine/core.py `_loop`; tier-1: within 2 %):
+    ``step``; ``hop``, from one step's end to the next step's start
+    while the engine did not go empty in between; ``idle``, the
+    empty-engine wait.  ``host_s.hop`` is taken on the step's own
+    thread from the two clock reads that close one ``step`` and open
+    the next (both thread hand-offs and the event loop's backlog are
+    inside it; no clock read of its own) and counts whole, like
+    ``step``: the scheduler calls drained between two steps keep their
+    own phases and are not subtracted.  The event-loop thread cannot
+    see a hop's beginning (its ``to_thread`` future resolves behind
+    whatever the loop had queued), so its ``with _phase("hop")`` adds
+    nothing to the counter and is the ``dyn.hop`` TraceMe / ring span
+    of the part it sees: the one place where counter and event differ.
+    What the three leave out is the loop's way into and out of an idle
+    wait.  Cost with no listener: two dict adds a step for ``hop``, one
+    handle an idle wait.
+
+  * **A pause names itself.**  A phase of any kind but ``step`` and
+    ``idle``, or a hop, that outlasts ``PAUSE_S`` (0.5 s) adds itself to
+    ``host_s.pause`` / ``host_n.pause``, appends one record to
+    ``engine.pauses`` (a deque of 64; keys ``PAUSE_KEYS``), logs one
+    ``pause {json}`` warning and asks the flight recorder for the ring.
+    The record says what stood behind the wait at the moment it ended:
+    ``inflight`` bursts dispatched and ``ready_behind`` of them ready
+    (the engine's callback; after a ``burst_fetch``, which pops the
+    OLDEST burst, all ready = the chip ran on and the host was late to
+    hear of it, none ready = the chip itself stood; after a
+    ``prefill_first`` the bursts were dispatched AHEAD of the prompt and
+    prove nothing; that wait carries ``programs``, the prompt's chunk
+    programs, and is held to ``PAUSE_S`` for each), and the step thread's and the process's CPU seconds
+    across the wait (near 0 of both across a 2 s wait = asleep in the
+    runtime; the wait's length in thread CPU = spinning or holding the
+    interpreter; process far above thread = another thread was busy: a
+    compile, the collector, a profile being written).  The two clocks
+    are read where a ``device_wait`` of ``what`` ``burst_fetch`` or
+    ``prefill_first`` opens (``CPU_TIMED_WAITS``) and a record of any
+    other kind carries None: ``time.thread_time()`` +
+    ``time.process_time()`` cost 12.0 us a pair on the chip's host
+    (0.5 us on a plain Linux; PERF.md section 6, PR 53), too much for
+    every step's opening.  A phase
+    whose span holds a compile event is no pause: the compile watch has
+    named that wait.  Cost with no listener: that pair of reads a
+    ``burst_fetch`` / ``prefill_first`` (about one a step), one float
+    compare a phase.
 
   * **Request stages, always on.**  Eight stamps a request, each set
     once where the work happens (engine/core.py): enqueued; seen by the
@@ -88,6 +135,13 @@ Span vocabulary (kind — where — what the time is):
 
   step             engine _sched_step / mocker _step: one scheduler
                    iteration end to end
+  hop              engine _loop: from a step's return to the next
+                   step's call while the engine did not go empty (the
+                   event-loop thread's line; the ``host_s.hop`` counter
+                   runs from step end to step start on the step's own
+                   thread and holds the hand-offs too)
+  idle             engine _loop: the empty engine waits to be woken
+                   (on the event-loop thread; never a pause)
   sched            host scheduling: cancellations, KVBM offload sweep,
                    admission (allocation + prefix match) — emitted only
                    when the device had nothing in flight (the host time
@@ -108,8 +162,10 @@ Span vocabulary (kind — where — what the time is):
                    ``cont`` (device-resident continuation vs full
                    upload), ``k``, ``lanes``
   device_wait      host blocked on a device fetch (burst readback,
-                   prefill first-token sync, KVBM gather); on the
-                   mocker, the simulated device step sleep
+                   prefill first-token sync, KVBM gather); a first-token
+                   sync carries ``programs``, the chunk programs of the
+                   prompt it stands behind; on the mocker, the simulated
+                   device step sleep
   spec_dispatch    proposing drafts and dispatching one packed
                    spec-verify program
   sample           host-side token acceptance: spec-decode rejection
@@ -169,7 +225,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 logger = logging.getLogger(__name__)
 
@@ -181,6 +237,32 @@ DEFAULT_RING = 16384
 STEP_PHASES = ("sched", "enqueue_ahead", "prefill_dispatch",
                "decode_dispatch", "spec_dispatch", "device_wait", "sample",
                "emit", "audit")
+
+# the scheduler loop's time outside a step (engine/core.py _loop): the
+# empty-engine wait and the way from one step to the next; with `step`
+# they tile the loop's wall time
+LOOP_PHASES = ("idle", "hop")
+
+# a phase (any kind but `step` and `idle`) or a hop that took longer names
+# itself (PhaseClock.pause).  Every cell's longest honest wait for ONE
+# program is under 0.3 s (a 2048-token prefill 155-230 ms, a burst of
+# passes 104 ms), the pauses on record are 1.6-3.8 s; a wait that says it
+# stood behind several `programs` is held to this many seconds for each
+# (a 12288-token prompt sent to an idle engine waits 0.5-1.4 s for its
+# first token, honestly: PERF.md section 6, PR 53)
+PAUSE_S = 0.5
+
+# the `device_wait` kinds (`what`) that read the thread's and the
+# process's CPU clocks as they open, so that a pause there can say whether
+# the thread slept or span: the two waits a step makes as a rule.  Not
+# every step or phase: the pair costs 12 us on the chip's host (PERF.md
+# section 6, PR 53; 0.5 us on a plain Linux)
+CPU_TIMED_WAITS = ("burst_fetch", "prefill_first")
+
+# the keys of one `engine.pauses` record, a closed set (PhaseClock.pause
+# fills them in this order)
+PAUSE_KEYS = ("t", "kind", "what", "seconds", "k", "inflight",
+              "ready_behind", "step_thread_cpu_s", "step_process_cpu_s")
 
 # the stages of one request's life (engine/core.py _emit_first,
 # _push_token): queue -> prefill -> emit are its time to first token,
@@ -195,7 +277,8 @@ REQUEST_STAGES = ("req_queue", "req_wake", "req_lane", "req_turn",
 # statically — a typo'd kind would otherwise produce an orphan span the
 # report buckets under its own name and no dashboard ever joins on.
 # Extend this set and the docstring table together when adding a kind.
-SPAN_KINDS = frozenset(STEP_PHASES + REQUEST_STAGES) | frozenset({
+SPAN_KINDS = frozenset(STEP_PHASES + LOOP_PHASES
+                       + REQUEST_STAGES) | frozenset({
     "step",
     "detok",
     "frame_egress",
@@ -466,7 +549,8 @@ def span(kind: str, track: Optional[str] = None,
 class _Phase:
     """One open phase (the handle `with clock(kind) as ph` yields)."""
 
-    __slots__ = ("clock", "kind", "attrs", "ring", "t0", "child_s", "tm")
+    __slots__ = ("clock", "kind", "attrs", "ring", "t0", "child_s", "tm",
+                 "cpu0")
 
     def __init__(self, clock: "PhaseClock", kind: str,
                  attrs: Optional[dict]):
@@ -476,6 +560,7 @@ class _Phase:
         self.ring = True
         self.child_s = 0.0
         self.tm = None
+        self.cpu0 = None
 
     def set(self, **attrs) -> None:
         """Attributes known only once the phase's work is done (a burst's
@@ -499,7 +584,15 @@ class _Phase:
                                      **(self.attrs or {}))
             self.tm.__enter__()
         clock.open.append(self)
-        self.t0 = time.monotonic()
+        self.t0 = t0 = time.monotonic()
+        kind = self.kind
+        if kind == "step":
+            clock.step_opens(t0)
+        elif kind == "idle":
+            clock.left_t = 0.0           # an idle wait is not a hop
+        elif kind == "device_wait" and self.attrs \
+                and self.attrs.get("what") in CPU_TIMED_WAITS:
+            self.cpu0 = (time.thread_time(), time.process_time())
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -509,31 +602,61 @@ class _Phase:
         clock.open.pop()
         if clock.open:
             clock.open[-1].child_s += dur
-        m = clock.metrics
-        # `step` counts whole steps; every other kind its self time (the
-        # seconds in no phase nested inside it), so the kinds partition
-        # the steps' wall time
-        own = dur if self.kind == "step" else dur - self.child_s
-        ks, kn = clock.keys[self.kind]
-        m[ks] = m.get(ks, 0.0) + own
-        m[kn] = m.get(kn, 0) + 1
+        kind = self.kind
+        if kind == "hop":
+            # the event-loop thread's line of a hop: an event only; the
+            # counter is the step thread's, end of step to start of step
+            # (PhaseClock.step_opens)
+            own = 0.0
+        else:
+            # `step`, like `hop`, counts whole; every other kind its self
+            # time (the seconds in no phase nested inside it), so the
+            # kinds partition the steps' wall time
+            if kind == "step":
+                own = dur
+                clock.left_t = t1
+            else:
+                own = dur - self.child_s
+            m = clock.metrics
+            ks, kn = clock.keys[kind]
+            m[ks] = m.get(ks, 0.0) + own
+            m[kn] = m.get(kn, 0) + 1
         if self.tm is not None:
             self.tm.__exit__(*exc)
         tr = _TRACER
         if tr is not None and self.ring:
-            tr.record(self.kind, self.t0, t1, self.attrs, None, clock.track)
+            tr.record(kind, self.t0, t1, self.attrs, None, clock.track)
+        if own > PAUSE_S and kind != "step" and kind != "idle":
+            clock.pause(kind, self.attrs, self.t0, t1, own, self.cpu0)
         return False
 
 
 class PhaseClock:
     """An engine's phase timer: ``with clock("device_wait", what=...)``.
-    One per engine, used from the one thread at a time that holds the
-    scheduler (steps and between-step scheduler calls are serialized), so
-    the stack of open phases needs no lock."""
+    One per engine.  The stack of open phases needs no lock: a step and
+    the scheduler calls between steps run on one pool thread at a time
+    (the step lock serializes them), and ``idle`` and the event-loop
+    thread's line of ``hop`` are opened by the scheduler loop itself
+    while no step is in flight, when it is the only opener.
 
-    __slots__ = ("metrics", "track", "open", "keys", "trace_me")
+    ``step``, ``hop`` and ``idle`` tile the loop's wall time (the module
+    docstring's third design point).  The ``hop`` COUNTER runs on the
+    step's own thread from the clock read that closed one ``step`` to
+    the one that opens the next (`step_opens`); ``with clock("hop")`` on
+    the event-loop thread adds nothing to it and is the ``dyn.hop``
+    TraceMe / ring span of the part that thread sees (a TraceMe cannot
+    cross threads): the one place where counter and event differ.
 
-    def __init__(self, metrics: dict, track: Optional[str] = None):
+    `behind()` -> (programs in flight, those whose result is ready) and
+    `compiles` (the compile watch's event ring) are the engine's; they
+    are asked only when a phase ran longer than ``PAUSE_S`` (`pause`)."""
+
+    __slots__ = ("metrics", "track", "open", "keys", "trace_me", "left_t",
+                 "behind", "compiles", "pauses")
+
+    def __init__(self, metrics: dict, track: Optional[str] = None,
+                 behind: Optional[Callable[[], Tuple[int, int]]] = None,
+                 compiles: Optional[Iterable[dict]] = None):
         self.metrics = metrics
         self.track = track
         self.open: List[_Phase] = []
@@ -543,13 +666,59 @@ class PhaseClock:
         from jax.profiler import TraceAnnotation
 
         self.trace_me = TraceAnnotation
-        for kind in ("step",) + STEP_PHASES:
-            ks, kn = self.keys[kind]
-            metrics.setdefault(ks, 0.0)
-            metrics.setdefault(kn, 0)
+        self.left_t = 0.0     # when the last step closed; 0.0 after idle
+        self.behind = behind
+        self.compiles = compiles if compiles is not None else ()
+        self.pauses: "deque[dict]" = deque(maxlen=64)
+        for kind in ("step",) + STEP_PHASES + LOOP_PHASES + ("pause",):
+            metrics.setdefault(f"host_s.{kind}", 0.0)
+            metrics.setdefault(f"host_n.{kind}", 0)
 
     def __call__(self, kind: str, **attrs) -> _Phase:
         return _Phase(self, kind, attrs or None)
+
+    def step_opens(self, t0: float) -> None:
+        """A `step` opens at `t0`: close the hop that led to it (none
+        after an idle wait or before the first step)."""
+        left = self.left_t
+        if left:
+            hop = t0 - left
+            m = self.metrics
+            m["host_s.hop"] += hop
+            m["host_n.hop"] += 1
+            if hop > PAUSE_S:
+                self.pause("hop", None, left, t0, hop)
+
+    def pause(self, kind: str, attrs: Optional[dict], t0: float, t1: float,
+              seconds: float,
+              cpu0: Optional[Tuple[float, float]] = None) -> None:
+        """A phase (or a hop) of more than ``PAUSE_S``: count it, keep a
+        record with the closed key set ``PAUSE_KEYS``, say so once in the
+        log and dump the ring.  Not where a compile event lies inside
+        its span: that wait has a name already.  Nor where the phase
+        says that it stood behind several `programs` by construction (a
+        long prompt's first token behind all of its chunks) and took
+        less than ``PAUSE_S`` for each.  `cpu0`: the thread's and the
+        process's CPU clocks at the phase's opening, where it read them
+        (``CPU_TIMED_WAITS``)."""
+        attrs = attrs or {}
+        if seconds <= PAUSE_S * attrs.get("programs", 1) or any(
+                t0 <= e["t"] <= t1 for e in list(self.compiles)):
+            return
+        m = self.metrics
+        m["host_s.pause"] += seconds
+        m["host_n.pause"] += 1
+        inflight, ready = self.behind() if self.behind is not None else (0, 0)
+        thread_s = process_s = None
+        if cpu0 is not None:     # closed on the thread that opened it
+            thread_s = round(time.thread_time() - cpu0[0], 6)
+            process_s = round(time.process_time() - cpu0[1], 6)
+        rec = dict(zip(PAUSE_KEYS, (
+            t1, kind, attrs.get("what", ""), round(seconds, 6),
+            attrs.get("k", 0), inflight, ready, thread_s, process_s)))
+        self.pauses.append(rec)
+        logger.warning("pause %s", json.dumps(rec))
+        flight_dump("pause")
 
 
 def flight_dump(reason: str) -> Optional[str]:
@@ -623,8 +792,12 @@ def install_from_env() -> Optional[Tracer]:
 
 
 __all__ = [
+    "CPU_TIMED_WAITS",
     "DEFAULT_RING",
     "HOP_KINDS",
+    "LOOP_PHASES",
+    "PAUSE_KEYS",
+    "PAUSE_S",
     "PhaseClock",
     "REQUEST_STAGES",
     "SPAN_KINDS",

@@ -49,6 +49,7 @@ from collections import defaultdict
 from typing import Any, Dict, Iterable, List, Tuple
 
 from ..runtime.metrics import percentile
+from . import LOOP_PHASES
 
 ENGINE_TRACK_PREFIX = "sched:"
 
@@ -143,6 +144,10 @@ def report(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         t1 = max(e["ts"] + e["dur"] for e in evs)
         wall_us += t1 - t0
         for kind, us in _self_times(evs).items():
+            if kind in LOOP_PHASES:
+                # the loop's own spans between steps: outside any step,
+                # which is what `idle` means here
+                continue
             key = "step_other" if kind == "step" else kind
             phase_us[key] += us
     idle_us = max(0.0, wall_us - sum(phase_us.values()))

@@ -6,6 +6,8 @@ numbers of one run."""
 
 import asyncio
 import glob
+import json
+import logging
 import threading
 import types
 from functools import partial
@@ -87,6 +89,12 @@ async def settled(eng):
     raise AssertionError("the scheduler loop never went idle")
 
 
+async def stands_empty(eng):
+    """Wait until the scheduler loop waits under its `idle` phase."""
+    await until(lambda: any(ph.kind == "idle" for ph in eng._phase.open),
+                "the scheduler loop never stood empty")
+
+
 def profiler_options():
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -119,10 +127,18 @@ def dyn_events(trace_dir):
 # ------------------------- the phase clock alone ---------------------------
 
 
-def fake_clock(monkeypatch, ticks):
+def fake_clock(monkeypatch, ticks, thread_cpu=(0.0,), process_cpu=(0.0,)):
+    """`ticks` are the reads of the wall clock; the CPU clocks read their
+    values in turn and then keep the last."""
+
+    def reads(values):
+        it = iter(values)
+        return lambda: next(it, values[-1])
+
     it = iter(ticks)
-    monkeypatch.setattr(obs, "time",
-                        types.SimpleNamespace(monotonic=lambda: next(it)))
+    monkeypatch.setattr(obs, "time", types.SimpleNamespace(
+        monotonic=lambda: next(it), thread_time=reads(thread_cpu),
+        process_time=reads(process_cpu)))
 
 
 def test_phase_clock_counts_self_time_and_whole_steps(monkeypatch):
@@ -178,19 +194,23 @@ def test_phase_clock_closes_on_exception():
 # ------------------------- the engine's phases -----------------------------
 
 
-def test_engine_phases_on_the_profilers_clock(tmp_path):
-    """Under a jax.profiler session the host plane holds `dyn.step` with
-    the phases nested inside it, carrying their attributes; no Tracer and
-    no environment variable is involved."""
+@pytest.fixture(scope="module")
+def profiler_capture(tmp_path_factory):
+    """The `dyn.*` events of one jax.profiler session over a mix, an
+    empty engine and one more request; no Tracer and no environment
+    variable is involved."""
+    trace_dir = tmp_path_factory.mktemp("capture")
 
     async def main():
         eng = make_engine()
         await serve(eng, 0)                      # compile outside the trace
         await settled(eng)       # a step open now would orphan its phases
-        jax.profiler.start_trace(str(tmp_path),
+        jax.profiler.start_trace(str(trace_dir),
                                  profiler_options=profiler_options())
         try:
             await serve_mix(eng)
+            await stands_empty(eng)      # an `idle` that opens in the session
+            await serve(eng, 6)          # and is closed in it
             await settled(eng)
         finally:
             jax.profiler.stop_trace()
@@ -198,12 +218,24 @@ def test_engine_phases_on_the_profilers_clock(tmp_path):
 
     assert obs.tracer() is None
     asyncio.run(main())
-    lines = dyn_events(str(tmp_path))
+    lines = dyn_events(str(trace_dir))
     assert lines, "no dyn.* event on /host:CPU"
+    return lines
+
+
+def with_steps(lines, has):
+    return [evs for evs in lines
+            if any(n == "dyn.step" for n, _, _, _ in evs) == has]
+
+
+def test_engine_phases_on_the_profilers_clock(profiler_capture):
+    """Under a jax.profiler session the host plane holds `dyn.step` with
+    the phases nested inside it, carrying their attributes."""
+    lines = with_steps(profiler_capture, True)
+    assert lines
     kinds = set()
     for evs in lines:
         steps = [(a, b) for n, a, b, _ in evs if n == "dyn.step"]
-        assert steps
         first, last = min(a for a, _ in steps), max(b for _, b in steps)
         for name, a, b, stats in evs:
             kinds.add(name)
@@ -222,13 +254,33 @@ def test_engine_phases_on_the_profilers_clock(tmp_path):
     assert {"dyn.step", "dyn.prefill_dispatch", "dyn.decode_dispatch",
             "dyn.device_wait", "dyn.emit"} <= kinds
     # a request's first chunk says what stood ahead of it on the device
-    # (r2's later chunks do not): five requests, at most five programs
+    # (r2's later chunks do not): six requests, at most six programs
     firsts = [stats for evs in lines for name, _, _, stats in evs
               if name == "dyn.prefill_dispatch" and "ahead_steps" in stats]
-    assert 1 <= len(firsts) <= 5
+    assert 1 <= len(firsts) <= 6
     assert all(int(st["ahead_steps"]) >= int(st["ahead_bursts"]) >= 0
                for st in firsts)
     assert kinds & {"dyn.sched", "dyn.enqueue_ahead"}
+
+
+def test_idle_and_hop_on_the_profilers_clock(profiler_capture):
+    """The loop's own two kinds are TraceMes of the event-loop thread:
+    `dyn.idle` over an empty-engine wait, `dyn.hop` from a step's return
+    to the next step's call; no step of any thread overlaps either and
+    they do not overlap each other."""
+    loop_lines = with_steps(profiler_capture, False)
+    assert len(loop_lines) == 1                  # one event-loop thread
+    mine = sorted((a, b, n) for n, a, b, _ in loop_lines[0])
+    assert {n for _, _, n in mine} == {"dyn.idle", "dyn.hop"}
+    steps = [(a, b) for evs in with_steps(profiler_capture, True)
+             for n, a, b, _ in evs if n == "dyn.step"]
+    for a, b, name in mine:
+        assert all(s1 <= a or b <= s0 for s0, s1 in steps), name
+    for (_, b0, _), (a1, _, _) in zip(mine, mine[1:]):
+        assert b0 <= a1
+    # more steps follow one another than follow an idle wait
+    n_idle = sum(1 for _, _, n in mine if n == "dyn.idle")
+    assert 1 <= n_idle < len(mine) - n_idle <= len(steps)
 
 
 def test_no_session_no_tracer_records_nothing(monkeypatch):
@@ -277,13 +329,308 @@ def test_phase_seconds_partition_the_steps():
     m = asyncio.run(main())
     assert m["host_n.step"] == m["steps"]
     whole = m["host_s.step"]
-    parts = sum(v for k, v in m.items()
-                if k.startswith("host_s.") and k != "host_s.step")
+    # the loop's kinds and the pauses' sum are not parts of a step
+    parts = sum(v for k, v in m.items() if k.startswith("host_s.")
+                and k[7:] not in ("step", "idle", "hop", "pause"))
     assert whole > 0
     assert 0.98 * whole <= parts <= whole * (1 + 1e-9)
     for kind in ("prefill_dispatch", "decode_dispatch", "device_wait",
                  "emit"):
         assert m[f"host_n.{kind}"] > 0 and m[f"host_s.{kind}"] > 0
+
+
+# ------------------------- the loop's own kinds ------------------------------
+
+
+def loop_seconds(m):
+    return m["host_s.step"] + m["host_s.hop"] + m["host_s.idle"]
+
+
+def test_step_hop_and_idle_tile_the_loops_wall_time():
+    """`step` + `hop` + `idle` is the scheduler loop's wall time: from the
+    opening of the idle wait that the first enqueue ends to the opening
+    of the one that follows the last finish frame, over three rounds of
+    requests with an empty engine between them, the three counters grow
+    by that stretch within 2 % (or 2 ms) and never by more.  What is
+    missing is the loop's way into and out of an idle wait, which no
+    phase owns."""
+
+    async def main():
+        eng = make_engine()
+        await serve(eng, 0)                      # the compiles
+        await stands_empty(eng)
+        t0, m0 = eng._phase.open[-1].t0, dict(eng.metrics)
+        for i in (1, 2, 3):
+            await asyncio.gather(serve(eng, i, max_tokens=64),
+                                 serve(eng, i + 3, n_prompt=9,
+                                       max_tokens=40))
+            await stands_empty(eng)
+        t1, m1 = eng._phase.open[-1].t0, dict(eng.metrics)
+        await eng.close()
+        return t1 - t0, m0, m1
+
+    wall, m0, m1 = asyncio.run(main())
+    assert m1["host_n.idle"] - m0["host_n.idle"] == 3
+    hops = m1["host_n.hop"] - m0["host_n.hop"]
+    steps = m1["host_n.step"] - m0["host_n.step"]
+    assert 0 < hops == steps - 3         # every step but a round's first
+    parts = loop_seconds(m1) - loop_seconds(m0)
+    assert parts <= wall * (1 + 1e-9)
+    assert wall - parts <= max(0.02 * wall, 0.002), (wall, parts)
+    assert m1["host_n.pause"] == 0 and not m1["host_s.pause"]
+
+
+def test_hop_runs_from_step_to_step_and_not_across_an_idle_wait(monkeypatch):
+    """The hop's counter is the step thread's: from the clock read that
+    closed one `step` to the one that opens the next; an idle wait in
+    between is `idle` and no hop, and `with clock("hop")`, the event-loop
+    thread's line of it, adds nothing to the counter."""
+    m = {}
+    clock = obs.PhaseClock(m, "sched:t")
+    assert m["host_s.idle"] == m["host_s.hop"] == m["host_s.pause"] == 0.0
+    assert m["host_n.idle"] == m["host_n.hop"] == m["host_n.pause"] == 0
+    # step 0..1, the loop's line of the hop 1.1..1.2, step 1.25..2,
+    # idle 2.5..7, step 7.5..8
+    fake_clock(monkeypatch, [0.0, 1.0, 1.1, 1.2, 1.25, 2.0, 2.5, 7.0,
+                             7.5, 8.0])
+    with clock("step"):
+        pass
+    with clock("hop"):
+        pass
+    with clock("step"):
+        pass
+    with clock("idle"):
+        pass
+    with clock("step"):
+        pass
+    assert m["host_s.step"] == 2.25 and m["host_n.step"] == 3
+    assert m["host_s.hop"] == 0.25 and m["host_n.hop"] == 1
+    assert m["host_s.idle"] == 4.5 and m["host_n.idle"] == 1
+    # an idle wait of any length is no pause
+    assert m["host_n.pause"] == 0 and not clock.pauses
+
+
+def test_idle_and_hop_record_nothing_without_a_listener(monkeypatch):
+    """Profiler off and no Tracer: an engine that empties twice opens no
+    TraceMe and records no span for `idle` or `hop` either (either would
+    raise here); their counters move."""
+
+    def refuse(*a, **kw):
+        raise AssertionError("a span was recorded with tracing off")
+
+    class NoTraceMe:
+        is_enabled = staticmethod(jax.profiler.TraceAnnotation.is_enabled)
+        __new__ = refuse
+
+    monkeypatch.setattr(obs.Tracer, "record", refuse)
+
+    async def main():
+        eng = make_engine()
+        eng._phase.trace_me = NoTraceMe
+        for i in (0, 1):
+            assert len(await serve(eng, i)) == 8
+            await stands_empty(eng)
+        assert len(await serve(eng, 2)) == 8
+        await settled(eng)
+        m = dict(eng.metrics)
+        await eng.close()
+        return m
+
+    assert obs.tracer() is None
+    m = asyncio.run(main())
+    assert m["host_n.idle"] >= 2 and m["host_s.idle"] > 0.0
+    assert m["host_n.hop"] > 0 and m["host_s.hop"] > 0.0
+    assert m["host_n.step"] == m["steps"]
+
+
+# ------------------------- a pause names itself ------------------------------
+
+
+def pause_lines(caplog):
+    return [json.loads(r.getMessage().split(" ", 1)[1])
+            for r in caplog.records
+            if r.name == "dynamo_tpu.obs" and r.getMessage().startswith(
+                "pause ")]
+
+
+@pytest.mark.parametrize("ready", [3, 0], ids=["chip_ran_on", "chip_stood"])
+def test_a_long_phase_names_itself(monkeypatch, caplog, ready):
+    """A `device_wait` of 2 s inside a step: one record with the closed
+    key set, `host_n.pause` counts it, `ready_behind` is what the engine's
+    callback says of the bursts in flight, the two CPU clocks are read
+    across the wait (a `burst_fetch` reads them as it opens), and the
+    log gets one line.  The step around it, as long, is no pause."""
+    m = {}
+    clock = obs.PhaseClock(m, "sched:t", behind=lambda: (3, ready))
+    # step 0..3 holding device_wait 0.5..2.5 and emit 2.5..2.75
+    fake_clock(monkeypatch, [0.0, 0.5, 2.5, 2.5, 2.75, 3.0],
+               thread_cpu=(10.0, 10.001), process_cpu=(50.0, 51.5))
+    with caplog.at_level(logging.WARNING, logger="dynamo_tpu.obs"):
+        with clock("step"):
+            with clock("device_wait", k=8, what="burst_fetch"):
+                pass
+            with clock("emit", k=8, what="burst"):
+                pass
+    rec, = clock.pauses
+    assert tuple(rec) == obs.PAUSE_KEYS
+    assert rec == {"t": 2.5, "kind": "device_wait", "what": "burst_fetch",
+                   "seconds": 2.0, "k": 8, "inflight": 3,
+                   "ready_behind": ready, "step_thread_cpu_s": 0.001,
+                   "step_process_cpu_s": 1.5}
+    assert m["host_n.pause"] == 1 and m["host_s.pause"] == 2.0
+    assert pause_lines(caplog) == [rec]
+
+
+def test_a_wait_behind_several_programs_is_held_to_the_limit_for_each(
+        monkeypatch):
+    """A first token waits behind every chunk of its prompt: 2 s behind
+    six programs is no pause, 3.5 s is."""
+    m = {}
+    clock = obs.PhaseClock(m, "sched:t")
+    fake_clock(monkeypatch, [0.0, 2.0, 10.0, 13.5])
+    with clock("device_wait", what="prefill_first", programs=6):
+        pass
+    assert not clock.pauses and m["host_n.pause"] == 0
+    with clock("device_wait", what="prefill_first", programs=6):
+        pass
+    rec, = clock.pauses
+    assert tuple(rec) == obs.PAUSE_KEYS and rec["seconds"] == 3.5
+
+
+def test_a_first_token_wait_says_how_many_programs_it_stands_behind(
+        tmp_path):
+    """The engine's `prefill_first` waits carry `programs`, the chunk
+    programs of the longest prompt they complete."""
+
+    async def main():
+        eng = make_engine(prefill_chunk_tokens=48)
+        seen = watch_stages(eng)
+        # a compile under a Tracer leaves a flight dump beside `out_path`
+        with obs.Tracer(out_path=str(tmp_path / "trace.json")) as tr:
+            await asyncio.gather(serve(eng, 1),
+                                 serve(eng, 2, n_prompt=100))
+            await settled(eng)
+        await eng.close()
+        waits = [s[4] for s in tr.spans if s[0] == "device_wait"
+                 and s[4]["what"] == "prefill_first"]
+        return waits, {slot.request.request_id: slot.prefill_chunks
+                       for slot, _ in seen}
+
+    waits, chunks = asyncio.run(main())
+    assert all(set(a) == {"what", "programs"} for a in waits)
+    # the two prompts share a 48-token program, so r1 completes before r2
+    assert chunks["r2"] > chunks["r1"] >= 1
+    assert [a["programs"] for a in waits] == [chunks["r1"], chunks["r2"]]
+
+
+def test_a_long_hop_names_itself(monkeypatch, caplog):
+    """A hop is held to the same limit where it is added; it has no
+    `what`, no `k` and no CPU clocks (nobody read them as it began)."""
+    m = {}
+    clock = obs.PhaseClock(m, "sched:t", behind=lambda: (2, 2))
+    fake_clock(monkeypatch, [0.0, 1.0, 2.75, 3.0])
+    with caplog.at_level(logging.WARNING, logger="dynamo_tpu.obs"):
+        with clock("step"):
+            pass
+        with clock("step"):
+            pass
+    rec, = clock.pauses
+    assert tuple(rec) == obs.PAUSE_KEYS
+    assert rec == {"t": 2.75, "kind": "hop", "what": "", "seconds": 1.75,
+                   "k": 0, "inflight": 2, "ready_behind": 2,
+                   "step_thread_cpu_s": None, "step_process_cpu_s": None}
+    assert m["host_s.hop"] == m["host_s.pause"] == 1.75
+    assert m["host_n.pause"] == 1 and pause_lines(caplog) == [rec]
+
+
+def test_a_compile_inside_the_phase_is_no_pause(monkeypatch, caplog):
+    """A phase that waited for a first compile is named by the compile
+    watch's event already: no record, no count, no line; the same phase
+    with the event outside its span is a pause."""
+    m = {}
+    compiles = [{"t": 1.5, "kind": "compile", "family": "prefill",
+                 "seconds": 1.4}]
+    clock = obs.PhaseClock(m, "sched:t", compiles=compiles)
+    fake_clock(monkeypatch, [0.0, 2.0, 3.0, 5.0])
+    with caplog.at_level(logging.WARNING, logger="dynamo_tpu.obs"):
+        with clock("prefill_dispatch", rows=1):
+            pass
+        assert not clock.pauses and m["host_n.pause"] == 0
+        assert not pause_lines(caplog)
+        with clock("prefill_dispatch", rows=1):
+            pass
+    rec, = clock.pauses
+    assert (rec["kind"], rec["seconds"], rec["inflight"]) == (
+        "prefill_dispatch", 2.0, 0)
+    assert rec["step_thread_cpu_s"] is None      # not a wait that reads it
+    assert m["host_n.pause"] == 1 and len(pause_lines(caplog)) == 1
+
+
+@pytest.mark.parametrize("ready", [True, False],
+                         ids=["chip_ran_on", "chip_stood"])
+def test_engine_pause_record_asks_the_bursts_in_flight(monkeypatch, ready):
+    """The engine's callback: `inflight` is the bursts it holds when the
+    phase closes, `ready_behind` those whose tokens are ready."""
+    monkeypatch.setattr(obs, "PAUSE_S", 0.0)
+    eng = make_engine()
+    assert eng.pauses is eng._phase.pauses and not eng.pauses
+    eng._inflight.extend({"burst": _Burst(ready), "k": 8, "lanes": {}}
+                         for _ in range(2))
+    with eng._phase("device_wait", k=4, what="burst_fetch"):
+        pass
+    rec, = eng.pauses
+    assert tuple(rec) == obs.PAUSE_KEYS
+    assert (rec["kind"], rec["what"], rec["k"]) == (
+        "device_wait", "burst_fetch", 4)
+    assert rec["inflight"] == 2
+    assert rec["ready_behind"] == (2 if ready else 0)
+    assert eng.metrics["host_n.pause"] == 1
+    eng._inflight.clear()
+
+
+def test_pause_records_of_a_served_mix_are_closed(monkeypatch, caplog):
+    """With the limit at 0 every phase and hop of a real run that holds
+    no compile is a pause: each record has the closed key set, the
+    counter counts them and the log has a line for each; the record of a
+    `burst_fetch` or `prefill_first` carries the CPU seconds across it,
+    every other carries none; the streams are what they are without
+    (`plain_outputs`' lengths)."""
+    monkeypatch.setattr(obs, "PAUSE_S", 0.0)
+
+    async def main():
+        eng = make_engine()
+        outs = await serve_mix(eng)
+        await settled(eng)
+        pauses, m = list(eng.pauses), dict(eng.metrics)
+        await eng.close()
+        return outs, pauses, m
+
+    with caplog.at_level(logging.WARNING, logger="dynamo_tpu.obs"):
+        outs, pauses, m = asyncio.run(main())
+    assert [len(o) for o in outs] == [8, 12, 8, 20, 8]
+    lines = pause_lines(caplog)
+    assert m["host_n.pause"] == len(lines) > 0
+    assert pauses == lines[-64:]                 # the deque's bound
+    kinds = set()
+    for rec in lines:
+        assert tuple(rec) == obs.PAUSE_KEYS
+        kinds.add((rec["kind"], rec["what"]))
+        assert rec["seconds"] >= 0.0
+        assert 0 <= rec["ready_behind"] <= rec["inflight"]
+        if rec["kind"] == "hop":
+            assert (rec["what"], rec["k"]) == ("", 0)
+        if rec["kind"] == "device_wait":
+            assert rec["what"] in obs.CPU_TIMED_WAITS
+            assert rec["step_thread_cpu_s"] >= 0.0
+            assert rec["step_process_cpu_s"] >= 0.0
+        else:
+            assert rec["step_thread_cpu_s"] is None
+            assert rec["step_process_cpu_s"] is None
+        if rec["what"] == "burst_fetch":
+            assert rec["k"] >= 1
+    assert {("hop", ""), ("device_wait", "burst_fetch"),
+            ("device_wait", "prefill_first"), ("emit", "burst")} <= kinds
+    assert not kinds & {("step", ""), ("idle", "")}
 
 
 # ------------------------- request stages ----------------------------------
