@@ -14,8 +14,18 @@ tables of 199, rings of 33 blocks a lane; one layer-call each):
                       scan at 512 tokens, where `auto` takes it)
              window   window_prefill_flash ([ring's tail || chunk], the
                       band): kernel (| scan at 512)
+             at 2048 tokens, 8 or 16 query heads a KV head, each beside
+             the forms of the kernel's head grouping (PR 54; the row
+             says which the kernel's own rule took, `heads_a_body`):
+             `one_body` (ONE kernel body over all the query heads of a
+             KV head), `body4` (4 heads a body, the runs of heads on
+             the grid) and `map4` (`lax.map` over calls of 4 heads a KV
+             head, q and the output transposed around it), with the
+             largest difference of their outputs from the kernel's
+             (0.0: a head's arithmetic is the same)
 
-    python3 benchmarks/bench_window_reads.py [--reps 10]
+    python3 benchmarks/bench_window_reads.py [--reps 10] [--prefill-only]
+        [--heads 128 --kv-heads 8]
 
 Prints one JSON line: milliseconds a call = the host's clock around
 block_until_ready of ONE program that makes `reps` dependent calls, over
@@ -28,6 +38,7 @@ pair) and the share of 197 TFLOP/s.  Fails without a TPU.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import statistics
@@ -36,20 +47,29 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-NH, NKV, HD, BS, LANES, NB, MB, WINDOW = 128, 8, 128, 128, 8, 1593, 199, 4096
-PAIR_FLOPS = NH * 4 * HD
+HD, BS, LANES, NB, MB, WINDOW = 128, 128, 8, 1593, 199, 4096
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--prefill-only", action="store_true")
+    ap.add_argument("--heads", type=int, default=128)
+    ap.add_argument("--kv-heads", type=int, default=8,
+                    help="another ratio of query heads a KV head at the "
+                    "same pools' sizes (SDAR: --heads 32 --kv-heads 4)")
     args = ap.parse_args()
+    NH, NKV = args.heads, args.kv_heads
+    PAIR_FLOPS = NH * 4 * HD
+
+    from unittest import mock
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from benchmark.lib.peaks import device_peaks
+    from dynamo_tpu.ops import pallas_packed_prefill as ppk
     from dynamo_tpu.ops.packed_prefill import packed_prefill_attention
     from dynamo_tpu.ops.paged_attention import paged_attention_decode
     from dynamo_tpu.ops.window_attention import (
@@ -74,7 +94,9 @@ def main() -> int:
                          .reshape(LANES, MB))
 
     def timed(read, q, *rest):
-        """ms a call of read(q, *rest) -> q-shaped."""
+        """ms a call of read(q, *rest) -> q-shaped.  The pools go in as
+        `rest`: closed over, they are constants of the executable (1.6
+        GB each program here, minutes a compile)."""
 
         @jax.jit
         def program(q, *rest):
@@ -96,24 +118,24 @@ def main() -> int:
     out = {"device": ident, "reps": args.reps, "decode": [], "prefill": []}
     qd = jax.random.normal(ks[4], (LANES, NH, HD), jnp.bfloat16)
     block_bytes = NKV * 2 * HD * BS * 2
-    for ctx in (8192, 16384, 25000):
+    for ctx in () if args.prefill_only else (8192, 16384, 25000):
         pos = jnp.full((LANES,), ctx, jnp.int32)
         row = {"ctx": ctx}
         live_g = LANES * -(-(ctx + 1) // BS)
         for impl in ("pallas", "jnp_bf16"):
             row[f"global.{impl}_ms"] = timed(
-                lambda q, impl=impl: paged_attention_decode(
-                    q, kg, vg, 0, tables, pos + 1, impl=impl), qd)
+                lambda q, kc, vc, impl=impl: paged_attention_decode(
+                    q, kc, vc, 0, tables, pos + 1, impl=impl), qd, kg, vg)
         w_table, w_lens, w_lo = ring_decode_table(pos, None, WINDOW, BS)
         live_w = LANES * (ctx // BS - (ctx - WINDOW + 1) // BS + 1)
         for impl in ("pallas", "jnp_bf16"):
             row[f"window.{impl}_ms"] = timed(
-                lambda q, impl=impl: paged_attention_decode(
-                    q, kw, vw, 0, w_table, w_lens, impl=impl, kv_lo=w_lo),
-                qd)
+                lambda q, kc, vc, impl=impl: paged_attention_decode(
+                    q, kc, vc, 0, w_table, w_lens, impl=impl, kv_lo=w_lo),
+                qd, kw, vw)
         row["window.mimo_gather_ms"] = timed(
-            lambda q: window_decode_attention(q, kw, vw, 0, pos, None,
-                                              WINDOW), qd)
+            lambda q, kc, vc: window_decode_attention(
+                q, kc, vc, 0, pos, None, WINDOW), qd, kw, vw)
         for kind, live in (("global", live_g), ("window", live_w)):
             row[f"{kind}.live_mb"] = round(live * block_bytes / 1e6, 1)
             row[f"{kind}.kernel_hbm_share"] = round(
@@ -123,6 +145,43 @@ def main() -> int:
         out["decode"].append(row)
         print(json.dumps(row), flush=True)
 
+    G = NH // NKV
+    Gk = ppk._group_heads(G)
+    kernel = ppk.packed_prefill_attention_pallas
+
+    def body_of(heads):
+        """A read whose kernel calls hold `heads` query heads of a KV
+        head a body (G: ONE body over the group), by the wrapper's own
+        static argument, which no model passes."""
+        def form(read):
+            def run(q, *rest):
+                with mock.patch.object(
+                        ppk, "packed_prefill_attention_pallas",
+                        lambda *a, **kw: kernel(*a, group_heads=heads,
+                                                **kw)):
+                    return read(q, *rest)
+            return run
+        return form
+
+    def map4(read):
+        """`read` a call of 4 heads a KV head, the calls one after
+        another (models/nemotron_h.py until PR 54): the heads lie KV
+        head major, [nkv, n, 4] -> n streams of [nkv, 4]."""
+        n = G // 4
+
+        def run(q, *rest):
+            T = q.shape[0]
+            qs = q.reshape(T, NKV, n, 4, HD).transpose(2, 0, 1, 3, 4)
+            out = jax.lax.map(lambda qi: read(qi, *rest),
+                              qs.reshape(n, T, NKV * 4, HD))
+            return out.reshape(n, T, NKV, 4, HD).transpose(
+                1, 2, 0, 3, 4).reshape(T, NH, HD)
+        return run
+
+    # the forms beside the rule's own (which a row's `pallas_ms` ran)
+    forms = [(name, form) for name, heads, form in (
+        ("one_body", G, body_of(G)), ("body4", 4, body_of(4)),
+        ("map4", 0, map4)) if heads != Gk and G in (8, 16)]
     lanes1 = jnp.asarray([3], jnp.int32)
     for T, ctx in ((2048, 0), (2048, 8192), (2048, 22528), (512, 22528)):
         q = jax.random.normal(ks[5], (T, NH, HD), jnp.bfloat16)
@@ -135,20 +194,34 @@ def main() -> int:
             width *= 2
         table = tables[3:4, :min(width, MB)]
         seen = np.arange(ctx, ctx + T) + 1
-        row = {"tokens": T, "ctx": ctx,
+        row = {"tokens": T, "ctx": ctx, "heads_a_kv_head": G,
+               "heads_a_body": Gk,
                "global.pairs_m": round(float(seen.sum()) / 1e6, 2),
                "window.pairs_m": round(float(np.minimum(
                    seen, WINDOW).sum()) / 1e6, 2)}
-        impls = ("pallas", "xla") if T <= 512 else ("pallas",)
-        for impl in impls:
-            row[f"global.{impl}_ms"] = timed(
-                lambda q, impl=impl: packed_prefill_attention(
-                    q, kg, vg, 0, table, seg, positions, valid, impl=impl),
-                q)
-            row[f"window.{impl}_ms"] = timed(
-                lambda q, impl=impl: window_prefill_flash(
-                    q, k, k, kw, vw, 0, lanes1, seg, positions, valid,
-                    WINDOW, impl=impl), q)
+        reads = {
+            "global": (lambda q, kc, vc, impl="pallas":
+                       packed_prefill_attention(
+                           q, kc, vc, 0, table, seg, positions, valid,
+                           impl=impl), (kg, vg)),
+            "window": (lambda q, kc, vc, impl="pallas":
+                       window_prefill_flash(
+                           q, k, k, kc, vc, 0, lanes1, seg, positions,
+                           valid, WINDOW, impl=impl), (kw, vw)),
+        }
+        for kind, (read, pools) in reads.items():
+            row[f"{kind}.pallas_ms"] = timed(read, q, *pools)
+            if T <= 512:
+                row[f"{kind}.xla_ms"] = timed(
+                    functools.partial(read, impl="xla"), q, *pools)
+                continue
+            for name, form in forms:
+                row[f"{kind}.{name}_ms"] = timed(form(read), q, *pools)
+                if ctx == 8192:   # whole and masked tiles, the band cut
+                    got, want = (jax.jit(f)(q, *pools).astype(jnp.float32)
+                                 for f in (form(read), read))
+                    row[f"{kind}.{name}_max_diff"] = float(
+                        jnp.abs(got - want).max())
         for kind in ("global", "window"):
             row[f"{kind}.kernel_mxu_share"] = round(
                 100 * row[f"{kind}.pairs_m"] * 1e6 * PAIR_FLOPS

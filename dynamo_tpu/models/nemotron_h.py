@@ -478,47 +478,6 @@ def _experts(layer, cfg: NemotronHConfig, h: jax.Array,
     return (out,) + moe_held_counts(cfg, top_e, valid)
 
 
-# query heads a KV head in ONE call of the packed prefill kernel.  Its body
-# is unrolled over the group's heads and holds their running max, sum and
-# accumulator in VMEM: at this family's 16 heads a KV head one call took
-# 0.50-0.60 ms a block at 1024 tokens and 1.05-1.15 at 2048 and made each
-# kernel-bearing program's executable 23 MB larger (3.9 s more to load at
-# a warm start, 16-20 s more to compile cold), where four calls of 4 heads
-# (Mistral's ratio, the one the kernel's tiles were chosen at) under
-# `lax.map`, one call site, take 0.28-0.39 and 0.64-0.75 ms and 2.5 MB,
-# bit for bit the same result (chip runs, PR 52; PERF.md section 6).  The
-# split belongs in the op, for every caller of this ratio (Command A+);
-# PR 52 could not edit ops/ (ROADMAP S9d).
-KERNEL_HEADS = 4
-
-
-def _attn_prefill(cfg: NemotronHConfig, q, k_cache, v_cache, pli, stream):
-    """The packed stream's queries [T, heads, hd] over K and V ALREADY
-    written to the pool (`stream`: tables, segment rows, positions,
-    valid), in the form the op's own rule gives this cache and stream.
-    The scan takes every head at once; the kernel takes `KERNEL_HEADS`
-    heads of each KV head a call, the calls one after another."""
-    T, nh, hd = q.shape
-    impl = resolve_packed_impl(cfg.packed_attn_impl, jax.default_backend(),
-                               k_cache.shape[4], k_cache.shape[3],
-                               k_cache.dtype, T)
-
-    def read(qi):
-        return packed_prefill_attention(qi, k_cache, v_cache, pli, *stream,
-                                        impl=impl)
-
-    nkv = cfg.n_kv_heads
-    n, odd = divmod(nh // nkv, KERNEL_HEADS)
-    if impl not in PALLAS_IMPLS or n < 2 or odd:
-        return read(q)
-    # heads lie KV head major: [nkv, n, KERNEL_HEADS] -> n streams of
-    # [nkv, KERNEL_HEADS]
-    qs = q.reshape(T, nkv, n, KERNEL_HEADS, hd).transpose(2, 0, 1, 3, 4)
-    out = jax.lax.map(read, qs.reshape(n, T, nkv * KERNEL_HEADS, hd))
-    return out.reshape(n, T, nkv, KERNEL_HEADS, hd).transpose(
-        1, 2, 0, 3, 4).reshape(T, nh, hd)
-
-
 # ---------------------------------------------------------------------------
 # prefill
 # ---------------------------------------------------------------------------
@@ -595,7 +554,9 @@ def prefill_batched(
                            h.astype(cfg.dtype).reshape(Bp * T, -1), None)
             k_cache, v_cache = write_packed_kv(
                 k_cache, v_cache, pli, k, v, *stream)
-            attn = _attn_prefill(cfg, q, k_cache, v_cache, pli, stream)
+            attn = packed_prefill_attention(
+                q, k_cache, v_cache, pli, *stream,
+                impl=cfg.packed_attn_impl)
             x = x + _attn_out(layer, attn.reshape(Bp, T, cfg.q_dim))
         else:
             out, n_on, _ = moe_rows(partial(_experts, layer, cfg), h, valid)

@@ -9,8 +9,9 @@ every step, and masks foreign tokens and the pairs above the causal
 diagonal after computing them.  This kernel computes the same pairs'
 attention and none of the rest:
 
-  * **Tile skip.**  The grid is (KV head, query tile, key tile); a key
-    tile is `chunk_cols` block columns of ONE segment row's table.  The
+  * **Tile skip.**  The grid is (KV head and run of its query heads,
+    query tile, key tile); a key tile is `chunk_cols` block columns of
+    ONE segment row's table.  The
     wrapper works out from `seg_ids` / `positions` which pairs run: a
     segment row's key tiles up to the farthest position that one of the
     tile's queries of that row holds — none where the row owns no query
@@ -31,10 +32,15 @@ attention and none of the rest:
     the next pair's blocks under the current pair's matmuls; a skipped
     step names the blocks already held, so nothing moves for it.
 
-  * **The group shares the tile.**  A query tile is [TB, G * hd]: the G
+  * **The group shares the tile.**  A query tile is [TB, Gk * hd]: Gk
     query heads of one KV head side by side, sliced by lanes, so the
     stream's [T, nh, hd] queries and output are used where they lie (no
-    head-major copy) and each key tile is read once for the group.
+    head-major copy) and each key tile is read once for them.  Gk is
+    the whole group up to `GROUP_WHOLE` heads a KV head and
+    `GROUP_HEADS` of them above (`_group_heads`): the stream's heads lie
+    KV head major, so a run of Gk heads is the next column block of the
+    same stream and the grid's first axis walks (KV head, run of
+    heads); a key tile is then fetched once a run.
 
 Numerics: matmul operands in the cache's dtype (the queries', bf16 on
 the serving path; an int8 cache's blocks and their per-position fp32
@@ -75,6 +81,35 @@ def _next_pow2(n: int) -> int:
 # 2048 tokens over 16 / 32 / 50 blocks (PERF.md section 6, PR 34)
 TOKEN_BLOCK, CHUNK_COLS = 512, 8
 
+# query heads of one KV head that ONE kernel body holds, and the largest
+# group a body still takes whole.  The body is unrolled over its heads
+# and keeps their running max, sum and accumulator in VMEM ([heads, TB,
+# 128] x 2 + [heads, TB, hd] float32: 12 MB at 16 heads); the tiles above
+# were chosen at 4 heads a KV head (Mistral).  One layer-call of 2048
+# tokens on a v5e, ms at context 0 / 8192 / 22528, ONE body | 4 heads a
+# body, bit for bit the same output (PERF.md section 6, PR 54):
+#   16 a KV head (128 over 8), inside Command A+'s prefill program under
+#   the profiler                read 2.46 / 11.19 / 25.25 | 1.98 / 11.04 / 24.76
+#                               band 2.60 /  6.85 /  6.85 | 1.90 /  5.17 /  5.17
+#   the op alone (benchmarks/bench_window_reads.py; it reads the 16-head
+#   body 1.5-1.9 x slower than the program runs it, cause unknown)
+#                               read 4.38 / 21.33 / 38.93 | 3.38 / 12.43 / 26.23
+#                               band 7.63 / 10.46 / 10.47 | 4.10 /  7.41 /  7.40
+#    8 a KV head (32 over 4), the op alone
+#                               read 0.60 /  2.72 /  6.10 | 0.63 /  2.95 /  6.37
+#                               band 0.70 /  1.53 /  1.53 | 0.82 /  1.62 /  1.63
+# (`lax.map` over four calls of 4, the op alone: 3.60 / 12.59 / 26.25 and
+# 4.34 / 7.60 / 7.60 beside two copies of q and the output.)  What the
+# 16-head body costs every start: 41.7 MB of code in that program
+# against 20.9, 30-38 s against 10-18 to compile.
+GROUP_HEADS, GROUP_WHOLE = 4, 8
+
+
+def _group_heads(G: int) -> int:
+    """Query heads a kernel body takes of a KV head's G: read from the
+    shape and from nothing else."""
+    return GROUP_HEADS if G > GROUP_WHOLE and G % GROUP_HEADS == 0 else G
+
 
 def _packed_kernel(
     # scalar prefetch
@@ -85,8 +120,8 @@ def _packed_kernel(
     # inputs
     seg_ref,       # [TB, 1] int32 segment row per query (-1 = padded)
     pos_ref,       # [TB, 1] int32 absolute position per query
-    q_ref,         # [TB, G * hd] this tile's queries of one KV head's
-                   #   group, pre-scaled, a head every hd lanes
+    q_ref,         # [TB, G * hd] this tile's queries of G heads of one
+                   #   KV head, pre-scaled, a head every hd lanes
     *rest,         # (`banded`: lo_ref [TB, 1] int32, the first position
                    #   a query keeps,) cc K blocks [hd, bs], cc V blocks
                    #   (+ cc + cc scale rows [1, bs] when quantized),
@@ -177,7 +212,8 @@ def _packed_kernel(
 @functools.partial(
     # dynlint: disable=DYN001 kernel-level jit: engine dispatch reaches this inside already-watched programs (prefill_packed/spec_verify); direct calls are bench/test-only
     jax.jit,
-    static_argnames=("chunk_cols", "token_block", "interpret"),
+    static_argnames=("chunk_cols", "token_block", "group_heads",
+                     "interpret"),
 )
 def packed_prefill_attention_pallas(
     q: jax.Array,             # [T, nh, hd] packed-stream queries (rope'd)
@@ -192,6 +228,10 @@ def packed_prefill_attention_pallas(
     *,
     chunk_cols: int = 0,      # block columns per key tile (0 = CHUNK_COLS)
     token_block: int = 0,     # query tokens per tile (0 = TOKEN_BLOCK)
+    group_heads: int = 0,     # query heads of a KV head a kernel body
+                              #   (0 = `_group_heads`; tests and benches
+                              #   name the whole group for the one-body
+                              #   form)
     interpret: bool = False,
     k_scale: jax.Array = None,  # [L, nkv, num_blocks, bs] fp32 (int8)
     v_scale: jax.Array = None,
@@ -211,6 +251,11 @@ def packed_prefill_attention_pallas(
     T, nh, hd = q.shape
     _, nkv, _, _, bs = k_cache.shape
     G = nh // nkv
+    Gk = group_heads or _group_heads(G)
+    n_g, odd = divmod(G, Gk)  # runs of Gk heads a KV head
+    if odd:
+        raise ValueError(f"group_heads {Gk} does not divide the {G} query "
+                         "heads of a KV head")
     S, mb = block_tables.shape
     quantized = k_scale is not None
 
@@ -278,10 +323,13 @@ def packed_prefill_attention_pallas(
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype) \
         .reshape(Tp, nh * hd)
 
+    # the grid's first axis is (KV head, run of Gk heads), KV head major
+    # like the stream's heads: step h reads column block h of q and the
+    # pool's head h // n_g
     def block_of(b):
         def index(h, i, j, layer_ref, tables_ref, fetch_ref, flag_ref):
             t = fetch_ref[i * n_kt + j]
-            return (layer_ref[0], h,
+            return (layer_ref[0], h if n_g == 1 else h // n_g,
                     tables_ref[(t // n_c) * wp + (t % n_c) * cc + b], 0, 0)
         return index
 
@@ -299,7 +347,7 @@ def packed_prefill_attention_pallas(
     inputs = [seg_eff[:, None], positions[:, None], qs] + bound \
         + [k_cache] * cc + [v_cache] * cc
     in_specs = [pl.BlockSpec((TB, 1), row), pl.BlockSpec((TB, 1), row),
-                pl.BlockSpec((TB, G * hd), heads)] \
+                pl.BlockSpec((TB, Gk * hd), heads)] \
         + [pl.BlockSpec((TB, 1), row)] * len(bound) + plane + plane
     if quantized:
         # scale rows as [.., 1, bs] planes, so a block is a whole tile
@@ -310,16 +358,16 @@ def packed_prefill_attention_pallas(
 
     pairs = Tp * n_kt * tk
     out = pl.pallas_call(
-        functools.partial(_packed_kernel, G=G, cc=cc, n_c=n_c,
+        functools.partial(_packed_kernel, G=Gk, cc=cc, n_c=n_c,
                           quantized=quantized, banded=banded),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(nkv, n_q, n_kt),
+            grid=(nkv * n_g, n_q, n_kt),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((TB, G * hd), heads),
-            scratch_shapes=[pltpu.VMEM((G, TB, 128), jnp.float32),
-                            pltpu.VMEM((G, TB, 128), jnp.float32),
-                            pltpu.VMEM((G, TB, hd), jnp.float32)],
+            out_specs=pl.BlockSpec((TB, Gk * hd), heads),
+            scratch_shapes=[pltpu.VMEM((Gk, TB, 128), jnp.float32),
+                            pltpu.VMEM((Gk, TB, 128), jnp.float32),
+                            pltpu.VMEM((Gk, TB, hd), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((Tp, nh * hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -330,7 +378,7 @@ def packed_prefill_attention_pallas(
         # (the tiles above a query tile's frontier are not computed)
         cost_estimate=pl.CostEstimate(
             flops=4 * pairs * nh * hd,
-            bytes_accessed=2 * n_q * nkv * n_kt * tk * hd
+            bytes_accessed=2 * n_q * nkv * n_g * n_kt * tk * hd
             * jnp.dtype(k_cache.dtype).itemsize,
             transcendentals=pairs * nh,
         ),

@@ -166,11 +166,12 @@ def test_paged_path_matches_reference_logits(model, impl):
 
 
 def test_kernel_takes_the_heads_of_a_group_four_at_a_time():
-    """16 query heads over 2 KV heads: under the kernel the attention
-    blocks' read is `nh.KERNEL_HEADS` = 4 heads of each KV head a call,
-    two calls one after another (`_attn_prefill`); the logits of a prompt
-    in two programs (the second reads the first's keys from the pool)
-    are the scan's, which takes all 16 at once, and the reference's."""
+    """16 query heads over 2 KV heads: the kernel holds 4 heads of each
+    KV head a body (the op's own rule, `pallas_packed_prefill.
+    _group_heads`; tests/test_packed_pallas.py reads the lowered call);
+    the logits of a prompt in two programs (the second reads the first's
+    keys from the pool) are the scan's, which takes all 16 at once, and
+    the reference's."""
     cfg = dataclasses.replace(TINY, n_heads=16, pattern="M*E*")
     params = nh.init_params(cfg, jax.random.PRNGKey(1))
     toks = np.random.default_rng(2).integers(3, cfg.vocab_size, 50)
@@ -178,17 +179,6 @@ def test_kernel_takes_the_heads_of_a_group_four_at_a_time():
     for impl in PACKED_IMPLS:
         got, _, _ = paged_logits(params, packed(impl, cfg), toks, 50)
         np.testing.assert_allclose(got[0], want, rtol=0, atol=TOL)
-    # and the split is really taken: one kernel call in a loop a block
-    S = jax.ShapeDtypeStruct
-    kv = tuple(S(s, d) for s, d in zip(
-        nh.kv_cache_shapes(cfg, 40, BS, lanes=LANES),
-        nh.kv_cache_dtypes(cfg)))
-    i32 = jnp.int32
-    text = PREFILL.lower(
-        jax.eval_shape(lambda: params), packed("pallas_interpret", cfg), kv,
-        S((32,), i32), S((32,), i32), S((TABLE,), i32), S((), i32),
-        S((), i32), lanes=S((), i32)).as_text()
-    assert f"tensor<2x32x{2 * nh.KERNEL_HEADS}x16xf32>" in text
 
 
 @pytest.mark.parametrize("impl", PACKED_IMPLS)

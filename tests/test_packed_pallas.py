@@ -345,6 +345,67 @@ def test_packed_kernel_runs_only_the_pairs_it_needs(monkeypatch):
     ]
 
 
+def _kernel_call(jaxpr):
+    """The `pallas_call` equation of a traced kernel wrapper."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found = _kernel_call(sub)
+            if found is not None:
+                return found
+    return None
+
+
+# query heads a KV head -> heads a kernel body holds (`_group_heads`)
+_GROUP_OF = {4: 4, 8: 8, 16: 4}
+
+
+@pytest.mark.parametrize("form", ["plain", "lower", "int8"])
+@pytest.mark.parametrize("G", sorted(_GROUP_OF))
+def test_packed_kernel_takes_a_group_four_heads_at_a_time(G, form):
+    """A body holds `GROUP_HEADS` query heads of a KV head where the
+    group is larger than `GROUP_WHOLE` (the grid's first axis then walks
+    the runs of heads, each the next column block of the stream): the same values bit for
+    bit as ONE body over the whole group (`group_heads=G`), the scan's
+    to bf16 tolerance, with a band (`lower`) and an int8 cache alike;
+    the traced call shows the width, and to 8 heads a KV head it is the
+    call it was."""
+    rng = np.random.default_rng(20 + G)
+    case = _packed_case(rng, [11, 9, 6], ctx0=[3, 0, 5], group=G, bucket=32,
+                        int8=form == "int8",
+                        dtype=jnp.float32 if form == "int8" else jnp.bfloat16)
+    q, kc, vc, ks, vs, tables, seg_ids, positions, valid = case
+    lower = jnp.maximum(positions - 6, 0) if form == "lower" else None
+    args = (q, kc, vc, 1, tables, seg_ids, positions, valid)
+    tiles = dict(token_block=8, chunk_cols=2, k_scale=ks, v_scale=vs,
+                 lower=lower)
+    out, whole = (np.asarray(packed_prefill_attention_pallas(
+        *args, interpret=True, group_heads=g, **tiles), np.float32)
+        for g in (0, G))
+    np.testing.assert_array_equal(out, whole)
+    ref = packed_prefill_attention(*args, impl="xla", k_scale=ks,
+                                   v_scale=vs, lower=lower)
+    np.testing.assert_allclose(out, np.asarray(ref, np.float32),
+                               rtol=0.05, atol=0.05)
+
+    def traced(g):
+        return jax.make_jaxpr(lambda *a: packed_prefill_attention_pallas(
+            *a, group_heads=g, **tiles))(*args)
+
+    Gk, hd, nkv = _GROUP_OF[G], q.shape[2], kc.shape[1]
+    call = _kernel_call(traced(0).jaxpr)
+    grid = call.params["grid_mapping"]
+    assert grid.grid[0] == nkv * (G // Gk)
+    q_block = grid.block_mappings[2].block_shape
+    assert [d.block_size for d in q_block] == [8, Gk * hd]
+    assert [v.aval.shape for v in call.outvars] == [(32, nkv * G * hd)]
+    if Gk == G:
+        assert str(traced(0)) == str(traced(G))
+    with pytest.raises(ValueError, match="does not divide"):
+        traced(3)
+
+
 async def test_the_counter_says_how_often_the_kernel_engaged():
     """`prefill_attn_kernel_tokens` rises by a packed program's tokens
     where the rule the traced code applies names the kernel for its

@@ -1187,6 +1187,13 @@ def test_window_ring_decode_and_prefill_compile_for_v5e(topo, one_chip,
     hlo = program.as_text()
     assert hlo.count("tpu_custom_call") == L + 3 * L
     pools_stay(hlo)
+    # a body's 4 heads of a KV head are a column block of q and of the
+    # output where they lie: nothing of q's size stands beside the call
+    # re-laid a run of heads at a time ([4, T, 8, 4, 128] and its like)
+    q_sized = {m for m in re.findall(r"bf16\[([\d,]+)\]", hlo)
+               if math.prod(int(d) for d in m.split(",")) == T * 128 * 128}
+    assert q_sized and not [m for m in q_sized if "4" in m.split(",")], \
+        q_sized
     assert f"bf16[16,{T},4096]" not in hlo
     # nothing the size of a score block ([2048, 128, 256] and up) but
     # the grouped dispatch's own combine of a token's 8 picks
