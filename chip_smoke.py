@@ -687,7 +687,7 @@ def check_kernels(args, pallas: str) -> dict:
          jnp.ones(T, bool))
     out["packed_auto_impl"] = {
         tag: resolve_packed_impl("auto", jax.default_backend(), bs, hd, dt,
-                                 T)
+                                 T, nh // nkv)
         for tag, dt in (("bf16", jnp.bfloat16), ("int8", jnp.int8))}
     compare("packed_auto", "the xla impl",
             f"T={T} one row at {start}.. width {mb} nkv={nkv} nh={nh}, "

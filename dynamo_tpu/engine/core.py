@@ -2757,12 +2757,13 @@ class JaxEngine:
         """Whether a packed prefill program of `bucket` tokens runs its
         attention in the Pallas kernel: the rule the traced code applies
         (ops/packed_prefill.resolve_packed_impl), asked from the host."""
-        impl = getattr(self.model_cfg, "packed_attn_impl", None)
+        m = self.model_cfg
+        impl = getattr(m, "packed_attn_impl", None)
         return impl is not None and resolve_packed_impl(
             impl, self.mesh.devices.flat[0].platform,
-            self.config.block_size, self.model_cfg.head_dim,
-            jnp.int8 if self.kv_dtype == "int8" else self.model_cfg.dtype,
-            bucket) in PALLAS_IMPLS
+            self.config.block_size, m.head_dim,
+            jnp.int8 if self.kv_dtype == "int8" else m.dtype, bucket,
+            m.n_heads // m.n_kv_heads) in PALLAS_IMPLS
 
     def _fpm_prefill(self, rows: int, tokens: int, bucket: int,
                      packed: bool = False, completing: int = 0) -> None:

@@ -26,9 +26,11 @@ shared ones averaged) are ONE parallel block under one LayerNorm
 pools' kernels) and the muP-scaled decoder whose sparse layers choose
 BLOCKS of keys from mean-pooled compressed keys, one set a KV group,
 beside lightning linear-attention layers with a constant decay a head
-(minicpm_sala.py) and the Qwen3-MoE-style decoder that generates by
+(minicpm_sala.py), the Qwen3-MoE-style decoder that generates by
 diffusion over blocks of a few positions under block-causal attention
-(sdar.py) serve through identical plumbing.
+(sdar.py) and the decoder of gated short-convolution layers beside a few
+GQA layers, whose lane state is a convolution's tail alone (lfm2.py)
+serve through identical plumbing.
 
 The cache is a tuple the family owns: the engine allocates one array a
 shape, hands the tuple to every program and takes it back.  A family
@@ -65,7 +67,8 @@ given (never through the family's type):
                              sequence, such as a window layer's ring.
                              kv_cache_shapes then takes `lanes=`, prefill
                              and prefill_batched (and prefill_packed,
-                             where the family has one: cohere2.py) take
+                             where the family has one: cohere2.py,
+                             lfm2.py) take
                              `lanes=` (the lane of each row); decode
                              rows ARE lanes.  Which
                              layers use which member is the family's own
@@ -79,7 +82,10 @@ given (never through the family's type):
                              a float32 matrix a head and the short
                              convolution's tail, a lane and layer;
                              minicpm_sala.py: member 3, the matrix
-                             alone;
+                             alone; lfm2.py: member 2, the short
+                             convolution's tail ALONE, two rows of the
+                             layer's width in the weights' dtype and
+                             no float32 state;
                              `kv_cache_dtypes` says which member is
                              which).  The family's programs then keep
                              its life (ops/lane_state.py, the one copy
@@ -153,6 +159,7 @@ from . import (
     cohere2,
     deepseek,
     keye,
+    lfm2,
     ling,
     llama,
     mimo,
@@ -163,6 +170,7 @@ from . import (
 from .cohere2 import Cohere2Config
 from .deepseek import DeepseekConfig
 from .keye import KeyeConfig
+from .lfm2 import Lfm2Config
 from .ling import LingConfig
 from .llama import LlamaConfig, init_params
 from .mimo import MimoConfig
@@ -172,7 +180,8 @@ from .sdar import SdarConfig
 
 PRESETS = {**llama.PRESETS, **deepseek.PRESETS, **mimo.PRESETS,
            **keye.PRESETS, **ling.PRESETS, **nemotron_h.PRESETS,
-           **cohere2.PRESETS, **minicpm_sala.PRESETS, **sdar.PRESETS}
+           **cohere2.PRESETS, **minicpm_sala.PRESETS, **sdar.PRESETS,
+           **lfm2.PRESETS}
 
 
 def get_family(cfg):
@@ -193,6 +202,8 @@ def get_family(cfg):
         return minicpm_sala
     if isinstance(cfg, SdarConfig):
         return sdar
+    if isinstance(cfg, Lfm2Config):
+        return lfm2
     if isinstance(cfg, LlamaConfig):
         return llama
     raise TypeError(f"unknown model config type: {type(cfg).__name__}")
@@ -202,6 +213,7 @@ __all__ = [
     "Cohere2Config",
     "DeepseekConfig",
     "KeyeConfig",
+    "Lfm2Config",
     "LingConfig",
     "LlamaConfig",
     "MimoConfig",

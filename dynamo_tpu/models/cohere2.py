@@ -228,7 +228,8 @@ def prefill_token_counts(cfg: Cohere2Config, pos: int, chunk: int,
     seen = pos + 1 + np.arange(chunk, dtype=np.int64)
     kernel = bucket > 0 and resolve_window_prefill_impl(
         cfg.packed_attn_impl, jax.default_backend(), cfg.sliding_window,
-        cfg.head_dim, cfg.dtype, bucket) in PALLAS_IMPLS
+        cfg.head_dim, cfg.dtype, bucket,
+        cfg.n_heads // cfg.n_kv_heads) in PALLAS_IMPLS
     return {
         "attn_pairs_window.prefill":
             int(np.minimum(seen, cfg.sliding_window).sum()),

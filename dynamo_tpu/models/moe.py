@@ -75,6 +75,13 @@ def ds_router(layer, cfg, x: jax.Array):
         choice = jnp.where(
             jnp.repeat(gmask, E // cfg.n_group, axis=1), choice, 0.0)
     _, top_e = jax.lax.top_k(choice, k)                      # [T, k]
+    if "moe_forced_picks" in layer:
+        # a witness's, never a served program's: the float32 reference's
+        # choice [T, k] in place of the program's own, the weights still
+        # from the program's scores (PERF.md section 7t: with random
+        # weights a pick that flips at bf16-level noise hides the
+        # arithmetic from a comparison of logits)
+        top_e = layer["moe_forced_picks"]
     top_w = jnp.take_along_axis(scores, top_e, axis=1)
     if cfg.norm_topk_prob:
         top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-20)

@@ -323,7 +323,7 @@ def prefill_token_counts(cfg: NemotronHConfig, pos: int, chunk: int,
     gqa = len(cfg.layers_of(ATTN)) * chunk
     kernel = resolve_packed_impl(
         cfg.packed_attn_impl, jax.default_backend(), 128, cfg.head_dim,
-        cfg.dtype, bucket) in PALLAS_IMPLS
+        cfg.dtype, bucket, cfg.n_heads // cfg.n_kv_heads) in PALLAS_IMPLS
     return {
         "ssm_tokens.prefill": chunk,
         "ssm_pad_tokens.prefill": max(bucket - chunk, 0),

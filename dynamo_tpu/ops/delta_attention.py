@@ -57,6 +57,21 @@ F32 = jnp.float32
 # ---------------------------------------------------------------------------
 
 
+def conv_taps(xx: jax.Array, w: jax.Array, T: int,
+              keep: jax.Array | None = None) -> jax.Array:
+    """The taps of a causal depthwise convolution, the one copy of that
+    arithmetic: xx [T + W - 1, C] the inputs with the W - 1 before them
+    in front, w [W, C] -> sum_j w[j] * xx[j : j + T], float32; `w[-1]`
+    meets the current input.  `keep` [W, T] bool, where given, says
+    which taps a token may read (ops/gated_conv.py: a packed stream's
+    taps stop at a row's first token); None reads them all."""
+    xf, wf = xx.astype(F32), w.astype(F32)
+    if keep is None:
+        return sum(wf[j] * xf[j:j + T] for j in range(w.shape[0]))
+    return sum(wf[j] * jnp.where(keep[j][:, None], xf[j:j + T], 0.0)
+               for j in range(w.shape[0]))
+
+
 def causal_conv(x: jax.Array, tail: jax.Array, w: jax.Array,
                 true_len: jax.Array, bias: jax.Array | None = None):
     """Causal depthwise convolution over time, then SiLU.
@@ -67,8 +82,7 @@ def causal_conv(x: jax.Array, tail: jax.Array, w: jax.Array,
     the tail)."""
     T, W = x.shape[0], w.shape[0]
     xx = jnp.concatenate([tail.astype(x.dtype), x], axis=0)   # [T+W-1, C]
-    xf, wf = xx.astype(F32), w.astype(F32)
-    c = sum(wf[j] * xf[j:j + T] for j in range(W))
+    c = conv_taps(xx, w, T)
     if bias is not None:
         c = c + bias.astype(F32)
     new_tail = jax.lax.dynamic_slice_in_dim(xx, true_len, W - 1, axis=0)
@@ -76,15 +90,16 @@ def causal_conv(x: jax.Array, tail: jax.Array, w: jax.Array,
 
 
 def causal_conv_step(x: jax.Array, tail: jax.Array, w: jax.Array,
-                     bias: jax.Array | None = None):
+                     bias: jax.Array | None = None, act=jax.nn.silu):
     """One token a lane: x [B, C], tail [B, W - 1, C] -> (c [B, C]
-    float32, new tail)."""
+    float32, new tail).  `act` None: the taps alone (ops/gated_conv.py,
+    whose operator has no activation)."""
     xx = jnp.concatenate([tail.astype(x.dtype), x[:, None]], axis=1)
     c = jnp.einsum("bwc,wc->bc", xx.astype(F32), w.astype(F32),
                    precision=HI)
     if bias is not None:
         c = c + bias.astype(F32)
-    return jax.nn.silu(c), xx[:, 1:].astype(tail.dtype)
+    return (c if act is None else act(c)), xx[:, 1:].astype(tail.dtype)
 
 
 # the same two under this family's scope (ops/ssm.py has them under its own)

@@ -87,26 +87,35 @@ KERNEL_MIN_TOKENS = 1024
 
 
 def resolve_packed_impl(impl: str, platform: str, block_size: int,
-                        head_dim: int, cache_dtype, tokens: int) -> str:
+                        head_dim: int, cache_dtype, tokens: int,
+                        group: int) -> str:
     """What `impl` means for this cache, on this platform, for a packed
-    stream of `tokens`: the one place "auto" is decided, from what the
-    code can observe (paged_attention.resolve_decode_impl's twin; the
-    engine asks it from the host for `prefill_attn_kernel_tokens`).  An
-    explicit impl is returned as given.
+    stream of `tokens` whose `group` query heads share a KV head: the
+    one place "auto" is decided, from what the code can observe
+    (paged_attention.resolve_decode_impl's twin; the engine asks it too,
+    to count the tokens whose program ran the kernel).  An explicit impl
+    is returned as given.
 
     "auto" is the kernel ("pallas") where it can run as written and
     repays its set-up: a TPU backend, block_size a multiple of 128 (the
-    lane dimension of the [hd, bs] planes it moves), head_dim a multiple
-    of 128 (a query tile holds a head every head_dim lanes), a bf16 or
-    int8 cache, and a stream of KERNEL_MIN_TOKENS tokens or more.
-    Everywhere else (CPU, block_size 16, fp32 caches, the short buckets,
-    speculative verification's rows of k + 1 tokens) it is the float32
-    scan ("xla").  Under tensor parallelism the kernel runs per shard
-    (`_packed_pallas_tp`): the rule is the same."""
+    lane dimension of the [hd, bs] planes it moves), a body's query tile
+    of whole 128-lane vregs (`pallas_packed_prefill.body_lanes`: any
+    group of 128-wide heads; 64-wide heads where a body holds an even
+    number of them, which it slices at 64-lane offsets: LFM2's 4 a KV
+    head, compiled for a described v5e and run on the chip, PERF.md
+    section 6, PR 55), a bf16 or int8 cache, and a stream of
+    KERNEL_MIN_TOKENS tokens or more.  Everywhere else (CPU, block_size
+    16, fp32 caches, the short buckets, speculative verification's rows
+    of k + 1 tokens) it is the float32 scan ("xla").  Under tensor
+    parallelism the kernel runs per shard (`_packed_pallas_tp`): the
+    rule is the same."""
     if impl != "auto":
         return impl
+    from .pallas_packed_prefill import body_lanes
+
     dt = jnp.dtype(cache_dtype)
-    if (platform == "tpu" and block_size % 128 == 0 and head_dim % 128 == 0
+    if (platform == "tpu" and block_size % 128 == 0
+            and body_lanes(group, head_dim) % 128 == 0
             and dt in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.int8))
             and tokens >= KERNEL_MIN_TOKENS):
         return "pallas"
@@ -399,7 +408,8 @@ def packed_prefill_attention(
         positions = upper
     impl = resolve_packed_impl(impl, jax.default_backend(),
                                k_cache.shape[4], k_cache.shape[3],
-                               k_cache.dtype, q.shape[0])
+                               k_cache.dtype, q.shape[0],
+                               q.shape[1] // k_cache.shape[1])
     if impl in ("pallas", "pallas_interpret"):
         interpret = impl == "pallas_interpret"
         # traced, like `_store_planes`' layer: the kernel (a jit of its

@@ -111,6 +111,14 @@ def _group_heads(G: int) -> int:
     return GROUP_HEADS if G > GROUP_WHOLE and G % GROUP_HEADS == 0 else G
 
 
+def body_lanes(G: int, head_dim: int) -> int:
+    """Lanes of one body's query tile for G query heads a KV head: its
+    heads side by side, a head every head_dim lanes.  The rule that
+    decides "auto" asks for whole 128-lane vregs
+    (packed_prefill.resolve_packed_impl)."""
+    return _group_heads(G) * head_dim
+
+
 def _packed_kernel(
     # scalar prefetch
     layer_ref,     # [1] int32 the pool's layer

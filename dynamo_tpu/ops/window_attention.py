@@ -301,12 +301,13 @@ def _band_block(window: int, tokens: int) -> int:
 
 
 def resolve_window_prefill_impl(impl: str, platform: str, window: int,
-                                head_dim: int, dtype, tokens: int) -> str:
+                                head_dim: int, dtype, tokens: int,
+                                group: int) -> str:
     """The impl of `window_prefill_flash` for a stream of `tokens`:
     `packed_prefill.resolve_packed_impl`'s rule over the band's own pool,
     asked by the traced read and by the host's count alike."""
     return resolve_packed_impl(impl, platform, _band_block(window, tokens),
-                               head_dim, dtype, tokens)
+                               head_dim, dtype, tokens, group)
 
 
 @jax.named_scope("dyn.attn_window")
@@ -358,7 +359,8 @@ def window_prefill_flash(q, k, v, k_ring, v_ring, layer: int, lanes,
     rel = positions - ctx[seg_ids] + window
     lower = jnp.maximum(rel - window + 1, window - ctx[seg_ids])
     impl = resolve_window_prefill_impl(impl, jax.default_backend(), window,
-                                       hd, k_ring.dtype, T)
+                                       hd, k_ring.dtype, T,
+                                       q.shape[1] // k.shape[1])
     return packed_prefill_attention(
         q, pool(k_ring, k), pool(v_ring, v), 0, tables, seg_ids, rel, valid,
         impl=impl, lower=lower)

@@ -602,7 +602,7 @@ def test_prefill_counts_follow_the_rule_the_read_applies():
             bucket)
         assert got["gqa_prefill_kernel_tokens.prefill"] == 4 * 200
     assert [resolve_packed_impl("auto", "tpu", 128, cut.head_dim,
-                                cut.dtype, t)
+                                cut.dtype, t, cut.n_heads // cut.n_kv_heads)
             for t in (KERNEL_MIN_TOKENS // 2, KERNEL_MIN_TOKENS)] \
         == ["xla", "pallas"]
 

@@ -223,28 +223,35 @@ def test_packed_pallas_tp_sharded_matches_xla():
 _BF16, _I8, _F32 = jnp.bfloat16, jnp.int8, jnp.float32
 
 
-@pytest.mark.parametrize("platform,bs,hd,dtype,tokens,want", [
+@pytest.mark.parametrize("platform,bs,hd,dtype,tokens,group,want", [
     # the buckets that carry the doc cell's tokens, and the chat
     # cell's long prompts: the kernel
-    ("tpu", 128, 128, _BF16, 2048, "pallas"),
-    ("tpu", 128, 128, _BF16, 1024, "pallas"),
-    ("tpu", 256, 128, _I8, 1024, "pallas"),
-    ("tpu", 128, 256, _I8, 4096, "pallas"),
+    ("tpu", 128, 128, _BF16, 2048, 4, "pallas"),
+    ("tpu", 128, 128, _BF16, 1024, 4, "pallas"),
+    ("tpu", 256, 128, _I8, 1024, 4, "pallas"),
+    ("tpu", 128, 256, _I8, 4096, 4, "pallas"),
     # under the measured length: the scan (the short buckets,
     # speculative verification's rows of k + 1 tokens)
-    ("tpu", 128, 128, _BF16, 512, "xla"),
-    ("tpu", 128, 128, _BF16, 128, "xla"),
-    ("tpu", 128, 128, _BF16, 32, "xla"),
-    ("tpu", 128, 128, _I8, 5, "xla"),
+    ("tpu", 128, 128, _BF16, 512, 4, "xla"),
+    ("tpu", 128, 128, _BF16, 128, 4, "xla"),
+    ("tpu", 128, 128, _BF16, 32, 4, "xla"),
+    ("tpu", 128, 128, _I8, 5, 4, "xla"),
     # where the kernel cannot run as written
-    ("cpu", 128, 128, _BF16, 2048, "xla"),
-    ("gpu", 128, 128, _BF16, 2048, "xla"),
-    ("tpu", 16, 128, _BF16, 2048, "xla"),
-    ("tpu", 128, 64, _BF16, 2048, "xla"),
-    ("tpu", 128, 128, _F32, 2048, "xla"),
-    ("tpu", 64, 128, _I8, 2048, "xla"),
+    ("cpu", 128, 128, _BF16, 2048, 4, "xla"),
+    ("gpu", 128, 128, _BF16, 2048, 4, "xla"),
+    ("tpu", 16, 128, _BF16, 2048, 4, "xla"),
+    # a body's query tile that is not whole vregs: 64-wide heads one a
+    # KV head (an even number of them is: LFM2's 4; 32 a KV head go 4 a
+    # body)
+    ("tpu", 128, 64, _BF16, 2048, 1, "xla"),
+    ("tpu", 128, 64, _BF16, 2048, 4, "pallas"),
+    ("tpu", 128, 64, _BF16, 2048, 32, "pallas"),
+    ("tpu", 128, 64, _BF16, 512, 4, "xla"),
+    ("tpu", 128, 128, _F32, 2048, 4, "xla"),
+    ("tpu", 64, 128, _I8, 2048, 4, "xla"),
 ])
-def test_resolve_packed_impl_auto(platform, bs, hd, dtype, tokens, want):
+def test_resolve_packed_impl_auto(platform, bs, hd, dtype, tokens, group,
+                                  want):
     """`auto` is decided in one place from platform, cache and the
     stream's length; an explicit impl is returned as given whatever the
     rest says."""
@@ -253,11 +260,11 @@ def test_resolve_packed_impl_auto(platform, bs, hd, dtype, tokens, want):
         resolve_packed_impl,
     )
 
-    assert resolve_packed_impl("auto", platform, bs, hd, dtype,
-                               tokens) == want
+    assert resolve_packed_impl("auto", platform, bs, hd, dtype, tokens,
+                               group) == want
     for impl in PACKED_IMPLS[1:]:
-        assert resolve_packed_impl(impl, platform, bs, hd, dtype,
-                                   tokens) == impl
+        assert resolve_packed_impl(impl, platform, bs, hd, dtype, tokens,
+                                   group) == impl
 
 
 def test_auto_off_the_chip_is_the_float32_scan():
@@ -433,7 +440,8 @@ async def test_the_counter_says_how_often_the_kernel_engaged():
             assert eng._prefill_attn_kernel(rec["bucket"]) == (
                 resolve_packed_impl(m.packed_attn_impl, "cpu",
                                     c.block_size, m.head_dim, m.dtype,
-                                    rec["bucket"]) != "xla")
+                                    rec["bucket"],
+                                    m.n_heads // m.n_kv_heads) != "xla")
         finally:
             await eng.close()
 

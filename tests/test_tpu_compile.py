@@ -295,7 +295,7 @@ def test_prefill_packed_attends_in_the_kernel(topo, one_chip, MB):
 
     L, NKV, NB, HD, SEGS, T = 4, 8, 320, 128, 1, 2048
     impl = resolve_packed_impl("auto", topo.devices[0].platform, BS, HD,
-                               jnp.bfloat16, T)
+                               jnp.bfloat16, T, 4)
     assert impl == "pallas"
     cfg = llama.LlamaConfig(
         name="mistral-7b-widths", vocab_size=32768, d_model=4096,
@@ -1071,7 +1071,7 @@ def test_ssm_decode_and_prefill_compile_for_v5e(topo, one_chip):
     _assert_experts_walk_the_visited_list(hlo, 16, B, 2688, 1856)
     assert program.memory_analysis().temp_size_in_bytes < 0.3e9
     packed = resolve_packed_impl("auto", topo.devices[0].platform, BS, 128,
-                                 jnp.bfloat16, T)
+                                 jnp.bfloat16, T, 16)
     assert packed == "pallas"
     pre = jax.jit(
         partial(JaxEngine._prefill_impl, nh,
@@ -1126,7 +1126,7 @@ def test_window_ring_decode_and_prefill_compile_for_v5e(topo, one_chip,
     assert impl == "pallas"
     assert resolve_window_prefill_impl(
         "auto", topo.devices[0].platform, 4096, 128, jnp.bfloat16,
-        T) == "pallas"
+        T, 16) == "pallas"
     cfg = dataclasses.replace(
         cohere2.PRESETS["command-a-plus"], n_layers=L,
         layer_kinds=(1, 1, 1, 0), experts_held=(0, 16), vocab_size=32768,
@@ -1410,3 +1410,90 @@ def test_diffusion_passes_and_prefill_compile_for_v5e(one_chip):
     assert hlo.count("tpu_custom_call") == 4 * L
     pools_stay(hlo)
     assert program.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
+def test_short_conv_decode_and_prefill_compile_for_v5e(topo, one_chip,
+                                                       capsys):
+    """The gated short-convolution family (models/lfm2.py) at LFM2-24B-
+    A2B's published widths and the long-context cell's WHOLE cut (9
+    layers: 7 conv, 2 attention of 32 heads over 8 KV heads of 64; one
+    dense feed-forward of 11776, eight layers of all 64 experts of 1536;
+    the whole vocabulary) and cache (8 lanes, 1593 blocks, tables of 199;
+    tails [7, 8, 2, 2048]), `auto` resolved as on the chip: both Pallas
+    reads at 64-wide heads.  A fused decode burst of the engine's own
+    program: one custom call an attention layer and one an expert layer
+    (the walk over the visited list), the pool never copied; and a
+    2048-token packed prefill chunk: one custom call an attention layer
+    (a body of 4 heads x 64 = 256 lanes) beside the experts' three
+    grouped matmuls a layer, no score block.  Arguments and temporaries
+    are printed: the cell holds 11.2 GB before temporaries."""
+    from dynamo_tpu.engine.core import JaxEngine
+    from dynamo_tpu.models import lfm2
+    from dynamo_tpu.ops.packed_prefill import resolve_packed_impl
+    from dynamo_tpu.ops.paged_attention import resolve_decode_impl
+
+    NB, B, MB, K, T = 1593, 8, 199, 8, 2048
+    platform = topo.devices[0].platform
+    big = lfm2.PRESETS["lfm2-24b-a2b"]
+    impl = resolve_decode_impl("auto", platform, BS, 64, jnp.bfloat16)
+    packed = resolve_packed_impl("auto", platform, BS, 64, jnp.bfloat16, T,
+                                 big.n_heads // big.n_kv_heads)
+    assert (impl, packed) == ("pallas", "pallas")
+    cfg = dataclasses.replace(
+        big, layer_kinds=big.layer_kinds[1:10], n_dense_layers=1,
+        attn_impl=impl, packed_attn_impl=packed)
+    S = _sds(one_chip)
+    shapes = jax.eval_shape(
+        lambda: lfm2.init_params(cfg, jax.random.PRNGKey(0)))
+    assert shapes["layers"][1]["moe_w_up"].shape == (64, 2048, 1536)
+    assert shapes["layers"][0]["w_up"].shape == (2048, 11776)
+    params = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), shapes)
+    kv = tuple(S(s, d) for s, d in zip(
+        lfm2.kv_cache_shapes(cfg, NB, BS, lanes=B),
+        lfm2.kv_cache_dtypes(cfg)))
+    assert kv[0].shape == (2, 8, NB, 64, BS)
+    assert kv[2].shape == (7, B, 2, 2048)
+    i32, f32, b1 = jnp.int32, jnp.float32, jnp.bool_
+
+    def report(what, program):
+        mem = program.memory_analysis()
+        with capsys.disabled():
+            print(f"\nlfm2 {what}: arguments "
+                  f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+                  f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+        return mem
+
+    fn = jax.jit(
+        partial(JaxEngine._decode_multi_impl, lfm2, cfg, None, True, K,
+                False),
+        donate_argnums=(1, 5, 7, 9))
+    lowered = fn.lower(
+        params, kv, S((B,), i32), S((B,), b1), S((B,), i32), S((B,), i32),
+        S((B, MB), i32), S((B,), i32), S((B,), i32), S((B,), i32),
+        S((B,), f32), S((B,), i32), S((B,), f32), S((B,), b1),
+        S((), i32))
+    assert lowered.out_info[0].shape == (K + len(lfm2.KV_COUNTERS), B)
+    program = lowered.compile()
+    hlo = program.as_text()
+    assert hlo.count("tpu_custom_call") == 2 + 8
+    _assert_pool_stays_where_it_lies(hlo, 2, 8, NB, 64)
+    _assert_experts_walk_the_visited_list(hlo, 64, B, 2048, 1536)
+    mem = report("decode burst", program)
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+
+    pre = jax.jit(
+        partial(JaxEngine._prefill_packed_impl, lfm2, cfg, None),
+        donate_argnums=(1,))
+    program = pre.lower(
+        params, kv, S((T,), i32), S((T,), i32), S((T,), i32),
+        S((1, MB), i32), S((1,), i32), S((T,), b1), S((1,), i32),
+        S((1,), f32), S((1,), i32), S((1,), f32), None, None,
+        S((1,), i32)).compile()
+    hlo = program.as_text()
+    assert hlo.count("tpu_custom_call") == 2 + 3 * 8
+    _assert_pool_stays_where_it_lies(hlo, 2, 8, NB, 64)
+    # no score block: nothing float32 of [T, 32 heads, a key tile] or more
+    assert f"f32[{T},32,{BS}" not in hlo and f"f32[32,{T},{BS}" not in hlo
+    mem = report("2048-token prefill", program)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
