@@ -71,6 +71,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.block_sparse_attention import (
     BlockSizes,
+    choice_impl,
     compress_chunk,
     compress_token,
     sparse_decode_attention,
@@ -276,18 +277,24 @@ def prefill_token_counts(cfg: SalaConfig, pos: int, chunk: int,
     """Host-side counts for `chunk` prompt tokens prefilled from
     position `pos`, ONE sparse layer: the (query, key) pairs the
     equations attend and the (query, compressed key) pairs they score,
-    the queries on either side of `dense_len`; and the tokens through
-    the lightning layers' chunked rule under the names models/ling.py
-    feeds (no kernel form: `ssd_chunked` is the one form)."""
+    the queries on either side of `dense_len`, with those past it whose
+    scores ops/pallas_block_choice.py's kernel made (`choice_impl` of
+    the program's `bucket` rows; 0 where the jnp form runs); and the
+    tokens through the lightning layers' chunked rule under the names
+    models/ling.py feeds (no kernel form: `ssd_chunked` is the one
+    form)."""
     nl = len(cfg.layers_of(LIGHTNING))
     t = pos + np.arange(chunk, dtype=np.int64)
     _, _, used, seen = _attended(cfg, t)
     n_dense = int((t + 1 <= cfg.dense_len).sum())
+    kernel = choice_impl(cfg.attn_impl, bucket) in PALLAS_IMPLS
     return {
         "sala_pairs_attended.prefill": int(used.sum()),
         "sala_pairs_scored.prefill": int(seen.sum()),
         "sala_dense_queries.prefill": n_dense,
         "sala_sparse_queries.prefill": chunk - n_dense,
+        "sala_choice_kernel_queries.prefill":
+            chunk - n_dense if kernel else 0,
         "recurrent_tokens.prefill": chunk,
         "recurrent_carried_tokens.prefill": chunk if pos > 0 else 0,
         "recurrent_resets": int(chunk > 0 and pos == 0),

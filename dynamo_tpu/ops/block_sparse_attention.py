@@ -46,7 +46,11 @@ tokens holds block_size / block blocks: one half chosen is read whole
 and masked (`sala_read_tokens` counts what is moved).
 
 Prefill (`sparse_prefill_attention`) is exact under the block mask: a
-row's compressed keys are scored `_SCORE_QUERIES` queries at a time, the
+row's compressed keys are scored (`prefill_block_choice`: on a TPU a
+prompt-sized row in ops/pallas_block_choice.py's kernel, which holds a
+query tile's scores in VMEM, stops at the tile's visible frontier and
+skips a tile with no query past `dense_len`; elsewhere, and for fewer
+rows, `_SCORE_QUERIES` queries at a time through `block_scores`), the
 token mask [nkv, T, S] is laid out once, and one flash pass runs under
 it: a Pallas kernel on a TPU whose (query tile, key tile) steps are
 skipped, compute and DMA, where no query of the tile chose a key of it
@@ -58,6 +62,7 @@ query a tile is rarely empty, which the count says.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -70,6 +75,9 @@ from .sparse_attention import _dot, _gqa_out, _gqa_scores, topk_mask
 _SCORE_QUERIES = 256
 # queries and keys of one tile of the Pallas flash pass under the mask
 _FLASH_TQ, _FLASH_TK = 256, 512
+# rows a grid step of the search over a prompt's (query, group) rows of
+# blocks: a block table's rows are short (782 blocks at 50 k tokens)
+_SEARCH_ROWS = 128
 
 
 class BlockSizes(NamedTuple):
@@ -223,6 +231,28 @@ def block_scores(q, ck_seq, t, sizes: BlockSizes):
     return P
 
 
+def _causal_blocks(t, valid, nkv: int, NB: int, block: int):
+    """-> [R, nkv, NB] bool: the blocks a valid row may attend,
+    j <= t // block."""
+    ok = (jnp.arange(NB)[None, :] <= (t // block)[:, None]) & valid[:, None]
+    return jnp.broadcast_to(ok[:, None, :], (t.shape[0], nkv, NB))
+
+
+def _forced_topk(P, t, valid, sizes: BlockSizes, rows: int = 0):
+    """P [R, nkv, NB] -> [R, nkv, NB] bool: `topk` of the blocks a
+    valid row may attend, the forced ones and then the best-scored.
+    `rows`: `topk_mask`'s."""
+    R, nkv, NB = P.shape
+    j = jnp.arange(NB)[None, :]
+    own = (t // sizes.block)[:, None]
+    forced = (j < sizes.init_blocks) \
+        | (j > own - sizes.window // sizes.block)
+    score = jnp.where(forced[:, None, :], jnp.inf, P)
+    okg = _causal_blocks(t, valid, nkv, NB, sizes.block)
+    return topk_mask(score.reshape(R * nkv, NB), okg.reshape(R * nkv, NB),
+                     sizes.topk, rows=rows).reshape(R, nkv, NB)
+
+
 def choose_blocks(q, ck_seq, t, valid, sizes: BlockSizes):
     """-> [R, nkv, NB] bool: the blocks each (query, KV group) attends;
     every block j <= t // block at or under `dense_len`; none for a row
@@ -230,17 +260,9 @@ def choose_blocks(q, ck_seq, t, valid, sizes: BlockSizes):
     with jax.named_scope("dyn.attn_index"):
         P = block_scores(q, ck_seq, t, sizes)
     with jax.named_scope("dyn.attn_select"):
-        R, nkv, NB = P.shape
-        j = jnp.arange(NB)[None, :]
-        own = (t // sizes.block)[:, None]
-        ok = (j <= own) & valid[:, None]
-        forced = (j < sizes.init_blocks) \
-            | (j > own - sizes.window // sizes.block)
-        score = jnp.where(forced[:, None, :], jnp.inf, P)
-        okg = jnp.broadcast_to(ok[:, None, :], P.shape)
-        top = topk_mask(score.reshape(R * nkv, NB), okg.reshape(R * nkv, NB),
-                        sizes.topk).reshape(R, nkv, NB)
-        return jnp.where((t + 1 <= sizes.dense_len)[:, None, None], okg, top)
+        return jnp.where((t + 1 <= sizes.dense_len)[:, None, None],
+                         _causal_blocks(t, valid, *P.shape[1:], sizes.block),
+                         _forced_topk(P, t, valid, sizes))
 
 
 # ---------------------------------------------------------------------------
@@ -337,11 +359,72 @@ def sparse_decode_attention(q, k_cache, v_cache, ck, layer, block_tables,
 # ---------------------------------------------------------------------------
 
 
+def choice_impl(attn_impl: str, rows: int) -> str:
+    """What the RESOLVED `attn_impl` (paged_attention.
+    resolve_decode_impl) means for the choice of a prefill row of `rows`
+    queries: ops/pallas_block_choice.py's kernel where the impl names
+    one and the ROW COUNT fills a tile of it (a prompt-sized input), the
+    jnp form (`block_scores` a `_SCORE_QUERIES` block under `lax.map`)
+    elsewhere: a decode step's 8 rows score [8, 32, 3128] = 3 MB,
+    nothing to win.  Asked by the traced program and by the host's
+    counts alike."""
+    from .pallas_block_choice import TILE_QUERIES
+
+    if attn_impl in PALLAS_IMPLS and rows >= TILE_QUERIES:
+        return attn_impl
+    return "jnp"
+
+
+@partial(
+    # dynlint: disable=DYN001 kernel-level jit: engine dispatch reaches this inside already-watched programs (prefill / prefill_batched); `layer` is traced, so one trace serves every sparse layer of a program
+    jax.jit, static_argnames=("sizes", "interpret"))
+def _choice_kernel(q, ck, layer, table, positions, valid, sizes: BlockSizes,
+                   interpret: bool):
+    """`prefill_block_choice` with the scores made on chip, every query
+    of the row in one call.  A chunk with no valid query past
+    `dense_len` scores and searches nothing."""
+    from .pallas_block_choice import block_scores_pallas
+
+    nkv = ck.shape[3]
+    NB = table.shape[0] * ck.shape[2] * sizes.stride // sizes.block
+    dense = positions + 1 <= sizes.dense_len
+    read = valid & ~dense
+    all_of = partial(_causal_blocks, positions, valid, nkv, NB, sizes.block)
+
+    # behind a barrier: XLA lays a gather's operand out for what reads the
+    # result, and the kernel's layout of the keys has it relay the WHOLE
+    # pool a layer (the jnp form's einsum takes the pages as they lie)
+    ck_seq = jax.lax.optimization_barrier(ck[layer, table])
+    ck_seq = ck_seq.reshape(-1, *ck.shape[3:])             # [NC, nkv, hd]
+
+    def choose():
+        with jax.named_scope("dyn.attn_index"):
+            P = block_scores_pallas(q, ck_seq, positions, read, sizes,
+                                    interpret=interpret)
+        with jax.named_scope("dyn.attn_select"):
+            return jnp.where(dense[:, None, None], all_of(),
+                             _forced_topk(P, positions, valid, sizes,
+                                          _SEARCH_ROWS))
+
+    return jax.lax.cond(jnp.any(read), choose, all_of)
+
+
 def prefill_block_choice(q, ck, layer, table, positions, valid,
-                         sizes: BlockSizes):
-    """One row: q [T, nh, hd] at `positions` -> [T, nkv, NB] bool,
-    `_SCORE_QUERIES` queries at a time."""
-    T = q.shape[0]
+                         sizes: BlockSizes, attn_impl: str = "jnp"):
+    """One row: q [T, nh, hd] at `positions` -> [T, nkv, NB] bool.
+    `attn_impl` as `sparse_prefill_attention`'s: by `choice_impl` one
+    kernel call over every query, or `_SCORE_QUERIES` queries at a time
+    through the jnp form."""
+    T, nkv = q.shape[0], ck.shape[3]
+    reach = table.shape[0] * ck.shape[2] * sizes.stride    # tokens
+    if reach <= sizes.dense_len:
+        # no position of this table lies past dense_len: nothing to score
+        return _causal_blocks(positions, valid, nkv, reach // sizes.block,
+                              sizes.block)
+    impl = choice_impl(attn_impl, T)
+    if impl in PALLAS_IMPLS:
+        return _choice_kernel(q, ck, jnp.int32(layer), table, positions,
+                              valid, sizes, impl == "pallas_interpret")
     ck_seq = ck[jnp.int32(layer), table]              # [W, spp, nkv, hd]
     ck_seq = ck_seq.reshape(-1, *ck_seq.shape[2:])
     n = min(_SCORE_QUERIES, T)
@@ -521,7 +604,7 @@ def sparse_prefill_attention(q, k_cache, v_cache, ck, layer, block_tables,
         positions = ctx_lens[b] + jnp.arange(T, dtype=jnp.int32)
         chosen = prefill_block_choice(q[b], ck, layer, block_tables[b],
                                       positions, jnp.arange(T) < true_lens[b],
-                                      sizes)
+                                      sizes, attn_impl)
         with jax.named_scope("dyn.attn_sparse"):
             mask = _token_mask(chosen, positions, sizes.block)
             kp = _planes(k_cache, layer, block_tables[b])

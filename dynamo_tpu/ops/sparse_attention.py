@@ -186,15 +186,20 @@ def _search_kth(key, k: int, index_bits: int):
     return thr, jax.lax.fori_loop(0, index_bits, index_bit, zero)
 
 
-def _search_kth_pallas(key, k: int, index_bits: int, interpret: bool):
+def _search_kth_pallas(key, k: int, index_bits: int, interpret: bool,
+                       rows: int = 0):
     """`_search_kth` with each tile of rows resident in VMEM for all of
     its 32 + index_bits passes: the XLA form reads the [R, S] keys from
-    HBM once a pass."""
+    HBM once a pass.  `rows`: the rows a grid step (0: 16, or 8 where R
+    is no multiple of 16).  A step is a chain of dependent passes, each
+    a count across lanes, so over SHORT rows it is latency and not work:
+    4096 rows of 782 keys take 1.27 ms at 16 rows a step (my chip run,
+    PR 56); the caller that knows its rows short names more."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     R, S = key.shape
-    rows = 8 if R % 16 else 16
+    rows = rows or (8 if R % 16 else 16)
     pad = -R % rows
     if pad:
         key = jnp.pad(key, ((0, pad), (0, 0)), constant_values=_INT_MIN)
@@ -219,25 +224,27 @@ def _search_kth_pallas(key, k: int, index_bits: int, interpret: bool):
 
 
 def topk_mask(scores: jax.Array, ok: jax.Array, k: int,
-              impl: str = "auto") -> jax.Array:
+              impl: str = "auto", rows: int = 0) -> jax.Array:
     """[R, S] float32 scores, [R, S] bool `ok` (the keys a row may
     choose from) -> [R, S] bool: the k largest of a row's ok entries,
     ties to the lower index; all of them where a row has at most k.
     Exact and sort-free (`_search_kth`); `impl`: "auto" (the Pallas
     kernel on a TPU, the XLA loop elsewhere) | "xla" | "pallas" |
-    "pallas_interpret"."""
+    "pallas_interpret"; `rows`: the kernel's rows a grid step where the
+    caller knows better than its own 16 (`_search_kth_pallas`)."""
     R, S = scores.shape
     key = jnp.where(ok, _sortable(scores), jnp.int32(_INT_MIN))
     bits = max(1, (S - 1).bit_length())
     if impl == "auto":
         thr, cut = jax.lax.platform_dependent(
-            key, tpu=lambda key: _search_kth_pallas(key, k, bits, False),
+            key,
+            tpu=lambda key: _search_kth_pallas(key, k, bits, False, rows),
             default=lambda key: _search_kth(key, k, bits))
     elif impl == "xla":
         thr, cut = _search_kth(key, k, bits)
     else:
         thr, cut = _search_kth_pallas(key, k, bits,
-                                      impl == "pallas_interpret")
+                                      impl == "pallas_interpret", rows)
     idx = jnp.arange(S, dtype=jnp.int32)[None, :]
     return ((key > thr) | ((key == thr) & (idx <= cut))) & ok
 

@@ -445,6 +445,132 @@ def test_kernel_forms_give_the_jnp_forms_result(model, phase):
         assert 0 < int(n_got) <= int(n_want)
 
 
+# the choice kernel against the jnp form: (sizes, pages of 16 tokens =
+# 8 compressed keys = 2 blocks, first position, valid queries of 256).
+# A key tile is 128 blocks = 1024 tokens, a query tile 128 queries.
+_S = bsa.BlockSizes(4, 2, 8, 1, 16, 4, 300)
+CHOICE_CASES = {
+    # 784 slots in 196 blocks (the cell's 3128 / 782 in small: no
+    # multiples of 128), both key tiles visited
+    "odd_widths": (_S, 98, 1200, 256),
+    # two windows of the next block touch a block; none does
+    "pad_2": (bsa.BlockSizes(6, 2, 8, 1, 16, 4, 300), 98, 1200, 256),
+    "pad_0": (bsa.BlockSizes(2, 2, 8, 1, 16, 4, 300), 98, 1200, 256),
+    # dense_len falls inside the first query tile, inside the second
+    "crosses_dense_len": (_S._replace(dense_len=1270), 98, 1200, 256),
+    "second_tile_chooses": (_S._replace(dense_len=1400), 98, 1200, 256),
+    # nothing scored: every block j <= t // block
+    "all_dense": (_S._replace(dense_len=1456), 98, 1200, 256),
+    "short_table": (_S._replace(dense_len=1568), 98, 1200, 256),
+    # invalid rows at the chunk's end: inside a tile, a whole tile
+    "invalid_rows": (_S, 98, 1200, 150),
+    "invalid_tile": (_S, 98, 1200, 100),
+    # the frontier (block 81 of 196) lies inside the first key tile; at
+    # 1025 tokens it has just entered the second
+    "frontier_in_tile": (_S, 98, 400, 256),
+    "frontier_at_tile_edge": (_S, 98, 770, 256),
+    # the first queries see no whole window at all
+    "no_window": (_S._replace(dense_len=0), 98, 0, 256),
+}
+
+
+def _choice_inputs(case, dtype):
+    sizes, pages, ctx, true = CHOICE_CASES[case]
+    rng = np.random.default_rng(sorted(CHOICE_CASES).index(case))
+    q = jnp.asarray(rng.standard_normal((256, 4, 16)), dtype)
+    ck = jnp.asarray(rng.standard_normal((2, pages + 7, 8, 2, 16)), dtype)
+    table = jnp.asarray(rng.permutation(pages + 7)[:pages], jnp.int32)
+    return (sizes, q, ck, table, jnp.asarray(ctx + np.arange(256), jnp.int32),
+            jnp.arange(256) < true)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(CHOICE_CASES))
+def test_choice_kernel_chooses_the_jnp_forms_sets(case, dtype):
+    """`prefill_block_choice` through ops/pallas_block_choice.py's
+    kernel under the interpreter: the chosen sets are the jnp form's,
+    block for block, on random (so distinct) scores."""
+    sizes, q, ck, table, pos, valid = _choice_inputs(case, dtype)
+    want = np.asarray(bsa.prefill_block_choice(q, ck, 1, table, pos, valid,
+                                               sizes))
+    got = np.asarray(bsa.prefill_block_choice(q, ck, 1, table, pos, valid,
+                                              sizes, "pallas_interpret"))
+    assert got.shape == want.shape == (256, 2, table.shape[0] * 2)
+    assert (got == want).all()
+    t, ok = np.asarray(pos), np.asarray(valid)
+    assert not got[~ok].any()
+    dense = ok & (t + 1 <= sizes.dense_len)
+    assert (got[dense].sum(-1) == (t[dense] // 8 + 1)[:, None]).all()
+    past = ok & ~dense & (t // 8 + 1 > sizes.topk)
+    assert (got[past].sum(-1) == sizes.topk).all()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(CHOICE_CASES))
+def test_choice_kernel_scores_are_the_jnp_forms(case, dtype):
+    """P of every query that is read, to 1e-6 of its value (another
+    order of the float32 sums; the same operands and rounding points),
+    at a query tile of 32 and the rule's own; exactly 0 past a query's
+    frontier on both sides, and for a tile nobody reads."""
+    from dynamo_tpu.ops.pallas_block_choice import (
+        block_scores_pallas,
+        choice_tile,
+    )
+
+    sizes, q, ck, table, pos, valid = _choice_inputs(case, dtype)
+    ck_seq = ck[1, table].reshape(-1, 2, 16)
+    read = valid & (pos + 1 > sizes.dense_len)
+    want = np.asarray(bsa.block_scores(q, ck_seq, pos, sizes))
+    for tq in (32, 128, 0):
+        got = np.asarray(block_scores_pallas(q, ck_seq, pos, read, sizes,
+                                             tq=tq, interpret=True))
+        assert got.shape == want.shape
+        rows = np.asarray(read)
+        np.testing.assert_allclose(got[rows], want[rows], rtol=1e-6,
+                                   atol=1e-12)
+        assert ((want[rows] == 0) == (got[rows] == 0)).all()
+        tiles = rows.reshape(-1, tq or choice_tile(
+            256, 2, 16, 4, want.shape[-1], q.dtype.itemsize)).any(1)
+        assert not got.reshape(len(tiles), -1)[~tiles].any()
+
+
+@pytest.mark.parametrize("rows", [0, 128])
+def test_search_rows_a_step_leave_the_mask(rows):
+    """`topk_mask`'s kernel at its own 16 rows a grid step and at the
+    128 the prefill choice names for its short rows (300 rows of 782:
+    a padded last step), under the interpreter, against the XLA loop;
+    ties among them."""
+    from dynamo_tpu.ops.sparse_attention import topk_mask
+
+    rng = np.random.default_rng(4)
+    scores = jnp.asarray(rng.integers(0, 40, (300, 782)) / 8.0, jnp.float32)
+    ok = jnp.asarray(rng.random((300, 782)) < 0.8)
+    want = np.asarray(topk_mask(scores, ok, 64, "xla"))
+    got = np.asarray(topk_mask(scores, ok, 64, "pallas_interpret", rows))
+    assert (got == want).all() and (want.sum(-1) == 64).all()
+
+
+def test_prefill_program_runs_the_choice_kernel(model):
+    """The family's own prefill program with the choice in the kernel
+    (a bucket of 144 rows: a tile and a padded one) gives the
+    reference's logits, and the rule is the resolved impl and the row
+    count alone."""
+    params, toks, full, _ = model
+    cfg = dataclasses.replace(TINY, attn_impl="pallas_interpret")
+    logits, _ = prefill_chunks(params, cfg, toks, (N,), bucket=144)
+    np.testing.assert_allclose(logits, full[N - 1], rtol=0, atol=TOL)
+    assert bsa.choice_impl("pallas", 2048) == "pallas"
+    assert bsa.choice_impl("pallas", 128) == "pallas"
+    assert bsa.choice_impl("pallas", 8) == "jnp"          # a decode step
+    assert bsa.choice_impl("pallas_interpret", 64) == "jnp"
+    assert bsa.choice_impl("jnp", 2048) == "jnp"
+    pre = sala.prefill_token_counts(cfg, 40, 20, 144)
+    assert pre["sala_choice_kernel_queries.prefill"] \
+        == pre["sala_sparse_queries.prefill"] == 12
+    assert sala.prefill_token_counts(cfg, 40, 20, 32)[
+        "sala_choice_kernel_queries.prefill"] == 0
+
+
 @pytest.mark.parametrize("ctx,k", [((10, 40, 47, 48, 100), 4), ((), 0)])
 def test_host_counts_follow_the_equations(ctx, k):
     """decode_block_counts / prefill_token_counts against a loop over
@@ -475,6 +601,7 @@ def test_host_counts_follow_the_equations(ctx, k):
             + sum(32 - (7 - t % 8) for t in range(48, 60))
         assert pre["state_chunk_tokens.prefill"] == 40
         assert pre["state_chunk_kernel_tokens.prefill"] == 0
+        assert pre["sala_choice_kernel_queries.prefill"] == 0   # jnp form
     else:
         assert set(pre) >= {"sala_pairs_scored.prefill",
                             "recurrent_tokens.prefill"}

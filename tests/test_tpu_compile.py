@@ -607,6 +607,8 @@ def test_recurrent_decode_and_prefill_compile_for_v5e(one_chip):
         for shape in (rf"f32\[5,{B},32,128,128\]",
                       rf"f32\[{B},32,128,128\]"):
             assert not re.findall(rf"= {shape}\S* copy\(", hlo), shape
+        # nor seen a page a row (PR 56: no bitcast of the tiled pool)
+        assert not re.findall(rf"bf16\[{2 * NB},2048\]", hlo)
 
     fn = jax.jit(
         partial(JaxEngine._decode_multi_impl, ling, cfg, None, True, K,
@@ -1265,8 +1267,10 @@ def test_block_sparse_decode_and_prefill_compile_for_v5e(one_chip):
     of heads at a time, or the compiler aborts) over the pool seen as
     [layers x nkv, 1, ...]: a bitcast, never a copy; the choice is
     `topk_mask`'s kernel; the state is stepped in place.  The prefill
-    chunk runs one search and one flash pass under the block mask a
-    sparse layer.  Neither copies a pool or the state."""
+    chunk runs the choice's scores as one kernel (PR 56: no [256, 32,
+    3128] float32 scores through HBM), one search and one flash pass
+    under the block mask a sparse layer.  Neither copies a pool or the
+    state."""
     import re
 
     from dynamo_tpu.engine.core import JaxEngine
@@ -1335,7 +1339,10 @@ def test_block_sparse_decode_and_prefill_compile_for_v5e(one_chip):
         None, S((), i32)).compile()
     hlo = program.as_text()
     members_stay(hlo)
-    assert hlo.count("tpu_custom_call") == 2 * 2
+    # a sparse layer: the choice's scores (ops/pallas_block_choice.py),
+    # the search and the flash pass; no [256, 32, 3128] float32 scores
+    assert hlo.count("tpu_custom_call") == 2 * 3
+    assert not re.findall(r"f32\[256,32,3128\]", hlo)
     # the token mask a KV group is the largest thing the chunk makes
     assert program.memory_analysis().temp_size_in_bytes < 2.0e9
 
