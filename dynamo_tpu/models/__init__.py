@@ -9,8 +9,10 @@
 
 What families share lives in no family's module and imports none:
 moe.py is the expert layer (the routers, the one dropless dispatch and
-the rule that picks its form), common.py the decode burst's scan, the
-one-row prefill and a layer's index inside its kind's cache members.
+the rule that picks its form), mamba2.py the Mamba-2 mixer between its
+projections and the two lane-addressed members it keeps, common.py the
+decode burst's scan, the one-row prefill and a layer's index inside its
+kind's cache members.
 
 The engine binds a family once via get_family(cfg) and never branches on
 architecture again — Llama/Qwen/Mixtral (llama.py, GQA cache), the
@@ -28,9 +30,12 @@ BLOCKS of keys from mean-pooled compressed keys, one set a KV group,
 beside lightning linear-attention layers with a constant decay a head
 (minicpm_sala.py), the Qwen3-MoE-style decoder that generates by
 diffusion over blocks of a few positions under block-causal attention
-(sdar.py) and the decoder of gated short-convolution layers beside a few
-GQA layers, whose lane state is a convolution's tail alone (lfm2.py)
-serve through identical plumbing.
+(sdar.py), the decoder of gated short-convolution layers beside a few
+GQA layers, whose lane state is a convolution's tail alone (lfm2.py) and
+the dense Mamba-2 hybrid whose every layer is a mixer AND a gated MLP
+under muP multipliers, nine Mamba-2 layers to one NoPE GQA layer
+(granite_hybrid.py; its mixer is mamba2.py's, the one nemotron_h.py
+calls) serve through identical plumbing.
 
 The cache is a tuple the family owns: the engine allocates one array a
 shape, hands the tuple to every program and takes it back.  A family
@@ -78,9 +83,18 @@ given (never through the family's type):
                              overwrites the one a window before it.  A
                              lane-addressed member may also be a STATE
                              that nothing overwrites by position
-                             (ling.py and nemotron_h.py: members 2-3,
+                             (ling.py, nemotron_h.py and
+                             granite_hybrid.py: members 2-3,
                              a float32 matrix a head and the short
                              convolution's tail, a lane and layer;
+                             granite_hybrid.py's member 2 at the
+                             published widths and 64 lanes is 4.89 GB
+                             in ONE array, the largest member the
+                             engine holds, stepped and put where it
+                             lies and never copied; its tail is a
+                             lane's rows end to end, and it has no
+                             counters member: nothing is held as a
+                             share;
                              minicpm_sala.py: member 3, the matrix
                              alone; lfm2.py: member 2, the short
                              convolution's tail ALONE, two rows of the
@@ -158,6 +172,7 @@ given (never through the family's type):
 from . import (
     cohere2,
     deepseek,
+    granite_hybrid,
     keye,
     lfm2,
     ling,
@@ -169,6 +184,7 @@ from . import (
 )
 from .cohere2 import Cohere2Config
 from .deepseek import DeepseekConfig
+from .granite_hybrid import GraniteHybridConfig
 from .keye import KeyeConfig
 from .lfm2 import Lfm2Config
 from .ling import LingConfig
@@ -181,7 +197,7 @@ from .sdar import SdarConfig
 PRESETS = {**llama.PRESETS, **deepseek.PRESETS, **mimo.PRESETS,
            **keye.PRESETS, **ling.PRESETS, **nemotron_h.PRESETS,
            **cohere2.PRESETS, **minicpm_sala.PRESETS, **sdar.PRESETS,
-           **lfm2.PRESETS}
+           **lfm2.PRESETS, **granite_hybrid.PRESETS}
 
 
 def get_family(cfg):
@@ -204,6 +220,8 @@ def get_family(cfg):
         return sdar
     if isinstance(cfg, Lfm2Config):
         return lfm2
+    if isinstance(cfg, GraniteHybridConfig):
+        return granite_hybrid
     if isinstance(cfg, LlamaConfig):
         return llama
     raise TypeError(f"unknown model config type: {type(cfg).__name__}")
@@ -212,6 +230,7 @@ def get_family(cfg):
 __all__ = [
     "Cohere2Config",
     "DeepseekConfig",
+    "GraniteHybridConfig",
     "KeyeConfig",
     "Lfm2Config",
     "LingConfig",

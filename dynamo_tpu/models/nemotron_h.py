@@ -57,7 +57,6 @@ for them; this is the autoregressive `nemotron_h` tower it declares.
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -67,37 +66,18 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..ops.lane_state import (
-    lanes_keep,
-    lanes_plan,
-    lanes_step,
-    resolve_state_impl,
-    rows_put,
-    rows_start,
-    rows_target,
-)
-from ..ops.packed_prefill import (
-    packed_prefill_attention,
-    resolve_packed_impl,
-    write_packed_kv,
-)
+from ..ops.lane_state import lanes_plan, rows_target
+from ..ops.packed_prefill import packed_prefill_attention, write_packed_kv
 from ..ops.paged_attention import (
     PALLAS_IMPLS,
     paged_attention_decode,
     resolve_decode_impl,
     write_token_kv,
 )
-from ..ops.pallas_lane_state import ssd_lanes_step
-from ..ops.ssm import (
-    gated_group_norm,
-    ssd_chunked,
-    ssd_step,
-    ssm_conv,
-    ssm_conv_step,
-    ssm_dt,
-)
+from . import mamba2
 from .common import burst_scan, prefill_one_row
 from .llama import _attn_out, _logits, _qkv, rms_norm
+from .mamba2 import Mamba2Dims, mm as _mm
 from .moe import (
     ds_router,
     moe_dispatch,
@@ -192,13 +172,23 @@ class NemotronHConfig:
                      for i, k in enumerate(self.pattern))
 
     @property
+    def ssm(self) -> Mamba2Dims:
+        """The Mamba-2 mixer's widths (models/mamba2.py)."""
+        return Mamba2Dims(
+            heads=self.ssm_heads, head_dim=self.ssm_head_dim,
+            state=self.ssm_state, groups=self.ssm_groups,
+            conv_width=self.conv_width, chunk=self.ssm_chunk,
+            eps=self.rms_eps, dtype=self.dtype,
+            state_dtype=self.state_dtype)
+
+    @property
     def ssm_inner(self) -> int:
-        return self.ssm_heads * self.ssm_head_dim
+        return self.ssm.inner
 
     @property
     def conv_dim(self) -> int:
         """x, B and C side by side."""
-        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+        return self.ssm.conv_dim
 
     @property
     def q_dim(self) -> int:
@@ -251,12 +241,8 @@ def kv_cache_shapes(cfg: NemotronHConfig, num_blocks: int, block_size: int,
     have one entry a lane and Mamba block."""
     na, nm = len(cfg.layers_of(ATTN)), len(cfg.layers_of(MAMBA))
     pool = (na, cfg.n_kv_heads, num_blocks, cfg.head_dim, block_size)
-    return (
-        pool, pool,
-        (nm, lanes, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
-        (nm, lanes, cfg.conv_width - 1, cfg.conv_dim),
-        (len(KV_COUNTERS),),
-    )
+    return (pool, pool) + mamba2.state_shapes(cfg.ssm, nm, lanes) \
+        + ((len(KV_COUNTERS),),)
 
 
 def kv_cache_dtypes(cfg: NemotronHConfig) -> Tuple[Any, ...]:
@@ -271,67 +257,24 @@ def kv_cache_specs() -> Tuple[P, ...]:
 def decode_block_counts(cfg: NemotronHConfig, ctx: np.ndarray, k: int,
                         block_size: int, lanes: int, table_width: int,
                         attn_impl: str) -> Dict[str, int]:
-    """Host-side counts for a decode burst of `k` steps over active
-    lanes holding `ctx` tokens (engine/core.py _count_decode_attn).  The
-    attention blocks' cache blocks, summed over layers and steps: `live`
-    what the mask needs, `read` what the impl that runs moves (the kernel
-    each step's live blocks, the gathering read every lane's whole
-    table).  And the state pool's lanes: each active lane moves one
-    state a Mamba block a step, out of `lanes` slots that a step's
-    program runs over; `state_live` those lane steps over the Mamba
-    blocks, `state_moved` the lanes whose state the step that runs moves
-    (`state_impl`: the kernel the busy ones, the jnp step every slot)."""
-    na, nm = len(cfg.layers_of(ATTN)), len(cfg.layers_of(MAMBA))
-    live = int((-(-(ctx[:, None] + 1 + np.arange(k)[None, :])
-                  // block_size)).sum())
-    read = live if attn_impl in PALLAS_IMPLS else k * lanes * table_width
-    return {
-        "decode_attn_live_blocks": na * live,
-        "decode_attn_read_blocks": na * read,
-        "ssm_lane_steps.decode": k * len(ctx),
-        "ssm_slot_steps.decode": k * lanes,
-        "state_live_lane_steps.decode": nm * k * len(ctx),
-        "state_moved_lane_steps.decode": nm * k * (
-            len(ctx) if state_impl(cfg, attn_impl) in PALLAS_IMPLS
-            else lanes),
-    }
+    """mamba2.decode_counts over this family's `*` and `M` blocks."""
+    return mamba2.decode_counts(
+        cfg.ssm, len(cfg.layers_of(ATTN)), len(cfg.layers_of(MAMBA)), ctx,
+        k, block_size, lanes, table_width, attn_impl)
 
 
 def state_impl(cfg: NemotronHConfig, attn_impl: str) -> str:
     """The impl of the state's decode step under `attn_impl`, by the
     state's own conditions (ops/lane_state.resolve_state_impl), asked by
     the traced step and by the host's counts alike."""
-    return resolve_state_impl(attn_impl, jax.default_backend(),
-                              cfg.ssm_head_dim, cfg.ssm_state,
-                              cfg.state_dtype)
+    return mamba2.state_impl(cfg.ssm, attn_impl)
 
 
 def prefill_token_counts(cfg: NemotronHConfig, pos: int, chunk: int,
                          bucket: int = 0) -> Dict[str, int]:
-    """Host-side counts for `chunk` prompt tokens prefilled from
-    position `pos` in a program of `bucket` rows: tokens through the
-    chunked scan, the bucket's rows beyond them (what padding costs the
-    scan), tokens in a program that started from a carried state, rows
-    that started from zeros; the tokens the attention blocks' prefill
-    read took, and those of them whose program ran it in the kernel: the
-    rule the traced read applies to its cache
-    (ops/packed_prefill.resolve_packed_impl), asked from the host as
-    `deepseek.mla_prefill_impl` asks its own.  The host has no cache to
-    show: it asks about the engine's default pool, 128-token blocks in
-    the configuration's dtype, and about one row a program (the stream
-    is the bucket; `Bp` rows make a stream `Bp` buckets long)."""
-    gqa = len(cfg.layers_of(ATTN)) * chunk
-    kernel = resolve_packed_impl(
-        cfg.packed_attn_impl, jax.default_backend(), 128, cfg.head_dim,
-        cfg.dtype, bucket, cfg.n_heads // cfg.n_kv_heads) in PALLAS_IMPLS
-    return {
-        "ssm_tokens.prefill": chunk,
-        "ssm_pad_tokens.prefill": max(bucket - chunk, 0),
-        "ssm_carried_tokens.prefill": chunk if pos > 0 else 0,
-        "ssm_resets": int(chunk > 0 and pos == 0),
-        "gqa_prefill_tokens.prefill": gqa,
-        "gqa_prefill_kernel_tokens.prefill": gqa if kernel else 0,
-    }
+    """mamba2.prefill_counts over this family's `*` blocks."""
+    return mamba2.prefill_counts(cfg, len(cfg.layers_of(ATTN)), pos, chunk,
+                                 bucket)
 
 
 # ---------------------------------------------------------------------------
@@ -342,15 +285,8 @@ def prefill_token_counts(cfg: NemotronHConfig, pos: int, chunk: int,
 def init_params(cfg: NemotronHConfig, key: jax.Array,
                 place=lambda tree: tree) -> Dict[str, Any]:
     """Random-init parameter pytree; `place` as in llama.init_params.
-    What a Mamba block adds to its matrices (A_log, dt_bias, D, the
-    convolution and its bias, the gated norm's weight) is random so that
-    leaving one out changes the answer."""
-
-    def dense(key, shape, scale=None):
-        scale = scale if scale is not None else 1.0 / math.sqrt(shape[-2])
-        return (jax.random.normal(key, shape, jnp.float32) * scale).astype(
-            cfg.dtype)
-
+    A Mamba block's parameters are mamba2.init_mixer's."""
+    dense = mamba2.dense_init(cfg.dtype)
     keys = jax.random.split(key, cfg.n_layers + 3)
     params: Dict[str, Any] = {
         "embedding": dense(keys[0], (cfg.vocab_size, cfg.d_model),
@@ -360,7 +296,7 @@ def init_params(cfg: NemotronHConfig, key: jax.Array,
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(keys[1], (cfg.d_model, cfg.vocab_size))
     params = place(params)
-    d, H = cfg.d_model, cfg.ssm_heads
+    d = cfg.d_model
     f, held = cfg.moe_ffn_dim, cfg.held[1]
     layers = []
     for li, kind in enumerate(cfg.pattern):
@@ -368,27 +304,7 @@ def init_params(cfg: NemotronHConfig, key: jax.Array,
         layer: Dict[str, Any] = {
             "norm": {"norm": jnp.ones((d,), jnp.float32)}}
         if kind == MAMBA:
-            layer.update({
-                # z | x B C | dt side by side: one matmul
-                "w_in": dense(k[0], (d, cfg.ssm_inner + cfg.conv_dim + H)),
-                "conv_w": (jax.random.normal(
-                    k[1], (cfg.conv_width, cfg.conv_dim), jnp.float32)
-                    * 0.5).astype(cfg.dtype),
-                "conv_b": (jax.random.normal(
-                    k[2], (cfg.conv_dim,), jnp.float32) * 0.5
-                    ).astype(cfg.dtype),
-                # dt = softplus(. + dt_bias) around 0.01 ... 1, A in
-                # -(1 ... 16): a token forgets between nothing and most
-                "dt_bias": jax.random.uniform(k[3], (H,), jnp.float32,
-                                              -4.0, 0.5),
-                "a_log": jnp.log(jax.random.uniform(k[4], (H,), jnp.float32,
-                                                    1.0, 16.0)),
-                "d_skip": 1.0 + 0.5 * jax.random.normal(k[5], (H,),
-                                                        jnp.float32),
-                "gate_norm": {"norm": 1.0 + 0.1 * jax.random.normal(
-                    k[6], (cfg.ssm_inner,), jnp.float32)},
-                "w_out": dense(k[7], (cfg.ssm_inner, d)),
-            })
+            layer.update(mamba2.init_mixer(cfg.ssm, d, k, dense))
         elif kind == ATTN:
             layer.update({
                 "wq": dense(k[0], (d, cfg.q_dim)),
@@ -415,44 +331,6 @@ def init_params(cfg: NemotronHConfig, key: jax.Array,
 # ---------------------------------------------------------------------------
 # building blocks
 # ---------------------------------------------------------------------------
-
-
-def _mm(a: jax.Array, w: jax.Array) -> jax.Array:
-    """a @ w with the accumulator kept: operands in the weights' dtype,
-    the result float32 (what the MXU sums in anyway)."""
-    return jnp.matmul(a, w, preferred_element_type=jnp.float32)
-
-
-@jax.named_scope("dyn.ssm_proj")
-def _ssm_in(layer, cfg: NemotronHConfig, h: jax.Array):
-    """h [..., d] -> (z [..., inner], x B C side by side before the
-    convolution [..., conv_dim], dt~ [..., H]), float32."""
-    zxd = _mm(h.astype(cfg.dtype), layer["w_in"])
-    a, b = cfg.ssm_inner, cfg.ssm_inner + cfg.conv_dim
-    return zxd[..., :a], zxd[..., a:b], zxd[..., b:]
-
-
-def _ssm_heads(cfg: NemotronHConfig, conv: jax.Array):
-    """The convolved channels [..., conv_dim] -> x [..., H, P], B and C
-    [..., G, N]."""
-    gn = cfg.ssm_groups * cfg.ssm_state
-    x, b, c = (conv[..., :cfg.ssm_inner],
-               conv[..., cfg.ssm_inner:cfg.ssm_inner + gn],
-               conv[..., cfg.ssm_inner + gn:])
-    lead = conv.shape[:-1]
-    return (x.reshape(*lead, cfg.ssm_heads, cfg.ssm_head_dim),
-            b.reshape(*lead, cfg.ssm_groups, cfg.ssm_state),
-            c.reshape(*lead, cfg.ssm_groups, cfg.ssm_state))
-
-
-def _ssm_out(layer, cfg: NemotronHConfig, y: jax.Array, z: jax.Array):
-    """y [..., H, P] float32 the scan's read, z [..., inner] the gate's
-    projection -> [..., d]."""
-    g = gated_group_norm(y.reshape(*y.shape[:-2], cfg.ssm_inner), z,
-                         layer["gate_norm"]["norm"], cfg.ssm_groups,
-                         cfg.rms_eps)
-    with jax.named_scope("dyn.ssm_proj"):
-        return _mm(g.astype(cfg.dtype), layer["w_out"])
 
 
 @jax.named_scope("dyn.mlp")
@@ -531,24 +409,10 @@ def prefill_batched(
         # the float32 stream, normed; a mixer casts it for its matmuls
         h = rms_norm(x, layer["norm"]["norm"], cfg.rms_eps)
         if kind == MAMBA:
-            z, xbc, dt = _ssm_in(layer, cfg, h)
-            t0 = rows_start(tail, pli, lanes, fresh)
-            s0 = rows_start(state, pli, lanes, fresh).astype(jnp.float32)
-            conv, t1 = jax.vmap(ssm_conv, in_axes=(0, 0, None, 0, None))(
-                xbc, t0, layer["conv_w"], true_lens, layer["conv_b"])
-            # padding: no decay (dt 0) and nothing fed (x 0)
-            conv = jnp.where(valid[..., None], conv, 0.0)
-            dt = jnp.where(valid[..., None], ssm_dt(dt, layer["dt_bias"]),
-                           0.0)
-            xs, b, c = _ssm_heads(cfg, conv)
-            y, s1 = jax.vmap(
-                partial(ssd_chunked, chunk=cfg.ssm_chunk),
-                in_axes=(0, 0, None, 0, 0, None, 0))(
-                xs, dt, -jnp.exp(layer["a_log"]), b, c, layer["d_skip"],
-                s0)
-            state = rows_put(state, pli, put, s1)
-            tail = rows_put(tail, pli, put, t1)
-            x = x + _ssm_out(layer, cfg, y, z)
+            y, state, tail = mamba2.mixer_prefill(
+                layer, cfg.ssm, h, state, tail, pli, lanes, fresh, put,
+                valid, true_lens)
+            x = x + y
         elif kind == ATTN:
             q, k, v = _qkv(layer, cfg,
                            h.astype(cfg.dtype).reshape(Bp * T, -1), None)
@@ -614,17 +478,10 @@ def decode(
         # the float32 stream, normed; a mixer casts it for its matmuls
         h = rms_norm(x, layer["norm"]["norm"], cfg.rms_eps)
         if kind == MAMBA:
-            z, xbc, dt = _ssm_in(layer, cfg, h)
-            conv, t1 = ssm_conv_step(xbc, tail[pli], layer["conv_w"],
-                                     layer["conv_b"])
-            xs, b, c = _ssm_heads(cfg, conv)
-            rule = (xs, ssm_dt(dt, layer["dt_bias"]),
-                    -jnp.exp(layer["a_log"]), b, c, layer["d_skip"])
-            y, state = lanes_step(state, pli, state_plan,
-                                  partial(ssd_step, *rule),
-                                  partial(ssd_lanes_step, *rule), s_impl)
-            tail = tail.at[pli].set(lanes_keep(live, t1, tail[pli]))
-            x = x + _ssm_out(layer, cfg, y, z)
+            y, state, tail = mamba2.mixer_decode(
+                layer, cfg.ssm, h, state, tail, pli, state_plan, s_impl,
+                live)
+            x = x + y
         elif kind == ATTN:
             q, k, v = _qkv(layer, cfg, h[:, None, :].astype(cfg.dtype), None)
             k_cache, v_cache = write_token(
